@@ -510,8 +510,12 @@ def _dataset_kwargs(entry: dict, args: argparse.Namespace) -> dict:
     return kw
 
 
-def run(args: argparse.Namespace) -> RunResult:
-    """Build the full stack from parsed flags and train."""
+def run(args: argparse.Namespace, devices=None) -> RunResult:
+    """Build the full stack from parsed flags and train.
+
+    ``devices``: the devices the mesh is built over (default: all of
+    ``jax.devices()``) — the ``build_mesh(devices=...)`` seam for a
+    caller that runs one job on a subset of a host's chips."""
     import jax
 
     from tensorflow_train_distributed_tpu.runtime import faults
@@ -590,6 +594,9 @@ def run(args: argparse.Namespace) -> RunResult:
                 "elastic relaunch: virtual CPU platform shrunk to %d "
                 "device(s) (%s)", args.cpu_devices, ENV_ELASTIC_DEVICES)
 
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform or args.cpu_devices:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,
@@ -627,7 +634,7 @@ def run(args: argparse.Namespace) -> RunResult:
     # 2. Mesh from strategy preset (+ explicit axis overrides).
     entry = registry.get_entry(args.config)
     strategy = args.strategy or entry["strategy"]
-    devices = list(jax.devices())
+    devices = list(jax.devices() if devices is None else devices)
     if elastic_devices is not None and elastic_devices < len(devices):
         # Real-backend elastic relaunch: the dead chips may still be
         # enumerable for a while — pin the mesh to the surviving count.
@@ -790,7 +797,7 @@ def run(args: argparse.Namespace) -> RunResult:
         # Keras validation_data semantics imply HELD-OUT data; without
         # --eval-split the val_* numbers measure the training
         # distribution — fine for smoke runs, misleading for model
-        # selection. Say so loudly rather than silently (VERDICT r2).
+        # selection. Say so loudly rather than silently.
         logger.warning(
             "evaluation will run on the TRAINING distribution (no "
             "--eval-split): val_* metrics are not held-out generalization "

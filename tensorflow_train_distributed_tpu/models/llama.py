@@ -223,7 +223,7 @@ def _checkpoint_policy(cfg: LlamaConfig):
         # "no_ffn" has NO outer block checkpoint (callers must not wrap;
         # gate on wants_outer_remat below).  The exclusion of the [B,S,ffn] SwiGLU
         # hiddens — the buffers that dominate the no-remat footprint
-        # (PROFILE.md) — is STRUCTURAL: DecoderBlock wraps the MlpBlock
+        # (measured on v5e) — is STRUCTURAL: DecoderBlock wraps the MlpBlock
         # in an inner nothing-saveable nn.remat, and everything outside
         # it is saved scan-normally.  Two approaches that do NOT work,
         # both verified empirically: (a) save_anything_except_these_names

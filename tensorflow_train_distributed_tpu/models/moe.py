@@ -245,6 +245,15 @@ class _StackedKernel(nn.Module):
             self.shape)
 
 
+# Pallas interpret mode for the grouped matmul is a TEST seam, not a
+# fallback: the megablox kernel lowers only for TPU, so the CPU suite
+# (tests/conftest.py), the CPU dry run (__graft_entry__) and bench_moe's
+# ``--platform cpu`` smoke switch it on, each explicitly.  Nothing else
+# does — on any other backend dispatch="gmm" gets the compiler's error,
+# never a silently interpreted kernel.
+GMM_INTERPRET = False
+
+
 def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):
     """Megablox grouped matmul: rows of ``lhs`` hit the ``rhs`` slice of
     their group (``group_sizes`` [E] row counts, summing to lhs rows).
@@ -541,7 +550,7 @@ class MoEMlpBlock(nn.Module):
         y = _GmmExperts(num_experts=cfg.num_experts, hidden=cfg.ffn_size,
                         dtype=cfg.dtype, name="experts")(
             flat, top_e, gate_w,
-            interpret=jax.default_backend() != "tpu", ep_mesh=ep_mesh)
+            interpret=GMM_INTERPRET, ep_mesh=ep_mesh)
         return nn.with_logical_constraint(
             y.reshape(groups, group_size, d_model),
             ("batch", "length", "embed"))

@@ -66,8 +66,8 @@ def _setup():
              strategy="dp", global_batch_size=1024,
              learning_rate=0.4, lr_schedule="resnet_steps",
              warmup_ratio=0.05)
-    # s2d + 2-strided BN statistics (the BN-HBM-traffic attack variant,
-    # PROFILE.md): CLI-trainable so its convergence can be certified
+    # s2d + 2-strided BN statistics (the BN-HBM-traffic attack
+    # variant): CLI-trainable so its convergence can be certified
     # against resnet50_imagenet_s2d before it claims the headline.
     register("resnet50_imagenet_s2d_bnsub",
              task_factory=lambda: resnet.make_task(

@@ -35,7 +35,7 @@ class ResNetConfig:
     bn_epsilon: float = 1e-5
     # BN statistics over a spatially strided subset (1 = exact).  The
     # measured v5e step-time ceiling is BatchNorm HBM traffic, not conv
-    # FLOPs (PROFILE.md: ~half the step in BN statistics/backward
+    # FLOPs (July v5e trace: ~half the step in BN statistics/backward
     # reductions); stride 2 reads 1/4 of each activation for the mean/var
     # passes while normalizing the full tensor — at batch 256 the
     # estimate still pools >800k samples/channel in the first stage.

@@ -210,7 +210,8 @@ def _splash_window_friendly(q, k, sinks, mask, force_reference) -> bool:
     OPT-IN (``TTD_SPLASH=1``), not the default: on silicon the chunked
     jnp path beat splash at the measured shape — llama_125m b8×s2048
     w512: chunked 58.1k tok/s (full remat) vs splash 43.8k (full remat)
-    / 53.7k (+no_ffn, which splash alone enables) — PROFILE.md round-4.
+    / 53.7k (+no_ffn, which splash alone enables) —
+    profiles/bench/last_tpu_result.json, 2026-07-31.
     Splash's remat freedom did not make up the kernel gap there; until a
     shape is measured where it wins, the measured winner stays default.
     """
@@ -239,7 +240,7 @@ def splash_window_attention(q, k, v, *, window: int,
     blocks through VMEM and SKIPPING fully-masked blocks — so unlike the
     jnp chunked path nothing [B,H,chunks,c,c+w]-shaped ever
     materializes, which removes the full-remat pairing constraint the
-    chunked path has (PROFILE.md: its saved f32 score stacks OOM a 16
+    chunked path has (measured: its saved f32 score stacks OOM a 16
     GiB chip under no-remat/no_ffn).  q/k/v: [B, H, S, D] with KV
     already repeated to full heads (the caller's GQA contract).
 
@@ -358,10 +359,31 @@ def multihead_attention_kernel(
         SegmentIds, flash_attention,
     )
 
+    from jax.sharding import PartitionSpec as P
+
+    from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
+        activation_spec, per_shard,
+    )
+
     scale = (softmax_scale if softmax_scale is not None
              else q.shape[-1] ** -0.5)
-    return flash_attention(
-        q, k, v,
-        segment_ids=(None if segment_ids is None
-                     else SegmentIds(q=segment_ids, kv=segment_ids)),
-        causal=causal, sm_scale=scale)
+
+    def kernel(q, k, v, seg=None):
+        return flash_attention(
+            q, k, v,
+            segment_ids=(None if seg is None
+                         else SegmentIds(q=seg, kv=seg)),
+            causal=causal, sm_scale=scale)
+
+    # [B, H, S, D]: batches and heads are independent, the sequence is
+    # whole here (the sequence-parallel path is ring_attention's).
+    def qkv_spec(mesh):
+        return activation_spec(mesh, q.shape, heads_dim=1)
+
+    def in_specs(mesh):
+        spec = qkv_spec(mesh)
+        seg = () if segment_ids is None else (P(spec[0], None),)
+        return (spec, spec, spec) + seg
+
+    args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
+    return per_shard(kernel, in_specs, qkv_spec)(*args)

@@ -55,12 +55,16 @@ blocks, grid innermost over the reduction axis).
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from tensorflow_train_distributed_tpu.runtime import compat
 
 _NEG = -1e30  # big finite negative: avoids -inf − -inf = NaN in masking
 
@@ -78,7 +82,7 @@ def env_flag(name: str) -> bool:
 def _use_pallas(override: Optional[bool]) -> bool:
     if override is not None:
         return override
-    # Kill switch for on-chip A/B (tools/chip_playbook.sh): the custom-VJP
+    # Kill switch for on-chip A/B: the custom-VJP
     # kernels block XLA fusion around them, so their win must be measured,
     # not assumed — TTD_NO_PALLAS=1 falls back to the pure-jax path.
     # ("0"/"false"/empty mean OFF — a raw truthiness check would make
@@ -86,6 +90,56 @@ def _use_pallas(override: Optional[bool]) -> bool:
     if env_flag("TTD_NO_PALLAS"):
         return False
     return jax.default_backend() == "tpu"
+
+
+def _unpartitioned_axes(mesh) -> dict:
+    """{axis: size} of the mesh axes GSPMD still partitions over: more
+    than one device wide and not already manual (inside an enclosing
+    shard_map, e.g. the grad-quant step's per-data-shard program)."""
+    return {a: n for a, n in mesh.shape.items()
+            if n > 1 and a not in mesh.manual_axes}
+
+
+def activation_spec(mesh, shape, *, heads_dim: Optional[int] = None):
+    """Where a kernel operand's independent dims ride the mesh, by the
+    framework's activation layout (``parallel.sharding.DEFAULT_RULES``):
+    dim 0 is the batch, over (data, fsdp); ``heads_dim`` over tensor;
+    dim 1 of a headless >= 3-D operand is the sequence, over seq.  A dim
+    whose size the axes do not divide, and every other dim, stays
+    whole."""
+    sizes = _unpartitioned_axes(mesh)
+    dims = [None] * len(shape)
+    batch = tuple(a for a in ("data", "fsdp") if a in sizes)
+    if batch and shape[0] % math.prod(sizes[a] for a in batch) == 0:
+        dims[0] = batch
+    if heads_dim is not None:
+        if "tensor" in sizes and shape[heads_dim] % sizes["tensor"] == 0:
+            dims[heads_dim] = "tensor"
+    elif (len(shape) >= 3 and "seq" in sizes
+          and shape[1] % sizes["seq"] == 0):
+        dims[1] = "seq"
+    return P(*dims)
+
+
+def per_shard(kernel, in_specs, out_specs):
+    """``kernel`` run on each device's shard under the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map" is the TPU compiler's own error), so under any mesh of
+    more than one device the kernels whose rows/heads are independent
+    run inside one; the specs say which dims those are
+    (``activation_spec``).  ``in_specs``/``out_specs`` are callables of
+    the mesh, evaluated only when there is one.  No mesh (one chip, the
+    serving engine's default): the kernel is called as is."""
+    mesh = compat.get_abstract_mesh()
+    if mesh is None or mesh.empty or not _unpartitioned_axes(mesh):
+        return kernel
+    # Every axis not manual yet, the one-device ones too: Mosaic lowers
+    # only where the whole mesh is manual.
+    return compat.shard_map(
+        kernel, mesh=mesh, in_specs=in_specs(mesh),
+        out_specs=out_specs(mesh), check_vma=False,
+        axis_names=frozenset(mesh.axis_names) - set(mesh.manual_axes))
 
 
 # ---------------------------------------------------------------------------
@@ -462,10 +516,18 @@ def rms_norm(x, scale, *, epsilon: float = 1e-5,
     """Fused RMSNorm. ``x``: [..., D]; ``scale``: [D]."""
     if not _use_pallas(use_pallas):
         return rms_norm_reference(x, scale, epsilon=epsilon)
-    d = x.shape[-1]
-    x2 = x.reshape(-1, d)
-    y = _rms_norm_pallas(x2, scale.reshape(1, d), epsilon, interpret)
-    return y.reshape(x.shape)
+
+    def kernel(x, scale):
+        d = x.shape[-1]
+        y = _rms_norm_pallas(x.reshape(-1, d), scale.reshape(1, d),
+                             epsilon, interpret)
+        return y.reshape(x.shape)
+
+    # Rows are independent: each device normalizes its own shard.
+    return per_shard(
+        kernel,
+        lambda mesh: (activation_spec(mesh, x.shape), P(None)),
+        lambda mesh: activation_spec(mesh, x.shape))(x, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -613,8 +675,19 @@ def fused_cross_entropy(logits, labels, *,
     softmax in HBM.  ``logits``: [..., V]; ``labels``: int [...]."""
     if not _use_pallas(use_pallas):
         return cross_entropy_reference(logits, labels)
-    v = logits.shape[-1]
-    flat = logits.reshape(-1, v)
-    lab = labels.reshape(-1, 1).astype(jnp.int32)
-    out = _cross_entropy_pallas(flat, lab, interpret)
-    return out.reshape(labels.shape)
+
+    def kernel(logits, labels):
+        out = _cross_entropy_pallas(
+            logits.reshape(-1, logits.shape[-1]),
+            labels.reshape(-1, 1).astype(jnp.int32), interpret)
+        return out.reshape(labels.shape)
+
+    # Examples are independent and each holds its whole vocab row (a
+    # vocab-sharded head takes the GSPMD path — ops.losses).
+    def label_spec(mesh):
+        return P(*activation_spec(mesh, logits.shape)[:-1])
+
+    return per_shard(
+        kernel,
+        lambda mesh: (activation_spec(mesh, logits.shape), label_spec(mesh)),
+        label_spec)(logits, labels)

@@ -1,66 +1,17 @@
-"""jax version-compatibility shims.
+"""The jax sharding entry points the package uses, under one import.
 
-The codebase targets current jax (``jax.shard_map`` with ``check_vma=``,
-``jax.set_mesh``, ``jax.sharding.get_abstract_mesh``); older jaxlibs in
-some containers predate all three.  Import the symbols from here instead
-of from ``jax`` so both work: on current jax this module re-exports the
-real thing untouched, on old jax it maps onto the era's equivalents —
-``jax.experimental.shard_map`` (translating ``check_vma`` to the
-pre-rename ``check_rep``), the ``Mesh`` context manager, and the
-thread-local physical mesh (whose ``.empty`` / ``.shape`` surface
-matches what call sites read).  No other call-signature differences are
-papered over — call sites must use keyword arguments (they all do).
+There is one installation (jax 0.9): these are direct re-exports, kept
+in one module so call sites read ``compat.shard_map`` / ``compat.set_mesh``
+the same way everywhere.
 """
 
-import contextlib
-
 import jax
+from jax import set_mesh, shard_map  # noqa: F401  (re-exports)
+from jax.lax import axis_size  # noqa: F401  (re-export)
 
-try:
-    from jax import shard_map  # noqa: F401  (current jax: re-export)
-except ImportError:  # pragma: no cover - exercised only on old jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-try:
-    from jax import set_mesh  # noqa: F401  (current jax: re-export)
-except ImportError:  # pragma: no cover - exercised only on old jax
-    @contextlib.contextmanager
-    def set_mesh(mesh):
-        # Entering the Mesh sets the thread-local physical mesh that
-        # get_abstract_mesh() below reads back — same pairing as
-        # current jax's set_mesh/get_abstract_mesh, scoped to `with`.
-        with mesh:
-            yield mesh
-
-try:
-    from jax.lax import axis_size  # noqa: F401  (current jax: re-export)
-except ImportError:  # pragma: no cover - exercised only on old jax
-    def axis_size(axis_name):
-        # psum of a constant is folded to a concrete int at trace time
-        # inside shard_map, so this stays usable as a Python loop bound.
-        return jax.lax.psum(1, axis_name)
+get_abstract_mesh = jax.sharding.get_abstract_mesh
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the constructor rename:
-    current jax takes ``(sizes_tuple, names_tuple)``, old jax takes one
-    ``((name, size), ...)`` tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes),
-                                         tuple(axis_names))
-    except TypeError:  # pragma: no cover - exercised only on old jax
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
-
-
-if hasattr(jax.sharding, "get_abstract_mesh"):
-    get_abstract_mesh = jax.sharding.get_abstract_mesh
-else:  # pragma: no cover - exercised only on old jax
-    def get_abstract_mesh():
-        from jax._src.mesh import thread_resources
-
-        return thread_resources.env.physical_mesh
+    """``jax.sharding.AbstractMesh`` from parallel size/name sequences."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))

@@ -250,8 +250,10 @@ def _factory_llama(spec: dict):
         LLAMA_PRESETS,
         LlamaModel,
     )
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
     from tensorflow_train_distributed_tpu.serving import ServingEngine
 
+    compile_cache.place_compile_cache()
     cfg = LLAMA_PRESETS[spec.get("preset", "llama_tiny")]
     params = LlamaModel(cfg).init(
         jax.random.PRNGKey(int(spec.get("init_seed", 0))),

@@ -2,7 +2,7 @@
 
 The reference's north star is training runs whose loss curves match the
 baseline (BASELINE.json); these tests are the CPU-mesh scale model of
-that contract (VERDICT r3 items 5/8): a few hundred steps over several
+that contract: a few hundred steps over several
 epochs through the REAL CLI must show a decreasing loss for each family,
 and the strided-BN-statistics variant (``resnet50_s2d_bnsub``) must
 track the exact-BN baseline closely enough to be a legitimate headline

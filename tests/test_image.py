@@ -103,7 +103,7 @@ class TestDecodeAugment:
 
 class TestPerEpochAugmentation:
     """Fresh crop/flip per epoch (reference tf.data semantics), still
-    deterministic across workers and restarts (VERDICT r3 item 4)."""
+    deterministic across workers and restarts."""
 
     def test_same_record_fresh_crop_per_epoch(self):
         rng = np.random.default_rng(11)
@@ -408,7 +408,7 @@ class TestJpegTfrecordPath:
 
     def test_cli_trains_resnet_from_encoded_jpegs(self, tmp_path):
         """--data-dir of encoded images trains ResNet through the real
-        CLI (VERDICT r2 item 6 'done' criterion)."""
+        CLI."""
         from tensorflow_train_distributed_tpu import launch
 
         root = _write_corpus(str(tmp_path))

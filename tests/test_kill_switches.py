@@ -10,7 +10,7 @@ import os
 import subprocess
 import sys
 
-from tensorflow_train_distributed_tpu.runtime import chip_lock, faults
+from tensorflow_train_distributed_tpu.runtime import faults
 from tensorflow_train_distributed_tpu.testing import multiprocess
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -32,36 +32,6 @@ def test_fault_plan_armed_from_env(monkeypatch):
     monkeypatch.delenv("TTD_FAULT_PLAN")
     assert faults.arm_from_env() is None
     assert not faults.ARMED
-
-
-# ── TTD_CHIP_LOCK_HELD / TTD_CHIP_LOCK_PATH ────────────────────────────
-
-
-def test_chip_lock_inherited_via_env_flag(monkeypatch):
-    """A child of a lock holder inherits the right to run: no flock,
-    no waiting — the ``TTD_CHIP_LOCK_HELD=1`` contract."""
-    monkeypatch.setenv("TTD_CHIP_LOCK_HELD", "1")
-    with chip_lock.chip_lock(timeout=0.01) as how:
-        assert how == "inherited"
-
-
-def test_chip_lock_path_overridden_by_env(tmp_path, monkeypatch):
-    """``TTD_CHIP_LOCK_PATH`` points the advisory lock elsewhere (read
-    at import: reload under the override, restore after)."""
-    path = str(tmp_path / "chip.lock")
-    monkeypatch.setenv("TTD_CHIP_LOCK_PATH", path)
-    monkeypatch.delenv("TTD_CHIP_LOCK_HELD", raising=False)
-    importlib.reload(chip_lock)
-    try:
-        assert chip_lock.LOCK_PATH == path
-        with chip_lock.chip_lock(timeout=1.0) as how:
-            assert how == "acquired"
-            with open(path) as f:
-                assert f.read().strip() == str(os.getpid())
-        assert chip_lock.lock_holder() is None      # released
-    finally:
-        monkeypatch.delenv("TTD_CHIP_LOCK_PATH")
-        importlib.reload(chip_lock)
 
 
 # ── TTD_TRACE_CAPACITY ─────────────────────────────────────────────────

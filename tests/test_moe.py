@@ -101,7 +101,7 @@ def test_loss_includes_aux(tiny):
 
 def test_routing_health_metrics_ample_capacity(tiny):
     """Generous capacity: nothing dropped, per-expert load is a
-    distribution over kept tokens (VERDICT r3 item 6 metrics)."""
+    distribution over kept tokens."""
     import dataclasses
 
     cfg = dataclasses.replace(tiny, capacity_factor=8.0)

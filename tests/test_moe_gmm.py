@@ -8,7 +8,7 @@ dense path drops tokens but gmm still equals the no-drop oracle
 formulations share one parameter tree, so checkpoints transfer.
 
 Kernels run in pallas interpret mode on the CPU test mesh
-(``models/moe.py`` gates ``interpret`` on the backend) — slow, so
+(``tests/conftest.py`` sets ``moe.GMM_INTERPRET``) — slow, so
 shapes here are tiny.
 """
 

@@ -245,8 +245,7 @@ class TestMoePacking:
     def test_parity_divergence_onset_flagged_by_dropped_frac(self,
                                                              moe_setup):
         """Pin WHEN packed==lone parity breaks: exactly when capacity
-        binds — and dropped_frac is the runtime signal (VERDICT r3 item
-        6).  Generous capacity: dropped_frac==0 and parity holds (the
+        binds — and dropped_frac is the runtime signal.  Generous capacity: dropped_frac==0 and parity holds (the
         test above).  Binding capacity: dropped_frac>0 AND the packed
         row diverges from the lone document (earlier documents consumed
         the shared per-row budget)."""

@@ -439,7 +439,7 @@ class TestSpeculativeServing:
 
     def test_sampled_spec_matches_plain_sampled_distribution(
             self, params, monkeypatch):
-        """The VERDICT property: rejection-sampled speculative serving
+        """The output-law property: rejection-sampled speculative serving
         follows the SAME output law as plain sampled serving even with
         a disagreeing draft.  Per-position chi-square homogeneity test
         on empirical marginals over two independent 768-stream samples
@@ -690,7 +690,7 @@ def test_moe_gmm_bucketed_and_chunked_prefill_match_generate():
 
 
 def test_serve_cli_dispatch_gmm_engages_buckets_and_prefix(capsys):
-    """--dispatch at the serving CLIs (VERDICT item 6): 'gmm' applied
+    """--dispatch at the serving CLIs: 'gmm' applied
     through serve.py's shared helper frees the MoE exact-length prefill
     rule — bucketed prefill and prefix caching ENGAGE, token-identical
     to generate() — while the same checkpoint under dense dispatch

@@ -391,7 +391,7 @@ def test_interleave_budget_groups_installments(params):
 
 @pytest.mark.slow
 def test_prefix_reuse_under_overlap_with_midstream_refill(params):
-    """VERDICT gap: preload_prefix + suffix-only prefill through the
+    """preload_prefix + suffix-only prefill through the
     overlapped (and now interleaved) path, including a refill that
     hits the prefix cache MID-STREAM (submitted while chunks are in
     flight) — token-identical to the no-prefix path and to generate(),

@@ -1,0 +1,185 @@
+"""The main-path kernels compile for a TPU v5e — without one.
+
+The TPU compiler is installed here and compiles for a chip that is
+*described*, not attached (``jax.experimental.topologies``).  Interpret
+mode cannot see what it refuses: a slice off the tiling, too much VMEM,
+a Mosaic kernel GSPMD cannot partition.  Each case hands one kernel real
+widths with ``use_pallas=True`` and asserts the compiled program holds a
+``tpu_custom_call`` — so a quiet pure-jax path cannot pass either.
+
+A compile that passes here is NOT a chip run: nothing executes, so it
+says nothing about numerics or time (``chip_smoke.py`` does, on the
+chip).  Kernels only — whole-program compiles (the train step, the
+engine's programs) are rehearsed from scratch scripts before a chip
+call, not in tier-1.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from tensorflow_train_distributed_tpu.ops import attention, pallas_kernels as pk
+from tensorflow_train_distributed_tpu.runtime.mesh import AXES
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e 2x2; skipped only where it cannot be described."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """An entry compiled for a described chip is written to the
+    persistent cache but cannot be read back without one (the next run
+    warns and recompiles), so these compiles keep the cache out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _rms(grad):
+    x = ((4, 2048, 1024), BF16)
+    scale = ((1024,), jnp.float32)
+
+    def fwd(x, s):
+        return pk.rms_norm(x, s, use_pallas=True)
+
+    def bwd(x, s):
+        return jax.grad(lambda x, s: fwd(x, s).astype(jnp.float32).sum(),
+                        argnums=(0, 1))(x, s)
+
+    return (bwd if grad else fwd), (x, scale)
+
+
+def _ce(vocab, grad):
+    logits = ((2048, vocab), jnp.float32)
+    labels = ((2048,), jnp.int32)
+
+    def fwd(lg, lb):
+        return pk.fused_cross_entropy(lg, lb, use_pallas=True)
+
+    def bwd(lg, lb):
+        return jax.grad(lambda lg: fwd(lg, lb).sum())(lg)
+
+    return (bwd if grad else fwd), (logits, labels)
+
+
+def _flash(grad):
+    # llama_350m's training attention: [B, H, S, D] causal.  On the CPU
+    # backend the dispatcher would take its reference branch, so the
+    # test steers it (monkeypatched default_backend) — see the case.
+    qkv = ((4, 16, 2048, 64), BF16)
+
+    def fwd(q, k, v):
+        return attention.multihead_attention_kernel(q, k, v, causal=True)
+
+    def bwd(q, k, v):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return (bwd if grad else fwd), (qkv, qkv, qkv)
+
+
+def _paged(heads, kvh, hd, bs, q_len, int8, gather=False):
+    lanes, cache_len = 8, 2048
+    n_blk = cache_len // bs
+    nb = 1 + lanes * n_blk
+    pool = ((nb, bs, kvh, hd), jnp.int8 if int8 else BF16)
+    table = ((lanes, n_blk), jnp.int32)
+    if gather:
+        return (lambda p, t: pk.paged_kv_gather(p, t, cache_len,
+                                                use_pallas=True),
+                (pool, table))
+    q = ((lanes, q_len, heads, hd), BF16)
+    lengths = ((lanes,), jnp.int32)
+    if not int8:
+        return (lambda q, k, v, t, n: pk.paged_attention(
+            q, k, v, t, n, cache_len=cache_len, use_pallas=True),
+            (q, pool, pool, table, lengths))
+    scales = ((nb, bs, kvh), jnp.float32)
+    return (lambda q, k, v, t, n, ks, vs: pk.paged_attention(
+        q, k, v, t, n, k_scales=ks, v_scales=vs, cache_len=cache_len,
+        use_pallas=True),
+        (q, pool, pool, table, lengths, scales, scales))
+
+
+# (heads, kv_heads, head_dim, block_size): llama_350m's layout at the
+# engine's default block size, and qwen25_7b's GQA layout.
+_LAYOUTS = {"h16kv16d64": (16, 16, 64, 16), "h28kv4d128": (28, 4, 128, 32)}
+
+CASES = {
+    "rms_norm-fwd": lambda: _rms(False),
+    "rms_norm-grad": lambda: _rms(True),
+    "fused_ce-v32000-fwd": lambda: _ce(32_000, False),
+    "fused_ce-v32000-grad": lambda: _ce(32_000, True),
+    "fused_ce-v152064-fwd": lambda: _ce(152_064, False),
+    "fused_ce-v152064-grad": lambda: _ce(152_064, True),
+    "flash-fwd": lambda: _flash(False),
+    "flash-grad": lambda: _flash(True),
+}
+for _name, (_h, _kvh, _hd, _bs) in _LAYOUTS.items():
+    CASES[f"paged_gather-{_name}"] = (
+        lambda a=(_h, _kvh, _hd, _bs): _paged(*a, 1, False, gather=True))
+    for _kv in ("bf16", "int8"):
+        for _q in (1, 3):
+            CASES[f"paged_attn-{_name}-{_kv}-q{_q}"] = (
+                lambda a=(_h, _kvh, _hd, _bs), q=_q, i8=(_kv == "int8"):
+                _paged(*a, q, i8))
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
+    # The flash dispatcher asks default_backend(); everything else is
+    # steered by use_pallas=True.  Steering lives here, in the test.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, shapes = CASES[case]()
+    one_chip = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("case", ["rms_norm-grad", "fused_ce-v32000-grad",
+                                  "flash-grad"])
+def test_kernel_partitions_over_a_2x2_mesh(case, v5e, monkeypatch):
+    """GSPMD refuses to partition a Mosaic kernel ("wrap the call in a
+    shard_map"): under a mesh the kernels run per shard
+    (``pallas_kernels.per_shard``).  data=2 × tensor=2 over the four
+    described chips, operands sharded the way the trainer shards them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, shapes = CASES[case]()
+    mesh = Mesh(np.asarray(v5e).reshape(1, 2, 1, 1, 1, 2), AXES)
+    heads_dim = 1 if case.startswith("flash") else None
+    args = []
+    for s, d in shapes:
+        spec = (pk.activation_spec(mesh, s, heads_dim=heads_dim)
+                if len(s) > 1 else P(None))
+        args.append(jax.ShapeDtypeStruct(
+            s, d, sharding=NamedSharding(mesh, spec)))
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -610,7 +610,7 @@ class TestSplashWindow:
 
     def test_splash_opt_in_and_kill_switch(self, monkeypatch):
         """Splash is OPT-IN (TTD_SPLASH=1): chunked beat it on silicon
-        at the measured shape (PROFILE.md round-4), so the measured
+        at the measured shape (last_tpu_result.json, 2026-07-31), so the measured
         winner is the default.  On CPU the splash route never fires;
         TTD_NO_SPLASH still wins over TTD_SPLASH (kill switch); and
         0/false/empty mean OFF for both flags (the TTD_NO_PALLAS

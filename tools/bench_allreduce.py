@@ -183,6 +183,9 @@ def main(argv=None) -> int:
     p.add_argument("--cpu-devices", type=int, default=None)
     args = p.parse_args(argv)
 
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform or args.cpu_devices:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,

@@ -82,9 +82,7 @@ def decode_mbu_fields(cfg, n_params, slots, cache_len,
     import jax
     import jax.numpy as jnp
 
-    from tensorflow_train_distributed_tpu.training.memory import (
-        hbm_bandwidth_bytes_per_sec,
-    )
+    from tensorflow_train_distributed_tpu.training.memory import tpu_peaks
 
     itemsize = jnp.dtype(cfg.dtype).itemsize
     kv_heads = cfg.num_kv_heads or cfg.num_heads
@@ -97,7 +95,7 @@ def decode_mbu_fields(cfg, n_params, slots, cache_len,
     out = {"decode_bytes_per_step": int(bytes_per_step),
            "mbu_pct": None}
     dev = jax.devices()[0]
-    bw = (hbm_bandwidth_bytes_per_sec(dev.device_kind)
+    bw = (tpu_peaks(dev.device_kind)["hbm_bytes_per_sec"]
           if dev.platform == "tpu" else None)
     if bw and tokens_per_sec:
         steps_per_sec = tokens_per_sec / slots
@@ -1088,20 +1086,15 @@ def main(argv=None) -> int:
     p.add_argument("--platform", default="",
                    help="force a jax platform ('cpu' for smoke runs)")
     args = p.parse_args(argv)
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,
         )
 
         force_platform(args.platform)
-    if args.base_url or (args.platform and args.platform != "tpu"):
-        cm = contextlib.nullcontext()
-    else:
-        from tensorflow_train_distributed_tpu.runtime.chip_lock import (
-            chip_lock,
-        )
-
-        cm = chip_lock()
     prompt_range = tuple(int(x) for x in args.prompt_range.split(","))
     new_range = tuple(int(x) for x in args.new_range.split(","))
     if args.mixed and args.base_url:
@@ -1123,44 +1116,43 @@ def main(argv=None) -> int:
                          "--base-url, --mixed, --replica-procs, "
                          "--disagg")
     try:
-        with cm:
-            if args.migrate_drain:
-                rec = bench_gateway_migrate_drain_ab(
-                    args.preset, args.slots, args.chunk,
-                    args.max_queue, args.cache_len or None,
-                    args.seed, args.timeout,
-                    replicas=max(2, args.replicas),
-                    reps=args.reps)
-            elif args.disagg:
-                rec = bench_gateway_disagg_ab(
-                    args.preset, args.slots, args.chunk,
-                    args.max_queue, args.clients,
-                    args.requests_per_client, prompt_range, new_range,
-                    args.cache_len or None, args.seed, args.timeout,
-                    decode_workers=max(2, args.replicas),
-                    reps=args.reps)
-            elif args.replica_procs:
-                rec = bench_gateway_procs_ab(
-                    args.preset, args.slots, args.chunk,
-                    args.max_queue, args.clients,
-                    args.requests_per_client, prompt_range, new_range,
-                    args.cache_len or None, args.seed, args.timeout,
-                    replicas=max(2, args.replicas),
-                    reps=args.reps)
-            elif args.mixed:
-                rec = bench_gateway_mixed(
-                    args.preset, args.slots, args.chunk,
-                    args.max_queue, args.seed, args.timeout,
-                    prefill_chunk=args.prefill_chunk,
-                    long_pieces=args.long_pieces, reps=args.reps)
-            else:
-                rec = bench_gateway(
-                    args.base_url, args.preset, args.slots, args.chunk,
-                    args.max_queue, args.clients,
-                    args.requests_per_client,
-                    prompt_range, new_range, args.cache_len or None,
-                    args.seed, args.timeout, overlap_ab=not args.no_ab,
-                    replicas=max(1, args.replicas))
+        if args.migrate_drain:
+            rec = bench_gateway_migrate_drain_ab(
+                args.preset, args.slots, args.chunk,
+                args.max_queue, args.cache_len or None,
+                args.seed, args.timeout,
+                replicas=max(2, args.replicas),
+                reps=args.reps)
+        elif args.disagg:
+            rec = bench_gateway_disagg_ab(
+                args.preset, args.slots, args.chunk,
+                args.max_queue, args.clients,
+                args.requests_per_client, prompt_range, new_range,
+                args.cache_len or None, args.seed, args.timeout,
+                decode_workers=max(2, args.replicas),
+                reps=args.reps)
+        elif args.replica_procs:
+            rec = bench_gateway_procs_ab(
+                args.preset, args.slots, args.chunk,
+                args.max_queue, args.clients,
+                args.requests_per_client, prompt_range, new_range,
+                args.cache_len or None, args.seed, args.timeout,
+                replicas=max(2, args.replicas),
+                reps=args.reps)
+        elif args.mixed:
+            rec = bench_gateway_mixed(
+                args.preset, args.slots, args.chunk,
+                args.max_queue, args.seed, args.timeout,
+                prefill_chunk=args.prefill_chunk,
+                long_pieces=args.long_pieces, reps=args.reps)
+        else:
+            rec = bench_gateway(
+                args.base_url, args.preset, args.slots, args.chunk,
+                args.max_queue, args.clients,
+                args.requests_per_client,
+                prompt_range, new_range, args.cache_len or None,
+                args.seed, args.timeout, overlap_ab=not args.no_ab,
+                replicas=max(1, args.replicas))
     except Exception as e:
         metric = (f"{args.preset}_gateway_mixed_p99_inter_token_ms"
                   if args.mixed

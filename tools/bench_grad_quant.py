@@ -295,8 +295,7 @@ def run_overlap_ab(args, mesh, task, batches) -> int:
             "overlapping comm with compute cannot create wall-clock "
             "headroom (there is no independent fabric to hide work on) "
             "— the blocking comm-fraction drop is the acceptance "
-            "metric here; the wall ratio realizes on TPU "
-            "(chip_playbook grad-overlap stanza is the hardware leg)")
+            "metric here; the wall ratio realizes on TPU (not measured)")
     if args.out:
         os.makedirs(os.path.dirname(args.out), exist_ok=True)
         with open(args.out, "a") as f:
@@ -343,6 +342,9 @@ def main(argv=None) -> int:
     if args.out is None:
         args.out = OUT_OVERLAP if args.overlap else OUT_DEFAULT
 
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform or args.cpu_devices:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,
@@ -429,8 +431,7 @@ def main(argv=None) -> int:
             "bench_allreduce documents for the host ring's q8 leg; the "
             "wire-bytes fraction above is the invariant lever, "
             "realized where per-rank fabric bandwidth is below quant "
-            "throughput (DCN/ICI-bound regimes; chip_playbook step 9 "
-            "is the TPU leg)")
+            "throughput (DCN/ICI-bound regimes; TPU leg not measured)")
     if args.out:
         os.makedirs(os.path.dirname(args.out), exist_ok=True)
         with open(args.out, "a") as f:

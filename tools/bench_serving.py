@@ -42,7 +42,6 @@ Prints one JSON line per run (bench_lm.py conventions).
 """
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -1318,77 +1317,71 @@ def main(argv=None) -> int:
     p.add_argument("--platform", default="",
                    help="force a jax platform ('cpu' for smoke runs)")
     args = p.parse_args(argv)
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,
         )
 
         force_platform(args.platform)
-    if args.platform and args.platform != "tpu":
-        cm = contextlib.nullcontext()
-    else:
-        from tensorflow_train_distributed_tpu.runtime.chip_lock import (
-            chip_lock,
-        )
-
-        cm = chip_lock()
     prompt_range = tuple(int(x) for x in args.prompt_range.split(","))
     new_range = tuple(int(x) for x in args.new_range.split(","))
     try:
-        with cm:
-            if args.mixed:
-                rec = bench_serving_mixed(
-                    args.preset, args.slots, args.chunk,
-                    args.cache_len or None, args.seed,
-                    args.prefill_chunk, args.long_pieces,
-                    reps=args.reps)
-            elif args.shared_prefix:
-                rec = bench_paged_kv_ab(
-                    args.preset, args.slots, args.chunk, args.requests,
-                    args.prefix_len, args.cache_len or None, args.seed,
-                    args.kv_block_size, reps=args.reps)
-            elif args.trace_ab:
-                rec = bench_trace_ab(args.preset, args.slots, args.chunk,
-                                     args.requests, prompt_range,
-                                     new_range, args.cache_len or None,
-                                     args.seed, reps=args.reps)
-            elif args.trace_fleet_ab:
-                rec = bench_trace_fleet_ab(
-                    args.preset, args.slots, args.chunk,
-                    args.requests, prompt_range, new_range,
-                    args.cache_len or None, args.seed,
-                    reps=args.reps, replicas=args.fleet_replicas)
-            elif args.spec_adaptive_ab:
-                depths = tuple(int(x)
-                               for x in args.spec_depths.split(","))
-                draft = (args.speculative_draft
-                         if args.speculative_draft != "self" else "")
-                rec = bench_spec_adaptive_ab(
-                    args.preset, draft, args.slots, args.chunk,
-                    args.requests, prompt_range, new_range,
-                    args.cache_len or None, args.seed, depths,
-                    reps=args.reps, wide_d_model=args.spec_d_model)
-            elif args.fused_ab:
-                sweep = ([int(s) for s in args.sweep_slots.split(",")]
-                         if args.sweep_slots
-                         else [args.slots, 2 * args.slots])
-                rec = bench_fused_attn_ab(
-                    args.preset, args.slots, args.chunk, args.requests,
-                    prompt_range, new_range, args.cache_len or None,
-                    args.seed, args.kv_block_size, sweep,
-                    reps=args.reps)
-            else:
-                rec = bench_serving(args.preset, args.slots, args.chunk,
-                                    args.requests, prompt_range,
-                                    new_range,
-                                    args.cache_len or None,
-                                    args.baseline,
-                                    args.seed,
-                                    draft_preset=args.speculative_draft,
-                                    speculative_k=args.speculative_k,
-                                    overlap_ab=not args.no_ab,
-                                    kv_int8=args.kv_int8,
-                                    reps=args.reps)
+        if args.mixed:
+            rec = bench_serving_mixed(
+                args.preset, args.slots, args.chunk,
+                args.cache_len or None, args.seed,
+                args.prefill_chunk, args.long_pieces,
+                reps=args.reps)
+        elif args.shared_prefix:
+            rec = bench_paged_kv_ab(
+                args.preset, args.slots, args.chunk, args.requests,
+                args.prefix_len, args.cache_len or None, args.seed,
+                args.kv_block_size, reps=args.reps)
+        elif args.trace_ab:
+            rec = bench_trace_ab(args.preset, args.slots, args.chunk,
+                                 args.requests, prompt_range,
+                                 new_range, args.cache_len or None,
+                                 args.seed, reps=args.reps)
+        elif args.trace_fleet_ab:
+            rec = bench_trace_fleet_ab(
+                args.preset, args.slots, args.chunk,
+                args.requests, prompt_range, new_range,
+                args.cache_len or None, args.seed,
+                reps=args.reps, replicas=args.fleet_replicas)
+        elif args.spec_adaptive_ab:
+            depths = tuple(int(x)
+                           for x in args.spec_depths.split(","))
+            draft = (args.speculative_draft
+                     if args.speculative_draft != "self" else "")
+            rec = bench_spec_adaptive_ab(
+                args.preset, draft, args.slots, args.chunk,
+                args.requests, prompt_range, new_range,
+                args.cache_len or None, args.seed, depths,
+                reps=args.reps, wide_d_model=args.spec_d_model)
+        elif args.fused_ab:
+            sweep = ([int(s) for s in args.sweep_slots.split(",")]
+                     if args.sweep_slots
+                     else [args.slots, 2 * args.slots])
+            rec = bench_fused_attn_ab(
+                args.preset, args.slots, args.chunk, args.requests,
+                prompt_range, new_range, args.cache_len or None,
+                args.seed, args.kv_block_size, sweep,
+                reps=args.reps)
+        else:
+            rec = bench_serving(args.preset, args.slots, args.chunk,
+                                args.requests, prompt_range,
+                                new_range,
+                                args.cache_len or None,
+                                args.baseline,
+                                args.seed,
+                                draft_preset=args.speculative_draft,
+                                speculative_k=args.speculative_k,
+                                overlap_ab=not args.no_ab,
+                                kv_int8=args.kv_int8,
+                                reps=args.reps)
     except Exception as e:
         if args.mixed:
             metric = f"{args.preset}_serving_mixed_p99_inter_token_ms"

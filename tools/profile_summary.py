@@ -1,6 +1,6 @@
 """Summarize a jax.profiler trace: per-op-category device time per step.
 
-The judge-facing evidence pipeline behind PROFILE.md: bench.py (and
+The trace-reading half of the evidence pipeline: bench.py (and
 ``--profile-dir`` on the CLI) capture XPlane traces; this tool aggregates
 the device plane's ``XLA Ops`` line into op-kind buckets (conv/matmul
 fusions, BN statistics, converts, elementwise, copies, ...) so "where does

@@ -1,7 +1,7 @@
 """Render mini-convergence JSONL curves as a text report.
 
 ``profiles/convergence/*.jsonl`` (written by the CLI's ``--jsonl-log``
-during the multi-epoch mini-convergence runs, VERDICT r3 items 5/8) →
+during the multi-epoch mini-convergence runs) →
 a compact human-readable report: per-curve sparkline + loss statistics,
 plus a numerics-agreement section for A/B pairs like
 ``resnet50_imagenet_s2d`` vs ``..._s2d_bnsub`` (the strided-BN-statistics

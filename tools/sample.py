@@ -166,6 +166,9 @@ def main(argv=None) -> int:
                    help="force a jax platform (e.g. 'cpu')")
     args = p.parse_args(argv)
 
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,

@@ -348,6 +348,9 @@ def worker_engine_factory(spec: dict):
     parent-side facades and worker-side engines are built from ONE
     flag surface and cannot drift."""
     args = argparse.Namespace(**spec)
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if getattr(args, "platform", ""):
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,
@@ -380,6 +383,9 @@ def main(argv=None) -> int:
                    help="output JSONL path ('-' = stdout)")
     args = p.parse_args(argv)
 
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,

@@ -181,7 +181,7 @@ def _serve_net(args, cfg) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     add_engine_args(p)
     p.add_argument("--host", default="0.0.0.0",
@@ -250,7 +250,50 @@ def main(argv=None) -> int:
     p.add_argument("--drain-timeout", type=float, default=0.0,
                    help="bound on the SIGTERM drain (replicas drain "
                         "one at a time; 0 = wait indefinitely)")
-    args = p.parse_args(argv)
+    return p
+
+
+def build_gateway(args, cfg, is_moe, prefix_ids):
+    """The in-process serving stack for parsed ``args``: checkpoint →
+    engine(s) → unstarted ``ServingGateway``.  ``main`` starts it and
+    waits on signals; ``chip_smoke.py`` starts it, drives it over HTTP
+    and drains it, all in the one process that holds the chip."""
+    from tensorflow_train_distributed_tpu.server import ServingGateway
+
+    # One engine per replica, configured identically (each builds its
+    # own caches and preloads the prefix into its own pool — replica
+    # state stays fully independent so any one can die alone).  They
+    # all land on the process's default (first) device: in-process
+    # replicas share one chip, they do not spread over several.
+    engines = [build_engine(args, cfg, is_moe, prefix_ids)
+               for _ in range(args.replicas)]
+    # Online: request lengths are unknowable at startup, so a dense-
+    # dispatch MoE always gets the compile-storm warning.
+    maybe_dense_moe_hint(engines[0])
+    if args.replicas > 1:
+        # Warm every replica before taking traffic: the decode program
+        # (and one prefill shape) compiles now, so the first user
+        # request is fast on every replica and the pool's
+        # hung-dispatch watchdog never has to stare down a cold
+        # compile (it additionally only arms after a replica's first
+        # completed step).
+        for i, eng in enumerate(engines):
+            print(f"warming replica {i}...", flush=True)
+            eng.submit([1], 1)
+            eng.run()
+
+    return ServingGateway(
+        engines if args.replicas > 1 else engines[0],
+        host=args.host, port=args.port, max_queue=args.max_queue,
+        default_timeout_s=args.default_timeout or None,
+        default_max_new=args.max_new,
+        validate=make_vocab_validator(cfg.vocab_size),
+        retry_after_s=args.retry_after,
+        watchdog_timeout_s=args.watchdog_timeout or None)
+
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     if args.replicas < 1:
         raise SystemExit(f"--replicas must be >= 1, got {args.replicas}")
 
@@ -258,14 +301,15 @@ def main(argv=None) -> int:
         level=logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
 
+    from tensorflow_train_distributed_tpu.runtime import compile_cache
+
+    compile_cache.place_compile_cache()
     if args.platform:
         from tensorflow_train_distributed_tpu.runtime.mesh import (
             force_platform,
         )
 
         force_platform(args.platform)
-
-    from tensorflow_train_distributed_tpu.server import ServingGateway
 
     _, cfg, is_moe = resolve_decoder_task(args.config, "serving")
     prefix_ids = parse_prefix_arg(args, cfg)
@@ -284,34 +328,7 @@ def main(argv=None) -> int:
             args.replica_procs = False
     if args.replica_procs:
         return _serve_procs(args, cfg)
-    # One engine per replica, configured identically (each builds its
-    # own caches and preloads the prefix into its own pool — replica
-    # state stays fully independent so any one can die alone).
-    engines = [build_engine(args, cfg, is_moe, prefix_ids)
-               for _ in range(args.replicas)]
-    # Online: request lengths are unknowable at startup, so a dense-
-    # dispatch MoE always gets the compile-storm warning.
-    maybe_dense_moe_hint(engines[0])
-    if args.replicas > 1:
-        # Warm every replica before taking traffic: the decode program
-        # (and one prefill shape) compiles now, so the first user
-        # request is fast on every replica and the pool's
-        # hung-dispatch watchdog never has to stare down a cold
-        # compile (it additionally only arms after a replica's first
-        # completed step).
-        for i, eng in enumerate(engines):
-            print(f"warming replica {i}...", flush=True)
-            eng.submit([1], 1)
-            eng.run()
-
-    gw = ServingGateway(
-        engines if args.replicas > 1 else engines[0],
-        host=args.host, port=args.port, max_queue=args.max_queue,
-        default_timeout_s=args.default_timeout or None,
-        default_max_new=args.max_new,
-        validate=make_vocab_validator(cfg.vocab_size),
-        retry_after_s=args.retry_after,
-        watchdog_timeout_s=args.watchdog_timeout or None)
+    gw = build_gateway(args, cfg, is_moe, prefix_ids)
     gw.install_signal_handlers(
         drain_timeout=args.drain_timeout or None)
     gw.start()

@@ -2,8 +2,7 @@
 
 Each point runs ``bench.py`` in a fresh subprocess (XLA/libtpu flags only
 apply at backend init) and records images/sec/chip.  Used to pick the
-batch size and libtpu flags for the headline benchmark — results land in
-PROFILE.md.
+batch size and libtpu flags for the headline benchmark.
 
 Usage: python tools/sweep_resnet.py [--quick]
 """
@@ -33,9 +32,7 @@ def run_point(batch: int, flags: str, iters: int, config: str):
         env["LIBTPU_INIT_ARGS"] = flags
     cmd = [sys.executable, os.path.join(REPO, "bench.py"),
            "--configs", config, "--batch-per-chip", str(batch),
-           "--iters", str(iters), "--acquire-timeout", "120",
-           "--families", "resnet",
-           "--no-cpu-fallback", "--no-persist", "--profile-dir", ""]
+           "--iters", str(iters), "--families", "resnet"]
     out = subprocess.run(cmd, capture_output=True, text=True, env=env,
                          timeout=900, cwd=REPO)
     for line in reversed(out.stdout.strip().splitlines()):
