@@ -70,6 +70,7 @@ class Embed(nn.Module):
             (self.vocab_size, self.features),
         )
 
+    @jax.named_scope("embed")
     def __call__(self, ids):
         emb = self.embedding.astype(self.dtype)
         if _active_mesh("fsdp") is not None:
@@ -120,6 +121,7 @@ def llama3_scaled_freqs(freqs, scaling):
     return jnp.where(medium, smoothed, scaled)
 
 
+@jax.named_scope("attn/qkv")    # the rotation belongs to q and k's making
 def apply_rope(x, positions, *, base: float = 10000.0, scaling=None):
     """RoPE applied to [B, S, H, D] at integer ``positions`` [B, S].
 
@@ -169,6 +171,7 @@ class RMSNorm(nn.Module):
     zero_centered: bool = False
 
     @nn.compact
+    @jax.named_scope("norm")
     def __call__(self, x):
         from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
             rms_norm,
@@ -294,6 +297,7 @@ class MultiHeadAttention(nn.Module):
         return nn.with_logical_constraint(
             y, ("batch", "length", self._head_ax(heads), "kv"))
 
+    @jax.named_scope("attn/qkv")
     def _qkv(self, x):
         """Self-attention q/k/v: three gemms, or one fused gemm
         (``fused_qkv``) split head-wise after the reshape."""
@@ -330,6 +334,7 @@ class MultiHeadAttention(nn.Module):
             return None
         return "heads"
 
+    @jax.named_scope("attn/out")
     def _out_proj(self, x, features):
         return nn.Dense(
             features, use_bias=self.use_bias, dtype=self.dtype, name="out",
@@ -768,40 +773,46 @@ class MultiHeadAttention(nn.Module):
         index.value = cur + q_len
 
         kdt = cache_k.value.dtype
-        if self.kv_cache_int8:
-            k_store, sk = _quantize_kv_rows(k)
-            v_store, sv = _quantize_kv_rows(v)
-        else:
-            k_store, v_store = k.astype(kdt), v.astype(kdt)
-        # Physical destination row per (lane, token): the table lookup
-        # CLIPS the block index (gather semantics would otherwise wrap)
-        # and overrun positions are sent out of range so the scatter
-        # drops them — an overrun lane goes silently inert, exactly the
-        # linear path's rule.
-        blk = jnp.clip(positions // bs, 0, n_blk - 1)
-        phys = jnp.take_along_axis(table.value, blk, axis=1)  # [B, q]
-        dest = jnp.where(positions < n_blk * bs,
-                         phys * bs + positions % bs, nb * bs)
+        # This step's rows into the pools.  The device-scope contract
+        # (PERF.md §3) names the scatter; the reshape back to blocks
+        # stays outside it, with whatever else moves whole pools about
+        # in the decode program (the compiler folds it into the
+        # kernel's own view of the pool: a copy of the layer's slab).
         flat_shape = (nb * bs, kv_heads, self.head_dim)
-        cache_k.value = (
-            cache_k.value.reshape(flat_shape)
-            .at[dest.reshape(-1)]
-            .set(k_store.reshape(-1, kv_heads, self.head_dim),
-                 mode="drop")
-            .reshape(nb, bs, kv_heads, self.head_dim))
-        cache_v.value = (
-            cache_v.value.reshape(flat_shape)
-            .at[dest.reshape(-1)]
-            .set(v_store.reshape(-1, kv_heads, self.head_dim),
-                 mode="drop")
-            .reshape(nb, bs, kv_heads, self.head_dim))
-        if self.kv_cache_int8:
-            sflat = kv_scales.value.reshape(2, nb * bs, kv_heads)
-            sflat = sflat.at[:, dest.reshape(-1)].set(
-                jnp.stack([sk, sv]).reshape(2, -1, kv_heads),
-                mode="drop")
-            kv_scales.value = sflat.reshape(2, nb, bs, kv_heads)
+        with jax.named_scope("kv_pool/write"):
+            if self.kv_cache_int8:
+                k_store, sk = _quantize_kv_rows(k)
+                v_store, sv = _quantize_kv_rows(v)
+            else:
+                k_store, v_store = k.astype(kdt), v.astype(kdt)
+            # Physical destination row per (lane, token): the table
+            # lookup CLIPS the block index (gather semantics would
+            # otherwise wrap) and overrun positions are sent out of
+            # range so the scatter drops them — an overrun lane goes
+            # silently inert, exactly the linear path's rule.
+            blk = jnp.clip(positions // bs, 0, n_blk - 1)
+            phys = jnp.take_along_axis(table.value, blk, axis=1)  # [B, q]
+            dest = jnp.where(positions < n_blk * bs,
+                             phys * bs + positions % bs, nb * bs)
+            k_flat = cache_k.value.reshape(flat_shape).at[
+                dest.reshape(-1)].set(
+                    k_store.reshape(-1, kv_heads, self.head_dim),
+                    mode="drop")
+            v_flat = cache_v.value.reshape(flat_shape).at[
+                dest.reshape(-1)].set(
+                    v_store.reshape(-1, kv_heads, self.head_dim),
+                    mode="drop")
+            if self.kv_cache_int8:
+                sflat = kv_scales.value.reshape(2, nb * bs, kv_heads)
+                sflat = sflat.at[:, dest.reshape(-1)].set(
+                    jnp.stack([sk, sv]).reshape(2, -1, kv_heads),
+                    mode="drop")
+                kv_scales.value = sflat.reshape(2, nb, bs, kv_heads)
+        cache_k.value = k_flat.reshape(nb, bs, kv_heads, self.head_dim)
+        cache_v.value = v_flat.reshape(nb, bs, kv_heads, self.head_dim)
 
+        # No scope from here to the kernel call: the benchmark finds the
+        # kernel's device events by this method's name.
         if self._fused_paged_ok():
             out = pk.paged_attention(
                 q, cache_k.value, cache_v.value, table.value, cur,
@@ -967,6 +978,7 @@ class MlpBlock(nn.Module):
     dropout_rate: float = 0.0
 
     @nn.compact
+    @jax.named_scope("mlp")
     def __call__(self, x, *, deterministic: bool = True):
         # "mlp_hidden" checkpoint_name tags document the [B,S,ffn]
         # intermediates (identity unless a policy names them).  NOTE:

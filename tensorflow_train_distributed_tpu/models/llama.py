@@ -527,8 +527,10 @@ class LlamaModel(nn.Module):
         x = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       zero_centered=cfg.norm_zero_centered,
                       name="final_norm")(x)
-        logits = L.dense(cfg.vocab_size, ("embed", "vocab"), use_bias=False,
-                         dtype=cfg.dtype, name="lm_head")(x)
+        with jax.named_scope("head"):
+            logits = L.dense(cfg.vocab_size, ("embed", "vocab"),
+                             use_bias=False, dtype=cfg.dtype,
+                             name="lm_head")(x)
         return nn.with_logical_constraint(
             logits, ("batch", "length", "vocab"))
 

@@ -187,6 +187,7 @@ def paged_kv_gather(pool, table, cache_len: int, *,
     flat = pool.reshape(nb, bs, kvh * hd)
     out = pl.pallas_call(
         _paged_gather_kernel,
+        name="paged_kv_gather",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(lanes, n_blk),
@@ -392,6 +393,9 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         functools.partial(
             _paged_attn_kernel, bs=bs, kvh=kvh, rep=rep, q_len=q_len,
             hd=hd, scale=hd ** -0.5, int8=int8),
+        # No name= here: a name becomes the HLO instruction's, and the
+        # benchmark finds this kernel's device events by the name the
+        # calling method gives it (``attention._paged_decode_step``).
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(lanes, n_blk),
@@ -452,6 +456,7 @@ def _rmsnorm_fwd_call(x2, s2, *, epsilon, interpret):
     bn = _rmsnorm_rows(n)
     return pl.pallas_call(
         functools.partial(_rmsnorm_fwd_kernel, epsilon=epsilon),
+        name="rms_norm_fwd",
         grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
@@ -486,6 +491,7 @@ def _rms_norm_pallas_bwd(epsilon, interpret, res, g):
     bn = _rmsnorm_rows(n)
     dx = pl.pallas_call(
         _rmsnorm_bwd_kernel,
+        name="rms_norm_bwd",
         grid=(pl.cdiv(n, bn),),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
@@ -612,6 +618,7 @@ def _ce_fwd(logits, labels2, *, interpret):
     sp = _ce_specs(n, v, bn, bv)
     loss, lse = pl.pallas_call(
         functools.partial(_ce_fwd_kernel, vocab=v, block_v=bv),
+        name="cross_entropy_fwd",
         grid=sp["grid"],
         in_specs=sp["in_specs"],
         out_specs=[
@@ -652,6 +659,7 @@ def _cross_entropy_pallas_bwd(interpret, res, g):
     sp = _ce_specs(n, v, bn, bv)
     dlogits = pl.pallas_call(
         functools.partial(_ce_bwd_kernel, vocab=v, block_v=bv),
+        name="cross_entropy_bwd",
         grid=sp["grid"],
         in_specs=sp["in_specs"] + [
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),

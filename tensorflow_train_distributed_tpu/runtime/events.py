@@ -9,8 +9,9 @@ default and designed to stay on in production:
 
 - recording is one lock-guarded ``deque.append`` of a small tuple —
   no I/O, no serialization, no allocation beyond the tuple and its
-  attrs dict (measured ≤ 2 % serving tok/s against the kill switch;
-  ``tools/bench_serving.py --trace-ab`` is the committed A/B);
+  attrs dict (on a TPU v5e the benchmark's cells serve the same tokens
+  with the recorder on or killed, PERF.md §6; on a one-core CPU host
+  ≤ 2 % tok/s, ``tools/bench_serving.py --trace-ab``);
 - the buffer is a bounded ring (``TTD_TRACE_CAPACITY`` events, default
   65536): old events fall off the back, memory is O(capacity) forever;
 - ``TTD_NO_TRACE=1`` is the kill switch: ``span()`` degrades to a
@@ -28,7 +29,18 @@ or ``chrome://tracing``):
 - ``span(name, **attrs)`` — a context manager recording ONE complete
   event (``ph="X"``) at exit with monotonic start + duration.
   Recording at exit means the ring never holds an unbalanced begin.
-- ``instant(name, **attrs)`` — a point event (``ph="i"``).
+  The same call opens a ``jax.profiler.TraceAnnotation(name, **attrs)``
+  for the block: under an active profiler capture (``--profile-dir``,
+  ``--profiler-port``) every span is also an event of the capture's
+  host plane, on the PROFILER's clock beside the device's operations,
+  under the same name and attrs (per-thread default attrs stay in the
+  ring: a thread is its own line of the capture).  With no capture
+  running the annotation is a flag check.  It touches no backend, so
+  a process that must never hold the chip (supervisor, procpool
+  parent) may record spans freely.  ``with span(...) as sp: ...;
+  sp.set(k=v)`` adds attrs known only at the block's end to both
+  sinks.
+- ``instant(name, **attrs)`` — a point event (``ph="i"``), ring only.
 - timestamps are ``time.monotonic()`` (immune to wall-clock steps;
   the export carries a wall-clock anchor for cross-run alignment),
   ``tid`` is the recording thread's ident, ``pid`` the process.
@@ -48,6 +60,15 @@ dispatch that compiles a new signature at an instrumented jit site —
 compile time shows up in the same timeline as the decode/prefill spans
 it stalls, and ``tools/trace_report.py`` folds the spans into a
 per-site compilation table.
+
+The names the serving engine and its driver record are a CONTRACT
+(``CONTRACT`` below, name -> attrs, grouped by PERF.md's layers): the
+benchmark's per-layer metrics, ``/v1/requests/<id>`` and
+``tools/trace_report.py`` read them by name, and
+``tests/test_events_profiler.py`` fails an engine that records a name
+or an attr outside the table.  A step's SELF time is the duration of
+its ``engine/step`` span minus its ``*/wait`` children (the reads that
+block on the device), by containment on the same thread.
 """
 
 from __future__ import annotations
@@ -68,6 +89,61 @@ from tensorflow_train_distributed_tpu.runtime.lint.registry import (
 _KILL_ENV = "TTD_NO_TRACE"
 _CAPACITY_ENV = "TTD_TRACE_CAPACITY"
 DEFAULT_CAPACITY = 65536
+
+#: name -> attrs of every event the serving engine and its driver
+#: record (spans unless marked as instants).  A name ending in ``/*``
+#: stands for a family keyed by a site or pool name.  A driver in a
+#: replica pool adds its thread's ``replica`` (and what the pool passes
+#: on) to the driver's events.
+CONTRACT = {
+    # -- layer "engine host loop" (serving.py) --
+    # what the step did: lanes active at its dispatch and the cached
+    # positions they held, prefill pieces run and their prompt tokens,
+    # tokens handed to requests, the engine queue's depth at exit
+    "engine/step": "lanes positions pieces prefill_tokens committed queued",
+    "decode/dispatch": "fused spec_k",
+    "decode/wait": "overlapped",
+    "decode/harvest": "overlapped",
+    "prefill/request": "rid tokens",
+    "prefill/piece": "rid piece n_pieces tokens",
+    "prefill/wait": "rid",
+    "prefill/insert": "rid",
+    "prefill/prefix": "tokens",
+    "kv/alloc": "rid blocks shared",
+    "kv/export": "tokens",
+    "kv/install": "tokens",
+    # instants
+    "engine/queued": "rid prompt_len max_new",
+    "engine/cancel": "rid where",
+    "slot/insert": "rid slot",
+    "slot/retire": "rid slot tokens",
+    "kv/evict": "blocks",
+    "kv/refused": "rid blocks",
+    "kv/prefix_hit": "rid tokens",
+    # -- layer "gateway / driver" (server/driver.py), all instants --
+    "request/admitted": "request_id prompt_len max_new stream resumed",
+    "request/engine_submit": "request_id rid",
+    "request/slot_granted": "request_id rid wait_ms",
+    "request/commit": "request_id tokens",
+    "request/retire": "request_id status tokens latency_ms",
+    "driver/died": "error",
+    # -- layers "CLI / launcher and set-up" and "device" --
+    "compile/*": "site signature aot",
+    "memory/*": "pool site bytes live budget",
+}
+
+
+def contract_attrs(name: str):
+    """The attrs ``CONTRACT`` allows an event called ``name`` (a set),
+    or ``None`` for a name outside it (families match by prefix)."""
+    attrs = CONTRACT.get(name, CONTRACT.get(name.split("/")[0] + "/*"))
+    return None if attrs is None else set(attrs.split())
+
+
+def in_contract(name: str) -> bool:
+    """Whether ``name`` is one of ``CONTRACT``'s."""
+    return contract_attrs(name) is not None
+
 
 # -- crash-durable spool knobs --------------------------------------------
 # ``TTD_TRACE_SPOOL=<dir>`` arms a per-process rotating JSONL spool: a
@@ -141,10 +217,26 @@ def make_env_flag_reader(env_name: str):
 trace_killed = make_env_flag_reader(_KILL_ENV)
 
 
-class _Span:
-    """One recording span: appends a single complete event at exit."""
+_ANNOTATION = None
 
-    __slots__ = ("_rec", "_name", "_attrs", "t0")
+
+def _annotation(name: str, attrs: Optional[dict]):
+    """``jax.profiler.TraceAnnotation(name, **attrs)``.  The class is
+    resolved on the first span, not at import: this module is imported
+    by processes that record nothing."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **attrs) if attrs else _ANNOTATION(name)
+
+
+class _Span:
+    """One recording span: a profiler annotation for the block, and a
+    single complete ring event appended at exit."""
+
+    __slots__ = ("_rec", "_name", "_attrs", "_ann", "t0")
 
     def __init__(self, rec: "Recorder", name: str, attrs: Optional[dict]):
         self._rec = rec
@@ -152,24 +244,38 @@ class _Span:
         self._attrs = attrs
 
     def __enter__(self) -> "_Span":
+        self._ann = _annotation(self._name, self._attrs)
+        self._ann.__enter__()
         self.t0 = time.monotonic()
         return self
 
+    def set(self, **attrs) -> None:
+        """Attrs known only inside the block (counts at a step's end):
+        they reach the ring event and the profiler's event alike."""
+        if self._attrs is None:
+            self._attrs = attrs
+        else:
+            self._attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
     def __exit__(self, *exc) -> bool:
-        t1 = time.monotonic()
-        self._rec._append(self._name, "X", self.t0, t1 - self.t0,
-                          self._attrs)
+        dur = time.monotonic() - self.t0
+        self._ann.__exit__(*exc)
+        self._rec._append(self._name, "X", self.t0, dur, self._attrs)
         return False
 
 
 class _NullSpan:
-    """The kill-switch span: no clock reads, no append, one shared
-    instance."""
+    """The kill-switch span: no clock reads, no append, no annotation,
+    one shared instance."""
 
     __slots__ = ()
 
     def __enter__(self):
         return self
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __exit__(self, *exc) -> bool:
         return False
@@ -213,6 +319,7 @@ class Recorder:
     # The spool state dict is shared by the flusher thread and any
     # thread calling flush_spool()/stop_spool() (worker drain, tests).
     _GUARDED_BY = {"_buf": ("_lock",), "_seq": ("_lock",),
+                   "_cleared": ("_lock",),
                    "_spool": ("_spool_lock",)}
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
@@ -227,6 +334,7 @@ class Recorder:
         # shift as the ring drops old events, a running sequence does
         # not).
         self._seq = 0
+        self._cleared = 0       # events taken out by clear(), not lapped
         self._lock = threading.Lock()
         # Wall-clock anchor: wall time at monotonic ``_anchor_mono`` —
         # lets offline tooling place the monotonic timeline in real
@@ -306,6 +414,7 @@ class Recorder:
 
     def clear(self) -> None:
         with self._lock:
+            self._cleared += len(self._buf)
             self._buf.clear()
 
     # -- query / export --------------------------------------------------
@@ -319,6 +428,24 @@ class Recorder:
             cutoff = time.monotonic() - last_s
             items = [e for e in items if e[2] + e[3] >= cutoff]
         return items
+
+    def spans_between(self, t0: float, t1: float,
+                      name: Optional[str] = None) -> tuple:
+        """``(spans, dropped)``: the complete events (``ph="X"``, ring
+        tuples, oldest first) that BEGAN in ``[t0, t1)`` on the
+        monotonic clock, those named ``name`` if given — what a reader
+        of one measurement window wants.  ``dropped`` is how many
+        events the ring has lapped if the oldest one it still holds
+        began after ``t0`` (part of the window may be among them), else
+        0: a reader reports it beside its number."""
+        with self._lock:
+            items = list(self._buf)
+            lapped = self._seq - self._cleared - len(items)
+        spans = [e for e in items
+                 if e[1] == "X" and t0 <= e[2] < t1
+                 and (name is None or e[0] == name)]
+        holds_start = bool(items) and items[0][2] <= t0
+        return spans, (0 if holds_start or not lapped else lapped)
 
     def events_after(self, cursor: int) -> tuple:
         """``(new_cursor, events)``: every event appended since

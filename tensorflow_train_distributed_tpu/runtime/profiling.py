@@ -9,14 +9,16 @@ machinery as ``jax.profiler``, so traces land in the same TensorBoard
 profile plugin — including TPU-side HLO op breakdowns this framework gets
 for free.
 
-Three surfaces:
+Two surfaces:
 
 - ``trace(logdir)`` / ``start_trace`` / ``stop_trace`` — whole-window
   capture (reference ``tf.profiler.experimental.start/stop``).
-- ``annotate(name)`` / ``annotate_function`` — host-side named spans that
-  nest inside the trace (reference ``tf.profiler.experimental.Trace``).
 - ``ProfileCallback`` — step-window capture inside ``Trainer.fit``
   (reference ``TensorBoard(profile_batch=(a, b))``).
+
+Host-side named spans inside a capture (reference
+``tf.profiler.experimental.Trace``) are ``runtime.events.span``: the
+flight recorder's spans are profiler annotations too.
 
 Plus ``device_memory_stats`` for HBM occupancy (per-device bytes in use),
 the observability hook the reference exposes via
@@ -79,16 +81,6 @@ def start_profiler_server(port: int):
         return None
     logger.info("profiler server listening on port %d", port)
     return server
-
-
-def annotate(name: str, **kwargs):
-    """Named host-side span (TraceMe); nests under an active trace."""
-    return jax.profiler.TraceAnnotation(name, **kwargs)
-
-
-def annotate_function(fn, name: Optional[str] = None):
-    """Decorator form of ``annotate``."""
-    return jax.profiler.annotate_function(fn, name=name)
 
 
 def device_memory_stats() -> list[dict]:

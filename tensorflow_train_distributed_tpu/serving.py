@@ -257,6 +257,14 @@ def _paged_killed() -> bool:
     return os.environ.get("TTD_NO_PAGED_KV", "0") not in ("", "0")
 
 
+#: What an ``engine/step`` span says its step did: lanes active at the
+#: dispatch and the cached positions they held (as the host knew them),
+#: prefill pieces run and the prompt tokens they carried, output tokens
+#: handed to requests.
+_STEP_COUNTS = ("lanes", "positions", "pieces", "prefill_tokens",
+                "committed")
+
+
 def _bucket_len(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:
@@ -660,7 +668,8 @@ class ServingEngine:
         self.interleave = (prefill_budget != 0
                            and not _interleave_killed())
         self._staging: dict = {}       # slot -> _PrefillTask (FIFO)
-        # stall_s: wall time spent prefilling while >= 1 lane was
+        # stall_s: time the host spent blocked on a prefill's first
+        # token (the ``prefill/wait`` spans) while >= 1 lane was
         # decoding with NO successor decode chunk in flight to hide it
         # (the head-of-line blocking this scheduler removes — the
         # gateway exposes it as ttd_engine_prefill_stall_seconds);
@@ -668,6 +677,10 @@ class ServingEngine:
         # requests that went through the staged path.
         self.prefill_stats = {"installments": 0, "staged_requests": 0,
                               "stall_s": 0.0}
+        # What the running serve_step has done so far: the attrs its
+        # ``engine/step`` span is given at exit (runtime/events.py,
+        # CONTRACT).
+        self._step_counts = dict.fromkeys(_STEP_COUNTS, 0)
         # The chunk in flight: rids pins which request occupied each
         # slot AT DISPATCH — harvest trims anything that retired or was
         # refilled since (the one-chunk decision lag made safe).
@@ -770,16 +783,18 @@ class ServingEngine:
         request's tokens do not depend on slot placement, neighbors, or
         chunk boundaries (reproducible under any contention).
         """
-        logits = logits.astype(jnp.float32)
-        if self._greedy:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        logits = filter_logits(logits, temperature=self.temperature,
-                               top_k=self.top_k, top_p=self.top_p)
-        keys = jax.vmap(jax.random.fold_in)(
-            jax.vmap(jax.random.key)(seeds.astype(jnp.uint32)), counts)
-        return jax.vmap(
-            lambda k, l: jax.random.categorical(k, l)
-        )(keys, logits).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            logits = logits.astype(jnp.float32)
+            if self._greedy:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            logits = filter_logits(logits, temperature=self.temperature,
+                                   top_k=self.top_k, top_p=self.top_p)
+            keys = jax.vmap(jax.random.fold_in)(
+                jax.vmap(jax.random.key)(seeds.astype(jnp.uint32)),
+                counts)
+            return jax.vmap(
+                lambda k, l: jax.random.categorical(k, l)
+            )(keys, logits).astype(jnp.int32)
 
     # Compile discipline (ttd-lint compilecheck + TTD_COMPILECHECK=1):
     # every program below declares which bucket rule pads its dynamic
@@ -2263,8 +2278,6 @@ class ServingEngine:
         wait it out (``prefill_stats['stall_s']`` measures that
         head-of-line time; the staged path keeps it ~0)."""
         stalled = any(s is not None for s in self._slot_states)
-        prefilled = False
-        t0 = time.perf_counter()
         for slot in range(self.slots):
             # Keep popping until this slot is OCCUPIED or the queue is
             # dry: a request that resolves at prefill time (max_new<=1
@@ -2286,10 +2299,6 @@ class ServingEngine:
                         # free as lanes retire).
                         self._queue.appendleft(
                             (rid, prompt, max_new, seed, resume))
-                        if prefilled and stalled:
-                            with self._stats_lock:
-                                self.prefill_stats["stall_s"] += (
-                                    time.perf_counter() - t0)
                         return
                     table_j = self._kv_table(kv)
                     pre_len, pre_pair = self._admission_match(kv, prompt)
@@ -2301,7 +2310,6 @@ class ServingEngine:
                                                            touch=True)
                 work = prompt[pre_len:]
                 self._note_moe_prefill_len(n)
-                prefilled = True
                 with self._ctx(), events.span(
                         "prefill/request", rid=rid, tokens=len(work)):
                     cache_1 = self._admission_cache_1(
@@ -2309,7 +2317,10 @@ class ServingEngine:
                     cache_1, first = self._prefill_tokens(
                         work, seed=seed, cache_1=cache_1, draft=False,
                         rng0=resume)
-                first = int(first)
+                self._step_counts["pieces"] += self._pieces_for(
+                    len(work))[1]
+                self._step_counts["prefill_tokens"] += len(work)
+                first = self._first_token(first, rid, stalled)
                 state = _SlotState(request_id=rid, remaining=max_new - 1,
                                    tokens=list(prompt) + [first],
                                    last_token=first, seed=seed,
@@ -2368,9 +2379,22 @@ class ServingEngine:
                 # carry (which still holds the previous tenant's).
                 self._refills.add(slot)
                 events.instant("slot/insert", rid=rid, slot=slot)
-        if prefilled and stalled:
+
+    def _first_token(self, first, rid: int, stalled: bool) -> int:
+        """The host copy of a prefill's first token: the read blocks
+        until the request's last piece has run, so it is a ``*/wait``
+        span.  ``stalled``: lanes are decoding and nothing is in flight
+        to keep them going through the wait, which is then charged to
+        ``prefill_stats['stall_s']`` (on the span's clock, read here so
+        that the gauge outlives ``TTD_NO_TRACE=1``)."""
+        t0 = time.monotonic()
+        with events.span("prefill/wait", rid=rid):
+            first = int(first)
+        if stalled:
             with self._stats_lock:
-                self.prefill_stats["stall_s"] += time.perf_counter() - t0
+                self.prefill_stats["stall_s"] += time.monotonic() - t0
+        self._step_counts["committed"] += 1
+        return first
 
     # -- staged prefill (decode-priority chunked-prefill scheduling) -------
 
@@ -2467,18 +2491,26 @@ class ServingEngine:
         self._refills.add(slot)        # next dispatch splices host carry
         events.instant("slot/insert", rid=task.request_id, slot=slot)
 
-    def _advance_piece(self, slot: int, task: _PrefillTask) -> int:
+    def _advance_piece(self, slot: int, task: _PrefillTask,
+                       stalled: bool) -> int:
         """Run ONE installment of ``task`` — the next target (then
         draft) prefill piece, exactly the program ``_prefill_tokens``
         would have run at this position, plus the finalize/insert when
         it was the last — and return its token cost.  The per-request
         piece programs, their order, and the rng inputs are identical
         to atomic admission, so outputs are bitwise-identical; only the
-        scheduling between OTHER lanes' decode chunks differs."""
+        scheduling between OTHER lanes' decode chunks differs.
+        ``stalled``: see ``_first_token``."""
+        draft = task.cursor >= task.n_pieces
+        i = task.d_cursor if draft else task.cursor
+        real = min(task.piece, len(task.work) - i * task.piece)
+        self._step_counts["pieces"] += 1
+        if not draft:           # the draft's pieces re-run the same tokens
+            self._step_counts["prefill_tokens"] += real
         with self._ctx(), events.span(
                 "prefill/piece", rid=task.request_id,
                 piece=task.cursor + task.d_cursor,
-                n_pieces=task.n_pieces):
+                n_pieces=task.n_pieces, tokens=real):
             if task.cursor < task.n_pieces:
                 if task.cache_1 is None:
                     task.cache_1 = self._admission_cache_1(
@@ -2492,7 +2524,8 @@ class ServingEngine:
                     # this piece — the in-flight decode chunk (enqueued
                     # AHEAD of it) keeps the device busy through the
                     # wait.
-                    first = int(task.first)
+                    first = self._first_token(task.first,
+                                              task.request_id, stalled)
                     task.first_host = first
                     if (task.max_new == 1
                             or (self.eos_id is not None
@@ -2532,11 +2565,11 @@ class ServingEngine:
         if not self._staging:
             return
         decoding = any(s is not None for s in self._slot_states)
-        t0 = time.perf_counter()
         spent = 0
         while self._staging:
             slot = next(iter(self._staging))
-            spent += self._advance_piece(slot, self._staging[slot])
+            spent += self._advance_piece(slot, self._staging[slot],
+                                         stalled=decoding and not hidden)
             with self._stats_lock:
                 self.prefill_stats["installments"] += 1
             if slot not in self._staging:
@@ -2546,19 +2579,16 @@ class ServingEngine:
             if decoding and (self.prefill_budget is None
                              or spent >= self.prefill_budget):
                 break
-        if decoding and not hidden:
-            with self._stats_lock:
-                self.prefill_stats["stall_s"] += time.perf_counter() - t0
 
     @thread_role("handler", "driver")
     def prefill_stall_s(self) -> float:
         """Cumulative seconds decode lanes spent blocked behind
-        admission prefill (wall time of prefill work run while >= 1
-        lane was decoding with no successor chunk in flight to hide
-        it).  Grows with every long admission on the atomic path;
-        collapses to ~0 with interleaving on.  The gateway exposes it
-        as ``ttd_engine_prefill_stall_seconds`` — scraped from handler
-        threads, so the read locks."""
+        admission prefill (the host's ``prefill/wait`` for a first
+        token while >= 1 lane was decoding with no successor chunk in
+        flight to hide it).  Grows with every long admission on the
+        atomic path; collapses to ~0 with interleaving on.  The gateway
+        exposes it as ``ttd_engine_prefill_stall_seconds`` — scraped
+        from handler threads, so the read locks."""
         with self._stats_lock:
             return self.prefill_stats["stall_s"]
 
@@ -2566,6 +2596,7 @@ class ServingEngine:
         """Append generated tokens to a slot's request, enforcing the
         budget and EOS — the ONE termination rule for chunked and
         speculative harvests alike."""
+        before = len(state.tokens)
         for t in tokens:
             t = int(t)
             state.tokens.append(t)
@@ -2576,6 +2607,7 @@ class ServingEngine:
                     or (self.eos_id is not None and t == self.eos_id)):
                 state.done = True
                 break
+        self._step_counts["committed"] += len(state.tokens) - before
 
     def _retire_if_done(self, slot, state):
         if state.done:
@@ -2713,19 +2745,21 @@ class ServingEngine:
         needs."""
         seeds = np.zeros((self.slots,), np.uint32)
         rids: list = [None] * self.slots
+        lanes = positions = 0
         for slot, state in enumerate(self._slot_states):
             if state is not None:
                 seeds[slot] = state.seed
                 rids[slot] = state.request_id
+                lanes += 1
+                positions += len(state.tokens)
+        self._step_counts.update(lanes=lanes, positions=positions)
         # Depth for THIS round: the controller's pick (adaptive) or the
         # fixed k.  Host ints end to end — read before the dispatch
         # window opens (the controller is _stats_lock-guarded; the
         # window must stay conversion- and contention-free).
         k = self._spec_depth()
         with self._ctx(), events.span(
-                "decode/dispatch",
-                active=sum(r is not None for r in rids),
-                fused=self._fused_tag, spec_k=k):
+                "decode/dispatch", fused=self._fused_tag, spec_k=k):
             # Retired/cancelled lanes' tables must point at scratch
             # BEFORE this chunk: their freed blocks may already be
             # reallocated, and this chunk decodes them as garbage.
@@ -2858,11 +2892,25 @@ class ServingEngine:
         so a long prompt's admission spreads across steps while decode
         chunks for occupied lanes keep flowing every step.  The kill
         switch (``prefill_budget=0`` / ``TTD_NO_INTERLEAVE=1``)
-        restores atomic admission byte-for-byte."""
-        if not self.overlap:
-            return self._serve_step_sync()
-        if not self.interleave:
-            return self._serve_step_overlap_atomic()
+        restores atomic admission byte-for-byte.
+
+        The whole step is one ``engine/step`` span, the parent of the
+        ``decode/*`` and ``prefill/*`` spans recorded inside it; at exit
+        it is given what the step did (``_STEP_COUNTS``) and the queue's
+        depth."""
+        with events.span("engine/step") as step:
+            self._step_counts = dict.fromkeys(_STEP_COUNTS, 0)
+            if not self.overlap:
+                out = self._serve_step_sync()
+            elif not self.interleave:
+                out = self._serve_step_overlap_atomic()
+            else:
+                out = self._serve_step_staged()
+            step.set(queued=len(self._queue), **self._step_counts)
+        return out
+
+    def _serve_step_staged(self) -> dict:
+        """The pipelined step with STAGED admission (the default)."""
         prev, self._inflight = self._inflight, None
         # DECODE PRIORITY: the successor chunk for occupied lanes goes
         # onto the device queue before any admission work, so active
@@ -2942,18 +2990,20 @@ class ServingEngine:
             tok = np.zeros((self.slots,), np.int32)
             seeds = np.zeros((self.slots,), np.uint32)
             counts = np.zeros((self.slots,), np.int32)
-            n_active = 0
+            n_active = positions = 0
             for slot, state in enumerate(self._slot_states):
                 if state is not None:
                     tok[slot] = state.last_token
                     seeds[slot] = state.seed
                     counts[slot] = state.count
                     n_active += 1
+                    positions += len(state.tokens)
+            self._step_counts.update(lanes=n_active, positions=positions)
             if self._draft_model is not None:
                 k = self._spec_depth()
                 with self._ctx(), events.span(
-                        "decode/dispatch", active=n_active,
-                        fused=self._fused_tag, spec_k=k):
+                        "decode/dispatch", fused=self._fused_tag,
+                        spec_k=k):
                     self._flush_stale_lanes()
                     (self._cache, self._d_cache, emit, emitted,
                      next_tok, acc, _) = self._spec_round(
@@ -2970,8 +3020,7 @@ class ServingEngine:
                     self._harvest_spec(*args, k)
             else:
                 with self._ctx(), events.span(
-                        "decode/dispatch", active=n_active,
-                        fused=self._fused_tag):
+                        "decode/dispatch", fused=self._fused_tag):
                     self._flush_stale_lanes()
                     self._cache, toks, _, _ = self._decode_chunk(
                         self._variables, self._cache, jnp.asarray(tok),

@@ -1,0 +1,279 @@
+"""One span call, two sinks: ``runtime.events.span`` in the ring and in a
+``jax.profiler`` capture, and the engine's span contract.
+
+CPU only.  A capture here has no device plane; what is checked is the
+host plane: the program's spans are events of it, under their names and
+attrs, which on the chip puts them on the clock of the device's
+operations (``benchmark/layer_metrics/idle_unowned_pct.longprompt.py``
+reads them there).
+"""
+
+import glob
+import os
+import subprocess
+import sys
+
+import pytest
+
+from tensorflow_train_distributed_tpu.runtime import events
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(autouse=True)
+def _trace_on(monkeypatch):
+    monkeypatch.delenv("TTD_NO_TRACE", raising=False)
+
+
+def _captured(tmp_path, body) -> dict:
+    """Run ``body()`` under a profiler capture; ``{name: [stats]}`` of
+    the capture's host events (stats as a dict per event)."""
+    import jax
+
+    logdir = str(tmp_path / "capture")
+    jax.profiler.start_trace(logdir)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (dict(ev.stats), ev.start_ns, ev.duration_ns))
+    return out
+
+
+def test_span_lands_in_the_capture_with_its_attrs(tmp_path):
+    rec = events.Recorder(16)
+
+    def body():
+        with rec.span("decode/dispatch", spec_k=3, fused=1):
+            pass
+
+    host = _captured(tmp_path, body)
+    (stats, _, dur_ns), = host["decode/dispatch"]
+    assert stats == {"spec_k": 3, "fused": 1}
+    (name, ph, _, dur, _, attrs), = rec.events()
+    assert (name, ph, attrs) == ("decode/dispatch", "X",
+                                 {"spec_k": 3, "fused": 1})
+    # Two clocks around one block: the same duration within a few us.
+    assert abs(dur - dur_ns * 1e-9) < 1e-3
+
+
+def test_set_attrs_reach_ring_and_capture(tmp_path):
+    rec = events.Recorder(16)
+
+    def body():
+        with rec.span("engine/step") as step:
+            step.set(lanes=2, committed=8)
+        with rec.span("engine/step", queued=1) as step:
+            step.set(lanes=0)
+
+    host = _captured(tmp_path, body)
+    assert [s for s, _, _ in host["engine/step"]] == [
+        {"lanes": 2, "committed": 8}, {"queued": 1, "lanes": 0}]
+    assert [e[5] for e in rec.events()] == [
+        {"lanes": 2, "committed": 8}, {"queued": 1, "lanes": 0}]
+
+
+def test_kill_switch_emits_to_neither_sink(tmp_path, monkeypatch):
+    monkeypatch.setenv("TTD_NO_TRACE", "1")
+    rec = events.Recorder(16)
+
+    def body():
+        with rec.span("decode/wait") as wait:
+            wait.set(overlapped=True)
+        rec.instant("slot/insert", rid=1)
+
+    host = _captured(tmp_path, body)
+    assert "decode/wait" not in host and "slot/insert" not in host
+    assert len(rec) == 0
+
+
+def test_instants_stay_in_the_ring(tmp_path):
+    rec = events.Recorder(16)
+    host = _captured(tmp_path, lambda: rec.instant("slot/insert", rid=1))
+    assert "slot/insert" not in host
+    assert [e[0] for e in rec.events()] == ["slot/insert"]
+
+
+def test_recording_spans_initialises_no_backend():
+    """The supervisor and the procpool parent record spans and must
+    never hold the chip: a span may import jax's profiler, never start a
+    backend.  A fresh process, so that nothing else has."""
+    code = (
+        "from tensorflow_train_distributed_tpu.runtime import events\n"
+        "with events.span('engine/step', lanes=1) as s:\n"
+        "    s.set(committed=2)\n"
+        "events.instant('slot/insert', rid=0)\n"
+        "from jax._src import xla_bridge\n"
+        "print(len(events.get_recorder()),\n"
+        "      xla_bridge.backends_are_initialized())\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=300,
+                         env=dict(os.environ, TTD_NO_TRACE="0"))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["2", "False"]
+
+
+def test_annotation_class_is_resolved_lazily():
+    """Not at import of ``events``: the module is imported by processes
+    that record nothing."""
+    code = (
+        "import sys\n"
+        "from tensorflow_train_distributed_tpu.runtime import events\n"
+        "print(events._ANNOTATION)\n"
+        "with events.span('x'): pass\n"
+        "print(events._ANNOTATION.__name__)\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=300,
+                         env=dict(os.environ, TTD_NO_TRACE="0"))
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["None", "TraceAnnotation"]
+
+
+def test_spans_between_windows_and_reports_laps():
+    rec = events.Recorder(4)
+    for i in range(3):
+        rec.record_at("engine/step", "X", 10.0 + i, 0.5, {"i": i})
+    rec.record_at("slot/insert", "i", 11.2)
+    spans, dropped = rec.spans_between(10.0, 12.0)
+    assert [e[5]["i"] for e in spans] == [0, 1] and dropped == 0
+    spans, _ = rec.spans_between(10.0, 13.0, name="decode/wait")
+    assert spans == []
+    # Two more events lap the ring: the window's start is gone, and the
+    # reader is told how much was lost, not handed a short list in
+    # silence.
+    rec.record_at("engine/step", "X", 13.0, 0.5, {"i": 3})
+    rec.record_at("engine/step", "X", 14.0, 0.5, {"i": 4})
+    spans, dropped = rec.spans_between(10.0, 15.0, name="engine/step")
+    assert [e[5]["i"] for e in spans] == [2, 3, 4] and dropped == 2
+    # A window the ring still holds whole reports nothing lost.
+    assert rec.spans_between(12.5, 15.0)[1] == 0
+    fresh = events.Recorder(4)
+    fresh.record_at("engine/step", "X", 1.0, 0.5)
+    fresh.clear()                   # taken out on purpose: not a lap
+    fresh.record_at("engine/step", "X", 3.0, 0.5)
+    assert fresh.spans_between(2.0, 4.0) == (fresh.events(), 0)
+
+
+@pytest.mark.parametrize("name, known", [
+    ("engine/step", True), ("prefill/wait", True),
+    ("compile/ServingEngine._decode_chunk", True),
+    ("memory/kv_pool", True), ("engine/stepper", False),
+    ("bench/window", False), ("$serving.py:2835 serve_step", False)])
+def test_contract_membership(name, known):
+    assert events.in_contract(name) is known
+
+
+# ── the engine keeps the contract ──────────────────────────────────────
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import jax
+    import jax.numpy as jnp
+
+    from tensorflow_train_distributed_tpu.models.llama import (
+        LLAMA_PRESETS,
+        LlamaModel,
+    )
+
+    cfg = LLAMA_PRESETS["llama_tiny"]
+    params = LlamaModel(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    return cfg, params
+
+
+VARIANTS = {
+    "staged": dict(),
+    "overlap-atomic": dict(prefill_budget=0),
+    "sync": dict(overlap=False),
+    "sync-atomic": dict(overlap=False, prefill_budget=0),
+}
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_engine_spans_keep_the_contract(tiny, variant):
+    """Every name the engine records is in the table; every ``decode/*``
+    and ``prefill/*`` span lies inside an ``engine/step`` of its thread;
+    the steps' ``committed`` add up to the tokens handed back."""
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    cfg, params = tiny
+    reqs = [([1, 2, 3], 6), ([4, 5], 5), ([9, 8, 7, 6, 5, 4, 3, 2, 1], 4),
+            ([7], 1)]
+    eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
+                        prefill_chunk=4, **VARIANTS[variant])
+    rec = events.get_recorder()
+    seq0 = rec.events_after(0)[0]
+    ids = [eng.submit(p, m) for p, m in reqs]
+    out = eng.run()
+    _, evs = rec.events_after(seq0)
+
+    assert {e[0] for e in evs if not events.in_contract(e[0])} == set()
+    for name, _, _, _, _, attrs in evs:
+        assert set(attrs or ()) <= events.contract_attrs(name), (
+            name, attrs)
+    steps = [e for e in evs if e[0] == "engine/step"]
+    for name, ph, t0, dur, tid, _ in evs:
+        if ph == "X" and name.split("/")[0] in ("decode", "prefill"):
+            assert any(s[4] == tid and s[2] <= t0
+                       and t0 + dur <= s[2] + s[3] for s in steps), name
+    returned = sum(len(out[i]) - len(p) for i, (p, _) in zip(ids, reqs))
+    assert returned == sum(m for _, m in reqs)
+    assert sum(s[5]["committed"] for s in steps) == returned
+    # Counts a step carries: what its dispatch saw, what its pieces held.
+    for s in steps:
+        assert set(s[5]) == {"lanes", "positions", "pieces",
+                             "prefill_tokens", "committed", "queued"}
+        assert 0 <= s[5]["lanes"] <= 2
+    assert sum(s[5]["prefill_tokens"] for s in steps) == sum(
+        len(p) for p, _ in reqs)
+    # One dispatch a step that had lanes to run, and none otherwise.
+    assert len([e for e in evs if e[0] == "decode/dispatch"]) == len(
+        [s for s in steps if s[5]["lanes"]])
+    assert all(s[5]["positions"] >= s[5]["lanes"] for s in steps)
+    pieces = [e for e in evs if e[0] == "prefill/piece"]
+    assert len(pieces) == (0 if "atomic" in variant else sum(
+        s[5]["pieces"] for s in steps))
+    assert all(1 <= p[5]["tokens"] <= 4 for p in pieces)
+    # A first token is read inside a */wait span, once per request.
+    assert len([e for e in evs if e[0] == "prefill/wait"]) == len(reqs)
+
+
+@pytest.mark.parametrize("killed", [False, True])
+def test_stall_seconds_are_the_wait_span_and_outlive_the_kill_switch(
+        tiny, killed, monkeypatch):
+    """``prefill_stats['stall_s']`` is the ``prefill/wait`` of the
+    admissions that made decoding lanes wait, on the span's clock; the
+    operator's gauge keeps counting under ``TTD_NO_TRACE=1``."""
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    if killed:
+        monkeypatch.setenv("TTD_NO_TRACE", "1")
+    cfg, params = tiny
+    eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
+                        prompt_buckets=(8,), overlap=False,
+                        prefill_budget=0)
+    rec = events.get_recorder()
+    seq0 = rec.events_after(0)[0]
+    eng.submit([1, 2, 3], 8)
+    eng.serve_step()                      # lane 0 decodes from here on
+    assert eng.prefill_stall_s() == 0.0   # nobody was waiting yet
+    eng.submit([4, 5, 6], 4)
+    eng.run()
+    waits = [e for e in rec.events_after(seq0)[1]
+             if e[0] == "prefill/wait"]
+    if killed:
+        assert not waits and eng.prefill_stall_s() > 0.0
+    else:
+        assert len(waits) == 2
+        # read around the span: its duration and the annotation's exit
+        assert 0.0 <= eng.prefill_stall_s() - waits[1][3] < 1e-3

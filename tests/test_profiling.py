@@ -5,21 +5,28 @@ import os
 
 import pytest
 
-from tensorflow_train_distributed_tpu.runtime import profiling
+from tensorflow_train_distributed_tpu.runtime import events, profiling
 
 
 def test_trace_writes_xplane(tmp_path):
+    """A capture lands as an xplane, and an ``events.span`` recorded
+    under it is one of its host events."""
     import jax
     import jax.numpy as jnp
 
     logdir = str(tmp_path / "trace")
     with profiling.trace(logdir):
-        with profiling.annotate("unit-test-span"):
+        with events.span("unit-test/span"):
             jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
     # XPlane capture lands under plugins/profile/<run>/ as .xplane.pb.
     found = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
                       recursive=True)
     assert found, f"no xplane produced under {logdir}"
+    data = jax.profiler.ProfileData.from_file(found[-1])
+    names = {ev.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "unit-test/span" in names
 
 
 def test_profile_callback_window(tmp_path, monkeypatch):

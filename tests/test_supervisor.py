@@ -33,6 +33,21 @@ REPO_ROOT = str(pathlib.Path(__file__).resolve().parents[1])
 _TOOLS = os.path.join(REPO_ROOT, "tools")
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _eight_cpu_devices_again():
+    """``tools/chaos_check.py`` re-targets this process to 2 CPU devices
+    for its in-process parity read (orbax rebuilds shardings from the
+    checkpoint's sharding file, which names the child CLIs' devices).
+    Put the suite's 8 back when the module ends: under xdist the worker
+    is handed another file next, and most need them."""
+    yield
+    from tensorflow_train_distributed_tpu.runtime.mesh import (
+        force_platform,
+    )
+
+    force_platform("cpu", 8)
+
+
 def _child(code: str) -> list:
     return [sys.executable, "-c", code]
 

@@ -9,8 +9,10 @@ widths with ``use_pallas=True`` and asserts the compiled program holds a
 
 A compile that passes here is NOT a chip run: nothing executes, so it
 says nothing about numerics or time (``chip_smoke.py`` does, on the
-chip).  Kernels only — whole-program compiles (the train step, the
-engine's programs) are rehearsed from scratch scripts before a chip
+chip).  Kernels, and ONE whole program: the decode program of the
+benchmark's ``qwen25-7b-1chip`` (three seconds), for the names the
+device trace is read by.  Other whole-program compiles (the train step,
+the prefill pieces) are rehearsed from scratch scripts before a chip
 call, not in tier-1.
 """
 
@@ -183,3 +185,66 @@ def test_kernel_partitions_over_a_2x2_mesh(case, v5e, monkeypatch):
     with jax.set_mesh(mesh):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_program_carries_its_scope_names(v5e, monkeypatch):
+    """The benchmark's decode program, at its real width, compiled for
+    the described chip: every region of the device-scope contract is in
+    the operations' metadata (``benchmark/harness/scopes.py`` reads a
+    trace by it), and the attention kernel is still a
+    ``tpu_custom_call`` that the method it is called from names
+    (``paged_attn_roofline.decode`` finds its events so: the kernel
+    takes no ``name=`` of its own, which would replace that name)."""
+    import dataclasses
+    import json
+    import os
+    import re
+
+    import flax.linen as nn
+
+    from tensorflow_train_distributed_tpu.models import llama
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "qwen25-7b-1chip.json")) as f:
+        cfg_file = json.load(f)
+    cfg = dataclasses.replace(
+        llama.LLAMA_PRESETS[cfg_file["program"]["preset"]],
+        **cfg_file["program"]["replace"])
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def described(tree, dtype=None):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(nn.meta.unbox(jax.eval_shape(
+        lambda: llama.LlamaModel(cfg).init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))["params"],
+        BF16)
+    kw = {k: v for k, v in cfg_file["engine"].items() if k != "max_queue"}
+    eng = ServingEngine(cfg, params, cast_params=False, **kw)
+    assert eng.fused_attn
+    cache = described(eng._cache_struct(eng.slots, grid=True))
+    lanes = {d: jax.ShapeDtypeStruct((eng.slots,), d, sharding=one_chip)
+             for d in (jnp.int32, jnp.uint32)}
+    program = ServingEngine._decode_chunk
+    while not hasattr(program, "lower"):     # past the compile sanitizer
+        program = program.__wrapped__
+    text = program.lower(eng, eng._variables, cache, lanes[jnp.int32],
+                         lanes[jnp.uint32],
+                         lanes[jnp.int32]).compile().as_text()
+
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("embed", "norm", "attn/qkv", "attn/out", "mlp", "head",
+                  "sample", "kv_pool/write"):
+        assert any(f"/{scope}/" in name for name in op_names), scope
+    # A device event is named by its instruction, without the metadata:
+    # the name has to say which kernel it is.
+    kernels = re.findall(
+        r'^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*'
+        r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert any("_paged_decode_step" in name for name in kernels), kernels
+    assert any(name.startswith("rms_norm_fwd") for name in kernels)
