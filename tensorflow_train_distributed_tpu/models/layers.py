@@ -814,8 +814,15 @@ class MultiHeadAttention(nn.Module):
         # No scope from here to the kernel call: the benchmark finds the
         # kernel's device events by this method's name.
         if self._fused_paged_ok():
+            # The kernel walks as many blocks as a lane's length
+            # reaches.  A lane whose table starts at the scratch block
+            # holds nothing (never inserted, or reset at retirement),
+            # yet its index grows with every chunk it idles through:
+            # it reads its one block of garbage, not a walk of scratch
+            # that lengthens until the lane is used again.
+            held = jnp.where(table.value[:, 0] == 0, 0, cur)
             out = pk.paged_attention(
-                q, cache_k.value, cache_v.value, table.value, cur,
+                q, cache_k.value, cache_v.value, table.value, held,
                 k_scales=(kv_scales.value[0] if self.kv_cache_int8
                           else None),
                 v_scales=(kv_scales.value[1] if self.kv_cache_int8

@@ -34,14 +34,17 @@ Two serving-side kernels back the engine's paged KV cache:
   KV view in HBM before attention ever runs — doubling HBM traffic on
   the one resource decode is bound by (the paged_kv_ab residual).
   This kernel computes flash-style decode attention DIRECTLY through
-  the block table: grid (lane, logical block) with the same
-  scalar-prefetched table steering each block's DMA, an online
-  (max, sumexp, acc) accumulator per (head, query row) carried across
-  blocks in VMEM scratch, per-lane causal masking from a prefetched
-  length vector, GQA handled per kv-head group in-kernel, and optional
-  int8-pool dequant fused into the block read (per-row symmetric
+  the block table: one grid step a lane, which walks the blocks its
+  prefetched length reaches and no others (``paged_blocks_walked``;
+  the pools stay in HBM and the scalar-prefetched table steers
+  hand-issued, double-buffered copies of 128 rows' worth of blocks a
+  step), an online (max, sumexp, acc) accumulator per (head, query
+  row) carried across steps in VMEM scratch, per-lane causal masking
+  from the same length, GQA handled per kv-head group in-kernel, and
+  optional int8-pool dequant fused into the walk (per-row symmetric
   scales ride in a parallel scale pool) — the dense per-lane view is
-  never materialized.  ``TTD_NO_FUSED_ATTN=1`` restores the
+  never materialized, and the kernel's time follows what the lanes
+  hold, not slots x blocks a lane.  ``TTD_NO_FUSED_ATTN=1`` restores the
   gather-then-attend path (the byte-comparable A/B leg);
   ``TTD_FUSED_ATTN_INTERPRET=1`` forces the kernel in interpret mode
   off-TPU (the CPU parity-test path).
@@ -277,69 +280,122 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
     return out.transpose(0, 2, 1, 3)
 
 
-def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                       bs, kvh, rep, q_len, hd, scale, int8):
-    """Grid (lane, logical block), block innermost: the index maps
-    already steered this step's K/V (and scale) DMA to physical block
-    ``table[lane, j]``; the body folds the block into each query row's
-    online (max, sumexp, acc) accumulator.  Row layout is
-    [heads·q_len, hd] with row = head·q_len + qi, so each GQA group's
+def paged_blocks_walked(lengths, q_len: int, bs: int, n_blk: int):
+    """Blocks of its table that ``paged_attention`` reads for a lane
+    holding ``lengths`` rows before the call: the ``q_len`` queries see
+    rows ``0 .. lengths + q_len - 1``, so ``ceil((lengths + q_len) /
+    bs)`` blocks, never more than the table has and never fewer than
+    one (block 0 always holds a visible row, which keeps an empty or
+    reset lane's accumulator off an all-masked zero).  One rule for the
+    kernel (a scalar out of SMEM) and for the host's ``kv_blocks``
+    counter (a numpy vector): ``lengths`` needs ``+``, ``//``, ``clip``.
+    """
+    return ((lengths + (q_len + bs - 1)) // bs).clip(1, n_blk)
+
+
+def _paged_fold(bs: int, n_blk: int) -> int:
+    """Table entries one step of the kernel's walk folds into the
+    accumulators: 128 rows' worth, so a step moves 256 KB at the
+    benchmark's widths instead of one 16-row block's 32 KB."""
+    return min(n_blk, max(1, 128 // bs))
+
+
+def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+                       bs, fold, last_row, kvh, rep, q_len, hd, scale,
+                       int8):
+    """Grid (lane,): the lane walks the blocks it holds
+    (``paged_blocks_walked``), ``fold`` table entries to a step, and
+    stops there.  The pools stay in HBM; each step's blocks come in by
+    hand-issued copies steered by the scalar-prefetched table, double
+    buffered so step s + 1 is in flight while step s is folded into
+    each query row's online (max, sumexp, acc) accumulator.  Row layout
+    is [heads*q_len, hd] with row = head*q_len + qi, so each GQA group's
     rows are one contiguous slice and the per-row query position is
     ``row % q_len``."""
+    from jax.experimental.pallas import tpu as pltpu
+
     if int8:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref = rest[:2]
+    o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = rest[-7:]
     i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[:] = jnp.full_like(m_ref, _NEG)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
     cur = len_ref[i]
-    qf = q_ref[0].astype(jnp.float32)        # [heads*q_len, hd]
-    kf = k_ref[0]                            # [bs, kvh*hd]
-    vf = v_ref[0]
-    r = rep * q_len                          # rows per kv-head group
-    for g in range(kvh):                     # static: tiny head count
-        kg = kf[:, g * hd:(g + 1) * hd].astype(jnp.float32)
-        vg = vf[:, g * hd:(g + 1) * hd].astype(jnp.float32)
-        if int8:
-            # Per-row symmetric dequant fused into the block read —
-            # int8 bytes came off HBM, f32 math from here.
-            kg = kg * ks_ref[0][:, g:g + 1]
-            vg = vg * vs_ref[0][:, g:g + 1]
-        qg = qf[g * r:(g + 1) * r]           # [r, hd]
-        logits = jax.lax.dot_general(
-            qg, kg, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [r, bs]
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (r, bs), 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (r, bs), 0) % q_len
-        # Causal through the table: row p visible to query qi iff
-        # p <= cur + qi.  Rows past the lane's length (incl. the whole
-        # scratch block a reset lane's table points at) mask out here;
-        # block 0 always has a visible row for every query (p=0), so
-        # the accumulator never divides by an all-masked zero.
-        logits = jnp.where(pos <= cur + qi, logits, _NEG)
-        rows = slice(g * r, (g + 1) * r)
-        m_prev = m_ref[rows]
-        m_new = jnp.maximum(m_prev,
-                            jnp.max(logits, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)
-        l_ref[rows] = (l_ref[rows] * alpha
-                       + jnp.sum(p, axis=-1, keepdims=True))
-        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
-            p, vg, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[rows] = m_new
+    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1)
+    steps = pl.cdiv(live, fold)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+    def copies(step, slot, wait=False):
+        # Entries of the lane's last step past its count repeat its
+        # last live block: finite rows the mask drops, and no block the
+        # lane does not hold is ever read.  A wait needs only shapes.
+        out = []
+        for p in range(fold):
+            blk = 0 if wait else tbl_ref[
+                i, jnp.minimum(step * fold + p, live - 1)]
+            out += [pltpu.make_async_copy(pool.at[blk], buf.at[slot, p],
+                                          sem.at[slot])
+                    for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
+        return out
+
+    m_ref[:] = jnp.full_like(m_ref, _NEG)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    for c in copies(0, 0):
+        c.start()
+    qf = q_ref[0].astype(jnp.float32)        # [heads*q_len, hd]
+    r = rep * q_len                          # rows per kv-head group
+    n = fold * bs                            # cache rows a step folds
+    # Causal through the table: row p visible to query qi iff
+    # p <= cur + qi, and the cache has it (an overrun lane sees the
+    # whole cache and no further).  Rows past the lane's length mask
+    # out; block 0 always has a visible row for every query (p=0), so
+    # the accumulator never divides by an all-masked zero.
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, n), 1)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (r, n), 0) % q_len
+    last_seen = jnp.minimum(cur + qi, last_row)
+
+    def fold_step(step, _):
+        slot = jax.lax.rem(step, 2)
+
+        @pl.when(step + 1 < steps)
+        def _():
+            for c in copies(step + 1, 1 - slot):
+                c.start()
+
+        for c in copies(step, slot, wait=True):
+            c.wait()
+        kf = k_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
+        vf = v_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
+        seen = step * n + col <= last_seen
+        if int8:
+            cols = pl.ds(pl.multiple_of(step * n, n), n)
+            ksf, vsf = ks_ref[0, :, cols], vs_ref[0, :, cols]  # [kvh, n]
+        for g in range(kvh):                 # static: tiny head count
+            rows = slice(g * r, (g + 1) * r)
+            logits = jax.lax.dot_general(
+                qf[rows], kf[:, g * hd:(g + 1) * hd],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale      # [r, n]
+            if int8:
+                # Per-row symmetric dequant of the int8 bytes that came
+                # off HBM: a cache row's scale is a factor of its whole
+                # logit column, so it multiplies after the product.
+                logits = logits * ksf[g:g + 1]
+            logits = jnp.where(seen, logits, _NEG)
+            m_prev = m_ref[rows]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            l_ref[rows] = (l_ref[rows] * alpha
+                           + jnp.sum(p, axis=-1, keepdims=True))
+            if int8:                         # and of its value row
+                p = jnp.where(seen, p * vsf[g:g + 1], 0.0)
+            acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+                p, vf[:, g * hd:(g + 1) * hd], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[rows] = m_new
+
+    jax.lax.fori_loop(0, steps, fold_step, None)
+    o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, table, lengths, *,
@@ -351,8 +407,13 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
     the dense per-lane KV view ``paged_kv_gather`` materializes never
     exists.  Arguments as ``paged_attention_reference`` (the pure-jax
     oracle this is tested against; also the CPU path).  One grid step
-    DMAs exactly one physical block per lane, so HBM reads are the
-    pool bytes once instead of pool-bytes + dense-copy twice."""
+    is one lane, which reads the blocks its length reaches
+    (``paged_blocks_walked``) and no others, ``_paged_fold`` of them to
+    a copy-and-fold step: HBM reads are the lane's own rows once, and
+    the kernel's time follows what the lanes hold, not the table's
+    width.  An int8 pool's scales (4 bytes a row and KV head against
+    ``head_dim`` of them) are too narrow for a copy of their own: they
+    come in as one gathered [kv_heads, rows] strip a lane."""
     if not _use_pallas(use_pallas) and not interpret:
         return paged_attention_reference(
             q, k_pool, v_pool, table, lengths, k_scales=k_scales,
@@ -367,42 +428,43 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
                          f"{kvh}")
     rep = heads // kvh
     int8 = k_scales is not None
+    fold = _paged_fold(bs, n_blk)
+    last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
     # [lanes, q_len, H, hd] → [lanes, H*q_len, hd]: row = h*q_len + qi,
     # so each kv-head group's rows are contiguous in the kernel.
     qt = q.transpose(0, 2, 1, 3).reshape(lanes, heads * q_len, hd)
-    kf = k_pool.reshape(nb, bs, kvh * hd)
-    vf = v_pool.reshape(nb, bs, kvh * hd)
-    in_specs = [
-        pl.BlockSpec((1, heads * q_len, hd),
-                     lambda i, j, tbl, lens: (i, 0, 0)),
-        pl.BlockSpec((1, bs, kvh * hd),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
-        pl.BlockSpec((1, bs, kvh * hd),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
-    ]
-    args = [table, lengths.astype(jnp.int32), qt, kf, vf]
+    rows = pl.BlockSpec((1, heads * q_len, hd),
+                        lambda i, tbl, lens: (i, 0, 0))
+    in_specs = [rows] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
+    args = [table, lengths.astype(jnp.int32), qt,
+            k_pool.reshape(nb, bs, kvh * hd),
+            v_pool.reshape(nb, bs, kvh * hd)]
     if int8:
-        in_specs += [
-            pl.BlockSpec((1, bs, kvh),
-                         lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
-            pl.BlockSpec((1, bs, kvh),
-                         lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
-        ]
-        args += [k_scales, v_scales]
+        # The strip is as wide as whole steps of the walk, so a step's
+        # slice of it never runs off the end.
+        wide = jnp.pad(table, ((0, 0), (0, -n_blk % fold)))
+        in_specs += [pl.BlockSpec((1, kvh, wide.shape[1] * bs),
+                                  lambda i, tbl, lens: (i, 0, 0))] * 2
+        args += [jnp.take(s, wide, axis=0, mode="clip")
+                 .reshape(lanes, -1, kvh).transpose(0, 2, 1)
+                 for s in (k_scales, v_scales)]
     out = pl.pallas_call(
         functools.partial(
-            _paged_attn_kernel, bs=bs, kvh=kvh, rep=rep, q_len=q_len,
-            hd=hd, scale=hd ** -0.5, int8=int8),
+            _paged_attn_kernel, bs=bs, fold=fold, last_row=last_row,
+            kvh=kvh, rep=rep, q_len=q_len, hd=hd, scale=hd ** -0.5,
+            int8=int8),
         # No name= here: a name becomes the HLO instruction's, and the
         # benchmark finds this kernel's device events by the name the
         # calling method gives it (``attention._paged_decode_step``).
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(lanes, n_blk),
+            grid=(lanes,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, heads * q_len, hd),
-                                   lambda i, j, tbl, lens: (i, 0, 0)),
+            out_specs=rows,
             scratch_shapes=[
+                pltpu.VMEM((2, fold, bs, kvh * hd), k_pool.dtype),
+                pltpu.VMEM((2, fold, bs, kvh * hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((heads * q_len, 1), jnp.float32),
                 pltpu.VMEM((heads * q_len, 1), jnp.float32),
                 pltpu.VMEM((heads * q_len, hd), jnp.float32),

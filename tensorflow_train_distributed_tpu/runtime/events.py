@@ -97,10 +97,13 @@ DEFAULT_CAPACITY = 65536
 #: on) to the driver's events.
 CONTRACT = {
     # -- layer "engine host loop" (serving.py) --
-    # what the step did: lanes active at its dispatch and the cached
-    # positions they held, prefill pieces run and their prompt tokens,
-    # tokens handed to requests, the engine queue's depth at exit
-    "engine/step": "lanes positions pieces prefill_tokens committed queued",
+    # what the step did: lanes active at its dispatch, the cached
+    # positions they held, the pool blocks those reach over all slots
+    # (what the fused attention kernel reads) of the blocks the slots'
+    # tables have, prefill pieces run and their prompt tokens, tokens
+    # handed to requests, the engine queue's depth at exit
+    "engine/step": ("lanes positions kv_blocks kv_table_blocks pieces "
+                    "prefill_tokens committed queued"),
     "decode/dispatch": "fused spec_k",
     "decode/wait": "overlapped",
     "decode/harvest": "overlapped",

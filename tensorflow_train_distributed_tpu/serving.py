@@ -159,6 +159,9 @@ from tensorflow_train_distributed_tpu.models.quant import (
     maybe_quant_variables,
     quantized_inference,
 )
+from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
+    paged_blocks_walked,
+)
 
 
 @dataclasses.dataclass
@@ -258,11 +261,14 @@ def _paged_killed() -> bool:
 
 
 #: What an ``engine/step`` span says its step did: lanes active at the
-#: dispatch and the cached positions they held (as the host knew them),
+#: dispatch, the cached positions they held (as the host knew them),
+#: the blocks of the paged pool those reach over all slots (what the
+#: fused attention kernel reads) and the block table's whole size
+#: (slots x blocks a lane, what it read before it followed lengths),
 #: prefill pieces run and the prompt tokens they carried, output tokens
 #: handed to requests.
-_STEP_COUNTS = ("lanes", "positions", "pieces", "prefill_tokens",
-                "committed")
+_STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
+                "pieces", "prefill_tokens", "committed")
 
 
 def _bucket_len(n: int, buckets) -> int:
@@ -2735,6 +2741,24 @@ class ServingEngine:
             self._refills.clear()
         return tok, counts
 
+    def _count_dispatch(self, held: list, spec_k: int) -> None:
+        """``engine/step``'s account of a decode dispatch, from the
+        positions each active lane ``held`` as the host knows them.
+        ``kv_blocks`` is the fused attention kernel's own rule
+        (``paged_blocks_walked``: the blocks a lane's rows and this
+        call's ``spec_k + 1`` queries reach, one for an idle slot) over
+        all slots, of the ``kv_table_blocks`` their tables have; both 0
+        on a linear cache."""
+        kv_blocks = kv_table_blocks = 0
+        if self.paged:
+            kv_table_blocks = self.slots * self._kv_nblk_lane
+            kv_blocks = self.slots - len(held) + int(paged_blocks_walked(
+                np.asarray(held, np.int64), spec_k + 1,
+                self.kv_block_size, self._kv_nblk_lane).sum())
+        self._step_counts.update(
+            lanes=len(held), positions=sum(held), kv_blocks=kv_blocks,
+            kv_table_blocks=kv_table_blocks)
+
     @dispatch_critical
     def _dispatch_chunk(self) -> None:
         """Enqueue one decode chunk (or speculative round) for ALL
@@ -2745,19 +2769,18 @@ class ServingEngine:
         needs."""
         seeds = np.zeros((self.slots,), np.uint32)
         rids: list = [None] * self.slots
-        lanes = positions = 0
+        held = []
         for slot, state in enumerate(self._slot_states):
             if state is not None:
                 seeds[slot] = state.seed
                 rids[slot] = state.request_id
-                lanes += 1
-                positions += len(state.tokens)
-        self._step_counts.update(lanes=lanes, positions=positions)
+                held.append(len(state.tokens))
         # Depth for THIS round: the controller's pick (adaptive) or the
         # fixed k.  Host ints end to end — read before the dispatch
         # window opens (the controller is _stats_lock-guarded; the
         # window must stay conversion- and contention-free).
         k = self._spec_depth()
+        self._count_dispatch(held, k)
         with self._ctx(), events.span(
                 "decode/dispatch", fused=self._fused_tag, spec_k=k):
             # Retired/cancelled lanes' tables must point at scratch
@@ -2990,17 +3013,16 @@ class ServingEngine:
             tok = np.zeros((self.slots,), np.int32)
             seeds = np.zeros((self.slots,), np.uint32)
             counts = np.zeros((self.slots,), np.int32)
-            n_active = positions = 0
+            held = []
             for slot, state in enumerate(self._slot_states):
                 if state is not None:
                     tok[slot] = state.last_token
                     seeds[slot] = state.seed
                     counts[slot] = state.count
-                    n_active += 1
-                    positions += len(state.tokens)
-            self._step_counts.update(lanes=n_active, positions=positions)
+                    held.append(len(state.tokens))
+            k = self._spec_depth()
+            self._count_dispatch(held, k)
             if self._draft_model is not None:
-                k = self._spec_depth()
                 with self._ctx(), events.span(
                         "decode/dispatch", fused=self._fused_tag,
                         spec_k=k):

@@ -325,6 +325,42 @@ def test_trace_report_counts_fused_dispatches(tmp_path):
     assert kv["prefix_hit_tokens"] == 8
 
 
+def test_trace_report_prints_the_kv_walk_live_share(tmp_path, capsys):
+    """``engine/step``'s ``kv_blocks`` (what the attention kernel's walk
+    read at the step's dispatch) beside ``kv_table_blocks`` (the slots x
+    blocks-a-lane table it spans): the report sums both over the window
+    and prints the share; a step with no dispatch, or a linear cache
+    (both 0), adds nothing."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(os.path.dirname(__file__),
+                                     "..", "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+
+    rec = Recorder(capacity=64)
+    for blocks in (1024, 968, 0):
+        with rec.span("engine/step") as step:
+            step.set(lanes=31, positions=15_445, kv_blocks=blocks,
+                     kv_table_blocks=8192 if blocks else 0)
+    path = tmp_path / "trace.json"
+    rec.save(str(path))
+    kv = mod.kv_cache_summary(mod.load_events(str(path)))
+    assert (kv["kv_blocks"], kv["kv_table_blocks"]) == (1992, 16384)
+    assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "kv blocks walked   1992 of 16384" in out
+    assert "live share 12.2%" in out
+
+    linear = Recorder(capacity=8)
+    with linear.span("engine/step") as step:
+        step.set(lanes=2, positions=9, kv_blocks=0, kv_table_blocks=0)
+    linear.save(str(path))
+    assert mod.kv_cache_summary(mod.load_events(str(path))) == {}
+
+
 # ── supervisor instants ────────────────────────────────────────────────
 
 

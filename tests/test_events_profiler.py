@@ -231,9 +231,17 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     assert sum(s[5]["committed"] for s in steps) == returned
     # Counts a step carries: what its dispatch saw, what its pieces held.
     for s in steps:
-        assert set(s[5]) == {"lanes", "positions", "pieces",
+        assert set(s[5]) == {"lanes", "positions", "kv_blocks",
+                             "kv_table_blocks", "pieces",
                              "prefill_tokens", "committed", "queued"}
         assert 0 <= s[5]["lanes"] <= 2
+        # A dispatch reads a block or more of every slot, of the 2
+        # slots x 2 blocks (cache 32, block 16) their tables have; a
+        # step without one reads none.
+        if s[5]["lanes"]:
+            assert 2 <= s[5]["kv_blocks"] <= s[5]["kv_table_blocks"] == 4
+        else:
+            assert s[5]["kv_blocks"] == s[5]["kv_table_blocks"] == 0
     assert sum(s[5]["prefill_tokens"] for s in steps) == sum(
         len(p) for p, _ in reqs)
     # One dispatch a step that had lanes to run, and none otherwise.

@@ -246,6 +246,91 @@ class TestPagedAttention:
         ref = pk.paged_attention_reference(q, kp, vp, table, lengths)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
+    # The walk: ``_paged_fold`` table entries to a step, as far as a
+    # lane's length reaches.  bs 4 and an 11-block table give a fold of
+    # 11 (one step), which hides the step edges; the cases pin a fold
+    # of 4, which 11 does not divide.
+    BS, N_BLK, FOLD = 4, 11, 4
+
+    @classmethod
+    def _walk_case(cls, q_len, heads, kvh, int8, seed=0):
+        """One lane at every length where the walk's count of blocks or
+        of steps changes, and one that overran its table."""
+        bs, n_blk, fold = cls.BS, cls.N_BLK, cls.FOLD
+        lengths = [0, 1, bs - 1, bs, fold * bs - 1, fold * bs,
+                   fold * bs + 1, n_blk * bs - q_len, n_blk * bs + 3]
+        lanes, nb, hd = len(lengths), 1 + len(lengths) * n_blk, 8
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.normal(size=(lanes, q_len, heads, hd)),
+                        jnp.float32)
+        # Every lane owns its blocks (1 + lane * n_blk ...), so a block
+        # a lane's length does not reach belongs to no one else either.
+        table = jnp.asarray(
+            1 + np.arange(lanes * n_blk).reshape(lanes, n_blk), jnp.int32)
+        shape = (nb, bs, kvh, hd)
+        scales = {}
+        if int8:
+            kp, vp = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                      for _ in range(2))
+            scales = {
+                name: jnp.asarray(np.abs(rng.normal(size=shape[:3])) / 127
+                                  + 1e-3, jnp.float32)
+                for name in ("k_scales", "v_scales")}
+        else:
+            kp, vp = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                      for _ in range(2))
+        return q, kp, vp, table, jnp.asarray(lengths, jnp.int32), scales
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("heads,kvh", [(28, 4), (4, 4)],
+                             ids=["gqa28-4", "mha"])
+    @pytest.mark.parametrize("q_len", [1, 4])
+    def test_ragged_walk_matches_oracle(self, q_len, heads, kvh, int8,
+                                        monkeypatch):
+        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        q, kp, vp, table, lengths, scales = self._walk_case(
+            q_len, heads, kvh, int8)
+        if not int8:    # the cell's storage: bf16 rows, f32 arithmetic
+            q, kp, vp = (a.astype(jnp.bfloat16) for a in (q, kp, vp))
+        ref = pk.paged_attention_reference(
+            q.astype(jnp.float32), kp, vp, table, lengths, **scales)
+        out = pk.paged_attention(q, kp, vp, table, lengths, **scales,
+                                 use_pallas=True, interpret=True)
+        assert out.dtype == q.dtype
+        tol = 1e-5 if int8 else 1e-2       # bf16 rounds the output
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref), rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+    @pytest.mark.parametrize("q_len", [1, 4])
+    def test_walk_stops_at_the_lanes_length(self, q_len, int8,
+                                            monkeypatch):
+        """Every block a lane's length does not reach is poisoned (NaN
+        rows, or NaN scales under int8 rows) and the output does not
+        move: the walk reads ``paged_blocks_walked`` blocks of a lane's
+        table and nothing behind them."""
+        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        q, kp, vp, table, lengths, scales = self._walk_case(
+            q_len, 4, 2, int8, seed=5)
+        clean = pk.paged_attention(q, kp, vp, table, lengths, **scales,
+                                   use_pallas=True, interpret=True)
+        reach = np.asarray(pk.paged_blocks_walked(
+            np.asarray(lengths), q_len, self.BS, self.N_BLK))
+        assert reach.tolist() == [
+            min(max(-(-(int(n) + q_len) // self.BS), 1), self.N_BLK)
+            for n in lengths]
+        dead = np.concatenate([[0]] + [
+            np.asarray(table[lane, n:]) for lane, n in enumerate(reach)])
+        assert 0 < len(dead) < kp.shape[0]
+        if int8:
+            scales = {k: v.at[dead].set(jnp.nan) for k, v in scales.items()}
+        else:
+            kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
+        dirty = pk.paged_attention(q, kp, vp, table, lengths, **scales,
+                                   use_pallas=True, interpret=True)
+        assert np.all(np.isfinite(np.asarray(clean)))
+        np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+
 
 def test_fused_attn_kill_switches(monkeypatch):
     """TTD_NO_FUSED_ATTN wins over everything (the production kill

@@ -162,6 +162,48 @@ def test_paged_engine_smoke_matches_generate(params):
     eng._radix.check_invariants()
 
 
+def test_step_counts_the_blocks_the_kernel_walks(params):
+    """``engine/step``'s ``kv_blocks`` is the attention kernel's rule
+    (``ceil((length + queries) / block)`` blocks of a lane's table,
+    never none and never more than it has, and one for an idle slot)
+    over the host's own lane lengths: for a known set, and as the
+    engine records it while it serves."""
+    from tensorflow_train_distributed_tpu.runtime import events
+
+    eng = ServingEngine(CFG, params, slots=6, cache_len=32, chunk=2,
+                        prompt_buckets=(8,), kv_block_size=4)
+    assert eng.paged and eng._kv_nblk_lane == 8
+
+    def counted(held, spec_k=0):
+        eng._count_dispatch(held, spec_k)
+        return (eng._step_counts["kv_blocks"],
+                eng._step_counts["kv_table_blocks"])
+
+    #          blocks: 1  1  1  2  2   8
+    assert counted([0, 1, 3, 4, 7, 31]) == (15, 48)
+    assert counted([40, 3]) == (8 + 1 + 4, 48)    # overrun: its 8, no more
+    assert counted([0, 3, 4]) == (1 + 1 + 2 + 3, 48)      # 3 idle slots
+    assert counted([0, 1, 4], spec_k=3) == (1 + 2 + 2 + 3, 48)
+    assert counted([]) == (6, 48)
+    linear = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=2,
+                           prompt_buckets=(8,), paged=False)
+    linear._count_dispatch([5, 9], 0)
+    assert linear._step_counts["kv_blocks"] == 0
+    assert linear._step_counts["kv_table_blocks"] == 0
+
+    rec = events.get_recorder()
+    seq0 = rec.events_after(0)[0]
+    eng.submit([5, 6, 7, 8, 9], 6)
+    eng.run()
+    steps = [e[5] for e in rec.events_after(seq0)[1]
+             if e[0] == "engine/step" and e[5]["lanes"]]
+    assert steps
+    for attrs in steps:     # one lane of `positions` rows, five idle
+        assert attrs["kv_table_blocks"] == 48
+        assert attrs["kv_blocks"] == 5 + -(-(attrs["positions"] + 1) // 4)
+    assert "kv_blocks" in events.contract_attrs("engine/step")
+
+
 def test_fused_kill_switch_bitwise_and_attrs(params, monkeypatch):
     """Fast-tier canary for the fused paged-attention plumbing: on CPU
     the fused kernel never engages (``fused_attn()`` False), so the

@@ -187,7 +187,72 @@ def test_kernel_partitions_over_a_2x2_mesh(case, v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_decode_program_carries_its_scope_names(v5e, monkeypatch):
+@pytest.fixture(scope="module")
+def decode_program(v5e):
+    """The benchmark's decode program (``qwen25-7b-1chip``: 32 lanes,
+    256 blocks of 16 a lane, 28/4 heads of 128, 12 layers), compiled
+    once for the described chip."""
+    import dataclasses
+    import json
+    import os
+
+    import flax.linen as nn
+
+    from tensorflow_train_distributed_tpu.models import llama
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    monkeypatch = pytest.MonkeyPatch()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(repo, "benchmark", "configs",
+                               "qwen25-7b-1chip.json")) as f:
+            cfg_file = json.load(f)
+        cfg = dataclasses.replace(
+            llama.LLAMA_PRESETS[cfg_file["program"]["preset"]],
+            **cfg_file["program"]["replace"])
+        one_chip = SingleDeviceSharding(v5e[0])
+
+        def described(tree, dtype=None):
+            return jax.tree.map(
+                lambda s: jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
+                                               sharding=one_chip), tree)
+
+        params = described(nn.meta.unbox(jax.eval_shape(
+            lambda: llama.LlamaModel(cfg).init(
+                jax.random.key(0),
+                jnp.zeros((1, 8), jnp.int32))))["params"], BF16)
+        kw = {k: v for k, v in cfg_file["engine"].items()
+              if k != "max_queue"}
+        eng = ServingEngine(cfg, params, cast_params=False, **kw)
+        assert eng.fused_attn
+        assert (eng.slots, eng._kv_nblk_lane, eng.kv_block_size) == (
+            32, 256, 16)
+        cache = described(eng._cache_struct(eng.slots, grid=True))
+        lanes = {d: jax.ShapeDtypeStruct((eng.slots,), d, sharding=one_chip)
+                 for d in (jnp.int32, jnp.uint32)}
+        program = ServingEngine._decode_chunk
+        while not hasattr(program, "lower"):   # past the compile sanitizer
+            program = program.__wrapped__
+        return program.lower(eng, eng._variables, cache, lanes[jnp.int32],
+                             lanes[jnp.uint32],
+                             lanes[jnp.int32]).compile()
+    finally:
+        monkeypatch.undo()
+
+
+def _kernels(text):
+    """Names of a compiled program's Mosaic kernels.  A device event is
+    named by its instruction, without the metadata: the name has to say
+    which kernel it is."""
+    import re
+
+    return re.findall(
+        r'^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*'
+        r'custom_call_target="tpu_custom_call"', text, re.M)
+
+
+def test_decode_program_carries_its_scope_names(decode_program):
     """The benchmark's decode program, at its real width, compiled for
     the described chip: every region of the device-scope contract is in
     the operations' metadata (``benchmark/harness/scopes.py`` reads a
@@ -195,56 +260,31 @@ def test_decode_program_carries_its_scope_names(v5e, monkeypatch):
     ``tpu_custom_call`` that the method it is called from names
     (``paged_attn_roofline.decode`` finds its events so: the kernel
     takes no ``name=`` of its own, which would replace that name)."""
-    import dataclasses
-    import json
-    import os
     import re
 
-    import flax.linen as nn
-
-    from tensorflow_train_distributed_tpu.models import llama
-    from tensorflow_train_distributed_tpu.serving import ServingEngine
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "benchmark", "configs",
-                           "qwen25-7b-1chip.json")) as f:
-        cfg_file = json.load(f)
-    cfg = dataclasses.replace(
-        llama.LLAMA_PRESETS[cfg_file["program"]["preset"]],
-        **cfg_file["program"]["replace"])
-    one_chip = SingleDeviceSharding(v5e[0])
-
-    def described(tree, dtype=None):
-        return jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, dtype or s.dtype,
-                                           sharding=one_chip), tree)
-
-    params = described(nn.meta.unbox(jax.eval_shape(
-        lambda: llama.LlamaModel(cfg).init(
-            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))["params"],
-        BF16)
-    kw = {k: v for k, v in cfg_file["engine"].items() if k != "max_queue"}
-    eng = ServingEngine(cfg, params, cast_params=False, **kw)
-    assert eng.fused_attn
-    cache = described(eng._cache_struct(eng.slots, grid=True))
-    lanes = {d: jax.ShapeDtypeStruct((eng.slots,), d, sharding=one_chip)
-             for d in (jnp.int32, jnp.uint32)}
-    program = ServingEngine._decode_chunk
-    while not hasattr(program, "lower"):     # past the compile sanitizer
-        program = program.__wrapped__
-    text = program.lower(eng, eng._variables, cache, lanes[jnp.int32],
-                         lanes[jnp.uint32],
-                         lanes[jnp.int32]).compile().as_text()
-
+    text = decode_program.as_text()
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("embed", "norm", "attn/qkv", "attn/out", "mlp", "head",
                   "sample", "kv_pool/write"):
         assert any(f"/{scope}/" in name for name in op_names), scope
-    # A device event is named by its instruction, without the metadata:
-    # the name has to say which kernel it is.
-    kernels = re.findall(
-        r'^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*'
-        r'custom_call_target="tpu_custom_call"', text, re.M)
+    kernels = _kernels(text)
     assert any("_paged_decode_step" in name for name in kernels), kernels
     assert any(name.startswith("rms_norm_fwd") for name in kernels)
+
+
+def test_decode_program_holds_one_attention_kernel_and_no_more_memory(
+        decode_program):
+    """The length-bounded walk is ONE ``tpu_custom_call`` in the layer
+    scan's body, named by the method that calls it (a second one would
+    be counted into ``paged_attn_roofline.decode``'s mean time a call),
+    and its buffers cost the program nothing: the temporaries were 4.37
+    GiB beside 10.24 GiB of arguments before it (compile, PR 24), and
+    12 layers fit a 15.75 GiB chip with 1.1 GiB to spare."""
+    paged = [k for k in _kernels(decode_program.as_text())
+             if "_paged_decode_step" in k]
+    assert len(paged) == 1, paged
+    mem = decode_program.memory_analysis()
+    gib = 1 << 30
+    assert mem.temp_size_in_bytes <= 4.38 * gib, mem.temp_size_in_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            <= 14.65 * gib), mem

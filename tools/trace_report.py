@@ -111,17 +111,27 @@ def kv_cache_summary(evs: list) -> dict:
     (the ``decode/dispatch`` span's ``fused`` tag: the engine records
     at each dispatch whether its programs were compiled with
     ``ops.pallas_kernels.paged_attention`` or the XLA block-gather
-    A/B leg).  Empty dict when the window has no paged-KV events
-    (linear cache)."""
+    A/B leg), and the blocks that kernel's walk read over the window's
+    dispatches against the whole block table (``engine/step``'s
+    ``kv_blocks`` and ``kv_table_blocks``: the live share).  Empty dict
+    when the window has no paged-KV events (linear cache)."""
     out = {"prefix_hits": 0, "prefix_hit_tokens": 0,
            "evicted_blocks": 0, "refused_admissions": 0,
-           "fused_attn_dispatches": 0}
+           "fused_attn_dispatches": 0, "kv_blocks": 0,
+           "kv_table_blocks": 0}
     seen = False
     for e in evs:
         name = e.get("name", "")
         args = e.get("args") or {}
         if name == "decode/dispatch" and args.get("fused"):
             out["fused_attn_dispatches"] += 1
+            seen = True
+            continue
+        if name == "engine/step" and args.get("kv_table_blocks"):
+            # What the attention kernel's walk read at the step's
+            # dispatch, of the slots x blocks-a-lane table it spans.
+            out["kv_blocks"] += args.get("kv_blocks", 0)
+            out["kv_table_blocks"] += args["kv_table_blocks"]
             seen = True
             continue
         if not name.startswith("kv/"):
@@ -771,6 +781,11 @@ def main(argv=None) -> int:
         print(f"  fused-attn dispatches {kv['fused_attn_dispatches']}"
               f"  (decode chunks through ops.pallas_kernels."
               f"paged_attention)")
+        if kv["kv_table_blocks"]:
+            print(f"  kv blocks walked   {kv['kv_blocks']} of "
+                  f"{kv['kv_table_blocks']} in the slots x blocks "
+                  f"table: live share "
+                  f"{100.0 * kv['kv_blocks'] / kv['kv_table_blocks']:.1f}%")
 
     spec = spec_depth_summary(evs)
     if spec:
