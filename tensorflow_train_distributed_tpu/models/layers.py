@@ -191,6 +191,20 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, epsilon=self.epsilon).astype(self.dtype)
 
 
+def fused_paged_ok() -> bool:
+    """Whether a paged decode step should run its fused kernel: the
+    env/backend decision (``use_fused_paged_attention``), vetoed under
+    any >1-way ambient mesh — sharded serving keeps the XLA gather path
+    so GSPMD can partition it (the hand kernels are single-device)."""
+    from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
+
+    mesh = compat.get_abstract_mesh()
+    if (mesh is not None and not mesh.empty
+            and any(v > 1 for v in mesh.shape.values())):
+        return False
+    return pk.use_fused_paged_attention()
+
+
 class MultiHeadAttention(nn.Module):
     """MHA/GQA over the shared attention kernel.
 
@@ -848,19 +862,7 @@ class MultiHeadAttention(nn.Module):
                                   q_len, x.shape[-1])
 
     def _fused_paged_ok(self) -> bool:
-        """Whether this paged decode should run the fused kernel: the
-        env/backend decision (``use_fused_paged_attention``), vetoed
-        under any >1-way ambient mesh — sharded serving keeps the XLA
-        gather path so GSPMD can partition it (the hand kernel is
-        single-device)."""
-        from tensorflow_train_distributed_tpu.ops import pallas_kernels \
-            as pk
-
-        mesh = compat.get_abstract_mesh()
-        if (mesh is not None and not mesh.empty
-                and any(v > 1 for v in mesh.shape.values())):
-            return False
-        return pk.use_fused_paged_attention()
+        return fused_paged_ok()
 
     def _cache_attend(self, q, kc, vc, mask, kv_heads, b, q_len, features):
         """Masked einsum attention of q over the cache buffers."""
@@ -973,6 +975,293 @@ class MultiHeadAttention(nn.Module):
             cache_v.value = jnp.roll(vcat[:, -w:], end, axis=1)
         return self._cache_attend(q, kcat, vcat, keep[None, None],
                                   kv_heads, b, q_len, x.shape[-1])
+
+
+def _pad_last(x, width: int):
+    """``x`` with zeros appended to its last axis up to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                   + [(0, width - x.shape[-1])])
+
+
+class KernelParam(nn.Module):
+    """One ``kernel`` parameter read as an array instead of through
+    ``nn.Dense``: a kernel used in more than one contraction (the latent
+    attention's ``kv_b``, whose halves are absorbed into the query and
+    the output at decode) or stacked over experts (``models.moe``).
+    ``batch_axis`` are leading axes the initializer leaves out of its
+    fan-in (the expert axis of a stacked kernel)."""
+
+    shape: tuple
+    logical_axes: tuple
+    batch_axis: tuple = ()
+
+    @nn.compact
+    def __call__(self):
+        if self.has_variable("quant", "scale"):
+            # The int8 serving path rewrites nn.Dense call sites via a
+            # method interceptor (models/quant.py) — this raw-param read
+            # would cast int8 CODES to bf16 with no scale applied and
+            # produce garbage silently.
+            raise NotImplementedError(
+                "int8 weight-only serving is not wired for kernels read "
+                "as arrays (the gmm dispatch path's stacked experts, "
+                "latent attention's kv_b) — serve quantized MoE "
+                "checkpoints with dispatch='dense', or "
+                "dequantize_params() first")
+        return self.param(
+            "kernel",
+            nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(batch_axis=self.batch_axis),
+                self.logical_axes),
+            self.shape)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2; GLM-4.7-Flash).
+
+    Queries and keys/values go through low-rank latents::
+
+        c_q = norm(x Wq_a)              [q_lora_rank]
+        q   = c_q Wq_b  -> H heads of  q_nope [nope] ‖ q_rope [rope]
+        [c_kv ‖ k_r] = x Wkv_a          [kv_lora_rank ‖ rope]
+        [k_nope ‖ v] = norm(c_kv) Wkv_b -> H heads of [nope] ‖ [v_head]
+
+    with one rotary key ``k_r`` shared by every head, so a token's whole
+    attention state is ONE row ``[norm(c_kv) ‖ rope(k_r)]`` of
+    ``kv_lora_rank + rope`` values: that row is what the cache holds
+    (``latent_cache`` [B, C, row] linear, ``latent_pool`` [blocks, bs,
+    row] paged; tables and indices as ``MultiHeadAttention``'s).  A
+    stored row is as wide as the 128-lane tiles it lies in (576 values
+    in 640; device memory pads the minor dimension so whatever the
+    shape says, and a kernel's copy may only cut it at a tile), the
+    tail zero.
+
+    Two ways to attend, the same mathematics:
+
+    - **up-projected** (training forward, and every multi-token call on
+      the linear cache, so the engine's prefill pieces): keys and values
+      of all heads are made from the rows (``Wkv_b``) and attention is
+      the ordinary one at head size nope + rope.  Per cached row and
+      query this costs 2·H·(nope+rope+v) operations against the
+      absorbed form's 2·H·(2·rank+rope), so it is the cheaper one
+      wherever many queries share the up-projection;
+    - **absorbed** (the paged decode step): ``Wkv_b``'s key half is
+      folded into the query (``q_lat = q_nope · W_uk``), scores are
+      taken against the rows themselves (``q_lat·c_kv + q_rope·k_r``),
+      the probabilities average the rows, and the value half brings the
+      result back (``o = (P·c_kv) · W_uv``).  The rows are read once
+      and serve as key and as value, which is the point of the cache:
+      a decode step is bound by the bytes of the rows it reads.
+    """
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+    dtype: Dtype = jnp.float32
+    rope_base: float = 10000.0
+    rms_epsilon: float = 1e-5
+    decode: bool = False
+    cache_len: int = 0
+    slot_decode: bool = False
+    paged_kv_blocks: int = 0
+    kv_block_size: int = 0
+
+    @property
+    def row_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def row_store(self) -> int:
+        return -(-self.row_dim // 128) * 128
+
+    def _dense(self, features, axes, name):
+        return nn.Dense(
+            features, use_bias=False, dtype=self.dtype, name=name,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), axes))
+
+    @jax.named_scope("attn/q_latent")
+    def _queries(self, x, positions):
+        """(q_nope [B,S,H,nope], rope(q_rope) [B,S,H,rope])."""
+        c_q = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
+                      name="q_norm")(
+            self._dense(self.q_lora_rank, ("embed", None), "q_a")(x))
+        q = self._dense(
+            self.num_heads * (self.qk_nope_dim + self.qk_rope_dim),
+            (None, "heads"), "q_b")(c_q)
+        q = q.reshape(*x.shape[:-1], self.num_heads, -1)
+        q = nn.with_logical_constraint(
+            q, ("batch", "length", "heads", "kv"))
+        return (q[..., :self.qk_nope_dim],
+                apply_rope(q[..., self.qk_nope_dim:], positions,
+                           base=self.rope_base))
+
+    @jax.named_scope("attn/kv_latent")
+    def _rows(self, x, positions):
+        """This call's cache rows [B, S, row_store]."""
+        kv = self._dense(self.row_dim, ("embed", None), "kv_a")(x)
+        c_kv = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
+                       name="kv_norm")(kv[..., :self.kv_lora_rank])
+        k_r = apply_rope(kv[..., None, self.kv_lora_rank:], positions,
+                         base=self.rope_base)[..., 0, :]
+        return _pad_last(jnp.concatenate([c_kv, k_r], axis=-1),
+                         self.row_store)
+
+    def _kv_b(self):
+        """``Wkv_b`` as [rank, H, nope + v_head]."""
+        per_head = self.qk_nope_dim + self.v_head_dim
+        return KernelParam(
+            (self.kv_lora_rank, self.num_heads * per_head),
+            (None, "heads"), name="kv_b")().astype(self.dtype).reshape(
+                self.kv_lora_rank, self.num_heads, per_head)
+
+    @jax.named_scope("attn/kv_latent")
+    def _up_project(self, rows):
+        """(k, v) of every head from rows [B, T, row_store]."""
+        kv = jnp.einsum("btc,chd->bthd", rows[..., :self.kv_lora_rank],
+                        self._kv_b())
+        k_r = jnp.broadcast_to(
+            rows[..., None, self.kv_lora_rank:self.row_dim],
+            (*rows.shape[:2], self.num_heads, self.qk_rope_dim))
+        k = jnp.concatenate([kv[..., :self.qk_nope_dim], k_r], axis=-1)
+        return k, kv[..., self.qk_nope_dim:]
+
+    @jax.named_scope("attn/out")
+    def _out(self, o, features):
+        o = nn.with_logical_constraint(
+            o, ("batch", "length", "heads", "kv"))
+        y = self._dense(features, ("heads", "embed"), "out")(
+            o.reshape(*o.shape[:2], self.num_heads * self.v_head_dim))
+        return nn.with_logical_constraint(y, ("batch", "length", "embed"))
+
+    @nn.compact
+    def __call__(self, x, *, positions=None, segment_ids=None):
+        if self.decode:
+            if positions is not None or segment_ids is not None:
+                raise ValueError(
+                    "decode=True takes positions from the cache index; "
+                    "explicit positions and segment ids are not "
+                    "supported in decode mode")
+            if self.cache_len <= 0:
+                raise ValueError("decode=True needs cache_len > 0")
+            if self.paged_kv_blocks:
+                if not self.slot_decode:
+                    raise ValueError(
+                        "paged_kv_blocks requires slot_decode=True")
+                if self.paged_kv_blocks < 2 or self.kv_block_size < 1:
+                    raise ValueError(
+                        "the paged latent pool needs >= 2 blocks (block "
+                        "0 is scratch) of >= 1 rows, got "
+                        f"{self.paged_kv_blocks} x {self.kv_block_size}")
+                return self._paged_step(x)
+            return self._linear_step(x)
+        if self.slot_decode or self.paged_kv_blocks:
+            raise ValueError("slot_decode / paged_kv_blocks are KV-cache "
+                             "modes: they require decode=True")
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(x.shape[1]),
+                                         x.shape[:2])
+        q_nope, q_rope = self._queries(x, positions)
+        k, v = self._up_project(self._rows(x, positions))
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        o = multihead_attention_kernel(
+            *(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True,
+            segment_ids=segment_ids).transpose(0, 2, 1, 3)
+        return self._out(o, x.shape[-1])
+
+    def _linear_step(self, x):
+        """Append this call's rows to the linear cache and attend over
+        it, up-projected.  ``index`` is a scalar (``models.generate``)
+        or, under ``slot_decode``, one per batch row (the engine's
+        batch-1 prefill cache)."""
+        from tensorflow_train_distributed_tpu.ops.attention import (
+            dot_product_attention,
+        )
+
+        b, q_len, _ = x.shape
+        cache = self.variable(
+            "cache", "latent_cache", jnp.zeros,
+            (b, self.cache_len, self.row_store), self.dtype)
+        index = self.variable(
+            "cache", "index", lambda: jnp.zeros(
+                (b,) if self.slot_decode else (), jnp.int32))
+        cur = jnp.broadcast_to(index.value, (b,))
+        positions = cur[:, None] + jnp.arange(q_len)            # [B, q]
+        index.value = index.value + q_len
+        q_nope, q_rope = self._queries(x, positions)
+        rows = self._rows(x, positions)
+        with jax.named_scope("kv_pool/write"):
+            # Out-of-range positions are dropped: an overrun row goes
+            # inert, as in MultiHeadAttention._slot_decode_step.
+            cache.value = cache.value.at[
+                jnp.arange(b)[:, None], positions].set(
+                    rows.astype(cache.value.dtype), mode="drop")
+        k, v = self._up_project(cache.value)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        mask = (jnp.arange(self.cache_len)[None, None, :]
+                <= positions[:, :, None])                       # [B,q,C]
+        o = dot_product_attention(
+            *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+            mask=mask[:, None]).transpose(0, 2, 1, 3)
+        return self._out(o, x.shape[-1])
+
+    def _paged_step(self, x):
+        """Per-slot decode over the paged latent pool, absorbed: the
+        append-and-attend contract of
+        ``MultiHeadAttention._paged_decode_step`` (block table, scratch
+        block 0, dropped overrun rows, an empty lane told length 0)
+        with ONE pool of rows a layer."""
+        from tensorflow_train_distributed_tpu.ops import pallas_kernels \
+            as pk
+
+        b, q_len, _ = x.shape
+        bs, nb = self.kv_block_size, self.paged_kv_blocks
+        n_blk = -(-self.cache_len // bs)
+        rank = self.kv_lora_rank
+        pool = self.variable("cache", "latent_pool", jnp.zeros,
+                             (nb, bs, self.row_store), self.dtype)
+        table = self.variable(
+            "cache", "block_table", jnp.zeros, (b, n_blk), jnp.int32)
+        index = self.variable(
+            "cache", "index", lambda: jnp.zeros((b,), jnp.int32))
+        cur = index.value
+        positions = cur[:, None] + jnp.arange(q_len)            # [B, q]
+        index.value = cur + q_len
+        q_nope, q_rope = self._queries(x, positions)
+        rows = self._rows(x, positions)
+        with jax.named_scope("kv_pool/write"):
+            blk = jnp.clip(positions // bs, 0, n_blk - 1)
+            phys = jnp.take_along_axis(table.value, blk, axis=1)
+            dest = jnp.where(positions < n_blk * bs,
+                             phys * bs + positions % bs, nb * bs)
+            flat = pool.value.reshape(nb * bs, self.row_store).at[
+                dest.reshape(-1)].set(
+                    rows.reshape(-1, self.row_store).astype(
+                        pool.value.dtype), mode="drop")
+        pool.value = flat.reshape(nb, bs, self.row_store)
+
+        w = self._kv_b()
+        with jax.named_scope("attn/absorb"):
+            q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope,
+                               w[..., :self.qk_nope_dim])
+            q_cat = _pad_last(jnp.concatenate([q_lat, q_rope], axis=-1),
+                              self.row_store)
+        # An empty lane (table starts at the scratch block) holds
+        # nothing, whatever its index has grown to while it idled.
+        held = jnp.where(table.value[:, 0] == 0, 0, cur)
+        o_lat = pk.paged_latent_attention(
+            q_cat, pool.value, table.value, held, value_dim=rank,
+            scale=(self.qk_nope_dim + self.qk_rope_dim) ** -0.5,
+            cache_len=self.cache_len,
+            use_pallas=fused_paged_ok(),
+            interpret=pk.fused_attn_interpret())
+        with jax.named_scope("attn/absorb"):
+            o = jnp.einsum("bqhc,chd->bqhd", o_lat.astype(self.dtype),
+                           w[..., self.qk_nope_dim:])
+        return self._out(o, x.shape[-1])
 
 
 class MlpBlock(nn.Module):

@@ -95,6 +95,33 @@ class MoeConfig:
     # q/k/v projection biases (Qwen attention convention; out stays
     # unbiased) — layers.MultiHeadAttention.qkv_bias.
     qkv_bias: bool = False
+    # Leading dense layers (DeepSeek / GLM ``first_k_dense_replace``):
+    # the first ``dense_layers`` blocks carry a plain SwiGLU of width
+    # ``dense_ffn_size`` (None = ``ffn_size``) and ``moe_every`` counts
+    # from the first block after them.
+    dense_layers: int = 0
+    dense_ffn_size: Optional[int] = None
+    # How the router scores and picks.  "softmax": probabilities over
+    # the experts, top-k of them (Mixtral, Qwen-MoE).  "sigmoid"
+    # (DeepSeek-V3 / GLM ``topk_method: noaux_tc`` with one group): an
+    # independent sigmoid score an expert, the top-k of score + a
+    # learned correction ``bias`` (a parameter: it steers the CHOICE
+    # toward idle experts and never enters the gate), gates the chosen
+    # scores themselves, renormalized under ``norm_topk_prob``.  Either
+    # way the gates are multiplied by ``routed_scaling``.  The sigmoid
+    # router runs under dispatch="gmm" only.
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    # Multi-head latent attention (layers.LatentAttention) instead of
+    # MHA/GQA when ``kv_lora_rank`` is set: q/kv low-rank sizes, the
+    # per-head split of a query/key into a position-free and a rotary
+    # part, and the value head size.  ``num_kv_heads``/``qkv_bias`` are
+    # then unused.
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
 
 MOE_PRESETS = {
@@ -122,6 +149,28 @@ MOE_PRESETS = {
         rms_epsilon=1e-6,
         shared_expert_size=5632, shared_expert_gate=True,
         norm_topk_prob=False, qkv_bias=True),
+    # GLM-4.7-Flash (zai-org, ``glm4_moe_lite``) at its published
+    # widths: latent attention, one leading dense layer of its own
+    # width, 64 sigmoid-routed experts (top 4, gates x 1.8) beside one
+    # shared expert, dropless dispatch.  47 layers as published;
+    # deployments cut the depth to their chip (benchmark/configs).
+    "glm47_flash": MoeConfig(
+        vocab_size=154_880, d_model=2048, num_layers=47, num_heads=20,
+        num_kv_heads=None, ffn_size=1536, num_experts=64, top_k=4,
+        max_positions=202_752, rope_base=1_000_000.0, rms_epsilon=1e-5,
+        dispatch="gmm", shared_expert_size=1536, norm_topk_prob=True,
+        dense_layers=1, dense_ffn_size=10_240, router="sigmoid",
+        routed_scaling=1.8, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256),
+    # The same block at test size (float32, two expert layers).
+    "glm_lite_tiny": MoeConfig(
+        vocab_size=256, d_model=64, num_layers=3, num_heads=4,
+        num_kv_heads=None, ffn_size=48, num_experts=8, top_k=2,
+        max_positions=128, dtype=jnp.float32, remat=False,
+        dispatch="gmm", shared_expert_size=48, dense_layers=1,
+        dense_ffn_size=160, router="sigmoid", routed_scaling=1.8,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_dim=12, qk_rope_dim=8,
+        v_head_dim=16),
     # DeepSeek/Qwen-MoE-style: always-on shared expert beside the
     # routed ones (tiny test shape).
     "moe_tiny_shared": MoeConfig(vocab_size=256, d_model=64,
@@ -212,39 +261,6 @@ class _ExpertFfn(nn.Module):
                        dtype=self.dtype, name="wo")(h)
 
 
-class _StackedKernel(nn.Module):
-    """One expert-stacked ``[num_experts, ...]`` kernel parameter.
-
-    Exists to give the gmm dispatch path the SAME parameter tree as the
-    dense path's ``nn.vmap(_ExpertFfn)`` — ``experts/<name>/kernel``,
-    expert-stacked, logical axes ``("expert", ...)`` — so checkpoints
-    transfer freely between the two formulations.  ``batch_axis=(0,)``
-    keeps per-expert init statistics identical to the vmap'd per-expert
-    lecun_normal (without it the expert axis would inflate fan_in).
-    """
-
-    shape: tuple
-    logical_axes: tuple
-
-    @nn.compact
-    def __call__(self):
-        if self.has_variable("quant", "scale"):
-            # The int8 serving path rewrites nn.Dense call sites via a
-            # method interceptor (models/quant.py) — this raw-param read
-            # would cast int8 CODES to bf16 with no scale applied and
-            # produce garbage silently.
-            raise NotImplementedError(
-                "int8 weight-only serving is not wired for the gmm "
-                "dispatch path — serve quantized MoE checkpoints with "
-                "dispatch='dense', or dequantize_params() first")
-        return self.param(
-            "kernel",
-            nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(batch_axis=(0,)),
-                self.logical_axes),
-            self.shape)
-
-
 # Pallas interpret mode for the grouped matmul is a TEST seam, not a
 # fallback: the megablox kernel lowers only for TPU, so the CPU suite
 # (tests/conftest.py), the CPU dry run (__graft_entry__) and bench_moe's
@@ -268,8 +284,24 @@ def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):
     """
     from jax.experimental.pallas.ops.tpu.megablox import ops as _mb
 
+    # A grid step is one [128, tk] x [tk, tn] product of one expert's
+    # rows.  With a row tile or less an expert (serving: lanes x top_k
+    # rows of a decode step, or a prefill piece's, over all experts) a
+    # step is bound by its slice of the expert's kernel coming in, so
+    # the slices are as large as VMEM carries double buffered (2 MB in
+    # bf16) and the steps few: at 2048 x 1536 three a group, where
+    # 128 x 128 tiles make 192.  With more rows an expert (training)
+    # the tiles stay megablox's own: only the few-row shapes were
+    # measured (PERF.md, PR 26), and the backward's transposed products
+    # take the same tiling.
+    groups, k, n = rhs.shape
+    tiling = (128, 128, 128)
+    if lhs.shape[0] <= 128 * groups:
+        tiling = (128, min(-(-k // 128) * 128, 2048),
+                  min(-(-n // 128) * 128, 512))
     return _mb.gmm(lhs, rhs, group_sizes,
                    preferred_element_type=jnp.float32, interpret=interpret,
+                   tiling=tiling,
                    group_offset=None if group_offset is None
                    else jnp.asarray(group_offset, jnp.int32))
 
@@ -293,29 +325,32 @@ def _routed_ffn_rows(flat, top_e, gate_w, num_experts, wi_gate, wi_up,
     t, d = flat.shape
     top_k = top_e.shape[-1]
     e_total = num_experts
-    e_flat = top_e.reshape(-1)                          # [T*k] token-major
-    order = jnp.argsort(e_flat)                         # stable
-    xs = jnp.take(flat, order // top_k, axis=0).astype(dtype)
-    sizes = jnp.bincount(e_flat, length=e_total).astype(jnp.int32)
     m = t * top_k
-    m_pad = -(-m // 128) * 128                          # kernel row tile
-    if m_pad != m:
-        # Zero rows appended to the LAST expert's range: zero inputs
-        # produce zero outputs (silu(0)*0 = 0), then sliced off before
-        # the combine — never observable, under EP included (the last
-        # shard computes them as zeros; psum adds zeros).
-        xs = jnp.pad(xs, ((0, m_pad - m), (0, 0)))
-        sizes = sizes.at[e_total - 1].add(m_pad - m)
-    gate = _gmm(xs, wi_gate, sizes, interpret, group_offset)
-    up = _gmm(xs, wi_up, sizes, interpret, group_offset)
-    h = (nn.silu(gate) * up).astype(dtype)
-    out = _gmm(h, wo, sizes, interpret, group_offset)   # [m_pad, D] f32
+    with jax.named_scope("moe/sort"):
+        e_flat = top_e.reshape(-1)                      # [T*k] token-major
+        order = jnp.argsort(e_flat)                     # stable
+        xs = jnp.take(flat, order // top_k, axis=0).astype(dtype)
+        sizes = jnp.bincount(e_flat, length=e_total).astype(jnp.int32)
+        m_pad = -(-m // 128) * 128                      # kernel row tile
+        if m_pad != m:
+            # Zero rows appended to the LAST expert's range: zero inputs
+            # produce zero outputs (silu(0)*0 = 0), then sliced off
+            # before the combine — never observable, under EP included
+            # (the last shard computes them as zeros; psum adds zeros).
+            xs = jnp.pad(xs, ((0, m_pad - m), (0, 0)))
+            sizes = sizes.at[e_total - 1].add(m_pad - m)
+    with jax.named_scope("moe/experts"):
+        gate = _gmm(xs, wi_gate, sizes, interpret, group_offset)
+        up = _gmm(xs, wi_up, sizes, interpret, group_offset)
+        h = (nn.silu(gate) * up).astype(dtype)
+        out = _gmm(h, wo, sizes, interpret, group_offset)  # [m_pad, D] f32
     if psum_axis is not None:
         out = jax.lax.psum(out, psum_axis)
-    inv = jnp.zeros((m,), jnp.int32).at[order].set(
-        jnp.arange(m, dtype=jnp.int32))
-    y = jnp.take(out[:m], inv, axis=0).reshape(t, top_k, d)
-    return jnp.sum(y * gate_w[..., None], axis=1).astype(dtype)
+    with jax.named_scope("moe/combine"):
+        inv = jnp.zeros((m,), jnp.int32).at[order].set(
+            jnp.arange(m, dtype=jnp.int32))
+        y = jnp.take(out[:m], inv, axis=0).reshape(t, top_k, d)
+        return jnp.sum(y * gate_w[..., None], axis=1).astype(dtype)
 
 
 class _GmmExperts(nn.Module):
@@ -344,12 +379,16 @@ class _GmmExperts(nn.Module):
     def __call__(self, flat, top_e, gate_w, *, interpret, ep_mesh=None):
         d = flat.shape[-1]
         e, f = self.num_experts, self.hidden
-        wi_gate = _StackedKernel((e, d, f), ("expert", "embed", "mlp"),
-                                 name="wi_gate")().astype(self.dtype)
-        wi_up = _StackedKernel((e, d, f), ("expert", "embed", "mlp"),
-                               name="wi_up")().astype(self.dtype)
-        wo = _StackedKernel((e, f, d), ("expert", "mlp", "embed"),
-                            name="wo")().astype(self.dtype)
+        # The dense path's ``nn.vmap(_ExpertFfn)`` tree
+        # (``experts/<name>/kernel``, expert-stacked, per-expert init
+        # statistics), so checkpoints transfer between formulations.
+        def stacked(shape, axes, name):
+            return L.KernelParam(shape, axes, batch_axis=(0,),
+                                 name=name)().astype(self.dtype)
+
+        wi_gate = stacked((e, d, f), ("expert", "embed", "mlp"), "wi_gate")
+        wi_up = stacked((e, d, f), ("expert", "embed", "mlp"), "wi_up")
+        wo = stacked((e, f, d), ("expert", "mlp", "embed"), "wo")
         if ep_mesh is None:
             return _routed_ffn_rows(
                 flat, top_e, gate_w, e, wi_gate, wi_up, wo,
@@ -402,12 +441,26 @@ class MoEMlpBlock(nn.Module):
         groups, group_size, d_model = x.shape
 
         # Router in float32: small matmul, numerically load-bearing.
-        logits = L.dense(cfg.num_experts, ("embed", "expert"),
-                         use_bias=False, dtype=jnp.float32,
-                         name="router")(x.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)          # [G, S, E]
+        with jax.named_scope("moe/router"):
+            logits = L.dense(cfg.num_experts, ("embed", "expert"),
+                             use_bias=False, dtype=jnp.float32,
+                             name="router")(x.astype(jnp.float32))
+            if cfg.router == "sigmoid":
+                probs = jax.nn.sigmoid(logits)           # [G, S, E] scores
+            elif cfg.router == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)  # [G, S, E]
+            else:
+                raise ValueError(
+                    f"unknown MoeConfig.router {cfg.router!r} "
+                    "(expected 'softmax' or 'sigmoid')")
         if cfg.dispatch == "gmm":
             return self._add_shared(x, self._gmm_moe(x, logits, probs))
+        if cfg.router != "softmax" or cfg.routed_scaling != 1.0:
+            raise ValueError(
+                "router='sigmoid' (a correction bias in the choice) and "
+                "routed_scaling run under dispatch='gmm' only; the "
+                "capacity-bounded dense dispatch routes by softmax "
+                "probabilities alone")
         if cfg.dispatch != "dense":
             raise ValueError(
                 f"unknown MoeConfig.dispatch {cfg.dispatch!r} "
@@ -470,10 +523,11 @@ class MoEMlpBlock(nn.Module):
         cfg = self.config
         if not cfg.shared_expert_size:
             return routed
-        shared = L.MlpBlock(hidden=cfg.shared_expert_size,
-                            dtype=cfg.dtype, gated=True,
-                            activation=nn.silu,  # SwiGLU, like every
-                            name="shared_mlp")(x)   # gated FFN here
+        with jax.named_scope("moe/shared"):
+            shared = L.MlpBlock(hidden=cfg.shared_expert_size,
+                                dtype=cfg.dtype, gated=True,
+                                activation=nn.silu,  # SwiGLU, like every
+                                name="shared_mlp")(x)   # gated FFN here
         if cfg.shared_expert_gate:
             # Qwen-MoE: one sigmoid scalar per token scales the shared
             # branch (f32 like the router — small and load-bearing).
@@ -500,16 +554,46 @@ class MoEMlpBlock(nn.Module):
         k = cfg.top_k
         flat = x.reshape(n_tokens, d_model)
         p2 = probs.reshape(n_tokens, cfg.num_experts)
-        top_p, top_e = jax.lax.top_k(p2, k)              # [T, k]
-        # GShard top-k gate rule: normalize over the chosen experts.
-        # (The dense path normalizes over *kept* gates — identical here
-        # because nothing is ever dropped.)  Computed ONCE; under EP it
-        # rides into the shard_map instead of re-running per shard.
-        if cfg.norm_topk_prob:
-            gate_w = top_p / jnp.maximum(
-                jnp.sum(top_p, axis=-1, keepdims=True), 1e-9)
-        else:
-            gate_w = top_p    # raw softmax gates (Qwen2-MoE rule)
+        with jax.named_scope("moe/router"):
+            if cfg.router == "sigmoid":
+                # The correction bias takes part in the choice and not
+                # in the gate; the gates are the chosen scores over
+                # their sum (the source's own epsilon).
+                bias = self.param(
+                    "bias", nn.with_logical_partitioning(
+                        nn.initializers.zeros, ("expert",)),
+                    (cfg.num_experts,), jnp.float32)
+                _, top_e = jax.lax.top_k(
+                    p2 + bias.astype(jnp.float32), k)
+                top_p = jnp.take_along_axis(p2, top_e, axis=-1)
+                eps = 1e-20
+                # Load-balance mass below: the scores as a distribution.
+                p2 = p2 / jnp.sum(p2, axis=-1, keepdims=True)
+            else:
+                top_p, top_e = jax.lax.top_k(p2, k)      # [T, k]
+                eps = 1e-9
+            # GShard top-k gate rule: normalize over the chosen experts.
+            # (The dense path normalizes over *kept* gates — identical
+            # here because nothing is ever dropped.)  Computed ONCE;
+            # under EP it rides into the shard_map instead of re-running
+            # per shard.
+            if cfg.norm_topk_prob and cfg.router == "sigmoid":
+                gate_w = top_p / (jnp.sum(top_p, axis=-1, keepdims=True)
+                                  + eps)
+            elif cfg.norm_topk_prob:
+                gate_w = top_p / jnp.maximum(
+                    jnp.sum(top_p, axis=-1, keepdims=True), eps)
+            else:
+                gate_w = top_p    # raw gates (Qwen2-MoE rule)
+            if cfg.routed_scaling != 1.0:
+                gate_w = gate_w * cfg.routed_scaling
+        # Rows each expert takes in this call, every lane counted (an
+        # idle serving lane's rows read expert weights like any
+        # other): the serving engine's ``experts_hit`` (sown; kept only
+        # where the caller makes ``moe_stats`` mutable).
+        self.sow("moe_stats", "expert_rows",
+                 jnp.bincount(top_e.reshape(-1),
+                              length=cfg.num_experts).astype(jnp.int32))
 
         # Aux losses — same definitions as the dense path, with
         # routed = all top-k assignments (dropless).
@@ -574,7 +658,36 @@ class MoeDecoderBlock(nn.Module):
         cfg = self.config
         h = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       name="attn_norm")(x)
-        x = x + L.MultiHeadAttention(
+        if cfg.kv_lora_rank:
+            attn = L.LatentAttention(
+                num_heads=cfg.num_heads, q_lora_rank=cfg.q_lora_rank,
+                kv_lora_rank=cfg.kv_lora_rank,
+                qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
+                v_head_dim=cfg.v_head_dim, dtype=cfg.dtype,
+                rope_base=cfg.rope_base, rms_epsilon=cfg.rms_epsilon,
+                name="attention", decode=self.decode,
+                cache_len=self.cache_len or cfg.max_positions,
+                slot_decode=self.slot_decode,
+                paged_kv_blocks=self.paged_kv_blocks,
+                kv_block_size=self.kv_block_size,
+            )(h, segment_ids=segment_ids, positions=positions)
+        else:
+            attn = self._mha(h, segment_ids, positions)
+        x = x + attn
+        h = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
+                      name="mlp_norm")(x)
+        if self.use_moe:
+            x = x + MoEMlpBlock(cfg, name="moe")(h)
+        else:
+            x = x + L.MlpBlock(hidden=cfg.dense_ffn_size or cfg.ffn_size,
+                               dtype=cfg.dtype,
+                               activation=nn.silu, gated=True,
+                               name="mlp")(h)
+        return x
+
+    def _mha(self, h, segment_ids, positions):
+        cfg = self.config
+        return L.MultiHeadAttention(
             qkv_bias=cfg.qkv_bias,
             num_heads=cfg.num_heads,
             head_dim=cfg.d_model // cfg.num_heads,
@@ -587,19 +700,11 @@ class MoeDecoderBlock(nn.Module):
             paged_kv_blocks=self.paged_kv_blocks,
             kv_block_size=self.kv_block_size,
         )(h, segment_ids=segment_ids, positions=positions)
-        h = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
-                      name="mlp_norm")(x)
-        if self.use_moe:
-            x = x + MoEMlpBlock(cfg, name="moe")(h)
-        else:
-            x = x + L.MlpBlock(hidden=cfg.ffn_size, dtype=cfg.dtype,
-                               activation=nn.silu, gated=True,
-                               name="mlp")(h)
-        return x
 
 
 class MoeLmModel(nn.Module):
-    """Decoder LM with MoE FFNs every ``moe_every``-th layer.
+    """Decoder LM with MoE FFNs every ``moe_every``-th layer after
+    ``dense_layers`` leading dense ones.
 
     Layers are a Python loop (not depth-scan): MoE layers interleave with
     dense ones, so blocks are not homogeneous when ``moe_every > 1``.
@@ -652,7 +757,9 @@ class MoeLmModel(nn.Module):
                 # No backward in decode, and KV-cache writes must not
                 # replay under a checkpoint.
                 blk = nn.remat(blk, prevent_cse=False)
-            x = blk(cfg, use_moe=(i % cfg.moe_every == 0),
+            x = blk(cfg, use_moe=(
+                        i >= cfg.dense_layers
+                        and (i - cfg.dense_layers) % cfg.moe_every == 0),
                     decode=self.decode, cache_len=self.cache_len,
                     slot_decode=self.slot_decode,
                     paged_kv_blocks=self.paged_kv_blocks,
@@ -660,8 +767,10 @@ class MoeLmModel(nn.Module):
                     name=f"layer_{i}")(x, segment_ids, positions)
         x = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       name="final_norm")(x)
-        logits = L.dense(cfg.vocab_size, ("embed", "vocab"), use_bias=False,
-                         dtype=cfg.dtype, name="lm_head")(x)
+        with jax.named_scope("head"):
+            logits = L.dense(cfg.vocab_size, ("embed", "vocab"),
+                             use_bias=False, dtype=cfg.dtype,
+                             name="lm_head")(x)
         return nn.with_logical_constraint(
             logits, ("batch", "length", "vocab"))
 

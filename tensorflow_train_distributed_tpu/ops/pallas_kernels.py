@@ -478,6 +478,150 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
 
 
 # ---------------------------------------------------------------------------
+# Paged latent attention (models.layers.LatentAttention paged decode)
+# ---------------------------------------------------------------------------
+
+
+def paged_latent_attention_reference(q, pool, table, lengths, *,
+                                     value_dim: int, scale: float,
+                                     cache_len: Optional[int] = None):
+    """Pure-jax oracle (and the CPU path) of ``paged_latent_attention``:
+    gather each lane's rows, attend.  ``q`` [lanes, q_len, heads, row]
+    (absorbed queries, RoPE applied); ``pool`` [num_blocks, block_size,
+    row], every head's key; its leading ``value_dim`` columns are every
+    head's value.  Returns [lanes, q_len, heads, value_dim]."""
+    nb, bs, row = pool.shape
+    lanes, q_len = q.shape[:2]
+    c = cache_len if cache_len is not None else table.shape[1] * bs
+    rows = jnp.take(pool, table, axis=0).reshape(lanes, -1, row)[:, :c]
+    logits = jnp.einsum("bqhr,bkr->bhqk", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    positions = lengths[:, None] + jnp.arange(q_len)        # [B, q]
+    mask = jnp.arange(c)[None, None, :] <= positions[:, :, None]
+    logits = jnp.where(mask[:, None], logits, _NEG)
+    p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkc->bqhc", p, rows[..., :value_dim])
+
+
+def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
+                         sem, m_ref, l_ref, acc_ref, *, bs, fold,
+                         last_row, q_len, value_dim, scale):
+    """``_paged_attn_kernel``'s walk over ONE pool: grid (lane,), the
+    lane's own blocks (``paged_blocks_walked``) ``fold`` to a
+    double-buffered copy-and-fold step.  A row is the key of every head
+    and, in its leading ``value_dim`` columns, the value of every head,
+    so it is copied once and the query rows [heads*q_len, row] meet it
+    in two products.  The products take the pool's own type (bf16 on
+    the chip) and accumulate in float32; max, sum and softmax are
+    float32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)
+    cur = len_ref[i]
+    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1)
+    steps = pl.cdiv(live, fold)
+
+    def copies(step, slot, wait=False):
+        # Past the lane's count a step repeats the lane's last live
+        # block: finite rows the mask drops; no block the lane does not
+        # hold is read.  A wait needs only shapes.
+        return [pltpu.make_async_copy(
+            pool_hbm.at[0 if wait else tbl_ref[
+                i, jnp.minimum(step * fold + p, live - 1)]],
+            buf.at[slot, p], sem.at[slot]) for p in range(fold)]
+
+    m_ref[:] = jnp.full_like(m_ref, _NEG)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    for c in copies(0, 0):
+        c.start()
+    q = q_ref[0]                             # [heads*q_len, row]
+    r, n = q.shape[0], fold * bs
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, n), 1)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (r, n), 0) % q_len
+    last_seen = jnp.minimum(cur + qi, last_row)
+
+    def fold_step(step, _):
+        slot = jax.lax.rem(step, 2)
+
+        @pl.when(step + 1 < steps)
+        def _():
+            for c in copies(step + 1, 1 - slot):
+                c.start()
+
+        for c in copies(step, slot, wait=True):
+            c.wait()
+        rows = buf[slot].reshape(n, buf.shape[-1])
+        logits = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale          # [r, n]
+        logits = jnp.where(step * n + col <= last_seen, logits, _NEG)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev,
+                            jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(logits - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    jax.lax.fori_loop(0, steps, fold_step, None)
+    o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+
+def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
+                           scale: float, cache_len: Optional[int] = None,
+                           use_pallas: Optional[bool] = None,
+                           interpret: bool = False):
+    """Absorbed latent-attention decode directly through the block
+    table.  Arguments as ``paged_latent_attention_reference``.  One grid
+    step a lane, which reads the blocks its length reaches
+    (``paged_blocks_walked``) and no others, each row once: HBM reads
+    are ``blocks x block_size x row`` values a call."""
+    if not _use_pallas(use_pallas) and not interpret:
+        return paged_latent_attention_reference(
+            q, pool, table, lengths, value_dim=value_dim, scale=scale,
+            cache_len=cache_len)
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, bs, row = pool.shape
+    lanes, q_len, heads, _ = q.shape
+    n_blk = table.shape[1]
+    fold = _paged_fold(bs, n_blk)
+    last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
+    r = heads * q_len
+    qt = q.transpose(0, 2, 1, 3).reshape(lanes, r, row).astype(pool.dtype)
+
+    def lane_rows(width):
+        return pl.BlockSpec((1, r, width), lambda i, tbl, lens: (i, 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_latent_kernel, bs=bs, fold=fold, last_row=last_row,
+            q_len=q_len, value_dim=value_dim, scale=scale),
+        name="paged_latent_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(lanes,),
+            in_specs=[lane_rows(row), pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=lane_rows(value_dim),
+            scratch_shapes=[
+                pltpu.VMEM((2, fold, bs, row), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((r, 1), jnp.float32),
+                pltpu.VMEM((r, 1), jnp.float32),
+                pltpu.VMEM((r, value_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, r, value_dim), q.dtype),
+        interpret=interpret,
+    )(table, lengths.astype(jnp.int32), qt, pool)
+    return out.reshape(lanes, heads, q_len, value_dim).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
 # RMSNorm
 # ---------------------------------------------------------------------------
 

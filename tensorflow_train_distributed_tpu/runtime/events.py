@@ -101,9 +101,13 @@ CONTRACT = {
     # positions they held, the pool blocks those reach over all slots
     # (what the fused attention kernel reads) of the blocks the slots'
     # tables have, prefill pieces run and their prompt tokens, tokens
-    # handed to requests, the engine queue's depth at exit
+    # handed to requests, the engine queue's depth at exit; with routed
+    # experts, of the decode chunk harvested in the step, the experts
+    # that took a row and the rows' coefficient of variation over the
+    # experts (means over the chunk's steps and expert layers)
     "engine/step": ("lanes positions kv_blocks kv_table_blocks pieces "
-                    "prefill_tokens committed queued"),
+                    "prefill_tokens committed queued experts_hit "
+                    "expert_load_cv"),
     "decode/dispatch": "fused spec_k",
     "decode/wait": "overlapped",
     "decode/harvest": "overlapped",

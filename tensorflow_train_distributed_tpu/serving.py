@@ -260,13 +260,31 @@ def _paged_killed() -> bool:
     return os.environ.get("TTD_NO_PAGED_KV", "0") not in ("", "0")
 
 
+#: Row-holding cache leaves: the paged pool's name -> (the batch-1
+#: linear cache's name, dims a row has).  A linear leaf is
+#: [..., B, C, *row], a pool leaf [..., blocks, block_size, *row]
+#: (leading axes: a layer axis under scan_layers; the int8 scales' 2).
+#: Per-head K and V rows are [kv_heads, head_dim], their int8 scales
+#: [kv_heads], a latent-attention row [row] (layers.LatentAttention).
+_ROW_LEAVES = {"key_pool": ("key_cache", 2),
+               "value_pool": ("value_cache", 2),
+               "kv_pool_scales": ("kv_scales", 1),
+               "latent_pool": ("latent_cache", 1)}
+_LINEAR_ROW_DIMS = {lin: dims for lin, dims in _ROW_LEAVES.values()}
+_POOL_OF = {lin: pool for pool, (lin, _) in _ROW_LEAVES.items()}
+
+
 #: What an ``engine/step`` span says its step did: lanes active at the
 #: dispatch, the cached positions they held (as the host knew them),
 #: the blocks of the paged pool those reach over all slots (what the
 #: fused attention kernel reads) and the block table's whole size
 #: (slots x blocks a lane, what it read before it followed lengths),
 #: prefill pieces run and the prompt tokens they carried, output tokens
-#: handed to requests.
+#: handed to requests.  A step that harvests a decode chunk of a model
+#: with routed experts adds, as means over the chunk's steps and expert
+#: layers, the experts that took at least one row (``experts_hit``:
+#: what the grouped matmuls read) and the rows' coefficient of
+#: variation over the experts (``expert_load_cv``): ``_count_experts``.
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
                 "pieces", "prefill_tokens", "committed")
 
@@ -739,8 +757,7 @@ class ServingEngine:
                     * jnp.dtype(leaf.dtype).itemsize
                     for p, leaf in
                     jax.tree_util.tree_flatten_with_path(struct)[0]
-                    if getattr(p[-1], "key", "") in
-                    ("key_pool", "value_pool", "kv_pool_scales"))
+                    if getattr(p[-1], "key", "") in _ROW_LEAVES)
 
             self._kv_pool_bytes = _pool_bytes(
                 self._cache_struct(self.slots, grid=True))
@@ -986,17 +1003,16 @@ class ServingEngine:
     def _insert(self, cache_b, cache_1, slot, true_len):
         """Copy a prefilled request's cache rows into ``slot`` and pin
         the slot's per-slot index to the TRUE prompt length.  Leaves are
-        [..., B, C, kv_heads, head_dim] (a leading layer axis under
-        scan_layers), the index [..., B], and — int8 configs — the
-        kv_scales [..., 2, B, C, kv_heads] (batch axis at ndim-3, not
-        ndim-4)."""
+        the index [..., B] and row-holding [..., B, C, *row]
+        (``_ROW_LEAVES``: the batch axis lies two before the row's own
+        dims)."""
         def ins(path, pb, p1):
             name = getattr(path[-1], "key", "")
             if name == "index":
                 return pb.at[..., slot].set(true_len)
             return jax.lax.dynamic_update_slice_in_dim(
                 pb, p1, slot,
-                axis=pb.ndim - (3 if name == "kv_scales" else 4))
+                axis=pb.ndim - (2 + _LINEAR_ROW_DIMS[name]))
 
         return jax.tree_util.tree_map_with_path(ins, cache_b, cache_1)
 
@@ -1028,28 +1044,22 @@ class ServingEngine:
         stores exactly the bytes the batch-1 prefill quantized, which
         is what keeps int8 paged parity bitwise."""
         dest = self._lane_dest_rows(table_row, start, end)
-        rename = {"key_pool": "key_cache", "value_pool": "value_cache",
-                  "kv_pool_scales": "kv_scales"}
         flat_1 = {self._path_key(p): leaf for p, leaf
                   in jax.tree_util.tree_flatten_with_path(cache_1)[0]}
 
         def scatter(path, leaf):
             name = getattr(path[-1], "key", "")
-            if name not in rename:
+            if name not in _ROW_LEAVES:
                 return leaf
-            src = flat_1[self._path_key(path[:-1]) + (rename[name],)]
-            if name == "kv_pool_scales":
-                # [..., 2, 1, C, kvh] → rows at axis -2 of the
-                # flattened [..., 2, nb*bs, kvh] pool.
-                src = jnp.squeeze(src, axis=-3)    # drop the batch-1 dim
-                n_lead = leaf.ndim - 3             # dims before (nb, bs)
-                flat = leaf.reshape(leaf.shape[:n_lead] + (-1,)
-                                    + leaf.shape[-1:])
-            else:
-                src = jnp.squeeze(src, axis=-4)    # drop the batch-1 dim
-                n_lead = leaf.ndim - 4
-                flat = leaf.reshape(leaf.shape[:n_lead] + (-1,)
-                                    + leaf.shape[-2:])
+            linear, row_dims = _ROW_LEAVES[name]
+            # [..., 1, C, *row] → rows of the flattened
+            # [..., nb*bs, *row] pool.
+            src = jnp.squeeze(                     # drop the batch-1 dim
+                flat_1[self._path_key(path[:-1]) + (linear,)],
+                axis=-(2 + row_dims))
+            n_lead = leaf.ndim - (2 + row_dims)    # dims before (nb, bs)
+            flat = leaf.reshape(leaf.shape[:n_lead] + (-1,)
+                                + leaf.shape[leaf.ndim - row_dims:])
             idx = (slice(None),) * n_lead + (dest,)
             flat = flat.at[idx].set(src.astype(flat.dtype), mode="drop")
             return flat.reshape(leaf.shape)
@@ -1106,8 +1116,6 @@ class ServingEngine:
         pos = jnp.arange(self.cache_len)
         rows = (table_row[jnp.clip(pos // bs, 0, self._kv_nblk_lane - 1)]
                 * bs + pos % bs)
-        rename = {"key_cache": "key_pool", "value_cache": "value_pool",
-                  "kv_scales": "kv_pool_scales"}
         pools = {self._path_key(p): leaf for p, leaf
                  in jax.tree_util.tree_flatten_with_path(cache)[0]}
         struct = self._cache_struct(1, draft=draft)
@@ -1116,17 +1124,13 @@ class ServingEngine:
             name = getattr(path[-1], "key", "")
             if name == "index":
                 return jnp.full(s.shape, matched, s.dtype)
-            src = pools[self._path_key(path[:-1]) + (rename[name],)]
-            if name == "kv_scales":
-                # Pool [..., 2, nb, bs, kvh] → batch-1 [..., 2, 1, C,
-                # kvh]: same row map, batch dim re-inserted at -3.
-                n_lead = src.ndim - 3
-                flat = src.reshape(src.shape[:n_lead] + (-1,)
-                                   + src.shape[-1:])
-            else:
-                n_lead = src.ndim - 4
-                flat = src.reshape(src.shape[:n_lead] + (-1,)
-                                   + src.shape[-2:])
+            src = pools[self._path_key(path[:-1]) + (_POOL_OF[name],)]
+            # Pool [..., nb, bs, *row] → batch-1 [..., 1, C, *row]: the
+            # same row map, the batch dim re-inserted before the rows.
+            row_dims = _LINEAR_ROW_DIMS[name]
+            n_lead = src.ndim - (2 + row_dims)
+            flat = src.reshape(src.shape[:n_lead] + (-1,)
+                               + src.shape[src.ndim - row_dims:])
             take = jnp.take(flat, rows, axis=n_lead)
             return jnp.expand_dims(take, axis=n_lead).astype(s.dtype)
 
@@ -1164,21 +1168,25 @@ class ServingEngine:
         Also returns the NEXT chunk's (tok, counts) carry — computed
         inside the same program so the overlap scheduler can chain
         chunks with zero extra dispatches (the sync path ignores
-        them)."""
+        them) — and, last, the rows each expert took in each step and
+        expert layer ([chunk, layers, experts]; None for a model
+        without routed experts, which sows no ``moe_stats``)."""
         def step(carry, j):
             cache, tok = carry
             with quantized_inference():
                 logits, upd = self._model.apply(
                     dict(variables, cache=cache), tok[:, None],
-                    mutable=["cache"])
+                    mutable=["cache", "moe_stats"])
             nxt = self._pick(logits[:, -1], seeds, counts + j).astype(
                 tok.dtype)
-            return (upd["cache"], nxt), nxt
+            rows = jax.tree.leaves(upd.get("moe_stats", {}))
+            return (upd["cache"], nxt), (
+                nxt, jnp.stack(rows) if rows else None)
 
-        (cache, last), toks = jax.lax.scan(
+        (cache, last), (toks, expert_rows) = jax.lax.scan(
             step, (cache, tok), jnp.arange(self.chunk))
         return (cache, jnp.moveaxis(toks, 0, 1),    # [slots, chunk]
-                last, counts + self.chunk)
+                last, counts + self.chunk, expert_rows)
 
     # -- host-side loop ----------------------------------------------------
 
@@ -1613,11 +1621,10 @@ class ServingEngine:
                 self._kv_pool.deref(b)
 
     # Row-holding cache leaves, by batch-1 linear name, with the axis
-    # their rows live on: the serialization manifest for KV handoff
-    # (``kv_scales`` is [..., 2, 1, C, kvh] — rows at -2; key/value are
-    # [..., 1, C, kvh, hd] — rows at -3).
-    _KV_LEAF_ROW_AXIS = {"key_cache": -3, "value_cache": -3,
-                         "kv_scales": -2}
+    # their rows live on ([..., 1, C, *row]): the serialization
+    # manifest for KV handoff.
+    _KV_LEAF_ROW_AXIS = {lin: -(1 + dims)
+                         for lin, dims in _LINEAR_ROW_DIMS.items()}
 
     @thread_role("main", "driver")
     def export_prefix_kv(self, tokens):
@@ -2759,6 +2766,19 @@ class ServingEngine:
             lanes=len(held), positions=sum(held), kv_blocks=kv_blocks,
             kv_table_blocks=kv_table_blocks)
 
+    def _count_experts(self, expert_rows) -> None:
+        """``engine/step``'s account of the routed experts of a
+        harvested decode chunk: ``expert_rows`` [chunk, layers, experts]
+        as ``_decode_chunk`` returns it (None: no routed experts)."""
+        if expert_rows is None:
+            return
+        rows = expert_rows.astype(np.float64)
+        mean = rows.mean(axis=-1)
+        self._step_counts.update(
+            experts_hit=float((rows > 0).sum(axis=-1).mean()),
+            expert_load_cv=float(
+                (rows.std(axis=-1) / np.maximum(mean, 1e-9)).mean()))
+
     @dispatch_critical
     def _dispatch_chunk(self) -> None:
         """Enqueue one decode chunk (or speculative round) for ALL
@@ -2803,12 +2823,13 @@ class ServingEngine:
                                   "emit": emit, "emitted": emitted,
                                   "next_tok": next_tok, "acc": acc}
             else:
-                (self._cache, toks, last,
-                 counts_next) = self._decode_chunk(
+                (self._cache, toks, last, counts_next,
+                 expert_rows) = self._decode_chunk(
                     self._variables, self._cache, tok, jseeds, counts)
                 self._carry = (last, counts_next)
                 self._inflight = {"spec": False, "rids": rids,
-                                  "toks": toks}
+                                  "toks": toks,
+                                  "expert_rows": expert_rows}
         with self._stats_lock:
             self.overlap_stats["chunks"] += 1
 
@@ -2857,12 +2878,16 @@ class ServingEngine:
                         np.asarray(inf["acc"]))
             else:
                 toks = np.asarray(inf["toks"])
+                expert_rows = inf["expert_rows"]
+                if expert_rows is not None:
+                    expert_rows = np.asarray(expert_rows)
         t0 = time.perf_counter()
         with events.span("decode/harvest", overlapped=overlapped):
             if inf["spec"]:
                 self._harvest_spec(*args, inf["k"], rids=rids)
             else:
                 self._harvest(toks, rids=rids)
+                self._count_experts(expert_rows)
         dt = time.perf_counter() - t0
         with self._stats_lock:
             self.overlap_stats["harvest_s"] += dt
@@ -3044,13 +3069,17 @@ class ServingEngine:
                 with self._ctx(), events.span(
                         "decode/dispatch", fused=self._fused_tag):
                     self._flush_stale_lanes()
-                    self._cache, toks, _, _ = self._decode_chunk(
+                    (self._cache, toks, _, _,
+                     expert_rows) = self._decode_chunk(
                         self._variables, self._cache, jnp.asarray(tok),
                         jnp.asarray(seeds), jnp.asarray(counts))
                 with events.span("decode/wait", overlapped=False):
                     toks = np.asarray(toks)
+                    if expert_rows is not None:
+                        expert_rows = np.asarray(expert_rows)
                 with events.span("decode/harvest", overlapped=False):
                     self._harvest(toks)
+                    self._count_experts(expert_rows)
         out, self._outputs = self._outputs, {}
         return out
 

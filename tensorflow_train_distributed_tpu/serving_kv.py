@@ -50,6 +50,7 @@ walks over O(prompt/block_size) nodes.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Dict, List, Optional, Tuple
 
 # Physical block 0 is the scratch block: never allocated, never shared,
@@ -261,17 +262,33 @@ class RadixPrefixIndex:
         blocks are available on the pool's free list (or nothing is
         left to evict).  Evicting a leaf may expose its parent as the
         next candidate, so whole retired subtrees drain under
-        sustained pressure.  Returns the number of blocks evicted."""
+        sustained pressure.  Returns the number of blocks evicted.
+
+        One walk of the tree a call, then a heap: a request of a few
+        thousand prompt tokens frees hundreds of blocks in one call,
+        and a walk a BLOCK (16k nodes each, in Python) held the engine's
+        host loop for longer than the decode chunk it runs beside."""
+        if self._pool.free_blocks() >= n_needed:
+            return 0
+        # (last_used, discovery order) keys: least recent first, ties in
+        # the order found; a parent exposed by its last child's eviction
+        # joins with its own recency.
+        heap = [(n.last_used, i, n)
+                for i, n in enumerate(self._evictable())]
+        heapq.heapify(heap)
+        seq = len(heap)
         evicted = 0
-        while self._pool.free_blocks() < n_needed:
-            leaves = self._evictable()
-            if not leaves:
-                break
-            victim = min(leaves, key=lambda n: n.last_used)
+        while heap and self._pool.free_blocks() < n_needed:
+            _, _, victim = heapq.heappop(heap)
+            parent = victim.parent
             self._pool.deref(victim.block)
-            del victim.parent.children[victim.chunk]
+            del parent.children[victim.chunk]
             self._nodes -= 1
             evicted += 1
+            if (parent is not self._root and not parent.children
+                    and self._pool.refcount(parent.block) == 1):
+                heapq.heappush(heap, (parent.last_used, seq, parent))
+                seq += 1
         self.stats["evicted_blocks"] += evicted
         return evicted
 

@@ -350,11 +350,11 @@ def test_overhead_bar_already_compiled_dispatch_pytree_args():
     for _ in range(6):                 # more reps: the min needs one
         t0 = time.perf_counter()       # quiet rep to land under the bar
         for _ in range(n):
-            cache, _, _, _ = eng._decode_chunk(
+            cache, _, _, _, _ = eng._decode_chunk(
                 eng._variables, cache, tok, seeds, counts)
         t1 = time.perf_counter()
         for _ in range(n):
-            cache, _, _, _ = inner(
+            cache, _, _, _, _ = inner(
                 eng, eng._variables, cache, tok, seeds, counts)
         t2 = time.perf_counter()
         best = min(best, ((t1 - t0) - (t2 - t1)) / n)

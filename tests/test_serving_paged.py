@@ -89,6 +89,40 @@ def test_radix_insert_match_evict_invariants():
     idx.check_invariants()
 
 
+def test_radix_eviction_walks_the_tree_once_a_call(monkeypatch):
+    """Long retired chains (a prompt of thousands of tokens is hundreds
+    of blocks) drain in ONE walk of the tree a call, least recent chain
+    first and each chain from its leaf up, stopping at a pinned block
+    and as soon as enough is free."""
+    pool = serving_kv.KVBlockPool(40, 2)
+    idx = serving_kv.RadixPrefixIndex(pool)
+    chains = {}
+    for first in (1, 2, 3):                     # three 10-block prompts
+        toks = [first] * 20
+        chains[first] = pool.alloc(10)
+        idx.insert(toks, lambda j, b=chains[first]: b[j])
+    for blocks in chains.values():
+        for blk in blocks:
+            pool.deref(blk)
+    idx.match([2] * 20 + [9])                   # chain 2 is most recent
+    pool.ref(chains[3][4])                      # a lane still reads 3's
+    walks = []                                  # first five blocks
+    walk = idx._evictable
+    monkeypatch.setattr(idx, "_evictable",
+                        lambda: walks.append(1) or walk())
+    assert pool.free_blocks() == 10
+    assert idx.evict_for(10) == 0 and not walks   # enough free already
+    assert idx.evict_for(23) == 13 and len(walks) == 1
+    # all of chain 1 (least recent), then chain 3 from its leaf up
+    assert idx.match([1] * 20 + [9])[0] == 0
+    assert idx.match([3] * 20 + [9])[0] == 14
+    assert idx.match([2] * 20 + [9])[0] == 20
+    # chain 3 stops at its pinned block; chain 2 goes next
+    assert idx.evict_for(40) == 12 and pool.free_blocks() == 35
+    assert idx.match([3] * 20 + [9])[0] == 10
+    idx.check_invariants()
+
+
 def test_radix_lru_evicts_least_recent_leaf():
     pool = serving_kv.KVBlockPool(4, 2)
     idx = serving_kv.RadixPrefixIndex(pool)

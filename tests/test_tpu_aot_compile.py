@@ -128,6 +128,20 @@ def _paged(heads, kvh, hd, bs, q_len, int8, gather=False):
         (q, pool, pool, table, lengths, scales, scales))
 
 
+def _paged_latent(q_len):
+    """GLM-4.7-Flash's absorbed decode kernel at its published sizes:
+    20 heads over rows of 512 + 64 values stored 640 wide, blocks of
+    16."""
+    lanes, cache_len, bs, heads, row, rank = 8, 2048, 16, 20, 640, 512
+    n_blk = cache_len // bs
+    return (lambda q, p, t, n: pk.paged_latent_attention(
+        q, p, t, n, value_dim=rank, scale=256 ** -0.5,
+        cache_len=cache_len, use_pallas=True),
+        (((lanes, q_len, heads, row), BF16),
+         ((1 + lanes * n_blk, bs, row), BF16),
+         ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
+
+
 # (heads, kv_heads, head_dim, block_size): llama_350m's layout at the
 # engine's default block size, and qwen25_7b's GQA layout.
 _LAYOUTS = {"h16kv16d64": (16, 16, 64, 16), "h28kv4d128": (28, 4, 128, 32)}
@@ -142,6 +156,8 @@ CASES = {
     "flash-fwd": lambda: _flash(False),
     "flash-grad": lambda: _flash(True),
 }
+for _q in (1, 3):
+    CASES[f"paged_latent-h20r576-q{_q}"] = lambda q=_q: _paged_latent(q)
 for _name, (_h, _kvh, _hd, _bs) in _LAYOUTS.items():
     CASES[f"paged_gather-{_name}"] = (
         lambda a=(_h, _kvh, _hd, _bs): _paged(*a, 1, False, gather=True))
