@@ -5,9 +5,11 @@ latent-attention, routed-expert block).
 ``decode_table(ctx)``: milliseconds a decode step by scope over the
 operations that began inside a WHOLE execution of ``_decode_chunk``
 (one the capture's edges did not cut: this cell's capture is short,
-``trace_s`` 1, because the reduction's attribution of idle gaps is
-quadratic in the capture's length and a step here is ~900 small
-operations), read once a run and kept in ``ctx``.  The Pallas kernels are classes of
+``trace_s`` 1, set when the reduction's attribution of idle gaps was
+quadratic in the capture and a step here ~900 small operations; it is
+one sweep since PR 28, and ``trace_s`` stays 1 until a ``benchmark`` PR
+that may move readings raises it), read once a run and kept in
+``ctx``.  The Pallas kernels are classes of
 their own, found by the names their calls carry
 (``paged_latent_attention``, megablox's ``gmm``); what lies under none
 of ``SCOPES`` and is no kernel is ``scopes.PLUMBING``.  ``None`` when

@@ -197,6 +197,24 @@ WAITING = ("acquire", "wait", "sleep", "select", "poll", "iter_tokens",
            "queue.py", "threading.py", "$<unknown> get")
 
 
+def _owner(live, glo: float, ghi: float, prefer: str):
+    """The event of ``live`` (in start order) that the gap [glo, ghi)
+    goes to by ``attribute_gaps``' rule; of equals the first wins."""
+    half = 0.5 * (ghi - glo)
+    inner = most = None
+    for ev in live:
+        cov = min(ghi, ev.start + ev.dur) - max(glo, ev.start)
+        if cov <= 0:
+            continue
+        if most is None or cov > most[0]:
+            most = (cov, ev)
+        if cov >= half:
+            key = (not ev.name.startswith(prefer), ev.dur)
+            if inner is None or key < inner[0]:
+                inner = (key, ev)
+    return inner[1] if inner else most[1] if most else None
+
+
 def attribute_gaps(trace: Trace, lo: float, hi: float, n: int = 10,
                    prefer: str = "bench/") -> list:
     """[[label, seconds]]: the first device's idle time inside [lo, hi),
@@ -208,29 +226,30 @@ def attribute_gaps(trace: Trace, lo: float, hi: float, n: int = 10,
     in which a thread only waits (locks, queues, sleeps: ``WAITING``)
     are passed over, since some thread is always waiting.  A gap no
     span half covers goes to the working span that covers most of it,
-    or to ``(no host span)``."""
+    or to ``(no host span)``.  Of equals the first in start order wins.
+
+    One sweep over gaps and events together, both by start: an event
+    is ``live`` from the first gap that ends after its start until a
+    gap begins at or after its end (the gaps are disjoint, so it can
+    cover none later), and a gap looks at the live events alone: the
+    threads' open frames and what starts inside it (14 to 38 in the
+    cells' captures), not at every event that began before it.  A
+    capture twice as long, or of a program twice as fast, costs twice
+    as much."""
     if not trace.devices:
         return []
     host = sorted((ev for ev in trace.host
                    if not any(w in ev.name for w in WAITING)),
                   key=lambda ev: ev.start)
     agg = collections.Counter()
+    live, nxt = [], 0
     for glo, ghi in idle_gaps(trace.devices[0], lo, hi):
-        half = 0.5 * (ghi - glo)
-        inner = most = None
-        for ev in host:
-            if ev.start >= ghi:
-                break
-            cov = min(ghi, ev.start + ev.dur) - max(glo, ev.start)
-            if cov <= 0:
-                continue
-            if most is None or cov > most[0]:
-                most = (cov, ev)
-            if cov >= half:
-                key = (not ev.name.startswith(prefer), ev.dur)
-                if inner is None or key < inner[0]:
-                    inner = (key, ev)
-        pick = inner[1] if inner else most[1] if most else None
+        live = [ev for ev in live if ev.start + ev.dur > glo]
+        while nxt < len(host) and host[nxt].start < ghi:
+            if host[nxt].start + host[nxt].dur > glo:
+                live.append(host[nxt])
+            nxt += 1
+        pick = _owner(live, glo, ghi, prefer)
         agg[pick.name if pick else "(no host span)"] += ghi - glo
     return [[name, secs] for name, secs in agg.most_common(n)]
 

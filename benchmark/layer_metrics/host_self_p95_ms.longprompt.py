@@ -2,7 +2,7 @@
 whole window (``engine/step`` less its ``*/wait`` children): the steps
 that stage, run and finalize prefills beside a decode chunk.  Read from
 the program's ring of spans.  Layer: engine host loop.  Moves
-``gap_p95_ms``."""
+``gap_p90_ms``."""
 
 from benchmark.harness import spans, stats
 

@@ -7,7 +7,7 @@ by owner (the innermost such span) go to the log, and so does what is
 left, by where it lies: under a step and none of its children, under a
 ``*/wait``, between steps, and at the capture's edges, before the first
 span that began inside it or after the last (a span open when the
-capture starts is not in it).  Layer: device.  Moves ``gap_p95_ms``."""
+capture starts is not in it).  Layer: device.  Moves ``gap_p90_ms``."""
 
 import collections
 

@@ -2,7 +2,7 @@
 ``_prefill_piece`` program: the real prompt tokens a piece carried on
 average over the whole load (the engine's count of pieces, the clients'
 prompt lengths) over the mean device time of the program's executions
-in the traced window.  Layer: engine programs.  Moves ``gap_p95_ms``."""
+in the traced window.  Layer: engine programs.  Moves ``gap_p90_ms``."""
 
 from benchmark.harness import trace
 

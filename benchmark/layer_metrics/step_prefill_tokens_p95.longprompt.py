@@ -3,7 +3,7 @@
 the step ran, as the engine counts them) over the ``engine/step`` spans
 of the window that also dispatched a chunk.  Every token of the cell
 waits on such a step.  Read from the program's ring of spans.  Layer:
-engine host loop.  Moves ``gap_p95_ms``."""
+engine host loop.  Moves ``gap_p90_ms``."""
 
 from benchmark.harness import spans, stats
 

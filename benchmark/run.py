@@ -16,7 +16,9 @@ The LAST line of standard output is the result: one JSON object with
 end-to-end metrics, or with ``--trace 1`` its per-layer metrics),
 ``device`` and, traced, ``breakdown``.  Every earlier line is a JSON
 object too (phases, medians beside tails, each number compared beside
-its limit) and is for people.
+its limit) and is for people; a traced run's last one before the result
+is ``{"phase": "reduce", ...}``, what the capture cost after the window
+(``reduce_capture``).
 """
 
 from __future__ import annotations
@@ -71,7 +73,7 @@ class Tracer:
 
     def __init__(self, directory: str):
         self.directory = directory
-        self.t0 = self.t1 = None
+        self.t0 = self.t1 = self.stop_s = None
 
     def start(self) -> None:
         import jax
@@ -85,7 +87,59 @@ class Tracer:
         import jax
 
         self.t1 = time.monotonic()
-        jax.profiler.stop_trace()
+        jax.profiler.stop_trace()       # collects and writes the capture
+        self.stop_s = time.monotonic() - self.t1
+
+
+def reduce_capture(ctx: dict, per_layer, reader_of) -> tuple:
+    """``(metrics, breakdown, device's busy_s and window_s)`` of a traced
+    run, from the capture ``ctx["tracer"]`` wrote: the cell's
+    ``per_layer`` entries through their readers (``reader_of(name)``),
+    the operations that took most device time and the idle gaps by what
+    the host was doing.
+
+    Logs what that cost as ``{"phase": "reduce", ...}``: seconds of the
+    capture's stop and export (inside the window), of loading it, of
+    each reader by name, of ``top_ops`` and of ``attribute_gaps``, with
+    the sizes they grow with: idle gaps, host events, device
+    operations (PERF.md §7 has the table by cell), so that a run which
+    is stopped at its time limit can be read from its log."""
+    from benchmark.harness import trace as trace_lib
+
+    tracer = ctx["tracer"]
+
+    def timed(fn, *args, **kwargs):
+        t0 = time.monotonic()
+        value = fn(*args, **kwargs)
+        return value, time.monotonic() - t0
+
+    tr, load_s = timed(trace_lib.load_xplane,
+                       trace_lib.find_xplane(tracer.directory))
+    win = trace_lib.window(tr)
+    if win is None:
+        raise RuntimeError("the trace shows no device operation")
+    ctx.update(trace=tr, trace_window=win)
+    seen = {"busy_s": trace_lib.mean_busy_seconds(tr, *win),
+            "window_s": win[1] - win[0]}
+    metrics, readers_s = {}, {}
+    for m in per_layer:
+        value, readers_s[m["name"]] = timed(reader_of(m["name"]), ctx)
+        if value is None:
+            continue          # nothing to read in this cell
+        if m["unit"] == "%" and value > 105.0:
+            raise RuntimeError(
+                f"{m['name']} reads {value:.1f}%: the count or the "
+                f"time is wrong")
+        metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    top, top_ops_s = timed(trace_lib.top_ops, tr, 10)
+    idle, attribute_gaps_s = timed(trace_lib.attribute_gaps, tr, *win, n=10)
+    ctx["log"](phase="reduce", stop_export_s=tracer.stop_s, load_s=load_s,
+               readers_s=readers_s, top_ops_s=top_ops_s,
+               attribute_gaps_s=attribute_gaps_s,
+               idle_gaps=len(trace_lib.idle_gaps(tr.devices[0], *win)),
+               host_events=len(tr.host),
+               device_ops=sum(len(d.ops) for d in tr.devices))
+    return metrics, {"device_ops": top, "idle_gaps": idle}, seen
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -135,7 +189,6 @@ def main(argv=None, *, root: str = REPO, require_platform="tpu",
     devices = devices[:cell["chips"]]
     kind = devices[0].device_kind
 
-    from benchmark.harness import trace as trace_lib
     from benchmark.harness.compiles import Compiles
     from benchmark.harness.peaks import peaks_for
 
@@ -201,27 +254,10 @@ def main(argv=None, *, root: str = REPO, require_platform="tpu",
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
     else:
-        capture = trace_lib.find_xplane(tracer.directory)
-        tr = trace_lib.load_xplane(capture)
-        win = trace_lib.window(tr)
-        if win is None:
-            raise RuntimeError("the trace shows no device operation")
-        ctx.update(trace=tr, trace_window=win, result=result, setup=setup)
-        device["busy_s"] = trace_lib.mean_busy_seconds(tr, *win)
-        device["window_s"] = win[1] - win[0]
-        metrics = {}
-        for m in man.per_layer_for(args.workload):
-            value = man.layer_reader(m["name"])(ctx)
-            if value is None:
-                continue          # nothing to read in this cell
-            if m["unit"] == "%" and value > 105.0:
-                raise RuntimeError(
-                    f"{m['name']} reads {value:.1f}%: the count or the "
-                    f"time is wrong")
-            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
-        out["breakdown"] = {
-            "device_ops": trace_lib.top_ops(tr, 10),
-            "idle_gaps": trace_lib.attribute_gaps(tr, *win, n=10)}
+        ctx.update(result=result, setup=setup)
+        metrics, out["breakdown"], seen = reduce_capture(
+            ctx, man.per_layer_for(args.workload), man.layer_reader)
+        device.update(seen)
     out["metrics"] = metrics
     out["device"] = device
     print(json.dumps(out), flush=True)
