@@ -275,8 +275,8 @@ def _kernel_checks(opts, cfg, batch: int, seq: int) -> dict:
     bs, lanes, c = 16, opts.slots, opts.cache_len
     n_blk = -(-c // bs)
     nb = 1 + lanes * n_blk
-    kp, vp = (jax.random.normal(next(key), (nb, bs, kvh, hd), dt)
-              for _ in range(2))
+    kp, vp = (jax.random.normal(next(key), (nb, bs, kvh * hd), dt)
+              for _ in range(2))       # rows as the cache stores them
     table = (1 + jax.random.permutation(next(key), lanes * n_blk)
              ).reshape(lanes, n_blk).astype(jnp.int32)
 

@@ -158,6 +158,51 @@ def _quantize_kv_rows(t):
     return qt, scale
 
 
+def paged_pool_leaves(blocks: int, block_size: int, kv_heads: int,
+                      head_dim: int, dtype, int8: bool) -> dict:
+    """name -> (shape, dtype) of one layer's paged K/V pool leaves, as
+    they are STORED: a row is its ``kv_heads * head_dim`` values side by
+    side, [blocks, block_size, row], which is the shape the attention
+    kernel copies blocks in, so nothing between the cache and the
+    kernel takes a view of a pool.  int8 rows bring their float32
+    scales, [2 (K, V), blocks, block_size, kv_heads].  One table for
+    the module that owns a layer's pools (``MultiHeadAttention``) and
+    for the depth scan that owns every layer's
+    (``llama._ScannedBlock``, one leading layer axis)."""
+    row = (blocks, block_size, kv_heads * head_dim)
+    store = jnp.int8 if int8 else dtype
+    leaves = {"key_pool": (row, store), "value_pool": (row, store)}
+    if int8:
+        leaves["kv_pool_scales"] = (
+            (2, blocks, block_size, kv_heads), jnp.float32)
+    return leaves
+
+
+def _paged_dest(table, positions, block_size: int, blocks: int):
+    """Where a step's rows go: (physical block, row in it) per (lane,
+    token).  The table lookup CLIPS the block index (gather semantics
+    would otherwise wrap); a position past the table's width gets block
+    ``blocks``, out of range, so that the scatter DROPS it — an overrun
+    lane goes silently inert, the linear path's rule.  A table slot the
+    engine zeroed sends its rows to the scratch block 0."""
+    n_blk = table.shape[1]
+    blk = jnp.clip(positions // block_size, 0, n_blk - 1)
+    phys = jnp.take_along_axis(table, blk, axis=1)              # [B, q]
+    return (jnp.where(positions < n_blk * block_size, phys, blocks),
+            positions % block_size)
+
+
+def _set_pool_rows(pool, lead: tuple, phys, row, rows):
+    """``pool[*lead, phys, row] = rows``: THE write of a decode step's
+    rows into a paged pool, one scatter of lanes x q_len rows on the
+    buffer the pool lives in (no slab is taken out and put back, no
+    view reshaped).  ``lead`` indexes the pool's leading axes: the
+    layer of a pool the depth scan carries, the K/V half of a scales
+    pool."""
+    return pool.at[(*lead, phys, row)].set(rows.astype(pool.dtype),
+                                           mode="drop")
+
+
 class RMSNorm(nn.Module):
     """Llama-family norm; scale is replicated ("norm" logical axis).
 
@@ -358,7 +403,13 @@ class MultiHeadAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x_q, x_kv=None, *, mask=None, positions=None,
-                 segment_ids=None, deterministic: bool = True):
+                 segment_ids=None, deterministic: bool = True,
+                 kv_pools=None):
+        if kv_pools is not None and not (self.decode
+                                         and self.paged_kv_blocks):
+            raise ValueError("kv_pools are the depth scan's carried "
+                             "paged pools: they go with decode=True "
+                             "and paged_kv_blocks")
         if self.decode:
             if (x_kv is not None or mask is not None
                     or segment_ids is not None or positions is not None):
@@ -367,7 +418,7 @@ class MultiHeadAttention(nn.Module):
                     "cache; cross-attention inputs (x_kv), dense masks, "
                     "segment ids and explicit positions are not supported "
                     "in decode mode (the cache index supplies positions)")
-            return self._decode_step(x_q)
+            return self._decode_step(x_q, kv_pools)
         if self.slot_decode:
             raise ValueError("slot_decode requires decode=True (it is a "
                              "KV-cache mode)")
@@ -453,7 +504,7 @@ class MultiHeadAttention(nn.Module):
         y = self._out_proj(out, x_q.shape[-1])
         return nn.with_logical_constraint(y, ("batch", "length", "embed"))
 
-    def _decode_step(self, x):
+    def _decode_step(self, x, kv_pools=None):
         """Append x's tokens to the KV cache, attend over the prefix.
 
         Submodule names match the training path exactly, so params trained
@@ -493,7 +544,8 @@ class MultiHeadAttention(nn.Module):
                     raise ValueError(
                         f"kv_block_size must be >= 1, got "
                         f"{self.kv_block_size}")
-                return self._paged_decode_step(x)
+                y, pools = self._paged_decode_step(x, kv_pools)
+                return y if kv_pools is None else (y, pools)
             return self._slot_decode_step(x)
         if self.sinks and (self.window is None
                            or self.sinks > self.window):
@@ -714,20 +766,34 @@ class MultiHeadAttention(nn.Module):
                                   mask[:, None], kv_heads, b, q_len,
                                   x.shape[-1])
 
-    def _paged_decode_step(self, x):
+    def _paged_decode_step(self, x, kv_pools=None):
         """Per-slot decode over the PAGED pool: same append-and-attend
         contract as ``_slot_decode_step``, with the lane's contiguous
         cache strip replaced by a block-table indirection.
 
-        Writes scatter each token's k/v row to ``pool[table[b, p //
-        bs], p %% bs]`` (positions past the table width map to an
-        out-of-range row and are DROPPED, the linear path's overrun
-        rule; positions in table slots the engine zeroed land in the
-        scratch block — garbage nobody reads).  Reads gather the lane's
-        logical rows back into a [B, cache_len] view
-        (``ops.pallas_kernels.paged_kv_gather`` — pure-jax on CPU, a
-        scalar-prefetch block-copy kernel on TPU) and attend exactly as
-        the linear path does: same mask, same positions, same einsum
+        The pools lie as the kernel reads them (``paged_pool_leaves``:
+        [blocks, block_size, kv_heads * head_dim]) and this step's rows
+        go into them IN PLACE: one scatter of lanes x q_len rows a pool
+        (``_set_pool_rows``) at ``pool[table[b, p // bs], p %% bs]``.
+        Positions past the table width are DROPPED, the linear path's
+        overrun rule; positions in table slots the engine zeroed land
+        in the scratch block — garbage nobody reads (``_paged_dest``).
+
+        Who owns the pools follows where the module stands.  Alone (a
+        model that unrolls its layers) it holds its layer's pools as
+        its own cache variables.  Under the depth scan
+        (``llama._ScannedBlock``) every layer's pools are ONE buffer
+        each, [layers, blocks, ...], that the scan carries:
+        ``kv_pools`` is ``(layer, pools)``, the rows go to
+        ``pool[layer, ...]``, the kernel reads the same buffer through
+        the table moved by ``layer * blocks``, and the step returns
+        ``(y, pools)``.  Neither way is a layer's slab taken out of a
+        stack and put back, nor a pool copied to another layout.
+
+        Reads gather the lane's logical rows back into a [B, cache_len]
+        view (``ops.pallas_kernels.paged_kv_gather`` — pure-jax on CPU,
+        a scalar-prefetch block-copy kernel on TPU) and attend exactly
+        as the linear path does: same mask, same positions, same einsum
         shapes, so outputs are bitwise-identical to the linear cache
         whenever the gathered bytes are (which the engine's block
         bookkeeping guarantees — pinned in tests/test_serving_paged.py).
@@ -744,9 +810,12 @@ class MultiHeadAttention(nn.Module):
 
         ``kv_cache_int8`` composes: pools store int8 rows quantized by
         the shared per-(row, kv_head) recipe, scales ride in a parallel
-        [2, num_blocks, block_size, kv_heads] pool, and the dequant
-        happens at read — fused into the kernel's block load, or into
-        the gathered view's attention read on the A/B leg.
+        [2, num_blocks, block_size, kv_heads] pool written by the same
+        scatter (carried with the others under the scan, where a read
+        takes the layer's scales out: a thirty-second of a slab's
+        bytes), and the dequant happens at read — fused into the
+        kernel's block load, or into the gathered view's attention read
+        on the A/B leg.
         """
         from tensorflow_train_distributed_tpu.ops import pallas_kernels \
             as pk
@@ -759,17 +828,16 @@ class MultiHeadAttention(nn.Module):
 
         q, k, v = self._qkv(x)
 
-        cache_dtype = jnp.int8 if self.kv_cache_int8 else self.dtype
-        cache_k = self.variable(
-            "cache", "key_pool", jnp.zeros,
-            (nb, bs, kv_heads, self.head_dim), cache_dtype)
-        cache_v = self.variable(
-            "cache", "value_pool", jnp.zeros,
-            (nb, bs, kv_heads, self.head_dim), cache_dtype)
-        if self.kv_cache_int8:
-            kv_scales = self.variable(
-                "cache", "kv_pool_scales", jnp.zeros,
-                (2, nb, bs, kv_heads), jnp.float32)
+        if kv_pools is None:            # this layer's own pools
+            own = {name: self.variable("cache", name, jnp.zeros, shape,
+                                       dtype)
+                   for name, (shape, dtype) in paged_pool_leaves(
+                       nb, bs, kv_heads, self.head_dim, self.dtype,
+                       self.kv_cache_int8).items()}
+            layer, pools = None, {n: var.value for n, var in own.items()}
+        else:                           # the depth scan's, carried
+            own, (layer, pools) = {}, kv_pools
+        lead = () if layer is None else (layer,)
         # All-zero init: every lane starts mapped to the scratch block,
         # so pre-insert garbage decode is self-contained by
         # construction.
@@ -786,44 +854,45 @@ class MultiHeadAttention(nn.Module):
                            scaling=self.rope_scaling)
         index.value = cur + q_len
 
-        kdt = cache_k.value.dtype
-        # This step's rows into the pools.  The device-scope contract
-        # (PERF.md §3) names the scatter; the reshape back to blocks
-        # stays outside it, with whatever else moves whole pools about
-        # in the decode program (the compiler folds it into the
-        # kernel's own view of the pool: a copy of the layer's slab).
-        flat_shape = (nb * bs, kv_heads, self.head_dim)
+        # This step's rows into the pools, under the name the
+        # device-scope contract (PERF.md §3) gives the write.
         with jax.named_scope("kv_pool/write"):
             if self.kv_cache_int8:
                 k_store, sk = _quantize_kv_rows(k)
                 v_store, sv = _quantize_kv_rows(v)
             else:
-                k_store, v_store = k.astype(kdt), v.astype(kdt)
-            # Physical destination row per (lane, token): the table
-            # lookup CLIPS the block index (gather semantics would
-            # otherwise wrap) and overrun positions are sent out of
-            # range so the scatter drops them — an overrun lane goes
-            # silently inert, exactly the linear path's rule.
-            blk = jnp.clip(positions // bs, 0, n_blk - 1)
-            phys = jnp.take_along_axis(table.value, blk, axis=1)  # [B, q]
-            dest = jnp.where(positions < n_blk * bs,
-                             phys * bs + positions % bs, nb * bs)
-            k_flat = cache_k.value.reshape(flat_shape).at[
-                dest.reshape(-1)].set(
-                    k_store.reshape(-1, kv_heads, self.head_dim),
-                    mode="drop")
-            v_flat = cache_v.value.reshape(flat_shape).at[
-                dest.reshape(-1)].set(
-                    v_store.reshape(-1, kv_heads, self.head_dim),
-                    mode="drop")
+                k_store, v_store = k, v
+            phys, row = _paged_dest(table.value, positions, bs, nb)
+            pools = dict(
+                pools,
+                key_pool=_set_pool_rows(
+                    pools["key_pool"], lead, phys, row,
+                    k_store.reshape(b, q_len, -1)),
+                value_pool=_set_pool_rows(
+                    pools["value_pool"], lead, phys, row,
+                    v_store.reshape(b, q_len, -1)))
             if self.kv_cache_int8:
-                sflat = kv_scales.value.reshape(2, nb * bs, kv_heads)
-                sflat = sflat.at[:, dest.reshape(-1)].set(
-                    jnp.stack([sk, sv]).reshape(2, -1, kv_heads),
-                    mode="drop")
-                kv_scales.value = sflat.reshape(2, nb, bs, kv_heads)
-        cache_k.value = k_flat.reshape(nb, bs, kv_heads, self.head_dim)
-        cache_v.value = v_flat.reshape(nb, bs, kv_heads, self.head_dim)
+                pools["kv_pool_scales"] = _set_pool_rows(
+                    pools["kv_pool_scales"],
+                    (*lead, jnp.arange(2)[:, None, None]), phys, row,
+                    jnp.stack([sk, sv]))
+        for name, var in own.items():
+            var.value = pools[name]
+
+        # What the read takes: the pool as it lies.  A carried pool's
+        # layers merge into its blocks (leading axes only, so the same
+        # bytes) and the table is read ``block0`` further on.
+        k_pool, v_pool = pools["key_pool"], pools["value_pool"]
+        scales = pools.get("kv_pool_scales")
+        block0 = 0
+        if layer is not None:
+            k_pool, v_pool = (p.reshape(-1, *p.shape[2:])
+                              for p in (k_pool, v_pool))
+            block0 = layer * nb
+            if scales is not None:
+                scales = jax.lax.dynamic_index_in_dim(
+                    scales, layer, keepdims=False)
+        k_scales, v_scales = scales if scales is not None else (None, None)
 
         # No scope from here to the kernel call: the benchmark finds the
         # kernel's device events by this method's name.
@@ -836,30 +905,27 @@ class MultiHeadAttention(nn.Module):
             # that lengthens until the lane is used again.
             held = jnp.where(table.value[:, 0] == 0, 0, cur)
             out = pk.paged_attention(
-                q, cache_k.value, cache_v.value, table.value, held,
-                k_scales=(kv_scales.value[0] if self.kv_cache_int8
-                          else None),
-                v_scales=(kv_scales.value[1] if self.kv_cache_int8
-                          else None),
-                cache_len=self.cache_len, use_pallas=True,
+                q, k_pool, v_pool, table.value, held,
+                k_scales=k_scales, v_scales=v_scales,
+                cache_len=self.cache_len, block0=block0, use_pallas=True,
                 interpret=pk.fused_attn_interpret())
-            return self._attn_epilogue(out, b, q_len, x.shape[-1])
+            return self._attn_epilogue(out, b, q_len, x.shape[-1]), pools
 
-        kc = pk.paged_kv_gather(cache_k.value, table.value,
-                                self.cache_len)
-        vc = pk.paged_kv_gather(cache_v.value, table.value,
-                                self.cache_len)
+        def lane_view(pool):
+            return pk.paged_kv_gather(
+                pool, table.value + block0, self.cache_len).reshape(
+                    b, self.cache_len, kv_heads, self.head_dim)
+
+        kc, vc = lane_view(k_pool), lane_view(v_pool)
         if self.kv_cache_int8:
-            ks = pk.paged_kv_gather(kv_scales.value[0][..., None],
-                                    table.value, self.cache_len)
-            vs = pk.paged_kv_gather(kv_scales.value[1][..., None],
-                                    table.value, self.cache_len)
-            kc = kc.astype(self.dtype) * ks.astype(self.dtype)
-            vc = vc.astype(self.dtype) * vs.astype(self.dtype)
+            ks = pk.paged_kv_gather(k_scales, table.value, self.cache_len)
+            vs = pk.paged_kv_gather(v_scales, table.value, self.cache_len)
+            kc = kc.astype(self.dtype) * ks[..., None].astype(self.dtype)
+            vc = vc.astype(self.dtype) * vs[..., None].astype(self.dtype)
         kv_pos = jnp.arange(self.cache_len)
         mask = kv_pos[None, None, :] <= positions[:, :, None]  # [B,q,C]
         return self._cache_attend(q, kc, vc, mask[:, None], kv_heads, b,
-                                  q_len, x.shape[-1])
+                                  q_len, x.shape[-1]), pools
 
     def _fused_paged_ok(self) -> bool:
         return fused_paged_ok()
@@ -1233,15 +1299,9 @@ class LatentAttention(nn.Module):
         q_nope, q_rope = self._queries(x, positions)
         rows = self._rows(x, positions)
         with jax.named_scope("kv_pool/write"):
-            blk = jnp.clip(positions // bs, 0, n_blk - 1)
-            phys = jnp.take_along_axis(table.value, blk, axis=1)
-            dest = jnp.where(positions < n_blk * bs,
-                             phys * bs + positions % bs, nb * bs)
-            flat = pool.value.reshape(nb * bs, self.row_store).at[
-                dest.reshape(-1)].set(
-                    rows.reshape(-1, self.row_store).astype(
-                        pool.value.dtype), mode="drop")
-        pool.value = flat.reshape(nb, bs, self.row_store)
+            pool.value = _set_pool_rows(
+                pool.value, (),
+                *_paged_dest(table.value, positions, bs, nb), rows)
 
         w = self._kv_b()
         with jax.named_scope("attn/absorb"):

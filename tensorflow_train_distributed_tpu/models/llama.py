@@ -257,12 +257,15 @@ class DecoderBlock(nn.Module):
     kv_block_size: int = 0
 
     @nn.compact
-    def __call__(self, x, segment_ids=None, positions=None):
+    def __call__(self, x, segment_ids=None, positions=None, kv_pools=None):
+        """``kv_pools``: the depth scan's carried paged pools, ``(layer,
+        pools)`` (``layers.MultiHeadAttention._paged_decode_step``); the
+        block then returns ``(x, pools)``."""
         cfg = self.config
         h = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       zero_centered=cfg.norm_zero_centered,
                       name="attn_norm")(x)
-        x = x + L.MultiHeadAttention(
+        attn = L.MultiHeadAttention(
             num_heads=cfg.num_heads,
             head_dim=cfg.head_dim or cfg.d_model // cfg.num_heads,
             num_kv_heads=cfg.num_kv_heads,
@@ -279,7 +282,11 @@ class DecoderBlock(nn.Module):
             fused_qkv=cfg.fused_qkv,
             qkv_bias=cfg.qkv_bias,
             name="attention",
-        )(h, segment_ids=segment_ids, positions=positions)
+        )(h, segment_ids=segment_ids, positions=positions,
+          kv_pools=kv_pools)
+        if kv_pools is not None:
+            attn, pools = attn
+        x = x + attn
         h = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       zero_centered=cfg.norm_zero_centered,
                       name="mlp_norm")(x)
@@ -301,7 +308,7 @@ class DecoderBlock(nn.Module):
             activation={"silu": nn.silu, "gelu": nn.gelu}[
                 cfg.mlp_activation],
             gated=True, name="mlp")(h)
-        return x
+        return x if kv_pools is None else (x, pools)
 
 
 def segment_relative_positions(segment_ids: jax.Array) -> jax.Array:
@@ -322,8 +329,12 @@ def segment_relative_positions(segment_ids: jax.Array) -> jax.Array:
 
 
 class _BlockStep(nn.Module):
-    """scan-compatible adapter: (carry, aux) → (carry, None); ``aux`` is
-    the nn.broadcast (segment_ids, positions) pair shared by all layers."""
+    """scan-compatible adapter: (carry, aux, layer) → (carry, None).
+    ``carry`` is ``(x, pools)``: the activations and, in paged decode,
+    every layer's KV pools (otherwise none: ``{}``); ``aux`` is the
+    nn.broadcast (segment_ids, positions) pair shared by all layers;
+    ``layer`` is this step's index, by which the block addresses its
+    part of the carried pools."""
 
     config: LlamaConfig
     decode: bool = False
@@ -333,20 +344,34 @@ class _BlockStep(nn.Module):
     kv_block_size: int = 0
 
     @nn.compact
-    def __call__(self, carry, aux):
+    def __call__(self, carry, aux, layer):
+        x, pools = carry
         segment_ids, positions = aux if aux is not None else (None, None)
-        return DecoderBlock(self.config, decode=self.decode,
-                            cache_len=self.cache_len,
-                            slot_decode=self.slot_decode,
-                            paged_kv_blocks=self.paged_kv_blocks,
-                            kv_block_size=self.kv_block_size,
-                            name="block")(carry, segment_ids,
-                                          positions), None
+        block = DecoderBlock(self.config, decode=self.decode,
+                             cache_len=self.cache_len,
+                             slot_decode=self.slot_decode,
+                             paged_kv_blocks=self.paged_kv_blocks,
+                             kv_block_size=self.kv_block_size,
+                             name="block")
+        if not pools:
+            return (block(x, segment_ids, positions), pools), None
+        return block(x, segment_ids, positions,
+                     kv_pools=(layer, pools)), None
 
 
 class _ScannedBlock(nn.Module):
     """Depth-scanned stack: params get a leading ``stage`` axis, so compile
-    time is O(1) in depth and the pipeline axis can shard layers."""
+    time is O(1) in depth and the pipeline axis can shard layers.
+
+    Cache variables are scanned with the params (a layer's linear cache,
+    index and block table are its slice of a stacked leaf) — but NOT the
+    paged KV pools of a decode step.  Those this module owns whole,
+    ``[layers, blocks, block_size, row]`` a pool
+    (``layers.paged_pool_leaves``), and the scan CARRIES them: a layer
+    writes its rows into the one buffer and its kernel reads that
+    buffer, so no 128 MiB slab is sliced out of a stack and put back
+    around a write of 32 rows, and the buffer the program was given is
+    the buffer its result lives in."""
 
     config: LlamaConfig
     decode: bool = False
@@ -378,6 +403,17 @@ class _ScannedBlock(nn.Module):
         if wants_outer_remat(self.config) and not self.decode:
             step = nn.remat(step, prevent_cse=False,
                             policy=_checkpoint_policy(self.config))
+        cfg = self.config
+        pools = {}
+        if self.decode and self.paged_kv_blocks:
+            pools = {
+                name: self.variable("cache", name, jnp.zeros,
+                                    (cfg.num_layers, *shape), dtype)
+                for name, (shape, dtype) in L.paged_pool_leaves(
+                    self.paged_kv_blocks, self.kv_block_size,
+                    cfg.num_kv_heads or cfg.num_heads,
+                    cfg.head_dim or cfg.d_model // cfg.num_heads,
+                    cfg.dtype, cfg.kv_cache_int8).items()}
         scanned = nn.scan(
             step,
             # "quant": stacked int8 serving scales (models.quant) slice
@@ -385,12 +421,16 @@ class _ScannedBlock(nn.Module):
             # absent collections are ignored by nn.scan.
             variable_axes={"params": 0, "cache": 0, "quant": 0},
             split_rngs={"params": True},
-            in_axes=nn.broadcast,  # (segment_ids, positions): all layers
-            length=self.config.num_layers,
+            # (segment_ids, positions): all layers; the layer's index.
+            in_axes=(nn.broadcast, 0),
+            length=cfg.num_layers,
             metadata_params={nn.PARTITION_NAME: "stage"},
         )
-        x, _ = scanned(self.config, name="stack")(
-            x, (segment_ids, positions))
+        (x, carried), _ = scanned(cfg, name="stack")(
+            (x, {name: var.value for name, var in pools.items()}),
+            (segment_ids, positions), jnp.arange(cfg.num_layers))
+        for name, var in pools.items():
+            var.value = carried[name]
         return x
 
 

@@ -153,19 +153,20 @@ def per_shard(kernel, in_specs, out_specs):
 def paged_kv_gather_reference(pool, table, cache_len: int):
     """Pure-jax oracle: gather each lane's logical KV rows.
 
-    ``pool``: [num_blocks, block_size, kv_heads, head_dim] physical
-    rows; ``table``: [lanes, n_blk] int32 physical block per logical
-    block.  Returns [lanes, cache_len, kv_heads, head_dim] — lane b's
-    logical row p is ``pool[table[b, p // bs], p % bs]``.
+    ``pool``: [num_blocks, block_size, row] physical rows, as the cache
+    stores them (a K or V row is its ``kv_heads * head_dim`` values side
+    by side); ``table``: [lanes, n_blk] int32 physical block per logical
+    block.  Returns [lanes, cache_len, row] — lane b's logical row p is
+    ``pool[table[b, p // bs], p % bs]``.
     """
-    nb, bs, kvh, hd = pool.shape
+    nb, bs, row = pool.shape
     lanes = table.shape[0]
-    # Gather whole BLOCKS (lanes * n_blk indices, contiguous
-    # [bs, kvh, hd] slices each) rather than per-row (lanes * cache_len
-    # indices): same bytes, far less index math — XLA lowers this to
-    # slice copies, which keeps the paged read from taxing decode.
-    blocks = jnp.take(pool, table, axis=0)     # [lanes, n_blk, bs, ...]
-    return blocks.reshape(lanes, -1, kvh, hd)[:, :cache_len]
+    # Gather whole BLOCKS (lanes * n_blk indices, contiguous [bs, row]
+    # slices each) rather than per-row (lanes * cache_len indices):
+    # same bytes, far less index math — XLA lowers this to slice
+    # copies, which keeps the paged read from taxing decode.
+    blocks = jnp.take(pool, table, axis=0)     # [lanes, n_blk, bs, row]
+    return blocks.reshape(lanes, -1, row)[:, :cache_len]
 
 
 def _paged_gather_kernel(tbl_ref, pool_ref, out_ref):
@@ -178,16 +179,18 @@ def _paged_gather_kernel(tbl_ref, pool_ref, out_ref):
 def paged_kv_gather(pool, table, cache_len: int, *,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False):
-    """Block-table KV gather: [num_blocks, bs, kvh, hd] pool + [lanes,
-    n_blk] table → [lanes, cache_len, kvh, hd] per-lane linear view
-    (bit-identical to the reference: a gather moves bytes, no math)."""
+    """Block-table KV gather: [num_blocks, bs, row] pool + [lanes,
+    n_blk] table → [lanes, cache_len, row] per-lane linear view
+    (bit-identical to the reference: a gather moves bytes, no math).
+    The pool is taken as it lies, with no view of it: a pool that holds
+    several layers' blocks is read through a table of ids into the
+    whole of it."""
     if not _use_pallas(use_pallas) and not interpret:
         return paged_kv_gather_reference(pool, table, cache_len)
     from jax.experimental.pallas import tpu as pltpu
 
-    nb, bs, kvh, hd = pool.shape
+    nb, bs, row = pool.shape
     lanes, n_blk = table.shape
-    flat = pool.reshape(nb, bs, kvh * hd)
     out = pl.pallas_call(
         _paged_gather_kernel,
         name="paged_kv_gather",
@@ -195,17 +198,17 @@ def paged_kv_gather(pool, table, cache_len: int, *,
             num_scalar_prefetch=1,
             grid=(lanes, n_blk),
             in_specs=[
-                pl.BlockSpec((1, bs, kvh * hd),
+                pl.BlockSpec((1, bs, row),
                              lambda i, j, tbl: (tbl[i, j], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, bs, kvh * hd),
+            out_specs=pl.BlockSpec((1, bs, row),
                                    lambda i, j, tbl: (i, j, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, n_blk * bs, kvh * hd),
+        out_shape=jax.ShapeDtypeStruct((lanes, n_blk * bs, row),
                                        pool.dtype),
         interpret=interpret,
-    )(table, flat)
-    return out[:, :cache_len].reshape(lanes, cache_len, kvh, hd)
+    )(table, pool)
+    return out[:, :cache_len]
 
 
 # ---------------------------------------------------------------------------
@@ -241,31 +244,37 @@ def fused_attn_interpret() -> bool:
 
 def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
                               k_scales=None, v_scales=None,
-                              cache_len: Optional[int] = None):
+                              cache_len: Optional[int] = None,
+                              block0=0):
     """Pure-jax oracle: gather-then-attend, the exact math of the
     engine's XLA block-gather leg (``models.layers`` ``_cache_attend``
     minus the sharding constraints, which are numerically no-ops).
 
     ``q``: [lanes, q_len, heads, head_dim] (RoPE already applied);
-    ``k_pool``/``v_pool``: [num_blocks, block_size, kv_heads, head_dim]
-    (int8 when ``k_scales``/``v_scales`` [num_blocks, block_size,
-    kv_heads] are given — per-row symmetric dequant, the linear-cache
-    kv8 recipe); ``table``: [lanes, n_blk] int32; ``lengths``: [lanes]
-    int32, each lane's pre-call row count (query i sits at position
-    ``lengths[lane] + i`` and sees rows ``<=`` it).  Returns
-    [lanes, q_len, heads, head_dim]."""
+    ``k_pool``/``v_pool``: [blocks, block_size, kv_heads * head_dim],
+    the layout the cache stores and the kernel copies (int8 when
+    ``k_scales``/``v_scales`` [num_blocks, block_size, kv_heads] are
+    given — per-row symmetric dequant, the linear-cache kv8 recipe);
+    ``table``: [lanes, n_blk] int32; ``lengths``: [lanes] int32, each
+    lane's pre-call row count (query i sits at position ``lengths[lane]
+    + i`` and sees rows ``<=`` it).  ``block0``: where the table's
+    block 0 lies in the pools, for pools that hold several layers'
+    blocks one after the other (the scales are one layer's, numbered by
+    the table itself).  Returns [lanes, q_len, heads, head_dim]."""
     from tensorflow_train_distributed_tpu.ops.attention import (
         dot_product_attention,
     )
 
-    nb, bs, kvh, hd = k_pool.shape
-    lanes, q_len, heads, _ = q.shape
+    bs = k_pool.shape[1]
+    lanes, q_len, heads, hd = q.shape
+    kvh = k_pool.shape[2] // hd
     c = cache_len if cache_len is not None else table.shape[1] * bs
-    kc = paged_kv_gather_reference(k_pool, table, c)
-    vc = paged_kv_gather_reference(v_pool, table, c)
+    kc = paged_kv_gather_reference(k_pool, table + block0, c)
+    vc = paged_kv_gather_reference(v_pool, table + block0, c)
+    kc, vc = (t.reshape(lanes, c, kvh, hd) for t in (kc, vc))
     if k_scales is not None:
-        ks = paged_kv_gather_reference(k_scales[..., None], table, c)
-        vs = paged_kv_gather_reference(v_scales[..., None], table, c)
+        ks = paged_kv_gather_reference(k_scales, table, c)[..., None]
+        vs = paged_kv_gather_reference(v_scales, table, c)[..., None]
         kc = kc.astype(q.dtype) * ks.astype(q.dtype)
         vc = vc.astype(q.dtype) * vs.astype(q.dtype)
     if kvh != heads:
@@ -400,28 +409,35 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def paged_attention(q, k_pool, v_pool, table, lengths, *,
                     k_scales=None, v_scales=None,
-                    cache_len: Optional[int] = None,
+                    cache_len: Optional[int] = None, block0=0,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False):
     """Flash-style decode attention DIRECTLY through the block table —
     the dense per-lane KV view ``paged_kv_gather`` materializes never
     exists.  Arguments as ``paged_attention_reference`` (the pure-jax
-    oracle this is tested against; also the CPU path).  One grid step
-    is one lane, which reads the blocks its length reaches
-    (``paged_blocks_walked``) and no others, ``_paged_fold`` of them to
-    a copy-and-fold step: HBM reads are the lane's own rows once, and
-    the kernel's time follows what the lanes hold, not the table's
-    width.  An int8 pool's scales (4 bytes a row and KV head against
-    ``head_dim`` of them) are too narrow for a copy of their own: they
-    come in as one gathered [kv_heads, rows] strip a lane."""
+    oracle this is tested against; also the CPU path).  The pools come
+    as the cache stores them, [blocks, block_size, kv_heads *
+    head_dim], and go to the kernel as they lie: no view is taken, so
+    no copy of a pool is made on the way.  A pool may hold the blocks
+    of several layers one after the other (the depth scan's carried
+    pool); ``block0`` (a traced scalar there) is added to the table
+    the kernel's copies are steered by.  One grid step is one lane,
+    which reads the blocks its length reaches (``paged_blocks_walked``)
+    and no others, ``_paged_fold`` of them to a copy-and-fold step: HBM
+    reads are the lane's own rows once, and the kernel's time follows
+    what the lanes hold, not the table's width.  An int8 pool's scales
+    (4 bytes a row and KV head against ``head_dim`` of them) are too
+    narrow for a copy of their own: they come in as one gathered
+    [kv_heads, rows] strip a lane."""
     if not _use_pallas(use_pallas) and not interpret:
         return paged_attention_reference(
             q, k_pool, v_pool, table, lengths, k_scales=k_scales,
-            v_scales=v_scales, cache_len=cache_len)
+            v_scales=v_scales, cache_len=cache_len, block0=block0)
     from jax.experimental.pallas import tpu as pltpu
 
-    nb, bs, kvh, hd = k_pool.shape
-    lanes, q_len, heads, _ = q.shape
+    bs = k_pool.shape[1]
+    lanes, q_len, heads, hd = q.shape
+    kvh = k_pool.shape[2] // hd
     n_blk = table.shape[1]
     if heads % kvh:
         raise ValueError(f"heads {heads} not a multiple of kv_heads "
@@ -436,9 +452,7 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
     rows = pl.BlockSpec((1, heads * q_len, hd),
                         lambda i, tbl, lens: (i, 0, 0))
     in_specs = [rows] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
-    args = [table, lengths.astype(jnp.int32), qt,
-            k_pool.reshape(nb, bs, kvh * hd),
-            v_pool.reshape(nb, bs, kvh * hd)]
+    args = [table + block0, lengths.astype(jnp.int32), qt, k_pool, v_pool]
     if int8:
         # The strip is as wide as whole steps of the walk, so a step's
         # slice of it never runs off the end.

@@ -261,17 +261,21 @@ def _paged_killed() -> bool:
 
 
 #: Row-holding cache leaves: the paged pool's name -> (the batch-1
-#: linear cache's name, dims a row has).  A linear leaf is
-#: [..., B, C, *row], a pool leaf [..., blocks, block_size, *row]
-#: (leading axes: a layer axis under scan_layers; the int8 scales' 2).
-#: Per-head K and V rows are [kv_heads, head_dim], their int8 scales
-#: [kv_heads], a latent-attention row [row] (layers.LatentAttention).
-_ROW_LEAVES = {"key_pool": ("key_cache", 2),
-               "value_pool": ("value_cache", 2),
-               "kv_pool_scales": ("kv_scales", 1),
-               "latent_pool": ("latent_cache", 1)}
-_LINEAR_ROW_DIMS = {lin: dims for lin, dims in _ROW_LEAVES.values()}
-_POOL_OF = {lin: pool for pool, (lin, _) in _ROW_LEAVES.items()}
+#: linear cache's name, dims a row has in the pool, dims it has in the
+#: linear cache).  A linear leaf is [..., B, C, *row], a pool leaf
+#: [..., blocks, block_size, *row] (leading axes: a layer axis under
+#: scan_layers; the int8 scales' 2).  A pool stores a per-head K or V
+#: row as the attention kernel copies it, its kv_heads * head_dim
+#: values side by side (``layers.paged_pool_leaves``), where the linear
+#: cache keeps [kv_heads, head_dim]: the same values in the same order.
+#: Their int8 scales are [kv_heads] on both sides, a latent-attention
+#: row [row] (``layers.LatentAttention``).
+_ROW_LEAVES = {"key_pool": ("key_cache", 1, 2),
+               "value_pool": ("value_cache", 1, 2),
+               "kv_pool_scales": ("kv_scales", 1, 1),
+               "latent_pool": ("latent_cache", 1, 1)}
+_LINEAR_ROW_DIMS = {lin: dims for lin, _, dims in _ROW_LEAVES.values()}
+_POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 
 
 #: What an ``engine/step`` span says its step did: lanes active at the
@@ -1022,47 +1026,80 @@ class ServingEngine:
     def _path_key(path) -> tuple:
         return tuple(getattr(k, "key", str(k)) for k in path)
 
-    def _lane_dest_rows(self, table_row, start, end):
-        """Physical pool row per logical position in [start, end);
-        positions outside map out of range (nb*bs) so scatters DROP
-        them — the shared-block copy-on-write guard (rows before
-        ``start`` belong to radix-shared blocks this lane must never
-        write)."""
-        bs = self.kv_block_size
-        nb = 1 + self._kv_pool.n_blocks
-        pos = jnp.arange(self.cache_len)
-        phys = table_row[jnp.clip(pos // bs, 0, self._kv_nblk_lane - 1)]
-        return jnp.where((pos >= start) & (pos < end),
-                         phys * bs + pos % bs, nb * bs)
+    @classmethod
+    def _paired_leaves(cls, pooled, linear) -> list:
+        """[(pool leaf's path key, linear leaf's path key)] for every
+        row-holding leaf of a paged cache tree and the
+        batch-1 LINEAR tree of the same model.  A pool pairs with the
+        linear leaf of its name (``_ROW_LEAVES``) below the module that
+        holds the pool: the attention module itself where the layers
+        are unrolled, the depth scan where it carries every layer's
+        pools as one leaf (``llama._ScannedBlock``; the linear cache
+        stays a scanned leaf of the attention below it, with the same
+        leading layer axis)."""
+        lin_keys = [cls._path_key(p) for p, _ in
+                    jax.tree_util.tree_flatten_with_path(linear)[0]]
+        pairs = []
+        for p, _ in jax.tree_util.tree_flatten_with_path(pooled)[0]:
+            key = cls._path_key(p)
+            if key[-1] not in _ROW_LEAVES:
+                continue
+            held = [k for k in lin_keys
+                    if k[-1] == _ROW_LEAVES[key[-1]][0]
+                    and k[:len(key) - 1] == key[:-1]]
+            if len(held) != 1:
+                raise ValueError(
+                    f"pool leaf {key} pairs with {len(held)} linear "
+                    f"cache leaves, not one: {held}")
+            pairs.append((key, held[0]))
+        return pairs
 
     def _scatter_rows_tree(self, cache, cache_1, table_row, start, end):
-        """Scatter the batch-1 LINEAR cache's rows [start, end) into the
+        """Put the batch-1 LINEAR cache's rows [start, end) into the
         paged pool at ``table_row``'s blocks (traced helper shared by
-        insert and preload; leaves pair by module path — only the leaf
-        names differ between the two cache layouts).  int8 configs
-        carry the per-row scales along the same row map: the pool
+        insert and preload; leaves pair by ``_paired_leaves``).  The
+        pool is written a BLOCK at a time, whole tiles of it where it
+        lies (a scatter of single rows under a leading layer axis made
+        the compiler move the whole pool to another layout and back:
+        compile, PR 29): a block with no row in [start, end) is sent
+        out of range and DROPPED — the shared-block copy-on-write
+        guard (rows before ``start`` belong to radix-shared blocks this
+        lane must never write) — and in a block the span covers in
+        part, the rows outside it keep the bytes they had.  int8
+        configs carry the per-row scales along the same map: the pool
         stores exactly the bytes the batch-1 prefill quantized, which
         is what keeps int8 paged parity bitwise."""
-        dest = self._lane_dest_rows(table_row, start, end)
+        bs, n_blk = self.kv_block_size, self._kv_nblk_lane
+        pos = jnp.arange(n_blk * bs).reshape(n_blk, bs)
+        live = (pos >= start) & (pos < end)
+        blocks = jnp.where(live.any(axis=1), table_row,
+                           1 + self._kv_pool.n_blocks)
         flat_1 = {self._path_key(p): leaf for p, leaf
                   in jax.tree_util.tree_flatten_with_path(cache_1)[0]}
+        source = dict(self._paired_leaves(cache, cache_1))
 
         def scatter(path, leaf):
-            name = getattr(path[-1], "key", "")
-            if name not in _ROW_LEAVES:
+            key = self._path_key(path)
+            if key not in source:
                 return leaf
-            linear, row_dims = _ROW_LEAVES[name]
-            # [..., 1, C, *row] → rows of the flattened
-            # [..., nb*bs, *row] pool.
-            src = jnp.squeeze(                     # drop the batch-1 dim
-                flat_1[self._path_key(path[:-1]) + (linear,)],
-                axis=-(2 + row_dims))
-            n_lead = leaf.ndim - (2 + row_dims)    # dims before (nb, bs)
-            flat = leaf.reshape(leaf.shape[:n_lead] + (-1,)
-                                + leaf.shape[leaf.ndim - row_dims:])
-            idx = (slice(None),) * n_lead + (dest,)
-            flat = flat.at[idx].set(src.astype(flat.dtype), mode="drop")
-            return flat.reshape(leaf.shape)
+            _, row_dims, lin_row_dims = _ROW_LEAVES[key[-1]]
+            axis = leaf.ndim - (2 + row_dims)      # the pool's blocks
+            # [..., 1, C, *row] → [..., n_blk, bs, *the pool's row]:
+            # drop the batch-1 dim, lay a row's values side by side,
+            # cut the rows into blocks.
+            src = jnp.squeeze(flat_1[source[key]],
+                              axis=-(2 + lin_row_dims))
+            src = src.reshape(src.shape[:axis + 1] + leaf.shape[axis + 2:])
+            tail = [(0, 0)] * src.ndim
+            tail[axis] = (0, n_blk * bs - self.cache_len)
+            src = jnp.pad(src, tail)
+            src = src.reshape(leaf.shape[:axis] + (n_blk,)
+                              + leaf.shape[axis + 1:])
+            had = jnp.take(leaf, blocks, axis=axis, mode="clip")
+            new = jnp.where(live.reshape(live.shape + (1,) * row_dims),
+                            src.astype(leaf.dtype), had)
+            return leaf.at[(slice(None),) * axis + (blocks,)].set(
+                new, mode="drop")
 
         return jax.tree_util.tree_map_with_path(scatter, cache)
 
@@ -1112,27 +1149,28 @@ class ServingEngine:
         Rows past ``matched`` gather whatever the lane's owned blocks
         hold — garbage the write-before-read prefill rule keeps
         invisible, exactly like the linear cache's stale rows."""
-        bs = self.kv_block_size
-        pos = jnp.arange(self.cache_len)
-        rows = (table_row[jnp.clip(pos // bs, 0, self._kv_nblk_lane - 1)]
-                * bs + pos % bs)
         pools = {self._path_key(p): leaf for p, leaf
                  in jax.tree_util.tree_flatten_with_path(cache)[0]}
         struct = self._cache_struct(1, draft=draft)
+        source = {lin: pool for pool, lin
+                  in self._paired_leaves(cache, struct)}
 
         def build(path, s):
             name = getattr(path[-1], "key", "")
             if name == "index":
                 return jnp.full(s.shape, matched, s.dtype)
-            src = pools[self._path_key(path[:-1]) + (_POOL_OF[name],)]
+            src = pools[source[self._path_key(path)]]
             # Pool [..., nb, bs, *row] → batch-1 [..., 1, C, *row]: the
-            # same row map, the batch dim re-inserted before the rows.
-            row_dims = _LINEAR_ROW_DIMS[name]
-            n_lead = src.ndim - (2 + row_dims)
-            flat = src.reshape(src.shape[:n_lead] + (-1,)
-                               + src.shape[src.ndim - row_dims:])
-            take = jnp.take(flat, rows, axis=n_lead)
-            return jnp.expand_dims(take, axis=n_lead).astype(s.dtype)
+            # lane's blocks, whole, in its table's order; their rows
+            # are its logical rows (the linear cache's own row dims:
+            # the same values), the batch dim re-inserted before them.
+            axis = src.ndim - (2 + _ROW_LEAVES[_POOL_OF[name]][1])
+            take = jnp.take(src, table_row, axis=axis)
+            take = take.reshape(take.shape[:axis] + (-1,)
+                                + take.shape[axis + 2:])
+            take = jax.lax.slice_in_dim(take, 0, self.cache_len, axis=axis)
+            return jnp.expand_dims(take, axis=axis).reshape(
+                s.shape).astype(s.dtype)
 
         return jax.tree_util.tree_map_with_path(build, struct)
 

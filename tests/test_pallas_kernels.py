@@ -144,9 +144,9 @@ class TestPagedAttention:
     def _mk(lanes, q_len, heads, kvh, hd=8, nb=9, bs=4, n_blk=5,
             seed=0, lengths=None):
         rng = np.random.default_rng(seed)
-        kp = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)).astype(
+        kp = jnp.asarray(rng.normal(size=(nb, bs, kvh * hd)).astype(
             np.float32))
-        vp = jnp.asarray(rng.normal(size=(nb, bs, kvh, hd)).astype(
+        vp = jnp.asarray(rng.normal(size=(nb, bs, kvh * hd)).astype(
             np.float32))
         table = jnp.asarray(rng.integers(0, nb, (lanes, n_blk)).astype(
             np.int32))
@@ -222,9 +222,9 @@ class TestPagedAttention:
         q, _, _, table, lengths = self._mk(3, 2, 4, kvh, hd=hd, nb=nb,
                                            bs=bs, seed=3)
         kp = jnp.asarray(rng.integers(-127, 128,
-                                      (nb, bs, kvh, hd)).astype(np.int8))
+                                      (nb, bs, kvh * hd)).astype(np.int8))
         vp = jnp.asarray(rng.integers(-127, 128,
-                                      (nb, bs, kvh, hd)).astype(np.int8))
+                                      (nb, bs, kvh * hd)).astype(np.int8))
         ks = jnp.asarray((np.abs(rng.normal(size=(nb, bs, kvh)))
                           .astype(np.float32) / 127.0) + 1e-3)
         vs = jnp.asarray((np.abs(rng.normal(size=(nb, bs, kvh)))
@@ -267,14 +267,14 @@ class TestPagedAttention:
         # a lane's length does not reach belongs to no one else either.
         table = jnp.asarray(
             1 + np.arange(lanes * n_blk).reshape(lanes, n_blk), jnp.int32)
-        shape = (nb, bs, kvh, hd)
+        shape = (nb, bs, kvh * hd)     # a row as the cache stores it
         scales = {}
         if int8:
             kp, vp = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
                       for _ in range(2))
             scales = {
-                name: jnp.asarray(np.abs(rng.normal(size=shape[:3])) / 127
-                                  + 1e-3, jnp.float32)
+                name: jnp.asarray(np.abs(rng.normal(size=(nb, bs, kvh)))
+                                  / 127 + 1e-3, jnp.float32)
                 for name in ("k_scales", "v_scales")}
         else:
             kp, vp = (jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -332,6 +332,35 @@ class TestPagedAttention:
         np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
 
 
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["reference", "kernel"])
+def test_block0_reads_one_layer_of_a_pool_that_holds_several(int8,
+                                                             interpret):
+    """The depth scan carries every layer's blocks in one pool, one
+    layer after the other: read through ``block0 = layer * blocks`` the
+    whole pool gives, to the bit, what the layer's own blocks give
+    alone (the scales are the layer's, numbered by the table itself)."""
+    layers, (q_len, heads, kvh) = 3, (2, 4, 2)
+    cases = [TestPagedAttention._walk_case(q_len, heads, kvh, int8,
+                                           seed=layer)
+             for layer in range(layers)]
+    q, _, _, table, lengths, _ = cases[0]
+    nb = cases[0][1].shape[0]
+    k_all, v_all = (jnp.concatenate([c[i] for c in cases])
+                    for i in (1, 2))
+    kw = dict(use_pallas=True, interpret=True) if interpret else {}
+    for layer, (_, kp, vp, _, _, scales) in enumerate(cases):
+        alone = pk.paged_attention(q, kp, vp, table, lengths, **scales,
+                                   **kw)
+        stacked = pk.paged_attention(
+            q, k_all, v_all, table, lengths, **scales,
+            block0=jnp.int32(layer * nb), **kw)
+        assert np.all(np.isfinite(np.asarray(alone)))
+        np.testing.assert_array_equal(np.asarray(stacked),
+                                      np.asarray(alone))
+
+
 def test_fused_attn_kill_switches(monkeypatch):
     """TTD_NO_FUSED_ATTN wins over everything (the production kill
     switch back to the XLA block-gather leg); TTD_FUSED_ATTN_INTERPRET
@@ -365,27 +394,26 @@ class TestPagedKvGather:
     def test_kernel_matches_reference(self, cache_len):
         rng = np.random.default_rng(0)
         pool = jnp.asarray(
-            rng.normal(size=(9, 4, 2, 8)).astype(np.float32))
+            rng.normal(size=(9, 4, 16)).astype(np.float32))
         table = jnp.asarray(
             rng.integers(0, 9, (3, 4)).astype(np.int32))
         ref = pk.paged_kv_gather_reference(pool, table, cache_len)
         out = pk.paged_kv_gather(pool, table, cache_len, interpret=True)
-        assert out.shape == (3, cache_len, 2, 8)
+        assert out.shape == (3, cache_len, 16)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
 
     def test_reference_row_semantics(self):
         # Lane b's logical row p must be pool[table[b, p//bs], p%bs].
-        pool = jnp.arange(6 * 2 * 1 * 1, dtype=jnp.float32).reshape(
-            6, 2, 1, 1)
+        pool = jnp.arange(6 * 2 * 1, dtype=jnp.float32).reshape(6, 2, 1)
         table = jnp.asarray([[3, 1, 0]], jnp.int32)
         out = np.asarray(
-            pk.paged_kv_gather_reference(pool, table, 6))[0, :, 0, 0]
+            pk.paged_kv_gather_reference(pool, table, 6))[0, :, 0]
         assert out.tolist() == [6.0, 7.0, 2.0, 3.0, 0.0, 1.0]
 
     def test_cpu_path_uses_reference(self):
         # On this CPU backend the public entry must route to the
         # reference (no pallas lowering attempted).
-        pool = jnp.zeros((3, 2, 1, 1))
+        pool = jnp.zeros((3, 2, 1))
         table = jnp.zeros((1, 2), jnp.int32)
         out = pk.paged_kv_gather(pool, table, 4)
-        assert out.shape == (1, 4, 1, 1)
+        assert out.shape == (1, 4, 1)

@@ -28,7 +28,10 @@ from tensorflow_train_distributed_tpu.models.llama import (
     LLAMA_PRESETS,
     LlamaModel,
 )
-from tensorflow_train_distributed_tpu.serving import ServingEngine
+from tensorflow_train_distributed_tpu.serving import (
+    _LINEAR_ROW_DIMS,
+    ServingEngine,
+)
 
 CFG = LLAMA_PRESETS["llama_tiny"]
 
@@ -310,6 +313,215 @@ def test_int8_paged_engine_smoke_matches_generate(params):
     assert eng.kv_pool_bytes() < eng_fp.kv_pool_bytes()
 
 
+# ── the depth scan's carried pools against a pool a layer and the ────
+# ── linear cache, program by program ─────────────────────────────────
+
+SCFG = dataclasses.replace(CFG, scan_layers=True)
+
+
+def _stacked(params):
+    """Unscanned parameters in the depth scan's layout: the same
+    weights, so the two programs must agree to the bit."""
+    import flax.linen as nn
+
+    params = nn.meta.unbox(params)
+    layers = [params[f"layer_{i}"] for i in range(CFG.num_layers)]
+    rest = {k: v for k, v in params.items() if not k.startswith("layer_")}
+    return dict(rest, layers={"stack": {"block": jax.tree.map(
+        lambda *a: jnp.stack(a), *layers)}})
+
+
+def _row_leaves(cache, scanned):
+    """name -> [layers, ...] array of a cache tree's row-holding
+    leaves, whichever module holds them: the depth scan's one leaf a
+    pool, or a leaf a layer stacked here."""
+    flat = {ServingEngine._path_key(p): np.asarray(leaf) for p, leaf
+            in jax.tree_util.tree_flatten_with_path(cache)[0]}
+    names = {k[-1] for k in flat} - {"index", "block_table"}
+    if scanned:
+        return {n: next(v for k, v in flat.items() if k[-1] == n)
+                for n in names}
+    return {n: np.stack([flat[(f"layer_{i}", "attention", n)]
+                         for i in range(CFG.num_layers)]) for n in names}
+
+
+class _Lanes:
+    """Four lanes set up by hand through the engine's own programs
+    (prefill pieces -> ``_paged_insert`` / ``_insert``), so that every
+    rule of the pool's write is on a lane of its own:
+
+    0. an ordinary lane (11 rows in blocks 1..8);
+    1. a lane whose first two blocks are lane 0's (a radix-shared
+       prefix: inserted from row 8 on, and the rows below it in its
+       batch-1 cache POISONED, so a write there would show);
+    2. a lane never inserted: table all scratch, index 0;
+    3. a lane two rows short of its table's end, which overruns it in
+       the steps that follow (rows dropped).
+    """
+
+    C, BS = 32, 4
+    TABLES = {0: list(range(1, 9)), 1: [1, 2] + list(range(9, 15)),
+              3: list(range(15, 23))}
+    START = {0: 0, 1: 8, 3: 0}
+
+    def __init__(self, cfg, params, *, paged, q_len):
+        rng = np.random.default_rng(11)
+        a = list(rng.integers(1, 200, 11))
+        self.prompts = {0: a, 1: a[:8] + list(rng.integers(1, 200, 3)),
+                        3: list(rng.integers(1, 200, 30))}
+        kw = dict(slots=4, cache_len=self.C, chunk=2,
+                  prompt_buckets=(16,), kv_block_size=self.BS,
+                  paged=paged)
+        self.q_len, self.spec = q_len, q_len > 1
+        if self.spec:
+            kw.update(draft_config=cfg, draft_params=params,
+                      speculative_k=q_len - 1)
+        self.eng = eng = ServingEngine(cfg, params, **kw)
+        self.caches = [eng._fresh_cache(4, grid=True)]
+        if self.spec:
+            self.caches.append(eng._fresh_cache(4, draft=True, grid=True))
+        tok = np.zeros(4, np.int32)
+        for slot, prompt in self.prompts.items():
+            for which, draft in enumerate([False, True][:len(self.caches)]):
+                cache_1, first = eng._prefill_tokens(
+                    prompt, seed=0,
+                    cache_1=eng._fresh_cache(1, draft=draft), draft=draft)
+                if not draft:
+                    tok[slot] = int(first)
+                if paged:
+                    cache_1 = self._poisoned(cache_1, self.START[slot])
+                    self.caches[which] = eng._paged_insert(
+                        self.caches[which], cache_1, jnp.int32(slot),
+                        jnp.asarray(self.TABLES[slot], jnp.int32),
+                        jnp.int32(self.START[slot]),
+                        jnp.int32(len(prompt)))
+                else:
+                    self.caches[which] = eng._insert(
+                        self.caches[which], cache_1, jnp.int32(slot),
+                        jnp.int32(len(prompt)))
+        self.tok = jnp.asarray(tok)
+        self.emitted = []
+
+    @staticmethod
+    def _poisoned(cache_1, start):
+        """Rows below ``start`` overwritten: they are another lane's
+        (shared) blocks, and an insert that wrote them would be seen."""
+        def poison(path, leaf):
+            name = getattr(path[-1], "key", "")
+            if name == "index" or not start:
+                return leaf
+            axis = leaf.ndim - (1 + _LINEAR_ROW_DIMS[name])
+            idx = [slice(None)] * leaf.ndim
+            idx[axis] = slice(0, start)
+            return leaf.at[tuple(idx)].set(77)
+
+        return jax.tree_util.tree_map_with_path(poison, cache_1)
+
+    def step(self):
+        """One decode chunk (two steps of one row a lane) or one
+        speculative round (a block of ``q_len`` rows a lane)."""
+        eng = self.eng
+        seeds = jnp.zeros(4, jnp.uint32)
+        counts = jnp.zeros(4, jnp.int32)
+        if self.spec:
+            (self.caches[0], self.caches[1], emit, emitted, self.tok, _,
+             _) = eng._spec_round(
+                eng._variables, eng._draft_variables, self.caches[0],
+                self.caches[1], self.tok, seeds, counts,
+                self.q_len - 1)
+            self.emitted.append((np.asarray(emit), np.asarray(emitted)))
+        else:
+            self.caches[0], toks, self.tok, _, _ = eng._decode_chunk(
+                eng._variables, self.caches[0], self.tok, seeds, counts)
+            self.emitted.append((np.asarray(toks),))
+
+    def lengths(self):
+        return np.asarray(next(
+            leaf for p, leaf in jax.tree_util.tree_flatten_with_path(
+                self.caches[0])[0]
+            if p[-1].key == "index")).reshape(-1, 4)[0]
+
+
+@pytest.mark.parametrize("leg", ["gather", "kernel"])
+@pytest.mark.parametrize("q_len", [1, 4], ids=["q1", "q4"])
+@pytest.mark.parametrize("cfg", [CFG, ICFG], ids=["f32", "int8"])
+def test_carried_pools_match_a_pool_a_layer_and_the_linear_cache(
+        params, cfg, q_len, leg, monkeypatch):
+    """The depth scan CARRIES the pools (one [layers, blocks, ...] leaf
+    a pool, written in place); an unrolled model holds a pool a layer.
+    With the same weights the two serve the same tokens and leave the
+    same bytes in every block, through ``_paged_insert`` -> decode
+    chunks (``q_len`` 1) or speculative rounds (``q_len`` 4, target and
+    draft) -> ``_gather_prefix``, and both agree with the linear cache
+    on the tokens and on every row a lane holds.  Lanes: ``_Lanes``.
+    ``kernel``: the same under the fused attention kernel, interpreted,
+    which reads the carried pool through ``block0`` (its online softmax
+    is not the linear cache's to the bit, so the paged two are
+    compared and the linear engine's tokens only)."""
+    if leg == "kernel":
+        monkeypatch.setenv("TTD_FUSED_ATTN_INTERPRET", "1")
+    scfg = dataclasses.replace(cfg, scan_layers=True)
+    carried = _Lanes(scfg, _stacked(params), paged=True, q_len=q_len)
+    layered = _Lanes(cfg, params, paged=True, q_len=q_len)
+    linear = _Lanes(cfg, params, paged=False, q_len=q_len)
+    assert carried.eng.fused_attn() is (leg == "kernel")
+    shared = {}
+    for which, cache in enumerate(carried.caches):
+        pools = _row_leaves(cache, scanned=True)
+        assert pools["key_pool"].shape == (
+            CFG.num_layers, 33, 4, CFG.num_kv_heads * 16)
+        shared[which] = {n: v[..., 1:3, :, :].copy()
+                         for n, v in pools.items()}
+    np.testing.assert_array_equal(carried.tok, layered.tok)
+    np.testing.assert_array_equal(carried.tok, linear.tok)
+    for _ in range(2):
+        for lanes in (carried, layered, linear):
+            lanes.step()
+    held = [0, 1, 3]             # lane 2 reads scratch: garbage, its own
+    for got, want, lin in zip(carried.emitted, layered.emitted,
+                              linear.emitted):
+        for g, w, l in zip(got, want, lin):
+            np.testing.assert_array_equal(g, w)
+            np.testing.assert_array_equal(g[held], l[held])
+    lengths = carried.lengths()
+    np.testing.assert_array_equal(lengths, layered.lengths())
+    np.testing.assert_array_equal(lengths[held], linear.lengths()[held])
+    assert lengths[3] > _Lanes.C                      # lane 3 overran
+    for which, (c_cache, l_cache, lin_cache) in enumerate(zip(
+            carried.caches, layered.caches, linear.caches)):
+        c_pools = _row_leaves(c_cache, scanned=True)
+        l_pools = _row_leaves(l_cache, scanned=False)
+        assert set(c_pools) == set(l_pools) == (
+            {"key_pool", "value_pool"}
+            | ({"kv_pool_scales"} if cfg.kv_cache_int8 else set()))
+        for name, pool in c_pools.items():
+            # every block but the scratch block, which takes whatever
+            # the idle and the reset lanes write
+            np.testing.assert_array_equal(pool[..., 1:, :, :],
+                                          l_pools[name][..., 1:, :, :])
+            # the shared blocks: lane 1's insert wrote nothing there
+            np.testing.assert_array_equal(pool[..., 1:3, :, :],
+                                          shared[which][name])
+        lin_rows = _row_leaves(lin_cache, scanned=False)
+        for slot in held if leg == "gather" else ():
+            n = min(int(lengths[slot]), _Lanes.C)
+            table = jnp.asarray(_Lanes.TABLES[slot], jnp.int32)
+            for lanes, cache, scanned in ((carried, c_cache, True),
+                                          (layered, l_cache, False)):
+                rows = _row_leaves(lanes.eng._gather_prefix(
+                    cache, table, bool(which), jnp.int32(n)), scanned)
+                for name, lin in lin_rows.items():
+                    # [layers, (2,) batch, C, *row]: the lane's rows
+                    batch = lin.ndim - (2 + _LINEAR_ROW_DIMS[name])
+
+                    def lane_rows(leaf, lane):
+                        return np.take(np.take(leaf, lane, axis=batch),
+                                       range(n), axis=batch)
+
+                    np.testing.assert_array_equal(
+                        lane_rows(rows[name], 0), lane_rows(lin, slot))
+
+
 # ── slow tier: the full parity matrix ──────────────────────────────────
 
 pytestmark_slow = pytest.mark.slow
@@ -447,7 +659,7 @@ def test_copy_on_write_divergence_after_shared_prefix(params):
             name = getattr(path[-1], "key", "")
             if name in ("key_pool", "value_pool"):
                 rows[ServingEngine._path_key(path)] = np.asarray(
-                    jnp.take(leaf, idx, axis=leaf.ndim - 4))
+                    jnp.take(leaf, idx, axis=leaf.ndim - 3))
         return rows
 
     before = pool_rows(shared)
