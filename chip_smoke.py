@@ -9,7 +9,7 @@ One process holds the chip for the whole run and drives the system
 through the entry points a user calls: ``launch.main`` trains
 ``llama_350m_lm`` exactly as registered and saves one checkpoint;
 ``tools/serve_http.py``'s gateway serves that checkpoint over HTTP with
-every default on (paged KV, fused paged attention, overlap, interleave).
+every default on (paged KV, fused paged attention).
 Nothing here may fall back: the TPU platform is forced, an unknown
 ``device_kind`` is an error, every kernel must appear as a
 ``tpu_custom_call`` in its lowered program, and any phase that raises
@@ -470,11 +470,9 @@ def phase_serve(opts, ckpt_dir: str) -> dict:
     gw = serve_http.build_gateway(args, cfg, is_moe, [])
     eng = gw.engine
     out = {"paged": eng.paged, "fused_attn": eng._fused_attn,
-           "overlap": eng.overlap, "interleave": eng.interleave,
            "kv_pool_gib": round(eng._kv_pool_bytes / 2**30, 3)}
     want_fused = not opts.rehearse_cpu
-    if not (eng.paged and eng.overlap and eng.interleave
-            and eng._fused_attn == want_fused):
+    if not (eng.paged and eng._fused_attn == want_fused):
         raise AssertionError(f"serving defaults are not all on: {out}")
     gw.start()
     try:

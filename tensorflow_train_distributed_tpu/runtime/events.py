@@ -16,7 +16,7 @@ default and designed to stay on in production:
   65536): old events fall off the back, memory is O(capacity) forever;
 - ``TTD_NO_TRACE=1`` is the kill switch: ``span()`` degrades to a
   shared no-op context manager and ``instant()`` to one dict lookup —
-  an env flip, no redeploy (the ``TTD_NO_OVERLAP`` contract).
+  an env flip, no redeploy.
 - ``TTD_TRACE_SPOOL=<dir>`` (off by default) adds the crash-durable
   layer: a flusher thread mirrors the ring into size-capped rotating
   JSONL segments (``TTD_TRACE_SPOOL_BYTES``, default 64 MiB/process),

@@ -72,8 +72,7 @@ MAX_BODY_BYTES = 1 << 20          # requests are token-id lists; 1 MiB
 def _failover_killed() -> bool:
     """``TTD_NO_FAILOVER=1`` restores the single-engine gateway
     byte-for-byte (only the FIRST engine of a multi-engine list is
-    used) — the same no-redeploy kill-switch contract as
-    ``TTD_NO_OVERLAP`` and friends."""
+    used): an env flip, no redeploy of callers."""
     return os.environ.get("TTD_NO_FAILOVER", "0") not in ("", "0")
 
 

@@ -409,25 +409,23 @@ class GatewayMetrics:
             "Serialized KV bytes (int8 pool rows + scales) shipped in "
             "successful lane migrations.")
         # Fraction of the engine's host harvest/refill time hidden
-        # under device compute by async decode pipelining — the
-        # driver-visible proof the overlap path engages (0 under the
-        # TTD_NO_OVERLAP kill switch, or for engines without the
-        # lookahead, e.g. test stubs).
+        # under device compute: the share of harvests that ran with a
+        # successor chunk already dispatched (below 1 by the
+        # harvest-first steps at batch tails; 0 for engines without
+        # the lookahead, e.g. test stubs).
         self.engine_overlap_ratio = r.gauge(
             "ttd_engine_overlap_ratio",
             "Host harvest time overlapped with device decode, as a "
-            "fraction of total harvest time (0 = synchronous path).",
+            "fraction of total harvest time.",
             fn=overlap_ratio_fn)
         # Cumulative head-of-line admission time: seconds decode lanes
-        # spent blocked behind a new prompt's prefill.  Grows with
-        # every long admission under atomic admission
-        # (prefill_budget=0 / TTD_NO_INTERLEAVE=1); collapses to ~0
-        # with the engine's interleaved prefill scheduler on — the
-        # driver-visible proof the scheduler engages.
+        # spent blocked behind a new prompt's first-token read with no
+        # decode chunk in flight.  The engine's step always admits
+        # behind a chunk, so a ServingEngine reports 0.
         self.engine_prefill_stall = r.gauge(
             "ttd_engine_prefill_stall_seconds",
             "Cumulative seconds decode lanes spent stalled behind "
-            "admission prefill (~0 with interleaved prefill on).",
+            "admission prefill with no decode chunk in flight.",
             fn=prefill_stall_fn)
         # Paged-KV cache economics (serving.ServingEngine paged mode;
         # all four scrape 0 for linear-cache engines and test stubs —

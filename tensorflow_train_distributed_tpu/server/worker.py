@@ -236,7 +236,7 @@ def _factory_stub(spec: dict):
 #: in-process reference engine stay configured identically).
 _LLAMA_ENGINE_KWARGS = (
     "slots", "cache_len", "chunk", "temperature", "top_k", "top_p",
-    "prefill_chunk", "prefill_budget", "overlap", "paged",
+    "prefill_chunk", "prefill_budget", "paged",
     "kv_block_size", "kv_pool_blocks", "prefix_cache_limit",
     "hbm_budget_bytes", "hbm_headroom", "spec_depths",
 )

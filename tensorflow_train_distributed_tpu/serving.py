@@ -1,122 +1,93 @@
 """Continuous-batching serving engine (slot-refill decode).
 
-Beyond the reference (a training harness — SURVEY.md §2.1: its SFT
-config produces a model users sample from elsewhere): an online serving
-loop in the JetStream/Orca style, TPU-first throughout.  ``generate()``
-(models/generate.py) serves one static batch: every request waits for
-the slowest.  This engine keeps ``slots`` requests in flight over ONE
-static-shaped decode program:
+``generate()`` (models/generate.py) serves one static batch: every
+request waits for the slowest.  ``ServingEngine`` keeps ``slots``
+requests in flight over ONE static-shaped decode program and refills a
+lane the moment its request finishes (JetStream / Orca style).  Slots
+change *when* work happens, never the math: per-slot positions give a
+request the RoPE/mask view it would have alone, so greedy output equals
+``generate()``'s token for token, and a sampled request draws from its
+own rng stream (seeded at submit) wherever it lands.
 
-- **prefill** runs each arriving prompt alone (batch 1; bucketed
-  lengths so a handful of compiles cover every prompt, or
-  ``prefill_chunk`` for ONE per-piece program at any prompt length),
-  producing that request's per-layer KV rows and first token;
-- **insert** copies those rows into a free slot of the big [slots,
-  cache_len] cache and pins the slot's per-slot position (the
-  ``slot_decode`` cache keeps a VECTOR index — each slot advances from
-  its own length; ``layers.MultiHeadAttention._slot_decode_step``);
-- **decode chunks** step all slots together ``chunk`` tokens at a time
-  (one fetch per chunk, not per token — every fetch is a host round
-  trip); the host harvests finished requests (EOS or
-  budget) between chunks and refills their slots from the queue.
+**A lane's state.**  A slot is free, STAGED or DECODING.
 
-**Async decode pipelining** (the static-batch decode step measured
-host-bound on v5e, 2026-07-31: llama_125m 2.13 ms/step vs a 0.38 ms weight-streaming
-roofline): by default ``serve_step`` runs with ONE-CHUNK LOOKAHEAD —
-the per-slot carry (next token, rng counters) stays device-resident,
-chunk N+1 is dispatched from those device arrays the moment chunk N is
-in flight (JAX async dispatch: enqueueing needs no sync), and chunk N's
-host copy is harvested — stop detection, streaming, refills — while the
-device computes N+1.  Stop/refill decisions therefore LAG ONE CHUNK: a
-slot whose request finished in chunk N keeps decoding garbage through
-chunk N+1; the harvest records which request occupied each slot at
-dispatch time and trims anything stale, so outputs are bitwise-identical
-to the synchronous path (greedy, seeded sampling, and speculative —
-per-slot seed/count streams are deterministic under trimming).
-``TTD_NO_OVERLAP=1`` (or ``overlap=False`` / the CLIs' ``--no-overlap``)
-is the kill switch back to the synchronous path.
+- Staged (``_PrefillTask`` in ``_staging``): the slot is reserved
+  while the request's batch-1 LINEAR cache is built piece by piece
+  (``_prefill_piece``; bucketed lengths, or ``prefill_chunk``-token
+  pieces of one program at any prompt length; a dense-dispatch MoE
+  prefills whole at its exact length, since router capacity depends on
+  it).  A speculative engine then builds the draft's cache over the
+  same piece grid.  The last target piece yields the first token.
+- Decoding (``_SlotState`` in ``_slot_states``): the finished batch-1
+  rows were inserted into the slot grid (``_paged_insert``); the host
+  holds the request's tokens, its remaining budget and its rng
+  counter; the device holds the grid cache and the CARRY, each slot's
+  next input token and rng counter, which never come to the host.
 
-**Decode-priority chunked-prefill scheduling**: admission is NOT
-atomic.  A newly admitted request's prefill is a per-slot STAGED
-activity (``_PrefillTask``: batch-1 cache under construction + piece
-cursor) advanced at most ``prefill_budget`` tokens per ``serve_step``
-(default: one piece), enqueued BEHIND the in-flight decode chunk — so
-decode chunks for occupied lanes keep flowing every step and a long
-prompt can no longer freeze active lanes for its full length.  The
-prefill MATH is untouched: the same batch-1 piece programs run in the
-same order per request (bucketed, ``prefill_chunk``, prefix-suffix
-alike), only their scheduling relative to other lanes' decode changes,
-so per-request outputs stay bitwise-identical to atomic admission for
-greedy, seeded sampling, and speculative serving (the draft's prefill
-stages alongside the target's).  Dense-dispatch MoE keeps its
-exact-length single-piece prefill (router capacity is
-length-dependent) — one installment regardless of budget — but still
-yields to decode between requests.  ``prefill_budget=0`` /
-``TTD_NO_INTERLEAVE=1`` (or the CLIs' ``--no-interleave``) is the kill
-switch restoring atomic admission byte-for-byte.
+**A step** (``serve_step``, one ``engine/step`` span), in order:
+
+1. *Dispatch* (``_dispatch_chunk``): when a lane is decoding, enqueue
+   the next decode chunk (``_decode_chunk``: ``chunk`` steps for all
+   slots; or one ``_spec_round``) from the device-resident carry,
+   BEFORE the chunk already in flight is read.  JAX dispatch is
+   asynchronous, so the successor queues behind its predecessor and the
+   device stays busy through everything below.  Refilled slots have
+   their host-known token and counter spliced over the carry
+   (``_carry_arrays``); stale lanes' tables are pointed at the scratch
+   block first (``_flush_stale_lanes``).  Skipped when every active
+   lane certainly retires in the chunk in flight
+   (``_skip_eager_dispatch``).
+2. *Admit* (``_advance_prefills``): claim free slots for queued
+   requests (``_stage_from_queue``: host bookkeeping only) and advance
+   staged prefills in arrival order by at most ``prefill_budget``
+   prompt tokens (default: one piece), enqueued BEHIND the chunk just
+   dispatched: the gap admission adds to a decoding lane is bounded by
+   the budget, and a long prompt spreads over steps.  With no lane
+   decoding there is nobody to delay and the budget is waived.
+3. *Harvest* (``_harvest_prev``): read the PREVIOUS chunk's tokens
+   (this blocks until that chunk is done), append them to their
+   requests, stop on budget or EOS, retire finished lanes.  Stop and
+   refill decisions therefore lag one chunk: a slot whose request
+   finished keeps decoding garbage through the successor; each chunk
+   records which request held each slot at dispatch and the harvest
+   drops what belongs to a previous tenant.
+4. *Restage* freed lanes, and dispatch now if step 1 did not (first
+   step of a session, a harvest-first step, restart after idle).
+5. Hand back the requests that finished, so callers can ``submit()``
+   between steps; one chunk stays in flight across the return.
 
 **Paged KV cache with cross-request prefix sharing** (the default;
 ``TTD_NO_PAGED_KV=1`` / ``paged=False`` / the CLIs' ``--no-paged-kv``
-restores the per-slot linear cache byte-for-byte): KV rows live in ONE
-fixed pool of ``--kv-block-size``-row physical blocks per layer, and
-each lane maps its logical positions through a per-lane block table
-(``serving_kv`` owns the host bookkeeping: block-pool allocator +
-refcounts + a radix tree over token ids at block granularity).  Two
-wins over the linear cache:
+select the per-slot linear grid): KV rows live in one pool of
+``kv_block_size``-row blocks per layer, and a lane maps its positions
+through a block table (``serving_kv``: allocator, refcounts, a radix
+tree over token ids at block granularity).  A lane holds
+``ceil((prompt + max_new) / block_size)`` blocks, admission keys on
+FREE BLOCKS (a request that cannot get them waits in the queue), and
+requests whose prompts share a block-aligned prefix map their leading
+table entries to the same blocks and prefill only the suffix (the
+matched rows are gathered into the batch-1 cache).  The radix index is
+fed at insert and retire; retired blocks stay cached until LRU eviction
+reclaims them.  ``preload_prefix`` warms a shared prefix by hand.
 
-- **capacity**: a lane holds ``ceil((prompt + max_new) / block_size)``
-  blocks instead of a full ``cache_len`` strip, so short requests stop
-  reserving long-request memory and admission is keyed on FREE BLOCKS,
-  not free slots — a request that cannot get its blocks waits in the
-  queue (refused admission, never a corrupted live lane);
-- **prefix sharing**: requests whose prompts share a block-aligned
-  prefix map their leading table entries to the SAME physical blocks
-  (copy-on-write at allocation — a suffix always starts at a block
-  boundary, so sharers never write shared blocks) and prefill only the
-  suffix.  The radix index is fed automatically at insert/retire, so
-  shared system prompts hit warm KV with no ``preload_prefix``
-  hand-wiring (which remains supported and now preloads into the same
-  pool); retired requests' blocks stay cached until LRU eviction under
-  pressure reclaims them.
+Shapes are static everywhere (slots, cache rows, chunk, prompt buckets
+or pieces, pool and tables); only cache contents and the per-slot index
+vector change, so a handful of programs serve a whole session.
 
-Prefill itself is UNCHANGED — the same batch-1 linear piece programs
-run in the same order (a matched prefix is gathered from the pool into
-the batch-1 cache, replacing recompute with a copy), and the decode
-grid reads/writes KV through the block table (gather/scatter —
-``ops.pallas_kernels.paged_kv_gather`` is the TPU seam), so outputs
-stay bitwise-identical to the linear engine for greedy, seeded
-sampling, and speculative serving (pinned in
-tests/test_serving_paged.py).
+Scope: the decoder families ``generate()`` serves (Llama, Mixtral-style
+and latent-attention MoE), greedy or sampled; int8 weight-only serving
+via ``quant_scales``; ``kv_cache_int8`` configs (int8 rows + per-row
+f32 scales, in the batch-1 cache and the pool alike); tensor-parallel
+serving via ``mesh=``; speculative decoding with a draft model at a
+fixed or adaptive depth.  LoRA-unmerged params and sliding windows keep
+the shared-index ``generate()`` path.
 
-Shapes are static everywhere (slot count, cache rows, chunk length,
-prompt buckets / prefill pieces, and the paged pool + block tables) —
-only cache *contents* and the per-slot index vector change, so XLA
-compiles a handful of programs and reuses them for the whole serving
-session.
-
-Scope: the decoder families ``generate()`` serves (Llama AND
-Mixtral-style MoE — one engine), linear cache, greedy or sampled
-decoding (per-request rng streams), with int8 weight-only serving via the same
-``quant_scales`` contract as generate and sharded (tensor-parallel)
-serving via ``mesh=`` — the models' logical constraints shard weights
-and cache over the mesh, GSPMD inserts the collectives, and outputs
-stay token-identical.  Shared prompt prefixes prefill once
-(``preload_prefix``); later requests prefill only their suffix on a
-copied cache.  ``kv_cache_int8`` configs serve here too: the per-slot
-prefill cache and the paged pool both quantize with the linear-cache
-recipe (int8 rows + per-row f32 scales in a parallel pool), halving
-cache HBM so ``--kv-pool-blocks`` can grow effective batch into the
-freed headroom.  LoRA-unmerged params and sliding windows keep the
-shared-index ``generate()`` path.
-
-**Fused paged attention** (TPU): the paged decode read is ONE Pallas
-kernel (``ops.pallas_kernels.paged_attention``) that computes
-flash-style attention directly through the block table — the dense
-per-lane KV copy ``paged_kv_gather`` would materialize never exists.
-``TTD_NO_FUSED_ATTN=1`` (set BEFORE engine construction — the choice
-compiles into the decode programs) restores gather-then-attend as the
-byte-comparable A/B leg; CPU and sharded (``mesh=``) serving always
-use the gather path.
+**Fused paged attention** (TPU): the paged decode read is one Pallas
+kernel (``ops.pallas_kernels.paged_attention``) that attends through
+the block table; the dense per-lane copy ``paged_kv_gather`` would
+make never exists.  ``TTD_NO_FUSED_ATTN=1`` (set BEFORE engine
+construction: the choice compiles into the decode programs) selects
+gather-then-attend; CPU and sharded (``mesh=``) serving always gather.
 """
 
 from __future__ import annotations
@@ -204,27 +175,11 @@ class _PrefillTask:
     table: object = None           # np.int32 [n_blk] physical block row
 
 
-def _overlap_killed() -> bool:
-    """The production kill switch: ``TTD_NO_OVERLAP=1`` forces the
-    synchronous decode path regardless of how the engine was
-    constructed (an env flip needs no redeploy of callers)."""
-    return os.environ.get("TTD_NO_OVERLAP", "0") not in ("", "0")
-
-
-def _interleave_killed() -> bool:
-    """``TTD_NO_INTERLEAVE=1`` restores atomic admission (a request's
-    whole prefill runs inline on the dispatch path) regardless of the
-    engine's ``prefill_budget`` — the same no-redeploy contract as
-    ``TTD_NO_OVERLAP``."""
-    return os.environ.get("TTD_NO_INTERLEAVE", "0") not in ("", "0")
-
-
 def _adaptive_spec_killed() -> bool:
     """``TTD_NO_ADAPTIVE_SPEC=1`` pins the draft depth back to the
     fixed ``speculative_k`` bitwise (the controller is never built;
     every round runs the same static-k program a fixed engine runs).
-    Read at construction — same no-redeploy contract as
-    ``TTD_NO_OVERLAP``."""
+    Read at construction: an env flip needs no redeploy of callers."""
     return os.environ.get("TTD_NO_ADAPTIVE_SPEC", "0") not in ("", "0")
 
 
@@ -256,7 +211,7 @@ def _paged_killed() -> bool:
     """``TTD_NO_PAGED_KV=1`` restores the per-slot LINEAR cache
     byte-for-byte (contiguous ``cache_len`` rows per lane, manual
     ``preload_prefix`` prefix caching) regardless of how the engine was
-    constructed — the same no-redeploy contract as ``TTD_NO_OVERLAP``."""
+    constructed."""
     return os.environ.get("TTD_NO_PAGED_KV", "0") not in ("", "0")
 
 
@@ -349,7 +304,6 @@ class ServingEngine:
                  speculative_k: int = 0,
                  spec_depths=None,
                  prompt_buckets=(32, 64, 128, 256, 512, 1024),
-                 overlap: Optional[bool] = None,
                  prefill_budget: Optional[int] = None,
                  paged: Optional[bool] = None,
                  kv_block_size: int = 16,
@@ -671,38 +625,33 @@ class ServingEngine:
         # _stale_slots are lanes retired/cancelled since the last
         # dispatch — their block-table rows must be zeroed (pointed at
         # the scratch block) BEFORE the next decode program runs, or
-        # the overlap scheduler's one garbage chunk would write into
-        # blocks already freed to (and maybe reallocated by) someone
-        # else.  _preloaded records preload_prefix token tuples for
-        # validate_request's bucket rule (the radix itself is
-        # evictable, so validation must not depend on it).
+        # the one garbage chunk a retired lane still decodes would
+        # write into blocks already freed to (and maybe reallocated
+        # by) someone else.  _preloaded records preload_prefix token
+        # tuples for validate_request's bucket rule (the radix itself
+        # is evictable, so validation must not depend on it).
         self._lane_kv: list = [None] * slots
         self._stale_slots: set = set()
         self._preloaded: dict = {}
         self._kv_refused_rid: Optional[int] = None  # dedup refusal count
-        # Async decode pipelining (one-chunk lookahead).  ``overlap``
-        # None/True enables it; TTD_NO_OVERLAP=1 kills it either way.
-        self.overlap = ((True if overlap is None else bool(overlap))
-                        and not _overlap_killed())
-        # Decode-priority chunked-prefill scheduling: prefill_budget
-        # tokens of staged prefill advance per serve_step (None = one
-        # piece — the default installment); 0 (or TTD_NO_INTERLEAVE=1)
-        # is the kill switch back to atomic admission.
-        if prefill_budget is not None and prefill_budget < 0:
+        # prefill_budget: the prompt tokens of staged prefill a
+        # serve_step may advance while a lane decodes (None = one
+        # piece).
+        if prefill_budget is not None and prefill_budget < 1:
             raise ValueError(
-                f"prefill_budget must be >= 0 (0 = atomic admission), "
-                f"got {prefill_budget}")
+                f"prefill_budget must be >= 1 prompt tokens a step, or "
+                f"None for the staged default of one prefill piece a "
+                f"step; got {prefill_budget} (a budget as large as the "
+                f"prompts admits a whole prompt in one step)")
         self.prefill_budget = prefill_budget
-        self.interleave = (prefill_budget != 0
-                           and not _interleave_killed())
         self._staging: dict = {}       # slot -> _PrefillTask (FIFO)
-        # stall_s: time the host spent blocked on a prefill's first
-        # token (the ``prefill/wait`` spans) while >= 1 lane was
-        # decoding with NO successor decode chunk in flight to hide it
-        # (the head-of-line blocking this scheduler removes — the
-        # gateway exposes it as ttd_engine_prefill_stall_seconds);
         # installments: budget installments run; staged_requests:
-        # requests that went through the staged path.
+        # requests staged.  stall_s (seconds a decoding lane waited on
+        # an admission's first token with no decode chunk in flight)
+        # cannot move: a lane starts decoding only inside a step, and
+        # a step that ends with a lane decoding ends with a chunk in
+        # flight.  The key stays for its readers
+        # (``prefill_stall_s``).
         self.prefill_stats = {"installments": 0, "staged_requests": 0,
                               "stall_s": 0.0}
         # What the running serve_step has done so far: the attrs its
@@ -996,8 +945,8 @@ class ServingEngine:
         t_cache = jax.tree_util.tree_map_with_path(rewind, t_cache)
         d_cache = jax.tree_util.tree_map_with_path(rewind, d_cache)
         # counts + emitted: the NEXT round's rng counters, computed in
-        # the same program so the overlap scheduler's device-resident
-        # carry costs zero extra dispatches (the sync path ignores it).
+        # the same program so the device-resident carry costs zero
+        # extra dispatches.
         return (t_cache, d_cache, emit, emitted, next_tok, a,
                 counts + emitted)
 
@@ -1180,10 +1129,10 @@ class ServingEngine:
     def _reset_lanes(self, cache, stale):
         """Point ``stale`` lanes' block tables at the scratch block and
         zero their indices: a retired/cancelled lane's blocks go back
-        to the pool at harvest, but the overlap scheduler has one more
-        garbage chunk for it in (or headed for) the device queue — this
-        runs BEFORE that chunk, so its writes land in scratch instead
-        of blocks someone else now owns."""
+        to the pool at harvest, but one more garbage chunk for it is in
+        (or headed for) the device queue — this runs BEFORE that chunk,
+        so its writes land in scratch instead of blocks someone else
+        now owns."""
         def rst(path, leaf):
             name = getattr(path[-1], "key", "")
             if name == "block_table":
@@ -1204,10 +1153,9 @@ class ServingEngine:
         ``seeds``/``counts`` [slots]: each slot's sampling stream and
         how many tokens it has already drawn (greedy ignores both).
         Also returns the NEXT chunk's (tok, counts) carry — computed
-        inside the same program so the overlap scheduler can chain
-        chunks with zero extra dispatches (the sync path ignores
-        them) — and, last, the rows each expert took in each step and
-        expert layer ([chunk, layers, experts]; None for a model
+        inside the same program so chunks chain with zero extra
+        dispatches — and, last, the rows each expert took in each step
+        and expert layer ([chunk, layers, experts]; None for a model
         without routed experts, which sows no ``moe_stats``)."""
         def step(carry, j):
             cache, tok = carry
@@ -1477,9 +1425,8 @@ class ServingEngine:
     def _run_target_piece(self, cache_1, padded, piece: int, i: int,
                           m: int, seed: int, rng0: int = 0):
         """Piece ``i`` of a target prefill — THE single source of the
-        per-piece layout/local-idx rule, shared by atomic admission
-        (``_prefill_tokens``) and the staged scheduler
-        (``_advance_piece``) so the two paths stay byte-for-byte.
+        per-piece layout/local-idx rule, shared by request admission
+        (``_advance_piece``) and prefix preload (``_prefill_tokens``).
         ``rng0``: the first pick's rng counter (resume-from-token
         admission continues a stream; fresh requests pick at 0)."""
         toks = jnp.asarray(padded[:, i * piece:(i + 1) * piece])
@@ -1497,13 +1444,11 @@ class ServingEngine:
         return self._draft_prefill_piece(self._draft_variables,
                                          d_cache_1, toks)
 
-    def _prefill_tokens(self, work, *, seed: int, cache_1, draft: bool,
-                        rng0: int = 0):
-        """Append ``work`` to a batch-1 cache in compile-bounded pieces
-        (shared by request prefill and prefix preload, target and
-        draft).  Returns (cache, first_token) — ``first`` is the pick
-        at the last REAL row (None for the draft, which only needs its
-        KV rows)."""
+    def _prefill_tokens(self, work, *, seed: int, cache_1, draft: bool):
+        """Append ``work`` to a batch-1 cache in compile-bounded pieces,
+        all at once (prefix preload; target and draft).  Returns
+        (cache, first_token) — ``first`` is the pick at the last REAL
+        row (None for the draft, which only needs its KV rows)."""
         m = len(work)
         piece, n_pieces = self._pieces_for(m)
         padded = np.zeros((1, piece * n_pieces), np.int32)
@@ -1515,7 +1460,7 @@ class ServingEngine:
                                                 piece, i)
             else:
                 cache_1, first = self._run_target_piece(
-                    cache_1, padded, piece, i, m, seed, rng0)
+                    cache_1, padded, piece, i, m, seed)
         return cache_1, first
 
     @thread_role("main", "driver")
@@ -2088,7 +2033,7 @@ class ServingEngine:
     def _flush_stale_lanes(self) -> None:
         """Zero retired/cancelled lanes' block-table rows before the
         next decode program (their freed blocks may already belong to
-        someone else; the overlap garbage chunk must write scratch)."""
+        someone else; the garbage chunk must write scratch)."""
         if not self.paged or not self._stale_slots:
             return
         if self._cache is None:
@@ -2322,139 +2267,13 @@ class ServingEngine:
         with self._stats_lock:
             return self.kv_stats["evictions"]
 
-    def _fill_free_slots(self):
-        """ATOMIC admission — the ``prefill_budget=0`` /
-        ``TTD_NO_INTERLEAVE`` path: a popped request's entire prefill
-        runs inline before control returns, so active decode lanes
-        wait it out (``prefill_stats['stall_s']`` measures that
-        head-of-line time; the staged path keeps it ~0)."""
-        stalled = any(s is not None for s in self._slot_states)
-        for slot in range(self.slots):
-            # Keep popping until this slot is OCCUPIED or the queue is
-            # dry: a request that resolves at prefill time (max_new<=1
-            # or first-token EOS) must not leave the slot idle for a
-            # whole decode chunk while runnable work waits.
-            while self._slot_states[slot] is None and self._queue:
-                rid, prompt, max_new, seed, resume = \
-                    self._queue.popleft()
-                if max_new == 0:
-                    self._outputs[rid] = list(prompt)
-                    continue
-                n = len(prompt)
-                kv = table_j = None
-                if self.paged:
-                    kv = self._kv_claim(rid, prompt, max_new)
-                    if kv is None:
-                        # No blocks: refuse admission, keep FIFO order
-                        # (the request takes its place back; blocks
-                        # free as lanes retire).
-                        self._queue.appendleft(
-                            (rid, prompt, max_new, seed, resume))
-                        return
-                    table_j = self._kv_table(kv)
-                    pre_len, pre_pair = self._admission_match(kv, prompt)
-                else:
-                    # Prefix reuse: prefill only the suffix on a copy
-                    # of the stored cache(s) (piece sizing follows the
-                    # suffix).
-                    pre_len, pre_pair = self._match_prefix(prompt,
-                                                           touch=True)
-                work = prompt[pre_len:]
-                self._note_moe_prefill_len(n)
-                with self._ctx(), events.span(
-                        "prefill/request", rid=rid, tokens=len(work)):
-                    cache_1 = self._admission_cache_1(
-                        pre_pair, kv, table_j, draft=False)
-                    cache_1, first = self._prefill_tokens(
-                        work, seed=seed, cache_1=cache_1, draft=False,
-                        rng0=resume)
-                self._step_counts["pieces"] += self._pieces_for(
-                    len(work))[1]
-                self._step_counts["prefill_tokens"] += len(work)
-                first = self._first_token(first, rid, stalled)
-                state = _SlotState(request_id=rid, remaining=max_new - 1,
-                                   tokens=list(prompt) + [first],
-                                   last_token=first, seed=seed,
-                                   count=resume + 1)
-                if (max_new == 1 or (self.eos_id is not None
-                                     and first == self.eos_id)):
-                    # Resolved at prefill — and checked BEFORE the draft
-                    # prefill, which such a request would waste.  Its
-                    # blocks were never written: hand them straight
-                    # back.
-                    if kv is not None:
-                        self._kv_release(kv)
-                    self._outputs[rid] = state.tokens
-                    continue  # slot still free: try the next request
-                with self._ctx(), events.span("prefill/insert", rid=rid):
-                    if self._draft_model is not None:
-                        d_cache_1 = self._admission_cache_1(
-                            pre_pair, kv, table_j, draft=True)
-                        d_cache_1, _ = self._prefill_tokens(
-                            work, seed=seed, cache_1=d_cache_1,
-                            draft=True)
-                    if self._cache is None:
-                        self._cache = self._fresh_cache(self.slots,
-                                                        grid=True)
-                    if self.paged:
-                        # Scatter everything past the SHARED blocks
-                        # (kv.matched, not pre_len — a preload pair's
-                        # sub-block tail lives only in cache_1 and must
-                        # land in this lane's owned blocks).
-                        self._cache = self._paged_insert(
-                            self._cache, cache_1, jnp.int32(slot),
-                            table_j, jnp.int32(kv.matched),
-                            jnp.int32(n))
-                    else:
-                        self._cache = self._insert(
-                            self._cache, cache_1, jnp.int32(slot),
-                            jnp.int32(len(prompt)))
-                    if self._draft_model is not None:
-                        if self._d_cache is None:
-                            self._d_cache = self._fresh_cache(
-                                self.slots, draft=True, grid=True)
-                        if self.paged:
-                            self._d_cache = self._paged_insert(
-                                self._d_cache, d_cache_1,
-                                jnp.int32(slot), table_j,
-                                jnp.int32(kv.matched), jnp.int32(n))
-                        else:
-                            self._d_cache = self._insert(
-                                self._d_cache, d_cache_1,
-                                jnp.int32(slot), jnp.int32(len(prompt)))
-                if kv is not None:
-                    self._lane_claim(slot, kv, prompt)
-                self._slot_states[slot] = state
-                # Overlap bookkeeping: the next dispatch must splice
-                # this slot's host-known token/count over the device
-                # carry (which still holds the previous tenant's).
-                self._refills.add(slot)
-                events.instant("slot/insert", rid=rid, slot=slot)
-
-    def _first_token(self, first, rid: int, stalled: bool) -> int:
-        """The host copy of a prefill's first token: the read blocks
-        until the request's last piece has run, so it is a ``*/wait``
-        span.  ``stalled``: lanes are decoding and nothing is in flight
-        to keep them going through the wait, which is then charged to
-        ``prefill_stats['stall_s']`` (on the span's clock, read here so
-        that the gauge outlives ``TTD_NO_TRACE=1``)."""
-        t0 = time.monotonic()
-        with events.span("prefill/wait", rid=rid):
-            first = int(first)
-        if stalled:
-            with self._stats_lock:
-                self.prefill_stats["stall_s"] += time.monotonic() - t0
-        self._step_counts["committed"] += 1
-        return first
-
-    # -- staged prefill (decode-priority chunked-prefill scheduling) -------
+    # -- admission: staged prefill under a budget ---------------------------
 
     def _stage_from_queue(self) -> None:
         """Claim free lanes for queued requests as staged-prefill
         tasks.  Host-only bookkeeping — no device work happens until a
         budget installment advances the task — so this is safe to call
-        anywhere in the step (it is the staged path's analog of the
-        slot-claiming half of ``_fill_free_slots``)."""
+        anywhere in the step."""
         for slot in range(self.slots):
             if not self._queue:
                 return
@@ -2542,16 +2361,12 @@ class ServingEngine:
         self._refills.add(slot)        # next dispatch splices host carry
         events.instant("slot/insert", rid=task.request_id, slot=slot)
 
-    def _advance_piece(self, slot: int, task: _PrefillTask,
-                       stalled: bool) -> int:
+    def _advance_piece(self, slot: int, task: _PrefillTask) -> int:
         """Run ONE installment of ``task`` — the next target (then
-        draft) prefill piece, exactly the program ``_prefill_tokens``
-        would have run at this position, plus the finalize/insert when
-        it was the last — and return its token cost.  The per-request
-        piece programs, their order, and the rng inputs are identical
-        to atomic admission, so outputs are bitwise-identical; only the
-        scheduling between OTHER lanes' decode chunks differs.
-        ``stalled``: see ``_first_token``."""
+        draft) prefill piece, plus the finalize/insert when it was the
+        last — and return its token cost.  A request's piece programs,
+        their order and their rng inputs depend on the request alone,
+        never on what other lanes do between them."""
         draft = task.cursor >= task.n_pieces
         i = task.d_cursor if draft else task.cursor
         real = min(task.piece, len(task.work) - i * task.piece)
@@ -2571,20 +2386,21 @@ class ServingEngine:
                     len(task.work), task.seed, task.resume)
                 task.cursor += 1
                 if task.cursor == task.n_pieces:
-                    # Materializing the first token blocks the host on
-                    # this piece — the in-flight decode chunk (enqueued
-                    # AHEAD of it) keeps the device busy through the
-                    # wait.
-                    first = self._first_token(task.first,
-                                              task.request_id, stalled)
+                    # The host copy of the first token: the read blocks
+                    # until this piece has run (behind the decode chunk
+                    # in flight), so it is a ``*/wait`` span.
+                    with events.span("prefill/wait",
+                                     rid=task.request_id):
+                        first = int(task.first)
+                    self._step_counts["committed"] += 1
                     task.first_host = first
                     if (task.max_new == 1
                             or (self.eos_id is not None
                                 and first == self.eos_id)):
                         # Resolved at prefill — before the draft
-                        # prefill, which such a request would waste
-                        # (the atomic path's rule).  Its blocks were
-                        # never written: hand them straight back.
+                        # prefill, which such a request would waste.
+                        # Its blocks were never written: hand them
+                        # straight back.
                         if task.kv is not None:
                             self._kv_release(task.kv)
                         self._outputs[task.request_id] = (
@@ -2604,14 +2420,14 @@ class ServingEngine:
                 self._finalize_prefill(slot, task)
             return task.piece
 
-    def _advance_prefills(self, hidden: bool) -> None:
+    def _advance_prefills(self) -> None:
         """Advance staged prefills by at most ``prefill_budget`` tokens
-        (default: one piece) in request-arrival order.  ``hidden``: a
-        decode chunk is already in flight AHEAD of this work on the
-        device queue, so decoding lanes lose no cadence to it and no
-        stall is charged.  With no lane decoding there is nobody to
-        stall, so the budget is waived and admission runs at full
-        speed (TTFT at session start matches atomic admission)."""
+        (default: one piece) in request-arrival order.  A decode chunk
+        is in flight AHEAD of this work on the device queue whenever a
+        lane is decoding, so decoding lanes lose no more cadence to it
+        than the budget.  With no lane decoding there is nobody to
+        delay, so the budget is waived and admission runs at full
+        speed."""
         self._stage_from_queue()
         if not self._staging:
             return
@@ -2619,8 +2435,7 @@ class ServingEngine:
         spent = 0
         while self._staging:
             slot = next(iter(self._staging))
-            spent += self._advance_piece(slot, self._staging[slot],
-                                         stalled=decoding and not hidden)
+            spent += self._advance_piece(slot, self._staging[slot])
             with self._stats_lock:
                 self.prefill_stats["installments"] += 1
             if slot not in self._staging:
@@ -2634,12 +2449,10 @@ class ServingEngine:
     @thread_role("handler", "driver")
     def prefill_stall_s(self) -> float:
         """Cumulative seconds decode lanes spent blocked behind
-        admission prefill (the host's ``prefill/wait`` for a first
-        token while >= 1 lane was decoding with no successor chunk in
-        flight to hide it).  Grows with every long admission on the
-        atomic path; collapses to ~0 with interleaving on.  The gateway
-        exposes it as ``ttd_engine_prefill_stall_seconds`` — scraped
-        from handler threads, so the read locks."""
+        admission prefill with no decode chunk in flight: 0.0, since
+        admission always runs behind one (``prefill_stats``).  The
+        gateway exposes it as ``ttd_engine_prefill_stall_seconds`` —
+        scraped from handler threads, so the read locks."""
         with self._stats_lock:
             return self.prefill_stats["stall_s"]
 
@@ -2672,21 +2485,19 @@ class ServingEngine:
             events.instant("slot/retire", rid=state.request_id,
                            slot=slot, tokens=len(state.tokens))
 
-    def _harvest(self, toks: np.ndarray, rids=None):
-        """``rids`` (overlap mode): the slot->request map captured at
-        dispatch — a slot whose occupant changed since (retired and
-        refilled, or cancelled) must NOT consume this chunk's tokens;
-        they belong to the previous tenant and are trimmed here."""
+    def _harvest(self, toks: np.ndarray, rids):
+        """``rids``: the slot->request map captured at dispatch — a
+        slot whose occupant changed since (retired and refilled, or
+        cancelled) must NOT consume this chunk's tokens; they belong
+        to the previous tenant and are trimmed here."""
         for slot, state in enumerate(self._slot_states):
-            if state is None:
-                continue
-            if rids is not None and state.request_id != rids[slot]:
+            if state is None or state.request_id != rids[slot]:
                 continue
             self._consume(state, toks[slot])
             self._retire_if_done(slot, state)
 
     def _harvest_spec(self, emit, emitted, next_tok, accepted, k,
-                      rids=None):
+                      rids):
         """Consume each slot's emitted prefix from a speculative round
         (variable per slot; budget/EOS via the shared consume rule),
         tracking acceptance stats.  The round's bonus token is the last
@@ -2695,16 +2506,14 @@ class ServingEngine:
         DISPATCHED at (recorded in the in-flight dict — under adaptive
         speculation the current pick may already differ); it sizes the
         drafted-token denominator and feeds the controller's
-        acceptance observation.  ``rids``: the overlap trim guard,
-        same rule as ``_harvest``."""
+        acceptance observation.  ``rids``: the trim guard, same rule
+        as ``_harvest``."""
         del next_tok  # == emit[slot, emitted-1], consumed above
         with self._stats_lock:
             self.spec_stats["rounds"] += 1  # engine, not slot-rounds
         n_slots = acc_sum = 0
         for slot, state in enumerate(self._slot_states):
-            if state is None:
-                continue
-            if rids is not None and state.request_id != rids[slot]:
+            if state is None or state.request_id != rids[slot]:
                 continue
             before = len(state.tokens)
             self._consume(state, emit[slot, :int(emitted[slot])])
@@ -2749,7 +2558,7 @@ class ServingEngine:
         return {s.request_id: list(s.tokens)
                 for s in self._slot_states if s is not None}
 
-    # -- async decode pipelining (one-chunk lookahead) ---------------------
+    # -- decode: one chunk dispatched ahead of the harvest ------------------
 
     @dispatch_critical
     def _carry_arrays(self):
@@ -2757,7 +2566,7 @@ class ServingEngine:
         from the previous chunk, with host values spliced in for slots
         refilled since (``jnp.where`` only ENQUEUES — still no sync).
         Retired-but-unrefilled slots keep garbage carry and decode
-        garbage, exactly as idle slots already do on the sync path."""
+        garbage, as idle slots do."""
         if self._carry is None:
             # First dispatch of the session: everything is host-known.
             tok = np.zeros((self.slots,), np.int32)
@@ -2937,8 +2746,8 @@ class ServingEngine:
     def overlap_ratio(self) -> float:
         """Fraction of host harvest wall time spent with a successor
         chunk concurrently in flight — the host-stall share the
-        lookahead hides (0.0 under TTD_NO_OVERLAP/overlap=False).
-        The gateway exposes it as ``ttd_engine_overlap_ratio``.
+        lookahead hides.  The gateway exposes it as
+        ``ttd_engine_overlap_ratio``.
 
         Scraped from the gateway's metrics thread while the driver
         harvests: the pair is read under ``_stats_lock`` (and the
@@ -2954,31 +2763,21 @@ class ServingEngine:
 
     @thread_role("driver", "main")
     def serve_step(self) -> dict:
-        """ONE service iteration: refill free slots from the queue, run
-        one decode chunk, harvest — then hand control back, so callers
-        can ``submit()`` new requests between steps (online serving: the
-        queue never has to be complete up front).  Returns the requests
-        that FINISHED this step, ``{request_id: tokens}`` (possibly
-        empty); poll ``pending()`` for completion.
+        """ONE service iteration, then control goes back to the caller,
+        who may ``submit()`` new requests between steps (online
+        serving: the queue never has to be complete up front).  Returns
+        the requests that FINISHED this step, ``{request_id: tokens}``
+        (possibly empty); poll ``pending()`` for completion.
 
-        With ``overlap`` on (the default), the step is PIPELINED: the
-        successor chunk is dispatched from the device-resident carry
-        BEFORE the in-flight chunk's host copy is touched, so stop
-        detection, refills, and the caller's streaming/deadline passes
-        (which run between ``serve_step`` calls — a chunk stays in
-        flight across the return) all hide under device compute.  Stop
-        decisions lag one chunk; the harvest trims the overshoot, so
-        outputs are bitwise-identical to the synchronous path.  Note a
-        finished session leaves one garbage chunk in flight — harmless,
-        discarded by the next cycle's trim guard.
-
-        With ``interleave`` on (the default), admission is STAGED:
-        after the eager dispatch, at most ``prefill_budget`` tokens of
-        staged prefill advance (enqueued behind the in-flight chunk),
-        so a long prompt's admission spreads across steps while decode
-        chunks for occupied lanes keep flowing every step.  The kill
-        switch (``prefill_budget=0`` / ``TTD_NO_INTERLEAVE=1``)
-        restores atomic admission byte-for-byte.
+        The step is pipelined: the successor chunk is dispatched from
+        the device-resident carry BEFORE the in-flight chunk's host
+        copy is touched, so stop detection, admission and the caller's
+        streaming/deadline passes (which run between ``serve_step``
+        calls — a chunk stays in flight across the return) all hide
+        under device compute.  Stop decisions lag one chunk; the
+        harvest trims the overshoot.  A finished session leaves one
+        garbage chunk in flight — harmless, discarded by the next
+        cycle's trim guard.
 
         The whole step is one ``engine/step`` span, the parent of the
         ``decode/*`` and ``prefill/*`` spans recorded inside it; at exit
@@ -2986,139 +2785,34 @@ class ServingEngine:
         depth."""
         with events.span("engine/step") as step:
             self._step_counts = dict.fromkeys(_STEP_COUNTS, 0)
-            if not self.overlap:
-                out = self._serve_step_sync()
-            elif not self.interleave:
-                out = self._serve_step_overlap_atomic()
-            else:
-                out = self._serve_step_staged()
+            prev, self._inflight = self._inflight, None
+            # DECODE PRIORITY: the successor chunk for occupied lanes
+            # goes onto the device queue before any admission work, so
+            # active lanes never wait behind a new prompt's prefill.
+            dispatched = False
+            if (any(s is not None for s in self._slot_states)
+                    and not self._skip_eager_dispatch()):
+                self._dispatch_chunk()      # device busy through the
+                dispatched = True           # host passes below
+            # One budget installment of admission, queued BEHIND the
+            # chunk just dispatched (or behind ``prev``, still in
+            # flight) — the gap it can add to an active lane is bounded
+            # by the budget.
+            self._advance_prefills()
+            if prev is not None:
+                self._harvest_prev(prev, overlapped=dispatched)
+            # Lanes the harvest freed stage immediately (host-only) so
+            # their first installment rides the next step's budget.
+            self._stage_from_queue()
+            if not dispatched and any(s is not None
+                                      for s in self._slot_states):
+                # Nothing was in flight to hide this pass behind (first
+                # step of a session / a harvest-first fallback step /
+                # post-idle restart): dispatch now so the NEXT step's
+                # harvest overlaps.
+                self._dispatch_chunk()
+            out, self._outputs = self._outputs, {}
             step.set(queued=len(self._queue), **self._step_counts)
-        return out
-
-    def _serve_step_staged(self) -> dict:
-        """The pipelined step with STAGED admission (the default)."""
-        prev, self._inflight = self._inflight, None
-        # DECODE PRIORITY: the successor chunk for occupied lanes goes
-        # onto the device queue before any admission work, so active
-        # lanes never wait behind a new prompt's prefill.
-        dispatched = False
-        if (any(s is not None for s in self._slot_states)
-                and not self._skip_eager_dispatch()):
-            self._dispatch_chunk()          # device busy through the
-            dispatched = True               # host passes below
-        # One budget installment of admission, queued BEHIND the chunk
-        # just dispatched (or behind ``prev``, still in flight) — the
-        # gap it can add to an active lane is bounded by the budget.
-        self._advance_prefills(hidden=dispatched or prev is not None)
-        if prev is not None:
-            self._harvest_prev(prev, overlapped=dispatched)
-        # Lanes the harvest freed stage immediately (host-only) so
-        # their first installment rides the next step's budget.
-        self._stage_from_queue()
-        if not dispatched and any(s is not None
-                                  for s in self._slot_states):
-            # Nothing was in flight to hide this pass behind (first
-            # step of a session / a harvest-first fallback step /
-            # post-idle restart): dispatch now so the NEXT step's
-            # harvest overlaps.
-            self._dispatch_chunk()
-        out, self._outputs = self._outputs, {}
-        return out
-
-    def _serve_step_overlap_atomic(self) -> dict:
-        """The pipelined step with ATOMIC admission — the path
-        ``prefill_budget=0`` / ``TTD_NO_INTERLEAVE=1`` restores,
-        byte-for-byte the pre-staged-prefill scheduling (pinned by
-        tests/test_serving_overlap.py)."""
-        prev, self._inflight = self._inflight, None
-        if self._queue and any(s is None for s in self._slot_states):
-            # Requests that arrived since the last harvest (the online
-            # pattern: callers submit between steps) take their free
-            # lanes BEFORE the eager dispatch, so they ride the very
-            # next chunk — their prefills enqueue behind the in-flight
-            # chunk, still overlapped.  Without this, a freed lane
-            # would idle one extra chunk per turnaround.
-            self._fill_free_slots()
-        dispatched = False
-        if (any(s is not None for s in self._slot_states)
-                and not self._skip_eager_dispatch()):
-            self._dispatch_chunk()          # device busy through the
-            dispatched = True               # host passes below
-        if prev is not None:
-            self._harvest_prev(prev, overlapped=dispatched)
-        self._fill_free_slots()
-        if not dispatched and any(s is not None
-                                  for s in self._slot_states):
-            # Nothing was in flight to hide this pass behind (first
-            # step of a session / a harvest-first fallback step /
-            # post-idle restart): dispatch now so the NEXT step's
-            # harvest overlaps.
-            self._dispatch_chunk()
-        out, self._outputs = self._outputs, {}
-        return out
-
-    def _serve_step_sync(self) -> dict:
-        """The synchronous path ``TTD_NO_OVERLAP``/``overlap=False``
-        restores: dispatch one chunk, block on its host copy, harvest —
-        the device idles through every host pass (the measured
-        host stall), but scheduling decisions never lag.  Staged
-        admission still applies here unless ITS kill switch is also
-        thrown: prefill advances at most ``prefill_budget`` tokens
-        before the chunk, so active lanes' inter-chunk gap stays
-        budget-bounded even without the lookahead."""
-        if self.interleave:
-            self._advance_prefills(hidden=False)
-        else:
-            self._fill_free_slots()
-        # (No active slots == everything resolved at prefill time or
-        # nothing queued: skip the decode, just drain what finished.)
-        if any(s is not None for s in self._slot_states):
-            tok = np.zeros((self.slots,), np.int32)
-            seeds = np.zeros((self.slots,), np.uint32)
-            counts = np.zeros((self.slots,), np.int32)
-            held = []
-            for slot, state in enumerate(self._slot_states):
-                if state is not None:
-                    tok[slot] = state.last_token
-                    seeds[slot] = state.seed
-                    counts[slot] = state.count
-                    held.append(len(state.tokens))
-            k = self._spec_depth()
-            self._count_dispatch(held, k)
-            if self._draft_model is not None:
-                with self._ctx(), events.span(
-                        "decode/dispatch", fused=self._fused_tag,
-                        spec_k=k):
-                    self._flush_stale_lanes()
-                    (self._cache, self._d_cache, emit, emitted,
-                     next_tok, acc, _) = self._spec_round(
-                        self._variables, self._draft_variables,
-                        self._cache, self._d_cache, jnp.asarray(tok),
-                        jnp.asarray(seeds), jnp.asarray(counts), k)
-                # decode/wait is the device block, decode/harvest the
-                # host pass — same split as the overlap path, so the
-                # two paths' traces are comparable span for span.
-                with events.span("decode/wait", overlapped=False):
-                    args = (np.asarray(emit), np.asarray(emitted),
-                            np.asarray(next_tok), np.asarray(acc))
-                with events.span("decode/harvest", overlapped=False):
-                    self._harvest_spec(*args, k)
-            else:
-                with self._ctx(), events.span(
-                        "decode/dispatch", fused=self._fused_tag):
-                    self._flush_stale_lanes()
-                    (self._cache, toks, _, _,
-                     expert_rows) = self._decode_chunk(
-                        self._variables, self._cache, jnp.asarray(tok),
-                        jnp.asarray(seeds), jnp.asarray(counts))
-                with events.span("decode/wait", overlapped=False):
-                    toks = np.asarray(toks)
-                    if expert_rows is not None:
-                        expert_rows = np.asarray(expert_rows)
-                with events.span("decode/harvest", overlapped=False):
-                    self._harvest(toks)
-                    self._count_experts(expert_rows)
-        out, self._outputs = self._outputs, {}
         return out
 
     @thread_role("main", "driver")

@@ -34,7 +34,7 @@ def dispatch_window(carry, toks):
     # PLANTED: four host-sync hazards inside the decode window.
     toks.block_until_ready()                # finding
     first = float(toks)                     # finding
-    if os.environ.get("TTD_NO_OVERLAP"):    # finding: slow env read
+    if os.environ.get("TTD_NO_TRACE"):      # finding: slow env read
         pass
     t = time.time()                         # finding: wall clock
     return first, t

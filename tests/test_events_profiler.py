@@ -191,11 +191,13 @@ def tiny():
     return cfg, params
 
 
+#: Engine settings callers really pass: the default, a budget of two
+#: pieces a step, the target as its own draft, int8 cache rows.
 VARIANTS = {
-    "staged": dict(),
-    "overlap-atomic": dict(prefill_budget=0),
-    "sync": dict(overlap=False),
-    "sync-atomic": dict(overlap=False, prefill_budget=0),
+    "default": dict(),
+    "budget-8": dict(prefill_budget=8),
+    "self-draft": dict(speculative_k=2),
+    "kv-int8": dict(),
 }
 
 
@@ -204,13 +206,20 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     """Every name the engine records is in the table; every ``decode/*``
     and ``prefill/*`` span lies inside an ``engine/step`` of its thread;
     the steps' ``committed`` add up to the tokens handed back."""
+    import dataclasses
+
     from tensorflow_train_distributed_tpu.serving import ServingEngine
 
     cfg, params = tiny
+    kw = dict(VARIANTS[variant])
+    if variant == "self-draft":
+        kw.update(draft_config=cfg, draft_params=params)
+    if variant == "kv-int8":
+        cfg = dataclasses.replace(cfg, kv_cache_int8=True)
     reqs = [([1, 2, 3], 6), ([4, 5], 5), ([9, 8, 7, 6, 5, 4, 3, 2, 1], 4),
             ([7], 1)]
     eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
-                        prefill_chunk=4, **VARIANTS[variant])
+                        prefill_chunk=4, **kw)
     rec = events.get_recorder()
     seq0 = rec.events_after(0)[0]
     ids = [eng.submit(p, m) for p, m in reqs]
@@ -244,44 +253,74 @@ def test_engine_spans_keep_the_contract(tiny, variant):
             assert s[5]["kv_blocks"] == s[5]["kv_table_blocks"] == 0
     assert sum(s[5]["prefill_tokens"] for s in steps) == sum(
         len(p) for p, _ in reqs)
-    # One dispatch a step that had lanes to run, and none otherwise.
-    assert len([e for e in evs if e[0] == "decode/dispatch"]) == len(
-        [s for s in steps if s[5]["lanes"]])
+    # One dispatch a step that had lanes to run, and none otherwise; a
+    # speculative round says its depth.
+    dispatches = [e for e in evs if e[0] == "decode/dispatch"]
+    assert len(dispatches) == len([s for s in steps if s[5]["lanes"]])
+    assert {d[5]["spec_k"] for d in dispatches} == {
+        kw.get("speculative_k", 0)}
     assert all(s[5]["positions"] >= s[5]["lanes"] for s in steps)
+    # A piece a span, the draft's included (it runs the target's grid
+    # again, for every request its first token did not resolve).
     pieces = [e for e in evs if e[0] == "prefill/piece"]
-    assert len(pieces) == (0 if "atomic" in variant else sum(
-        s[5]["pieces"] for s in steps))
+    assert len(pieces) == sum(s[5]["pieces"] for s in steps) == (
+        11 if variant == "self-draft" else 6)
     assert all(1 <= p[5]["tokens"] <= 4 for p in pieces)
+    # The long prompt arrives at a step that began with a lane decoding
+    # and is metered: one piece by default, two under a budget of 8.
+    if "draft" not in variant:
+        assert min(s[5]["pieces"] for s in steps if s[5]["pieces"]) == (
+            2 if variant == "budget-8" else 1)
     # A first token is read inside a */wait span, once per request.
     assert len([e for e in evs if e[0] == "prefill/wait"]) == len(reqs)
+    # Every harvest waited in a decode/wait span of its own; the last
+    # of a session has no successor dispatched over it.
+    waits = [e[5]["overlapped"] for e in evs if e[0] == "decode/wait"]
+    assert len(waits) == len(
+        [e for e in evs if e[0] == "decode/harvest"]) > 0
+    assert True in waits and False in waits
 
 
 @pytest.mark.parametrize("killed", [False, True])
-def test_stall_seconds_are_the_wait_span_and_outlive_the_kill_switch(
+def test_harvest_seconds_split_by_the_wait_span_and_outlive_the_kill_switch(
         tiny, killed, monkeypatch):
-    """``prefill_stats['stall_s']`` is the ``prefill/wait`` of the
-    admissions that made decoding lanes wait, on the span's clock; the
-    operator's gauge keeps counting under ``TTD_NO_TRACE=1``."""
+    """A harvest-first step (every active lane certainly retires in the
+    chunk in flight, so no successor is dispatched over it) waits in a
+    ``decode/wait`` that says ``overlapped=False``; ``overlap_stats``
+    counts its host pass in ``harvest_s`` only, so ``overlap_ratio()``
+    is below 1; the operator's gauge keeps counting under
+    ``TTD_NO_TRACE=1``.  An admission in such a step still runs behind
+    the chunk in flight: no stall is charged."""
     from tensorflow_train_distributed_tpu.serving import ServingEngine
 
     if killed:
         monkeypatch.setenv("TTD_NO_TRACE", "1")
     cfg, params = tiny
     eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
-                        prompt_buckets=(8,), overlap=False,
-                        prefill_budget=0)
+                        prompt_buckets=(8,))
     rec = events.get_recorder()
     seq0 = rec.events_after(0)[0]
-    eng.submit([1, 2, 3], 8)
+    eng.submit([1, 2, 3], 7)              # first token + three chunks
     eng.serve_step()                      # lane 0 decodes from here on
-    assert eng.prefill_stall_s() == 0.0   # nobody was waiting yet
-    eng.submit([4, 5, 6], 4)
+    eng.serve_step()                      # 4 tokens left: two chunks
+    eng.serve_step()                      # 2 left: retires in flight
+    assert eng._skip_eager_dispatch()
+    eng.submit([4, 5, 6], 4)              # admitted harvest-first
+    skipped = eng.overlap_stats["chunks"]
+    eng.serve_step()
+    # No chunk over the harvest; one after it, for the new lane.
+    assert eng.overlap_stats["chunks"] == skipped + 1
+    assert eng.active_slots() == 1
     eng.run()
-    waits = [e for e in rec.events_after(seq0)[1]
-             if e[0] == "prefill/wait"]
+    stats = eng.overlap_stats
+    assert 0 < stats["overlapped_harvests"] < stats["chunks"]
+    assert 0.0 < stats["overlapped_harvest_s"] < stats["harvest_s"]
+    assert 0.0 < eng.overlap_ratio() < 1.0
+    assert eng.prefill_stall_s() == 0.0
+    waits = [e[5]["overlapped"] for e in rec.events_after(seq0)[1]
+             if e[0] == "decode/wait"]
     if killed:
-        assert not waits and eng.prefill_stall_s() > 0.0
+        assert not waits
     else:
-        assert len(waits) == 2
-        # read around the span: its duration and the annotation's exit
-        assert 0.0 <= eng.prefill_stall_s() - waits[1][3] < 1e-3
+        assert waits.count(True) == stats["overlapped_harvests"]
+        assert False in waits

@@ -7,18 +7,7 @@ reports the bench trajectory's first serving-latency datapoints: p50 /
 p99 request latency, generated tokens/sec, mean TTFT and inter-token
 latency (scraped from the gateway's own /metrics histograms), and the
 shed rate (429s per attempt; a shed client honors Retry-After and
-retries, so the loop stays closed under overload).  In-process runs
-A/B the engine's async decode pipelining by default — overlap ON is
-the headline, OFF lands in a ``no_overlap`` sub-record with the
-``ttd_engine_overlap_ratio`` the driver would scrape; ``--no-ab``
-skips the OFF leg.
-
-``--mixed`` instead runs the tail-latency workload: streaming clients
-decode on most lanes while one LONG prompt (several prefill-piece
-budget installments) is injected mid-stream, A/B'ing the engine's
-interleaved prefill scheduler against its atomic-admission kill switch
-— reported are the CLIENT-observed p99 inter-token latency of active
-lanes during the admission window and the injected requests' TTFTs.
+retries, so the loop stays closed under overload).
 
 Self-contained by default — builds a random-init ``--preset`` engine
 and an in-process gateway on an ephemeral port, so the bench needs no
@@ -31,7 +20,6 @@ Prints one driver-parsable JSON line (bench_lm.py conventions).
 
 import argparse
 import contextlib
-import http.client
 import json
 import os
 import sys
@@ -251,197 +239,9 @@ def _run_closed_loop(base_url, clients, requests_per_client,
     }
 
 
-class _StreamLane(threading.Thread):
-    """One streaming 'active lane' client: posts a stream=True request
-    and records each token chunk's (arrival time, token count) so the
-    mixed bench can compute client-observed inter-token gaps around a
-    long-prompt injection."""
-
-    def __init__(self, base_url, prompt, max_new, timeout):
-        super().__init__(daemon=True)
-        self.base_url, self.prompt = base_url, prompt
-        self.max_new, self.timeout = max_new, timeout
-        self.events: list = []          # (t, n_tokens) per NDJSON chunk
-        self.first_token_at = None
-        self.error = None
-
-    @_loadgen_role
-    def run(self):
-        req = urllib.request.Request(
-            self.base_url + "/v1/generate",
-            data=json.dumps({"prompt": self.prompt,
-                             "max_new": self.max_new,
-                             "stream": True}).encode(),
-            headers={"Content-Type": "application/json"})
-        try:
-            with urllib.request.urlopen(req, timeout=self.timeout) as r:
-                for raw in r:
-                    obj = json.loads(raw)
-                    if "tokens" in obj:
-                        now = time.perf_counter()
-                        if self.first_token_at is None:
-                            self.first_token_at = now
-                        self.events.append((now, len(obj["tokens"])))
-                    elif "error" in obj:
-                        self.error = obj["error"]
-        except (OSError, http.client.HTTPException, ValueError) as e:
-            # OSError: refused/reset/timeout; HTTPException covers
-            # IncompleteRead on a mid-stream drop; ValueError covers a
-            # torn NDJSON line.  Anything uncaught would kill the
-            # thread with error=None and the pass would misreport a
-            # timeout instead of the real failure.
-            self.error = f"{type(e).__name__}: {e}"
-
-
-def _mixed_gateway_pass(base_url, lanes, active_new, long_prompt,
-                        tail_prompt, vocab, seed, timeout):
-    """Fill ``lanes`` streaming clients, wait for all to be decoding,
-    inject one LONG prompt then a trailing short (both streaming, so
-    TTFT is client-observable), and measure the active clients'
-    per-token gaps during the admission window."""
-    import numpy as np
-
-    rng = np.random.default_rng(seed)
-    lanes_t = [_StreamLane(base_url,
-                           [int(t) for t in rng.integers(1, vocab, 8)],
-                           active_new, timeout) for _ in range(lanes)]
-    for w in lanes_t:
-        w.start()
-    deadline = time.perf_counter() + timeout
-    while (any(w.first_token_at is None for w in lanes_t)
-           and time.perf_counter() < deadline):
-        if any(w.error for w in lanes_t):
-            raise RuntimeError(
-                f"active lane failed: {[w.error for w in lanes_t]}")
-        time.sleep(0.002)
-    if any(w.first_token_at is None for w in lanes_t):
-        raise RuntimeError(
-            "active lane produced no token before the timeout "
-            "(wedged engine?) — refusing to report a truncated p99")
-    t_inject = time.perf_counter()
-    long_t = _StreamLane(base_url, long_prompt, 8, timeout)
-    long_t.start()
-    tail_t = _StreamLane(base_url, tail_prompt, 8, timeout)
-    tail_t.start()
-    for w in lanes_t + [long_t, tail_t]:
-        w.join(timeout)
-    failed = [w.error for w in lanes_t + [long_t, tail_t] if w.error]
-    if failed:
-        # A lane that died mid-stream leaves a truncated event trail;
-        # computing a p99 from it would report an optimistic number as
-        # if the pass succeeded — fail the pass instead.
-        raise RuntimeError(f"mixed pass had failed requests: {failed}")
-    if long_t.first_token_at is None or tail_t.first_token_at is None:
-        raise RuntimeError("injected request produced no tokens")
-    # Active-lane per-token gaps inside [inject, long's first token] —
-    # the window a blocking admission would freeze.
-    t_end = long_t.first_token_at
-    gaps = []
-    for w in lanes_t:
-        prev = None
-        for t, n in w.events:
-            if prev is not None and t_inject <= t <= t_end and n:
-                gaps.extend([(t - prev) / n] * n)
-            prev = t
-    gaps.sort()
-    return {
-        "p99_inter_token_ms_active": round(
-            1e3 * _percentile(gaps, 0.99), 3),
-        "max_gap_ms_active": round(1e3 * gaps[-1], 3) if gaps else 0.0,
-        "ttft_long_ms": round(1e3 * (long_t.first_token_at - t_inject),
-                              2),
-        "ttft_short_behind_long_ms": round(
-            1e3 * (tail_t.first_token_at - t_inject), 2),
-    }
-
-
-def bench_gateway_mixed(preset, slots, chunk, max_queue, seed, timeout,
-                        prefill_chunk=16, long_pieces=6, reps=3):
-    """The gateway face of the --mixed A/B: same workload as
-    bench_serving --mixed but through HTTP streaming clients, so the
-    inter-token gaps and TTFTs are what a USER of the gateway observes
-    (driver/stream overhead included).  Interleave ON vs the
-    prefill_budget=0 kill switch."""
-    import jax
-    import jax.numpy as jnp
-
-    from tensorflow_train_distributed_tpu.models.llama import (
-        LLAMA_PRESETS, LlamaModel,
-    )
-    from tensorflow_train_distributed_tpu.server import ServingGateway
-    from tensorflow_train_distributed_tpu.serving import ServingEngine
-
-    cfg = LLAMA_PRESETS[preset]
-    vocab = min(cfg.vocab_size, 30_000)
-    params = LlamaModel(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    import numpy as np
-
-    rng = np.random.default_rng(seed + 1)
-    lanes = max(1, slots - 2)
-    long_len = prefill_chunk * long_pieces
-    long_prompt = [int(t) for t in rng.integers(1, vocab, long_len)]
-    tail_prompt = [int(t) for t in rng.integers(1, vocab, 8)]
-    active_new = chunk * (long_pieces + 6)
-    cache_len = max(long_len + 16, 8 + active_new + 8)
-
-    def one_mode(interleave):
-        eng = ServingEngine(cfg, params, slots=slots, chunk=chunk,
-                            cache_len=cache_len,
-                            prefill_chunk=prefill_chunk,
-                            prefill_budget=None if interleave else 0)
-        gw = ServingGateway(eng, host="127.0.0.1", port=0,
-                            max_queue=max_queue).start()
-        url = f"http://127.0.0.1:{gw.port}"
-        try:
-            args = (url, lanes, active_new, long_prompt, tail_prompt,
-                    vocab, seed, timeout)
-            _mixed_gateway_pass(*args)          # warmup: compiles
-            stall0 = eng.prefill_stall_s()      # exclude the warmup
-            best = None
-            n = max(1, reps)
-            for _ in range(n):
-                rec = _mixed_gateway_pass(*args)
-                if (best is None or rec["p99_inter_token_ms_active"]
-                        < best["p99_inter_token_ms_active"]):
-                    best = rec
-            # MEAN per-pass stall over the timed reps — the same
-            # single-pass semantics as bench_serving --mixed's field,
-            # so the two tools' A/B records are comparable.
-            best["prefill_stall_s"] = round(
-                (eng.prefill_stall_s() - stall0) / n, 4)
-            return best
-        finally:
-            gw.drain(timeout=30)
-
-    on = one_mode(True)
-    off = one_mode(False)
-    dev = jax.devices()[0]
-    rec = {
-        "metric": f"{preset}_gateway_mixed_p99_inter_token_ms",
-        "value": on["p99_inter_token_ms_active"],
-        "unit": "ms p99 active-lane inter-token during long admission",
-        "slots": slots,
-        "chunk": chunk,
-        "prefill_chunk": prefill_chunk,
-        "long_prompt_len": long_len,
-        "long_pieces": long_pieces,
-        "interleave": on,
-        "no_interleave": off,
-        "backend": dev.platform,
-        "device_kind": dev.device_kind,
-    }
-    if on["p99_inter_token_ms_active"]:
-        rec["p99_improvement"] = round(
-            off["p99_inter_token_ms_active"]
-            / on["p99_inter_token_ms_active"], 3)
-    return rec
-
-
 def bench_gateway(base_url, preset, slots, chunk, max_queue, clients,
                   requests_per_client, prompt_range, new_range,
-                  cache_len, seed, timeout, overlap_ab=True,
-                  replicas=1):
+                  cache_len, seed, timeout, replicas=1):
     loop_args = (clients, requests_per_client, prompt_range, new_range)
 
     def finish(rec):
@@ -460,7 +260,7 @@ def bench_gateway(base_url, preset, slots, chunk, max_queue, clients,
 
     if base_url:
         # External gateway: its engine is whatever it was launched
-        # with — no overlap A/B possible from here.
+        # with.
         vocab = 30_000       # conservative id ceiling
         return finish(_run_closed_loop(base_url, *loop_args, vocab,
                                        seed, timeout))
@@ -479,20 +279,17 @@ def bench_gateway(base_url, preset, slots, chunk, max_queue, clients,
     params = LlamaModel(cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
 
-    def one_mode(overlap):
-        engines = [ServingEngine(cfg, params, slots=slots, chunk=chunk,
-                                 cache_len=cache_len, overlap=overlap)
-                   for _ in range(replicas)]
-        gw = ServingGateway(engines if replicas > 1 else engines[0],
-                            host="127.0.0.1", port=0,
-                            max_queue=max_queue).start()
-        try:
-            return _run_closed_loop(f"http://127.0.0.1:{gw.port}",
-                                    *loop_args, vocab, seed, timeout)
-        finally:
-            gw.drain(timeout=30)
-
-    rec = finish(one_mode(overlap=True))
+    engines = [ServingEngine(cfg, params, slots=slots, chunk=chunk,
+                             cache_len=cache_len)
+               for _ in range(replicas)]
+    gw = ServingGateway(engines if replicas > 1 else engines[0],
+                        host="127.0.0.1", port=0,
+                        max_queue=max_queue).start()
+    try:
+        rec = finish(_run_closed_loop(f"http://127.0.0.1:{gw.port}",
+                                      *loop_args, vocab, seed, timeout))
+    finally:
+        gw.drain(timeout=30)
     dev = jax.devices()[0]
     rec["backend"] = dev.platform
     rec["device_kind"] = dev.device_kind
@@ -500,14 +297,6 @@ def bench_gateway(base_url, preset, slots, chunk, max_queue, clients,
     rows = cache_len or cfg.max_positions
     rec.update(decode_mbu_fields(cfg, n_params, slots, rows,
                                  rec["value"]))
-    if overlap_ab:
-        off = one_mode(overlap=False)
-        off.update(decode_mbu_fields(cfg, n_params, slots, rows,
-                                     off["tokens_per_sec"]))
-        rec["no_overlap"] = off
-        if rec["value"] and off["tokens_per_sec"]:
-            rec["overlap_speedup"] = round(
-                rec["value"] / off["tokens_per_sec"], 3)
     return rec
 
 
@@ -1020,7 +809,7 @@ def main(argv=None) -> int:
     p.add_argument("--replicas", type=int, default=1,
                    help="engine replicas behind the in-process gateway "
                         "(load + KV-affinity routed; ignored with "
-                        "--base-url and --mixed)")
+                        "--base-url)")
     p.add_argument("--replica-procs", action="store_true",
                    help="A/B subprocess replica workers "
                         "(server.procpool) against in-process "
@@ -1060,27 +849,8 @@ def main(argv=None) -> int:
                    help="0 -> config.max_positions")
     p.add_argument("--timeout", type=float, default=300.0,
                    help="client-side HTTP timeout per request")
-    p.add_argument("--no-ab", action="store_true",
-                   help="skip the overlap-OFF leg of the async-decode "
-                        "pipelining A/B (in-process runs only)")
-    p.add_argument("--mixed", action="store_true",
-                   help="mixed long/short workload instead of the "
-                        "closed loop: streaming clients decode on most "
-                        "lanes, one LONG prompt is injected mid-stream, "
-                        "and interleaved prefill is A/B'd against the "
-                        "atomic-admission kill switch — reports the "
-                        "client-observed p99 inter-token latency "
-                        "during the admission plus injected TTFTs "
-                        "(in-process runs only)")
-    p.add_argument("--prefill-chunk", type=int, default=16,
-                   help="--mixed only: prefill piece size (one budget "
-                        "installment)")
-    p.add_argument("--long-pieces", type=int, default=6,
-                   help="--mixed only: budget installments the long "
-                        "prompt spans")
     p.add_argument("--reps", type=int, default=3,
-                   help="--mixed: passes per leg (best p99 wins); "
-                        "--replica-procs: back-to-back A/B pairs "
+                   help="--replica-procs: back-to-back A/B pairs "
                         "(median of per-pair wall ratios)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--platform", default="",
@@ -1097,24 +867,19 @@ def main(argv=None) -> int:
         force_platform(args.platform)
     prompt_range = tuple(int(x) for x in args.prompt_range.split(","))
     new_range = tuple(int(x) for x in args.new_range.split(","))
-    if args.mixed and args.base_url:
-        raise SystemExit("--mixed builds its own A/B gateways "
-                         "in-process; it cannot target --base-url")
-    if args.replica_procs and (args.base_url or args.mixed):
+    if args.replica_procs and args.base_url:
         raise SystemExit("--replica-procs builds its own A/B gateways "
-                         "in-process; it composes with neither "
-                         "--base-url nor --mixed")
-    if args.disagg and (args.base_url or args.mixed
-                        or args.replica_procs):
+                         "in-process; it does not compose with "
+                         "--base-url")
+    if args.disagg and (args.base_url or args.replica_procs):
         raise SystemExit("--disagg builds its own A/B fleets "
-                         "in-process; it composes with none of "
-                         "--base-url, --mixed, --replica-procs")
-    if args.migrate_drain and (args.base_url or args.mixed
-                               or args.replica_procs or args.disagg):
+                         "in-process; it composes with neither "
+                         "--base-url nor --replica-procs")
+    if args.migrate_drain and (args.base_url or args.replica_procs
+                               or args.disagg):
         raise SystemExit("--migrate-drain builds its own A/B gateways "
                          "in-process; it composes with none of "
-                         "--base-url, --mixed, --replica-procs, "
-                         "--disagg")
+                         "--base-url, --replica-procs, --disagg")
     try:
         if args.migrate_drain:
             rec = bench_gateway_migrate_drain_ab(
@@ -1139,28 +904,18 @@ def main(argv=None) -> int:
                 args.cache_len or None, args.seed, args.timeout,
                 replicas=max(2, args.replicas),
                 reps=args.reps)
-        elif args.mixed:
-            rec = bench_gateway_mixed(
-                args.preset, args.slots, args.chunk,
-                args.max_queue, args.seed, args.timeout,
-                prefill_chunk=args.prefill_chunk,
-                long_pieces=args.long_pieces, reps=args.reps)
         else:
             rec = bench_gateway(
                 args.base_url, args.preset, args.slots, args.chunk,
                 args.max_queue, args.clients,
                 args.requests_per_client,
                 prompt_range, new_range, args.cache_len or None,
-                args.seed, args.timeout, overlap_ab=not args.no_ab,
+                args.seed, args.timeout,
                 replicas=max(1, args.replicas))
     except Exception as e:
-        metric = (f"{args.preset}_gateway_mixed_p99_inter_token_ms"
-                  if args.mixed
-                  else f"{args.preset}_gateway_tokens_per_sec")
-        unit = ("ms p99 active-lane inter-token during long admission"
-                if args.mixed else "generated tokens/sec")
         print(json.dumps({
-            "metric": metric, "value": 0.0, "unit": unit,
+            "metric": f"{args.preset}_gateway_tokens_per_sec",
+            "value": 0.0, "unit": "generated tokens/sec",
             "error": f"{type(e).__name__}: {e}"}), flush=True)
         return 1
     print(json.dumps(rec), flush=True)

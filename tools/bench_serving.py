@@ -2,24 +2,13 @@
 
 Serves a mixed-length synthetic request stream through
 ``serving.ServingEngine`` (slot-refill decode) and reports GENERATED
-tokens/sec plus p50 TTFT and mean inter-token latency.  By default the
-run is an A/B over async decode pipelining — overlap ON (the headline
-numbers) vs OFF (``no_overlap`` sub-record) — with the engine's
-``overlap_ratio`` (host-harvest share hidden under device compute)
-committed alongside; ``--no-ab`` skips the OFF leg.  ``--baseline``
-also times the static-batch path the engine replaces — same requests
+tokens/sec plus p50 TTFT and mean inter-token latency, with the
+engine's ``overlap_ratio`` (host-harvest share hidden under device
+compute) alongside.  ``--baseline`` also times the static-batch path the engine replaces — same requests
 grouped into arrival-order batches of ``--slots``, each batch padded to
 its longest prompt and decoded for its largest max_new (what
 ``generate()`` forces) — so the engine's win IS the padding/straggler
 waste it removes.
-
-``--mixed`` instead runs the tail-latency workload the interleaved
-prefill scheduler exists for: short requests decode on most lanes while
-one LONG prompt (spanning several ``--prefill-chunk`` budget
-installments) is injected mid-stream, A/B'ing interleave ON vs the
-atomic-admission kill switch (``prefill_budget=0``) — reported are the
-active lanes' p99 inter-token latency during the admission window, the
-long and trailing-short TTFTs, and the engine's prefill-stall seconds.
 
 ``--trace-ab`` instead A/Bs the always-on flight recorder
 (``runtime.events``) against its ``TTD_NO_TRACE=1`` kill switch on
@@ -95,152 +84,6 @@ def _run_engine_timed(eng, reqs):
         if rid in first and rid in done_at and gen > 1:
             itls.append((done_at[rid] - first[rid]) / (gen - 1))
     return wall, ttfts, itls, sum(len(v) for v in out.values())
-
-
-def _mixed_pass(eng, active_prompts, active_new, long_prompt, long_new,
-                tail_prompt, tail_new):
-    """One mixed-workload pass: fill ``len(active_prompts)`` lanes,
-    wait until every lane is decoding, then inject one LONG prompt
-    plus one short prompt queued behind it.  Measures the active
-    lanes' per-token gaps during the long admission window
-    (submit → long's first token) — the head-of-line stall interleaved
-    prefill removes — plus both injected requests' TTFTs and the
-    engine's prefill-stall delta."""
-    ids = [eng.submit(p, active_new) for p in active_prompts]
-    plens = {rid: len(p) for rid, p in zip(ids, active_prompts)}
-    done: dict = {}
-    while not all(rid in done
-                  or eng.progress().get(rid, 0) > plens[rid]
-                  for rid in ids):
-        done.update(eng.serve_step())
-    stall0 = eng.prefill_stall_s()
-    counts = {rid: (len(done[rid]) if rid in done
-                    else eng.progress().get(rid, plens[rid]))
-              for rid in ids}
-    t_inject = time.perf_counter()
-    long_id = eng.submit(long_prompt, long_new)
-    tail_id = eng.submit(tail_prompt, tail_new)
-    gaps: list = []        # active-lane per-token gaps while admitting
-    ttft_long = ttft_tail = None
-    last = t_inject
-    while eng.pending():
-        step_done = eng.serve_step()
-        now = time.perf_counter()
-        done.update(step_done)
-        prog = eng.progress()
-        admitting = ttft_long is None
-        for rid in ids:
-            n_now = (len(done[rid]) if rid in done
-                     else prog.get(rid, counts[rid]))
-            d = n_now - counts[rid]
-            if d > 0 and admitting:
-                gaps.extend([(now - last) / d] * d)
-            counts[rid] = n_now
-        if ttft_long is None:
-            n = (len(done[long_id]) if long_id in done
-                 else prog.get(long_id, 0))
-            if n > len(long_prompt):
-                ttft_long = now - t_inject
-        if ttft_tail is None:
-            n = (len(done[tail_id]) if tail_id in done
-                 else prog.get(tail_id, 0))
-            if n > len(tail_prompt):
-                ttft_tail = now - t_inject
-        last = now
-    gaps.sort()
-    return {
-        "p99_inter_token_ms_active": round(
-            1e3 * _percentile(gaps, 0.99), 3),
-        "max_gap_ms_active": round(1e3 * gaps[-1], 3) if gaps else 0.0,
-        "ttft_long_ms": round(1e3 * ttft_long, 2),
-        "ttft_short_behind_long_ms": round(1e3 * ttft_tail, 2),
-        "prefill_stall_s": round(eng.prefill_stall_s() - stall0, 4),
-    }
-
-
-def bench_serving_mixed(preset, slots, chunk, cache_len, seed,
-                        prefill_chunk, long_pieces, reps=3):
-    """The --mixed A/B: long prompts arriving during active decode,
-    interleaved prefill ON (the headline) vs the atomic-admission kill
-    switch (``no_interleave`` sub-record).  The long prompt spans
-    ``long_pieces`` budget installments (``prefill_chunk`` tokens
-    each), so the OFF leg's admission blocks active lanes for the
-    whole prompt while the ON leg bounds each gap by one installment."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tensorflow_train_distributed_tpu.models.llama import (
-        LLAMA_PRESETS, LlamaModel,
-    )
-    from tensorflow_train_distributed_tpu.serving import ServingEngine
-
-    cfg = LLAMA_PRESETS[preset]
-    params = LlamaModel(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    vocab = min(cfg.vocab_size, 30_000)
-    rng = np.random.default_rng(seed)
-    # Two lanes stay free: one for the long admission, one for the
-    # tail short — so the tail's TTFT measures queueing behind the
-    # long prefill, not waiting for an active lane to retire.
-    lanes = max(1, slots - 2)
-    active_prompts = [list(rng.integers(1, vocab, 8))
-                      for _ in range(lanes)]
-    long_len = prefill_chunk * long_pieces
-    long_prompt = list(rng.integers(1, vocab, long_len))
-    tail_prompt = list(rng.integers(1, vocab, 8))
-    # Active lanes must outlive the admission window (~long_pieces
-    # steps of chunk tokens each) with margin.
-    active_new = chunk * (long_pieces + 6)
-    cache_len = cache_len or max(long_len + 16,
-                                 8 + active_new + 8)
-    if cache_len > cfg.max_positions:
-        raise ValueError(
-            f"mixed workload needs cache_len {cache_len} but the "
-            f"preset caps max_positions at {cfg.max_positions} — "
-            f"shrink --long-pieces/--prefill-chunk/--chunk")
-
-    def one_mode(interleave):
-        eng = ServingEngine(
-            cfg, params, slots=slots, chunk=chunk, cache_len=cache_len,
-            prefill_chunk=prefill_chunk,
-            prefill_budget=None if interleave else 0)
-        args = (eng, active_prompts, active_new, long_prompt, 8,
-                tail_prompt, 8)
-        _mixed_pass(*args)                  # warmup: compiles
-        best = None
-        for _ in range(max(1, reps)):
-            rec = _mixed_pass(*args)
-            if (best is None or rec["p99_inter_token_ms_active"]
-                    < best["p99_inter_token_ms_active"]):
-                best = rec
-        return best
-
-    on = one_mode(True)
-    off = one_mode(False)
-    dev = jax.devices()[0]
-    rec = {
-        "metric": f"{preset}_serving_mixed_p99_inter_token_ms",
-        "value": on["p99_inter_token_ms_active"],
-        "unit": "ms p99 active-lane inter-token during long admission",
-        "slots": slots,
-        "chunk": chunk,
-        "prefill_chunk": prefill_chunk,
-        "long_prompt_len": long_len,
-        "long_pieces": long_pieces,
-        "interleave": on,
-        "no_interleave": off,
-        "backend": dev.platform,
-        "device_kind": dev.device_kind,
-    }
-    if on["p99_inter_token_ms_active"]:
-        rec["p99_improvement"] = round(
-            off["p99_inter_token_ms_active"]
-            / on["p99_inter_token_ms_active"], 3)
-    if on["max_gap_ms_active"]:
-        rec["max_gap_improvement"] = round(
-            off["max_gap_ms_active"] / on["max_gap_ms_active"], 3)
-    return rec
 
 
 def bench_trace_ab(preset, slots, chunk, n_requests, prompt_range,
@@ -838,8 +681,8 @@ def bench_fused_attn_ab(preset, slots, chunk, n_requests, prompt_range,
 
 def bench_serving(preset, slots, chunk, n_requests, prompt_range,
                   new_range, cache_len, baseline, seed,
-                  draft_preset="", speculative_k=0, overlap_ab=True,
-                  kv_int8=False, reps=3):
+                  draft_preset="", speculative_k=0, kv_int8=False,
+                  reps=3):
     import dataclasses
 
     import jax
@@ -883,24 +726,18 @@ def bench_serving(preset, slots, chunk, n_requests, prompt_range,
         draft_params = LlamaModel(draft_cfg).init(
             jax.random.PRNGKey(1), jnp.zeros((1, 8), jnp.int32))["params"]
 
-    def make_engine(overlap):
-        return ServingEngine(
-            cfg, params, slots=slots, chunk=chunk, cache_len=cache_len,
-            draft_config=draft_cfg, draft_params=draft_params,
-            speculative_k=speculative_k if draft_cfg else 0,
-            overlap=overlap)
-
-    def warm(overlap):
-        # ONE engine for warmup + timed runs: the jitted programs are
-        # keyed on the engine instance (static self), so a fresh engine
-        # would pay every compile again inside the timed region.
-        # run()/serve_step are reentrant (tests/test_serving.py) —
-        # stale slot caches cannot contaminate.
-        e = make_engine(overlap)
-        for p, m in reqs:                          # warmup: compiles
-            e.submit(p, m)
-        e.run()
-        return e
+    # ONE engine for warmup + timed runs: the jitted programs are
+    # keyed on the engine instance (static self), so a fresh engine
+    # would pay every compile again inside the timed region.
+    # run()/serve_step are reentrant (tests/test_serving.py) — stale
+    # slot caches cannot contaminate.
+    eng = ServingEngine(
+        cfg, params, slots=slots, chunk=chunk, cache_len=cache_len,
+        draft_config=draft_cfg, draft_params=draft_params,
+        speculative_k=speculative_k if draft_cfg else 0)
+    for p, m in reqs:                              # warmup: compiles
+        eng.submit(p, m)
+    eng.run()
 
     def one_pass(e):
         # Zero the accounting per pass so the committed ratio
@@ -923,29 +760,15 @@ def bench_serving(preset, slots, chunk, n_requests, prompt_range,
             "overlapped_harvests": stats["overlapped_harvests"],
         }, total
 
-    # Best-of-``reps``, with the A/B legs INTERLEAVED (on, off, on,
-    # off, ...): single-pass walls on a shared/loaded host are noisy at
-    # these scales, min-wall reads through scheduler noise, and
-    # alternating the legs keeps slow drift in background load from
-    # biasing whichever leg runs later.
-    eng = warm(overlap=True)
-    eng_off = warm(overlap=False) if overlap_ab else None
-    best_on = best_off = None
-    for _ in range(max(1, reps)):
-        rec = one_pass(eng)
-        if best_on is None or rec[0] < best_on[0]:
-            best_on = rec
-        if eng_off is not None:
-            rec = one_pass(eng_off)
-            if best_off is None or rec[0] < best_off[0]:
-                best_off = rec
+    # Best-of-``reps``: single-pass walls on a shared/loaded host are
+    # noisy at these scales, and min-wall reads through scheduler noise.
+    best_on = min((one_pass(eng) for _ in range(max(1, reps))),
+                  key=lambda rec: rec[0])
     on_rec, total_len = summarize(best_on)
     dt = on_rec["wall_s"]
     dev = jax.devices()[0]
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     rows = cache_len or cfg.max_positions
-    mbu_of = lambda tps: decode_mbu_fields(  # noqa: E731 (leg helper)
-        cfg, n_params, slots, rows, tps, kv_int8)
     # Ceiling ('self') and floor (random-init) runs must be
     # distinguishable by metric name alone, not just the draft_preset
     # field — and int8-KV runs by the _kv8 suffix (the bench_lm
@@ -972,19 +795,11 @@ def bench_serving(preset, slots, chunk, n_requests, prompt_range,
         "backend": dev.platform,
         "device_kind": dev.device_kind,
     }
-    rec.update(mbu_of(on_rec["tokens_per_sec"]))
+    rec.update(decode_mbu_fields(cfg, n_params, slots, rows,
+                                 on_rec["tokens_per_sec"], kv_int8))
     if kv_int8:
         rec["kv_cache"] = "int8"
         rec["kv_pool_bytes"] = eng.kv_pool_bytes()
-    if overlap_ab:
-        # The OFF leg: the synchronous path the TTD_NO_OVERLAP kill
-        # switch restores — the host-stall A/B the headline claims.
-        off_rec, _ = summarize(best_off)
-        off_rec.update(mbu_of(off_rec["tokens_per_sec"]))
-        rec["no_overlap"] = off_rec
-        if off_rec["wall_s"]:
-            rec["overlap_speedup"] = round(
-                off_rec["wall_s"] / dt, 3) if dt else 0.0
     if draft_preset:
         rec["draft_preset"] = draft_preset
         rec["speculative_k"] = speculative_k
@@ -1223,17 +1038,6 @@ def main(argv=None) -> int:
                         "for itself, the acceptance CEILING — the pair "
                         "brackets real trained drafts)")
     p.add_argument("--speculative-k", type=int, default=4)
-    p.add_argument("--no-ab", action="store_true",
-                   help="skip the overlap-OFF leg of the async-decode "
-                        "pipelining A/B (halves the timed work)")
-    p.add_argument("--mixed", action="store_true",
-                   help="mixed long/short workload instead of the "
-                        "throughput run: fill the lanes with short "
-                        "decoders, inject one LONG prompt mid-stream, "
-                        "and A/B interleaved prefill ON vs the atomic-"
-                        "admission kill switch — reports active lanes' "
-                        "p99 inter-token latency during the admission "
-                        "plus the injected requests' TTFTs")
     p.add_argument("--shared-prefix", action="store_true",
                    help="paged-KV prefix-sharing A/B instead of the "
                         "throughput run: every request shares one "
@@ -1303,13 +1107,6 @@ def main(argv=None) -> int:
                         "this d_model so decode is weight-streaming "
                         "bound (the CPU leg's sizing; 0 = preset "
                         "unchanged, the TPU leg)")
-    p.add_argument("--prefill-chunk", type=int, default=16,
-                   help="--mixed only: prefill piece size (one budget "
-                        "installment)")
-    p.add_argument("--long-pieces", type=int, default=6,
-                   help="--mixed only: budget installments the long "
-                        "prompt spans (its length = pieces * "
-                        "prefill_chunk)")
     p.add_argument("--reps", type=int, default=3,
                    help="timed passes per leg; min wall is reported "
                         "(reads through host scheduler noise)")
@@ -1329,13 +1126,7 @@ def main(argv=None) -> int:
     prompt_range = tuple(int(x) for x in args.prompt_range.split(","))
     new_range = tuple(int(x) for x in args.new_range.split(","))
     try:
-        if args.mixed:
-            rec = bench_serving_mixed(
-                args.preset, args.slots, args.chunk,
-                args.cache_len or None, args.seed,
-                args.prefill_chunk, args.long_pieces,
-                reps=args.reps)
-        elif args.shared_prefix:
+        if args.shared_prefix:
             rec = bench_paged_kv_ab(
                 args.preset, args.slots, args.chunk, args.requests,
                 args.prefix_len, args.cache_len or None, args.seed,
@@ -1379,14 +1170,10 @@ def main(argv=None) -> int:
                                 args.seed,
                                 draft_preset=args.speculative_draft,
                                 speculative_k=args.speculative_k,
-                                overlap_ab=not args.no_ab,
                                 kv_int8=args.kv_int8,
                                 reps=args.reps)
     except Exception as e:
-        if args.mixed:
-            metric = f"{args.preset}_serving_mixed_p99_inter_token_ms"
-            unit = "ms p99 active-lane inter-token during long admission"
-        elif args.shared_prefix:
+        if args.shared_prefix:
             metric = (f"{args.preset}_serving_paged_kv_shared_prefix_"
                       f"ttft_improvement")
             unit = "x TTFT p50, shared-prefix paged vs linear"

@@ -145,12 +145,6 @@ def add_engine_args(p) -> None:
                         "(dense compiles one prefill program per "
                         "distinct prompt length and refuses "
                         "--prefix). Default: the config's own setting")
-    p.add_argument("--no-overlap", action="store_true",
-                   help="disable async decode pipelining (the engine's "
-                        "one-chunk-lookahead host/device overlap); "
-                        "TTD_NO_OVERLAP=1 is the no-redeploy "
-                        "equivalent. Outputs are bitwise-identical "
-                        "either way — this is a perf kill switch")
     p.add_argument("--prefill-chunk", type=int, default=None,
                    help="prefill prompts in fixed-size pieces of this "
                         "many tokens (ONE compiled program at any "
@@ -164,16 +158,8 @@ def add_engine_args(p) -> None:
                         "prompt's prefill interleaves with active "
                         "lanes' decode chunks instead of blocking "
                         "them). Default: one prefill piece per step; "
-                        "0 restores atomic admission")
-    p.add_argument("--no-interleave", action="store_true",
-                   help="disable the interleaved prefill scheduler "
-                        "(same as --prefill-budget 0: a request's "
-                        "whole prefill runs inline at admission, "
-                        "stalling active decode lanes for its "
-                        "length); TTD_NO_INTERLEAVE=1 is the "
-                        "no-redeploy equivalent. Outputs are "
-                        "bitwise-identical either way — this is a "
-                        "scheduling kill switch")
+                        "a budget as large as the prompts admits a "
+                        "whole prompt in one step; 0 is refused")
     p.add_argument("--kv-block-size", type=int, default=16,
                    help="paged KV cache: rows per physical block. "
                         "Smaller blocks = finer prefix sharing and "
@@ -323,10 +309,8 @@ def build_engine(args, cfg, is_moe, prefix_ids):
             speculative_k=(spec_k if draft_cfg is not None else 0),
             spec_depths=(spec_depths if draft_cfg is not None
                          else None),
-            overlap=not getattr(args, "no_overlap", False),
             prefill_chunk=getattr(args, "prefill_chunk", None),
-            prefill_budget=(0 if getattr(args, "no_interleave", False)
-                            else getattr(args, "prefill_budget", None)),
+            prefill_budget=getattr(args, "prefill_budget", None),
             paged=not getattr(args, "no_paged_kv", False),
             kv_block_size=getattr(args, "kv_block_size", 16),
             kv_pool_blocks=getattr(args, "kv_pool_blocks", None),

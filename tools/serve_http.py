@@ -14,8 +14,8 @@ and times out requests concurrently:
 - ``GET /metrics``  Prometheus text: request/token counters, queue
   depth, slot occupancy (decoding + prefilling lanes), TTFT /
   inter-token / latency histograms, the engine's overlap ratio,
-  ``ttd_engine_prefill_stall_seconds`` (decode time lost to atomic
-  admission — ~0 with the default interleaved prefill scheduler), and
+  ``ttd_engine_prefill_stall_seconds`` (decode time lost behind an
+  admission with no decode chunk in flight: 0 from this engine), and
   the paged-KV cache economics: ``ttd_engine_kv_blocks_in_use`` /
   ``ttd_engine_kv_blocks_total`` (admission is block-keyed by
   default), ``ttd_engine_prefix_hit_tokens_total`` (prefill skipped
