@@ -632,8 +632,9 @@ class MultiHeadAttention(nn.Module):
                 mask = jnp.concatenate(
                     [(jnp.arange(self.sinks) <= cur)[None, :], mask],
                     axis=1)
-            return self._cache_attend(q, kc, vc, mask[None, None],
-                                      kv_heads, b, q_len, x.shape[-1])
+            return self._cache_attend(q, kc, vc, kv_heads, b, q_len,
+                                      x.shape[-1], mask=mask[None, None])
+        scales = None
         if self.kv_cache_int8:
             # Quantize this call's rows: amax over head_dim per
             # (batch, position, kv_head) — the shared recipe.
@@ -645,30 +646,18 @@ class MultiHeadAttention(nn.Module):
                 cache_v.value, qv, (0, cur, 0, 0))
             kv_scales.value = jax.lax.dynamic_update_slice(
                 kv_scales.value, jnp.stack([sk, sv]), (0, 0, cur, 0))
-            # Dequant at read: XLA fuses the convert+multiply into the
-            # attention einsum's cache read (int8 bytes off HBM).
-            kc = (cache_k.value.astype(self.dtype)
-                  * kv_scales.value[0][..., None].astype(self.dtype))
-            vc = (cache_v.value.astype(self.dtype)
-                  * kv_scales.value[1][..., None].astype(self.dtype))
+            scales = tuple(kv_scales.value)     # dequant at read
         else:
             cache_k.value = jax.lax.dynamic_update_slice(
                 cache_k.value, k.astype(kdt), (0, cur, 0, 0))
             cache_v.value = jax.lax.dynamic_update_slice(
                 cache_v.value, v.astype(kdt), (0, cur, 0, 0))
-            kc, vc = cache_k.value, cache_v.value
-        kv_pos = jnp.arange(cache_rows)
-        mask = kv_pos[None, :] <= positions[:, None]   # [q, cache]
-        if self.window is not None:
-            # Linear cache + window: the last `window` positions
-            # (including self) and the sink prefix stay visible.
-            band = kv_pos[None, :] > positions[:, None] - self.window
-            if self.sinks:
-                band = jnp.logical_or(band, (kv_pos < self.sinks)[None, :])
-            mask = jnp.logical_and(mask, band)
-        return self._cache_attend(q, kc, vc,
-                                  mask[None, None], kv_heads, b, q_len,
-                                  x.shape[-1])
+        # A window no shorter than the cache (the linear cache under a
+        # window) hides no row a position inside the cache can see, so
+        # the prefix rule is the whole mask here.
+        return self._cache_attend(q, cache_k.value, cache_v.value,
+                                  kv_heads, b, q_len, x.shape[-1],
+                                  start=cur, scales=scales)
 
     def _sink_buffers(self, b, kv_heads):
         """The StreamingLLM sink KV buffer pair ([B, sinks, Hkv, D])."""
@@ -706,7 +695,10 @@ class MultiHeadAttention(nn.Module):
         and the causal mask compares against per-slot positions.  A
         refilled slot's stale rows are harmless: position p's row is
         always rewritten before any query can attend it (mask is
-        kv_pos <= position and writes happen first).
+        kv_pos <= position and writes happen first).  The attention
+        walks the row tiles the longest slot holds and no others
+        (``_cache_attend``): a prefill piece at the head of a long
+        cache does not pay for the rows behind it.
 
         ``kv_cache_int8`` composes: rows store int8 with the shared
         per-(slot, position, kv_head) scale recipe
@@ -743,6 +735,7 @@ class MultiHeadAttention(nn.Module):
 
         kdt = cache_k.value.dtype
         bidx = jnp.arange(b)[:, None]
+        scales = None
         if self.kv_cache_int8:
             qk, sk = _quantize_kv_rows(k)
             qv, sv = _quantize_kv_rows(v)
@@ -750,21 +743,15 @@ class MultiHeadAttention(nn.Module):
             cache_v.value = cache_v.value.at[bidx, positions].set(qv)
             kv_scales.value = kv_scales.value.at[
                 :, bidx, positions].set(jnp.stack([sk, sv]))
-            kc = (cache_k.value.astype(self.dtype)
-                  * kv_scales.value[0][..., None].astype(self.dtype))
-            vc = (cache_v.value.astype(self.dtype)
-                  * kv_scales.value[1][..., None].astype(self.dtype))
+            scales = tuple(kv_scales.value)
         else:
             cache_k.value = cache_k.value.at[bidx, positions].set(
                 k.astype(kdt))
             cache_v.value = cache_v.value.at[bidx, positions].set(
                 v.astype(kdt))
-            kc, vc = cache_k.value, cache_v.value
-        kv_pos = jnp.arange(self.cache_len)
-        mask = kv_pos[None, None, :] <= positions[:, :, None]  # [B,q,C]
-        return self._cache_attend(q, kc, vc,
-                                  mask[:, None], kv_heads, b, q_len,
-                                  x.shape[-1])
+        return self._cache_attend(q, cache_k.value, cache_v.value,
+                                  kv_heads, b, q_len, x.shape[-1],
+                                  start=cur, scales=scales)
 
     def _paged_decode_step(self, x, kv_pools=None):
         """Per-slot decode over the PAGED pool: same append-and-attend
@@ -916,43 +903,62 @@ class MultiHeadAttention(nn.Module):
                 pool, table.value + block0, self.cache_len).reshape(
                     b, self.cache_len, kv_heads, self.head_dim)
 
-        kc, vc = lane_view(k_pool), lane_view(v_pool)
-        if self.kv_cache_int8:
-            ks = pk.paged_kv_gather(k_scales, table.value, self.cache_len)
-            vs = pk.paged_kv_gather(v_scales, table.value, self.cache_len)
-            kc = kc.astype(self.dtype) * ks[..., None].astype(self.dtype)
-            vc = vc.astype(self.dtype) * vs[..., None].astype(self.dtype)
-        kv_pos = jnp.arange(self.cache_len)
-        mask = kv_pos[None, None, :] <= positions[:, :, None]  # [B,q,C]
-        return self._cache_attend(q, kc, vc, mask[:, None], kv_heads, b,
-                                  q_len, x.shape[-1]), pools
+        if self.kv_cache_int8:      # else ``scales`` is None already
+            scales = tuple(
+                pk.paged_kv_gather(s, table.value, self.cache_len)
+                for s in (k_scales, v_scales))
+        return self._cache_attend(
+            q, lane_view(k_pool), lane_view(v_pool), kv_heads, b, q_len,
+            x.shape[-1], start=cur, scales=scales), pools
 
     def _fused_paged_ok(self) -> bool:
         return fused_paged_ok()
 
-    def _cache_attend(self, q, kc, vc, mask, kv_heads, b, q_len, features):
-        """Masked einsum attention of q over the cache buffers."""
+    def _cache_attend(self, q, kc, vc, kv_heads, b, q_len, features, *,
+                      start=None, mask=None, scales=None):
+        """Attention of q over the cache buffers [B, rows, kv_heads, D].
+
+        A linear cache hands in ``start`` [B] (or a scalar), the
+        position of each lane's first query: ``prefix_attention`` then
+        walks the row tiles the longest lane holds (``ops.attention.
+        prefix_tiles_walked``) with a running softmax, and repeats
+        grouped heads and dequantizes int8 rows (``scales``: the keys'
+        and the values', [B, rows, kv_heads]) a tile at a time, so a row
+        no lane holds is not read.  A cache of one tile is the ordinary
+        masked attention over all of it.  A ring (the rolling window
+        and its sinks) addresses no prefix: it hands in its own ``mask``
+        over every row."""
+        from tensorflow_train_distributed_tpu.ops.attention import (
+            dot_product_attention, prefix_attention,
+        )
+
         # Same logical sharding as the training path: under a tensor/fsdp
         # mesh the cache reads and attention activations shard over heads
         # rather than replicating (B, cache_len, H, D) per device.
         kv_ax = self._head_ax(kv_heads)
-        kh = nn.with_logical_constraint(
-            kc, ("batch", "length", kv_ax, "kv"))
-        vh = nn.with_logical_constraint(
-            vc, ("batch", "length", kv_ax, "kv"))
-        if kv_heads != self.num_heads:
-            rep = self.num_heads // kv_heads
-            kh = jnp.repeat(kh, rep, axis=2)
-            vh = jnp.repeat(vh, rep, axis=2)
-        # [B, S, H, D] → [B, H, S, D].
-        qh = q.transpose(0, 2, 1, 3)
-        kh = kh.transpose(0, 2, 1, 3)
-        vh = vh.transpose(0, 2, 1, 3)
-        from tensorflow_train_distributed_tpu.ops.attention import (
-            dot_product_attention,
-        )
+        rep = self.num_heads // kv_heads
 
-        out = dot_product_attention(qh, kh, vh, mask=mask)
+        def heads(rows):
+            """(k, v) of every head, [B, H, T, D], from T cache rows."""
+            kv = rows[:2]
+            if rows[2] is not None:
+                # Dequant at read: XLA fuses the convert+multiply into
+                # the attention einsum's cache read (int8 bytes off HBM).
+                kv = [c.astype(self.dtype)
+                      * s[..., None].astype(self.dtype)
+                      for c, s in zip(kv, rows[2])]
+            kv = [nn.with_logical_constraint(
+                c, ("batch", "length", kv_ax, "kv")) for c in kv]
+            if rep != 1:
+                kv = [jnp.repeat(c, rep, axis=2) for c in kv]
+            return [c.transpose(0, 2, 1, 3) for c in kv]
+
+        qh = q.transpose(0, 2, 1, 3)        # [B, S, H, D] → [B, H, S, D]
+        if start is None:
+            out = dot_product_attention(qh, *heads((kc, vc, scales)),
+                                        mask=mask)
+        else:
+            out = prefix_attention(qh, (kc, vc, scales), start, heads)
         out = out.transpose(0, 2, 1, 3)
         return self._attn_epilogue(out, b, q_len, features)
 
@@ -1039,8 +1045,8 @@ class MultiHeadAttention(nn.Module):
             end = jnp.mod(cur + q_len, w)
             cache_k.value = jnp.roll(kcat[:, -w:], end, axis=1)
             cache_v.value = jnp.roll(vcat[:, -w:], end, axis=1)
-        return self._cache_attend(q, kcat, vcat, keep[None, None],
-                                  kv_heads, b, q_len, x.shape[-1])
+        return self._cache_attend(q, kcat, vcat, kv_heads, b, q_len,
+                                  x.shape[-1], mask=keep[None, None])
 
 
 def _pad_last(x, width: int):
@@ -1104,10 +1110,13 @@ class LatentAttention(nn.Module):
 
     Two ways to attend, the same mathematics:
 
-    - **up-projected** (training forward, and every multi-token call on
-      the linear cache, so the engine's prefill pieces): keys and values
-      of all heads are made from the rows (``Wkv_b``) and attention is
-      the ordinary one at head size nope + rope.  Per cached row and
+    - **up-projected** (training forward, and every call on the linear
+      cache, so the engine's prefill pieces): keys and values of all
+      heads are made from the rows (``Wkv_b``) and attention is the
+      ordinary one at head size nope + rope; over the linear cache it
+      walks, tile by tile with a running softmax, the rows the call's
+      lanes hold and up-projects no others (``_linear_step``).  Per
+      cached row and
       query this costs 2·H·(nope+rope+v) operations against the
       absorbed form's 2·H·(2·rank+rope), so it is the cheaper one
       wherever many queries share the up-projection;
@@ -1185,10 +1194,12 @@ class LatentAttention(nn.Module):
                 self.kv_lora_rank, self.num_heads, per_head)
 
     @jax.named_scope("attn/kv_latent")
-    def _up_project(self, rows):
-        """(k, v) of every head from rows [B, T, row_store]."""
+    def _up_project(self, rows, kv_b):
+        """(k, v) of every head from rows [B, T, row_store] through
+        ``kv_b`` (``_kv_b()``, read by the caller: this runs inside the
+        tile loop of ``_linear_step``, where no parameter is read)."""
         kv = jnp.einsum("btc,chd->bthd", rows[..., :self.kv_lora_rank],
-                        self._kv_b())
+                        kv_b)
         k_r = jnp.broadcast_to(
             rows[..., None, self.kv_lora_rank:self.row_dim],
             (*rows.shape[:2], self.num_heads, self.qk_rope_dim))
@@ -1231,7 +1242,7 @@ class LatentAttention(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(x.shape[1]),
                                          x.shape[:2])
         q_nope, q_rope = self._queries(x, positions)
-        k, v = self._up_project(self._rows(x, positions))
+        k, v = self._up_project(self._rows(x, positions), self._kv_b())
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         o = multihead_attention_kernel(
             *(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True,
@@ -1240,11 +1251,15 @@ class LatentAttention(nn.Module):
 
     def _linear_step(self, x):
         """Append this call's rows to the linear cache and attend over
-        it, up-projected.  ``index`` is a scalar (``models.generate``)
-        or, under ``slot_decode``, one per batch row (the engine's
-        batch-1 prefill cache)."""
+        the rows the lanes hold, up-projected a tile at a time
+        (``ops.attention.prefix_attention``: a row past the longest
+        lane's last query is neither read nor up-projected; a cache of
+        one tile is the ordinary masked attention over all of it).
+        ``index`` is a scalar (``models.generate``) or, under
+        ``slot_decode``, one per batch row (the engine's batch-1
+        prefill cache)."""
         from tensorflow_train_distributed_tpu.ops.attention import (
-            dot_product_attention,
+            prefix_attention,
         )
 
         b, q_len, _ = x.shape
@@ -1265,13 +1280,13 @@ class LatentAttention(nn.Module):
             cache.value = cache.value.at[
                 jnp.arange(b)[:, None], positions].set(
                     rows.astype(cache.value.dtype), mode="drop")
-        k, v = self._up_project(cache.value)
+        kv_b = self._kv_b()
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        mask = (jnp.arange(self.cache_len)[None, None, :]
-                <= positions[:, :, None])                       # [B,q,C]
-        o = dot_product_attention(
-            *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
-            mask=mask[:, None]).transpose(0, 2, 1, 3)
+        o = prefix_attention(
+            q.transpose(0, 2, 1, 3), cache.value, cur,
+            lambda rows: [t.transpose(0, 2, 1, 3)
+                          for t in self._up_project(rows, kv_b)],
+        ).transpose(0, 2, 1, 3)
         return self._out(o, x.shape[-1])
 
     def _paged_step(self, x):

@@ -12,6 +12,11 @@ Dispatch contract: ``multihead_attention_kernel`` takes [B, H, S, D] q/k/v
 and routes to pallas on TPU when shapes are kernel-friendly, else to the
 reference einsum path (always used on CPU test meshes — it is also the
 numerics oracle the kernel is tested against).
+
+Decode-mode calls on a linear KV cache (the engine's prefill pieces,
+``generate()``) go through ``prefix_attention``: plain jax on every
+backend, it walks the row tiles the call's lanes hold with a running
+softmax, so a piece at the head of a long cache pays for its own rows.
 """
 
 from __future__ import annotations
@@ -72,6 +77,109 @@ def dot_product_attention(
         logits = jnp.where(mask, logits, mask_value)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
+
+
+#: Rows of a linear KV cache that ``prefix_attention`` folds into its
+#: running softmax a step (chosen on the chip: PERF.md section 6, PR 31).
+PREFIX_TILE = 512
+
+
+def prefix_tiles_walked(start, q_len: int, tile: int, cache_len: int):
+    """Tiles of ``tile`` rows that ``prefix_attention`` walks for lanes
+    whose ``q_len`` queries sit at positions ``start .. start + q_len -
+    1`` of a cache of ``cache_len`` rows: the queries of the longest
+    lane see rows ``0 .. max(start) + q_len - 1``, so ``ceil((max(start)
+    + q_len) / tile)`` tiles, never more than the cache has and never
+    fewer than one (row 0 is visible to every query, which keeps the
+    running softmax off an all-masked start).  One rule for the device's
+    trip count (a traced vector) and for the host's ``prefill/piece``
+    ``rows`` (a numpy integer): ``start`` needs ``max``, ``+``, ``//``,
+    ``clip``."""
+    return ((start.max() + (q_len + tile - 1)) // tile).clip(
+        1, -(-cache_len // tile))
+
+
+def prefix_attention(
+    q: jax.Array,
+    cache,
+    start: jax.Array,
+    kv_of,
+    *,
+    tile: Optional[int] = None,
+    softmax_scale: Optional[float] = None,
+) -> jax.Array:
+    """Attention of ``q`` [B, H, Q, D] over the prefix of a linear KV
+    cache that its lanes hold, tile by tile with a running softmax.
+
+    ``cache`` is a tree of arrays with the cache's rows on axis 1 ([B,
+    C, ...]: keys and values, their int8 scales, or latent rows);
+    ``kv_of(rows)`` makes the keys and values of every head, [B, H, T,
+    D] and [B, H, T, Dv], from a tree of T rows (repeat grouped heads,
+    dequantize, up-project: whatever the caller's cache needs, paid for
+    the rows walked and no others).  Lane ``b``'s queries sit at
+    positions ``start[b] + arange(Q)`` and see ``kv_pos <= position``,
+    this call's own rows (already in the cache) included.
+
+    Only ``prefix_tiles_walked`` tiles are read, a traced trip count:
+    one compiled program whatever the lanes hold, and its float32
+    scores are [B, H, Q, tile] and not [B, H, Q, C].  The arithmetic is
+    ``dot_product_attention``'s (scores scaled in the inputs' type,
+    softmax in float32, probabilities cast to the values' type before
+    the product) with the maximum, the sum and the accumulator carried
+    in float32 across tiles.  A cache of one tile IS that expression
+    over the whole cache under the mask, to the bit.  ``tile`` is
+    ``PREFIX_TILE`` unless a test makes a small cache walk several.
+    """
+    tile = PREFIX_TILE if tile is None else tile
+    cache_len = jax.tree.leaves(cache)[0].shape[1]
+    q_len = q.shape[-2]
+    start = jnp.asarray(start, jnp.int32).reshape(-1)     # [B] or [1]
+    positions = start[:, None, None] + jnp.arange(q_len)[:, None]
+
+    def keep(kv_pos):                       # [B | 1, 1, Q, rows]
+        return (kv_pos <= positions)[:, None]
+
+    if cache_len <= tile:
+        k, v = kv_of(cache)
+        return dot_product_attention(
+            q, k, v, mask=keep(jnp.arange(cache_len)),
+            softmax_scale=softmax_scale)
+
+    scale = (softmax_scale if softmax_scale is not None
+             else q.shape[-1] ** -0.5)
+    mask_value = jnp.finfo(jnp.float32).min / 2
+
+    def tile_kv(first):
+        # The last tile of a cache that is no multiple of ``tile``
+        # starts early enough to fit; the rows it shares with the tile
+        # before are masked below.
+        row0 = jnp.minimum(first, cache_len - tile)
+        return row0, kv_of(jax.tree.map(
+            lambda c: jax.lax.dynamic_slice_in_dim(c, row0, tile, axis=1),
+            cache))
+
+    def fold(t, carry):
+        m, l, acc = carry
+        row0, (k, v) = tile_kv(t * tile)
+        kv_pos = row0 + jnp.arange(tile)
+        s = (jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale).astype(
+            jnp.float32)
+        s = jnp.where(keep(kv_pos) & (kv_pos >= t * tile), s, mask_value)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
+                acc * alpha + jnp.einsum(
+                    "bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32))
+
+    v_like = jax.eval_shape(lambda: tile_kv(0)[1][1])
+    stat = jnp.full((*q.shape[:-1], 1), mask_value, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, prefix_tiles_walked(start, q_len, tile, cache_len), fold,
+        (stat, jnp.zeros_like(stat),
+         jnp.zeros((*q.shape[:-1], v_like.shape[-1]), jnp.float32)))
+    return (acc / l).astype(v_like.dtype)
 
 
 def local_attention_chunked(

@@ -112,7 +112,10 @@ CONTRACT = {
     "decode/wait": "overlapped",
     "decode/harvest": "overlapped",
     "prefill/request": "rid tokens",
-    "prefill/piece": "rid piece n_pieces tokens",
+    # rows: the cache rows the piece's attention walks (a prefix, in
+    # whole tiles: ops.attention.prefix_tiles_walked) of the cache_rows
+    # a lane's cache has
+    "prefill/piece": "rid piece n_pieces tokens rows cache_rows",
     "prefill/wait": "rid",
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",

@@ -130,6 +130,7 @@ from tensorflow_train_distributed_tpu.models.quant import (
     maybe_quant_variables,
     quantized_inference,
 )
+from tensorflow_train_distributed_tpu.ops import attention as attention_ops
 from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
     paged_blocks_walked,
 )
@@ -2373,10 +2374,20 @@ class ServingEngine:
         self._step_counts["pieces"] += 1
         if not draft:           # the draft's pieces re-run the same tokens
             self._step_counts["prefill_tokens"] += real
+        # The cache rows the piece's attention walks, by its own rule
+        # (``prefix_tiles_walked``): from row 0, whatever prefix was
+        # matched and gathered, to the end of the piece's last tile.
+        tile = attention_ops.PREFIX_TILE
+        rows = min(self.cache_len, tile * int(
+            attention_ops.prefix_tiles_walked(
+                np.int64(len(task.prompt) - len(task.work)
+                         + i * task.piece),
+                task.piece, tile, self.cache_len)))
         with self._ctx(), events.span(
                 "prefill/piece", rid=task.request_id,
                 piece=task.cursor + task.d_cursor,
-                n_pieces=task.n_pieces, tokens=real):
+                n_pieces=task.n_pieces, tokens=real, rows=rows,
+                cache_rows=self.cache_len):
             if task.cursor < task.n_pieces:
                 if task.cache_1 is None:
                     task.cache_1 = self._admission_cache_1(

@@ -83,6 +83,28 @@ def mesh8():
     return build_mesh(MeshConfig(data=-1))
 
 
+@pytest.fixture
+def walk_in_tiles(monkeypatch):
+    """``walk_in_tiles(tile)``: programs traced from here on walk their
+    linear KV caches in tiles of ``tile`` rows (``None``: the program's
+    own ``PREFIX_TILE``, which no test cache outgrows); returns the
+    list that collects the (q_len, tile, cache_len) of every walk
+    traced (``ops.attention.prefix_tiles_walked``'s arguments)."""
+    from tensorflow_train_distributed_tpu.ops import attention
+
+    def set_tile(tile):
+        walks = []
+        if tile is not None:
+            rule = attention.prefix_tiles_walked
+            monkeypatch.setattr(attention, "PREFIX_TILE", tile)
+            monkeypatch.setattr(
+                attention, "prefix_tiles_walked",
+                lambda start, *a: walks.append(a) or rule(start, *a))
+        return walks
+
+    return set_tile
+
+
 @pytest.fixture(scope="session")
 def mesh_2d():
     """2×4 data×tensor mesh (the DTensor-style 2-D layout)."""

@@ -361,6 +361,45 @@ def test_trace_report_prints_the_kv_walk_live_share(tmp_path, capsys):
     assert mod.kv_cache_summary(mod.load_events(str(path))) == {}
 
 
+def test_trace_report_prints_the_share_of_the_cache_prefill_walked(
+        tmp_path, capsys):
+    """``prefill/piece``'s ``rows`` (what the piece's attention walked)
+    beside ``cache_rows`` (a whole cache a piece, what it read before
+    the walk): the stage table's footnote sums both over the window's
+    pieces and prints walked / (cache_len x pieces); a trace from
+    before the attribute prints no such line."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(os.path.dirname(__file__),
+                                     "..", "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+
+    rec = Recorder(capacity=64)
+    for i, rows in enumerate((1024, 2048, 3072)):
+        with rec.span("prefill/piece", rid=7, piece=i, n_pieces=3,
+                      tokens=1024, rows=rows, cache_rows=8192):
+            pass
+    with rec.span("prefill/piece", rid=8):      # an older program's
+        pass
+    path = tmp_path / "trace.json"
+    rec.save(str(path))
+    assert mod.prefill_walk(mod.load_events(str(path))) == (
+        3, 6144, 24576)
+    assert mod.main([str(path)]) == 0
+    assert ("walked 6144 of 24576 cache rows in 3 pieces: share walked "
+            "0.250") in capsys.readouterr().out
+
+    old = Recorder(capacity=8)
+    with old.span("prefill/piece", rid=8):
+        pass
+    old.save(str(path))
+    assert mod.main([str(path)]) == 0
+    assert "share walked" not in capsys.readouterr().out
+
+
 # ── supervisor instants ────────────────────────────────────────────────
 
 

@@ -281,6 +281,44 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     assert True in waits and False in waits
 
 
+@pytest.mark.parametrize("tile, int8, want", [
+    (None, False, [32, 32, 32]),    # the cache of 32 is one tile
+    (4, False, [4, 8, 12]),         # a tile a piece: 1x, 2x, 3x the piece
+    (4, True, [4, 8, 12]),          # int8 rows, dequantized a tile
+    (8, False, [8, 8, 16]),         # two pieces a tile: whole tiles
+    (10, False, [10, 10, 20])])     # tiles the cache is no multiple of
+def test_a_piece_records_the_rows_its_attention_walks(
+        tiny, walk_in_tiles, tile, int8, want):
+    """``prefill/piece`` ``rows``: the cache rows the piece's attention
+    walks by ``ops.attention.prefix_tiles_walked`` (whole tiles from
+    row 0 through the piece's last row), beside ``cache_rows``; the
+    tokens do not depend on the tile."""
+    import dataclasses
+
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    cfg, params = tiny
+    cfg = dataclasses.replace(cfg, kv_cache_int8=int8)
+    prompt = [9, 8, 7, 6, 5, 4, 3, 2, 1]            # three pieces of 4
+
+    def serve():
+        eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
+                            prefill_chunk=4)
+        rec = events.get_recorder()
+        seq0 = rec.events_after(0)[0]
+        rid = eng.submit(prompt, 4)
+        out = eng.run()[rid]
+        return out, [e[5] for e in rec.events_after(seq0)[1]
+                     if e[0] == "prefill/piece"]
+
+    whole, _ = serve()
+    walk_in_tiles(tile)
+    out, pieces = serve()
+    assert out == whole
+    assert [p["rows"] for p in pieces] == want
+    assert {p["cache_rows"] for p in pieces} == {32}
+
+
 @pytest.mark.parametrize("killed", [False, True])
 def test_harvest_seconds_split_by_the_wait_span_and_outlive_the_kill_switch(
         tiny, killed, monkeypatch):

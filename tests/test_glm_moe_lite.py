@@ -203,14 +203,23 @@ def _serve(params, prompt, n_new, **kw):
     return eng.run()[rid][len(prompt):], eng
 
 
-def test_chunked_prefill_equals_whole_prefill_under_gmm(params):
+@pytest.mark.parametrize("tile", [None, 8], ids=["one-tile", "tile-8"])
+def test_chunked_prefill_equals_whole_prefill_under_gmm(
+        params, walk_in_tiles, tile):
     """Dropless routing takes every token by itself, so a prompt in
-    8-token pieces serves the tokens of the prompt in one piece."""
+    8-token pieces serves the tokens of the prompt in one piece.
+    ``tile-8``: the latent cache of 64 rows is walked in tiles of a
+    piece, so the three pieces up-project and attend over one, two and
+    three tiles of its eight (the whole-prompt engine before it walks
+    the cache as one tile: the whole-cache expression)."""
     prompt = _tokens(21, seed=3)
     whole, _ = _serve(params, prompt, 10, prompt_buckets=(32,))
+    walks = walk_in_tiles(tile)
     pieces, eng = _serve(params, prompt, 10, prefill_chunk=8)
     assert eng.prefill_stats["installments"] >= 3
     assert pieces == whole
+    # The pieces' walks (and the one-token trace that sizes a cache).
+    assert set(walks) == ({(8, 8, 64), (1, 8, 64)} if tile else set())
 
 
 @pytest.mark.parametrize("rows, want", [

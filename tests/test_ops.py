@@ -9,6 +9,8 @@ from tensorflow_train_distributed_tpu.models.layers import apply_rope
 from tensorflow_train_distributed_tpu.ops.attention import (
     dot_product_attention,
     multihead_attention_kernel,
+    prefix_attention,
+    prefix_tiles_walked,
 )
 from tensorflow_train_distributed_tpu.ops.losses import softmax_cross_entropy
 
@@ -90,6 +92,158 @@ class TestAttention:
                 causal=True, sm_scale=D**-0.5)
         np.testing.assert_allclose(np.asarray(out_flash), np.asarray(want),
                                    atol=2e-6)
+
+
+#: A 64-row cache in tiles of 16: up to four tiles to walk.
+_CACHE, _TILE = 64, 16
+
+
+def _prefix_case(form, dtype, q_len, lanes=1, seed=0):
+    """(q, cache, kv_of) for ``prefix_attention``: ``grouped`` is the
+    dense family's cache (2 KV heads serving 4, repeated a tile at a
+    time), ``latent`` the latent family's (rows of rank 6 + 2 rotary
+    values up-projected to 4 heads of 4 + 2 key and 5 value dims, as
+    ``layers.LatentAttention._up_project`` does)."""
+    ks = jax.random.split(jax.random.key(seed), 4)
+    if form == "grouped":
+        q = jax.random.normal(ks[0], (lanes, 4, q_len, 8), dtype)
+        cache = tuple(jax.random.normal(k, (lanes, _CACHE, 2, 8), dtype)
+                      for k in ks[1:3])
+
+        def kv_of(rows):
+            return [jnp.repeat(r, 2, axis=2).transpose(0, 2, 1, 3)
+                    for r in rows]
+    else:
+        q = jax.random.normal(ks[0], (lanes, 4, q_len, 6), dtype)
+        cache = jax.random.normal(ks[1], (lanes, _CACHE, 8), dtype)
+        w = jax.random.normal(ks[2], (6, 4, 9), dtype) * 0.4
+
+        def kv_of(rows):
+            kv = jnp.einsum("btc,chd->bthd", rows[..., :6], w)
+            k_r = jnp.broadcast_to(rows[..., None, 6:],
+                                   (*rows.shape[:2], 4, 2))
+            k = jnp.concatenate([kv[..., :4], k_r], axis=-1)
+            return [t.transpose(0, 2, 1, 3) for t in (k, kv[..., 4:])]
+    return q, cache, kv_of
+
+
+def _whole_cache_attention(q, cache, kv_of, start):
+    """Today's expression: every row of the cache under the mask."""
+    pos = jnp.asarray(start)[:, None] + jnp.arange(q.shape[2])
+    mask = jnp.arange(_CACHE)[None, None, :] <= pos[:, :, None]
+    return dot_product_attention(q, *kv_of(cache), mask=mask[:, None])
+
+
+class TestPrefixAttention:
+    """``prefix_attention``: a call on a linear KV cache walks the row
+    tiles its lanes hold, with a running softmax, and is the masked
+    attention over the whole cache."""
+
+    @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
+                                            (jnp.bfloat16, 2e-2)],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("form", ["grouped", "latent"])
+    @pytest.mark.parametrize("q_len", [1, 7, _TILE, 2 * _TILE])
+    @pytest.mark.parametrize("start", ["0", "tile-1", "tile", "end"])
+    def test_is_the_masked_attention_over_the_whole_cache(
+            self, start, q_len, form, dtype, tol):
+        start = {"0": 0, "tile-1": _TILE - 1, "tile": _TILE,
+                 "end": _CACHE - q_len}[start]
+        q, cache, kv_of = _prefix_case(form, dtype, q_len)
+        out = prefix_attention(q, cache, jnp.array([start]), kv_of,
+                               tile=_TILE)
+        ref = _whole_cache_attention(q, cache, kv_of, [start])
+        assert out.dtype == ref.dtype and out.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("form", ["grouped", "latent"])
+    @pytest.mark.parametrize("start, q_len", [(0, 7), (_TILE - 1, 1),
+                                              (_TILE, _TILE), (9, 2 * _TILE)])
+    def test_rows_past_the_last_walked_tile_are_never_read(
+            self, start, q_len, form):
+        """NaN in every row past the walked tiles changes nothing, where
+        the whole-cache expression gives NaN (a masked weight of 0 times
+        NaN): the witness that those rows are not read, not up-projected,
+        not repeated.  Rows inside the last walked tile past the last
+        query stay masked, as they always were."""
+        q, cache, kv_of = _prefix_case(form, jnp.float32, q_len)
+        tiles = int(prefix_tiles_walked(np.array([start]), q_len, _TILE,
+                                        _CACHE))
+        assert tiles < _CACHE // _TILE
+        poisoned = jax.tree.map(
+            lambda c: c.at[:, tiles * _TILE:].set(jnp.nan), cache)
+        clean = prefix_attention(q, cache, jnp.array([start]), kv_of,
+                                 tile=_TILE)
+        out = prefix_attention(q, poisoned, jnp.array([start]), kv_of,
+                               tile=_TILE)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+        assert np.isnan(np.asarray(_whole_cache_attention(
+            q, poisoned, kv_of, [start]))).any()
+
+    @pytest.mark.parametrize("form", ["grouped", "latent"])
+    def test_lanes_at_different_positions_walk_to_the_longest(self, form):
+        """One trip count a call: the longest lane's.  Every lane still
+        gets its own mask, and the tiles past the longest are not read."""
+        starts, q_len = [3, 30, 17], 5
+        q, cache, kv_of = _prefix_case(form, jnp.float32, q_len, lanes=3)
+        assert int(prefix_tiles_walked(
+            np.array(starts), q_len, _TILE, _CACHE)) == 3
+        poisoned = jax.tree.map(
+            lambda c: c.at[:, 3 * _TILE:].set(jnp.nan), cache)
+        out = jax.jit(lambda q, c, s: prefix_attention(
+            q, c, s, kv_of, tile=_TILE))(q, poisoned, jnp.array(starts))
+        np.testing.assert_allclose(
+            np.asarray(out),
+            np.asarray(_whole_cache_attention(q, cache, kv_of, starts)),
+            atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("form", ["grouped", "latent"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_a_cache_of_one_tile_is_the_whole_cache_expression_to_the_bit(
+            self, dtype, form):
+        q, cache, kv_of = _prefix_case(form, dtype, 7, lanes=2)
+        starts = [5, 40]
+        out = prefix_attention(q, cache, jnp.array(starts), kv_of,
+                               tile=_CACHE)
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32),
+            np.asarray(_whole_cache_attention(q, cache, kv_of, starts),
+                       np.float32))
+
+    @pytest.mark.parametrize("start", [0, 20, 37])
+    def test_a_cache_that_is_no_multiple_of_the_tile(self, start):
+        """40 rows in tiles of 16: the third tile starts at row 24, and
+        the rows it shares with the second count once."""
+        q, cache, kv_of = _prefix_case("grouped", jnp.float32, 3)
+        cache = tuple(c[:, :40] for c in cache)
+        out = prefix_attention(q, cache, jnp.array([start]), kv_of,
+                               tile=_TILE)
+        mask = jnp.arange(40)[None, :] <= start + jnp.arange(3)[:, None]
+        ref = dot_product_attention(q, *kv_of(cache),
+                                    mask=mask[None, None])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("tile, cache_len", [(16, 64), (16, 40),
+                                                 (512, 4096), (64, 32)])
+    def test_the_walk_rule_is_a_brute_force_count(self, tile, cache_len):
+        """Tiles holding a row some query may see: never none, never
+        past the cache (an overrun lane included), the longest lane's."""
+        n_tiles = -(-cache_len // tile)
+        for q_len in (1, 7, tile, 2 * tile):
+            for starts in ([0], [tile - 1], [tile], [cache_len - 1],
+                           [cache_len + 3 * tile], [0, 2 * tile + 1, 5]):
+                last = min(max(starts) + q_len, cache_len) - 1
+                want = max(1, sum(1 for t in range(n_tiles)
+                                  if t * tile <= last))
+                for xp in (np, jnp):
+                    got = int(prefix_tiles_walked(
+                        xp.asarray(starts), q_len, tile, cache_len))
+                    assert got == want and 1 <= got <= n_tiles
 
 
 class TestRope:

@@ -163,10 +163,14 @@ def test_moe_family_matches_generate():
         assert out[rid] == ref, f"moe request {rid}"
 
 
-def test_sharded_engine_matches_unsharded(params, mesh_2d):
+@pytest.mark.parametrize("tile", [None, 4], ids=["one-tile", "tile-4"])
+def test_sharded_engine_matches_unsharded(params, mesh_2d, walk_in_tiles,
+                                          tile):
     """Tensor-parallel serving: under a data×tensor mesh the engine's
     logical constraints shard weights/cache over ``tensor`` (GSPMD
-    inserts the collectives) and the outputs stay token-identical."""
+    inserts the collectives) and the outputs stay token-identical.
+    ``tile-4``: the constraints sit inside the tile loop of the cache
+    walk (up to all eight tiles of the cache of 32)."""
     reqs = [([3, 1, 4, 1, 5], 6), ([2, 7, 1], 8)]
 
     def serve(mesh):
@@ -176,7 +180,9 @@ def test_sharded_engine_matches_unsharded(params, mesh_2d):
         out = eng.run()
         return [out[i] for i in ids]
 
-    assert serve(None) == serve(mesh_2d)
+    want = serve(None)
+    walk_in_tiles(tile)
+    assert serve(mesh_2d) == want
 
 
 def test_expert_sharded_moe_serving_matches_unsharded():
@@ -291,19 +297,25 @@ class TestSampling:
             eng.submit([1, 2], 3, seed=2 ** 32)
 
 
-def test_chunked_prefill_matches_generate(params):
+@pytest.mark.parametrize("tile", [None, 4], ids=["one-tile", "tile-4"])
+def test_chunked_prefill_matches_generate(params, walk_in_tiles, tile):
     """prefill_chunk: prompts run through one per-piece program in
-    fixed-size pieces (lengths off and ON the piece boundary, plus one
-    shorter than a piece) — token-identical to generate()."""
+    fixed-size pieces (lengths off and ON the piece boundary, one
+    shorter than a piece, one of three pieces) — token-identical to
+    generate().  ``tile-4``: a tile is a piece, so the second and third
+    pieces of a prompt walk two and three tiles of the cache of eight,
+    and decode steps up to all of them."""
     rng = np.random.default_rng(12)
+    reqs = [(list(rng.integers(1, 200, n)), m)
+            for n, m in [(5, 6), (8, 5), (3, 7), (4, 4), (11, 5)]]
+    want = [_ref(params, p, m) for p, m in reqs]   # whole-cache attention
+    walks = walk_in_tiles(tile)
     eng = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=3,
                         prefill_chunk=4)
-    reqs = [(list(rng.integers(1, 200, n)), m)
-            for n, m in [(5, 6), (8, 5), (3, 7), (4, 4)]]
     ids = [eng.submit(p, m) for p, m in reqs]
     out = eng.run()
-    for rid, (p, m) in zip(ids, reqs):
-        assert out[rid] == _ref(params, p, m), f"request {rid}"
+    assert [out[rid] for rid in ids] == want
+    assert set(walks) == ({(4, 4, 32), (1, 4, 32)} if tile else set())
 
 
 def test_chunked_prefill_takes_over_bucket_prompts(params):
@@ -799,12 +811,18 @@ class TestPrefixCaching:
     """preload_prefix(): shared prompt prefixes prefill once; suffix
     prefill on a copied cache must be token-identical to full prefill."""
 
-    def test_prefix_reuse_matches_full_prefill(self, params):
+    @pytest.mark.parametrize("tile", [None, 4], ids=["one-tile", "tile-4"])
+    def test_prefix_reuse_matches_full_prefill(self, params, walk_in_tiles,
+                                               tile):
+        """``tile-4``: a suffix piece appended at row 6 of the copied
+        prefix cache walks from row 0 through its own last tile."""
         rng = np.random.default_rng(9)
         system = list(rng.integers(1, 200, 6))
         reqs = [(system + list(rng.integers(1, 200, d)), m)
                 for d, m in [(3, 6), (5, 5), (1, 7)]]
         reqs.append((list(rng.integers(1, 200, 4)), 5))  # no prefix match
+        want = [_ref(params, p, m) for p, m in reqs]
+        walks = walk_in_tiles(tile)
         eng = ServingEngine(CFG, params, slots=2, cache_len=64, chunk=4,
                             prompt_buckets=(8, 16))
         eng.preload_prefix(system)
@@ -822,9 +840,9 @@ class TestPrefixCaching:
         eng._prefill_piece = counting
         ids = [eng.submit(p, m) for p, m in reqs]
         out = eng.run()
-        for rid, (p, m) in zip(ids, reqs):
-            assert out[rid] == _ref(params, p, m), f"request {rid}"
+        assert [out[rid] for rid in ids] == want
         assert calls == [8, 8, 8, 8]   # suffix-sized pieces only
+        assert set(walks) == ({(8, 4, 64), (1, 4, 64)} if tile else set())
 
     def test_longest_prefix_wins_and_exact_prompt_is_excluded(self,
                                                               params):

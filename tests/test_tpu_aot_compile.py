@@ -181,6 +181,67 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _prefix_dense():
+    """A 1024-token prefill piece of qwen25_7b over its batch-1 cache of
+    4096 rows: 28 heads of 128 on 4 KV heads, repeated a tile at a
+    time."""
+    heads, kvh, hd, cache_len = 28, 4, 128, 4096
+    kv = ((1, cache_len, kvh, hd), BF16)
+
+    def fn(q, k, v, start):
+        return attention.prefix_attention(
+            q, (k, v), start,
+            lambda rows: [jnp.repeat(r, heads // kvh, axis=2).transpose(
+                0, 2, 1, 3) for r in rows])
+
+    return fn, (((1, heads, 1024, hd), BF16), kv, kv,
+                ((1,), jnp.int32)), (heads, 1024, cache_len)
+
+
+def _prefix_latent():
+    """A 1024-token piece of GLM-4.7-Flash over its cache of 8192 rows
+    of 512 + 64 values stored 640 wide: 20 heads of 192 + 64 key and
+    256 value dims, up-projected a tile at a time."""
+    heads, rank, nope, rope, vd, cache_len = 20, 512, 192, 64, 256, 8192
+
+    def fn(q, rows, w, start):
+        def kv_of(rows):
+            kv = jnp.einsum("btc,chd->bthd", rows[..., :rank], w)
+            k_r = jnp.broadcast_to(rows[..., None, rank:rank + rope],
+                                   (*rows.shape[:2], heads, rope))
+            k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
+            return [t.transpose(0, 2, 1, 3) for t in (k, kv[..., nope:])]
+
+        return attention.prefix_attention(q, rows, start, kv_of)
+
+    return fn, (((1, heads, 1024, nope + rope), BF16),
+                ((1, cache_len, 640), BF16),
+                ((rank, heads, nope + vd), BF16),
+                ((1,), jnp.int32)), (heads, 1024, cache_len)
+
+
+@pytest.mark.parametrize("case", [_prefix_dense, _prefix_latent],
+                         ids=["qwen25_7b-1024x4096", "glm47-1024x8192"])
+def test_prefix_attention_compiles_to_a_loop_with_tile_sized_scores(
+        case, v5e):
+    """The prefill pieces' attention at both cells' widths: one program
+    with a loop in it (a traced trip count, no signature a prompt
+    length) whose temporaries are below the float32 scores of the whole
+    cache, [heads, q, cache_len], that the masked expression holds: no
+    value of the program has a query's scores over every row."""
+    fn, shapes, (heads, q_len, cache_len) = case()
+    one_chip = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        for s, d in shapes)).compile()
+    text = compiled.as_text()
+    assert " while(" in text
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < heads * q_len * cache_len * 4)
+    assert f"{q_len},{cache_len}]" not in text
+    assert f"{heads},{q_len},{attention.PREFIX_TILE}]" in text
+
+
 @pytest.mark.parametrize("case", ["rms_norm-grad", "fused_ce-v32000-grad",
                                   "flash-grad"])
 def test_kernel_partitions_over_a_2x2_mesh(case, v5e, monkeypatch):

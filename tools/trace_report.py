@@ -95,6 +95,20 @@ def stage_table(evs: list) -> list:
     return rows
 
 
+def prefill_walk(evs: list) -> tuple:
+    """(pieces, rows walked, rows their caches have) over the window's
+    ``prefill/piece`` spans: what the pieces' attention read
+    (``rows``, the engine's account by ``ops.attention.
+    prefix_tiles_walked``) of a whole ``cache_len`` a piece
+    (``cache_rows``).  Spans from before the walk carry neither and
+    add nothing."""
+    walked = [(e["args"]["rows"], e["args"]["cache_rows"]) for e in evs
+              if e.get("name") == "prefill/piece"
+              and "rows" in (e.get("args") or {})]
+    return (len(walked), sum(r for r, _ in walked),
+            sum(c for _, c in walked))
+
+
 def instant_counts(evs: list) -> list:
     counts = collections.Counter(
         e["name"] for e in evs if e.get("ph") == "i")
@@ -765,6 +779,11 @@ def main(argv=None) -> int:
         for name, n, total, mean, p50, p99, mx in rows:
             print(f"{n:7d}  {total:10.2f}  {mean:9.3f}  {p50:8.3f}  "
                   f"{p99:8.3f}  {mx:8.3f}  {name}")
+    pieces, walked, held = prefill_walk(evs)
+    if held:
+        print(f"  prefill/piece attention walked {walked} of {held} "
+              f"cache rows in {pieces} pieces: share walked "
+              f"{walked / held:.3f}")
     inst = instant_counts(evs)
     if inst:
         print(f"\n{'count':>7}  instant")
