@@ -16,6 +16,8 @@ Megatron prescribes.
 
 from __future__ import annotations
 
+import contextlib
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -121,20 +123,65 @@ def llama3_scaled_freqs(freqs, scaling):
     return jnp.where(medium, smoothed, scaled)
 
 
+def yarn_scaled_freqs(freqs, scaling, base: float):
+    """YaRN frequencies (arXiv:2309.00071; DeepSeek-V3's
+    ``precompute_freqs_cis``): each of the ``dim / 2`` frequencies is
+    blended between itself and itself / ``factor`` by a linear ramp
+    over the pair index, from the pair that turns ``beta_fast`` times
+    in ``original_max`` positions (kept) to the one that turns
+    ``beta_slow`` times (divided).  ``scaling`` = (factor, beta_fast,
+    beta_slow, original_max_positions); cos and sin stay unscaled (the
+    attention's softmax scale carries ``yarn_mscale``)."""
+    factor, fast, slow, old_len = scaling
+    dim = 2 * freqs.shape[0]
+
+    def pair_of(turns):
+        return (dim * math.log(old_len / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_of(fast)), 0)
+    high = min(math.ceil(pair_of(slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale_all_dim: float = 1.0) -> float:
+    """What YaRN multiplies the attention logits' scale by, squared by
+    the caller (once for the query, once for the key)."""
+    return 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 \
+        else 1.0
+
+
+def scaled_freqs(freqs, scaling, base: float):
+    """``apply_rope``'s ``scaling=`` rule, chosen by the tuple's tag:
+    ``("yarn", ...)`` is YaRN; four bare numbers are the llama3 rule
+    (``LlamaConfig.rope_scaling`` predates the tag)."""
+    if not isinstance(scaling[0], str):
+        return llama3_scaled_freqs(freqs, scaling)
+    if scaling[0] != "yarn":
+        raise ValueError(f"unknown rope scaling {scaling[0]!r} (expected "
+                         "'yarn' or the llama3 four numbers)")
+    return yarn_scaled_freqs(freqs, tuple(scaling[1:]), base)
+
+
 @jax.named_scope("attn/qkv")    # the rotation belongs to q and k's making
 def apply_rope(x, positions, *, base: float = 10000.0, scaling=None):
     """RoPE applied to [B, S, H, D] at integer ``positions`` [B, S].
 
     Applied separately to q and k so each uses its own positions (KV-cache
     decode and cross-length attention need different q/k position vectors).
-    ``scaling``: optional llama3 rope-scaling tuple (see
-    ``llama3_scaled_freqs``).
+    ``scaling``: optional rope-scaling tuple, ``("yarn", factor,
+    beta_fast, beta_slow, original_max)`` or the llama3 four numbers
+    (``scaled_freqs``).
     """
     head_dim = x.shape[-1]
     freqs = 1.0 / base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                            / head_dim)
     if scaling is not None:
-        freqs = llama3_scaled_freqs(freqs, scaling)
+        freqs = scaled_freqs(freqs, scaling, base)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
     sin = jnp.sin(angles)[:, :, None, :]
     cos = jnp.cos(angles)[:, :, None, :]
@@ -1088,6 +1135,11 @@ class KernelParam(nn.Module):
             self.shape)
 
 
+def _scope_when(named: bool, scope: str):
+    """``jax.named_scope(scope)`` where ``named``, else no scope."""
+    return jax.named_scope(scope) if named else contextlib.nullcontext()
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2; GLM-4.7-Flash).
 
@@ -1137,16 +1189,37 @@ class LatentAttention(nn.Module):
     v_head_dim: int
     dtype: Dtype = jnp.float32
     rope_base: float = 10000.0
+    rope_scaling: Optional[tuple] = None
     rms_epsilon: float = 1e-5
     decode: bool = False
     cache_len: int = 0
     slot_decode: bool = False
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    # The learned selection (class docstring): ``index_heads`` heads of
+    # ``index_dim`` score every cached row for a query, and attention
+    # sees the ``index_topk`` best.  0 = attention over every row.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
 
     @property
     def row_dim(self) -> int:
         return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        """``(nope + rope) ** -0.5``, times YaRN's ``mscale`` squared
+        where the frequencies are YaRN's."""
+        scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.rope_scaling and self.rope_scaling[0] == "yarn":
+            scale *= yarn_mscale(self.rope_scaling[1]) ** 2
+        return scale
+
+    def _selects(self, rows: int) -> bool:
+        """Whether attention over ``rows`` positions can leave any out
+        (statically: up to ``index_topk`` rows the choice is all)."""
+        return bool(self.index_topk) and rows > self.index_topk
 
     @property
     def row_store(self) -> int:
@@ -1158,9 +1231,14 @@ class LatentAttention(nn.Module):
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), axes))
 
+    def _rope(self, x, positions):
+        return apply_rope(x, positions, base=self.rope_base,
+                          scaling=self.rope_scaling)
+
     @jax.named_scope("attn/q_latent")
     def _queries(self, x, positions):
-        """(q_nope [B,S,H,nope], rope(q_rope) [B,S,H,rope])."""
+        """(q_nope [B,S,H,nope], rope(q_rope) [B,S,H,rope], the query
+        latent c_q [B,S,q_lora_rank] the indexer shares)."""
         c_q = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
                       name="q_norm")(
             self._dense(self.q_lora_rank, ("embed", None), "q_a")(x))
@@ -1171,8 +1249,7 @@ class LatentAttention(nn.Module):
         q = nn.with_logical_constraint(
             q, ("batch", "length", "heads", "kv"))
         return (q[..., :self.qk_nope_dim],
-                apply_rope(q[..., self.qk_nope_dim:], positions,
-                           base=self.rope_base))
+                self._rope(q[..., self.qk_nope_dim:], positions), c_q)
 
     @jax.named_scope("attn/kv_latent")
     def _rows(self, x, positions):
@@ -1180,10 +1257,56 @@ class LatentAttention(nn.Module):
         kv = self._dense(self.row_dim, ("embed", None), "kv_a")(x)
         c_kv = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
                        name="kv_norm")(kv[..., :self.kv_lora_rank])
-        k_r = apply_rope(kv[..., None, self.kv_lora_rank:], positions,
-                         base=self.rope_base)[..., 0, :]
+        k_r = self._rope(kv[..., None, self.kv_lora_rank:],
+                         positions)[..., 0, :]
         return _pad_last(jnp.concatenate([c_kv, k_r], axis=-1),
                          self.row_store)
+
+    def _rope_head(self, t, positions):
+        """The indexer's rotation: the leading ``qk_rope_dim`` dims of
+        every head of ``t`` [B, S, H, index_dim], the attention's own
+        frequency table."""
+        r = self.qk_rope_dim
+        return jnp.concatenate(
+            [self._rope(t[..., :r], positions), t[..., r:]], axis=-1)
+
+    @jax.named_scope("attn/index_q")
+    def _index_queries(self, c_q, x, positions):
+        """(q_I [B,S,Hi,Di] in the cache's type, w [B,S,Hi] float32):
+        the indexer's queries from the query latent, and the weight of
+        each of its heads from the layer's input, scaled by
+        ``Hi ** -0.5 * Di ** -0.5``."""
+        q = self._dense(self.index_heads * self.index_dim,
+                        (None, "heads"), "index_q")(c_q)
+        q = self._rope_head(
+            q.reshape(*x.shape[:-1], self.index_heads, self.index_dim),
+            positions)
+        w = dense(self.index_heads, ("embed", None), use_bias=False,
+                  dtype=jnp.float32, name="index_w")(
+                      x.astype(jnp.float32))
+        return q, w * (self.index_heads ** -0.5 * self.index_dim ** -0.5)
+
+    @jax.named_scope("attn/index_k")
+    def _index_keys(self, x, positions):
+        """This call's index keys [B, S, Di]: one a token, LayerNorm
+        (scale and bias) of a projection of the layer's input."""
+        k = nn.LayerNorm(epsilon=1e-6, dtype=self.dtype,
+                         name="index_k_norm")(
+            self._dense(self.index_dim, ("embed", None), "index_k")(x))
+        return self._rope_head(k[..., None, :], positions)[..., 0, :]
+
+    def _chosen_rows(self, q_i, w_i, keys, start):
+        """Bool [B, Q, C]: the ``index_topk`` rows of the linear
+        ``keys`` [B, C, Di] that each query (lane ``b``'s at ``start[b]
+        + arange(Q)``) attends."""
+        from tensorflow_train_distributed_tpu.ops.attention import (
+            prefix_index_scores, select_top_rows,
+        )
+
+        with jax.named_scope("attn/index_score"):
+            scores = prefix_index_scores(q_i, w_i, keys, start)
+        with jax.named_scope("attn/select"):
+            return select_top_rows(scores, self.index_topk)
 
     def _kv_b(self):
         """``Wkv_b`` as [rank, H, nope + v_head]."""
@@ -1241,12 +1364,28 @@ class LatentAttention(nn.Module):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(x.shape[1]),
                                          x.shape[:2])
-        q_nope, q_rope = self._queries(x, positions)
+        q_nope, q_rope, c_q = self._queries(x, positions)
         k, v = self._up_project(self._rows(x, positions), self._kv_b())
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        o = multihead_attention_kernel(
-            *(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True,
-            segment_ids=segment_ids).transpose(0, 2, 1, 3)
+        mask = None
+        if self.index_topk:
+            # The indexer's parameters exist whatever the length; its
+            # choice is read only where it can leave a row out, as one
+            # dense [B, S, S] mask (the training forward: no cache).
+            q_i, w_i = self._index_queries(c_q, x, positions)
+            k_i = self._index_keys(x, positions)
+            if self._selects(x.shape[1]):
+                if segment_ids is not None:
+                    raise ValueError("the learned selection does not "
+                                     "take packed segments")
+                mask = self._chosen_rows(
+                    q_i, w_i, k_i,
+                    jnp.zeros((x.shape[0],), jnp.int32))[:, None]
+        with _scope_when(mask is not None, "attn/sparse"):
+            o = multihead_attention_kernel(
+                *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+                causal=mask is None, mask=mask, segment_ids=segment_ids,
+                softmax_scale=self.softmax_scale).transpose(0, 2, 1, 3)
         return self._out(o, x.shape[-1])
 
     def _linear_step(self, x):
@@ -1272,21 +1411,37 @@ class LatentAttention(nn.Module):
         cur = jnp.broadcast_to(index.value, (b,))
         positions = cur[:, None] + jnp.arange(q_len)            # [B, q]
         index.value = index.value + q_len
-        q_nope, q_rope = self._queries(x, positions)
+        q_nope, q_rope, c_q = self._queries(x, positions)
         rows = self._rows(x, positions)
-        with jax.named_scope("kv_pool/write"):
+
+        def write(cache, rows):
             # Out-of-range positions are dropped: an overrun row goes
             # inert, as in MultiHeadAttention._slot_decode_step.
             cache.value = cache.value.at[
                 jnp.arange(b)[:, None], positions].set(
                     rows.astype(cache.value.dtype), mode="drop")
+
+        with jax.named_scope("kv_pool/write"):
+            write(cache, rows)
+        keep = None
+        if self._selects(self.cache_len):
+            keys = self.variable(
+                "cache", "index_cache", jnp.zeros,
+                (b, self.cache_len, self.index_dim), self.dtype)
+            q_i, w_i = self._index_queries(c_q, x, positions)
+            k_i = self._index_keys(x, positions)
+            with jax.named_scope("index_pool/write"):
+                write(keys, k_i)
+            keep = self._chosen_rows(q_i, w_i, keys.value, cur)
         kv_b = self._kv_b()
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        o = prefix_attention(
-            q.transpose(0, 2, 1, 3), cache.value, cur,
-            lambda rows: [t.transpose(0, 2, 1, 3)
-                          for t in self._up_project(rows, kv_b)],
-        ).transpose(0, 2, 1, 3)
+        with _scope_when(keep is not None, "attn/sparse"):
+            o = prefix_attention(
+                q.transpose(0, 2, 1, 3), cache.value, cur,
+                lambda rows: [t.transpose(0, 2, 1, 3)
+                              for t in self._up_project(rows, kv_b)],
+                keep=keep, softmax_scale=self.softmax_scale,
+            ).transpose(0, 2, 1, 3)
         return self._out(o, x.shape[-1])
 
     def _paged_step(self, x):
@@ -1311,12 +1466,11 @@ class LatentAttention(nn.Module):
         cur = index.value
         positions = cur[:, None] + jnp.arange(q_len)            # [B, q]
         index.value = cur + q_len
-        q_nope, q_rope = self._queries(x, positions)
+        q_nope, q_rope, c_q = self._queries(x, positions)
         rows = self._rows(x, positions)
         with jax.named_scope("kv_pool/write"):
-            pool.value = _set_pool_rows(
-                pool.value, (),
-                *_paged_dest(table.value, positions, bs, nb), rows)
+            dest = _paged_dest(table.value, positions, bs, nb)
+            pool.value = _set_pool_rows(pool.value, (), *dest, rows)
 
         w = self._kv_b()
         with jax.named_scope("attn/absorb"):
@@ -1327,16 +1481,79 @@ class LatentAttention(nn.Module):
         # An empty lane (table starts at the scratch block) holds
         # nothing, whatever its index has grown to while it idled.
         held = jnp.where(table.value[:, 0] == 0, 0, cur)
-        o_lat = pk.paged_latent_attention(
-            q_cat, pool.value, table.value, held, value_dim=rank,
-            scale=(self.qk_nope_dim + self.qk_rope_dim) ** -0.5,
-            cache_len=self.cache_len,
-            use_pallas=fused_paged_ok(),
-            interpret=pk.fused_attn_interpret())
+        kernel = dict(value_dim=rank, scale=self.softmax_scale,
+                      use_pallas=fused_paged_ok(),
+                      interpret=pk.fused_attn_interpret())
+        if self._selects(self.cache_len):
+            o_lat = self._paged_selected(
+                x, c_q, positions, dest, q_cat, pool.value, table.value,
+                held, kernel)
+        else:
+            o_lat = pk.paged_latent_attention(
+                q_cat, pool.value, table.value, held,
+                cache_len=self.cache_len, **kernel)
         with jax.named_scope("attn/absorb"):
             o = jnp.einsum("bqhc,chd->bqhd", o_lat.astype(self.dtype),
                            w[..., self.qk_nope_dim:])
         return self._out(o, x.shape[-1])
+
+    def _paged_selected(self, x, c_q, positions, dest, q_cat, pool, table,
+                        held, kernel):
+        """The absorbed attention of a paged step over the rows the
+        indexer chooses: append this step's index keys to
+        ``index_pool`` (one key a token, the latent pool's blocks and
+        table), score every row a lane holds through its table
+        (``paged_index_scores``: the walk rule of the attention
+        kernel, 256 B a row in bf16), take the ``index_topk`` best of
+        each query (``lax.top_k``: ties to the lower position), gather
+        their latent rows, and run ``paged_latent_attention`` over the
+        gathered rows laid out as a pool of their own: a query reads
+        ``index_topk`` latent rows, not its lane's history.  A lane
+        with no more rows than that chooses all of them."""
+        from tensorflow_train_distributed_tpu.ops import pallas_kernels \
+            as pk
+
+        b, q_len = positions.shape
+        bs, nb, top = self.kv_block_size, self.paged_kv_blocks, \
+            self.index_topk
+        if top % bs:
+            raise ValueError(f"index_topk={top} is no multiple of the "
+                             f"block size {bs}")
+        keys = self.variable("cache", "index_pool", jnp.zeros,
+                             (nb, bs, self.index_dim), self.dtype)
+        q_i, w_i = self._index_queries(c_q, x, positions)
+        k_i = self._index_keys(x, positions)
+        with jax.named_scope("index_pool/write"):
+            keys.value = _set_pool_rows(keys.value, (), *dest, k_i)
+        with jax.named_scope("attn/index_score"):
+            scores = pk.paged_index_scores(
+                q_i, w_i, keys.value, table, held,
+                cache_len=self.cache_len, use_pallas=kernel["use_pallas"],
+                interpret=kernel["interpret"])            # [B, q, C]
+        seen = jnp.minimum(held[:, None] + 1 + jnp.arange(q_len),
+                           self.cache_len)                 # rows a query sees
+        chosen = jnp.minimum(seen, top)
+        with jax.named_scope("attn/select"):
+            _, at = jax.lax.top_k(scores, top)             # [B, q, top]
+            at = at.reshape(b, q_len * top)
+            phys = jnp.take_along_axis(table, at // bs, axis=1)
+            picked = jnp.take(pool.reshape(nb * bs, -1),
+                              phys * bs + at % bs, axis=0)
+        # Rows a step scored and attended, idle lanes left out: the
+        # engine's ``rows_scored`` / ``rows_selected`` (kept only where
+        # the caller makes ``attn_stats`` mutable).
+        self.sow("attn_stats", "rows", jnp.stack(
+            [jnp.sum(jnp.where(table[:, :1] != 0, n, 0))
+             for n in (seen, chosen)]).astype(jnp.int32))
+        with jax.named_scope("attn/sparse"):
+            lanes = b * q_len
+            o = pk.paged_latent_attention(
+                q_cat.reshape(lanes, 1, *q_cat.shape[2:]),
+                picked.reshape(lanes * top // bs, bs, -1),
+                jnp.arange(lanes * top // bs,
+                           dtype=jnp.int32).reshape(lanes, top // bs),
+                chosen.reshape(lanes) - 1, cache_len=top, **kernel)
+        return o.reshape(b, q_len, *o.shape[2:])
 
 
 class MlpBlock(nn.Module):

@@ -122,6 +122,31 @@ class MoeConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # Rotary scaling of the attention (``layers.apply_rope``'s tagged
+    # tuple, e.g. ("yarn", factor, beta_fast, beta_slow, original_max)).
+    rope_scaling: Optional[tuple] = None
+    # Latent attention's learned selection (DeepSeek-V3.2): an indexer
+    # of ``index_heads`` heads of ``index_dim`` scores every cached row
+    # and a query attends over the ``index_topk`` best
+    # (layers.LatentAttention).  0 = attention over every row.
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
+    # Group-limited choice of the sigmoid router (``noaux_tc`` with
+    # ``n_group`` > 1): the experts lie in ``n_group`` equal groups, a
+    # group scores the sum of its two best (score + bias), the
+    # ``topk_group`` best groups stay and the top-k is taken among
+    # their experts.  One group is the plain top-k.
+    n_group: int = 1
+    topk_group: int = 1
+    # The experts that live here, of ``num_experts``: a chip's share of
+    # an expert-parallel deployment.  The router keeps its width and
+    # its ``top_k``; the layer computes what experts [offset, offset +
+    # held) add for the tokens routed to them and leaves the rest out
+    # (their chips would add it: nothing here stands in for them).  The
+    # shared expert and the residual are whole.  None = all of them.
+    experts_held: Optional[int] = None
+    experts_offset: int = 0
 
 
 MOE_PRESETS = {
@@ -171,6 +196,34 @@ MOE_PRESETS = {
         dense_ffn_size=160, router="sigmoid", routed_scaling=1.8,
         q_lora_rank=24, kv_lora_rank=32, qk_nope_dim=12, qk_rope_dim=8,
         v_head_dim=16),
+    # DeepSeek-V3.2-Exp (``deepseek_v32``) at its published widths:
+    # latent attention with the learned selection of 2048 rows a query,
+    # YaRN, three leading dense layers, 256 sigmoid-routed experts in 8
+    # groups (4 stay, top 8, gates x 2.5) beside one shared expert.  No
+    # chip holds it whole: deployments give ``experts_held`` and cut the
+    # depth and the vocabulary to their share (benchmark/configs).
+    "deepseek_v32": MoeConfig(
+        vocab_size=129_280, d_model=7168, num_layers=61, num_heads=128,
+        num_kv_heads=None, ffn_size=2048, num_experts=256, top_k=8,
+        max_positions=163_840, rope_base=10_000.0, rms_epsilon=1e-6,
+        dispatch="gmm", shared_expert_size=2048, norm_topk_prob=True,
+        dense_layers=3, dense_ffn_size=18_432, router="sigmoid",
+        routed_scaling=2.5, n_group=8, topk_group=4, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        rope_scaling=("yarn", 40.0, 32.0, 1.0, 4096),
+        index_heads=64, index_dim=128, index_topk=2048),
+    # The same block at test size (float32): a query chooses 16 rows,
+    # 8 experts in 4 groups of which 2 stay, all of them held.
+    "deepseek_v32_tiny": MoeConfig(
+        vocab_size=256, d_model=64, num_layers=3, num_heads=4,
+        num_kv_heads=None, ffn_size=48, num_experts=8, top_k=2,
+        max_positions=128, dtype=jnp.float32, remat=False,
+        dispatch="gmm", shared_expert_size=48, dense_layers=1,
+        dense_ffn_size=160, router="sigmoid", routed_scaling=2.5,
+        n_group=4, topk_group=2, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_dim=12, qk_rope_dim=8, v_head_dim=16,
+        rope_scaling=("yarn", 40.0, 32.0, 1.0, 16),
+        index_heads=4, index_dim=16, index_topk=16, rms_epsilon=1e-6),
     # DeepSeek/Qwen-MoE-style: always-on shared expert beside the
     # routed ones (tiny test shape).
     "moe_tiny_shared": MoeConfig(vocab_size=256, d_model=64,
@@ -294,9 +347,11 @@ def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):
     # the tiles stay megablox's own: only the few-row shapes were
     # measured (PERF.md, PR 26), and the backward's transposed products
     # take the same tiling.
-    groups, k, n = rhs.shape
+    # ``rhs`` may hold a share of the groups (``group_offset``): the
+    # rows are then all groups' and a held group still gets its share.
+    _, k, n = rhs.shape
     tiling = (128, 128, 128)
-    if lhs.shape[0] <= 128 * groups:
+    if lhs.shape[0] <= 128 * group_sizes.shape[0]:
         tiling = (128, min(-(-k // 128) * 128, 2048),
                   min(-(-n // 128) * 128, 512))
     return _mb.gmm(lhs, rhs, group_sizes,
@@ -304,6 +359,23 @@ def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):
                    tiling=tiling,
                    group_offset=None if group_offset is None
                    else jnp.asarray(group_offset, jnp.int32))
+
+
+def _within_best_groups(choice, n_group: int, topk_group: int):
+    """``choice`` [T, E] (score + bias) with every expert outside its
+    token's ``topk_group`` best groups at ``-inf``: the experts lie in
+    ``n_group`` equal runs, and a group scores the sum of its two
+    largest entries (DeepSeek-V3's ``noaux_tc``)."""
+    t, e = choice.shape
+    if e % n_group or e // n_group < 2 or not 1 <= topk_group <= n_group:
+        raise ValueError(
+            f"{e} experts do not lie in {n_group} groups of two or more "
+            f"of which {topk_group} stay")
+    grouped = choice.reshape(t, n_group, e // n_group)
+    best_two = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)    # [T, G]
+    _, stay = jax.lax.top_k(best_two, topk_group)
+    stays = jnp.any(jax.nn.one_hot(stay, n_group, dtype=jnp.bool_), axis=1)
+    return jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(t, e)
 
 
 def _routed_ffn_rows(flat, top_e, gate_w, num_experts, wi_gate, wi_up,
@@ -374,11 +446,22 @@ class _GmmExperts(nn.Module):
     num_experts: int
     hidden: int
     dtype: object
+    # A share of the experts (``MoeConfig.experts_held``): the kernels
+    # are [held, ...] and the rows of every other expert come back
+    # zero, which is what one shard's body below computes by
+    # ``group_offset`` under an ``expert`` mesh: the same call.
+    held: Optional[int] = None
+    offset: int = 0
 
     @nn.compact
     def __call__(self, flat, top_e, gate_w, *, interpret, ep_mesh=None):
         d = flat.shape[-1]
         e, f = self.num_experts, self.hidden
+        n_here = e if self.held is None else self.held
+        if not 0 <= self.offset <= e - n_here:
+            raise ValueError(
+                f"experts [{self.offset}, {self.offset + n_here}) are not "
+                f"among the router's {e}")
         # The dense path's ``nn.vmap(_ExpertFfn)`` tree
         # (``experts/<name>/kernel``, expert-stacked, per-expert init
         # statistics), so checkpoints transfer between formulations.
@@ -386,13 +469,20 @@ class _GmmExperts(nn.Module):
             return L.KernelParam(shape, axes, batch_axis=(0,),
                                  name=name)().astype(self.dtype)
 
-        wi_gate = stacked((e, d, f), ("expert", "embed", "mlp"), "wi_gate")
-        wi_up = stacked((e, d, f), ("expert", "embed", "mlp"), "wi_up")
-        wo = stacked((e, f, d), ("expert", "mlp", "embed"), "wo")
+        wi_gate = stacked((n_here, d, f), ("expert", "embed", "mlp"),
+                          "wi_gate")
+        wi_up = stacked((n_here, d, f), ("expert", "embed", "mlp"),
+                        "wi_up")
+        wo = stacked((n_here, f, d), ("expert", "mlp", "embed"), "wo")
         if ep_mesh is None:
             return _routed_ffn_rows(
                 flat, top_e, gate_w, e, wi_gate, wi_up, wo,
-                dtype=self.dtype, interpret=interpret)
+                dtype=self.dtype, interpret=interpret,
+                group_offset=None if self.held is None else self.offset)
+        if self.held is not None:
+            raise ValueError(
+                "experts_held is one chip's share; an expert mesh divides "
+                "all num_experts over its own shards")
 
         from tensorflow_train_distributed_tpu.runtime.compat import (
             shard_map,
@@ -563,8 +653,12 @@ class MoEMlpBlock(nn.Module):
                     "bias", nn.with_logical_partitioning(
                         nn.initializers.zeros, ("expert",)),
                     (cfg.num_experts,), jnp.float32)
-                _, top_e = jax.lax.top_k(
-                    p2 + bias.astype(jnp.float32), k)
+                choice = p2 + bias.astype(jnp.float32)
+                if cfg.n_group > 1:
+                    with jax.named_scope("moe/route_groups"):
+                        choice = _within_best_groups(
+                            choice, cfg.n_group, cfg.topk_group)
+                _, top_e = jax.lax.top_k(choice, k)
                 top_p = jnp.take_along_axis(p2, top_e, axis=-1)
                 eps = 1e-20
                 # Load-balance mass below: the scores as a distribution.
@@ -591,9 +685,16 @@ class MoEMlpBlock(nn.Module):
         # idle serving lane's rows read expert weights like any
         # other): the serving engine's ``experts_hit`` (sown; kept only
         # where the caller makes ``moe_stats`` mutable).
-        self.sow("moe_stats", "expert_rows",
-                 jnp.bincount(top_e.reshape(-1),
-                              length=cfg.num_experts).astype(jnp.int32))
+        rows = jnp.bincount(top_e.reshape(-1),
+                            length=cfg.num_experts).astype(jnp.int32)
+        if cfg.experts_held is not None:
+            # Counted over the experts held; beside them the share of
+            # the call's (token, choice) pairs that fell here.
+            rows = jax.lax.dynamic_slice_in_dim(
+                rows, cfg.experts_offset, cfg.experts_held)
+            self.sow("moe_stats", "routed_here",
+                     jnp.sum(rows) / float(n_tokens * k))
+        self.sow("moe_stats", "expert_rows", rows)
 
         # Aux losses — same definitions as the dense path, with
         # routed = all top-k assignments (dropless).
@@ -632,7 +733,8 @@ class MoEMlpBlock(nn.Module):
                     "(GSPMD shards both axes there)")
             ep_mesh = mesh
         y = _GmmExperts(num_experts=cfg.num_experts, hidden=cfg.ffn_size,
-                        dtype=cfg.dtype, name="experts")(
+                        dtype=cfg.dtype, held=cfg.experts_held,
+                        offset=cfg.experts_offset, name="experts")(
             flat, top_e, gate_w,
             interpret=GMM_INTERPRET, ep_mesh=ep_mesh)
         return nn.with_logical_constraint(
@@ -664,7 +766,10 @@ class MoeDecoderBlock(nn.Module):
                 kv_lora_rank=cfg.kv_lora_rank,
                 qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
                 v_head_dim=cfg.v_head_dim, dtype=cfg.dtype,
-                rope_base=cfg.rope_base, rms_epsilon=cfg.rms_epsilon,
+                rope_base=cfg.rope_base, rope_scaling=cfg.rope_scaling,
+                rms_epsilon=cfg.rms_epsilon,
+                index_heads=cfg.index_heads, index_dim=cfg.index_dim,
+                index_topk=cfg.index_topk,
                 name="attention", decode=self.decode,
                 cache_len=self.cache_len or cfg.max_positions,
                 slot_decode=self.slot_decode,

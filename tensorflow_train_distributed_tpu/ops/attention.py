@@ -107,6 +107,7 @@ def prefix_attention(
     *,
     tile: Optional[int] = None,
     softmax_scale: Optional[float] = None,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention of ``q`` [B, H, Q, D] over the prefix of a linear KV
     cache that its lanes hold, tile by tile with a running softmax.
@@ -129,6 +130,10 @@ def prefix_attention(
     in float32 across tiles.  A cache of one tile IS that expression
     over the whole cache under the mask, to the bit.  ``tile`` is
     ``PREFIX_TILE`` unless a test makes a small cache walk several.
+
+    ``keep`` [B, Q, C] bool (``select_top_rows``) further restricts
+    each query to the rows it marks: the walk and its cost stay those
+    of the rows held, the rows left out are masked in each tile.
     """
     tile = PREFIX_TILE if tile is None else tile
     cache_len = jax.tree.leaves(cache)[0].shape[1]
@@ -136,13 +141,17 @@ def prefix_attention(
     start = jnp.asarray(start, jnp.int32).reshape(-1)     # [B] or [1]
     positions = start[:, None, None] + jnp.arange(q_len)[:, None]
 
-    def keep(kv_pos):                       # [B | 1, 1, Q, rows]
-        return (kv_pos <= positions)[:, None]
+    def seen(kv_pos, row0=None):            # [B | 1, 1, Q, rows]
+        ok = kv_pos <= positions
+        if keep is not None:
+            ok &= keep if row0 is None else jax.lax.dynamic_slice_in_dim(
+                keep, row0, kv_pos.shape[0], axis=2)
+        return ok[:, None]
 
     if cache_len <= tile:
         k, v = kv_of(cache)
         return dot_product_attention(
-            q, k, v, mask=keep(jnp.arange(cache_len)),
+            q, k, v, mask=seen(jnp.arange(cache_len)),
             softmax_scale=softmax_scale)
 
     scale = (softmax_scale if softmax_scale is not None
@@ -164,7 +173,8 @@ def prefix_attention(
         kv_pos = row0 + jnp.arange(tile)
         s = (jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale).astype(
             jnp.float32)
-        s = jnp.where(keep(kv_pos) & (kv_pos >= t * tile), s, mask_value)
+        s = jnp.where(seen(kv_pos, row0) & (kv_pos >= t * tile), s,
+                      mask_value)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -180,6 +190,79 @@ def prefix_attention(
         (stat, jnp.zeros_like(stat),
          jnp.zeros((*q.shape[:-1], v_like.shape[-1]), jnp.float32)))
     return (acc / l).astype(v_like.dtype)
+
+
+def prefix_index_scores(q, w, keys, start, *, tile: Optional[int] = None):
+    """The learned selection's score of every cached row for every
+    query (DeepSeek-V3.2's indexer)::
+
+        I[b, t, s] = sum_h w[b, t, h] * relu(q[b, t, h] . keys[b, s])
+
+    ``q`` [B, Q, H, D] and ``keys`` [B, C, D] (a linear cache of one
+    key a row) in their own type, products accumulated in float32;
+    ``w`` [B, Q, H] float32.  Lane ``b``'s queries sit at ``start[b] +
+    arange(Q)``; a row past a query's position scores ``-inf``.
+    Returns float32 [B, Q, C].  Walked as ``prefix_attention`` walks:
+    the tiles ``prefix_tiles_walked`` gives and no others (the rest
+    stay ``-inf``), so the per-head scores are [B, Q, H, tile] at a
+    time and never [B, Q, H, C]."""
+    tile = PREFIX_TILE if tile is None else tile
+    b, q_len = q.shape[:2]
+    cache_len = keys.shape[1]
+    start = jnp.asarray(start, jnp.int32).reshape(-1)
+    positions = start[:, None] + jnp.arange(q_len)             # [B|1, Q]
+
+    def score(k, kv_pos):
+        s = jnp.einsum("bqhd,bkd->bqhk", q, k,
+                       preferred_element_type=jnp.float32)
+        s = jnp.einsum("bqhk,bqh->bqk", jax.nn.relu(s), w,
+                       precision=jax.lax.Precision.HIGHEST)
+        return jnp.where(kv_pos <= positions[..., None], s, -jnp.inf)
+
+    if cache_len <= tile:
+        return score(keys, jnp.arange(cache_len))
+
+    def fold(t, out):
+        row0 = jnp.minimum(t * tile, cache_len - tile)
+        s = score(jax.lax.dynamic_slice_in_dim(keys, row0, tile, axis=1),
+                  row0 + jnp.arange(tile))
+        return jax.lax.dynamic_update_slice_in_dim(out, s, row0, axis=2)
+
+    return jax.lax.fori_loop(
+        0, prefix_tiles_walked(start, q_len, tile, cache_len), fold,
+        jnp.full((b, q_len, cache_len), -jnp.inf, jnp.float32))
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
+
+
+def select_top_rows(scores, k: int):
+    """Bool [..., C]: for each query the ``k`` rows of largest score,
+    ties to the lower position (``lax.top_k``'s rule), and only rows
+    whose score is not ``-inf`` (a query that sees fewer than ``k``
+    rows keeps them all).  The k-th largest score is found on the
+    scores' bit patterns, one bit a pass from the top (32 counts over
+    the scores: no sort of [queries, C]), then the rows above it and
+    the first of those equal to it are kept."""
+    bits = _ordered_bits(scores)
+
+    def grow(i, thr):
+        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(bits >= cand, axis=-1, keepdims=True) >= k
+        return jnp.where(enough, cand, thr)
+
+    # The largest threshold that k scores reach: the k-th largest score
+    # (0, below every float, where fewer than k rows exist).
+    thr = jax.lax.fori_loop(
+        0, 32, grow, jnp.zeros((*scores.shape[:-1], 1), jnp.uint32))
+    above = bits > thr
+    level = bits == thr
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    first = jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= room
+    return (above | (level & first)) & (scores > -jnp.inf)
 
 
 def local_attention_chunked(

@@ -636,6 +636,140 @@ def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
 
 
 # ---------------------------------------------------------------------------
+# Paged index scores (models.layers.LatentAttention's learned selection)
+# ---------------------------------------------------------------------------
+
+
+def paged_index_scores_reference(q, w, pool, table, lengths, *,
+                                 cache_len: Optional[int] = None):
+    """Pure-jax oracle (and the CPU path) of ``paged_index_scores``:
+    gather each lane's index keys, score.  ``q`` [lanes, q_len, heads,
+    dim] (the indexer's queries, RoPE applied), ``w`` [lanes, q_len,
+    heads] float32, ``pool`` [num_blocks, block_size, dim] one key a
+    row.  Returns float32 [lanes, q_len, cache_len]: ``sum_h w_h *
+    relu(q_h . key)``, ``-inf`` past a query's position."""
+    nb, bs, dim = pool.shape
+    lanes, q_len = q.shape[:2]
+    c = cache_len if cache_len is not None else table.shape[1] * bs
+    keys = jnp.take(pool, table, axis=0).reshape(lanes, -1, dim)[:, :c]
+    s = jnp.einsum("bqhd,bkd->bqhk", q.astype(pool.dtype), keys,
+                   preferred_element_type=jnp.float32)
+    s = jnp.einsum("bqhk,bqh->bqk", jax.nn.relu(s), w,
+                   precision=jax.lax.Precision.HIGHEST)
+    positions = lengths[:, None] + jnp.arange(q_len)        # [B, q]
+    return jnp.where(jnp.arange(c) <= positions[..., None], s, -jnp.inf)
+
+
+def _paged_index_kernel(tbl_ref, len_ref, q_ref, w_ref, pool_hbm, o_ref,
+                        buf, sem, *, bs, fold, last_row, q_len, heads):
+    """``_paged_latent_kernel``'s walk over the index keys: grid
+    (lane,), the lane's own blocks (``paged_blocks_walked``) ``fold``
+    to a double-buffered copy-and-score step.  A step's keys [fold *
+    bs, dim] meet the query rows [q_len * heads, dim] in one product
+    (the pool's own type, float32 accumulation); ReLU, the heads'
+    weights and their sum are float32 on the vector unit.  The scores
+    of a step are one row ``o_ref[0, query, step]`` [fold * bs] a
+    query; the rows the lane's walk does not reach stay ``-inf``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)
+    cur = len_ref[i]
+    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1)
+    steps = pl.cdiv(live, fold)
+
+    def copies(step, slot, wait=False):
+        return [pltpu.make_async_copy(
+            pool_hbm.at[0 if wait else tbl_ref[
+                i, jnp.minimum(step * fold + p, live - 1)]],
+            buf.at[slot, p], sem.at[slot]) for p in range(fold)]
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+    for c in copies(0, 0):
+        c.start()
+    q = q_ref[0]                             # [q_len*heads, dim]
+    w = w_ref[0]                             # [q_len*heads, 1] float32
+    n = fold * bs
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    last_seen = jnp.minimum(cur + jax.lax.broadcasted_iota(
+        jnp.int32, (q_len, n), 0), last_row)
+
+    def score_step(step, _):
+        slot = jax.lax.rem(step, 2)
+
+        @pl.when(step + 1 < steps)
+        def _():
+            for c in copies(step + 1, 1 - slot):
+                c.start()
+
+        for c in copies(step, slot, wait=True):
+            c.wait()
+        keys = buf[slot].reshape(n, buf.shape[-1])
+        s = jax.lax.dot_general(
+            q, keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # [r, n]
+        s = jnp.maximum(s, 0.0) * w
+        for j in range(q_len):
+            o_ref[0, j, pl.ds(step, 1), :] = jnp.where(
+                step * n + col <= last_seen[j:j + 1],
+                jnp.sum(s[j * heads:(j + 1) * heads], axis=0,
+                        keepdims=True), -jnp.inf)
+
+    jax.lax.fori_loop(0, steps, score_step, None)
+
+
+def paged_index_scores(q, w, pool, table, lengths, *,
+                       cache_len: Optional[int] = None,
+                       use_pallas: Optional[bool] = None,
+                       interpret: bool = False):
+    """The indexer's scores of a paged decode step directly through the
+    block table.  Arguments as ``paged_index_scores_reference``.  One
+    grid step a lane, which reads the blocks its length reaches
+    (``paged_blocks_walked``) and no others, each key once: HBM reads
+    are ``blocks x block_size x dim`` values a call."""
+    if not _use_pallas(use_pallas) and not interpret:
+        return paged_index_scores_reference(
+            q, w, pool, table, lengths, cache_len=cache_len)
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, bs, dim = pool.shape
+    lanes, q_len, heads, _ = q.shape
+    n_blk = table.shape[1]
+    fold = _paged_fold(bs, n_blk)
+    c = min(cache_len or n_blk * bs, n_blk * bs)
+    n = fold * bs
+    slabs = -(-n_blk // fold)
+    r = q_len * heads
+
+    def lane_rows(*tail):
+        return pl.BlockSpec((1, *tail), lambda i, tbl, lens: (
+            i, *(0,) * len(tail)))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_index_kernel, bs=bs, fold=fold, last_row=c - 1,
+            q_len=q_len, heads=heads),
+        name="paged_index_scores",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(lanes,),
+            in_specs=[lane_rows(r, dim), lane_rows(r, 1),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=lane_rows(q_len, slabs, n),
+            scratch_shapes=[
+                pltpu.VMEM((2, fold, bs, dim), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, q_len, slabs, n),
+                                       jnp.float32),
+        interpret=interpret,
+    )(table, lengths.astype(jnp.int32),
+      q.reshape(lanes, r, dim).astype(pool.dtype),
+      w.reshape(lanes, r, 1).astype(jnp.float32), pool)
+    return out.reshape(lanes, q_len, slabs * n)[..., :c]
+
+
+# ---------------------------------------------------------------------------
 # RMSNorm
 # ---------------------------------------------------------------------------
 

@@ -104,17 +104,24 @@ CONTRACT = {
     # handed to requests, the engine queue's depth at exit; with routed
     # experts, of the decode chunk harvested in the step, the experts
     # that took a row and the rows' coefficient of variation over the
-    # experts (means over the chunk's steps and expert layers)
+    # experts (means over the chunk's steps and expert layers, counted
+    # over the experts the layer holds); where it holds a share of the
+    # experts, how many and the share of the (token, choice) pairs that
+    # fell on them; where attention chooses its rows (a learned
+    # selection), the rows a step and layer scored and attended over
+    # the live lanes
     "engine/step": ("lanes positions kv_blocks kv_table_blocks pieces "
                     "prefill_tokens committed queued experts_hit "
-                    "expert_load_cv"),
+                    "expert_load_cv experts_held routed_here "
+                    "rows_scored rows_selected"),
     "decode/dispatch": "fused spec_k",
     "decode/wait": "overlapped",
     "decode/harvest": "overlapped",
     "prefill/request": "rid tokens",
     # rows: the cache rows the piece's attention walks (a prefix, in
     # whole tiles: ops.attention.prefix_tiles_walked) of the cache_rows
-    # a lane's cache has
+    # a lane's cache has (a learned selection is a mask inside that
+    # walk: the rows read are these, whatever it chooses)
     "prefill/piece": "rid piece n_pieces tokens rows cache_rows",
     "prefill/wait": "rid",
     "prefill/insert": "rid",

@@ -225,11 +225,13 @@ def _paged_killed() -> bool:
 #: values side by side (``layers.paged_pool_leaves``), where the linear
 #: cache keeps [kv_heads, head_dim]: the same values in the same order.
 #: Their int8 scales are [kv_heads] on both sides, a latent-attention
-#: row [row] (``layers.LatentAttention``).
+#: row [row] and the index key of its learned selection [index_dim]
+#: (``layers.LatentAttention``: two kinds of row a token and layer).
 _ROW_LEAVES = {"key_pool": ("key_cache", 1, 2),
                "value_pool": ("value_cache", 1, 2),
                "kv_pool_scales": ("kv_scales", 1, 1),
-               "latent_pool": ("latent_cache", 1, 1)}
+               "latent_pool": ("latent_cache", 1, 1),
+               "index_pool": ("index_cache", 1, 1)}
 _LINEAR_ROW_DIMS = {lin: dims for lin, _, dims in _ROW_LEAVES.values()}
 _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 
@@ -244,7 +246,12 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: with routed experts adds, as means over the chunk's steps and expert
 #: layers, the experts that took at least one row (``experts_hit``:
 #: what the grouped matmuls read) and the rows' coefficient of
-#: variation over the experts (``expert_load_cv``): ``_count_experts``.
+#: variation over the experts (``expert_load_cv``), both over the
+#: experts the layer holds; where it holds a share of them, how many
+#: (``experts_held``) and the share of the step's (token, choice) pairs
+#: that fell on them (``routed_here``); where attention chooses its
+#: rows, the rows a step and layer scored and attended over its live
+#: lanes (``rows_scored``, ``rows_selected``): ``_count_sown``.
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
                 "pieces", "prefill_tokens", "committed")
 
@@ -1155,25 +1162,32 @@ class ServingEngine:
         how many tokens it has already drawn (greedy ignores both).
         Also returns the NEXT chunk's (tok, counts) carry — computed
         inside the same program so chunks chain with zero extra
-        dispatches — and, last, the rows each expert took in each step
-        and expert layer ([chunk, layers, experts]; None for a model
-        without routed experts, which sows no ``moe_stats``)."""
+        dispatches — and, last, what the layers sowed in each step, by
+        name and stacked over the layers that sowed it ([chunk, layers,
+        ...]): ``expert_rows``, the rows each expert held here took;
+        ``routed_here`` and ``rows`` as ``_count_sown`` reads them.
+        None for a model that sows nothing (no routed experts)."""
         def step(carry, j):
             cache, tok = carry
             with quantized_inference():
                 logits, upd = self._model.apply(
                     dict(variables, cache=cache), tok[:, None],
-                    mutable=["cache", "moe_stats"])
+                    mutable=["cache", "moe_stats", "attn_stats"])
             nxt = self._pick(logits[:, -1], seeds, counts + j).astype(
                 tok.dtype)
-            rows = jax.tree.leaves(upd.get("moe_stats", {}))
+            sown = {}
+            for coll in ("moe_stats", "attn_stats"):
+                for path, leaf in jax.tree_util.tree_flatten_with_path(
+                        upd.get(coll, {}))[0]:
+                    sown.setdefault(self._path_key(path)[-2],
+                                    []).append(leaf)
             return (upd["cache"], nxt), (
-                nxt, jnp.stack(rows) if rows else None)
+                nxt, {n: jnp.stack(v) for n, v in sown.items()} or None)
 
-        (cache, last), (toks, expert_rows) = jax.lax.scan(
+        (cache, last), (toks, sown) = jax.lax.scan(
             step, (cache, tok), jnp.arange(self.chunk))
         return (cache, jnp.moveaxis(toks, 0, 1),    # [slots, chunk]
-                last, counts + self.chunk, expert_rows)
+                last, counts + self.chunk, sown)
 
     # -- host-side loop ----------------------------------------------------
 
@@ -2624,18 +2638,33 @@ class ServingEngine:
             lanes=len(held), positions=sum(held), kv_blocks=kv_blocks,
             kv_table_blocks=kv_table_blocks)
 
-    def _count_experts(self, expert_rows) -> None:
-        """``engine/step``'s account of the routed experts of a
-        harvested decode chunk: ``expert_rows`` [chunk, layers, experts]
-        as ``_decode_chunk`` returns it (None: no routed experts)."""
-        if expert_rows is None:
+    def _count_sown(self, sown) -> None:
+        """``engine/step``'s account of what the layers of a harvested
+        decode chunk sowed, as ``_decode_chunk`` returns it (None:
+        nothing), each a mean over the chunk's steps and the layers
+        that sowed it: ``expert_rows`` [chunk, layers, experts held],
+        ``routed_here`` [chunk, layers], ``rows`` [chunk, layers, 2
+        (scored, selected)]."""
+        if not sown:
             return
-        rows = expert_rows.astype(np.float64)
-        mean = rows.mean(axis=-1)
-        self._step_counts.update(
-            experts_hit=float((rows > 0).sum(axis=-1).mean()),
-            expert_load_cv=float(
-                (rows.std(axis=-1) / np.maximum(mean, 1e-9)).mean()))
+        counts = {}
+        if "expert_rows" in sown:
+            rows = sown["expert_rows"].astype(np.float64)
+            mean = rows.mean(axis=-1)
+            counts.update(
+                experts_hit=float((rows > 0).sum(axis=-1).mean()),
+                expert_load_cv=float(
+                    (rows.std(axis=-1) / np.maximum(mean, 1e-9)).mean()))
+        if "routed_here" in sown:
+            counts.update(
+                experts_held=int(sown["expert_rows"].shape[-1]),
+                routed_here=float(sown["routed_here"].mean()))
+        if "rows" in sown:
+            scored, selected = sown["rows"].astype(
+                np.float64).mean(axis=(0, 1))
+            counts.update(rows_scored=float(scored),
+                          rows_selected=float(selected))
+        self._step_counts.update(counts)
 
     @dispatch_critical
     def _dispatch_chunk(self) -> None:
@@ -2682,12 +2711,11 @@ class ServingEngine:
                                   "next_tok": next_tok, "acc": acc}
             else:
                 (self._cache, toks, last, counts_next,
-                 expert_rows) = self._decode_chunk(
+                 sown) = self._decode_chunk(
                     self._variables, self._cache, tok, jseeds, counts)
                 self._carry = (last, counts_next)
                 self._inflight = {"spec": False, "rids": rids,
-                                  "toks": toks,
-                                  "expert_rows": expert_rows}
+                                  "toks": toks, "sown": sown}
         with self._stats_lock:
             self.overlap_stats["chunks"] += 1
 
@@ -2736,16 +2764,14 @@ class ServingEngine:
                         np.asarray(inf["acc"]))
             else:
                 toks = np.asarray(inf["toks"])
-                expert_rows = inf["expert_rows"]
-                if expert_rows is not None:
-                    expert_rows = np.asarray(expert_rows)
+                sown = jax.tree.map(np.asarray, inf["sown"])
         t0 = time.perf_counter()
         with events.span("decode/harvest", overlapped=overlapped):
             if inf["spec"]:
                 self._harvest_spec(*args, inf["k"], rids=rids)
             else:
                 self._harvest(toks, rids=rids)
-                self._count_experts(expert_rows)
+                self._count_sown(sown)
         dt = time.perf_counter() - t0
         with self._stats_lock:
             self.overlap_stats["harvest_s"] += dt

@@ -128,6 +128,18 @@ def mesh_2d():
 _LLAMA_ONLY = ("test_benchmark_manifest.py::"
                "test_configuration_file_is_what_the_program_runs")
 
+# The same stop-gap for the family of one chip's share of an
+# expert-parallel deployment: ``benchmark/harness/serve_share.py``
+# registers ``program.family`` "moe_share" in ``serve_family.FAMILIES``
+# when it is imported (as ``benchmark/run.py`` imports it, by the
+# traffic file's ``kind``).  ``test_every_configuration_file_is_what_
+# its_family_runs`` walks every configuration through that table and
+# imports ``serve_family`` alone, so the registration happens here, at
+# collection, in every worker.  The `benchmark` PR that folds
+# ``serve.py`` into ``serve_family.py`` moves the registration into the
+# table itself and deletes this import with the hook below.
+import benchmark.harness.serve_share  # noqa: E402,F401
+
 
 def pytest_collection_modifyitems(items):
     for item in items:

@@ -389,8 +389,31 @@ def test_trace_report_prints_the_share_of_the_cache_prefill_walked(
     assert mod.prefill_walk(mod.load_events(str(path))) == (
         3, 6144, 24576)
     assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
     assert ("walked 6144 of 24576 cache rows in 3 pieces: share walked "
-            "0.250") in capsys.readouterr().out
+            "0.250") in out
+    assert "share selected" not in out      # no learned selection here
+
+    # Beside it, where attention chooses its rows: what the decode
+    # steps attended of what they scored (a piece walks all it holds).
+    with rec.span("prefill/piece", rid=9, piece=2, n_pieces=3, tokens=4,
+                  rows=3072, cache_rows=8192):
+        pass
+    with rec.span("engine/step", lanes=2, rows_scored=7700.0,
+                  rows_selected=4096.0):
+        pass
+    with rec.span("engine/step", lanes=3, rows_scored=12300.0,
+                  rows_selected=8192.0):
+        pass
+    with rec.span("engine/step", lanes=0):
+        pass
+    rec.save(str(path))
+    assert mod.rows_selected(mod.load_events(str(path))) == (
+        2, 12288.0, 20000.0)
+    assert mod.main([str(path)]) == 0
+    assert ("learned selection attended 12288 of 20000 rows scored in 2 "
+            "decode steps: share selected 0.614"
+            ) in capsys.readouterr().out
 
     old = Recorder(capacity=8)
     with old.span("prefill/piece", rid=8):

@@ -243,8 +243,9 @@ def test_grouped_matmul_tiles_follow_the_rows_an_expert_gets(
 
 def test_engine_counts_the_experts_a_chunk_hits(params):
     """``_decode_chunk`` returns the rows every expert took, every step
-    and expert layer, and the harvest folds them into the step's
-    counts (``engine/step``'s ``experts_hit``, ``expert_load_cv``)."""
+    and expert layer (what the layers sowed, by name), and the harvest
+    folds them into the step's counts (``engine/step``'s
+    ``experts_hit``, ``expert_load_cv``)."""
     _, eng = _serve(params, _tokens(9, seed=4), 6)
     counts = eng._step_counts
     # 2 lanes x top-2 rows a step: between 2 and 4 distinct experts.
@@ -253,7 +254,7 @@ def test_engine_counts_the_experts_a_chunk_hits(params):
     rows = np.asarray(eng._decode_chunk(
         eng._variables, eng._fresh_cache(2, grid=True),
         jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.uint32),
-        jnp.zeros((2,), jnp.int32))[4])
+        jnp.zeros((2,), jnp.int32))[4]["expert_rows"])
     assert rows.shape == (eng.chunk, 2, CFG.num_experts)
     assert (rows.sum(axis=-1) == 2 * CFG.top_k).all()
 

@@ -142,6 +142,20 @@ def _paged_latent(q_len):
          ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
 
 
+def _paged_index(q_len):
+    """DeepSeek-V3.2's index scores at their published sizes through a
+    lane's table: 64 heads of 128 against one key of 128 a row, blocks
+    of 16, a cache of 32768 rows."""
+    lanes, cache_len, bs, heads, dim = 4, 32768, 16, 64, 128
+    n_blk = cache_len // bs
+    return (lambda q, w, p, t, n: pk.paged_index_scores(
+        q, w, p, t, n, cache_len=cache_len, use_pallas=True),
+        (((lanes, q_len, heads, dim), BF16),
+         ((lanes, q_len, heads), jnp.float32),
+         ((1 + lanes * n_blk, bs, dim), BF16),
+         ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
+
+
 # (heads, kv_heads, head_dim, block_size): llama_350m's layout at the
 # engine's default block size, and qwen25_7b's GQA layout.
 _LAYOUTS = {"h16kv16d64": (16, 16, 64, 16), "h28kv4d128": (28, 4, 128, 32)}
@@ -158,6 +172,7 @@ CASES = {
 }
 for _q in (1, 3):
     CASES[f"paged_latent-h20r576-q{_q}"] = lambda q=_q: _paged_latent(q)
+    CASES[f"paged_index-h64d128-q{_q}"] = lambda q=_q: _paged_index(q)
 for _name, (_h, _kvh, _hd, _bs) in _LAYOUTS.items():
     CASES[f"paged_gather-{_name}"] = (
         lambda a=(_h, _kvh, _hd, _bs): _paged(*a, 1, False, gather=True))

@@ -109,6 +109,19 @@ def prefill_walk(evs: list) -> tuple:
             sum(c for _, c in walked))
 
 
+def rows_selected(evs: list) -> tuple:
+    """(steps, rows attended, rows scored) over the window's
+    ``engine/step`` spans of a program whose attention chooses its
+    rows (``rows_selected`` of ``rows_scored``: means over the steps
+    and layers of the decode chunk a step harvested, counted on the
+    device over its live lanes).  Spans without them add nothing; a
+    prefill piece walks every row its lane holds (``prefill_walk``)."""
+    got = [(e["args"]["rows_selected"], e["args"]["rows_scored"])
+           for e in evs if e.get("name") == "engine/step"
+           and (e.get("args") or {}).get("rows_scored")]
+    return (len(got), sum(s for s, _ in got), sum(r for _, r in got))
+
+
 def instant_counts(evs: list) -> list:
     counts = collections.Counter(
         e["name"] for e in evs if e.get("ph") == "i")
@@ -784,6 +797,11 @@ def main(argv=None) -> int:
         print(f"  prefill/piece attention walked {walked} of {held} "
               f"cache rows in {pieces} pieces: share walked "
               f"{walked / held:.3f}")
+    spans_n, attended, scored = rows_selected(evs)
+    if scored:
+        print(f"  learned selection attended {attended:.0f} of "
+              f"{scored:.0f} rows scored in {spans_n} decode steps: "
+              f"share selected {attended / scored:.3f}")
     inst = instant_counts(evs)
     if inst:
         print(f"\n{'count':>7}  instant")
