@@ -1306,7 +1306,7 @@ class LatentAttention(nn.Module):
         with jax.named_scope("attn/index_score"):
             scores = prefix_index_scores(q_i, w_i, keys, start)
         with jax.named_scope("attn/select"):
-            return select_top_rows(scores, self.index_topk)
+            return select_top_rows(scores, self.index_topk, start)
 
     def _kv_b(self):
         """``Wkv_b`` as [rank, H, nope + v_head]."""
@@ -1505,11 +1505,16 @@ class LatentAttention(nn.Module):
         table), score every row a lane holds through its table
         (``paged_index_scores``: the walk rule of the attention
         kernel, 256 B a row in bf16), take the ``index_topk`` best of
-        each query (``lax.top_k``: ties to the lower position), gather
-        their latent rows, and run ``paged_latent_attention`` over the
-        gathered rows laid out as a pool of their own: a query reads
-        ``index_topk`` latent rows, not its lane's history.  A lane
-        with no more rows than that chooses all of them."""
+        each query, gather their latent rows, and run
+        ``paged_latent_attention`` over the gathered rows laid out as a
+        pool of their own: a query reads ``index_topk`` latent rows, not
+        its lane's history.  A lane with no more rows than that chooses
+        all of them.  The choice is ``lax.top_k`` here (ties to the
+        lower position, the set ``select_top_rows`` marks for a prefill
+        piece): a step's scores are 2 MB, and on the chip the sort beat
+        the counted threshold with the positions read off by block
+        counts, a few dozen small operations (PERF.md section 6,
+        PR 33)."""
         from tensorflow_train_distributed_tpu.ops import pallas_kernels \
             as pk
 

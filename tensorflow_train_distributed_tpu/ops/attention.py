@@ -131,9 +131,11 @@ def prefix_attention(
     over the whole cache under the mask, to the bit.  ``tile`` is
     ``PREFIX_TILE`` unless a test makes a small cache walk several.
 
-    ``keep`` [B, Q, C] bool (``select_top_rows``) further restricts
-    each query to the rows it marks: the walk and its cost stay those
-    of the rows held, the rows left out are masked in each tile.
+    ``keep`` [B, Q, C] bool (``select_top_rows``, which counts and
+    marks inside the same ``prefix_tiles_walked`` tiles and leaves every
+    row past them unmarked and unread) further restricts each query to
+    the rows it marks: the walk and its cost stay those of the rows
+    held, the rows left out are masked in each tile.
     """
     tile = PREFIX_TILE if tile is None else tile
     cache_len = jax.tree.leaves(cache)[0].shape[1]
@@ -239,30 +241,123 @@ def _ordered_bits(x):
     return jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(1 << 31))
 
 
-def select_top_rows(scores, k: int):
-    """Bool [..., C]: for each query the ``k`` rows of largest score,
+#: Bits of a score's pattern that one counting pass of
+#: ``select_top_rows`` settles: ``32 / SELECT_BITS`` passes of ``2 **
+#: SELECT_BITS - 1`` thresholds each (chosen on the chip: PERF.md
+#: section 6, PR 33).
+SELECT_BITS = 4
+
+
+def select_tiles_counted(start, q_len: int, k: int, tile: int,
+                         cache_len: int):
+    """Tiles of ``tile`` rows that ``select_top_rows`` counts over: the
+    ``prefix_tiles_walked`` tiles the scores were written to, or none
+    where the last query of the longest lane sees no more than ``k``
+    rows (``max(start) + q_len <= k``: nothing to choose, every row
+    seen is kept).  One rule for the device (a traced vector) and for
+    the host's ``prefill/piece`` ``select_rows`` (a numpy integer), as
+    ``prefix_tiles_walked`` is."""
+    return (start.max() + q_len > k) * prefix_tiles_walked(
+        start, q_len, tile, cache_len)
+
+
+def select_top_rows(scores, k: int, start, *, tile: Optional[int] = None):
+    """Bool [B, Q, C]: for each query the ``k`` rows of largest score,
     ties to the lower position (``lax.top_k``'s rule), and only rows
     whose score is not ``-inf`` (a query that sees fewer than ``k``
-    rows keeps them all).  The k-th largest score is found on the
-    scores' bit patterns, one bit a pass from the top (32 counts over
-    the scores: no sort of [queries, C]), then the rows above it and
-    the first of those equal to it are kept."""
-    bits = _ordered_bits(scores)
+    rows keeps them all).
 
-    def grow(i, thr):
-        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        enough = jnp.sum(bits >= cand, axis=-1, keepdims=True) >= k
-        return jnp.where(enough, cand, thr)
+    ``scores`` [B, Q, C] float32 are ``prefix_index_scores``'s: lane
+    ``b``'s queries sit at ``start[b] + arange(Q)`` and every row past
+    the ``prefix_tiles_walked`` tiles is ``-inf`` by construction.
+    Those rows are not read: the k-th largest score is found by
+    counting over the tiles walked alone (a traced trip count, one
+    compiled program whatever the lanes hold), on the scores' bit
+    patterns, ``SELECT_BITS`` bits a pass from the top (each pass reads
+    a tile once and counts the rows at or above every candidate for the
+    next digit: no sort of [queries, C]); then the rows above it and
+    the first of those equal to it are kept, tile by tile with the
+    count of equals carried.  Where ``select_tiles_counted`` is 0 no
+    pass runs and every row seen is kept.  A cache of one tile is the
+    same count and the same tie rule as one expression over all of it.
+    """
+    tile = PREFIX_TILE if tile is None else tile
+    b, q_len, cache_len = scores.shape
+    scores = jax.lax.stop_gradient(scores)   # a choice has no gradient
+    start = jnp.asarray(start, jnp.int32).reshape(-1)
+    one_tile = cache_len <= tile
+    walked = 1 if one_tile else prefix_tiles_walked(
+        start, q_len, tile, cache_len)
+
+    def rows_of(t):
+        """Tile ``t``: its first row, its scores, and which of its rows
+        are its own (the last tile of a cache that is no multiple of
+        ``tile`` starts early enough to fit)."""
+        if one_tile:
+            return 0, scores, True
+        row0 = jnp.minimum(t * tile, cache_len - tile)
+        return (row0,
+                jax.lax.dynamic_slice_in_dim(scores, row0, tile, axis=2),
+                row0 + jnp.arange(tile) >= t * tile)
+
+    def over_tiles(fold, init):
+        return (fold(0, init) if one_tile
+                else jax.lax.fori_loop(0, walked, fold, init))
+
+    digits = jnp.arange(1, 1 << SELECT_BITS, dtype=jnp.uint32)
+
+    def settle(i, carry):
+        """One pass: the next ``SELECT_BITS`` bits of the largest
+        threshold that ``k`` scores reach, and the rows above what is
+        settled so far."""
+        thr, above = carry
+        shift = (32 - SELECT_BITS * (i + 1)).astype(jnp.uint32)
+        cands = (thr | (digits << shift))[..., None]       # [B, Q, D, 1]
+
+        def count(t, n):
+            _, s, own = rows_of(t)
+            return n + jnp.sum(
+                (_ordered_bits(s)[:, :, None] >= cands) & own, axis=-1,
+                dtype=jnp.int32)
+
+        n = over_tiles(count, jnp.zeros(cands.shape[:3], jnp.int32))
+        # n falls as the digit grows: the digits k rows reach are the
+        # first ``d``, and the rows above the new prefix are those at
+        # or above digit d + 1 (or, past the last digit, those above
+        # the old prefix).
+        d = jnp.sum(n >= k, axis=-1, keepdims=True)
+        above = jnp.sum(
+            jnp.where(jnp.arange(digits.size + 1) == d,
+                      jnp.concatenate([n, above], axis=-1), 0),
+            axis=-1, keepdims=True)
+        return thr | (d.astype(jnp.uint32) << shift), above
 
     # The largest threshold that k scores reach: the k-th largest score
-    # (0, below every float, where fewer than k rows exist).
-    thr = jax.lax.fori_loop(
-        0, 32, grow, jnp.zeros((*scores.shape[:-1], 1), jnp.uint32))
-    above = bits > thr
-    level = bits == thr
-    room = k - jnp.sum(above, axis=-1, keepdims=True)
-    first = jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= room
-    return (above | (level & first)) & (scores > -jnp.inf)
+    # (0, below every float, where fewer than k rows exist or nothing
+    # is counted), and how many scores lie above it.
+    nothing = (jnp.zeros((b, q_len, 1), jnp.uint32),
+               jnp.zeros((b, q_len, 1), jnp.int32))
+    thr, above = jax.lax.cond(
+        select_tiles_counted(start, q_len, k, tile, cache_len) == 0,
+        lambda: nothing,
+        lambda: jax.lax.fori_loop(0, 32 // SELECT_BITS, settle, nothing))
+    room = k - above
+
+    def mark(t, carry):
+        keep, equal = carry
+        row0, s, own = rows_of(t)
+        bits = _ordered_bits(s)
+        level = (bits == thr) & own
+        run = equal + jnp.cumsum(level, axis=-1, dtype=jnp.int32)
+        kept = ((bits > thr) | (level & (run <= room))) & (s > -jnp.inf)
+        if one_tile:
+            return kept, run[..., -1:]
+        old = jax.lax.dynamic_slice_in_dim(keep, row0, tile, axis=2)
+        return (jax.lax.dynamic_update_slice_in_dim(
+            keep, jnp.where(own, kept, old), row0, axis=2), run[..., -1:])
+
+    return over_tiles(mark, (jnp.zeros(scores.shape, bool),
+                             jnp.zeros((b, q_len, 1), jnp.int32)))[0]
 
 
 def local_attention_chunked(

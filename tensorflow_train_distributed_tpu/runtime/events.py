@@ -121,8 +121,13 @@ CONTRACT = {
     # rows: the cache rows the piece's attention walks (a prefix, in
     # whole tiles: ops.attention.prefix_tiles_walked) of the cache_rows
     # a lane's cache has (a learned selection is a mask inside that
-    # walk: the rows read are these, whatever it chooses)
-    "prefill/piece": "rid piece n_pieces tokens rows cache_rows",
+    # walk: the rows read are these, whatever it chooses);
+    # select_rows: of them, the rows such a selection counts over to
+    # find its k-th score (ops.attention.select_tiles_counted: 0 where
+    # no query of the piece sees more rows than it keeps, or the model
+    # has no selection)
+    "prefill/piece": ("rid piece n_pieces tokens rows select_rows "
+                      "cache_rows"),
     "prefill/wait": "rid",
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",

@@ -350,6 +350,11 @@ class ServingEngine:
         self.top_p = top_p
         self._greedy = temperature == 0.0
         self.config = config
+        # Rows a query of the target's (False) and the draft's (True)
+        # attention chooses among those it sees; 0: no selection.
+        self._index_topk = {
+            is_draft: getattr(c, "index_topk", 0)
+            for is_draft, c in ((False, config), (True, draft_config))}
         self.slots = slots
         self.cache_len = cache_len or config.max_positions
         if self.cache_len > config.max_positions:
@@ -2391,17 +2396,24 @@ class ServingEngine:
         # The cache rows the piece's attention walks, by its own rule
         # (``prefix_tiles_walked``): from row 0, whatever prefix was
         # matched and gathered, to the end of the piece's last tile.
+        # Of them, those a learned selection counts over for its k-th
+        # score (``select_tiles_counted``: none where no query of the
+        # piece sees more than ``index_topk`` rows).
         tile = attention_ops.PREFIX_TILE
+        start = np.int64(len(task.prompt) - len(task.work) + i * task.piece)
+        top = self._index_topk[draft]
         rows = min(self.cache_len, tile * int(
             attention_ops.prefix_tiles_walked(
-                np.int64(len(task.prompt) - len(task.work)
-                         + i * task.piece),
-                task.piece, tile, self.cache_len)))
+                start, task.piece, tile, self.cache_len)))
+        select_rows = min(self.cache_len, tile * int(
+            attention_ops.select_tiles_counted(
+                start, task.piece, top, tile, self.cache_len))
+        ) if 0 < top < self.cache_len else 0
         with self._ctx(), events.span(
                 "prefill/piece", rid=task.request_id,
                 piece=task.cursor + task.d_cursor,
                 n_pieces=task.n_pieces, tokens=real, rows=rows,
-                cache_rows=self.cache_len):
+                select_rows=select_rows, cache_rows=self.cache_len):
             if task.cursor < task.n_pieces:
                 if task.cache_1 is None:
                     task.cache_1 = self._admission_cache_1(

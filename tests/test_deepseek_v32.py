@@ -217,7 +217,8 @@ def test_the_programs_choice_is_the_references(params, tile):
             k_i = self._index_keys(x, positions[None])
             scores = attention_ops.prefix_index_scores(
                 q_i, w_i, k_i, jnp.zeros((1,), jnp.int32), tile=tile)
-            return attention_ops.select_top_rows(scores, 16)
+            return attention_ops.select_top_rows(
+                scores, 16, jnp.zeros((1,), jnp.int32), tile=tile)
 
     attn = Chooser(
         num_heads=4, q_lora_rank=24, kv_lora_rank=32, qk_nope_dim=12,
@@ -230,22 +231,108 @@ def test_the_programs_choice_is_the_references(params, tile):
     np.testing.assert_array_equal(got, want)
 
 
-def test_select_top_rows_breaks_ties_as_top_k_does():
-    """Scores with many exact ties, ``-inf`` rows and rows of fewer than
-    k visible entries: the mask is ``lax.top_k``'s set among the
-    visible rows."""
-    rng = np.random.default_rng(0)
-    scores = rng.integers(-3, 4, (2, 9, 40)).astype(np.float32)
-    scores[0, 0, 5:] = -np.inf           # five visible rows, k = 8
-    scores[0, 1] = 0.0                   # all equal: the first 8
-    scores[1, 2, ::2] = -np.inf
-    got = np.asarray(attention_ops.select_top_rows(jnp.asarray(scores), 8))
-    _, idx = jax.lax.top_k(jnp.asarray(scores), 8)
-    want = np.zeros_like(got)
+def _top_k_set(scores, k):
+    """``lax.top_k``'s rows as a mask, the ``-inf`` ones left out."""
+    _, idx = jax.lax.top_k(jnp.asarray(scores), k)
+    want = np.zeros(scores.shape, bool)
     np.put_along_axis(want, np.asarray(idx), True, axis=-1)
-    want &= np.isfinite(scores)
+    return want & np.isfinite(scores)
+
+
+def _lane_scores(start, q_len, cache_len, ties, seed=0):
+    """Scores as ``prefix_index_scores`` leaves them: lane ``b``'s
+    queries at ``start[b] + arange(q_len)``, a row past a query
+    ``-inf``; small integers (many exact ties) or normal draws."""
+    rng = np.random.default_rng(seed)
+    shape = (len(start), q_len, cache_len)
+    scores = (rng.integers(-3, 4, shape) if ties
+              else rng.normal(size=shape)).astype(np.float32)
+    seen = np.asarray(start)[:, None] + np.arange(q_len)
+    scores[np.arange(cache_len) > seen[..., None]] = -np.inf
+    return scores
+
+
+#: name: (each lane's start, queries, cache_len, tile), with k = 8.
+_WALKS = {
+    "planted": None,
+    "fewer-than-k": ([0, 2], 5, 40, 8),
+    "exactly-k": ([3, 0], 5, 40, 8),
+    "k-plus-1": ([4, 1], 5, 40, 8),
+    "ends-mid-tile": ([17, 2], 4, 48, 8),
+    "ragged-cache": ([30, 7], 9, 43, 8),
+    "whole-ragged-cache": ([34, 0], 9, 43, 8),
+    "one-tile": ([12, 0], 9, 40, 64),
+}
+
+
+@pytest.mark.parametrize("ties", [True, False], ids=["ties", "random"])
+@pytest.mark.parametrize("walk", list(_WALKS))
+def test_select_top_rows_breaks_ties_as_top_k_does(walk, ties):
+    """The mask is ``lax.top_k``'s set among the visible rows: on
+    scores with many exact ties, ``-inf`` rows and rows of fewer than k
+    visible entries (``planted``), and on lanes whose ``start`` differs
+    with fewer than, exactly and one more than k rows seen, a walk that
+    ends mid-tile, a cache that is no multiple of the tile, one tile.
+    The rows past the tiles walked are never read (NaN there moves
+    nothing), and no pass runs exactly where no query sees more than k
+    rows."""
+    k = 8
+    if _WALKS[walk] is None:
+        start, tile = [0, 0], None
+        scores = _lane_scores([40, 40], 9, 40, ties)      # nothing hidden
+        scores[0, 0, 5:] = -np.inf        # five visible rows
+        scores[0, 1] = 0.0                # all equal: the first 8
+        scores[1, 2, ::2] = -np.inf
+    else:
+        start, q_len, cache_len, tile = _WALKS[walk]
+        scores = _lane_scores(start, q_len, cache_len, ties)
+    q_len, cache_len = scores.shape[1:]
+    want = _top_k_set(scores, k)
+    dirty = scores.copy()
+    if tile is not None and cache_len > tile:
+        walked = int(attention_ops.prefix_tiles_walked(
+            np.asarray(start), q_len, tile, cache_len))
+        dirty[..., walked * tile:] = np.nan
+        assert walk != "ends-mid-tile" or np.isnan(dirty).any()
+    got = np.asarray(jax.jit(lambda s, at: attention_ops.select_top_rows(
+        s, k, at, tile=tile))(jnp.asarray(dirty), jnp.asarray(start)))
     np.testing.assert_array_equal(got, want)
-    assert got[0, 0].sum() == 5 and got[0, 1, :8].all()
+    counted = int(attention_ops.select_tiles_counted(
+        np.asarray(start), q_len, k,
+        tile or attention_ops.PREFIX_TILE, cache_len))
+    if max(start) + q_len <= k:
+        assert counted == 0
+        np.testing.assert_array_equal(got, np.isfinite(scores))
+    else:
+        assert counted == int(attention_ops.prefix_tiles_walked(
+            np.asarray(start), q_len, tile or attention_ops.PREFIX_TILE,
+            cache_len))
+    if walk == "planted":
+        assert got[0, 0].sum() == 5 and got[0, 1, :8].all()
+
+
+@pytest.mark.parametrize("bits", [1, 2, 8])
+def test_select_top_rows_is_the_same_mask_at_any_bits_a_pass(
+        bits, monkeypatch):
+    """``SELECT_BITS`` is a cost, not a result."""
+    monkeypatch.setattr(attention_ops, "SELECT_BITS", bits)
+    scores = _lane_scores([30, 7], 9, 43, ties=bits == 1, seed=bits)
+    got = attention_ops.select_top_rows(
+        jnp.asarray(scores), 8, jnp.asarray([30, 7]), tile=8)
+    np.testing.assert_array_equal(np.asarray(got), _top_k_set(scores, 8))
+
+
+def test_no_pass_runs_where_nothing_is_to_choose():
+    """The counting passes lie in one branch of a ``cond`` on the
+    traced ``start``, and the other branch holds no loop."""
+    jaxpr = jax.make_jaxpr(lambda s, at: attention_ops.select_top_rows(
+        s, 8, at, tile=8))(jnp.zeros((2, 5, 40)), jnp.zeros((2,), jnp.int32))
+    conds = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    loops = sorted(sum(q.primitive.name in ("while", "scan")
+                       for q in branch.jaxpr.eqns)
+                   for branch in conds[0].params["branches"])
+    assert loops == [0, 1]
 
 
 class TestPagedIndexKernel:
@@ -470,13 +557,21 @@ def test_the_expert_mesh_computes_what_the_shares_compute(params):
     np.testing.assert_allclose(shares, meshed, atol=2e-5)
 
 
-def test_engine_counts_the_share_and_the_selection(params):
-    """``engine/step``'s new counters, through a served request on a
-    model that holds experts [2, 4) of 8."""
+@pytest.mark.parametrize("tile", [None, 16], ids=["one-tile", "tile-16"])
+def test_engine_counts_the_share_and_the_selection(params, tile,
+                                                   walk_in_tiles):
+    """``engine/step``'s counters and ``prefill/piece``'s
+    ``select_rows``, through a served request on a model that holds
+    experts [2, 4) of 8."""
+    from tensorflow_train_distributed_tpu.runtime import events
+
+    walk_in_tiles(tile)
     cfg = dataclasses.replace(CFG, experts_held=2, experts_offset=2)
     eng = ServingEngine(cfg, _params(cfg), slots=2, chunk=4, cache_len=128,
                         kv_block_size=8, prefill_chunk=16)
     prompt = [int(t) for t in _tokens(40, seed=9)]
+    rec = events.get_recorder()
+    seq0 = rec.events_after(0)[0]
     rid = eng.submit(prompt, 9)
     assert len(eng.run()[rid]) == 49
     counts = eng._step_counts
@@ -487,6 +582,13 @@ def test_engine_counts_the_share_and_the_selection(params):
     assert counts["rows_selected"] == 16.0
     assert 41.0 <= counts["rows_scored"] <= 49.0
     assert eng.kv_pool_bytes() == cfg.num_layers * 33 * 8 * (128 + 16) * 4
+    # Three pieces of 16: the first sees no more than index_topk rows
+    # and counts nothing, the others count over the tiles they walk.
+    pieces = [e[5] for e in rec.events_after(seq0)[1]
+              if e[0] == "prefill/piece"]
+    walked = [128, 128, 128] if tile is None else [16, 32, 48]
+    assert [p["rows"] for p in pieces] == walked
+    assert [p["select_rows"] for p in pieces] == [0] + walked[1:]
 
 
 # -- (g) the index rows travel with the latent rows -------------------------

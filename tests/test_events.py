@@ -380,24 +380,30 @@ def test_trace_report_prints_the_share_of_the_cache_prefill_walked(
     rec = Recorder(capacity=64)
     for i, rows in enumerate((1024, 2048, 3072)):
         with rec.span("prefill/piece", rid=7, piece=i, n_pieces=3,
-                      tokens=1024, rows=rows, cache_rows=8192):
+                      tokens=1024, rows=rows, select_rows=0,
+                      cache_rows=8192):
             pass
     with rec.span("prefill/piece", rid=8):      # an older program's
         pass
     path = tmp_path / "trace.json"
     rec.save(str(path))
     assert mod.prefill_walk(mod.load_events(str(path))) == (
-        3, 6144, 24576)
+        3, 6144, 24576, 0)
     assert mod.main([str(path)]) == 0
     out = capsys.readouterr().out
     assert ("walked 6144 of 24576 cache rows in 3 pieces: share walked "
-            "0.250") in out
+            "0.250\n") in out
     assert "share selected" not in out      # no learned selection here
 
-    # Beside it, where attention chooses its rows: what the decode
-    # steps attended of what they scored (a piece walks all it holds).
+    # Beside it, where attention chooses its rows: what the choice of
+    # a piece counted over (nothing in a piece that keeps all it sees;
+    # a span from before the attribute adds nothing) and what the
+    # decode steps attended of what they scored.
     with rec.span("prefill/piece", rid=9, piece=2, n_pieces=3, tokens=4,
-                  rows=3072, cache_rows=8192):
+                  rows=3072, select_rows=3072, cache_rows=8192):
+        pass
+    with rec.span("prefill/piece", rid=9, piece=3, n_pieces=3, tokens=4,
+                  rows=1024, cache_rows=8192):
         pass
     with rec.span("engine/step", lanes=2, rows_scored=7700.0,
                   rows_selected=4096.0):
@@ -410,10 +416,14 @@ def test_trace_report_prints_the_share_of_the_cache_prefill_walked(
     rec.save(str(path))
     assert mod.rows_selected(mod.load_events(str(path))) == (
         2, 12288.0, 20000.0)
+    assert mod.prefill_walk(mod.load_events(str(path))) == (
+        5, 10240, 40960, 3072)
     assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert ("walked 10240 of 40960 cache rows in 5 pieces: share walked "
+            "0.250, share the selection counted over 0.075\n") in out
     assert ("learned selection attended 12288 of 20000 rows scored in 2 "
-            "decode steps: share selected 0.614"
-            ) in capsys.readouterr().out
+            "decode steps: share selected 0.614") in out
 
     old = Recorder(capacity=8)
     with old.span("prefill/piece", rid=8):

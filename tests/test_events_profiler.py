@@ -317,6 +317,7 @@ def test_a_piece_records_the_rows_its_attention_walks(
     assert out == whole
     assert [p["rows"] for p in pieces] == want
     assert {p["cache_rows"] for p in pieces} == {32}
+    assert {p["select_rows"] for p in pieces} == {0}    # nothing chooses
 
 
 @pytest.mark.parametrize("killed", [False, True])

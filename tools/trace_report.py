@@ -96,17 +96,21 @@ def stage_table(evs: list) -> list:
 
 
 def prefill_walk(evs: list) -> tuple:
-    """(pieces, rows walked, rows their caches have) over the window's
-    ``prefill/piece`` spans: what the pieces' attention read
-    (``rows``, the engine's account by ``ops.attention.
-    prefix_tiles_walked``) of a whole ``cache_len`` a piece
-    (``cache_rows``).  Spans from before the walk carry neither and
-    add nothing."""
-    walked = [(e["args"]["rows"], e["args"]["cache_rows"]) for e in evs
-              if e.get("name") == "prefill/piece"
-              and "rows" in (e.get("args") or {})]
-    return (len(walked), sum(r for r, _ in walked),
-            sum(c for _, c in walked))
+    """(pieces, rows walked, rows their caches have, rows a learned
+    selection counted over) over the window's ``prefill/piece`` spans:
+    what the pieces' attention read (``rows``, the engine's account by
+    ``ops.attention.prefix_tiles_walked``) of a whole ``cache_len`` a
+    piece (``cache_rows``), and what their choice of rows counted over
+    to find its k-th score (``select_rows``, by ``ops.attention.
+    select_tiles_counted``: 0 in a piece that keeps every row it sees,
+    in a model with no selection and in a trace from before the
+    attribute).  Spans from before the walk carry none and add
+    nothing."""
+    walked = [(a["rows"], a["cache_rows"], a.get("select_rows", 0))
+              for a in (e.get("args") or {} for e in evs
+                        if e.get("name") == "prefill/piece")
+              if "rows" in a]
+    return (len(walked), *(sum(w[i] for w in walked) for i in range(3)))
 
 
 def rows_selected(evs: list) -> tuple:
@@ -792,11 +796,13 @@ def main(argv=None) -> int:
         for name, n, total, mean, p50, p99, mx in rows:
             print(f"{n:7d}  {total:10.2f}  {mean:9.3f}  {p50:8.3f}  "
                   f"{p99:8.3f}  {mx:8.3f}  {name}")
-    pieces, walked, held = prefill_walk(evs)
+    pieces, walked, held, counted = prefill_walk(evs)
     if held:
         print(f"  prefill/piece attention walked {walked} of {held} "
               f"cache rows in {pieces} pieces: share walked "
-              f"{walked / held:.3f}")
+              f"{walked / held:.3f}"
+              + (f", share the selection counted over "
+                 f"{counted / held:.3f}" if counted else ""))
     spans_n, attended, scored = rows_selected(evs)
     if scored:
         print(f"  learned selection attended {attended:.0f} of "
