@@ -164,19 +164,38 @@ def scaled_freqs(freqs, scaling, base: float):
     if scaling[0] != "yarn":
         raise ValueError(f"unknown rope scaling {scaling[0]!r} (expected "
                          "'yarn' or the llama3 four numbers)")
-    return yarn_scaled_freqs(freqs, tuple(scaling[1:]), base)
+    return yarn_scaled_freqs(freqs, tuple(scaling[1:5]), base)
+
+
+def rope_attention_factor(scaling) -> float:
+    """What cos and sin are multiplied by: the sixth entry of a
+    ``("yarn", factor, beta_fast, beta_slow, original_max,
+    attention_factor)`` tuple (transformers' YaRN convention: the
+    rotated part of q and of k each carry it, the unrotated part does
+    not); 1 for every shorter tuple, whose users scale the softmax
+    instead (``yarn_mscale``)."""
+    tagged = scaling is not None and isinstance(scaling[0], str)
+    return float(scaling[5]) if tagged and len(scaling) > 5 else 1.0
 
 
 @jax.named_scope("attn/qkv")    # the rotation belongs to q and k's making
-def apply_rope(x, positions, *, base: float = 10000.0, scaling=None):
+def apply_rope(x, positions, *, base: float = 10000.0, scaling=None,
+               rotary_dim: Optional[int] = None):
     """RoPE applied to [B, S, H, D] at integer ``positions`` [B, S].
 
     Applied separately to q and k so each uses its own positions (KV-cache
     decode and cross-length attention need different q/k position vectors).
     ``scaling``: optional rope-scaling tuple, ``("yarn", factor,
-    beta_fast, beta_slow, original_max)`` or the llama3 four numbers
-    (``scaled_freqs``).
+    beta_fast, beta_slow, original_max[, attention_factor])`` or the
+    llama3 four numbers (``scaled_freqs``).  ``rotary_dim``: the first
+    so many values of a head are rotated (half-split among themselves,
+    frequencies over ``rotary_dim``) and the rest pass as they are
+    (``partial_rotary_factor``); None rotates the whole head.
     """
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = apply_rope(x[..., :rotary_dim], positions, base=base,
+                            scaling=scaling)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     head_dim = x.shape[-1]
     freqs = 1.0 / base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                            / head_dim)
@@ -185,6 +204,9 @@ def apply_rope(x, positions, *, base: float = 10000.0, scaling=None):
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
     sin = jnp.sin(angles)[:, :, None, :]
     cos = jnp.cos(angles)[:, :, None, :]
+    factor = rope_attention_factor(scaling)
+    if factor != 1.0:
+        sin, cos = sin * factor, cos * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
@@ -225,17 +247,26 @@ def paged_pool_leaves(blocks: int, block_size: int, kv_heads: int,
     return leaves
 
 
-def _paged_dest(table, positions, block_size: int, blocks: int):
+def _paged_dest(table, positions, block_size: int, blocks: int,
+                ring_of: Optional[int] = None):
     """Where a step's rows go: (physical block, row in it) per (lane,
     token).  The table lookup CLIPS the block index (gather semantics
     would otherwise wrap); a position past the table's width gets block
     ``blocks``, out of range, so that the scatter DROPS it — an overrun
     lane goes silently inert, the linear path's rule.  A table slot the
-    engine zeroed sends its rows to the scratch block 0."""
+    engine zeroed sends its rows to the scratch block 0.  ``ring_of``:
+    the table is a sliding-window layer's RING over a context of so
+    many rows: position ``p`` lies in entry ``(p // block_size) %
+    width``, and a position past the context is the overrun."""
     n_blk = table.shape[1]
-    blk = jnp.clip(positions // block_size, 0, n_blk - 1)
+    if ring_of is None:
+        blk = jnp.clip(positions // block_size, 0, n_blk - 1)
+        rows = n_blk * block_size
+    else:
+        blk = jnp.mod(positions // block_size, n_blk)
+        rows = ring_of
     phys = jnp.take_along_axis(table, blk, axis=1)              # [B, q]
-    return (jnp.where(positions < n_blk * block_size, phys, blocks),
+    return (jnp.where(positions < rows, phys, blocks),
             positions % block_size)
 
 
@@ -382,6 +413,47 @@ class MultiHeadAttention(nn.Module):
     # post-gemm q/k/v slices cut across the fused dim's shards, so keep
     # it for single-chip/dp serving and training runs.
     fused_qkv: bool = False
+    # Partial rotary (``partial_rotary_factor``): the first
+    # ``rotary_dim`` values of a head are rotated, the rest pass
+    # (``apply_rope``); None rotates the whole head.
+    rotary_dim: Optional[int] = None
+    # Per-head output gate: ``sigmoid(x @ W_g)``, one value a head from
+    # the layer's own input, multiplies the head's attention output
+    # before the out projection (bias-free ``gate`` kernel).
+    out_gate: bool = False
+    # Paged serving of a ``window`` layer: a lane's rows live in a RING
+    # of ``ring_blocks`` blocks of this layer's own pool (1 + lanes x
+    # ring_blocks blocks; position p in ring entry (p // block_size) %
+    # ring_blocks), so the layer's memory and a step's reads are
+    # bounded by the window whatever the context.  The engine sets it
+    # (``serving.ServingEngine``); ``paged_kv_blocks`` is then the full
+    # layers' business alone.
+    ring_blocks: int = 0
+
+    def _rope(self, t, positions):
+        return apply_rope(t, positions, base=self.rope_base,
+                          scaling=self.rope_scaling,
+                          rotary_dim=self.rotary_dim)
+
+    def _gate(self, x):
+        """[B, S, H] gate of the heads' outputs, or None."""
+        if not self.out_gate:
+            return None
+        with jax.named_scope("attn/gate"):
+            return jax.nn.sigmoid(nn.Dense(
+                self.num_heads, use_bias=False, dtype=self.dtype,
+                name="gate",
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "heads")),
+            )(x))
+
+    @staticmethod
+    def _gated(out, gate):
+        """The heads' outputs [B, S, H, D] under ``_gate``'s values."""
+        if gate is None:
+            return out
+        with jax.named_scope("attn/gate"):
+            return out * gate[..., None].astype(out.dtype)
 
     def _proj(self, x, heads, name):
         # Plain 2-D kernel (embed, heads*head_dim) + reshape: maps onto
@@ -505,10 +577,8 @@ class MultiHeadAttention(nn.Module):
             kv_positions = (positions if x_kv is x_q
                             else jnp.broadcast_to(
                                 jnp.arange(x_kv.shape[1]), x_kv.shape[:2]))
-            q = apply_rope(q, positions, base=self.rope_base,
-                           scaling=self.rope_scaling)
-            k = apply_rope(k, kv_positions, base=self.rope_base,
-                           scaling=self.rope_scaling)
+            q = self._rope(q, positions)
+            k = self._rope(k, kv_positions)
 
         # [B, S, H, D] → [B, H, S, D] for the kernel.
         qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
@@ -546,6 +616,7 @@ class MultiHeadAttention(nn.Module):
         if self.dropout_rate > 0 and not deterministic:
             out = nn.Dropout(self.dropout_rate)(out,
                                                 deterministic=deterministic)
+        out = self._gated(out, self._gate(x_q))
         out = out.reshape(*out.shape[:-2],
                           self.num_heads * self.head_dim)
         y = self._out_proj(out, x_q.shape[-1])
@@ -576,11 +647,13 @@ class MultiHeadAttention(nn.Module):
                 "paged_kv_blocks requires slot_decode=True (the paged "
                 "pool is the serving engine's per-lane cache mode)")
         if self.slot_decode:
-            if self.window is not None or self.sinks:
+            if self.sinks:
                 raise ValueError(
-                    "slot_decode (per-slot cache positions) supports the "
-                    "LINEAR cache only (full-precision or kv_cache_int8) "
-                    "— window/sinks keep the shared-index path")
+                    "slot_decode (per-slot cache positions) holds no "
+                    "attention sinks: they keep the shared-index path")
+            if self.window is not None and self.kv_cache_int8:
+                raise ValueError(
+                    "a window layer's per-slot caches hold no int8 rows")
             if self.paged_kv_blocks:
                 if self.paged_kv_blocks < 2:
                     raise ValueError(
@@ -638,16 +711,15 @@ class MultiHeadAttention(nn.Module):
         positions = cur + jnp.arange(q_len)
         if self.use_rope:
             pos_b = jnp.broadcast_to(positions, (b, q_len))
-            q = apply_rope(q, pos_b, base=self.rope_base,
-                           scaling=self.rope_scaling)
-            k = apply_rope(k, pos_b, base=self.rope_base,
-                           scaling=self.rope_scaling)
+            q = self._rope(q, pos_b)
+            k = self._rope(k, pos_b)
         index.value = cur + q_len
+        gate = self._gate(x)
 
         if rolling and q_len > 1:
             return self._rolling_block(x, q, k, v, cache_k, cache_v,
                                        cur, kv_heads, b, q_len,
-                                       fresh_cache)
+                                       fresh_cache, gate)
 
         kdt = cache_k.value.dtype
         if rolling:
@@ -680,7 +752,8 @@ class MultiHeadAttention(nn.Module):
                     [(jnp.arange(self.sinks) <= cur)[None, :], mask],
                     axis=1)
             return self._cache_attend(q, kc, vc, kv_heads, b, q_len,
-                                      x.shape[-1], mask=mask[None, None])
+                                      x.shape[-1], mask=mask[None, None],
+                                      gate=gate)
         scales = None
         if self.kv_cache_int8:
             # Quantize this call's rows: amax over head_dim per
@@ -704,7 +777,7 @@ class MultiHeadAttention(nn.Module):
         # the prefix rule is the whole mask here.
         return self._cache_attend(q, cache_k.value, cache_v.value,
                                   kv_heads, b, q_len, x.shape[-1],
-                                  start=cur, scales=scales)
+                                  start=cur, scales=scales, gate=gate)
 
     def _sink_buffers(self, b, kv_heads):
         """The StreamingLLM sink KV buffer pair ([B, sinks, Hkv, D])."""
@@ -774,10 +847,8 @@ class MultiHeadAttention(nn.Module):
         cur = index.value                                   # [B]
         positions = cur[:, None] + jnp.arange(q_len)        # [B, q]
         if self.use_rope:
-            q = apply_rope(q, positions, base=self.rope_base,
-                           scaling=self.rope_scaling)
-            k = apply_rope(k, positions, base=self.rope_base,
-                           scaling=self.rope_scaling)
+            q = self._rope(q, positions)
+            k = self._rope(k, positions)
         index.value = cur + q_len
 
         kdt = cache_k.value.dtype
@@ -796,9 +867,13 @@ class MultiHeadAttention(nn.Module):
                 k.astype(kdt))
             cache_v.value = cache_v.value.at[bidx, positions].set(
                 v.astype(kdt))
+        # A window layer keeps every row here (the batch-1 prefill
+        # cache and the linear slot grid are ``cache_len`` long) and
+        # walks the tiles its window reaches.
         return self._cache_attend(q, cache_k.value, cache_v.value,
                                   kv_heads, b, q_len, x.shape[-1],
-                                  start=cur, scales=scales)
+                                  start=cur, scales=scales,
+                                  window=self.window, gate=self._gate(x))
 
     def _paged_decode_step(self, x, kv_pools=None):
         """Per-slot decode over the PAGED pool: same append-and-attend
@@ -859,6 +934,20 @@ class MultiHeadAttention(nn.Module):
         bs = self.kv_block_size
         nb = self.paged_kv_blocks
         n_blk = -(-self.cache_len // bs)
+        ring = self.window is not None
+        if ring:
+            # A window layer's own pool: a ring of blocks a lane and the
+            # scratch block, its table under a name of its own.
+            if kv_pools is not None:
+                raise ValueError("a depth scan's carried pools hold no "
+                                 "window layer's ring")
+            if (self.ring_blocks * bs
+                    < self.window + q_len - 1):
+                raise ValueError(
+                    f"a ring of {self.ring_blocks} blocks of {bs} rows "
+                    f"cannot hold a window of {self.window} and "
+                    f"{q_len} new rows")
+            nb, n_blk = 1 + b * self.ring_blocks, self.ring_blocks
 
         q, k, v = self._qkv(x)
 
@@ -876,27 +965,30 @@ class MultiHeadAttention(nn.Module):
         # so pre-insert garbage decode is self-contained by
         # construction.
         table = self.variable(
-            "cache", "block_table", jnp.zeros, (b, n_blk), jnp.int32)
+            "cache", "window_table" if ring else "block_table", jnp.zeros,
+            (b, n_blk), jnp.int32)
         index = self.variable(
             "cache", "index", lambda: jnp.zeros((b,), jnp.int32))
         cur = index.value                                   # [B]
         positions = cur[:, None] + jnp.arange(q_len)        # [B, q]
         if self.use_rope:
-            q = apply_rope(q, positions, base=self.rope_base,
-                           scaling=self.rope_scaling)
-            k = apply_rope(k, positions, base=self.rope_base,
-                           scaling=self.rope_scaling)
+            q = self._rope(q, positions)
+            k = self._rope(k, positions)
         index.value = cur + q_len
+        gate = self._gate(x)
 
         # This step's rows into the pools, under the name the
         # device-scope contract (PERF.md §3) gives the write.
-        with jax.named_scope("kv_pool/write"):
+        with jax.named_scope("kv_pool/write/window" if ring
+                             else "kv_pool/write"):
             if self.kv_cache_int8:
                 k_store, sk = _quantize_kv_rows(k)
                 v_store, sv = _quantize_kv_rows(v)
             else:
                 k_store, v_store = k, v
-            phys, row = _paged_dest(table.value, positions, bs, nb)
+            phys, row = _paged_dest(
+                table.value, positions, bs, nb,
+                ring_of=self.cache_len if ring else None)
             pools = dict(
                 pools,
                 key_pool=_set_pool_rows(
@@ -941,28 +1033,39 @@ class MultiHeadAttention(nn.Module):
             out = pk.paged_attention(
                 q, k_pool, v_pool, table.value, held,
                 k_scales=k_scales, v_scales=v_scales,
-                cache_len=self.cache_len, block0=block0, use_pallas=True,
+                cache_len=self.cache_len, block0=block0,
+                window=self.window, use_pallas=True,
                 interpret=pk.fused_attn_interpret())
-            return self._attn_epilogue(out, b, q_len, x.shape[-1]), pools
+            return self._attn_epilogue(out, b, q_len, x.shape[-1],
+                                       gate), pools
+
+        rows = n_blk * bs if ring else self.cache_len
 
         def lane_view(pool):
             return pk.paged_kv_gather(
-                pool, table.value + block0, self.cache_len).reshape(
-                    b, self.cache_len, kv_heads, self.head_dim)
+                pool, table.value + block0, rows).reshape(
+                    b, rows, kv_heads, self.head_dim)
 
+        if ring:
+            # The ring, whole, under the mask of what each row holds.
+            seen = pk.ring_mask(cur, q_len, rows, self.window)
+            return self._cache_attend(
+                q, lane_view(k_pool), lane_view(v_pool), kv_heads, b,
+                q_len, x.shape[-1], mask=seen[:, None], gate=gate), pools
         if self.kv_cache_int8:      # else ``scales`` is None already
             scales = tuple(
                 pk.paged_kv_gather(s, table.value, self.cache_len)
                 for s in (k_scales, v_scales))
         return self._cache_attend(
             q, lane_view(k_pool), lane_view(v_pool), kv_heads, b, q_len,
-            x.shape[-1], start=cur, scales=scales), pools
+            x.shape[-1], start=cur, scales=scales, gate=gate), pools
 
     def _fused_paged_ok(self) -> bool:
         return fused_paged_ok()
 
     def _cache_attend(self, q, kc, vc, kv_heads, b, q_len, features, *,
-                      start=None, mask=None, scales=None):
+                      start=None, mask=None, scales=None, window=None,
+                      gate=None):
         """Attention of q over the cache buffers [B, rows, kv_heads, D].
 
         A linear cache hands in ``start`` [B] (or a scalar), the
@@ -973,8 +1076,10 @@ class MultiHeadAttention(nn.Module):
         and the values', [B, rows, kv_heads]) a tile at a time, so a row
         no lane holds is not read.  A cache of one tile is the ordinary
         masked attention over all of it.  A ring (the rolling window
-        and its sinks) addresses no prefix: it hands in its own ``mask``
-        over every row."""
+        and its sinks, a paged window layer's gathered ring) addresses
+        no prefix: it hands in its own ``mask`` over every row.
+        ``window`` narrows a linear cache's walk to the tiles a sliding
+        window reaches (``prefix_attention``)."""
         from tensorflow_train_distributed_tpu.ops.attention import (
             dot_product_attention, prefix_attention,
         )
@@ -1005,22 +1110,25 @@ class MultiHeadAttention(nn.Module):
             out = dot_product_attention(qh, *heads((kc, vc, scales)),
                                         mask=mask)
         else:
-            out = prefix_attention(qh, (kc, vc, scales), start, heads)
+            out = prefix_attention(qh, (kc, vc, scales), start, heads,
+                                   window=window)
         out = out.transpose(0, 2, 1, 3)
-        return self._attn_epilogue(out, b, q_len, features)
+        return self._attn_epilogue(out, b, q_len, features, gate)
 
-    def _attn_epilogue(self, out, b, q_len, features):
-        """Shared decode tail — constraint, head-merge, out-proj — for
-        the gathered-attend path and the fused paged-attention kernel
-        (one epilogue keeps the two paths' param use identical)."""
+    def _attn_epilogue(self, out, b, q_len, features, gate=None):
+        """Shared decode tail — constraint, the heads' gate
+        (``out_gate``), head-merge, out-proj — for the gathered-attend
+        path and the fused paged-attention kernel (one epilogue keeps
+        the two paths' param use identical)."""
         out = nn.with_logical_constraint(
             out, ("batch", "length", self._head_ax(self.num_heads), "kv"))
+        out = self._gated(out, gate)
         out = out.reshape(b, q_len, self.num_heads * self.head_dim)
         y = self._out_proj(out, features)
         return nn.with_logical_constraint(y, ("batch", "length", "embed"))
 
     def _rolling_block(self, x, q, k, v, cache_k, cache_v, cur, kv_heads,
-                       b, q_len, fresh):
+                       b, q_len, fresh, gate=None):
         """Multi-token call under the rolling cache, correct at ANY
         ``cur`` (first prefill, chunked prefill, speculative blocks).
 
@@ -1093,7 +1201,8 @@ class MultiHeadAttention(nn.Module):
             cache_k.value = jnp.roll(kcat[:, -w:], end, axis=1)
             cache_v.value = jnp.roll(vcat[:, -w:], end, axis=1)
         return self._cache_attend(q, kcat, vcat, kv_heads, b, q_len,
-                                  x.shape[-1], mask=keep[None, None])
+                                  x.shape[-1], mask=keep[None, None],
+                                  gate=gate)
 
 
 def _pad_last(x, width: int):

@@ -44,6 +44,21 @@ from tensorflow_train_distributed_tpu.ops.losses import (
 
 
 @dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer in a model whose layers differ
+    (``MoeConfig.attn_period``): its query heads (the KV heads and the
+    head size are the model's), its sliding window (None: every row),
+    and its rotary rule (``layers.apply_rope``): base, the share of a
+    head that is rotated, the scaling tuple."""
+
+    num_heads: int
+    window: Optional[int] = None
+    rope_base: float = 10_000.0
+    rotary_share: float = 1.0
+    rope_scaling: Optional[tuple] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class MoeConfig:
     vocab_size: int = 32_000
     d_model: int = 4096
@@ -147,6 +162,45 @@ class MoeConfig:
     # shared expert and the residual are whole.  None = all of them.
     experts_held: Optional[int] = None
     experts_offset: int = 0
+    # A head's size where it is not ``d_model // num_heads``.
+    head_dim: Optional[int] = None
+    # Attention kinds that differ by layer: a period of ``AttnKind``s
+    # counted from layer 0 (layer i is ``attn_period[i % len]``), each
+    # with its own query heads, window and rotary rule; ``num_heads``,
+    # ``rope_base`` and ``rope_scaling`` above then say nothing.  The
+    # layers of such a model share no parameter shape (they are
+    # unrolled here anyway).  None: every layer alike, from the fields
+    # above.  MHA/GQA only (no ``kv_lora_rank``).
+    attn_period: Optional[tuple] = None
+    # Per-head output gate of the attention (``layers.
+    # MultiHeadAttention.out_gate``), every layer.
+    attn_gate: bool = False
+
+    def attn_kind(self, layer: int) -> Optional[AttnKind]:
+        """Layer ``layer``'s kind, or None where layers do not differ."""
+        if not self.attn_period:
+            return None
+        return self.attn_period[layer % len(self.attn_period)]
+
+    @property
+    def attn_window(self) -> Optional[int]:
+        """The sliding window of the model's window layers (None: it has
+        none).  One size a model: the serving engine sizes one ring."""
+        sizes = {k.window for k in self.attn_period or ()
+                 if k.window is not None}
+        if len(sizes) > 1:
+            raise ValueError(f"window layers of several sizes {sizes}")
+        return sizes.pop() if sizes else None
+
+
+_LAGUNA_KINDS = (
+    # [full, sliding, sliding, sliding]: a full layer rotates half of
+    # each head under YaRN (cos and sin x attention_factor), a sliding
+    # layer the whole head, unscaled, and sees the last 512 rows.
+    AttnKind(num_heads=48, rope_base=500_000.0, rotary_share=0.5,
+             rope_scaling=("yarn", 128.0, 32.0, 1.0, 8192,
+                           1.4852030263919618)),
+) + (AttnKind(num_heads=72, window=512),) * 3
 
 
 MOE_PRESETS = {
@@ -224,6 +278,34 @@ MOE_PRESETS = {
         qk_nope_dim=12, qk_rope_dim=8, v_head_dim=16,
         rope_scaling=("yarn", 40.0, 32.0, 1.0, 16),
         index_heads=4, index_dim=16, index_topk=16, rms_epsilon=1e-6),
+    # Laguna-S-2.1 (poolside, ``laguna``) at its published widths: full
+    # and sliding-window (512) GQA layers 1 : 3 with their own query
+    # heads (48 / 72 over 8 KV heads of 128) and rotary rules, a
+    # per-head output gate, one leading SwiGLU layer, then 256
+    # sigmoid-routed experts (top 10, gates x 2.5) beside one shared.
+    # Deployments give ``experts_held`` and cut depth and vocabulary
+    # (benchmark/configs).
+    "laguna_s21": MoeConfig(
+        vocab_size=100_352, d_model=3072, num_layers=48, num_heads=48,
+        num_kv_heads=8, head_dim=128, ffn_size=1024, num_experts=256,
+        top_k=10, max_positions=1_048_576, rms_epsilon=1e-6,
+        dispatch="gmm", shared_expert_size=1024, norm_topk_prob=True,
+        dense_layers=1, dense_ffn_size=12_288, router="sigmoid",
+        routed_scaling=2.5, attn_period=_LAGUNA_KINDS, attn_gate=True),
+    # The same block at test size (float32): 4 and 6 query heads over
+    # 2 KV heads of 16, a window of 8.
+    "laguna_tiny": MoeConfig(
+        vocab_size=256, d_model=64, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=16, ffn_size=48, num_experts=8, top_k=2,
+        max_positions=128, dtype=jnp.float32, remat=False,
+        dispatch="gmm", shared_expert_size=48, dense_layers=1,
+        dense_ffn_size=160, router="sigmoid", routed_scaling=2.5,
+        rms_epsilon=1e-6, attn_gate=True,
+        attn_period=(
+            AttnKind(num_heads=4, rope_base=500_000.0, rotary_share=0.5,
+                     rope_scaling=("yarn", 8.0, 32.0, 1.0, 16,
+                                   1.2079441541679836)),
+        ) + (AttnKind(num_heads=6, window=8),) * 3),
     # DeepSeek/Qwen-MoE-style: always-on shared expert beside the
     # routed ones (tiny test shape).
     "moe_tiny_shared": MoeConfig(vocab_size=256, d_model=64,
@@ -745,6 +827,9 @@ class MoEMlpBlock(nn.Module):
 class MoeDecoderBlock(nn.Module):
     config: MoeConfig
     use_moe: bool = True
+    # Which layer of the model this is: it picks the layer's attention
+    # kind where they differ (``MoeConfig.attn_period``).
+    layer: int = 0
     # Autoregressive decode (models.generate): KV-cached attention; the
     # MoE dispatch needs nothing special — at q_len 1 each group holds
     # one token, capacity is >= 1 per expert, so routing never drops.
@@ -754,6 +839,9 @@ class MoeDecoderBlock(nn.Module):
     # Paged serving KV cache — see layers.MultiHeadAttention.
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    # Blocks of a window layer's paged ring a lane (layers.
+    # MultiHeadAttention.ring_blocks).
+    ring_blocks: int = 0
 
     @nn.compact
     def __call__(self, x, segment_ids=None, positions=None):
@@ -792,19 +880,36 @@ class MoeDecoderBlock(nn.Module):
 
     def _mha(self, h, segment_ids, positions):
         cfg = self.config
-        return L.MultiHeadAttention(
-            qkv_bias=cfg.qkv_bias,
-            num_heads=cfg.num_heads,
-            head_dim=cfg.d_model // cfg.num_heads,
+        head_dim = cfg.head_dim or cfg.d_model // cfg.num_heads
+        common = dict(
+            qkv_bias=cfg.qkv_bias, head_dim=head_dim,
             num_kv_heads=cfg.num_kv_heads,
             dtype=cfg.dtype, causal=True, use_rope=True,
-            rope_base=cfg.rope_base, name="attention",
-            decode=self.decode,
+            name="attention", decode=self.decode,
             cache_len=self.cache_len or cfg.max_positions,
             slot_decode=self.slot_decode,
             paged_kv_blocks=self.paged_kv_blocks,
-            kv_block_size=self.kv_block_size,
-        )(h, segment_ids=segment_ids, positions=positions)
+            kv_block_size=self.kv_block_size)
+        kind = cfg.attn_kind(self.layer)
+        if kind is None:
+            return L.MultiHeadAttention(
+                num_heads=cfg.num_heads, rope_base=cfg.rope_base,
+                out_gate=cfg.attn_gate, **common,
+            )(h, segment_ids=segment_ids, positions=positions)
+        # What the layer is comes from its kind's fields; the scope
+        # names it for the device trace.
+        with jax.named_scope("attn/full" if kind.window is None
+                             else "attn/window"):
+            return L.MultiHeadAttention(
+                num_heads=kind.num_heads, window=kind.window,
+                rope_base=kind.rope_base, rope_scaling=kind.rope_scaling,
+                rotary_dim=(None if kind.rotary_share == 1.0
+                            else int(head_dim * kind.rotary_share)),
+                out_gate=cfg.attn_gate,
+                ring_blocks=(self.ring_blocks
+                             if kind.window is not None else 0),
+                **common,
+            )(h, segment_ids=segment_ids, positions=positions)
 
 
 class MoeLmModel(nn.Module):
@@ -832,12 +937,16 @@ class MoeLmModel(nn.Module):
     # Paged serving KV cache — see layers.MultiHeadAttention.
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    ring_blocks: int = 0    # see MoeDecoderBlock
 
     @nn.compact
     def __call__(self, tokens, *, segment_ids=None, positions=None):
         cfg = self.config
         if segment_ids is not None and self.decode:
             raise ValueError("decode mode does not take packed segments")
+        if cfg.attn_period and cfg.kv_lora_rank:
+            raise ValueError("attn_period gives kinds of MHA/GQA layers; "
+                             "latent attention has one kind")
         if segment_ids is not None and positions is None:
             # Packed rows (llama-path contract): segment-masked attention
             # + RoPE positions restarting at each document boundary.
@@ -869,6 +978,7 @@ class MoeLmModel(nn.Module):
                     slot_decode=self.slot_decode,
                     paged_kv_blocks=self.paged_kv_blocks,
                     kv_block_size=self.kv_block_size,
+                    layer=i, ring_blocks=self.ring_blocks,
                     name=f"layer_{i}")(x, segment_ids, positions)
         x = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       name="final_norm")(x)

@@ -99,6 +99,19 @@ def prefix_tiles_walked(start, q_len: int, tile: int, cache_len: int):
         1, -(-cache_len // tile))
 
 
+def prefix_first_tile(start, tile: int, window: Optional[int]):
+    """The first tile ``prefix_attention`` walks under a sliding
+    ``window``: the shortest lane's first query, at ``min(start)``, sees
+    no row before ``min(start) - window + 1``, so the tiles before that
+    row's are not read (0 without a window).  With
+    ``prefix_tiles_walked`` for the end, one rule for the device's trip
+    range and for the host's ``prefill/piece`` ``window_rows``:
+    ``start`` needs ``min``, ``-``, ``//``, ``clip``."""
+    if window is None:
+        return 0
+    return (start.min() - (window - 1)).clip(0) // tile
+
+
 def prefix_attention(
     q: jax.Array,
     cache,
@@ -108,6 +121,7 @@ def prefix_attention(
     tile: Optional[int] = None,
     softmax_scale: Optional[float] = None,
     keep: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Attention of ``q`` [B, H, Q, D] over the prefix of a linear KV
     cache that its lanes hold, tile by tile with a running softmax.
@@ -136,6 +150,13 @@ def prefix_attention(
     row past them unmarked and unread) further restricts each query to
     the rows it marks: the walk and its cost stay those of the rows
     held, the rows left out are masked in each tile.
+
+    ``window``: a query at position p sees ``p - window < kv_pos <= p``
+    and no other row, and the walk starts at ``prefix_first_tile``: a
+    piece of a window layer reads the tiles its window and its own rows
+    reach, whatever the lane holds behind them.  A tile may then hold
+    no row that some query sees, so a masked entry's probability is
+    set to zero and not left to the running maximum.
     """
     tile = PREFIX_TILE if tile is None else tile
     cache_len = jax.tree.leaves(cache)[0].shape[1]
@@ -145,6 +166,8 @@ def prefix_attention(
 
     def seen(kv_pos, row0=None):            # [B | 1, 1, Q, rows]
         ok = kv_pos <= positions
+        if window is not None:
+            ok &= positions - kv_pos < window
         if keep is not None:
             ok &= keep if row0 is None else jax.lax.dynamic_slice_in_dim(
                 keep, row0, kv_pos.shape[0], axis=2)
@@ -175,10 +198,12 @@ def prefix_attention(
         kv_pos = row0 + jnp.arange(tile)
         s = (jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale).astype(
             jnp.float32)
-        s = jnp.where(seen(kv_pos, row0) & (kv_pos >= t * tile), s,
-                      mask_value)
+        ok = seen(kv_pos, row0) & (kv_pos >= t * tile)
+        s = jnp.where(ok, s, mask_value)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if window is not None:
+            p = jnp.where(ok, p, 0.0)
         alpha = jnp.exp(m - m_new)
         return (m_new, l * alpha + p.sum(axis=-1, keepdims=True),
                 acc * alpha + jnp.einsum(
@@ -188,7 +213,8 @@ def prefix_attention(
     v_like = jax.eval_shape(lambda: tile_kv(0)[1][1])
     stat = jnp.full((*q.shape[:-1], 1), mask_value, jnp.float32)
     _, l, acc = jax.lax.fori_loop(
-        0, prefix_tiles_walked(start, q_len, tile, cache_len), fold,
+        prefix_first_tile(start, tile, window),
+        prefix_tiles_walked(start, q_len, tile, cache_len), fold,
         (stat, jnp.zeros_like(stat),
          jnp.zeros((*q.shape[:-1], v_like.shape[-1]), jnp.float32)))
     return (acc / l).astype(v_like.dtype)

@@ -242,10 +242,32 @@ def fused_attn_interpret() -> bool:
             and jax.default_backend() != "tpu")
 
 
+def ring_row_positions(lengths, q_len: int, rows: int):
+    """The position each row of a lane's RING holds once a call's
+    ``q_len`` rows are in it: a ring of ``rows`` rows keeps position
+    ``p`` at row ``p % rows``, so after rows up to ``last = lengths +
+    q_len - 1`` row ``i`` holds ``last - ((last - i) mod rows)``, the
+    newest position of its residue (negative: never written).
+    ``lengths`` [lanes] -> int32 [lanes, rows]."""
+    last = lengths.astype(jnp.int32)[:, None] + (q_len - 1)
+    return last - jnp.mod(last - jnp.arange(rows, dtype=jnp.int32), rows)
+
+
+def ring_mask(lengths, q_len: int, rows: int, window: int):
+    """bool [lanes, q_len, rows]: the rows of a lane's ring that each of
+    a call's queries sees (query ``i`` sits at ``lengths + i``): written
+    at all, not after the query, and inside its window.  The mask of
+    every read that gathers a ring whole (``paged_attention_reference``,
+    the engine's gather leg)."""
+    held = ring_row_positions(lengths, q_len, rows)[:, None, :]
+    at = (lengths[:, None] + jnp.arange(q_len))[:, :, None]
+    return (held >= 0) & (held <= at) & (at - held < window)
+
+
 def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
                               k_scales=None, v_scales=None,
                               cache_len: Optional[int] = None,
-                              block0=0):
+                              block0=0, window: Optional[int] = None):
     """Pure-jax oracle: gather-then-attend, the exact math of the
     engine's XLA block-gather leg (``models.layers`` ``_cache_attend``
     minus the sharding constraints, which are numerically no-ops).
@@ -260,7 +282,11 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
     + i`` and sees rows ``<=`` it).  ``block0``: where the table's
     block 0 lies in the pools, for pools that hold several layers'
     blocks one after the other (the scales are one layer's, numbered by
-    the table itself).  Returns [lanes, q_len, heads, head_dim]."""
+    the table itself).  ``window``: a sliding-window layer, whose table
+    is a RING (``table[lane, (p // block_size) % n_blk]`` holds
+    position ``p``: ``ring_row_positions``) of at least ``window +
+    q_len - 1`` rows, and whose query at ``p`` sees ``p - window <
+    row <= p``.  Returns [lanes, q_len, heads, head_dim]."""
     from tensorflow_train_distributed_tpu.ops.attention import (
         dot_product_attention,
     )
@@ -269,6 +295,8 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
     lanes, q_len, heads, hd = q.shape
     kvh = k_pool.shape[2] // hd
     c = cache_len if cache_len is not None else table.shape[1] * bs
+    if window is not None:
+        c = table.shape[1] * bs             # the ring, whole
     kc = paged_kv_gather_reference(k_pool, table + block0, c)
     vc = paged_kv_gather_reference(v_pool, table + block0, c)
     kc, vc = (t.reshape(lanes, c, kvh, hd) for t in (kc, vc))
@@ -282,14 +310,30 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
         kc = jnp.repeat(kc, rep, axis=2)
         vc = jnp.repeat(vc, rep, axis=2)
     positions = lengths[:, None] + jnp.arange(q_len)        # [B, q]
-    mask = jnp.arange(c)[None, None, :] <= positions[:, :, None]
+    if window is None:
+        mask = jnp.arange(c)[None, None, :] <= positions[:, :, None]
+    else:
+        mask = ring_mask(lengths, q_len, c, window)
     out = dot_product_attention(
         q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
         vc.transpose(0, 2, 1, 3), mask=mask[:, None])
     return out.transpose(0, 2, 1, 3)
 
 
-def paged_blocks_walked(lengths, q_len: int, bs: int, n_blk: int):
+def paged_first_block(lengths, bs: int, window: Optional[int]):
+    """The first block of a lane's rows that ``paged_attention`` reads
+    under a sliding ``window``: the call's first query, at position
+    ``lengths``, sees no row before ``lengths - window + 1`` (block 0
+    without a window).  As ``paged_blocks_walked``, one rule for the
+    kernel and for the host: ``lengths`` needs ``-``, ``//``, ``clip``.
+    """
+    if window is None:
+        return 0
+    return (lengths - (window - 1)).clip(0) // bs
+
+
+def paged_blocks_walked(lengths, q_len: int, bs: int, n_blk: int,
+                        window: Optional[int] = None):
     """Blocks of its table that ``paged_attention`` reads for a lane
     holding ``lengths`` rows before the call: the ``q_len`` queries see
     rows ``0 .. lengths + q_len - 1``, so ``ceil((lengths + q_len) /
@@ -298,8 +342,14 @@ def paged_blocks_walked(lengths, q_len: int, bs: int, n_blk: int):
     reset lane's accumulator off an all-masked zero).  One rule for the
     kernel (a scalar out of SMEM) and for the host's ``kv_blocks``
     counter (a numpy vector): ``lengths`` needs ``+``, ``//``, ``clip``.
+    Under a sliding ``window`` the walk starts at ``paged_first_block``
+    and the count is of the blocks from there (``n_blk``: the blocks
+    the lane's whole context has, not its ring's).
     """
-    return ((lengths + (q_len + bs - 1)) // bs).clip(1, n_blk)
+    last = ((lengths + (q_len + bs - 1)) // bs).clip(1, n_blk)
+    if window is None:
+        return last
+    return last - paged_first_block(lengths, bs, window).clip(0, last - 1)
 
 
 def _paged_fold(bs: int, n_blk: int) -> int:
@@ -311,7 +361,7 @@ def _paged_fold(bs: int, n_blk: int) -> int:
 
 def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                        bs, fold, last_row, kvh, rep, q_len, hd, scale,
-                       int8):
+                       int8, window=None, ring=0):
     """Grid (lane,): the lane walks the blocks it holds
     (``paged_blocks_walked``), ``fold`` table entries to a step, and
     stops there.  The pools stay in HBM; each step's blocks come in by
@@ -320,7 +370,10 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     each query row's online (max, sumexp, acc) accumulator.  Row layout
     is [heads*q_len, hd] with row = head*q_len + qi, so each GQA group's
     rows are one contiguous slice and the per-row query position is
-    ``row % q_len``."""
+    ``row % q_len``.  A sliding ``window`` (static) starts the walk at
+    ``paged_first_block``, finds a block in the lane's ``ring`` table
+    entries by its number modulo ``ring``, and drops the rows behind
+    each query's window."""
     from jax.experimental.pallas import tpu as pltpu
 
     if int8:
@@ -328,8 +381,9 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = rest[-7:]
     i = pl.program_id(0)
     cur = len_ref[i]
-    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1)
+    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1, window)
     steps = pl.cdiv(live, fold)
+    first = paged_first_block(cur, bs, window)
 
     def copies(step, slot, wait=False):
         # Entries of the lane's last step past its count repeat its
@@ -337,8 +391,10 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
         # lane does not hold is ever read.  A wait needs only shapes.
         out = []
         for p in range(fold):
-            blk = 0 if wait else tbl_ref[
-                i, jnp.minimum(step * fold + p, live - 1)]
+            entry = jnp.minimum(step * fold + p, live - 1)
+            if window is not None:
+                entry = jax.lax.rem(first + entry, ring)
+            blk = 0 if wait else tbl_ref[i, entry]
             out += [pltpu.make_async_copy(pool.at[blk], buf.at[slot, p],
                                           sem.at[slot])
                     for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
@@ -373,7 +429,11 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             c.wait()
         kf = k_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
         vf = v_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
-        seen = step * n + col <= last_seen
+        if window is None:
+            seen = step * n + col <= last_seen
+        else:
+            pos = first * bs + step * n + col
+            seen = (pos <= last_seen) & (cur + qi - pos < window)
         if int8:
             cols = pl.ds(pl.multiple_of(step * n, n), n)
             ksf, vsf = ks_ref[0, :, cols], vs_ref[0, :, cols]  # [kvh, n]
@@ -394,6 +454,10 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                                 jnp.max(logits, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(logits - m_new)
+            if window is not None:
+                # A step may hold no row some query sees (its window
+                # starts further on): such entries add nothing.
+                p = jnp.where(seen, p, 0.0)
             l_ref[rows] = (l_ref[rows] * alpha
                            + jnp.sum(p, axis=-1, keepdims=True))
             if int8:                         # and of its value row
@@ -410,6 +474,7 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 def paged_attention(q, k_pool, v_pool, table, lengths, *,
                     k_scales=None, v_scales=None,
                     cache_len: Optional[int] = None, block0=0,
+                    window: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False):
     """Flash-style decode attention DIRECTLY through the block table —
@@ -428,11 +493,22 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
     what the lanes hold, not the table's width.  An int8 pool's scales
     (4 bytes a row and KV head against ``head_dim`` of them) are too
     narrow for a copy of their own: they come in as one gathered
-    [kv_heads, rows] strip a lane."""
+    [kv_heads, rows] strip a lane.  ``window``: the same kernel over a
+    sliding-window layer's RING (``paged_attention_reference``): the
+    walk starts at the window's first block (``paged_first_block``), so
+    a lane reads the blocks its window spans whatever its context, and
+    ``cache_len`` is the context's, not the ring's."""
+    if window is not None:
+        if k_scales is not None:
+            raise ValueError("a window layer's ring holds no int8 rows")
+        if cache_len is None:
+            raise ValueError("a ring says nothing of the context's "
+                             "length: window needs cache_len")
     if not _use_pallas(use_pallas) and not interpret:
         return paged_attention_reference(
             q, k_pool, v_pool, table, lengths, k_scales=k_scales,
-            v_scales=v_scales, cache_len=cache_len, block0=block0)
+            v_scales=v_scales, cache_len=cache_len, block0=block0,
+            window=window)
     from jax.experimental.pallas import tpu as pltpu
 
     bs = k_pool.shape[1]
@@ -446,6 +522,10 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
     int8 = k_scales is not None
     fold = _paged_fold(bs, n_blk)
     last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
+    walk = {}
+    if window is not None:
+        last_row = cache_len - 1
+        walk = dict(window=window, ring=n_blk)
     # [lanes, q_len, H, hd] → [lanes, H*q_len, hd]: row = h*q_len + qi,
     # so each kv-head group's rows are contiguous in the kernel.
     qt = q.transpose(0, 2, 1, 3).reshape(lanes, heads * q_len, hd)
@@ -466,7 +546,7 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         functools.partial(
             _paged_attn_kernel, bs=bs, fold=fold, last_row=last_row,
             kvh=kvh, rep=rep, q_len=q_len, hd=hd, scale=hd ** -0.5,
-            int8=int8),
+            int8=int8, **walk),
         # No name= here: a name becomes the HLO instruction's, and the
         # benchmark finds this kernel's device events by the name the
         # calling method gives it (``attention._paged_decode_step``).

@@ -109,8 +109,12 @@ CONTRACT = {
     # experts, how many and the share of the (token, choice) pairs that
     # fell on them; where attention chooses its rows (a learned
     # selection), the rows a step and layer scored and attended over
-    # the live lanes
-    "engine/step": ("lanes positions kv_blocks kv_table_blocks pieces "
+    # the live lanes; where some layers see a sliding window, the
+    # blocks one such layer's walk of its rings reaches over all slots
+    # (kv_window_blocks: the kernel's rule from the window's first
+    # block on)
+    "engine/step": ("lanes positions kv_blocks kv_table_blocks "
+                    "kv_window_blocks pieces "
                     "prefill_tokens committed queued experts_hit "
                     "expert_load_cv experts_held routed_here "
                     "rows_scored rows_selected"),
@@ -125,13 +129,17 @@ CONTRACT = {
     # select_rows: of them, the rows such a selection counts over to
     # find its k-th score (ops.attention.select_tiles_counted: 0 where
     # no query of the piece sees more rows than it keeps, or the model
-    # has no selection)
+    # has no selection); window_rows: of them, the rows a sliding-
+    # window layer's walk reads (ops.attention.prefix_first_tile on; 0
+    # where no layer has a window)
     "prefill/piece": ("rid piece n_pieces tokens rows select_rows "
-                      "cache_rows"),
+                      "window_rows cache_rows"),
     "prefill/wait": "rid",
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",
-    "kv/alloc": "rid blocks shared",
+    # pool: "full" (blocks of the allocator's pool, a lane's whole
+    # context) or "window" (the slot's ring in each window layer)
+    "kv/alloc": "rid blocks shared pool",
     "kv/export": "tokens",
     "kv/install": "tokens",
     # instants

@@ -79,8 +79,28 @@ and latent-attention MoE), greedy or sampled; int8 weight-only serving
 via ``quant_scales``; ``kv_cache_int8`` configs (int8 rows + per-row
 f32 scales, in the batch-1 cache and the pool alike); tensor-parallel
 serving via ``mesh=``; speculative decoding with a draft model at a
-fixed or adaptive depth.  LoRA-unmerged params and sliding windows keep
-the shared-index ``generate()`` path.
+fixed or adaptive depth.  LoRA-unmerged params, attention sinks and the
+dense family's one global ``sliding_window`` keep the shared-index
+``generate()`` path.
+
+**Window and full attention layers side by side** (a ``MoeConfig`` with
+``attn_period``): two kinds of cache in the one slot grid.  A full layer
+holds a lane's whole context in the blocks of its table, as above.  A
+window layer holds, in a pool of its own, a RING of ``ring_blocks``
+blocks a lane (``ceil(window / kv_block_size) + 1``: a decode step adds
+one row, and a window that starts mid-block reaches one block more;
+position ``p`` in ring entry ``(p // block_size) % ring_blocks``): its memory and a decode step's reads are bounded by the
+window whatever the context (``paged_blocks_walked`` with a first
+block).  Slot ``s`` owns ring ``s``: admission keys on the full layers'
+free blocks and on a free slot, and a retired lane's ring table points
+at the scratch block like its block table.  The batch-1 prefill cache
+keeps every row in both kinds of layer (a piece of a window layer walks
+the tiles its window reaches: ``prefix_first_tile``) and the insert
+copies the last ``ring_blocks`` blocks into the ring.  Rows behind a
+window are gone and cannot be rebuilt without running every layer below
+over them, so such an engine shares no prefix: no radix match,
+``preload_prefix`` raises, KV export ships nothing (the receiver
+prefills).
 
 **Fused paged attention** (TPU): the paged decode read is one Pallas
 kernel (``ops.pallas_kernels.paged_attention``) that attends through
@@ -133,7 +153,7 @@ from tensorflow_train_distributed_tpu.models.quant import (
 from tensorflow_train_distributed_tpu.ops import attention as attention_ops
 from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
     paged_blocks_walked,
-)
+)  # with a window: from ``paged_first_block`` on
 
 
 @dataclasses.dataclass
@@ -241,6 +261,9 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: the blocks of the paged pool those reach over all slots (what the
 #: fused attention kernel reads) and the block table's whole size
 #: (slots x blocks a lane, what it read before it followed lengths),
+#: the blocks ONE window layer's walk of its rings reaches over all
+#: slots (``kv_window_blocks``: the same rule from the window's first
+#: block on; 0 for a model without window layers),
 #: prefill pieces run and the prompt tokens they carried, output tokens
 #: handed to requests.  A step that harvests a decode chunk of a model
 #: with routed experts adds, as means over the chunk's steps and expert
@@ -253,7 +276,8 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: rows, the rows a step and layer scored and attended over its live
 #: lanes (``rows_scored``, ``rows_selected``): ``_count_sown``.
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
-                "pieces", "prefill_tokens", "committed")
+                "kv_window_blocks", "pieces", "prefill_tokens",
+                "committed")
 
 
 def _bucket_len(n: int, buckets) -> int:
@@ -319,18 +343,20 @@ class ServingEngine:
                  prefix_cache_limit: int = 32,
                  hbm_budget_bytes: Optional[int] = None,
                  hbm_headroom: float = 0.1):
-        # MoeConfig has no window knob; getattr keeps one check covering
-        # both decoder families.  kv_cache_int8 configs SERVE here (the
-        # per-slot and paged caches both quantize with the linear-cache
-        # recipe); only the rolling-window/sink cache shapes stay
-        # generate()-only.
+        # kv_cache_int8 configs SERVE here (the per-slot and paged
+        # caches both quantize with the linear-cache recipe), and so do
+        # window layers that a MoeConfig's ``attn_period`` names (a ring
+        # of blocks a lane, below).  The dense family's ONE global
+        # ``sliding_window`` and attention sinks stay generate()-only:
+        # their rolling cache and sink buffer have no per-slot form yet.
         if (getattr(config, "sliding_window", None) is not None
                 or getattr(config, "attention_sinks", 0)):
             raise ValueError(
-                "the serving engine's per-slot caches hold the full "
-                "context; sliding_window / attention_sinks configs "
-                "serve through models.generate (kv_cache_int8 is "
-                "supported here)")
+                "the serving engine holds a window layer's rows in a "
+                "ring only where the config names its layers' kinds "
+                "(MoeConfig.attn_period); a LlamaConfig's global "
+                "sliding_window and attention_sinks serve through "
+                "models.generate (kv_cache_int8 is supported here)")
         if has_lora_leaves(params):
             raise ValueError(
                 "merge LoRA adapters before engine serving: params = "
@@ -393,6 +419,21 @@ class ServingEngine:
 
         self._exact_prefill = (isinstance(config, MoeConfig)
                                and config.dispatch != "gmm")
+        # The sliding window of the config's window layers (None: it
+        # has none): derived from the config, no flag.
+        self._window = getattr(config, "attn_window", None)
+        if self._window is not None and draft_config is not None:
+            raise ValueError(
+                "speculative decoding beside window layers is not served "
+                "yet (a verify block's rollback over a ring is untested)")
+        if getattr(draft_config, "attn_window", None) is not None:
+            raise ValueError("a draft with window layers is not served")
+        # Whether requests may share cached prefix rows (the radix
+        # index, preloaded pairs, KV handoff).  Not where routing
+        # depends on the prefill's length, and not beside window
+        # layers, whose rows behind the window are gone.
+        self._share_prefix = (not self._exact_prefill
+                              and self._window is None)
         # Chunked prefill: long prompts run through the SAME per-piece
         # program in ``prefill_chunk``-token pieces (the decode cache
         # appends multi-token blocks at any position), bounding prefill
@@ -569,6 +610,18 @@ class ServingEngine:
             self.hbm_budget_bytes = budget
             self._hbm_autosized = budget
         self._kv_pool = self._radix = None
+        # Blocks of a window layer's ring a lane: the window of the
+        # newest of the rows ONE model call adds (a decode step adds
+        # one: speculation beside window layers is refused above, and
+        # a chunk's steps add theirs one after another), and one block
+        # more for a window that starts mid-block.  No prefill piece
+        # writes a ring: pieces run on the batch-1 cache, which keeps
+        # every row, and the insert copies the ring's blocks from it.
+        self._ring_blocks = 0
+        if self.paged and self._window is not None:
+            q_len = 1
+            self._ring_blocks = 1 + -(-(self._window + q_len - 1)
+                                      // self.kv_block_size)
         if self.paged:
             self._kv_pool = serving_kv.KVBlockPool(
                 kv_pool_blocks, self.kv_block_size)
@@ -576,7 +629,8 @@ class ServingEngine:
         self._model = (_decode_model(
             config, self.cache_len, slot_decode=True,
             paged_kv_blocks=1 + kv_pool_blocks,
-            kv_block_size=self.kv_block_size)
+            kv_block_size=self.kv_block_size,
+            ring_blocks=self._ring_blocks)
             if self.paged else self._prefill_model)
         if draft_config is not None:
             self._draft_model = (_decode_model(
@@ -715,29 +769,37 @@ class ServingEngine:
         # eval_shape (host-only trace, no device work) so the /metrics
         # scrape thread reads a plain int.  The --kv-pool-blocks
         # oversizing lever is sized against this number.
-        self._kv_pool_bytes = 0
+        self._kv_pool_bytes = self._kv_ring_bytes = 0
         if self.paged:
-            def _pool_bytes(struct):
+            def _pool_bytes(struct, ringed: bool):
+                """Bytes of the row-holding leaves of one kind: a
+                window layer's rings, or the blocks tables map."""
+                rings = self._ringed_modules(struct)
                 return sum(
                     int(np.prod(leaf.shape))
                     * jnp.dtype(leaf.dtype).itemsize
                     for p, leaf in
                     jax.tree_util.tree_flatten_with_path(struct)[0]
-                    if getattr(p[-1], "key", "") in _ROW_LEAVES)
+                    if getattr(p[-1], "key", "") in _ROW_LEAVES
+                    and (self._path_key(p)[:-1] in rings) == ringed)
 
-            self._kv_pool_bytes = _pool_bytes(
-                self._cache_struct(self.slots, grid=True))
+            grid = self._cache_struct(self.slots, grid=True)
+            self._kv_ring_bytes = _pool_bytes(grid, True)
+            self._kv_pool_bytes = (_pool_bytes(grid, False)
+                                   + self._kv_ring_bytes)
             if self._draft_model is not None:
                 self._kv_pool_bytes += _pool_bytes(
                     self._cache_struct(self.slots, draft=True,
-                                       grid=True))
-            # Per-block row bytes across layers (draft + int8 scale
-            # pools included): the host allocator's byte view of its
-            # own blocks, so block-count accounting (serving_kv) can
-            # be read in BYTES too — what admission and the memcheck
-            # gauges reason in.
+                                       grid=True), False)
+            # Per-block row bytes across the layers whose blocks the
+            # allocator hands out (draft + int8 scale pools included;
+            # a window layer's rings are the slots', not the
+            # allocator's): its byte view of its own blocks, so
+            # block-count accounting (serving_kv) can be read in BYTES
+            # too — what admission and the memcheck gauges reason in.
             self._kv_pool.bytes_per_block = (
-                self._kv_pool_bytes // (1 + self._kv_pool.n_blocks))
+                (self._kv_pool_bytes - self._kv_ring_bytes)
+                // (1 + self._kv_pool.n_blocks))
         if self.hbm_budget_bytes is not None:
             # Budgeted engines precompute the admission projection NOW:
             # validate_request runs on gateway HANDLER threads, which
@@ -989,6 +1051,21 @@ class ServingEngine:
         return tuple(getattr(k, "key", str(k)) for k in path)
 
     @classmethod
+    def _ringed_modules(cls, cache) -> set:
+        """Path keys of the modules of a grid cache tree that hold
+        their rows in a ring (a window layer: the module with a
+        ``window_table``)."""
+        return {cls._path_key(p)[:-1] for p, _ in
+                jax.tree_util.tree_flatten_with_path(cache)[0]
+                if getattr(p[-1], "key", "") == "window_table"}
+
+    def _ring_row(self, slot):
+        """Slot ``slot``'s ring in a window layer's pool: block 0 is
+        scratch, ring ``s`` the ``ring_blocks`` blocks after it."""
+        return (1 + slot * self._ring_blocks
+                + jnp.arange(self._ring_blocks, dtype=jnp.int32))
+
+    @classmethod
     def _paired_leaves(cls, pooled, linear) -> list:
         """[(pool leaf's path key, linear leaf's path key)] for every
         row-holding leaf of a paged cache tree and the
@@ -1016,7 +1093,8 @@ class ServingEngine:
             pairs.append((key, held[0]))
         return pairs
 
-    def _scatter_rows_tree(self, cache, cache_1, table_row, start, end):
+    def _scatter_rows_tree(self, cache, cache_1, table_row, start, end,
+                           slot=None):
         """Put the batch-1 LINEAR cache's rows [start, end) into the
         paged pool at ``table_row``'s blocks (traced helper shared by
         insert and preload; leaves pair by ``_paired_leaves``).  The
@@ -1030,8 +1108,25 @@ class ServingEngine:
         part, the rows outside it keep the bytes they had.  int8
         configs carry the per-row scales along the same map: the pool
         stores exactly the bytes the batch-1 prefill quantized, which
-        is what keeps int8 paged parity bitwise."""
+        is what keeps int8 paged parity bitwise.
+
+        A window layer's pool (``_ringed_modules``) takes the last
+        ``ring_blocks`` blocks that end with row ``end - 1``, each at
+        its number modulo the ring, into ``slot``'s ring: the rows a
+        decode step's window can reach, whole blocks of the batch-1
+        cache (no block of a ring is shared, so ``start`` says nothing
+        there); with no ``slot`` (a preload) its pools stay as they
+        are."""
         bs, n_blk = self.kv_block_size, self._kv_nblk_lane
+        rings = self._ringed_modules(cache)
+        if rings and slot is not None:
+            ring = self._ring_blocks
+            entry = jnp.arange(ring)
+            top = (end - 1) // bs           # the block of the last row
+            # The newest block of each entry's residue, none before 0.
+            ring_src = top - jnp.mod(top - entry, ring)
+            ring_dst = jnp.where(ring_src >= 0, self._ring_row(slot),
+                                 1 + self.slots * ring)
         pos = jnp.arange(n_blk * bs).reshape(n_blk, bs)
         live = (pos >= start) & (pos < end)
         blocks = jnp.where(live.any(axis=1), table_row,
@@ -1042,7 +1137,7 @@ class ServingEngine:
 
         def scatter(path, leaf):
             key = self._path_key(path)
-            if key not in source:
+            if key not in source or (key[:-1] in rings and slot is None):
                 return leaf
             _, row_dims, lin_row_dims = _ROW_LEAVES[key[-1]]
             axis = leaf.ndim - (2 + row_dims)      # the pool's blocks
@@ -1057,6 +1152,10 @@ class ServingEngine:
             src = jnp.pad(src, tail)
             src = src.reshape(leaf.shape[:axis] + (n_blk,)
                               + leaf.shape[axis + 1:])
+            if key[:-1] in rings:
+                return leaf.at[(slice(None),) * axis + (ring_dst,)].set(
+                    jnp.take(src, ring_src, axis=axis, mode="clip")
+                    .astype(leaf.dtype), mode="drop")
             had = jnp.take(leaf, blocks, axis=axis, mode="clip")
             new = jnp.where(live.reshape(live.shape + (1,) * row_dims),
                             src.astype(leaf.dtype), had)
@@ -1076,12 +1175,14 @@ class ServingEngine:
         ``start`` come from radix-shared blocks and are already
         there)."""
         cache = self._scatter_rows_tree(cache, cache_1, table_row,
-                                        start, true_len)
+                                        start, true_len, slot)
 
         def pin(path, leaf):
             name = getattr(path[-1], "key", "")
             if name == "block_table":
                 return leaf.at[..., slot, :].set(table_row)
+            if name == "window_table":
+                return leaf.at[..., slot, :].set(self._ring_row(slot))
             if name == "index":
                 return leaf.at[..., slot].set(true_len)
             return leaf
@@ -1111,6 +1212,10 @@ class ServingEngine:
         Rows past ``matched`` gather whatever the lane's owned blocks
         hold — garbage the write-before-read prefill rule keeps
         invisible, exactly like the linear cache's stale rows."""
+        if self._ringed_modules(cache):
+            raise ValueError(
+                "a window layer's rows behind its window are gone: no "
+                "prefix is gathered out of its ring")
         pools = {self._path_key(p): leaf for p, leaf
                  in jax.tree_util.tree_flatten_with_path(cache)[0]}
         struct = self._cache_struct(1, draft=draft)
@@ -1148,7 +1253,7 @@ class ServingEngine:
         now owns."""
         def rst(path, leaf):
             name = getattr(path[-1], "key", "")
-            if name == "block_table":
+            if name in ("block_table", "window_table"):
                 return jnp.where(stale[:, None], 0, leaf)
             if name == "index":
                 return jnp.where(stale, 0, leaf)
@@ -1507,6 +1612,12 @@ class ServingEngine:
                 "prefix caching needs length-independent routing; "
                 "dense-dispatch MoE prefills at the exact prompt length "
                 "(dispatch='gmm' supports prefix caching)")
+        if self._window is not None:
+            raise ValueError(
+                f"this model has window layers (window {self._window}): "
+                f"their rows behind the window are not kept and cannot "
+                f"be rebuilt without running every layer below over "
+                f"them, so no prefix is shared between requests")
         n = len(tokens)
         if n >= self.cache_len:
             raise ValueError(
@@ -1648,7 +1759,7 @@ class ServingEngine:
         decode worker, same as any radix hit).  Mutates engine state —
         callers marshal onto the engine's owning thread
         (``EngineDriver.call``)."""
-        if not self.paged or self._exact_prefill:
+        if not self.paged or not self._share_prefix:
             return None
         tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
         bs = self.kv_block_size
@@ -1729,7 +1840,7 @@ class ServingEngine:
         and partial-failure semantics are all the tested ones.  Mutates
         engine state — callers marshal onto the engine's owning thread
         (``EngineDriver.call``)."""
-        if not self.paged or self._exact_prefill:
+        if not self.paged or not self._share_prefix:
             return 0
         tokens = [int(t) for t in meta.get("tokens", ())]
         n = int(meta.get("n", 0))
@@ -1859,7 +1970,7 @@ class ServingEngine:
             bs = self.kv_block_size
             m = max(0, (len(state.tokens) - 1) // bs)
             kv = (self._lane_kv[slot]
-                  if self.paged and not self._exact_prefill else None)
+                  if self.paged and self._share_prefix else None)
             if kv is not None and m > 0 and self._cache is not None:
                 head = [int(t) for t in state.tokens[:m * bs]]
                 # The lane's claim already holds a ref on every block
@@ -1966,7 +2077,7 @@ class ServingEngine:
         # hit stats (one admission must not read as thousands), same
         # per-request rule as the refusal counter below.
         retry = rid == self._kv_refused_rid
-        matched, shared = ((0, []) if self._exact_prefill
+        matched, shared = ((0, []) if not self._share_prefix
                            else self._radix.match(prompt,
                                                   record=not retry))
         # Ref the shared blocks BEFORE allocating: eviction only takes
@@ -1977,7 +2088,7 @@ class ServingEngine:
         n_owned = need - len(shared)
         with (contextlib.nullcontext() if retry
               else events.span("kv/alloc", rid=rid, blocks=n_owned,
-                               shared=len(shared))):
+                               shared=len(shared), pool="full")):
             owned = self._kv_pool.alloc(n_owned)
             if owned is None:
                 evicted = self._radix.evict_for(n_owned)
@@ -2025,7 +2136,7 @@ class ServingEngine:
         with the same prefix share them immediately."""
         self._lane_kv[slot] = kv
         self._stale_slots.discard(slot)
-        if not self._exact_prefill:
+        if self._share_prefix:
             table = kv.table(self._kv_nblk_lane)
             self._radix.insert(prompt, lambda j: table[j])
 
@@ -2040,7 +2151,7 @@ class ServingEngine:
         kv = self._lane_kv[slot]
         if kv is None:
             return
-        if tokens is not None and not self._exact_prefill:
+        if tokens is not None and self._share_prefix:
             bs = self.kv_block_size
             keep = tokens[:((len(tokens) - 1) // bs) * bs]
             table = kv.table(self._kv_nblk_lane)
@@ -2077,7 +2188,7 @@ class ServingEngine:
         copies the 4-token tail).  Suffix prefill piece sizing follows
         ``pre_len`` exactly as on the linear path."""
         pre_len, pre_pair = kv.matched, None
-        if not self._exact_prefill:
+        if self._share_prefix:
             lin_len, lin_pair = self._match_prefix(prompt, touch=True)
             if lin_len > pre_len:
                 pre_len, pre_pair = lin_len, lin_pair
@@ -2317,6 +2428,12 @@ class ServingEngine:
                             (rid, prompt, max_new, seed, resume))
                         return
                     table_j = self._kv_table(kv)
+                    if self._ring_blocks:
+                        # The slot's own ring in each window layer:
+                        # claimed with the slot, nothing to refuse.
+                        events.instant("kv/alloc", rid=rid,
+                                       blocks=self._ring_blocks, shared=0,
+                                       pool="window")
                     pre_len, pre_pair = self._admission_match(kv, prompt)
                 else:
                     pre_len, pre_pair = self._match_prefix(prompt,
@@ -2409,11 +2526,17 @@ class ServingEngine:
             attention_ops.select_tiles_counted(
                 start, task.piece, top, tile, self.cache_len))
         ) if 0 < top < self.cache_len else 0
+        # Of ``rows``, those a WINDOW layer's walk reads: from its
+        # window's first tile on (``prefix_first_tile``).
+        window_rows = 0 if self._window is None else max(
+            0, rows - tile * int(attention_ops.prefix_first_tile(
+                start, tile, self._window)))
         with self._ctx(), events.span(
                 "prefill/piece", rid=task.request_id,
                 piece=task.cursor + task.d_cursor,
                 n_pieces=task.n_pieces, tokens=real, rows=rows,
-                select_rows=select_rows, cache_rows=self.cache_len):
+                select_rows=select_rows, window_rows=window_rows,
+                cache_rows=self.cache_len):
             if task.cursor < task.n_pieces:
                 if task.cache_1 is None:
                     task.cache_1 = self._admission_cache_1(
@@ -2638,17 +2761,25 @@ class ServingEngine:
         ``kv_blocks`` is the fused attention kernel's own rule
         (``paged_blocks_walked``: the blocks a lane's rows and this
         call's ``spec_k + 1`` queries reach, one for an idle slot) over
-        all slots, of the ``kv_table_blocks`` their tables have; both 0
-        on a linear cache."""
-        kv_blocks = kv_table_blocks = 0
+        all slots, of the ``kv_table_blocks`` their tables have;
+        ``kv_window_blocks`` the same rule from a window's first block
+        on, for one window layer; all 0 on a linear cache."""
+        kv_blocks = kv_table_blocks = kv_window_blocks = 0
         if self.paged:
             kv_table_blocks = self.slots * self._kv_nblk_lane
+            lengths = np.asarray(held, np.int64)
             kv_blocks = self.slots - len(held) + int(paged_blocks_walked(
-                np.asarray(held, np.int64), spec_k + 1,
-                self.kv_block_size, self._kv_nblk_lane).sum())
+                lengths, spec_k + 1, self.kv_block_size,
+                self._kv_nblk_lane).sum())
+            if self._window is not None:
+                kv_window_blocks = self.slots - len(held) + int(
+                    paged_blocks_walked(
+                        lengths, spec_k + 1, self.kv_block_size,
+                        self._kv_nblk_lane, self._window).sum())
         self._step_counts.update(
             lanes=len(held), positions=sum(held), kv_blocks=kv_blocks,
-            kv_table_blocks=kv_table_blocks)
+            kv_table_blocks=kv_table_blocks,
+            kv_window_blocks=kv_window_blocks)
 
     def _count_sown(self, sown) -> None:
         """``engine/step``'s account of what the layers of a harvested

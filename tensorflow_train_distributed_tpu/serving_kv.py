@@ -41,6 +41,14 @@ rows to the prefill it skipped.  Partial (sub-block) prefixes are not
 shared — the tail of a prompt that doesn't fill a block is private to
 its lane, which is what makes lane writes copy-free.
 
+What is NOT here: a sliding-window layer's rows.  Such a layer has a
+pool of its own, ``1 + slots x ring_blocks`` blocks, in which slot ``s``
+owns ring ``s`` for as long as the engine runs (``serving.ServingEngine.
+_ring_row``): nothing to allocate, share or evict, so admission keys on
+this module's free blocks (the full layers' whole contexts) and on a
+free slot, and an engine with window layers asks the radix index for no
+match at all (rows behind a window are gone: ``_share_prefix``).
+
 Everything here is plain Python on the engine's single-threaded host
 loop — no jax imports, no device work — so the allocator is testable
 without a device and adds nothing to the serving hot path beyond dict

@@ -139,6 +139,24 @@ _LLAMA_ONLY = ("test_benchmark_manifest.py::"
 # ``serve.py`` into ``serve_family.py`` moves the registration into the
 # table itself and deletes this import with the hook below.
 import benchmark.harness.serve_share  # noqa: E402,F401
+# The same stop-gap, for the family of a decoder whose attention layers
+# differ by a pattern (``serve_pattern.py`` registers "moe_pattern").
+import benchmark.harness.serve_pattern  # noqa: E402,F401
+
+
+# ``tests/benchmark/test_benchmark_deepseek_v32.py::
+# test_new_cells_traffic_and_metrics_are_found_by_name`` asserts that
+# its cell is the LAST name in the ``workloads`` of the two accepted
+# metrics it was appended to (``host_self_ms.decode``,
+# ``decode_lanes_mean.decode``).  A later cell is appended after it, as
+# the contract has it, and that file is the benchmark's (a `benchmark`
+# PR's to change: ``in`` for ``[-1]``).  Until then
+# ``test_benchmark_laguna.py::test_the_earlier_share_cell_reads_as_
+# before_a_later_cell_was_appended`` runs the same function, every
+# assertion of it, on the manifest with the later cell's name taken off
+# those lists.  The same stop-gap as above, strict for the same reason.
+_LAST_IN_ITS_LISTS = ("test_benchmark_deepseek_v32.py::"
+                      "test_new_cells_traffic_and_metrics_are_found_by_name")
 
 
 def pytest_collection_modifyitems(items):
@@ -149,3 +167,10 @@ def pytest_collection_modifyitems(items):
                        "superseded for every family by "
                        "test_every_configuration_file_is_what_its_"
                        "family_runs", strict=True))
+        if item.nodeid.endswith(_LAST_IN_ITS_LISTS):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts its cell is the last appended to two "
+                       "accepted metrics' lists; run whole on the "
+                       "manifest as it was by test_the_earlier_share_"
+                       "cell_reads_as_before_a_later_cell_was_appended",
+                strict=True))

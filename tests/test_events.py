@@ -330,7 +330,10 @@ def test_trace_report_prints_the_kv_walk_live_share(tmp_path, capsys):
     read at the step's dispatch) beside ``kv_table_blocks`` (the slots x
     blocks-a-lane table it spans): the report sums both over the window
     and prints the share; a step with no dispatch, or a linear cache
-    (both 0), adds nothing."""
+    (both 0), adds nothing.  Where some layers see a sliding window,
+    ``kv_window_blocks`` (one such layer's walk of its rings) is
+    printed as a share of ``kv_blocks``; a model without one prints no
+    such line."""
     import importlib.util
     import os
 
@@ -353,6 +356,20 @@ def test_trace_report_prints_the_kv_walk_live_share(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "kv blocks walked   1992 of 16384" in out
     assert "live share 12.2%" in out
+    assert "window share" not in out
+
+    windowed = Recorder(capacity=8)
+    for blocks, ring in ((9000, 1056), (11000, 1088)):
+        with windowed.span("engine/step") as step:
+            step.set(lanes=30, positions=160_000, kv_blocks=blocks,
+                     kv_table_blocks=34816, kv_window_blocks=ring)
+    windowed.save(str(path))
+    assert mod.kv_cache_summary(
+        mod.load_events(str(path)))["kv_window_blocks"] == 2144
+    assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "a window layer read 2144 of the 20000 blocks" in out
+    assert "window share 10.7%" in out
 
     linear = Recorder(capacity=8)
     with linear.span("engine/step") as step:

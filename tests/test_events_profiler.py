@@ -241,8 +241,10 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     # Counts a step carries: what its dispatch saw, what its pieces held.
     for s in steps:
         assert set(s[5]) == {"lanes", "positions", "kv_blocks",
-                             "kv_table_blocks", "pieces",
-                             "prefill_tokens", "committed", "queued"}
+                             "kv_table_blocks", "kv_window_blocks",
+                             "pieces", "prefill_tokens", "committed",
+                             "queued"}
+        assert s[5]["kv_window_blocks"] == 0     # no window layer here
         assert 0 <= s[5]["lanes"] <= 2
         # A dispatch reads a block or more of every slot, of the 2
         # slots x 2 blocks (cache 32, block 16) their tables have; a

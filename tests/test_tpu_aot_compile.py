@@ -128,6 +128,20 @@ def _paged(heads, kvh, hd, bs, q_len, int8, gather=False):
         (q, pool, pool, table, lengths, scales, scales))
 
 
+def _paged_ring(heads, q_len):
+    """A sliding-window layer's decode attention at Laguna-S-2.1's
+    published sizes: ``heads`` query heads (72, or a full layer's 48)
+    over 8 KV heads of 128, a window of 512 over a ring of 33 blocks of
+    16 rows a lane (the cell's engine's at ``q_len`` 1, a block more
+    than the window's at 3), a context of 17,408 rows."""
+    lanes, kvh, hd, bs, ring = 8, 8, 128, 16, 33
+    pool = ((1 + lanes * ring, bs, kvh * hd), BF16)
+    return (lambda q, k, v, t, n: pk.paged_attention(
+        q, k, v, t, n, cache_len=17408, window=512, use_pallas=True),
+        (((lanes, q_len, heads, hd), BF16), pool, pool,
+         ((lanes, ring), jnp.int32), ((lanes,), jnp.int32)))
+
+
 def _paged_latent(q_len):
     """GLM-4.7-Flash's absorbed decode kernel at its published sizes:
     20 heads over rows of 512 + 64 values stored 640 wide, blocks of
@@ -157,8 +171,10 @@ def _paged_index(q_len):
 
 
 # (heads, kv_heads, head_dim, block_size): llama_350m's layout at the
-# engine's default block size, and qwen25_7b's GQA layout.
-_LAYOUTS = {"h16kv16d64": (16, 16, 64, 16), "h28kv4d128": (28, 4, 128, 32)}
+# engine's default block size, qwen25_7b's GQA layout, and a full layer
+# of Laguna-S-2.1 (6 queries a KV head).
+_LAYOUTS = {"h16kv16d64": (16, 16, 64, 16), "h28kv4d128": (28, 4, 128, 32),
+            "h48kv8d128": (48, 8, 128, 16)}
 
 CASES = {
     "rms_norm-fwd": lambda: _rms(False),
@@ -171,6 +187,9 @@ CASES = {
     "flash-grad": lambda: _flash(True),
 }
 for _q in (1, 3):
+    for _h in (48, 72):
+        CASES[f"paged_ring-h{_h}kv8d128-w512-q{_q}"] = (
+            lambda h=_h, q=_q: _paged_ring(h, q))
     CASES[f"paged_latent-h20r576-q{_q}"] = lambda q=_q: _paged_latent(q)
     CASES[f"paged_index-h64d128-q{_q}"] = lambda q=_q: _paged_index(q)
 for _name, (_h, _kvh, _hd, _bs) in _LAYOUTS.items():

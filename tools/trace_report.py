@@ -144,12 +144,15 @@ def kv_cache_summary(evs: list) -> dict:
     ``ops.pallas_kernels.paged_attention`` or the XLA block-gather
     A/B leg), and the blocks that kernel's walk read over the window's
     dispatches against the whole block table (``engine/step``'s
-    ``kv_blocks`` and ``kv_table_blocks``: the live share).  Empty dict
-    when the window has no paged-KV events (linear cache)."""
+    ``kv_blocks`` and ``kv_table_blocks``: the live share), and, where
+    some layers see a sliding window, the blocks one such layer's walk
+    of its rings read (``kv_window_blocks``: the window layers' share
+    of what the lanes hold).  Empty dict when the window has no
+    paged-KV events (linear cache)."""
     out = {"prefix_hits": 0, "prefix_hit_tokens": 0,
            "evicted_blocks": 0, "refused_admissions": 0,
            "fused_attn_dispatches": 0, "kv_blocks": 0,
-           "kv_table_blocks": 0}
+           "kv_table_blocks": 0, "kv_window_blocks": 0}
     seen = False
     for e in evs:
         name = e.get("name", "")
@@ -163,6 +166,7 @@ def kv_cache_summary(evs: list) -> dict:
             # dispatch, of the slots x blocks-a-lane table it spans.
             out["kv_blocks"] += args.get("kv_blocks", 0)
             out["kv_table_blocks"] += args["kv_table_blocks"]
+            out["kv_window_blocks"] += args.get("kv_window_blocks", 0)
             seen = True
             continue
         if not name.startswith("kv/"):
@@ -829,6 +833,11 @@ def main(argv=None) -> int:
                   f"{kv['kv_table_blocks']} in the slots x blocks "
                   f"table: live share "
                   f"{100.0 * kv['kv_blocks'] / kv['kv_table_blocks']:.1f}%")
+        if kv["kv_window_blocks"] and kv["kv_blocks"]:
+            print(f"  a window layer read {kv['kv_window_blocks']} of the "
+                  f"{kv['kv_blocks']} blocks its lanes hold: window "
+                  f"share "
+                  f"{100.0 * kv['kv_window_blocks'] / kv['kv_blocks']:.1f}%")
 
     spec = spec_depth_summary(evs)
     if spec:
