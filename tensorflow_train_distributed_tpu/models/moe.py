@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from tensorflow_train_distributed_tpu.runtime import compat
 from tensorflow_train_distributed_tpu.models import layers as L
+from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
 from tensorflow_train_distributed_tpu.ops.losses import (
     fold_sample_weight, softmax_cross_entropy,
 )
@@ -468,13 +469,21 @@ def _routed_ffn_rows(flat, top_e, gate_w, num_experts, wi_gate, wi_up,
     ``flat`` [T, D] tokens; ``top_e``/``gate_w`` [T, k] the router's
     expert choices and normalized gates (computed ONCE by the caller —
     under EP they ride into the shard_map rather than being recomputed
-    per expert shard).  Sort token copies by expert, run the SwiGLU as
-    grouped matmuls, unsort and gate-combine.  With
-    ``group_offset``/``psum_axis`` set this is the per-shard body of
-    the expert-parallel formulation: each expert shard computes ONLY
-    its experts' rows (zeros elsewhere) and the psum over the expert
-    axis assembles the full row set — every row is computed by exactly
-    one shard, so the sum is exact, not averaged.
+    per expert shard).  The (token, choice) pairs go to expert order
+    and come back in one pass each: one stable sort carries a pair's
+    number and its gate along with its expert, the tokens' rows are
+    gathered by indices promised in bounds (no fill pass), the SwiGLU
+    runs as grouped matmuls, and ``ops.pallas_kernels.moe_combine``
+    adds each float32 row the third matmul returns, times its gate, to
+    its token's sum as the rows lie — no row is moved back to token
+    order, and of a held share (``group_offset`` without a psum) only
+    the rows of the experts held are read, the others being zero by
+    ``gmm``'s mask.  With ``group_offset``/``psum_axis`` set this is
+    the per-shard body of the expert-parallel formulation: each expert
+    shard computes ONLY its experts' rows (zeros elsewhere) and the
+    psum over the expert axis assembles the full row set — every row
+    is computed by exactly one shard, so the sum is exact, not
+    averaged.
     """
     t, d = flat.shape
     top_k = top_e.shape[-1]
@@ -482,14 +491,21 @@ def _routed_ffn_rows(flat, top_e, gate_w, num_experts, wi_gate, wi_up,
     m = t * top_k
     with jax.named_scope("moe/sort"):
         e_flat = top_e.reshape(-1)                      # [T*k] token-major
-        order = jnp.argsort(e_flat)                     # stable
-        xs = jnp.take(flat, order // top_k, axis=0).astype(dtype)
-        sizes = jnp.bincount(e_flat, length=e_total).astype(jnp.int32)
+        # One stable sort carries each pair's number and its gate to
+        # expert order; a row's token is its pair's.
+        _, order, gates = jax.lax.sort(
+            (e_flat, jnp.arange(m, dtype=jnp.int32),
+             gate_w.reshape(-1).astype(jnp.float32)), num_keys=1)
+        tok = order // top_k
+        xs = flat.at[tok].get(mode="promise_in_bounds").astype(dtype)
+        experts = jnp.arange(e_total, dtype=e_flat.dtype)
+        sizes = jnp.sum(e_flat[None, :] == experts[:, None], axis=1,
+                        dtype=jnp.int32)       # no scatter of T*k scalars
         m_pad = -(-m // 128) * 128                      # kernel row tile
         if m_pad != m:
             # Zero rows appended to the LAST expert's range: zero inputs
-            # produce zero outputs (silu(0)*0 = 0), then sliced off
-            # before the combine — never observable, under EP included
+            # produce zero outputs (silu(0)*0 = 0), and no pair's row
+            # lies among them — never observable, under EP included
             # (the last shard computes them as zeros; psum adds zeros).
             xs = jnp.pad(xs, ((0, m_pad - m), (0, 0)))
             sizes = sizes.at[e_total - 1].add(m_pad - m)
@@ -501,10 +517,16 @@ def _routed_ffn_rows(flat, top_e, gate_w, num_experts, wi_gate, wi_up,
     if psum_axis is not None:
         out = jax.lax.psum(out, psum_axis)
     with jax.named_scope("moe/combine"):
-        inv = jnp.zeros((m,), jnp.int32).at[order].set(
-            jnp.arange(m, dtype=jnp.int32))
-        y = jnp.take(out[:m], inv, axis=0).reshape(t, top_k, d)
-        return jnp.sum(y * gate_w[..., None], axis=1).astype(dtype)
+        # Expert order keeps the rows of the experts held together, and
+        # every other row is zero (``gmm``'s mask) until a psum fills it.
+        span = (0, m)
+        if group_offset is not None and psum_axis is None:
+            held = jax.lax.dynamic_slice_in_dim(
+                sizes, group_offset, wi_gate.shape[0])
+            lo = (jnp.cumsum(sizes) - sizes)[group_offset]
+            span = (lo, lo + jnp.sum(held))
+        return pk.moe_combine(out, tok, gates, jnp.stack(span), t, dtype,
+                              interpret)
 
 
 class _GmmExperts(nn.Module):

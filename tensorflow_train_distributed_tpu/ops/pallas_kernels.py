@@ -1133,3 +1133,159 @@ def fused_cross_entropy(logits, labels, *,
         kernel,
         lambda mesh: (activation_spec(mesh, logits.shape), label_spec(mesh)),
         label_spec)(logits, labels)
+
+
+# ---------------------------------------------------------------------------
+# Routed experts' un-sort and gate-combine (models.moe._routed_ffn_rows)
+# ---------------------------------------------------------------------------
+#
+# The grouped matmuls return one float32 row a (token, choice) pair, in
+# expert order.  Gathering them back to token order and summing is two
+# to three passes over those rows in XLA; the kernel streams the rows
+# once as they lie (only those of the experts held, which expert order
+# keeps together) and adds each, times its gate, to its token's row of
+# a float32 sum that stays in VMEM.  (At the end of the file: a line
+# added above a kernel moves the source lines in its serialized body,
+# and every program that holds it compiles anew.)
+
+
+def moe_combine_reference(out, tok, gates, tokens: int, dtype):
+    """``y[t]`` = the sum over the rows ``j`` with ``tok[j] == t`` of
+    ``gates[j] * out[j]``, in float32, one cast at the end.  ``out``
+    [rows, D] float32 in expert order; ``tok`` (int32) / ``gates``
+    (float32) [pairs] the token and the gate of the pair in each of the
+    first ``pairs`` rows."""
+    pairs = tok.shape[0]
+    y = jnp.zeros((tokens, out.shape[-1]), jnp.float32)
+    return y.at[tok].add(out[:pairs] * gates[:, None],
+                         mode="promise_in_bounds").astype(dtype)
+
+
+#: Rows of ``out`` a grid step of ``moe_combine`` takes in.
+_COMBINE_ROWS = 256
+#: A one-dimensional int32 or float32 operand lies in tiles of this
+#: many scalars, and a block of it in scalar memory is one of them.
+_SMEM_TILE = 1024
+#: Bytes of the float32 sums ``moe_combine`` keeps in fast memory: the
+#: columns are walked in chunks that fit.
+_COMBINE_ACC_BYTES = 16 << 20
+
+
+def _combine_cols(t: int, d: int) -> int:
+    """Columns ``moe_combine`` sums at a time: the widest multiple of
+    128 that divides ``d`` and keeps ``t`` float32 rows inside
+    ``_COMBINE_ACC_BYTES`` (128 where none does)."""
+    fits = [c for c in range(128, d + 1, 128)
+            if d % c == 0 and t * c * 4 <= _COMBINE_ACC_BYTES]
+    return max(fits, default=128 if d % 128 == 0 else d)
+
+
+def _moe_combine_kernel(span_ref, out_ref, tok_ref, gate_ref, o_ref, acc,
+                        *, rows):
+    """Grid (column chunk, row block).  ``span_ref`` [2]: the rows
+    ``[lo, hi)`` of ``out`` that can be other than zero; blocks outside
+    them are neither copied (the index map stays on a live block) nor
+    read.  A live row is multiplied by its pair's gate and added to its
+    token's sum, row by row in expert order."""
+    i = pl.program_id(1)
+    lo, hi = span_ref[0], span_ref[1]
+    block = lo // rows + i
+
+    @pl.when(i == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(block * rows < hi)
+    def _():
+        # The scalars come in XLA's own tiles of ``_SMEM_TILE``.
+        base = jax.lax.rem(block * rows, _SMEM_TILE)
+
+        def add(j, _):
+            at = pl.ds(tok_ref[base + j], 1)
+            acc[at, :] = (acc[at, :]
+                          + gate_ref[base + j] * out_ref[pl.ds(j, 1), :])
+
+        jax.lax.fori_loop(jnp.maximum(lo - block * rows, 0),
+                          jnp.minimum(hi - block * rows, rows), add, None)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def moe_combine(out, tok, gates, span, tokens: int, dtype,
+                interpret=False):
+    """``moe_combine_reference`` as one pass over ``out``: the rows
+    ``span`` = ``[lo, hi)`` are streamed through fast memory once,
+    block by block as they lie, each multiplied by its pair's gate and
+    added in float32 to its token's row of a ``[tokens, columns]`` sum
+    that stays there; ``[tokens, D]`` is written once, in ``dtype``.
+    No row is moved to token order, and no row outside ``span`` is
+    read: the caller promises those are zero (``gmm`` under
+    ``group_offset`` masks the rows of experts not held, and expert
+    order keeps the held ones together).  The backward is the same
+    sum's: a row's gradient is its gate times its token's, a gate's
+    the product of its row with its token's gradient."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = out.shape
+    pairs = tok.shape[0]
+    rows = math.gcd(n, _COMBINE_ROWS)
+    if rows % 8:
+        raise ValueError(f"{n} expert rows are no multiple of 8")
+    cols = _combine_cols(tokens, d)
+    t_pad = -(-tokens // 16) * 16
+    pad = (0, -(-n // _SMEM_TILE) * _SMEM_TILE - pairs)
+    span = jnp.minimum(jnp.asarray(span, jnp.int32), pairs)
+
+    def live(i, span):
+        # The span's i-th block, or its last one where it has no i-th.
+        last = jnp.maximum(span[1] - 1, span[0]) // rows
+        return jnp.minimum(span[0] // rows + i, last)
+
+    def scalars():
+        return pl.BlockSpec(
+            (_SMEM_TILE,),
+            lambda j, i, span: (live(i, span) * rows // _SMEM_TILE,),
+            memory_space=pltpu.SMEM)
+
+    vmem = (t_pad * cols * (4 + 2 * jnp.dtype(dtype).itemsize)
+            + 2 * rows * cols * 4)
+    y = pl.pallas_call(
+        functools.partial(_moe_combine_kernel, rows=rows),
+        name="moe_combine",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(d // cols, n // rows),
+            in_specs=[pl.BlockSpec((rows, cols),
+                                   lambda j, i, span: (live(i, span), j)),
+                      scalars(), scalars()],
+            out_specs=pl.BlockSpec((t_pad, cols),
+                                   lambda j, i, span: (0, j)),
+            scratch_shapes=[pltpu.VMEM((t_pad, cols), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t_pad, d), dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + (8 << 20)),
+        interpret=interpret,
+    )(span, out, jnp.pad(tok, pad), jnp.pad(gates, pad))
+    return y[:tokens]
+
+
+def _moe_combine_fwd(out, tok, gates, span, tokens, dtype, interpret):
+    return (moe_combine(out, tok, gates, span, tokens, dtype, interpret),
+            (out, tok, gates))
+
+
+def _moe_combine_bwd(tokens, dtype, interpret, res, g):
+    out, tok, gates = res
+    pairs = tok.shape[0]
+    theirs = g.astype(jnp.float32).at[tok].get(mode="promise_in_bounds")
+    d_out = jnp.pad(theirs * gates[:, None],
+                    ((0, out.shape[0] - pairs), (0, 0)))
+    d_gates = jnp.sum(out[:pairs] * theirs, axis=-1)
+    return d_out.astype(out.dtype), None, d_gates, None
+
+
+moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)

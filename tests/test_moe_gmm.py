@@ -16,7 +16,10 @@ import dataclasses
 
 import pytest
 
-pytestmark = pytest.mark.slow  # interpret-mode pallas: full-suite tier
+# Interpret-mode pallas through whole blocks and models: full-suite
+# tier.  The routed rows against the per-pair loop (the last test) are
+# small enough for the fast tier.
+slow = pytest.mark.slow
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +47,7 @@ def _apply(cfg, params, x):
         {"params": params}, x, mutable=["aux_loss", "router_stats"])
 
 
+@slow
 def test_same_param_tree(tiny_pair):
     cfg_d, cfg_g, params, x = tiny_pair
     params_g = moe.MoEMlpBlock(cfg_g).init(
@@ -55,6 +59,7 @@ def test_same_param_tree(tiny_pair):
     assert shapes_d == shapes_g
 
 
+@slow
 def test_forward_matches_dense_with_ample_capacity(tiny_pair):
     cfg_d, cfg_g, params, x = tiny_pair
     yd, _ = _apply(cfg_d, params, x)
@@ -63,6 +68,7 @@ def test_forward_matches_dense_with_ample_capacity(tiny_pair):
                                atol=1e-5, rtol=1e-5)
 
 
+@slow
 def test_aux_losses_match_dense(tiny_pair):
     cfg_d, cfg_g, params, x = tiny_pair
     _, sd = _apply(cfg_d, params, x)
@@ -73,6 +79,7 @@ def test_aux_losses_match_dense(tiny_pair):
             rtol=1e-5)
 
 
+@slow
 def test_grads_match_dense(tiny_pair):
     cfg_d, cfg_g, params, x = tiny_pair
 
@@ -87,6 +94,7 @@ def test_grads_match_dense(tiny_pair):
         gd, gg)
 
 
+@slow
 def test_dropless_under_binding_capacity(tiny_pair):
     cfg_d, cfg_g, params, x = tiny_pair
     cfg_bind = dataclasses.replace(cfg_d, capacity_factor=0.5)
@@ -102,6 +110,7 @@ def test_dropless_under_binding_capacity(tiny_pair):
     assert float(jnp.max(jnp.abs(yb - yg))) > 1e-2
 
 
+@slow
 def test_expert_load_sums_to_one(tiny_pair):
     _, cfg_g, params, x = tiny_pair
     _, sg = _apply(cfg_g, params, x)
@@ -110,6 +119,7 @@ def test_expert_load_sums_to_one(tiny_pair):
     assert (load >= 0).all()
 
 
+@slow
 def test_unknown_dispatch_rejected(tiny_pair):
     cfg_d, _, params, x = tiny_pair
     bad = dataclasses.replace(cfg_d, dispatch="scatter")
@@ -117,6 +127,7 @@ def test_unknown_dispatch_rejected(tiny_pair):
         _apply(bad, params, x)
 
 
+@slow
 def test_gmm_rejects_quantized_serving(tiny_pair):
     """int8 serving scales present → loud refusal, not silent garbage
     (the quant interceptor only rewrites nn.Dense call sites, which the
@@ -129,6 +140,7 @@ def test_gmm_rejects_quantized_serving(tiny_pair):
             mutable=["aux_loss", "router_stats"])
 
 
+@slow
 def test_gmm_expert_sharded_matches_unsharded(tiny_pair):
     """Expert-parallel gmm (shard_map: local sort + group_offset gmm +
     one psum) == unsharded gmm on a data×expert mesh — every row is
@@ -166,6 +178,7 @@ def test_gmm_expert_sharded_matches_unsharded(tiny_pair):
         g_sharded, g_unsharded)
 
 
+@slow
 def test_gmm_trains_under_expert_mesh():
     """Full Trainer step: gmm dispatch on a data×expert mesh, loss
     decreases (the dropless EP training path end-to-end)."""
@@ -197,6 +210,7 @@ def test_gmm_trains_under_expert_mesh():
     assert losses[-1] < losses[0], losses
 
 
+@slow
 def test_gmm_rejects_expert_tensor_mesh(tiny_pair):
     """expert×tensor meshes must refuse gmm loudly: the shard_map would
     silently replicate expert kernels over tensor (undoing TP)."""
@@ -217,6 +231,7 @@ def test_gmm_rejects_expert_tensor_mesh(tiny_pair):
                 mutable=["aux_loss", "router_stats"]))(params, x)
 
 
+@slow
 def test_gmm_rejects_indivisible_expert_axis(tiny_pair):
     from tensorflow_train_distributed_tpu.parallel import (
         sharding as sharding_lib,
@@ -237,6 +252,7 @@ def test_gmm_rejects_indivisible_expert_axis(tiny_pair):
                 mutable=["aux_loss", "router_stats"]))(params6, x)
 
 
+@slow
 def test_full_task_trains_with_gmm():
     """One gradient step through MoeLmTask(dispatch='gmm') under remat:
     finite loss, finite grads touching every expert kernel."""
@@ -272,6 +288,7 @@ def test_full_task_trains_with_gmm():
         assert bool((norms > 0).all()), norms
 
 
+@slow
 def test_decode_smoke_with_gmm():
     """The decode path (one-token groups) routes through gmm too."""
     cfg = dataclasses.replace(moe.MOE_PRESETS["moe_tiny"], dispatch="gmm",
@@ -283,3 +300,65 @@ def test_decode_smoke_with_gmm():
                          mutable=["aux_loss", "router_stats"])[0]
     assert logits.shape == (2, 8, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
+
+
+def _per_pair(flat, top_e, gate_w, wi_gate, wi_up, wo, offset):
+    """Every (token, choice) pair through its expert's SwiGLU, one
+    choice at a time, float32; a pair whose expert is not among the
+    kernels held adds nothing."""
+    held = wi_gate.shape[0]
+    y = jnp.zeros(flat.shape, jnp.float32)
+    for c in range(top_e.shape[1]):
+        local = top_e[:, c] - offset
+        here = (local >= 0) & (local < held)
+        e = jnp.clip(local, 0, held - 1)
+        h = (jax.nn.silu(jnp.einsum("td,tdf->tf", flat, wi_gate[e]))
+             * jnp.einsum("td,tdf->tf", flat, wi_up[e]))
+        pair = jnp.einsum("tf,tfd->td", h, wo[e])
+        y = y + jnp.where(here[:, None], pair, 0.0) * gate_w[:, c:c + 1]
+    return y
+
+
+@pytest.mark.parametrize("top_k,share,tokens", [
+    (4, False, 16), (4, True, 16), (8, False, 16), (8, True, 16),
+    (10, False, 16), (10, True, 16), (10, True, 12)],
+    ids=lambda v: str(v))
+def test_routed_rows_equal_a_per_pair_loop(top_k, share, tokens):
+    """The sort to expert order, the grouped matmuls and the un-sort
+    and gate-combine against the plain loop, in value and in the
+    gradient of the tokens, the gates and the three kernels: at every
+    k, with all experts and with a held share behind an offset, and at
+    a token count that is no multiple of 8 (the pairs then pad and the
+    sums' rows do; no value may change)."""
+    experts, d, f = 16, 128, 128
+    held, offset = (6, 5) if share else (experts, 0)
+    keys = jax.random.split(jax.random.PRNGKey(top_k + tokens), 7)
+    flat = jax.random.normal(keys[0], (tokens, d), jnp.float32)
+    _, top_e = jax.lax.top_k(
+        jax.random.normal(keys[1], (tokens, experts)), top_k)
+    gate_w = jax.nn.softmax(jax.random.normal(keys[2], (tokens, top_k)))
+    wi_gate, wi_up, wo = (
+        jax.random.normal(key, shape, jnp.float32) * 0.1
+        for key, shape in zip(keys[3:6], [(held, d, f), (held, d, f),
+                                          (held, f, d)]))
+    cot = jax.random.normal(keys[6], (tokens, d), jnp.float32)
+
+    def routed(flat, gate_w, wi_gate, wi_up, wo):
+        return moe._routed_ffn_rows(
+            flat, top_e, gate_w, experts, wi_gate, wi_up, wo,
+            dtype=jnp.float32, interpret=True,
+            group_offset=offset if share else None)
+
+    def plain(flat, gate_w, wi_gate, wi_up, wo):
+        return _per_pair(flat, top_e, gate_w, wi_gate, wi_up, wo, offset)
+
+    args = (flat, gate_w, wi_gate, wi_up, wo)
+    np.testing.assert_allclose(routed(*args), plain(*args),
+                               rtol=1e-5, atol=1e-5)
+    got, want = (jax.grad(lambda *a: jnp.sum(fn(*a) * cot),
+                          argnums=tuple(range(5)))(*args)
+                 for fn in (routed, plain))
+    for g, w, name in zip(got, want,
+                          ["flat", "gate_w", "wi_gate", "wi_up", "wo"]):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5,
+                                   err_msg=name)

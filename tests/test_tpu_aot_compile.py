@@ -298,6 +298,43 @@ def test_kernel_partitions_over_a_2x2_mesh(case, v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_routed_rows_go_to_expert_order_and_back_in_one_pass_each(v5e):
+    """``_routed_ffn_rows`` at ``laguna-s21-1chip``'s piece shape (1024
+    tokens, 10 choices, width 3072, 64 of 256 experts of width 1024
+    held): the float32 rows the grouped matmuls return are never laid
+    out again by token (no ``reshape`` or ``copy`` to ``[1024, 10,
+    3072]`` or ``[10, 1024, 3072]``: 126 MB read and 201 written when
+    the pairs lay token-major), no ``select`` pass follows the sort's
+    gather (its indices are promised in bounds), and the un-sort and
+    gate-combine is the one kernel named ``moe_combine``."""
+    import re
+
+    from tensorflow_train_distributed_tpu.models import moe
+
+    t, k, d, f, experts, held = 1024, 10, 3072, 1024, 256, 64
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def rows(flat, top_e, gate_w, wi_gate, wi_up, wo):
+        return moe._routed_ffn_rows(
+            flat, top_e, gate_w, experts, wi_gate, wi_up, wo, dtype=BF16,
+            interpret=False, group_offset=0)
+
+    text = jax.jit(rows).lower(*(
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+            ((t, d), BF16), ((t, k), jnp.int32), ((t, k), jnp.float32),
+            ((held, d, f), BF16), ((held, d, f), BF16),
+            ((held, f, d), BF16)))).compile().as_text()
+    made = re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+\[[\d,]*\])\S* ([\w\-]+)\(",
+        text, re.M)
+    assert (f"f32[{t * k},{d}]", "custom-call") in made     # gmm's rows
+    relaid = {f"f32[{t},{k},{d}]", f"f32[{k},{t},{d}]"}
+    assert not [m for m in made
+                if m[0] in relaid and m[1] in ("reshape", "copy")]
+    assert (f"bf16[{t * k},{d}]", "select") not in made
+    assert "moe_combine" in {name.split(".")[0] for name in _kernels(text)}
+
+
 @pytest.fixture(scope="module")
 def decode_program(v5e):
     """The benchmark's decode program (``qwen25-7b-1chip``: 32 lanes,
