@@ -69,6 +69,29 @@ benchmark's per-layer metrics, ``/v1/requests/<id>`` and
 or an attr outside the table.  A step's SELF time is the duration of
 its ``engine/step`` span minus its ``*/wait`` children (the reads that
 block on the device), by containment on the same thread.
+
+Under ``engine/step`` every stage of the host's work is a span of its
+own, so a step's time and the device's idle have owners by name and
+not by Python frame: ``prefill/stage`` (one request's staging: block
+claim, prefix match, padding; ``kv/alloc`` nests inside),
+``prefill/piece`` with its children ``prefill/cache`` (the batch-1
+cache a request's pieces append to: ``fresh``, ``copy`` or ``gather``),
+``prefill/dispatch`` (a piece's puts and its enqueue), ``prefill/wait``
+and ``prefill/insert`` (the inserts and the lane's claim), and
+``decode/dispatch`` with its child ``decode/stage`` (the host prelude
+of a chunk: slot loop, counts, stale lanes' reset, the carry, the
+seeds' put; the program call lies directly under ``decode/dispatch``),
+``decode/wait``, ``decode/harvest``.  ``engine/step`` also counts, on
+the engine's own clock and with no capture running, what the device
+was left without: ``starved_ms`` (milliseconds the device's queue was
+known empty while the engine had work, from the moment a poll of the
+newest program's output found it ready to the next enqueue: a lower
+bound of the device's idle with work pending), ``drains`` (how often
+that happened) and ``away_ms`` (from the previous step's exit to this
+one's entry: what the caller, ``server/driver.py``'s loop, held the
+engine for).  ``ServingEngine.device_starved_s()`` is their running
+sum, served as ``ttd_engine_device_starved_seconds``; it counts under
+the kill switch too.
 """
 
 from __future__ import annotations
@@ -112,16 +135,24 @@ CONTRACT = {
     # the live lanes; where some layers see a sliding window, the
     # blocks one such layer's walk of its rings reaches over all slots
     # (kv_window_blocks: the kernel's rule from the window's first
-    # block on)
+    # block on); starved_ms / drains: the milliseconds, and the times,
+    # the device's queue was known empty while the engine had work
+    # (ServingEngine._launch, _poll_drained); away_ms: from the
+    # previous step's exit to this one's entry (the caller's pass)
     "engine/step": ("lanes positions kv_blocks kv_table_blocks "
                     "kv_window_blocks pieces "
                     "prefill_tokens committed queued experts_hit "
                     "expert_load_cv experts_held routed_here "
-                    "rows_scored rows_selected"),
+                    "rows_scored rows_selected "
+                    "starved_ms drains away_ms"),
     "decode/dispatch": "fused spec_k",
+    # the host prelude of a chunk's dispatch: stale lanes it resets,
+    # slots refilled since the last one
+    "decode/stage": "stale refills",
     "decode/wait": "overlapped",
     "decode/harvest": "overlapped",
-    "prefill/request": "rid tokens",
+    # one request's staging; matched: prompt tokens a prefix supplied
+    "prefill/stage": "rid tokens matched",
     # rows: the cache rows the piece's attention walks (a prefix, in
     # whole tiles: ops.attention.prefix_tiles_walked) of the cache_rows
     # a lane's cache has (a learned selection is a mask inside that
@@ -134,6 +165,12 @@ CONTRACT = {
     # where no layer has a window)
     "prefill/piece": ("rid piece n_pieces tokens rows select_rows "
                       "window_rows cache_rows"),
+    # kind: "fresh" (zeros), "copy" (a preloaded pair's), "gather"
+    # (the radix-matched rows out of the pool)
+    "prefill/cache": "rid kind",
+    # a piece's puts (tokens, scalars) and its enqueue; draft: 1 for
+    # the draft model's piece
+    "prefill/dispatch": "rid piece tokens rows draft",
     "prefill/wait": "rid",
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",

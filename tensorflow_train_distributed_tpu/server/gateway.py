@@ -496,7 +496,7 @@ class ServingGateway:
                 driver_alive_fn=self.driver.alive,
                 replicas_alive_fn=self.pool.alive_count,
                 overlap_ratio_fn=self.pool.overlap_ratio,
-                prefill_stall_fn=self.pool.prefill_stall_s,
+                device_starved_fn=self.pool.device_starved_s,
                 kv_blocks_in_use_fn=self.pool.kv_blocks_in_use,
                 kv_blocks_total_fn=self.pool.kv_blocks_total,
                 kv_prefix_hit_tokens_fn=self.pool.kv_prefix_hit_tokens,
@@ -524,7 +524,7 @@ class ServingGateway:
                 # scrape a truthful constant 0.
                 overlap_ratio_fn=_agg(one, "overlap_ratio",
                                       ratio=True),
-                prefill_stall_fn=_agg(one, "prefill_stall_s"),
+                device_starved_fn=_agg(one, "device_starved_s"),
                 kv_blocks_in_use_fn=_agg(one, "kv_blocks_in_use"),
                 kv_blocks_total_fn=_agg(one, "kv_blocks_total"),
                 kv_prefix_hit_tokens_fn=_agg(one,

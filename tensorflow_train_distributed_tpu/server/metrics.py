@@ -278,7 +278,7 @@ class GatewayMetrics:
                  driver_alive_fn: Optional[Callable[[], bool]] = None,
                  replicas_alive_fn: Optional[Callable[[], int]] = None,
                  overlap_ratio_fn: Optional[Callable[[], float]] = None,
-                 prefill_stall_fn: Optional[Callable[[], float]] = None,
+                 device_starved_fn: Optional[Callable[[], float]] = None,
                  kv_blocks_in_use_fn: Optional[Callable[[], int]] = None,
                  kv_blocks_total_fn: Optional[Callable[[], int]] = None,
                  kv_prefix_hit_tokens_fn: Optional[
@@ -418,15 +418,17 @@ class GatewayMetrics:
             "Host harvest time overlapped with device decode, as a "
             "fraction of total harvest time.",
             fn=overlap_ratio_fn)
-        # Cumulative head-of-line admission time: seconds decode lanes
-        # spent blocked behind a new prompt's first-token read with no
-        # decode chunk in flight.  The engine's step always admits
-        # behind a chunk, so a ServingEngine reports 0.
-        self.engine_prefill_stall = r.gauge(
-            "ttd_engine_prefill_stall_seconds",
-            "Cumulative seconds decode lanes spent stalled behind "
-            "admission prefill with no decode chunk in flight.",
-            fn=prefill_stall_fn)
+        # Cumulative seconds the engine left the device with an empty
+        # queue while it had work (a lane decoding, a task staged, a
+        # queue): from a poll that found the newest program's output
+        # ready to the next enqueue, on the engine's own clock.  A
+        # lower bound of the device's idle with work pending; its rate
+        # is the share of a chip the host loop wastes.
+        self.engine_device_starved = r.gauge(
+            "ttd_engine_device_starved_seconds",
+            "Cumulative seconds the engine left the device with an "
+            "empty queue while it had work pending.",
+            fn=device_starved_fn)
         # Paged-KV cache economics (serving.ServingEngine paged mode;
         # all four scrape 0 for linear-cache engines and test stubs —
         # the truthful constant).  Occupancy pair: admission is keyed

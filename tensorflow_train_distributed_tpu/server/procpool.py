@@ -253,8 +253,8 @@ class RemoteEngine:
     def overlap_ratio(self) -> float:
         return self._g("overlap_ratio")
 
-    def prefill_stall_s(self) -> float:
-        return self._g("prefill_stall_s")
+    def device_starved_s(self) -> float:
+        return self._g("device_starved_s")
 
     def spec_depth(self) -> float:
         return self._g("spec_depth")

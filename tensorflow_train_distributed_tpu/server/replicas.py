@@ -517,8 +517,8 @@ class ReplicaPool:
     def overlap_ratio(self) -> float:
         return self._engine_stat("overlap_ratio", ratio=True)
 
-    def prefill_stall_s(self) -> float:
-        return self._engine_stat("prefill_stall_s")
+    def device_starved_s(self) -> float:
+        return self._engine_stat("device_starved_s")
 
     def kv_blocks_in_use(self) -> float:
         return self._engine_stat("kv_blocks_in_use")

@@ -485,7 +485,7 @@ def _engine_gauges(engine) -> dict:
     for name in ("kv_blocks_total", "kv_blocks_in_use",
                  "kv_prefix_hit_tokens", "kv_evictions",
                  "kv_pool_bytes", "kv_bytes_in_use", "overlap_ratio",
-                 "prefill_stall_s", "spec_depth",
+                 "device_starved_s", "spec_depth",
                  "spec_accepted_tokens", "spec_drafted_tokens",
                  "hbm_autosized_bytes"):
         fn = getattr(engine, name, None)

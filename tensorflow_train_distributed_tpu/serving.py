@@ -275,9 +275,13 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: that fell on them (``routed_here``); where attention chooses its
 #: rows, the rows a step and layer scored and attended over its live
 #: lanes (``rows_scored``, ``rows_selected``): ``_count_sown``.
+#: ``starved_ms`` / ``drains``: the milliseconds, and the times, the
+#: device's queue was known empty while the engine had work
+#: (``_launch``, ``_poll_drained``); ``away_ms``: from the previous
+#: step's exit to this one's entry, the caller's pass between them.
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
                 "kv_window_blocks", "pieces", "prefill_tokens",
-                "committed")
+                "committed", "starved_ms", "drains", "away_ms")
 
 
 def _bucket_len(n: int, buckets) -> int:
@@ -713,14 +717,19 @@ class ServingEngine:
         self.prefill_budget = prefill_budget
         self._staging: dict = {}       # slot -> _PrefillTask (FIFO)
         # installments: budget installments run; staged_requests:
-        # requests staged.  stall_s (seconds a decoding lane waited on
-        # an admission's first token with no decode chunk in flight)
-        # cannot move: a lane starts decoding only inside a step, and
-        # a step that ends with a lane decoding ends with a chunk in
-        # flight.  The key stays for its readers
-        # (``prefill_stall_s``).
-        self.prefill_stats = {"installments": 0, "staged_requests": 0,
-                              "stall_s": 0.0}
+        # requests staged.
+        self.prefill_stats = {"installments": 0, "staged_requests": 0}
+        # The starved-device account (``_launch``, ``_poll_drained``):
+        # an output of the newest program enqueued; when a poll found
+        # it ready with work pending, the clock's reading then (None:
+        # the queue is not known empty); the seconds so charged
+        # (``device_starved_s``); the clock, a seam for tests; when the
+        # last serve_step handed control back.
+        self._newest = None
+        self._drained_at: Optional[float] = None
+        self._starved_s = 0.0
+        self._clock = time.monotonic
+        self._left_at: Optional[float] = None
         # What the running serve_step has done so far: the attrs its
         # ``engine/step`` span is given at exit (runtime/events.py,
         # CONTRACT).
@@ -1558,16 +1567,16 @@ class ServingEngine:
         # local_idx only matters on the piece holding the last real
         # token (the final one).
         local = min(m - 1 - i * piece, piece - 1)
-        return self._prefill_piece(self._variables, cache_1, toks,
-                                   jnp.int32(max(local, 0)),
-                                   jnp.uint32(seed), jnp.int32(rng0))
+        return self._launch(self._prefill_piece, self._variables,
+                            cache_1, toks, jnp.int32(max(local, 0)),
+                            jnp.uint32(seed), jnp.int32(rng0))
 
     def _run_draft_piece(self, d_cache_1, padded, piece: int, i: int):
         """Piece ``i`` of a draft prefill (same piece grid as the
         target's — both caches must hold identical row sets)."""
         toks = jnp.asarray(padded[:, i * piece:(i + 1) * piece])
-        return self._draft_prefill_piece(self._draft_variables,
-                                         d_cache_1, toks)
+        return self._launch(self._draft_prefill_piece,
+                            self._draft_variables, d_cache_1, toks)
 
     def _prefill_tokens(self, work, *, seed: int, cache_1, draft: bool):
         """Append ``work`` to a batch-1 cache in compile-bounded pieces,
@@ -1716,14 +1725,15 @@ class ServingEngine:
             start, end = jnp.int32(matched), jnp.int32(m * bs)
             if self._cache is None:
                 self._cache = self._fresh_cache(self.slots, grid=True)
-            self._cache = self._paged_preload(self._cache, cache_1,
-                                              table_j, start, end)
+            self._cache = self._launch(self._paged_preload, self._cache,
+                                       cache_1, table_j, start, end)
             if self._draft_model is not None:
                 if self._d_cache is None:
                     self._d_cache = self._fresh_cache(
                         self.slots, draft=True, grid=True)
-                self._d_cache = self._paged_preload(
-                    self._d_cache, d_cache_1, table_j, start, end)
+                self._d_cache = self._launch(
+                    self._paged_preload, self._d_cache, d_cache_1,
+                    table_j, start, end)
             self._radix.insert(tokens[:m * bs], lambda j: row[j])
             # The tree took one reference per NEW node; release the
             # allocation's own (a node already present keeps its
@@ -1801,11 +1811,11 @@ class ServingEngine:
         hold refs on (or own) the table's blocks and run on the
         engine-owning thread."""
         span = jnp.int32(n)
-        pairs = [(False, self._gather_prefix(
-            self._cache, table_j, False, span))]
+        pairs = [(False, self._launch(
+            self._gather_prefix, self._cache, table_j, False, span))]
         if self._draft_model is not None:
-            pairs.append((True, self._gather_prefix(
-                self._d_cache, table_j, True, span)))
+            pairs.append((True, self._launch(
+                self._gather_prefix, self._d_cache, table_j, True, span)))
         leaves, chunks = [], []
         for draft, cache_1 in pairs:
             flat = jax.tree_util.tree_flatten_with_path(cache_1)[0]
@@ -1902,8 +1912,8 @@ class ServingEngine:
                 fill, self._fresh_cache(1, draft=draft))
 
         with self._ctx(), events.span("kv/install", tokens=n):
-            cache_1 = build_one(False)
-            d_cache_1 = (build_one(True)
+            cache_1 = self._launch(build_one, False)
+            d_cache_1 = (self._launch(build_one, True)
                          if self._draft_model is not None else None)
             self._seed_radix_from_cache(tokens, cache_1, d_cache_1)
         matched, _ = self._radix.match(tokens, allow_full=True,
@@ -2174,9 +2184,10 @@ class ServingEngine:
         for s in self._stale_slots:
             mask[s] = True
         jm = jnp.asarray(mask)
-        self._cache = self._reset_lanes(self._cache, jm)
+        self._cache = self._launch(self._reset_lanes, self._cache, jm)
         if self._d_cache is not None:
-            self._d_cache = self._reset_lanes(self._d_cache, jm)
+            self._d_cache = self._launch(self._reset_lanes,
+                                         self._d_cache, jm)
         self._stale_slots.clear()
 
     def _admission_match(self, kv, prompt):
@@ -2210,20 +2221,31 @@ class ServingEngine:
         the same batch-1 layout, which is what the @memory_budget
         projection charges (the nested ``_fresh_cache`` call defers to
         this outermost charge — the sanitizer's re-entrancy rule)."""
-        if pre_pair is not None:
-            return jax.tree.map(jnp.copy, pre_pair[1 if draft else 0])
-        if not self.paged or kv is None or kv.matched == 0:
-            return self._fresh_cache(1, draft=draft)
+        kind = self._admission_kind(pre_pair, kv)
+        if kind == "copy":
+            return self._launch(jax.tree.map, jnp.copy,
+                                pre_pair[1 if draft else 0])
+        if kind == "fresh":
+            return self._launch(self._fresh_cache, 1, draft=draft)
         cache = self._d_cache if draft else self._cache
         if cache is None:          # defensive: matched blocks imply a
-            cache = self._fresh_cache(self.slots, draft=draft,
-                                      grid=True)
+            cache = self._launch(self._fresh_cache, self.slots,
+                                 draft=draft, grid=True)
             if draft:              # built grid, so keep it
                 self._d_cache = cache
             else:
                 self._cache = cache
-        return self._gather_prefix(cache, table_j, draft,
-                                   jnp.int32(kv.matched))
+        return self._launch(self._gather_prefix, cache, table_j, draft,
+                            jnp.int32(kv.matched))
+
+    def _admission_kind(self, pre_pair, kv) -> str:
+        """Which of ``_admission_cache_1``'s three a request gets (the
+        ``prefill/cache`` span's ``kind``)."""
+        if pre_pair is not None:
+            return "copy"
+        if not self.paged or kv is None or kv.matched == 0:
+            return "fresh"
+        return "gather"
 
     def kv_blocks_total(self) -> int:
         """Allocatable physical blocks in the paged pool (0 when the
@@ -2417,42 +2439,61 @@ class ServingEngine:
                 if max_new == 0:
                     self._outputs[rid] = list(prompt)
                     continue
-                kv = table_j = None
-                if self.paged:
-                    kv = self._kv_claim(rid, prompt, max_new)
-                    if kv is None:
-                        # No blocks: refuse the claim and stop staging
-                        # entirely (FIFO — nothing behind may jump the
-                        # head; blocks free as lanes retire).
-                        self._queue.appendleft(
-                            (rid, prompt, max_new, seed, resume))
-                        return
-                    table_j = self._kv_table(kv)
-                    if self._ring_blocks:
-                        # The slot's own ring in each window layer:
-                        # claimed with the slot, nothing to refuse.
-                        events.instant("kv/alloc", rid=rid,
-                                       blocks=self._ring_blocks, shared=0,
-                                       pool="window")
-                    pre_len, pre_pair = self._admission_match(kv, prompt)
-                else:
-                    pre_len, pre_pair = self._match_prefix(prompt,
-                                                           touch=True)
-                work = prompt[pre_len:]
-                self._note_moe_prefill_len(len(prompt))
-                m = len(work)
-                piece, n_pieces = self._pieces_for(m)
-                padded = np.zeros((1, piece * n_pieces), np.int32)
-                padded[0, :m] = work
-                self._staging[slot] = _PrefillTask(
-                    request_id=rid, prompt=list(prompt),
-                    max_new=max_new, seed=seed, work=work,
-                    padded=padded, piece=piece, n_pieces=n_pieces,
-                    resume=resume, pre_pair=pre_pair, kv=kv,
-                    table=table_j)
-                with self._stats_lock:
-                    self.prefill_stats["staged_requests"] += 1
+                # A block-starved queue head comes back every step: its
+                # retries record no span (``_kv_claim``'s rule).
+                with (contextlib.nullcontext()
+                      if rid == self._kv_refused_rid
+                      else events.span("prefill/stage", rid=rid,
+                                       tokens=len(prompt))) as stage:
+                    staged = self._stage_request(
+                        slot, rid, prompt, max_new, seed, resume)
+                    if stage is not None:
+                        stage.set(matched=staged or 0)
+                self._poll_drained()
+                if staged is None:
+                    return
                 break
+
+    def _stage_request(self, slot: int, rid: int, prompt, max_new: int,
+                       seed: int, resume: int):
+        """Stage one request into ``slot`` (the ``prefill/stage`` span's
+        body): claim its blocks, match a prefix, pad what is left into
+        pieces.  Returns the prompt tokens a prefix supplied, or None
+        when the pool has no blocks for it: the request goes back to
+        the queue's head and staging stops (FIFO — nothing behind may
+        jump the head; blocks free as lanes retire)."""
+        kv = table_j = None
+        if self.paged:
+            kv = self._kv_claim(rid, prompt, max_new)
+            if kv is None:
+                self._queue.appendleft(
+                    (rid, prompt, max_new, seed, resume))
+                return None
+            table_j = self._kv_table(kv)
+            if self._ring_blocks:
+                # The slot's own ring in each window layer: claimed
+                # with the slot, nothing to refuse.
+                events.instant("kv/alloc", rid=rid,
+                               blocks=self._ring_blocks, shared=0,
+                               pool="window")
+            pre_len, pre_pair = self._admission_match(kv, prompt)
+        else:
+            pre_len, pre_pair = self._match_prefix(prompt, touch=True)
+        work = prompt[pre_len:]
+        self._note_moe_prefill_len(len(prompt))
+        m = len(work)
+        piece, n_pieces = self._pieces_for(m)
+        padded = np.zeros((1, piece * n_pieces), np.int32)
+        padded[0, :m] = work
+        self._staging[slot] = _PrefillTask(
+            request_id=rid, prompt=list(prompt),
+            max_new=max_new, seed=seed, work=work,
+            padded=padded, piece=piece, n_pieces=n_pieces,
+            resume=resume, pre_pair=pre_pair, kv=kv,
+            table=table_j)
+        with self._stats_lock:
+            self.prefill_stats["staged_requests"] += 1
+        return pre_len
 
     def _finalize_prefill(self, slot: int, task: _PrefillTask) -> None:
         """Both caches complete: insert into the slot grid and flip the
@@ -2463,31 +2504,11 @@ class ServingEngine:
                            tokens=list(task.prompt) + [first],
                            last_token=first, seed=task.seed,
                            count=task.resume + 1)
-        if self._cache is None:
-            self._cache = self._fresh_cache(self.slots, grid=True)
-        n = len(task.prompt)
-        if self.paged:
-            self._cache = self._paged_insert(
-                self._cache, task.cache_1, jnp.int32(slot), task.table,
-                jnp.int32(task.kv.matched), jnp.int32(n))
-        else:
-            self._cache = self._insert(self._cache, task.cache_1,
-                                       jnp.int32(slot), jnp.int32(n))
-        if self._draft_model is not None:
-            if self._d_cache is None:
-                self._d_cache = self._fresh_cache(self.slots, draft=True,
-                                                 grid=True)
-            if self.paged:
-                self._d_cache = self._paged_insert(
-                    self._d_cache, task.d_cache_1, jnp.int32(slot),
-                    task.table, jnp.int32(task.kv.matched), jnp.int32(n))
-            else:
-                self._d_cache = self._insert(self._d_cache,
-                                             task.d_cache_1,
-                                             jnp.int32(slot),
-                                             jnp.int32(n))
-        if task.kv is not None:
-            self._lane_claim(slot, task.kv, task.prompt)
+        with events.span("prefill/insert", rid=task.request_id):
+            self._insert_lane(slot, task)
+            if task.kv is not None:
+                self._lane_claim(slot, task.kv, task.prompt)
+        self._poll_drained()
         # Staging is cleared BEFORE the slot state is set: the gateway's
         # metrics thread reads active_slots() (= decoding + staged)
         # concurrently, and this order keeps a torn read at or below
@@ -2497,6 +2518,27 @@ class ServingEngine:
         self._slot_states[slot] = state
         self._refills.add(slot)        # next dispatch splices host carry
         events.instant("slot/insert", rid=task.request_id, slot=slot)
+
+    def _insert_lane(self, slot: int, task: _PrefillTask) -> None:
+        """A finished prefill's batch-1 cache(s) into lane ``slot`` of
+        the slot grid (target, then draft)."""
+        n = len(task.prompt)
+        grids = [("_cache", task.cache_1, False)]
+        if self._draft_model is not None:
+            grids.append(("_d_cache", task.d_cache_1, True))
+        for attr, cache_1, draft in grids:
+            grid = getattr(self, attr)
+            if grid is None:
+                grid = self._launch(self._fresh_cache, self.slots,
+                                    draft=draft, grid=True)
+            if self.paged:
+                grid = self._launch(
+                    self._paged_insert, grid, cache_1, jnp.int32(slot),
+                    task.table, jnp.int32(task.kv.matched), jnp.int32(n))
+            else:
+                grid = self._launch(self._insert, grid, cache_1,
+                                    jnp.int32(slot), jnp.int32(n))
+            setattr(self, attr, grid)
 
     def _advance_piece(self, slot: int, task: _PrefillTask) -> int:
         """Run ONE installment of ``task`` — the next target (then
@@ -2537,13 +2579,31 @@ class ServingEngine:
                 n_pieces=task.n_pieces, tokens=real, rows=rows,
                 select_rows=select_rows, window_rows=window_rows,
                 cache_rows=self.cache_len):
-            if task.cursor < task.n_pieces:
-                if task.cache_1 is None:
-                    task.cache_1 = self._admission_cache_1(
-                        task.pre_pair, task.kv, task.table, draft=False)
-                task.cache_1, task.first = self._run_target_piece(
-                    task.cache_1, task.padded, task.piece, task.cursor,
-                    len(task.work), task.seed, task.resume)
+            if (task.d_cache_1 if draft else task.cache_1) is None:
+                with events.span("prefill/cache", rid=task.request_id,
+                                 kind=self._admission_kind(task.pre_pair,
+                                                           task.kv)):
+                    cache_1 = self._admission_cache_1(
+                        task.pre_pair, task.kv, task.table, draft=draft)
+                if draft:
+                    task.d_cache_1 = cache_1
+                else:
+                    task.cache_1 = cache_1
+                self._poll_drained()
+            with events.span("prefill/dispatch", rid=task.request_id,
+                             piece=task.cursor + task.d_cursor,
+                             tokens=real, rows=rows, draft=int(draft)):
+                if draft:
+                    task.d_cache_1 = self._run_draft_piece(
+                        task.d_cache_1, task.padded, task.piece,
+                        task.d_cursor)
+                else:
+                    task.cache_1, task.first = self._run_target_piece(
+                        task.cache_1, task.padded, task.piece,
+                        task.cursor, len(task.work), task.seed,
+                        task.resume)
+            self._poll_drained()
+            if not draft:
                 task.cursor += 1
                 if task.cursor == task.n_pieces:
                     # The host copy of the first token: the read blocks
@@ -2552,6 +2612,7 @@ class ServingEngine:
                     with events.span("prefill/wait",
                                      rid=task.request_id):
                         first = int(task.first)
+                    self._poll_drained()
                     self._step_counts["committed"] += 1
                     task.first_host = first
                     if (task.max_new == 1
@@ -2569,12 +2630,7 @@ class ServingEngine:
                     elif self._draft_model is None:
                         self._finalize_prefill(slot, task)
                 return task.piece
-            # Target done, request unresolved: draft pieces.
-            if task.d_cache_1 is None:
-                task.d_cache_1 = self._admission_cache_1(
-                    task.pre_pair, task.kv, task.table, draft=True)
-            task.d_cache_1 = self._run_draft_piece(
-                task.d_cache_1, task.padded, task.piece, task.d_cursor)
+            # Target done, request unresolved: that was a draft piece.
             task.d_cursor += 1
             if task.d_cursor == task.n_pieces:
                 self._finalize_prefill(slot, task)
@@ -2606,15 +2662,69 @@ class ServingEngine:
                              or spent >= self.prefill_budget):
                 break
 
+    # -- the starved-device account -----------------------------------------
+
+    def _launch(self, program, *args, **kwargs):
+        """THE enqueue of a device program: every site that puts a
+        program on the device's queue (chunk, piece, insert, reset,
+        gather, a fresh or copied cache, the carry's splice) calls it
+        through here.  Before the enqueue, a queue known empty
+        (``_drained_at``) is charged to ``starved_ms`` up to now: the
+        stream runs in order, so from the moment its newest output was
+        seen ready to this enqueue the device had nothing of this
+        engine's to do.  The handle on the previous program is dropped
+        first, because this one may be donated its buffers; an output
+        of this one takes its place."""
+        if self._drained_at is not None:
+            dt = self._clock() - self._drained_at
+            self._drained_at = None
+            self._step_counts["starved_ms"] += 1e3 * dt
+            with self._stats_lock:
+                self._starved_s += dt
+        self._newest = None
+        out = program(*args, **kwargs)
+        self._newest = self._handle_of(out)
+        return out
+
+    @staticmethod
+    def _handle_of(out):
+        """The output of a program that stands for it in
+        ``_poll_drained``: its last leaf (a chunk's counts, a piece's
+        first token; a cache leaf where the program returns a cache
+        alone)."""
+        return jax.tree.leaves(out)[-1]
+
+    def _has_work(self) -> bool:
+        return bool(self._queue or self._staging) or any(
+            s is not None for s in self._slot_states)
+
+    def _poll_drained(self) -> None:
+        """At a stage's boundary and at the return of a ``*/wait``: if
+        the newest program's output is ready (``is_ready()`` asks, it
+        does not wait) the device's queue is empty; with work pending
+        that moment is kept until the next ``_launch`` charges it.  A
+        handle is polled only before the enqueue that may consume it
+        (``_launch`` drops it first)."""
+        handle = self._newest
+        if (handle is not None and self._drained_at is None
+                and handle.is_ready() and self._has_work()):
+            self._drained_at = self._clock()
+            self._step_counts["drains"] += 1
+
     @thread_role("handler", "driver")
-    def prefill_stall_s(self) -> float:
-        """Cumulative seconds decode lanes spent blocked behind
-        admission prefill with no decode chunk in flight: 0.0, since
-        admission always runs behind one (``prefill_stats``).  The
-        gateway exposes it as ``ttd_engine_prefill_stall_seconds`` —
-        scraped from handler threads, so the read locks."""
+    def device_starved_s(self) -> float:
+        """Cumulative seconds the engine left the device with an empty
+        queue while it had work (a lane decoding, a task staged, a
+        queue): each from the poll that found the newest program's
+        output ready to the next enqueue, so a lower bound of the
+        device's idle with work pending, on the engine's own clock and
+        with no capture running (``engine/step``'s ``starved_ms`` is
+        the same a step).  The gateway exposes it as
+        ``ttd_engine_device_starved_seconds``; it counts under
+        ``TTD_NO_TRACE=1`` too.  Scraped from handler threads, so the
+        read locks."""
         with self._stats_lock:
-            return self.prefill_stats["stall_s"]
+            return self._starved_s
 
     def _consume(self, state, tokens) -> None:
         """Append generated tokens to a slot's request, enforcing the
@@ -2749,9 +2859,13 @@ class ServingEngine:
                 mask[slot] = True
                 tok_h[slot] = state.last_token
                 cnt_h[slot] = state.count
-            jmask = jnp.asarray(mask)
-            tok = jnp.where(jmask, jnp.asarray(tok_h), tok)
-            counts = jnp.where(jmask, jnp.asarray(cnt_h), counts)
+            jmask, jtok, jcnt = (jnp.asarray(mask), jnp.asarray(tok_h),
+                                 jnp.asarray(cnt_h))
+            # The puts took their time, and the lanes' reset ahead of
+            # them is short: ask again before the splice is enqueued.
+            self._poll_drained()
+            tok = self._launch(jnp.where, jmask, jtok, tok)
+            counts = self._launch(jnp.where, jmask, jcnt, counts)
             self._refills.clear()
         return tok, counts
 
@@ -2817,31 +2931,42 @@ class ServingEngine:
         chunk — the successor simply queues behind it.  Captures the
         dispatch-time slot->request map the harvest's trim guard
         needs."""
-        seeds = np.zeros((self.slots,), np.uint32)
-        rids: list = [None] * self.slots
-        held = []
-        for slot, state in enumerate(self._slot_states):
-            if state is not None:
-                seeds[slot] = state.seed
-                rids[slot] = state.request_id
-                held.append(len(state.tokens))
-        # Depth for THIS round: the controller's pick (adaptive) or the
-        # fixed k.  Host ints end to end — read before the dispatch
-        # window opens (the controller is _stats_lock-guarded; the
-        # window must stay conversion- and contention-free).
-        k = self._spec_depth()
-        self._count_dispatch(held, k)
         with self._ctx(), events.span(
-                "decode/dispatch", fused=self._fused_tag, spec_k=k):
-            # Retired/cancelled lanes' tables must point at scratch
-            # BEFORE this chunk: their freed blocks may already be
-            # reallocated, and this chunk decodes them as garbage.
-            self._flush_stale_lanes()
-            tok, counts = self._carry_arrays()
-            jseeds = jnp.asarray(seeds)
+                "decode/dispatch", fused=self._fused_tag) as dispatch:
+            # The host's prelude is a span of its own; the program call
+            # below lies directly under ``decode/dispatch``.
+            with events.span("decode/stage",
+                             stale=len(self._stale_slots),
+                             refills=len(self._refills)):
+                seeds = np.zeros((self.slots,), np.uint32)
+                rids: list = [None] * self.slots
+                held = []
+                for slot, state in enumerate(self._slot_states):
+                    if state is not None:
+                        seeds[slot] = state.seed
+                        rids[slot] = state.request_id
+                        held.append(len(state.tokens))
+                # Depth for THIS round: the controller's pick (adaptive)
+                # or the fixed k.  Host ints end to end — read before
+                # the first enqueue (the controller is
+                # _stats_lock-guarded; the enqueues must stay
+                # conversion- and contention-free).
+                k = self._spec_depth()
+                self._count_dispatch(held, k)
+                # Retired/cancelled lanes' tables must point at scratch
+                # BEFORE this chunk: their freed blocks may already be
+                # reallocated, and this chunk decodes them as garbage.
+                self._poll_drained()
+                self._flush_stale_lanes()
+                tok, counts = self._carry_arrays()
+                self._poll_drained()
+                jseeds = jnp.asarray(seeds)
+            dispatch.set(spec_k=k)
+            self._poll_drained()
             if self._draft_model is not None:
                 (self._cache, self._d_cache, emit, emitted, next_tok,
-                 acc, counts_next) = self._spec_round(
+                 acc, counts_next) = self._launch(
+                    self._spec_round,
                     self._variables, self._draft_variables, self._cache,
                     self._d_cache, tok, jseeds, counts, k)
                 # Continuing slots consumed exactly ``emitted`` tokens,
@@ -2854,11 +2979,13 @@ class ServingEngine:
                                   "next_tok": next_tok, "acc": acc}
             else:
                 (self._cache, toks, last, counts_next,
-                 sown) = self._decode_chunk(
+                 sown) = self._launch(
+                    self._decode_chunk,
                     self._variables, self._cache, tok, jseeds, counts)
                 self._carry = (last, counts_next)
                 self._inflight = {"spec": False, "rids": rids,
                                   "toks": toks, "sown": sown}
+        self._poll_drained()
         with self._stats_lock:
             self.overlap_stats["chunks"] += 1
 
@@ -2908,6 +3035,7 @@ class ServingEngine:
             else:
                 toks = np.asarray(inf["toks"])
                 sown = jax.tree.map(np.asarray, inf["sown"])
+        self._poll_drained()
         t0 = time.perf_counter()
         with events.span("decode/harvest", overlapped=overlapped):
             if inf["spec"]:
@@ -2960,11 +3088,16 @@ class ServingEngine:
         cycle's trim guard.
 
         The whole step is one ``engine/step`` span, the parent of the
-        ``decode/*`` and ``prefill/*`` spans recorded inside it; at exit
-        it is given what the step did (``_STEP_COUNTS``) and the queue's
-        depth."""
+        ``decode/*`` and ``prefill/*`` spans recorded inside it (every
+        stage of the host's work is one); at exit it is given what the
+        step did (``_STEP_COUNTS``, the starved-device account among
+        them: ``_launch``, ``_poll_drained``) and the queue's depth."""
         with events.span("engine/step") as step:
             self._step_counts = dict.fromkeys(_STEP_COUNTS, 0)
+            if self._left_at is not None:
+                self._step_counts["away_ms"] = 1e3 * (
+                    self._clock() - self._left_at)
+            self._poll_drained()
             prev, self._inflight = self._inflight, None
             # DECODE PRIORITY: the successor chunk for occupied lanes
             # goes onto the device queue before any admission work, so
@@ -2992,7 +3125,14 @@ class ServingEngine:
                 # harvest overlaps.
                 self._dispatch_chunk()
             out, self._outputs = self._outputs, {}
+            self._poll_drained()
+            if self._drained_at is not None and not self._has_work():
+                # The work went without an enqueue (its last request
+                # retired or was cancelled): idle from here on has no
+                # request in the engine and is not charged.
+                self._drained_at = None
             step.set(queued=len(self._queue), **self._step_counts)
+            self._left_at = self._clock()
         return out
 
     @thread_role("main", "driver")

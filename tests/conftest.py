@@ -159,8 +159,35 @@ _LAST_IN_ITS_LISTS = ("test_benchmark_deepseek_v32.py::"
                       "test_new_cells_traffic_and_metrics_are_found_by_name")
 
 
+# Two tests of the benchmark's own files assert the EXACT set of
+# per-layer metrics read in a cell that was there before them
+# (``test_benchmark_glm.py::test_new_cells_traffic_and_metrics_are_
+# found_by_name``: ``ctx-decode``'s set; ``test_benchmark_laguna.py::
+# test_the_earlier_share_cell_reads_as_before_a_later_cell_was_
+# appended``: how many lists ``mixed-queue`` was appended to).  A PR
+# that appends per-layer metrics which read in those cells (the stage
+# spans' and starved-device readers, ``benchmark/harness/
+# step_stages.py``) may edit neither file.  ``tests/benchmark/
+# test_benchmark_step_stages.py::test_the_cells_read_as_before_the_
+# stage_metrics_were_appended`` runs both functions, every assertion
+# of them, on the manifest with the appended entries taken off.  The
+# same stop-gap as above, strict for the same reason: the `benchmark`
+# PR makes those two assertions ``<=`` / ``>=`` and deletes this.
+_EXACT_PER_LAYER_SETS = (
+    "test_benchmark_glm.py::"
+    "test_new_cells_traffic_and_metrics_are_found_by_name",
+    "test_benchmark_laguna.py::test_the_earlier_share_cell_reads_as_"
+    "before_a_later_cell_was_appended")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_EXACT_PER_LAYER_SETS):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts the exact set of per-layer metrics of "
+                       "an earlier cell; run whole on the manifest as "
+                       "it was by test_the_cells_read_as_before_the_"
+                       "stage_metrics_were_appended", strict=True))
         if item.nodeid.endswith(_LLAMA_ONLY):
             item.add_marker(pytest.mark.xfail(
                 reason="hard-wired to the llama family's builder; "

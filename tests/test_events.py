@@ -296,6 +296,58 @@ def test_trace_report_renders_tables_and_waterfall(tmp_path, capsys):
     assert "class=crash" in out            # journal overlay
 
 
+def test_trace_report_prints_a_step_by_stage_and_what_starved(
+        tmp_path, capsys):
+    """From a ring export: a step's time by the innermost span open
+    (self time), what no span covers, and the starved / away seconds a
+    step from ``engine/step``'s own counters."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(os.path.dirname(__file__),
+                                     "..", "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+
+    rec = Recorder(capacity=64)
+    # 100 ms: a dispatch of 30 with its prelude of 20 inside; a piece
+    # of 50 holding a dispatch of 10 and a wait of 30; 20 under no span.
+    for name, off, dur in (("decode/stage", 0.0, 0.02),
+                           ("decode/dispatch", 0.0, 0.03),
+                           ("prefill/dispatch", 0.035, 0.01),
+                           ("prefill/wait", 0.05, 0.03),
+                           ("prefill/piece", 0.03, 0.05)):
+        rec.record_at(name, "X", 10.0 + off, dur)
+    rec.record_at("engine/step", "X", 10.0, 0.1,
+                  dict(starved_ms=4.0, drains=1, away_ms=0.0))
+    rec.record_at("engine/step", "X", 10.15, 0.05,
+                  dict(starved_ms=8.0, drains=2, away_ms=50.0))
+    path = tmp_path / "trace.json"
+    rec.save(str(path))
+    got = mod.step_stages(mod.load_events(str(path)))
+    rows = {name: (mean, seen) for name, mean, _, _, seen in got["rows"]}
+    assert rows == pytest.approx({
+        "decode/stage": (10.0, 1), "decode/dispatch": (5.0, 1),
+        "prefill/piece": (5.0, 1), "prefill/dispatch": (5.0, 1),
+        "prefill/wait": (15.0, 1), "(no span)": (35.0, 2)})
+    assert (got["starved_ms"], got["drains"], got["away_ms"]) == (
+        12.0, 3, 50.0)
+    assert got["span_ms"] == pytest.approx(200.0)
+    assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "== engine step by stage (2 steps" in out
+    assert "device starved     0.012 s of 0.200 s" in out
+    assert "25.000 ms between two steps" in out
+    # A trace from before the counters prints the table alone.
+    old = Recorder(capacity=8)
+    old.record_at("engine/step", "X", 1.0, 0.1, dict(lanes=1))
+    old.save(str(path))
+    assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "engine step by stage" in out and "device starved" not in out
+
+
 def test_trace_report_counts_fused_dispatches(tmp_path):
     """The paged-KV summary reports how many decode dispatches ran the
     fused paged-attention kernel (the ``decode/dispatch`` span's

@@ -167,9 +167,32 @@ def test_spans_between_windows_and_reports_laps():
     ("engine/step", True), ("prefill/wait", True),
     ("compile/ServingEngine._decode_chunk", True),
     ("memory/kv_pool", True), ("engine/stepper", False),
-    ("bench/window", False), ("$serving.py:2835 serve_step", False)])
+    ("bench/window", False), ("$serving.py:2835 serve_step", False),
+    # the stages of a step's host work are spans of their own; the
+    # name nothing ever recorded is gone
+    ("prefill/stage", True), ("prefill/cache", True),
+    ("prefill/dispatch", True), ("prefill/insert", True),
+    ("decode/stage", True), ("prefill/request", False)])
 def test_contract_membership(name, known):
     assert events.in_contract(name) is known
+
+
+def test_a_step_counts_what_the_device_was_left_without():
+    assert {"starved_ms", "drains", "away_ms"} <= events.contract_attrs(
+        "engine/step")
+
+
+class _Handle:
+    """Stands for an output of the newest program in the engine's
+    starved-device account: ``is_ready`` as the test sets it."""
+
+    def __init__(self, ready=False):
+        self.ready = ready
+        self.polls = 0
+
+    def is_ready(self):
+        self.polls += 1
+        return self.ready
 
 
 # ── the engine keeps the contract ──────────────────────────────────────
@@ -243,7 +266,9 @@ def test_engine_spans_keep_the_contract(tiny, variant):
         assert set(s[5]) == {"lanes", "positions", "kv_blocks",
                              "kv_table_blocks", "kv_window_blocks",
                              "pieces", "prefill_tokens", "committed",
-                             "queued"}
+                             "queued", "starved_ms", "drains",
+                             "away_ms"}
+        assert s[5]["starved_ms"] >= 0 and s[5]["away_ms"] >= 0
         assert s[5]["kv_window_blocks"] == 0     # no window layer here
         assert 0 <= s[5]["lanes"] <= 2
         # A dispatch reads a block or more of every slot, of the 2
@@ -281,6 +306,56 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     assert len(waits) == len(
         [e for e in evs if e[0] == "decode/harvest"]) > 0
     assert True in waits and False in waits
+    # The steps' starved milliseconds are the engine's running sum (the
+    # gauge), and the first step was not away from anybody.
+    assert sum(s[5]["starved_ms"] for s in steps) == pytest.approx(
+        1e3 * eng.device_starved_s())
+    # (a queue found empty in one step may be charged in the next)
+    assert sum(s[5]["drains"] for s in steps) >= len(
+        [s for s in steps if s[5]["starved_ms"]])
+    assert steps[0][5]["away_ms"] == 0
+    assert all(s[5]["away_ms"] > 0 for s in steps[1:])
+
+    # The stages of a step's host work, each a span under its parent.
+    def named(name):
+        return [e for e in evs if e[0] == name]
+
+    def inside(child, parents):
+        return any(p[4] == child[4] and p[2] <= child[2]
+                   and child[2] + child[3] <= p[2] + p[3]
+                   for p in parents)
+
+    staged = named("prefill/stage")
+    assert [(e[5]["rid"], e[5]["tokens"], e[5]["matched"])
+            for e in staged] == [(i, len(p), 0)
+                                 for i, (p, _) in zip(ids, reqs)]
+    allocs = named("kv/alloc")
+    assert len(allocs) == len(reqs) and all(
+        inside(a, staged) for a in allocs)
+    # A batch-1 cache a request and model, a dispatch a piece, an
+    # insert a request that reached a lane (the last resolves at its
+    # first token): all under a piece.
+    drafted = 3 if variant == "self-draft" else 0
+    caches = named("prefill/cache")
+    assert len(caches) == len(reqs) + drafted
+    assert {c[5]["kind"] for c in caches} == {"fresh"}
+    launched = named("prefill/dispatch")
+    assert [(d[5]["rid"], d[5]["piece"], d[5]["tokens"], d[5]["rows"])
+            for d in launched] == [
+        (p[5]["rid"], p[5]["piece"], p[5]["tokens"], p[5]["rows"])
+        for p in pieces]
+    assert sum(d[5]["draft"] for d in launched) == (
+        5 if variant == "self-draft" else 0)
+    inserts = named("prefill/insert")
+    assert sorted(e[5]["rid"] for e in inserts) == ids[:3]
+    for child in caches + launched + inserts:
+        assert inside(child, pieces), child
+    # A chunk's host prelude lies in its dispatch span.
+    stages = named("decode/stage")
+    assert len(stages) == len(dispatches)
+    assert all(inside(st, dispatches) for st in stages)
+    assert all(set(st[5]) == {"stale", "refills"} for st in stages)
+    assert sum(st[5]["refills"] for st in stages) == 3
 
 
 @pytest.mark.parametrize("tile, int8, want", [
@@ -331,7 +406,9 @@ def test_harvest_seconds_split_by_the_wait_span_and_outlive_the_kill_switch(
     counts its host pass in ``harvest_s`` only, so ``overlap_ratio()``
     is below 1; the operator's gauge keeps counting under
     ``TTD_NO_TRACE=1``.  An admission in such a step still runs behind
-    the chunk in flight: no stall is charged."""
+    the chunk in flight: while every poll finds a successor in flight
+    (a handle that is never ready), the device is never counted
+    starved."""
     from tensorflow_train_distributed_tpu.serving import ServingEngine
 
     if killed:
@@ -339,6 +416,8 @@ def test_harvest_seconds_split_by_the_wait_span_and_outlive_the_kill_switch(
     cfg, params = tiny
     eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
                         prompt_buckets=(8,))
+    busy = _Handle(ready=False)
+    eng._handle_of = lambda out: busy
     rec = events.get_recorder()
     seq0 = rec.events_after(0)[0]
     eng.submit([1, 2, 3], 7)              # first token + three chunks
@@ -357,7 +436,9 @@ def test_harvest_seconds_split_by_the_wait_span_and_outlive_the_kill_switch(
     assert 0 < stats["overlapped_harvests"] < stats["chunks"]
     assert 0.0 < stats["overlapped_harvest_s"] < stats["harvest_s"]
     assert 0.0 < eng.overlap_ratio() < 1.0
-    assert eng.prefill_stall_s() == 0.0
+    assert busy.polls > 0 and eng.device_starved_s() == 0.0
+    assert not any(e[5]["drains"] for e in rec.events_after(seq0)[1]
+                   if e[0] == "engine/step")
     waits = [e[5]["overlapped"] for e in rec.events_after(seq0)[1]
              if e[0] == "decode/wait"]
     if killed:
@@ -365,3 +446,98 @@ def test_harvest_seconds_split_by_the_wait_span_and_outlive_the_kill_switch(
     else:
         assert waits.count(True) == stats["overlapped_harvests"]
         assert False in waits
+
+
+@pytest.mark.parametrize("killed", [False, True])
+def test_a_wait_that_drains_the_queue_is_charged_to_the_next_enqueue(
+        tiny, killed, monkeypatch):
+    """A harvest-first step: the chunk in flight is the newest program,
+    so its ``decode/wait`` returns on an empty queue while a request
+    still waits for a lane.  From that return to the next enqueue (the
+    stale lane's reset, ahead of the new lane's chunk) the device is
+    starved: on a stubbed clock exactly the interval, in
+    ``device_starved_s()`` (under ``TTD_NO_TRACE=1`` too) and in the
+    step's ``starved_ms`` / ``drains``."""
+    import numpy as np
+
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    if killed:
+        monkeypatch.setenv("TTD_NO_TRACE", "1")
+    cfg, params = tiny
+    eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
+                        prompt_buckets=(8,))
+    now = [0.0]
+    eng._clock = lambda: now[0]
+    handle = _Handle(ready=False)
+    eng._handle_of = lambda out: handle
+    rec = events.get_recorder()
+    eng.submit([1, 2, 3], 7)
+    for _ in range(3):
+        eng.serve_step()
+    assert eng._skip_eager_dispatch()     # the lane retires in flight
+    eng.submit([4, 5, 6], 4)
+    assert eng.device_starved_s() == 0.0
+
+    toks = eng._inflight["toks"]
+
+    class Read:                 # the wait's read empties the queue
+        def __array__(self, dtype=None, copy=None):
+            handle.ready, now[0] = True, 10.0
+            return np.asarray(toks)
+
+    eng._inflight["toks"] = Read()
+    harvest = eng._harvest
+
+    def later(toks, rids):      # host work between wait and enqueue
+        handle.ready, now[0] = False, 10.25
+        return harvest(toks, rids=rids)
+
+    eng._harvest = later
+    seq0 = rec.events_after(0)[0]
+    eng.serve_step()
+    assert eng.device_starved_s() == pytest.approx(0.25)
+    step = [e[5] for e in rec.events_after(seq0)[1]
+            if e[0] == "engine/step"]
+    if killed:
+        assert not step
+    else:
+        assert step[0]["starved_ms"] == pytest.approx(250.0)
+        assert step[0]["drains"] == 1
+    eng.run()
+    assert eng.device_starved_s() == pytest.approx(0.25)
+
+
+@pytest.mark.parametrize("variant", ["default", "self-draft"])
+def test_a_donated_handle_is_never_polled(tiny, variant):
+    """Every cache leaf is donated to the next program, and a deleted
+    array cannot be asked whether it is ready: the engine polls the
+    newest program's output only before the enqueue that may consume
+    it.  A session that stages, prefills, inserts, decodes, retires
+    and resets lanes, with every poll checked."""
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    cfg, params = tiny
+    kw = dict(VARIANTS[variant])
+    if variant == "self-draft":
+        kw.update(draft_config=cfg, draft_params=params)
+    eng = ServingEngine(cfg, params, slots=2, cache_len=32, chunk=2,
+                        prefill_chunk=4, **kw)
+    polls = []
+
+    class Probe:
+        def __init__(self, leaf):
+            self.leaf = leaf
+
+        def is_ready(self):
+            polls.append(self.leaf.is_deleted())
+            return self.leaf.is_ready()
+
+    leaf_of = ServingEngine._handle_of
+    eng._handle_of = lambda out: Probe(leaf_of(out))
+    for prompt, new in [([1, 2, 3], 6), ([4, 5], 5),
+                        ([9, 8, 7, 6, 5, 4, 3, 2, 1], 4), ([7], 1)]:
+        eng.submit(prompt, new)
+    eng.run()
+    assert polls and not any(polls)
+    assert eng.device_starved_s() >= 0.0

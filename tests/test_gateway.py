@@ -286,6 +286,8 @@ def test_metrics_scrape_parses_and_counters_move():
         # must render a truthful constant 0 (a real-engine gateway's
         # value is pinned in tests/test_serving_overlap.py).
         assert s["ttd_engine_overlap_ratio"] == 0
+        # ... and counts no starved device: the same constant.
+        assert s["ttd_engine_device_starved_seconds"] == 0
         assert s["ttd_gateway_slots_total"] == 2
         assert s["ttd_gateway_queue_depth"] == 0
         assert s["ttd_gateway_slots_in_use"] == 0

@@ -426,6 +426,9 @@ def test_gateway_over_procpool_http_healthz_metrics():
         assert prom['ttd_gateway_replica_rss_bytes{replica="1"}'] > 0
         assert prom["ttd_gateway_replica_restarts_total"] == 0
         assert prom["ttd_gateway_slots_total"] == 4   # live aggregate
+        # engine gauges ride the workers' stats frames: the starved-
+        # device seconds are served over a process pool too
+        assert prom["ttd_engine_device_starved_seconds"] >= 0
         # A real SIGKILL moves the restart counter through the full
         # metrics pipeline (scaler -> GatewayMetrics -> scrape).
         os.kill(pool.replicas[0].driver.pid, signal.SIGKILL)

@@ -462,6 +462,9 @@ def test_gateway_pool_healthz_metrics_and_failover():
         s = _parse_prom(_get(gw.port, "/metrics")[1])
         assert s["ttd_gateway_replicas_alive"] == 1
         assert s["ttd_gateway_failovers_total"] >= 1
+        # the pool sums its usable replicas' starved-device seconds
+        assert s["ttd_engine_device_starved_seconds"] == pytest.approx(
+            gw.pool.device_starved_s())
         assert s['ttd_gateway_requests_total{status="ok"}'] == 5
         # No token duplicated or dropped across the hop.
         assert s["ttd_gateway_tokens_generated_total"] == 5 * 25

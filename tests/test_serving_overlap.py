@@ -115,6 +115,16 @@ def test_interleave_smoke(params):
     eng = ServingEngine(CFG, params, slots=2, cache_len=64, chunk=2,
                         prefill_chunk=4)
     events = _instrument(eng)
+
+    class Busy:                 # the newest program, never finished
+        polls = 0
+
+        def is_ready(self):
+            Busy.polls += 1
+            return False
+
+    busy = Busy()
+    eng._handle_of = lambda out: busy
     out = {}
     a = eng.submit(active, 16)
     out.update(eng.serve_step())
@@ -135,7 +145,8 @@ def test_interleave_smoke(params):
     assert between.count("d") >= 2, tail
     # ... and the lane kept committing while the installments ran.
     assert committed[0] < committed[1] < committed[2], committed
-    assert eng.prefill_stall_s() == 0.0
+    # ... behind a chunk in flight at every poll: never starved.
+    assert busy.polls > 0 and eng.device_starved_s() == 0.0
     assert out[a] == _ref(params, active, 16)
     assert out[b] == _ref(params, long_prompt, 4)
 
@@ -461,5 +472,11 @@ def test_overlap_gateway_streaming_chunk_granular(params):
         line = [ln for ln in prom.splitlines()
                 if ln.startswith("ttd_engine_overlap_ratio ")][0]
         assert float(line.split()[1]) > 0.0
+        # ... and the starved-device gauge reads the engine's own sum
+        # (nothing pending now, so it stands still).
+        line = [ln for ln in prom.splitlines() if ln.startswith(
+            "ttd_engine_device_starved_seconds ")][0]
+        assert float(line.split()[1]) == pytest.approx(
+            eng.device_starved_s()) and eng.device_starved_s() >= 0.0
     finally:
         gw.drain(timeout=30)

@@ -227,10 +227,10 @@ def _run_closed_loop(base_url, clients, requests_per_client,
         "inter_token_ms_mean": _histogram_mean_ms(
             prom, "ttd_gateway_inter_token_seconds", prom_base),
         "overlap_ratio": _prom_sample(prom, "ttd_engine_overlap_ratio"),
-        "prefill_stall_s": round(
-            _prom_sample(prom, "ttd_engine_prefill_stall_seconds")
+        "device_starved_s": round(
+            _prom_sample(prom, "ttd_engine_device_starved_seconds")
             - _prom_sample(prom_base,
-                           "ttd_engine_prefill_stall_seconds"), 4),
+                           "ttd_engine_device_starved_seconds"), 4),
         "shed_rate": round(sheds / attempts, 4) if attempts else 0.0,
         "n_ok": len(lats),
         "n_shed": sheds,

@@ -14,8 +14,8 @@ and times out requests concurrently:
 - ``GET /metrics``  Prometheus text: request/token counters, queue
   depth, slot occupancy (decoding + prefilling lanes), TTFT /
   inter-token / latency histograms, the engine's overlap ratio,
-  ``ttd_engine_prefill_stall_seconds`` (decode time lost behind an
-  admission with no decode chunk in flight: 0 from this engine), and
+  ``ttd_engine_device_starved_seconds`` (seconds the engine left the
+  device with an empty queue while it had work pending), and
   the paged-KV cache economics: ``ttd_engine_kv_blocks_in_use`` /
   ``ttd_engine_kv_blocks_total`` (admission is block-keyed by
   default), ``ttd_engine_prefix_hit_tokens_total`` (prefill skipped

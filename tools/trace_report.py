@@ -5,7 +5,10 @@ buffer), in the same spirit as ``tools/profile_summary.py`` for XPlane
 captures: given a Chrome-trace JSON — fetched from a live gateway's
 ``GET /debug/trace?last_s=N`` or written by ``Recorder.save()`` — it
 answers "where did the time go" (a per-stage latency table over span
-names: count, mean, p50, p99, max) and "what happened to request X"
+names: count, mean, p50, p99, max; then an engine step by the stage
+spans inside it, each with its SELF time, what no span names, and the
+seconds a step the engine left the device starved or was away with its
+caller) and "what happened to request X"
 (``--request N``: that request's admission→prefill→decode→retire
 waterfall, the offline twin of ``GET /v1/requests/<id>``).
 ``--requests`` lists every request id in the window with its terminal
@@ -93,6 +96,75 @@ def stage_table(evs: list) -> list:
                      ds[-1]))
     rows.sort(key=lambda r: -r[2])
     return rows
+
+
+def step_stages(evs: list) -> dict:
+    """Where an engine step's host time goes, by the stage spans the
+    engine records under ``engine/step``: every moment of a step
+    belongs to the innermost span its thread had open (a span's SELF
+    time), or to no span at all.  ``{"steps": n, "rows": [(name,
+    self_mean_ms, self_p50_ms, self_p75_ms, steps_with_it)], busiest
+    first, with ``(no span)`` among them, "starved_ms", "away_ms",
+    "drains", "span_ms"}``: the last four are sums of the steps' own
+    counters (milliseconds the engine knew the device's queue empty
+    while it had work, the caller's passes between steps, how often
+    the queue was found empty) and of the steps' durations plus those
+    passes; ``None`` for counters a trace predates.  The benchmark
+    reads the same from the live ring (``benchmark/harness/
+    step_stages.py``, which may not import this tool)."""
+    by_thread = collections.defaultdict(list)
+    steps = []
+    for e in evs:
+        if e.get("ph") != "X":
+            continue
+        key = (e.get("pid"), e.get("tid"))
+        if e["name"] == "engine/step":
+            steps.append((key, e["ts"], e.get("dur", 0.0),
+                          e.get("args") or {}))
+        else:
+            by_thread[key].append((e["ts"], e.get("dur", 0.0), e["name"]))
+    for inner in by_thread.values():
+        inner.sort(key=lambda s: (s[0], -s[1]))
+    own = collections.defaultdict(list)
+    for key, t0, dur, _ in steps:
+        end_of_step = t0 + dur
+        mine = collections.Counter()
+        stack, at = [], t0
+        for k0, kdur, name in by_thread[key]:
+            if not t0 <= k0 <= end_of_step:
+                continue
+            while stack and stack[-1][0] <= k0:
+                end, top = stack.pop()
+                mine[top] += max(end - at, 0.0)
+                at = max(at, end)
+            if k0 > at:
+                mine[stack[-1][1] if stack else "(no span)"] += k0 - at
+                at = k0
+            stack.append((min(k0 + kdur, end_of_step), name))
+        while stack:
+            end, top = stack.pop()
+            mine[top] += max(end - at, 0.0)
+            at = max(at, end)
+        mine["(no span)"] += max(end_of_step - at, 0.0)
+        for name, us in mine.items():
+            own[name].append(us / 1e3)
+    n = len(steps)
+    rows = []
+    for name, ms in own.items():
+        padded = sorted(ms + [0.0] * (n - len(ms)))
+        rows.append((name, sum(ms) / n, _percentile(padded, 0.5),
+                     _percentile(padded, 0.75),
+                     sum(1 for v in ms if v > 0)))
+    rows.sort(key=lambda r: -r[1])
+
+    def total(attr):
+        got = [a[attr] for _, _, _, a in steps if attr in a]
+        return sum(got) if got else None
+
+    away = total("away_ms")
+    return {"steps": n, "rows": rows, "starved_ms": total("starved_ms"),
+            "away_ms": away, "drains": total("drains"),
+            "span_ms": sum(d for _, _, d, _ in steps) / 1e3 + (away or 0.0)}
 
 
 def prefill_walk(evs: list) -> tuple:
@@ -800,6 +872,26 @@ def main(argv=None) -> int:
         for name, n, total, mean, p50, p99, mx in rows:
             print(f"{n:7d}  {total:10.2f}  {mean:9.3f}  {p50:8.3f}  "
                   f"{p99:8.3f}  {mx:8.3f}  {name}")
+    stages = step_stages(evs)
+    if stages["steps"]:
+        n = stages["steps"]
+        print(f"\n== engine step by stage ({n} steps; self time: what "
+              f"no span nested in it covers)")
+        print(f"{'mean_ms':>9}  {'p50_ms':>8}  {'p75_ms':>8}  "
+              f"{'steps':>6}  span")
+        for name, mean, p50, p75, seen in stages["rows"]:
+            print(f"{mean:9.3f}  {p50:8.3f}  {p75:8.3f}  {seen:6d}  "
+                  f"{name}")
+        if stages["starved_ms"] is not None:
+            span = stages["span_ms"]
+            print(f"  device starved     {stages['starved_ms'] / 1e3:.3f} s"
+                  f" of {span / 1e3:.3f} s of steps and the caller's "
+                  f"passes ({100.0 * stages['starved_ms'] / span:.2f}%): "
+                  f"{stages['starved_ms'] / n:.3f} ms a step, queue "
+                  f"found empty {stages['drains'] / n:.2f} times a step")
+            print(f"  away (the caller)  {stages['away_ms'] / 1e3:.3f} s "
+                  f"({100.0 * stages['away_ms'] / span:.2f}%): "
+                  f"{stages['away_ms'] / n:.3f} ms between two steps")
     pieces, walked, held, counted = prefill_walk(evs)
     if held:
         print(f"  prefill/piece attention walked {walked} of {held} "
