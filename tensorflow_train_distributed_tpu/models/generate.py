@@ -38,15 +38,17 @@ from tensorflow_train_distributed_tpu.runtime.lint.registry import (
 
 def _decode_model(config, cache_len: int, slot_decode: bool = False,
                   paged_kv_blocks: int = 0, kv_block_size: int = 0,
-                  ring_blocks: int = 0):
+                  ring_blocks: int = 0, query_block: int = 0):
     """The decode-mode model for a decoder-family config: LlamaModel for
     LlamaConfig, MoeLmModel for MoeConfig (Mixtral-style) — one generate
     path serves every decoder family.  ``slot_decode`` selects the
     per-slot cache-index mode (serving.ServingEngine), and
     ``paged_kv_blocks``/``kv_block_size`` its paged-pool variant (the
     engine's block-table cache; ``ring_blocks`` the blocks of a window
-    layer's ring a lane, for a config that has window layers); this is
-    the ONE family-dispatch point, shared by generate and the engine."""
+    layer's ring a lane, for a config that has window layers;
+    ``query_block`` the queries its batch-1 prefill model's attention
+    walks at a time: the engine's ``prefill_chunk``); this is the ONE
+    family-dispatch point, shared by generate and the engine."""
     from tensorflow_train_distributed_tpu.models.moe import (
         MoeConfig,
         MoeLmModel,
@@ -56,7 +58,7 @@ def _decode_model(config, cache_len: int, slot_decode: bool = False,
     return cls(config, decode=True, cache_len=cache_len,
                slot_decode=slot_decode,
                paged_kv_blocks=paged_kv_blocks,
-               kv_block_size=kv_block_size,
+               kv_block_size=kv_block_size, query_block=query_block,
                # a field of the family whose layers may differ by kind
                **({"ring_blocks": ring_blocks} if ring_blocks else {}))
 

@@ -429,6 +429,11 @@ class MultiHeadAttention(nn.Module):
     # (``serving.ServingEngine``); ``paged_kv_blocks`` is then the full
     # layers' business alone.
     ring_blocks: int = 0
+    # Queries of one call that attention over a linear cache walks at
+    # a time (``ops.attention.prefix_attention``'s ``block``): a call
+    # over k prefill pieces of a prompt reads what the pieces read.
+    # The engine sets it to its ``prefill_chunk``; 0: all at once.
+    query_block: int = 0
 
     def _rope(self, t, positions):
         return apply_rope(t, positions, base=self.rope_base,
@@ -1111,7 +1116,8 @@ class MultiHeadAttention(nn.Module):
                                         mask=mask)
         else:
             out = prefix_attention(qh, (kc, vc, scales), start, heads,
-                                   window=window)
+                                   window=window,
+                                   block=self.query_block)
         out = out.transpose(0, 2, 1, 3)
         return self._attn_epilogue(out, b, q_len, features, gate)
 
@@ -1305,6 +1311,7 @@ class LatentAttention(nn.Module):
     slot_decode: bool = False
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see MultiHeadAttention
     # The learned selection (class docstring): ``index_heads`` heads of
     # ``index_dim`` score every cached row for a query, and attention
     # sees the ``index_topk`` best.  0 = attention over every row.
@@ -1413,9 +1420,11 @@ class LatentAttention(nn.Module):
         )
 
         with jax.named_scope("attn/index_score"):
-            scores = prefix_index_scores(q_i, w_i, keys, start)
+            scores = prefix_index_scores(q_i, w_i, keys, start,
+                                         block=self.query_block)
         with jax.named_scope("attn/select"):
-            return select_top_rows(scores, self.index_topk, start)
+            return select_top_rows(scores, self.index_topk, start,
+                                   block=self.query_block)
 
     def _kv_b(self):
         """``Wkv_b`` as [rank, H, nope + v_head]."""
@@ -1550,6 +1559,7 @@ class LatentAttention(nn.Module):
                 lambda rows: [t.transpose(0, 2, 1, 3)
                               for t in self._up_project(rows, kv_b)],
                 keep=keep, softmax_scale=self.softmax_scale,
+                block=self.query_block,
             ).transpose(0, 2, 1, 3)
         return self._out(o, x.shape[-1])
 

@@ -255,6 +255,7 @@ class DecoderBlock(nn.Module):
     # layers.MultiHeadAttention.paged_kv_blocks.
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see layers.MultiHeadAttention
 
     @nn.compact
     def __call__(self, x, segment_ids=None, positions=None, kv_pools=None):
@@ -279,6 +280,7 @@ class DecoderBlock(nn.Module):
             slot_decode=self.slot_decode,
             paged_kv_blocks=self.paged_kv_blocks,
             kv_block_size=self.kv_block_size,
+            query_block=self.query_block,
             fused_qkv=cfg.fused_qkv,
             qkv_bias=cfg.qkv_bias,
             name="attention",
@@ -342,6 +344,7 @@ class _BlockStep(nn.Module):
     slot_decode: bool = False
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see layers.MultiHeadAttention
 
     @nn.compact
     def __call__(self, carry, aux, layer):
@@ -352,6 +355,7 @@ class _BlockStep(nn.Module):
                              slot_decode=self.slot_decode,
                              paged_kv_blocks=self.paged_kv_blocks,
                              kv_block_size=self.kv_block_size,
+                             query_block=self.query_block,
                              name="block")
         if not pools:
             return (block(x, segment_ids, positions), pools), None
@@ -379,6 +383,7 @@ class _ScannedBlock(nn.Module):
     slot_decode: bool = False
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see layers.MultiHeadAttention
 
     @nn.compact
     def __call__(self, x, segment_ids=None, positions=None):
@@ -392,12 +397,14 @@ class _ScannedBlock(nn.Module):
                          cache_len=self.cache_len,
                          slot_decode=self.slot_decode,
                          paged_kv_blocks=self.paged_kv_blocks,
-                         kv_block_size=self.kv_block_size)
+                         kv_block_size=self.kv_block_size,
+                         query_block=self.query_block)
                 if self.decode
                 else _partial(_BlockStep,
                               slot_decode=self.slot_decode,
                               paged_kv_blocks=self.paged_kv_blocks,
-                              kv_block_size=self.kv_block_size))
+                              kv_block_size=self.kv_block_size,
+                              query_block=self.query_block))
         # No remat in decode mode: there is no backward pass to save memory
         # for, and the KV-cache writes must not replay under a checkpoint.
         if wants_outer_remat(self.config) and not self.decode:
@@ -512,6 +519,7 @@ class LlamaModel(nn.Module):
     # layers.MultiHeadAttention.paged_kv_blocks.
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see layers.MultiHeadAttention
 
     @nn.compact
     def __call__(self, tokens, *, segment_ids=None, positions=None):
@@ -549,6 +557,7 @@ class LlamaModel(nn.Module):
                               slot_decode=self.slot_decode,
                               paged_kv_blocks=self.paged_kv_blocks,
                               kv_block_size=self.kv_block_size,
+                              query_block=self.query_block,
                               name="layers")(
                 x, segment_ids, positions)
         else:
@@ -562,6 +571,7 @@ class LlamaModel(nn.Module):
                         slot_decode=self.slot_decode,
                         paged_kv_blocks=self.paged_kv_blocks,
                         kv_block_size=self.kv_block_size,
+                        query_block=self.query_block,
                         name=f"layer_{i}")(
                     x, segment_ids, positions)
         x = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,

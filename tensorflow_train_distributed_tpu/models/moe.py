@@ -30,6 +30,7 @@ bf16 stability); both are sown into an ``aux_loss`` collection that
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import flax.linen as nn
@@ -406,6 +407,29 @@ class _ExpertFfn(nn.Module):
 GMM_INTERPRET = False
 
 
+def _gmm_tiling(rows: int, groups: int, k: int, n: int) -> tuple:
+    """Tiles (rows, k, n) of a grouped matmul of ``rows`` rows over
+    ``groups`` groups.  A grid step is one [128, tk] x [tk, tn] product
+    of one expert's rows.  With a few row tiles or less an expert
+    (serving: lanes x top_k rows of a decode step, or those of a
+    prefill call of one to four pieces, over all experts) a step is
+    bound by its slice of the expert's kernel coming in, so the slices
+    are as large as VMEM carries double buffered (2 MB in bf16) and the
+    steps few: at 2048 x 1536 three a group, where 128 x 128 tiles make
+    192.  Measured up to 160 rows an expert (PERF.md, PR 26 and PR 38:
+    at 40,960 rows over 256 experts megablox's own tiles took four
+    times as long as these); the rule holds to four row tiles an
+    expert.  With more (training) the tiles stay megablox's own, which
+    nothing here has measured, and the backward's transposed products
+    take the same tiling.  ``rhs`` may hold a share of the groups
+    (``group_offset``): the rows are then all groups' and a held group
+    still gets its share."""
+    if rows <= 4 * 128 * groups:
+        return (128, min(-(-k // 128) * 128, 2048),
+                min(-(-n // 128) * 128, 512))
+    return (128, 128, 128)
+
+
 def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):
     """Megablox grouped matmul: rows of ``lhs`` hit the ``rhs`` slice of
     their group (``group_sizes`` [E] row counts, summing to lhs rows).
@@ -420,23 +444,8 @@ def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):
     """
     from jax.experimental.pallas.ops.tpu.megablox import ops as _mb
 
-    # A grid step is one [128, tk] x [tk, tn] product of one expert's
-    # rows.  With a row tile or less an expert (serving: lanes x top_k
-    # rows of a decode step, or a prefill piece's, over all experts) a
-    # step is bound by its slice of the expert's kernel coming in, so
-    # the slices are as large as VMEM carries double buffered (2 MB in
-    # bf16) and the steps few: at 2048 x 1536 three a group, where
-    # 128 x 128 tiles make 192.  With more rows an expert (training)
-    # the tiles stay megablox's own: only the few-row shapes were
-    # measured (PERF.md, PR 26), and the backward's transposed products
-    # take the same tiling.
-    # ``rhs`` may hold a share of the groups (``group_offset``): the
-    # rows are then all groups' and a held group still gets its share.
     _, k, n = rhs.shape
-    tiling = (128, 128, 128)
-    if lhs.shape[0] <= 128 * group_sizes.shape[0]:
-        tiling = (128, min(-(-k // 128) * 128, 2048),
-                  min(-(-n // 128) * 128, 512))
+    tiling = _gmm_tiling(lhs.shape[0], group_sizes.shape[0], k, n)
     return _mb.gmm(lhs, rhs, group_sizes,
                    preferred_element_type=jnp.float32, interpret=interpret,
                    tiling=tiling,
@@ -461,10 +470,15 @@ def _within_best_groups(choice, n_group: int, topk_group: int):
     return jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(t, e)
 
 
-def _routed_ffn_rows(flat, top_e, gate_w, num_experts, wi_gate, wi_up,
-                     wo, *, dtype, interpret, group_offset=None,
-                     psum_axis=None):
-    """The dropless routed FFN over a block of tokens.
+@partial(jax.jit, static_argnames=("num_experts", "dtype", "interpret",
+                                    "psum_axis"))
+def _routed_ffn_rows(  # ttd-lint: disable=compilecheck -- no dispatch site: traced inside the programs of declared sites (the engine's, the trainer's)
+        flat, top_e, gate_w, num_experts, wi_gate, wi_up, wo, *, dtype,
+        interpret, group_offset=None, psum_axis=None):
+    """The dropless routed FFN over a block of tokens.  Jitted on its
+    own, so the expert layers of one program, alike in every shape,
+    are traced and lowered once and not once a layer (set-up seconds
+    of every serving program: PERF.md, PR 38).
 
     ``flat`` [T, D] tokens; ``top_e``/``gate_w`` [T, k] the router's
     expert choices and normalized gates (computed ONCE by the caller —
@@ -861,6 +875,7 @@ class MoeDecoderBlock(nn.Module):
     # Paged serving KV cache — see layers.MultiHeadAttention.
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see layers.MultiHeadAttention
     # Blocks of a window layer's paged ring a lane (layers.
     # MultiHeadAttention.ring_blocks).
     ring_blocks: int = 0
@@ -885,6 +900,7 @@ class MoeDecoderBlock(nn.Module):
                 slot_decode=self.slot_decode,
                 paged_kv_blocks=self.paged_kv_blocks,
                 kv_block_size=self.kv_block_size,
+                query_block=self.query_block,
             )(h, segment_ids=segment_ids, positions=positions)
         else:
             attn = self._mha(h, segment_ids, positions)
@@ -911,7 +927,8 @@ class MoeDecoderBlock(nn.Module):
             cache_len=self.cache_len or cfg.max_positions,
             slot_decode=self.slot_decode,
             paged_kv_blocks=self.paged_kv_blocks,
-            kv_block_size=self.kv_block_size)
+            kv_block_size=self.kv_block_size,
+            query_block=self.query_block)
         kind = cfg.attn_kind(self.layer)
         if kind is None:
             return L.MultiHeadAttention(
@@ -959,6 +976,7 @@ class MoeLmModel(nn.Module):
     # Paged serving KV cache — see layers.MultiHeadAttention.
     paged_kv_blocks: int = 0
     kv_block_size: int = 0
+    query_block: int = 0    # see layers.MultiHeadAttention
     ring_blocks: int = 0    # see MoeDecoderBlock
 
     @nn.compact
@@ -1000,6 +1018,7 @@ class MoeLmModel(nn.Module):
                     slot_decode=self.slot_decode,
                     paged_kv_blocks=self.paged_kv_blocks,
                     kv_block_size=self.kv_block_size,
+                    query_block=self.query_block,
                     layer=i, ring_blocks=self.ring_blocks,
                     name=f"layer_{i}")(x, segment_ids, positions)
         x = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,

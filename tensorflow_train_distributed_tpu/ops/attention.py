@@ -112,6 +112,45 @@ def prefix_first_tile(start, tile: int, window: Optional[int]):
     return (start.min() - (window - 1)).clip(0) // tile
 
 
+def _by_query_blocks(op, start, q_len: int, block: Optional[int], axis: int,
+                     *per_query):
+    """``op(start, *per_query)`` over queries ``q_len`` long, ``block``
+    at a time: block ``j`` is the slice ``[j * block, (j + 1) * block)``
+    of every ``per_query`` array along its query axis (``per_query``
+    holds ``(array, axis)`` pairs), at ``start + j * block``, and the
+    results are joined along ``axis``.  Each block walks its own
+    tile range (its own ``prefix_tiles_walked``, ``prefix_first_tile``
+    and ``select_tiles_counted``), so a call over the pieces of one
+    prompt reads the tiles, in the order and with the arithmetic, of the
+    pieces run apart.  The whole blocks are one loop (traced and
+    compiled once, however many there are) that slices its block out
+    of each array and writes its result into place: no array is laid
+    out again by block.  A last, shorter block is a call of its own.
+    ``None`` when one call covers every query (no ``block``, or no more
+    queries than one): the caller's expression stands as it is."""
+    if not block or q_len <= block:
+        return None
+    start = jnp.asarray(start, jnp.int32)
+
+    def at(row0, size):
+        return op(start + row0, *(
+            jax.lax.dynamic_slice_in_dim(a, row0, size, axis=ax)
+            for a, ax in per_query))
+
+    like = jax.eval_shape(lambda: at(0, block))
+    out = jnp.zeros((*like.shape[:axis], q_len, *like.shape[axis + 1:]),
+                    like.dtype)
+    out = jax.lax.fori_loop(
+        0, q_len // block,
+        lambda j, out: jax.lax.dynamic_update_slice_in_dim(
+            out, at(j * block, block), j * block, axis=axis), out)
+    rest = q_len % block
+    if rest:
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, at(q_len - rest, rest), q_len - rest, axis=axis)
+    return out
+
+
 def prefix_attention(
     q: jax.Array,
     cache,
@@ -122,6 +161,7 @@ def prefix_attention(
     softmax_scale: Optional[float] = None,
     keep: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    block: Optional[int] = None,
 ) -> jax.Array:
     """Attention of ``q`` [B, H, Q, D] over the prefix of a linear KV
     cache that its lanes hold, tile by tile with a running softmax.
@@ -157,10 +197,23 @@ def prefix_attention(
     reach, whatever the lane holds behind them.  A tile may then hold
     no row that some query sees, so a masked entry's probability is
     set to zero and not left to the running maximum.
+
+    ``block``: more queries than that are walked ``block`` at a time,
+    each block over its own tiles (``_by_query_blocks``): the cost of a
+    call over k pieces of a prompt is the k pieces', not k times the
+    last one's.
     """
     tile = PREFIX_TILE if tile is None else tile
     cache_len = jax.tree.leaves(cache)[0].shape[1]
     q_len = q.shape[-2]
+    blocks = _by_query_blocks(
+        lambda s, q, keep=None: prefix_attention(
+            q, cache, s, kv_of, tile=tile, softmax_scale=softmax_scale,
+            keep=keep, window=window),
+        start, q_len, block, q.ndim - 2, (q, q.ndim - 2),
+        *(() if keep is None else ((keep, 1),)))
+    if blocks is not None:
+        return blocks
     start = jnp.asarray(start, jnp.int32).reshape(-1)     # [B] or [1]
     positions = start[:, None, None] + jnp.arange(q_len)[:, None]
 
@@ -220,7 +273,8 @@ def prefix_attention(
     return (acc / l).astype(v_like.dtype)
 
 
-def prefix_index_scores(q, w, keys, start, *, tile: Optional[int] = None):
+def prefix_index_scores(q, w, keys, start, *, tile: Optional[int] = None,
+                        block: Optional[int] = None):
     """The learned selection's score of every cached row for every
     query (DeepSeek-V3.2's indexer)::
 
@@ -233,10 +287,16 @@ def prefix_index_scores(q, w, keys, start, *, tile: Optional[int] = None):
     Returns float32 [B, Q, C].  Walked as ``prefix_attention`` walks:
     the tiles ``prefix_tiles_walked`` gives and no others (the rest
     stay ``-inf``), so the per-head scores are [B, Q, H, tile] at a
-    time and never [B, Q, H, C]."""
+    time and never [B, Q, H, C].  ``block``: as ``prefix_attention``'s,
+    a block's rows past its own tiles ``-inf``."""
     tile = PREFIX_TILE if tile is None else tile
     b, q_len = q.shape[:2]
     cache_len = keys.shape[1]
+    blocks = _by_query_blocks(
+        lambda s, q, w: prefix_index_scores(q, w, keys, s, tile=tile),
+        start, q_len, block, 1, (q, 1), (w, 1))
+    if blocks is not None:
+        return blocks
     start = jnp.asarray(start, jnp.int32).reshape(-1)
     positions = start[:, None] + jnp.arange(q_len)             # [B|1, Q]
 
@@ -287,7 +347,8 @@ def select_tiles_counted(start, q_len: int, k: int, tile: int,
         start, q_len, tile, cache_len)
 
 
-def select_top_rows(scores, k: int, start, *, tile: Optional[int] = None):
+def select_top_rows(scores, k: int, start, *, tile: Optional[int] = None,
+                    block: Optional[int] = None):
     """Bool [B, Q, C]: for each query the ``k`` rows of largest score,
     ties to the lower position (``lax.top_k``'s rule), and only rows
     whose score is not ``-inf`` (a query that sees fewer than ``k``
@@ -306,9 +367,16 @@ def select_top_rows(scores, k: int, start, *, tile: Optional[int] = None):
     count of equals carried.  Where ``select_tiles_counted`` is 0 no
     pass runs and every row seen is kept.  A cache of one tile is the
     same count and the same tie rule as one expression over all of it.
+    ``block``: as ``prefix_index_scores``'s, whose blocks these are;
+    each counts over its own tiles, or none.
     """
     tile = PREFIX_TILE if tile is None else tile
     b, q_len, cache_len = scores.shape
+    blocks = _by_query_blocks(
+        lambda s, scores: select_top_rows(scores, k, s, tile=tile),
+        start, q_len, block, 1, (scores, 1))
+    if blocks is not None:
+        return blocks
     scores = jax.lax.stop_gradient(scores)   # a choice has no gradient
     start = jnp.asarray(start, jnp.int32).reshape(-1)
     one_tile = cache_len <= tile

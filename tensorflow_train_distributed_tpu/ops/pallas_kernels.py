@@ -881,13 +881,21 @@ def _rmsnorm_bwd_kernel(x_ref, s_ref, r_ref, g_ref, dx_ref):
     dx_ref[:] = dx.astype(dx_ref.dtype)
 
 
-def _rmsnorm_rows(n_rows: int) -> int:
-    return min(256, max(8, n_rows))
+def _rmsnorm_rows(n_rows: int, d: int) -> int:
+    """Rows a grid step normalizes: 256, or fewer where a row is wider
+    than 4096 values, so that a block's float32 image stays within 4
+    MiB (a power of two of rows, at least 8).  The kernel's fast memory
+    is several such images and does not grow with ``n_rows``, but what
+    the compiler leaves it does shrink as the program around it grows:
+    256 rows of 7168 took 49 MiB, which a prefill call of 2048 rows no
+    longer had (44)."""
+    wide = max(8, 1 << (((4 << 20) // (4 * d)).bit_length() - 1))
+    return min(256, wide, max(8, n_rows))
 
 
 def _rmsnorm_fwd_call(x2, s2, *, epsilon, interpret):
     n, d = x2.shape
-    bn = _rmsnorm_rows(n)
+    bn = _rmsnorm_rows(n, d)
     return pl.pallas_call(
         functools.partial(_rmsnorm_fwd_kernel, epsilon=epsilon),
         name="rms_norm_fwd",
@@ -922,7 +930,7 @@ def _rms_norm_pallas_fwd(x2, s2, epsilon, interpret):
 def _rms_norm_pallas_bwd(epsilon, interpret, res, g):
     x2, s2, r = res
     n, d = x2.shape
-    bn = _rmsnorm_rows(n)
+    bn = _rmsnorm_rows(n, d)
     dx = pl.pallas_call(
         _rmsnorm_bwd_kernel,
         name="rms_norm_bwd",

@@ -123,8 +123,10 @@ CONTRACT = {
     # what the step did: lanes active at its dispatch, the cached
     # positions they held, the pool blocks those reach over all slots
     # (what the fused attention kernel reads) of the blocks the slots'
-    # tables have, prefill pieces run and their prompt tokens, tokens
-    # handed to requests, the engine queue's depth at exit; with routed
+    # tables have, prefill pieces run, the piece programs launched for
+    # them (piece_calls: one call runs one piece, or a budget's worth
+    # of consecutive pieces of one prompt) and their prompt tokens,
+    # tokens handed to requests, the engine queue's depth at exit; with routed
     # experts, of the decode chunk harvested in the step, the experts
     # that took a row and the rows' coefficient of variation over the
     # experts (means over the chunk's steps and expert layers, counted
@@ -140,7 +142,7 @@ CONTRACT = {
     # (ServingEngine._launch, _poll_drained); away_ms: from the
     # previous step's exit to this one's entry (the caller's pass)
     "engine/step": ("lanes positions kv_blocks kv_table_blocks "
-                    "kv_window_blocks pieces "
+                    "kv_window_blocks pieces piece_calls "
                     "prefill_tokens committed queued experts_hit "
                     "expert_load_cv experts_held routed_here "
                     "rows_scored rows_selected "
@@ -153,7 +155,11 @@ CONTRACT = {
     "decode/harvest": "overlapped",
     # one request's staging; matched: prompt tokens a prefix supplied
     "prefill/stage": "rid tokens matched",
-    # rows: the cache rows the piece's attention walks (a prefix, in
+    # one call of the piece program: pieces consecutive pieces of the
+    # request's prompt from number piece on, tokens real prompt tokens
+    # in all.
+    # rows: the cache rows the attention of the call's LAST piece walks
+    # (each piece of a call walks its own; a prefix, in
     # whole tiles: ops.attention.prefix_tiles_walked) of the cache_rows
     # a lane's cache has (a learned selection is a mask inside that
     # walk: the rows read are these, whatever it chooses);
@@ -162,15 +168,15 @@ CONTRACT = {
     # no query of the piece sees more rows than it keeps, or the model
     # has no selection); window_rows: of them, the rows a sliding-
     # window layer's walk reads (ops.attention.prefix_first_tile on; 0
-    # where no layer has a window)
-    "prefill/piece": ("rid piece n_pieces tokens rows select_rows "
+    # where no layer has a window); both of the last piece, as rows
+    "prefill/piece": ("rid piece pieces n_pieces tokens rows select_rows "
                       "window_rows cache_rows"),
     # kind: "fresh" (zeros), "copy" (a preloaded pair's), "gather"
     # (the radix-matched rows out of the pool)
     "prefill/cache": "rid kind",
-    # a piece's puts (tokens, scalars) and its enqueue; draft: 1 for
-    # the draft model's piece
-    "prefill/dispatch": "rid piece tokens rows draft",
+    # a call's puts (tokens, scalars) and its enqueue; pieces, tokens,
+    # rows: its prefill/piece's; draft: 1 for the draft model's pieces
+    "prefill/dispatch": "rid piece pieces tokens rows draft",
     "prefill/wait": "rid",
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",

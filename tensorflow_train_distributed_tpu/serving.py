@@ -14,10 +14,12 @@ own rng stream (seeded at submit) wherever it lands.
 - Staged (``_PrefillTask`` in ``_staging``): the slot is reserved
   while the request's batch-1 LINEAR cache is built piece by piece
   (``_prefill_piece``; bucketed lengths, or ``prefill_chunk``-token
-  pieces of one program at any prompt length; a dense-dispatch MoE
+  pieces of one program at any prompt length, the consecutive pieces
+  of one prompt that ride one step as ONE call where the head request
+  has as many left as the step's budget holds; a dense-dispatch MoE
   prefills whole at its exact length, since router capacity depends on
   it).  A speculative engine then builds the draft's cache over the
-  same piece grid.  The last target piece yields the first token.
+  same piece grid.  The last target call yields the first token.
 - Decoding (``_SlotState`` in ``_slot_states``): the finished batch-1
   rows were inserted into the slot grid (``_paged_insert``); the host
   holds the request's tokens, its remaining budget and its rng
@@ -122,6 +124,7 @@ from typing import Optional
 
 import contextlib
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -275,13 +278,33 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: that fell on them (``routed_here``); where attention chooses its
 #: rows, the rows a step and layer scored and attended over its live
 #: lanes (``rows_scored``, ``rows_selected``): ``_count_sown``.
+#: ``pieces`` counts prefill pieces (``_pieces_for``'s), ``piece_calls``
+#: the piece programs launched for them: one call runs one piece, or
+#: the budget's worth of consecutive pieces of one prompt
+#: (``_advance_piece``, ``_piece_counts``).
 #: ``starved_ms`` / ``drains``: the milliseconds, and the times, the
 #: device's queue was known empty while the engine had work
 #: (``_launch``, ``_poll_drained``); ``away_ms``: from the previous
 #: step's exit to this one's entry, the caller's pass between them.
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
-                "kv_window_blocks", "pieces", "prefill_tokens",
-                "committed", "starved_ms", "drains", "away_ms")
+                "kv_window_blocks", "pieces", "piece_calls",
+                "prefill_tokens", "committed", "starved_ms", "drains",
+                "away_ms")
+
+
+def _ffn_passes(next_fun, args, kwargs, context):
+    """Method interceptor for a trace that asks for the cache's shapes
+    alone (``_cache_struct``): the cache's leaves are the attention
+    layers', and a feed-forward block between them (dense or routed)
+    hands on its input's shape, so its own trace, the larger part of a
+    layer's, is left out."""
+    from tensorflow_train_distributed_tpu.models.layers import MlpBlock
+    from tensorflow_train_distributed_tpu.models.moe import MoEMlpBlock
+
+    if (context.method_name == "__call__"
+            and isinstance(context.module, (MlpBlock, MoEMlpBlock))):
+        return args[0]
+    return next_fun(*args, **kwargs)
 
 
 def _bucket_len(n: int, buckets) -> int:
@@ -528,8 +551,12 @@ class ServingEngine:
         # piece programs as the linear engine — prefix reuse replaces
         # recompute with a pool gather, never changes the math); only
         # the slot-grid decode/verify/insert programs go paged.
-        self._prefill_model = _decode_model(config, self.cache_len,
-                                            slot_decode=True)
+        # Its attention walks a call's queries a piece at a time
+        # (``query_block``), so a call over several pieces of a prompt
+        # (``_advance_piece``) costs what the pieces cost.
+        self._prefill_model = _decode_model(
+            config, self.cache_len, slot_decode=True,
+            query_block=prefill_chunk or 0)
         # (the slot-grid decode model is built below, once
         # kv_pool_blocks has resolved — possibly via the autosize
         # solve, which needs the draft variables prepared first)
@@ -590,7 +617,8 @@ class ServingEngine:
             # row sets by the speculative invariant), so one allocation
             # covers both pools; only the pool row shapes differ.
             self._draft_prefill_model = _decode_model(
-                draft_config, self.cache_len, slot_decode=True)
+                draft_config, self.cache_len, slot_decode=True,
+                query_block=prefill_chunk or 0)
         # Acceptance-adaptive speculation (opt-in): precompiled
         # draft-depth buckets + a host-side controller that SELECTS
         # among them per round from measured acceptance — it never
@@ -715,6 +743,25 @@ class ServingEngine:
                 f"step; got {prefill_budget} (a budget as large as the "
                 f"prompts admits a whole prompt in one step)")
         self.prefill_budget = prefill_budget
+        # Pieces one ``_prefill_piece`` call may cover: one, or the
+        # largest power of two of them that a step's budget holds
+        # (``prefill_chunk`` engines; every other engine runs one piece
+        # a call).  ONE shape beside the single piece, because a shape
+        # is a whole trace of the model at the engine's first admission
+        # (seconds of set-up: PERF.md, PR 38), and the largest because
+        # a call of k pieces saves k - 1 reads of every weight.  Not
+        # where attention chooses its rows (a learned selection): a
+        # piece there is mostly its walk, which a call does not share,
+        # and a call of two read slower than two pieces (PERF.md, PR
+        # 38: measured, the cause not found).
+        most = 1
+        if (prefill_chunk is not None and prefill_budget is not None
+                and not self._index_topk[False]):
+            most = max(1, min(-(-prefill_budget // prefill_chunk),
+                              -(-self.cache_len // prefill_chunk)))
+        most = 1 << (most.bit_length() - 1)
+        self._piece_counts = (1, most) if most > 1 else (1,)
+        self._piece_shapes_ready = most == 1    # _compile_piece_shapes
         self._staging: dict = {}       # slot -> _PrefillTask (FIFO)
         # installments: budget installments run; staged_requests:
         # requests staged.
@@ -815,6 +862,31 @@ class ServingEngine:
             # must read a memoized int, never trace an eval_shape
             # concurrently with the driver.
             self._prefill_pair_bytes()
+
+    def _compile_piece_shapes(self) -> None:
+        """Run every piece shape this engine may dispatch once, on a
+        zeroed batch-1 cache that is dropped, before the first request
+        is admitted (``_advance_prefills``): which of them a request
+        meets depends on where a step's budget falls in its prompt, so
+        traffic that warms one would leave another to compile under
+        the first request that meets it.  (Not at construction, which
+        runs nothing on the device and takes parameters that are
+        shapes alone.)"""
+        self._piece_shapes_ready = True
+        chunk = self.prefill_chunk
+        padded = np.zeros((1, self._piece_counts[-1] * chunk), np.int32)
+        with self._ctx():
+            for k in self._piece_counts:
+                t0 = time.monotonic()
+                self._run_target_piece(self._fresh_cache(1), padded,
+                                       chunk, 0, k * chunk, 0, k=k)
+                if self._draft_model is not None:
+                    self._run_draft_piece(
+                        self._fresh_cache(1, draft=True), padded, chunk,
+                        0, k)
+                logger.info("prefill call of %d x %d tokens ready in "
+                            "%.2f s", k, chunk, time.monotonic() - t0)
+        self._newest = None
 
     def _ctx(self):
         """Mesh + logical-rules context for device calls (no-op unsharded).
@@ -1502,7 +1574,8 @@ class ServingEngine:
                          else self._variables)
 
             def shape_fn(variables):
-                with quantized_inference():
+                with quantized_inference(), nn.intercept_methods(
+                        _ffn_passes):
                     return model.apply(
                         variables, jnp.zeros((batch, 1), jnp.int32),
                         mutable=["cache"])[1]["cache"]
@@ -1557,24 +1630,27 @@ class ServingEngine:
         return piece, -(-m // piece)
 
     def _run_target_piece(self, cache_1, padded, piece: int, i: int,
-                          m: int, seed: int, rng0: int = 0):
-        """Piece ``i`` of a target prefill — THE single source of the
-        per-piece layout/local-idx rule, shared by request admission
-        (``_advance_piece``) and prefix preload (``_prefill_tokens``).
-        ``rng0``: the first pick's rng counter (resume-from-token
-        admission continues a stream; fresh requests pick at 0)."""
-        toks = jnp.asarray(padded[:, i * piece:(i + 1) * piece])
-        # local_idx only matters on the piece holding the last real
+                          m: int, seed: int, rng0: int = 0, k: int = 1):
+        """Pieces ``i .. i + k - 1`` of a target prefill as one call —
+        THE single source of the per-piece layout/local-idx rule,
+        shared by request admission (``_advance_piece``) and prefix
+        preload (``_prefill_tokens``).  ``rng0``: the first pick's rng
+        counter (resume-from-token admission continues a stream; fresh
+        requests pick at 0)."""
+        toks = jnp.asarray(padded[:, i * piece:(i + k) * piece])
+        # local_idx only matters on the call holding the last real
         # token (the final one).
-        local = min(m - 1 - i * piece, piece - 1)
+        local = min(m - 1 - i * piece, k * piece - 1)
         return self._launch(self._prefill_piece, self._variables,
                             cache_1, toks, jnp.int32(max(local, 0)),
                             jnp.uint32(seed), jnp.int32(rng0))
 
-    def _run_draft_piece(self, d_cache_1, padded, piece: int, i: int):
-        """Piece ``i`` of a draft prefill (same piece grid as the
-        target's — both caches must hold identical row sets)."""
-        toks = jnp.asarray(padded[:, i * piece:(i + 1) * piece])
+    def _run_draft_piece(self, d_cache_1, padded, piece: int, i: int,
+                         k: int = 1):
+        """Pieces ``i .. i + k - 1`` of a draft prefill as one call
+        (same piece grid as the target's — both caches must hold
+        identical row sets)."""
+        toks = jnp.asarray(padded[:, i * piece:(i + k) * piece])
         return self._launch(self._draft_prefill_piece,
                             self._draft_variables, d_cache_1, toks)
 
@@ -2540,26 +2616,42 @@ class ServingEngine:
                                     jnp.int32(slot), jnp.int32(n))
             setattr(self, attr, grid)
 
-    def _advance_piece(self, slot: int, task: _PrefillTask) -> int:
-        """Run ONE installment of ``task`` — the next target (then
-        draft) prefill piece, plus the finalize/insert when it was the
-        last — and return its token cost.  A request's piece programs,
-        their order and their rng inputs depend on the request alone,
-        never on what other lanes do between them."""
+    def _advance_piece(self, slot: int, task: _PrefillTask,
+                       room: int = 1) -> int:
+        """Run ONE installment of ``task`` — its next target (then
+        draft) prefill pieces as one call, plus the finalize/insert
+        when they were the last — and return its token cost.  The call
+        covers ``k`` pieces: the largest count the engine compiled
+        (``_piece_counts``) that the task has left (of the target's,
+        then of the draft's: both caches hold the same rows and follow
+        the same rule) and the step has ``room`` for.  A request's rng
+        inputs and the mathematics of its rows depend on the request
+        alone; which PROGRAMS run its prompt depends on where the
+        steps' budgets fall in it, so two schedules agree on a
+        request's output to rounding (every attention row to the bit:
+        ``ops.attention.prefix_attention`` walks a call's queries a
+        piece at a time; the matmuls are row-wise), not by construction
+        to the bit."""
         draft = task.cursor >= task.n_pieces
         i = task.d_cursor if draft else task.cursor
-        real = min(task.piece, len(task.work) - i * task.piece)
-        self._step_counts["pieces"] += 1
+        k = self._piece_counts[-1]
+        if min(task.n_pieces - i, room) < k:
+            k = 1
+        real = min(k * task.piece, len(task.work) - i * task.piece)
+        self._step_counts["pieces"] += k
+        self._step_counts["piece_calls"] += 1
         if not draft:           # the draft's pieces re-run the same tokens
             self._step_counts["prefill_tokens"] += real
-        # The cache rows the piece's attention walks, by its own rule
-        # (``prefix_tiles_walked``): from row 0, whatever prefix was
-        # matched and gathered, to the end of the piece's last tile.
-        # Of them, those a learned selection counts over for its k-th
-        # score (``select_tiles_counted``: none where no query of the
-        # piece sees more than ``index_topk`` rows).
+        # The cache rows the attention of the call's LAST piece walks,
+        # by its own rule (``prefix_tiles_walked``: each piece of a
+        # call walks its own): from row 0, whatever prefix was matched
+        # and gathered, to the end of the piece's last tile.  Of them,
+        # those a learned selection counts over for its k-th score
+        # (``select_tiles_counted``: none where no query of the piece
+        # sees more than ``index_topk`` rows).
         tile = attention_ops.PREFIX_TILE
-        start = np.int64(len(task.prompt) - len(task.work) + i * task.piece)
+        start = np.int64(len(task.prompt) - len(task.work)
+                         + (i + k - 1) * task.piece)
         top = self._index_topk[draft]
         rows = min(self.cache_len, tile * int(
             attention_ops.prefix_tiles_walked(
@@ -2575,7 +2667,7 @@ class ServingEngine:
                 start, tile, self._window)))
         with self._ctx(), events.span(
                 "prefill/piece", rid=task.request_id,
-                piece=task.cursor + task.d_cursor,
+                piece=task.cursor + task.d_cursor, pieces=k,
                 n_pieces=task.n_pieces, tokens=real, rows=rows,
                 select_rows=select_rows, window_rows=window_rows,
                 cache_rows=self.cache_len):
@@ -2591,20 +2683,20 @@ class ServingEngine:
                     task.cache_1 = cache_1
                 self._poll_drained()
             with events.span("prefill/dispatch", rid=task.request_id,
-                             piece=task.cursor + task.d_cursor,
+                             piece=task.cursor + task.d_cursor, pieces=k,
                              tokens=real, rows=rows, draft=int(draft)):
                 if draft:
                     task.d_cache_1 = self._run_draft_piece(
                         task.d_cache_1, task.padded, task.piece,
-                        task.d_cursor)
+                        task.d_cursor, k)
                 else:
                     task.cache_1, task.first = self._run_target_piece(
                         task.cache_1, task.padded, task.piece,
                         task.cursor, len(task.work), task.seed,
-                        task.resume)
+                        task.resume, k)
             self._poll_drained()
             if not draft:
-                task.cursor += 1
+                task.cursor += k
                 if task.cursor == task.n_pieces:
                     # The host copy of the first token: the read blocks
                     # until this piece has run (behind the decode chunk
@@ -2629,29 +2721,41 @@ class ServingEngine:
                         del self._staging[slot]
                     elif self._draft_model is None:
                         self._finalize_prefill(slot, task)
-                return task.piece
-            # Target done, request unresolved: that was a draft piece.
-            task.d_cursor += 1
+                return k * task.piece
+            # Target done, request unresolved: that was a draft call.
+            task.d_cursor += k
             if task.d_cursor == task.n_pieces:
                 self._finalize_prefill(slot, task)
-            return task.piece
+            return k * task.piece
 
     def _advance_prefills(self) -> None:
         """Advance staged prefills by at most ``prefill_budget`` tokens
-        (default: one piece) in request-arrival order.  A decode chunk
-        is in flight AHEAD of this work on the device queue whenever a
-        lane is decoding, so decoding lanes lose no more cadence to it
-        than the budget.  With no lane decoding there is nobody to
-        delay, so the budget is waived and admission runs at full
-        speed."""
+        (default: one piece) in request-arrival order, the head
+        request's pieces first and as ONE call as far as the budget has
+        room for them (``_advance_piece``: the pieces of a step mostly
+        belong to one prompt, and one call reads every weight once).  A
+        decode chunk is in flight AHEAD of this work on the device
+        queue whenever a lane is decoding, so decoding lanes lose no
+        more cadence to it than the budget.  With no lane decoding
+        there is nobody to delay, so the budget is waived and admission
+        runs at full speed, in the largest calls the engine compiled."""
         self._stage_from_queue()
         if not self._staging:
             return
+        if not self._piece_shapes_ready:
+            self._compile_piece_shapes()
         decoding = any(s is not None for s in self._slot_states)
         spent = 0
         while self._staging:
             slot = next(iter(self._staging))
-            spent += self._advance_piece(slot, self._staging[slot])
+            task = self._staging[slot]
+            if not decoding:
+                room = self._piece_counts[-1]
+            elif self.prefill_budget is None:
+                room = 1
+            else:           # pieces until the budget is spent, >= 1
+                room = -(-(self.prefill_budget - spent) // task.piece)
+            spent += self._advance_piece(slot, task, room)
             with self._stats_lock:
                 self.prefill_stats["installments"] += 1
             if slot not in self._staging:

@@ -180,8 +180,31 @@ _EXACT_PER_LAYER_SETS = (
     "before_a_later_cell_was_appended")
 
 
+# Two tests of ``tests/benchmark/test_benchmark_step_stages.py`` assert
+# that the six metrics of the stage spans are the LAST six of
+# ``per_layer``.  The next PR that adds a per-layer metric appends it
+# after them (``prefill_pieces_per_call.*``) and may not edit that
+# file.  ``tests/benchmark/test_benchmark_piece_calls.py::test_the_
+# stage_metrics_read_as_before_the_call_counts_were_appended`` runs
+# both functions, every assertion of them, on the manifest with the
+# later entries taken off.  The same stop-gap as the three above,
+# strict for the same reason: the `benchmark` PR finds the six by name
+# and deletes this.
+_LAST_SIX_PER_LAYER = (
+    "test_benchmark_step_stages.py::"
+    "test_new_metrics_are_appended_and_found_by_name",
+    "test_benchmark_step_stages.py::test_the_cells_read_as_before_the_"
+    "stage_metrics_were_appended")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_LAST_SIX_PER_LAYER):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts its six metrics are the last of "
+                       "per_layer; run whole on the manifest as it was "
+                       "by test_the_stage_metrics_read_as_before_the_"
+                       "call_counts_were_appended", strict=True))
         if item.nodeid.endswith(_EXACT_PER_LAYER_SETS):
             item.add_marker(pytest.mark.xfail(
                 reason="asserts the exact set of per-layer metrics of "

@@ -460,8 +460,8 @@ def test_trace_report_prints_the_share_of_the_cache_prefill_walked(
         3, 6144, 24576, 0)
     assert mod.main([str(path)]) == 0
     out = capsys.readouterr().out
-    assert ("walked 6144 of 24576 cache rows in 3 pieces: share walked "
-            "0.250\n") in out
+    assert ("walked 6144 of 24576 cache rows in the last pieces of 3 "
+            "calls: share walked 0.250\n") in out
     assert "share selected" not in out      # no learned selection here
 
     # Beside it, where attention chooses its rows: what the choice of
@@ -489,8 +489,9 @@ def test_trace_report_prints_the_share_of_the_cache_prefill_walked(
         5, 10240, 40960, 3072)
     assert mod.main([str(path)]) == 0
     out = capsys.readouterr().out
-    assert ("walked 10240 of 40960 cache rows in 5 pieces: share walked "
-            "0.250, share the selection counted over 0.075\n") in out
+    assert ("walked 10240 of 40960 cache rows in the last pieces of 5 "
+            "calls: share walked 0.250, share the selection counted over "
+            "0.075\n") in out
     assert ("learned selection attended 12288 of 20000 rows scored in 2 "
             "decode steps: share selected 0.614") in out
 

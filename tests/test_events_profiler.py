@@ -265,9 +265,9 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     for s in steps:
         assert set(s[5]) == {"lanes", "positions", "kv_blocks",
                              "kv_table_blocks", "kv_window_blocks",
-                             "pieces", "prefill_tokens", "committed",
-                             "queued", "starved_ms", "drains",
-                             "away_ms"}
+                             "pieces", "piece_calls", "prefill_tokens",
+                             "committed", "queued", "starved_ms",
+                             "drains", "away_ms"}
         assert s[5]["starved_ms"] >= 0 and s[5]["away_ms"] >= 0
         assert s[5]["kv_window_blocks"] == 0     # no window layer here
         assert 0 <= s[5]["lanes"] <= 2
@@ -287,12 +287,18 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     assert {d[5]["spec_k"] for d in dispatches} == {
         kw.get("speculative_k", 0)}
     assert all(s[5]["positions"] >= s[5]["lanes"] for s in steps)
-    # A piece a span, the draft's included (it runs the target's grid
-    # again, for every request its first token did not resolve).
+    # A call of the piece program a span, the draft's included (it runs
+    # the target's grid again, for every request its first token did
+    # not resolve); a call runs one piece, or under a budget of two the
+    # two of one prompt that ride a step.
     pieces = [e for e in evs if e[0] == "prefill/piece"]
-    assert len(pieces) == sum(s[5]["pieces"] for s in steps) == (
-        11 if variant == "self-draft" else 6)
-    assert all(1 <= p[5]["tokens"] <= 4 for p in pieces)
+    assert len(pieces) == sum(s[5]["piece_calls"] for s in steps)
+    assert sum(p[5]["pieces"] for p in pieces) == sum(
+        s[5]["pieces"] for s in steps) == (
+            11 if variant == "self-draft" else 6)
+    assert {p[5]["pieces"] for p in pieces} == (
+        {1, 2} if variant == "budget-8" else {1})
+    assert all(1 <= p[5]["tokens"] <= 4 * p[5]["pieces"] for p in pieces)
     # The long prompt arrives at a step that began with a lane decoding
     # and is metered: one piece by default, two under a budget of 8.
     if "draft" not in variant:

@@ -223,11 +223,14 @@ def test_chunked_prefill_equals_whole_prefill_under_gmm(
 
 
 @pytest.mark.parametrize("rows, want", [
-    # 32 lanes x top 4 over 64 experts, and a 1024-token piece's rows:
-    # a row tile or less an expert, whole slices of an expert's kernel
+    # 32 lanes x top 4 over 64 experts, a 1024-token piece's rows and a
+    # prefill call's of four pieces: a few row tiles or less an expert,
+    # whole slices of an expert's kernel
     (128, (128, 2048, 512)), (4096, (128, 2048, 512)),
-    # a training batch's rows: megablox's own tiles, as before
-    (8192 + 128, (128, 128, 128))])
+    (16384, (128, 2048, 512)),
+    # a training batch's rows, more than four row tiles an expert:
+    # megablox's own tiles, as before
+    (4 * 8192 + 128, (128, 128, 128))])
 def test_grouped_matmul_tiles_follow_the_rows_an_expert_gets(
         monkeypatch, rows, want):
     from jax.experimental.pallas.ops.tpu.megablox import ops as mb

@@ -356,8 +356,8 @@ def test_interleave_parity_speculative(params):
 def test_interleave_budget_groups_installments(params):
     """An explicit ``prefill_budget`` spanning two pieces advances two
     pieces per step: the 12-token admission takes 2 installments (and
-    at most one decode chunk lands between the piece pairs) — the knob
-    actually meters tokens, not just pieces."""
+    one decode chunk lands between them) — the knob actually meters
+    tokens, not just pieces."""
     rng = np.random.default_rng(29)
     active = list(rng.integers(1, 200, 3))
     long_prompt = list(rng.integers(1, 200, 12))
@@ -374,11 +374,11 @@ def test_interleave_budget_groups_installments(params):
         out.update(eng.serve_step())
     tail = events[mark:]
     pieces = [i for i, e in enumerate(tail) if e == "p"]
-    assert len(pieces) == 3
     # Budget 8 = two 4-token pieces per step: pieces 1+2 run together,
-    # piece 3 next step — exactly one decode dispatch in between.
-    assert tail[pieces[0]:pieces[0] + 2] == ["p", "p"]
-    assert tail[pieces[1] + 1:pieces[2]].count("d") == 1, tail
+    # as ONE call of the piece program, piece 3 next step — exactly one
+    # decode dispatch in between.
+    assert len(pieces) == 2
+    assert tail[pieces[0] + 1:pieces[1]].count("d") == 1, tail
     assert out[a] == _ref(params, active, 12)
     assert out[b] == _ref(params, long_prompt, 4)
 

@@ -168,11 +168,12 @@ def step_stages(evs: list) -> dict:
 
 
 def prefill_walk(evs: list) -> tuple:
-    """(pieces, rows walked, rows their caches have, rows a learned
-    selection counted over) over the window's ``prefill/piece`` spans:
-    what the pieces' attention read (``rows``, the engine's account by
+    """(calls, rows walked, rows their caches have, rows a learned
+    selection counted over) over the window's ``prefill/piece`` spans,
+    one a call of one or several pieces, each read for its LAST piece:
+    what that piece's attention read (``rows``, the engine's account by
     ``ops.attention.prefix_tiles_walked``) of a whole ``cache_len`` a
-    piece (``cache_rows``), and what their choice of rows counted over
+    piece (``cache_rows``), and what its choice of rows counted over
     to find its k-th score (``select_rows``, by ``ops.attention.
     select_tiles_counted``: 0 in a piece that keeps every row it sees,
     in a model with no selection and in a trace from before the
@@ -895,7 +896,8 @@ def main(argv=None) -> int:
     pieces, walked, held, counted = prefill_walk(evs)
     if held:
         print(f"  prefill/piece attention walked {walked} of {held} "
-              f"cache rows in {pieces} pieces: share walked "
+              f"cache rows in the last pieces of {pieces} calls: share "
+              f"walked "
               f"{walked / held:.3f}"
               + (f", share the selection counted over "
                  f"{counted / held:.3f}" if counted else ""))
