@@ -1255,6 +1255,17 @@ def _scope_when(named: bool, scope: str):
     return jax.named_scope(scope) if named else contextlib.nullcontext()
 
 
+def _head_gate(x, heads: int, dtype):
+    """``sigmoid(x W_g)`` [B, S, heads]: the per-head output gate of the
+    module whose compact method calls this (its bias-free ``gate``
+    kernel, as ``MultiHeadAttention._gate``'s)."""
+    with jax.named_scope("attn/gate"):
+        return jax.nn.sigmoid(nn.Dense(
+            heads, use_bias=False, dtype=dtype, name="gate",
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("embed", "heads")))(x))
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2; GLM-4.7-Flash).
 
@@ -1297,7 +1308,9 @@ class LatentAttention(nn.Module):
     """
 
     num_heads: int
-    q_lora_rank: int
+    # None: the queries are one plain projection of the input (no query
+    # latent, no ``q_norm``); the learned selection needs the latent.
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_dim: int
     qk_rope_dim: int
@@ -1318,6 +1331,8 @@ class LatentAttention(nn.Module):
     index_heads: int = 0
     index_dim: int = 0
     index_topk: int = 0
+    # Per-head output gate, as ``MultiHeadAttention.out_gate``.
+    out_gate: bool = False
 
     @property
     def row_dim(self) -> int:
@@ -1354,13 +1369,19 @@ class LatentAttention(nn.Module):
     @jax.named_scope("attn/q_latent")
     def _queries(self, x, positions):
         """(q_nope [B,S,H,nope], rope(q_rope) [B,S,H,rope], the query
-        latent c_q [B,S,q_lora_rank] the indexer shares)."""
-        c_q = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
-                      name="q_norm")(
-            self._dense(self.q_lora_rank, ("embed", None), "q_a")(x))
-        q = self._dense(
-            self.num_heads * (self.qk_nope_dim + self.qk_rope_dim),
-            (None, "heads"), "q_b")(c_q)
+        latent c_q [B,S,q_lora_rank] the indexer shares, or None)."""
+        width = self.num_heads * (self.qk_nope_dim + self.qk_rope_dim)
+        if self.q_lora_rank is None:
+            if self.index_topk:
+                raise ValueError("the learned selection reads the query "
+                                 "latent: it needs q_lora_rank")
+            c_q = None
+            q = self._dense(width, ("embed", "heads"), "query")(x)
+        else:
+            c_q = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
+                          name="q_norm")(
+                self._dense(self.q_lora_rank, ("embed", None), "q_a")(x))
+            q = self._dense(width, (None, "heads"), "q_b")(c_q)
         q = q.reshape(*x.shape[:-1], self.num_heads, -1)
         q = nn.with_logical_constraint(
             q, ("batch", "length", "heads", "kv"))
@@ -1447,8 +1468,14 @@ class LatentAttention(nn.Module):
         k = jnp.concatenate([kv[..., :self.qk_nope_dim], k_r], axis=-1)
         return k, kv[..., self.qk_nope_dim:]
 
+    def _gate(self, x):
+        """[B, S, H] gate of the heads' outputs, or None."""
+        return (_head_gate(x, self.num_heads, self.dtype)
+                if self.out_gate else None)
+
     @jax.named_scope("attn/out")
-    def _out(self, o, features):
+    def _out(self, o, features, gate=None):
+        o = MultiHeadAttention._gated(o, gate)
         o = nn.with_logical_constraint(
             o, ("batch", "length", "heads", "kv"))
         y = self._dense(features, ("heads", "embed"), "out")(
@@ -1504,7 +1531,7 @@ class LatentAttention(nn.Module):
                 *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
                 causal=mask is None, mask=mask, segment_ids=segment_ids,
                 softmax_scale=self.softmax_scale).transpose(0, 2, 1, 3)
-        return self._out(o, x.shape[-1])
+        return self._out(o, x.shape[-1], self._gate(x))
 
     def _linear_step(self, x):
         """Append this call's rows to the linear cache and attend over
@@ -1561,7 +1588,7 @@ class LatentAttention(nn.Module):
                 keep=keep, softmax_scale=self.softmax_scale,
                 block=self.query_block,
             ).transpose(0, 2, 1, 3)
-        return self._out(o, x.shape[-1])
+        return self._out(o, x.shape[-1], self._gate(x))
 
     def _paged_step(self, x):
         """Per-slot decode over the paged latent pool, absorbed: the
@@ -1614,7 +1641,7 @@ class LatentAttention(nn.Module):
         with jax.named_scope("attn/absorb"):
             o = jnp.einsum("bqhc,chd->bqhd", o_lat.astype(self.dtype),
                            w[..., self.qk_nope_dim:])
-        return self._out(o, x.shape[-1])
+        return self._out(o, x.shape[-1], self._gate(x))
 
     def _paged_selected(self, x, c_q, positions, dest, q_cat, pool, table,
                         held, kernel):
@@ -1678,6 +1705,177 @@ class LatentAttention(nn.Module):
                            dtype=jnp.int32).reshape(lanes, top // bs),
                 chosen.reshape(lanes) - 1, cache_len=top, **kernel)
         return o.reshape(b, q_len, *o.shape[2:])
+
+
+def delta_log_decay(raw, rate, floor: float):
+    """The log of one step's decay of the delta rule's state, a value a
+    key channel, in ``(floor, 0)``: the bounded form ``floor *
+    sigmoid(rate * raw)`` (``raw`` the decay projection with its bias,
+    ``rate = exp(A_log)`` a head).  ``ops.attention.delta_rule_scan``
+    counts on the bound: a chunk's decays multiply inside float32's
+    range."""
+    return floor * jax.nn.sigmoid(rate * raw)
+
+
+class BiasParam(nn.Module):
+    """One ``bias`` parameter read as an array (``KernelParam``'s
+    sibling): a vector that is no Dense layer's, zero at init."""
+
+    shape: tuple
+    logical_axes: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param(
+            "bias", nn.with_logical_partitioning(nn.initializers.zeros,
+                                                 self.logical_axes),
+            self.shape)
+
+
+class DeltaAttention(nn.Module):
+    """Linear attention by the gated delta rule with a decay per key
+    channel (Kimi Delta Attention, arXiv:2510.26692): a layer whose
+    memory of the sequence is one ``[head_dim, head_dim]`` float32
+    state a head, whatever the context, and no positions.
+
+    Per token, with ``H`` heads of ``d = head_dim`` key and value
+    channels::
+
+        q~, k~, v~ = x Wq, x Wk, x Wv                  [H d] each
+        q, k, v = silu(conv4(q~)), silu(conv4(k~)), silu(conv4(v~))
+        q = l2norm_h(q) d^-1/2;  k = l2norm_h(k)
+        g = decay_floor * sigmoid(exp(A_h) (x Wf + b))  [H d], in (floor, 0)
+        beta = sigmoid(x Wb)                            [H]
+        S_ = diag(exp g) S;  S = S_ + beta k (v - S_^T k)^T;  o = S^T q
+        y = concat_h(gamma_h rmsnorm(o_h)) Wo,  gamma = sigmoid(x Wg)
+
+    ``conv4`` is a depthwise causal convolution over the last
+    ``conv_size`` rows (zeros before the sequence, no bias).  A call of
+    several rows runs the recurrence a chunk at a time
+    (``ops.attention.delta_rule_scan``), a call of one row as one step
+    (``ops.pallas_kernels.delta_state_step``: the kernel under a paged
+    engine's slot grid, its reference elsewhere).
+
+    ``decode=True`` keeps, in the "cache" collection, what the next
+    call needs: ``delta_state`` [B, H, d, d] float32, ``conv_tail`` [B,
+    conv_size - 1, 3 H d] (the last rows of q~|k~|v~ before the
+    convolution) and ``pad_rows`` [B], which the CALLER sets: how many
+    of this call's trailing rows are padding.  A padded row leaves the
+    state as it was (``g = 0``, ``beta = 0``) and the tail is the last
+    REAL rows: a positional cache forgives a row run twice or a pad row
+    written, a state does not.  Zero (a fresh cache, ``generate()``,
+    a decode step) says every row is real.
+    """
+
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+    decay_floor: float = -5.0
+    dtype: Dtype = jnp.float32
+    rms_epsilon: float = 1e-6
+    out_gate: bool = False
+    decode: bool = False
+    # The engine's slot grid (``paged_kv_blocks`` set): a decode step
+    # runs the fused state kernel where the paged kernels run fused.
+    paged_kv_blocks: int = 0
+
+    def _dense(self, features, axes, name, dtype=None, use_bias=False):
+        return nn.Dense(
+            features, use_bias=use_bias, dtype=dtype or self.dtype,
+            name=name, kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), axes))
+
+    @nn.compact
+    def __call__(self, x, *, positions=None, segment_ids=None):
+        if segment_ids is not None:
+            raise ValueError("the delta rule's state runs through a whole "
+                             "row: it does not take packed segments")
+        del positions                      # the layer has none
+        b, t, _ = x.shape
+        h, d, taps = self.num_heads, self.head_dim, self.conv_size
+        width = h * d
+        f32 = jnp.float32
+        if self.decode:
+            state = self.variable("cache", "delta_state", jnp.zeros,
+                                  (b, h, d, d), f32)
+            tail = self.variable("cache", "conv_tail", jnp.zeros,
+                                 (b, taps - 1, 3 * width), self.dtype)
+            pad = self.variable("cache", "pad_rows", jnp.zeros, (b,),
+                                jnp.int32)
+            s0, tail0, real = state.value, tail.value, t - pad.value
+        else:
+            s0 = jnp.zeros((b, h, d, d), f32)
+            tail0 = jnp.zeros((b, taps - 1, 3 * width), self.dtype)
+            real = jnp.full((b,), t, jnp.int32)
+
+        qkv = jnp.concatenate(
+            [self._dense(width, ("embed", "heads"), name)(x)
+             for name in ("query", "key", "value")], axis=-1)
+        with jax.named_scope("attn/linear/conv"):
+            taps_w = jnp.concatenate(
+                [KernelParam((taps, width), (None, "heads"),
+                             name=name)() for name in
+                 ("conv_q", "conv_k", "conv_v")], axis=-1).astype(f32)
+            ext = jnp.concatenate([tail0.astype(qkv.dtype), qkv], axis=1)
+            mixed = sum(taps_w[i] * ext[:, i:i + t].astype(f32)
+                        for i in range(taps))
+            mixed = nn.silu(mixed).reshape(b, t, 3, h, d)
+            # The rows the next call's convolution reaches back to: the
+            # last ``taps - 1`` REAL ones.
+            tail1 = jax.vmap(lambda e, r: jax.lax.dynamic_slice_in_dim(
+                e, r, taps - 1, axis=0))(ext, real)
+            q, k, v = mixed[:, :, 0], mixed[:, :, 1], mixed[:, :, 2]
+
+            def l2norm(u):
+                return u * jax.lax.rsqrt(
+                    jnp.sum(u * u, axis=-1, keepdims=True) + 1e-6)
+
+            q, k = l2norm(q) * d ** -0.5, l2norm(k)
+        with jax.named_scope("attn/linear/gates"):
+            # Float32 from the projection on: a step's decay is
+            # multiplied into the state for as long as the sequence is.
+            a = self._dense(width, ("embed", "heads"), "decay", f32,
+                            use_bias=True)(x.astype(f32)).reshape(b, t, h, d)
+            rate = jnp.exp(BiasParam((h,), ("heads",),
+                                     name="a_log")().astype(f32))
+            g = delta_log_decay(a, rate[:, None], self.decay_floor)
+            beta = jax.nn.sigmoid(self._dense(
+                h, ("embed", "heads"), "beta", f32)(x.astype(f32)))
+            live = jnp.arange(t)[None, :] < real[:, None]       # [B, T]
+            g = jnp.where(live[..., None, None], g, 0.0)
+            beta = jnp.where(live[..., None], beta, 0.0)
+        if t == 1:
+            from tensorflow_train_distributed_tpu.ops import (
+                pallas_kernels as pk,
+            )
+
+            with jax.named_scope("attn/linear/step"):
+                fused = bool(self.paged_kv_blocks) and fused_paged_ok()
+                s1, o = pk.delta_state_step(
+                    s0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    use_pallas=fused,
+                    interpret=fused and pk.fused_attn_interpret())
+                o = o[:, None]
+        else:
+            from tensorflow_train_distributed_tpu.ops.attention import (
+                delta_rule_scan,
+            )
+
+            with jax.named_scope("attn/linear/scan"):
+                o, s1 = delta_rule_scan(q, k, v, g, beta, s0,
+                                        dtype=self.dtype)
+        if self.decode:
+            with jax.named_scope("state_pool/write"):
+                state.value = s1
+                tail.value = tail1.astype(self.dtype)
+        o = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
+                    name="out_norm")(o)
+        if self.out_gate:
+            o = MultiHeadAttention._gated(o, _head_gate(x, h, self.dtype))
+        with jax.named_scope("attn/out"):
+            y = self._dense(x.shape[-1], ("heads", "embed"), "out")(
+                o.reshape(b, t, width))
+        return nn.with_logical_constraint(y, ("batch", "length", "embed"))
 
 
 class MlpBlock(nn.Module):

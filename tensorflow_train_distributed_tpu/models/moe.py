@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
+from typing import ClassVar, Optional
 
 import flax.linen as nn
 import jax
@@ -50,14 +50,38 @@ class AttnKind:
     """One kind of attention layer in a model whose layers differ
     (``MoeConfig.attn_period``): its query heads (the KV heads and the
     head size are the model's), its sliding window (None: every row),
-    and its rotary rule (``layers.apply_rope``): base, the share of a
-    head that is rotated, the scaling tuple."""
+    its rotary rule (``layers.apply_rope``): base, the share of a
+    head that is rotated, the scaling tuple.  What KIND of attention
+    it is, is the class's own ``kind``: ``"softmax"`` here (MHA/GQA
+    over cached keys and values), ``"latent"`` (``LatentKind``) or
+    ``"linear"`` (``LinearKind``); a class attribute and no field, so
+    that the five fields stay what readers of ``dataclasses.astuple``
+    compare (``benchmark/harness/serve_pattern.py``)."""
 
     num_heads: int
     window: Optional[int] = None
     rope_base: float = 10_000.0
     rotary_share: float = 1.0
     rope_scaling: Optional[tuple] = None
+    kind: ClassVar[str] = "softmax"
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentKind(AttnKind):
+    """A latent-attention layer of a period (``layers.LatentAttention``
+    at the model's latent sizes: ``kv_lora_rank`` and the rest);
+    ``window`` and ``rotary_share`` say nothing."""
+
+    kind: ClassVar[str] = "latent"
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearKind(AttnKind):
+    """A linear-attention layer of a period (``layers.DeltaAttention``):
+    a recurrent state a head and no positions; of the fields only
+    ``num_heads`` says anything."""
+
+    kind: ClassVar[str] = "linear"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,17 +196,30 @@ class MoeConfig:
     # ``rope_base`` and ``rope_scaling`` above then say nothing.  The
     # layers of such a model share no parameter shape (they are
     # unrolled here anyway).  None: every layer alike, from the fields
-    # above.  MHA/GQA only (no ``kv_lora_rank``).
+    # above.  With ``kv_lora_rank`` set the kinds are "latent" and
+    # "linear" alone (the latent sizes above are the latent layers').
     attn_period: Optional[tuple] = None
-    # Per-head output gate of the attention (``layers.
-    # MultiHeadAttention.out_gate``), every layer.
+    # Per-head output gate of the attention (``out_gate`` of the
+    # layer's module), every layer.
     attn_gate: bool = False
+    # A "linear" layer's short convolution and the floor of its log
+    # decay (``layers.DeltaAttention``).
+    linear_conv: int = 4
+    linear_decay_floor: float = -5.0
 
     def attn_kind(self, layer: int) -> Optional[AttnKind]:
         """Layer ``layer``'s kind, or None where layers do not differ."""
         if not self.attn_period:
             return None
         return self.attn_period[layer % len(self.attn_period)]
+
+    @property
+    def recurrent_layers(self) -> int:
+        """How many of the model's layers keep a recurrent state
+        instead of rows (kind "linear")."""
+        return sum(self.attn_kind(i).kind == "linear"
+                   for i in range(self.num_layers)) if self.attn_period \
+            else 0
 
     @property
     def attn_window(self) -> Optional[int]:
@@ -203,6 +240,12 @@ _LAGUNA_KINDS = (
              rope_scaling=("yarn", 128.0, 32.0, 1.0, 8192,
                            1.4852030263919618)),
 ) + (AttnKind(num_heads=72, window=512),) * 3
+
+
+#: Five linear layers to one latent one, counted from layer 0 (layer i
+#: is latent where (i + 1) % 6 == 0).
+_LING_KINDS = (LinearKind(num_heads=32),) * 5 + (
+    LatentKind(num_heads=32, rope_base=6_000_000.0),)
 
 
 MOE_PRESETS = {
@@ -308,6 +351,37 @@ MOE_PRESETS = {
                      rope_scaling=("yarn", 8.0, 32.0, 1.0, 16,
                                    1.2079441541679836)),
         ) + (AttnKind(num_heads=6, window=8),) * 3),
+    # Ling-3.0-flash (inclusionAI, ``bailing_hybrid``) at its published
+    # widths: delta-rule linear-attention layers (32 heads of 128, a
+    # decay per key channel, a convolution of 4) 5 : 1 with latent
+    # attention that has no query latent, per-head output gates on
+    # both, two leading SwiGLU layers, then 512 sigmoid-routed experts
+    # in 8 groups (4 stay, top 8, gates x 2.5) beside one shared.
+    # Deployments give ``experts_held`` and cut depth and vocabulary
+    # (benchmark/configs).
+    "ling3_flash": MoeConfig(
+        vocab_size=157_184, d_model=2560, num_layers=42, num_heads=32,
+        num_kv_heads=None, head_dim=128, ffn_size=768, num_experts=512,
+        top_k=8, max_positions=262_144, rope_base=6_000_000.0,
+        rms_epsilon=1e-6, dispatch="gmm", shared_expert_size=768,
+        norm_topk_prob=True, dense_layers=2, dense_ffn_size=6144,
+        router="sigmoid", routed_scaling=2.5, n_group=8, topk_group=4,
+        q_lora_rank=None, kv_lora_rank=512, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, attn_period=_LING_KINDS,
+        attn_gate=True),
+    # The same block at test size (float32): 4 heads of 16, one dense
+    # layer, then linear x 4, latent, linear; 8 experts in 4 groups.
+    "ling_tiny": MoeConfig(
+        vocab_size=256, d_model=64, num_layers=7, num_heads=4,
+        num_kv_heads=None, head_dim=16, ffn_size=48, num_experts=8,
+        top_k=2, max_positions=128, dtype=jnp.float32, remat=False,
+        dispatch="gmm", shared_expert_size=48, dense_layers=1,
+        dense_ffn_size=160, router="sigmoid", routed_scaling=2.5,
+        n_group=4, topk_group=2, rms_epsilon=1e-6, q_lora_rank=None,
+        kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+        attn_gate=True,
+        attn_period=(LinearKind(num_heads=4),) * 5 + (
+            LatentKind(num_heads=4, rope_base=6_000_000.0),)),
     # DeepSeek/Qwen-MoE-style: always-on shared expert beside the
     # routed ones (tiny test shape).
     "moe_tiny_shared": MoeConfig(vocab_size=256, d_model=64,
@@ -885,23 +959,42 @@ class MoeDecoderBlock(nn.Module):
         cfg = self.config
         h = L.RMSNorm(epsilon=cfg.rms_epsilon, dtype=cfg.dtype,
                       name="attn_norm")(x)
-        if cfg.kv_lora_rank:
-            attn = L.LatentAttention(
-                num_heads=cfg.num_heads, q_lora_rank=cfg.q_lora_rank,
-                kv_lora_rank=cfg.kv_lora_rank,
-                qk_nope_dim=cfg.qk_nope_dim, qk_rope_dim=cfg.qk_rope_dim,
-                v_head_dim=cfg.v_head_dim, dtype=cfg.dtype,
-                rope_base=cfg.rope_base, rope_scaling=cfg.rope_scaling,
-                rms_epsilon=cfg.rms_epsilon,
-                index_heads=cfg.index_heads, index_dim=cfg.index_dim,
-                index_topk=cfg.index_topk,
-                name="attention", decode=self.decode,
-                cache_len=self.cache_len or cfg.max_positions,
-                slot_decode=self.slot_decode,
-                paged_kv_blocks=self.paged_kv_blocks,
-                kv_block_size=self.kv_block_size,
-                query_block=self.query_block,
-            )(h, segment_ids=segment_ids, positions=positions)
+        kind = cfg.attn_kind(self.layer)
+        if kind is not None and kind.kind == "linear":
+            with jax.named_scope("attn/linear"):
+                attn = L.DeltaAttention(
+                    num_heads=kind.num_heads,
+                    head_dim=cfg.head_dim or cfg.d_model // kind.num_heads,
+                    conv_size=cfg.linear_conv,
+                    decay_floor=cfg.linear_decay_floor, dtype=cfg.dtype,
+                    rms_epsilon=cfg.rms_epsilon, out_gate=cfg.attn_gate,
+                    name="attention", decode=self.decode,
+                    paged_kv_blocks=self.paged_kv_blocks,
+                )(h, segment_ids=segment_ids, positions=positions)
+        elif cfg.kv_lora_rank:
+            # Every layer alike from the model's fields, or the latent
+            # layers of a period, whose scope names them for the trace.
+            with L._scope_when(kind is not None, "attn/latent"):
+                attn = L.LatentAttention(
+                    num_heads=kind.num_heads if kind else cfg.num_heads,
+                    q_lora_rank=cfg.q_lora_rank,
+                    kv_lora_rank=cfg.kv_lora_rank,
+                    qk_nope_dim=cfg.qk_nope_dim,
+                    qk_rope_dim=cfg.qk_rope_dim,
+                    v_head_dim=cfg.v_head_dim, dtype=cfg.dtype,
+                    rope_base=kind.rope_base if kind else cfg.rope_base,
+                    rope_scaling=(kind.rope_scaling if kind
+                                  else cfg.rope_scaling),
+                    rms_epsilon=cfg.rms_epsilon,
+                    index_heads=cfg.index_heads, index_dim=cfg.index_dim,
+                    index_topk=cfg.index_topk, out_gate=cfg.attn_gate,
+                    name="attention", decode=self.decode,
+                    cache_len=self.cache_len or cfg.max_positions,
+                    slot_decode=self.slot_decode,
+                    paged_kv_blocks=self.paged_kv_blocks,
+                    kv_block_size=self.kv_block_size,
+                    query_block=self.query_block,
+                )(h, segment_ids=segment_ids, positions=positions)
         else:
             attn = self._mha(h, segment_ids, positions)
         x = x + attn
@@ -984,9 +1077,18 @@ class MoeLmModel(nn.Module):
         cfg = self.config
         if segment_ids is not None and self.decode:
             raise ValueError("decode mode does not take packed segments")
-        if cfg.attn_period and cfg.kv_lora_rank:
-            raise ValueError("attn_period gives kinds of MHA/GQA layers; "
-                             "latent attention has one kind")
+        kinds = {k.kind for k in cfg.attn_period or ()}
+        if kinds - {"softmax", "latent", "linear"}:
+            raise ValueError(f"unknown kinds of attention layer {kinds}")
+        if ("latent" in kinds) != bool(cfg.attn_period
+                                       and cfg.kv_lora_rank) or (
+                cfg.kv_lora_rank and "softmax" in kinds):
+            raise ValueError(
+                "a period's \"latent\" layers take the model's latent "
+                "sizes (kv_lora_rank), and a model with latent sizes has "
+                "no MHA/GQA layer: its kinds are \"latent\" and "
+                f"\"linear\"; got {sorted(kinds)} with "
+                f"kv_lora_rank={cfg.kv_lora_rank}")
         if segment_ids is not None and positions is None:
             # Packed rows (llama-path contract): segment-masked attention
             # + RoPE positions restarting at each document boundary.

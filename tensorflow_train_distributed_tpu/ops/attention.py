@@ -21,6 +21,7 @@ softmax, so a piece at the head of a long cache pays for its own rows.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -767,3 +768,136 @@ def multihead_attention_kernel(
 
     args = (q, k, v) if segment_ids is None else (q, k, v, segment_ids)
     return per_shard(kernel, in_specs, qkv_spec)(*args)
+
+
+# ---------------------------------------------------------------------------
+# Gated delta rule (models.layers.DeltaAttention): the chunked scan
+# ---------------------------------------------------------------------------
+
+#: Rows of one chunk of ``delta_rule_scan``.  A row's decay is a value a
+#: key channel, so the rows of a chunk meet through products of
+#: ``exp(+L)`` and ``exp(-L)`` of the chunk's running log decay ``L``.
+#: With the log of one step's decay bounded below by -5 (the layer's
+#: bounded gate), 16 rows keep ``|L|`` under 80, inside float32's (and
+#: bfloat16's) range, whose ends are e^-87 and e^88; taken about the
+#: chunk's middle row the factors stay within e^-40 .. e^40.
+DELTA_CHUNK = 16
+
+
+def _unit_lower_inverse(m):
+    """``(I + m)^-1`` of strictly lower triangular ``m`` [..., n, n]:
+    ``m`` is nilpotent, so the inverse is the finite series ``sum_k
+    (-m)^k = (I - m)(I + m^2)(I + m^4)...``, a few small products."""
+    n = m.shape[-1]
+    eye = jnp.eye(n, dtype=m.dtype)
+    hi = jax.lax.Precision.HIGHEST
+    inv, power, span = eye - m, m, 2
+    while span < n:
+        power = jnp.matmul(power, power, precision=hi)
+        inv = jnp.matmul(inv, eye + power, precision=hi)
+        span *= 2
+    return inv
+
+
+def delta_rule_scan(q, k, v, g, beta, state, *, chunk: int = DELTA_CHUNK,
+                    dtype=None):
+    """The gated delta rule over a call's rows, a chunk at a time.
+
+    For each batch row and head, with ``S`` [dk, dv] starting at
+    ``state``::
+
+        S_ = diag(exp g_t) S;   S = S_ + beta_t k_t (v_t - S_^T k_t)^T
+        o_t = S^T q_t
+
+    ``q``, ``k`` [B, T, H, dk] (normalised and scaled by the caller),
+    ``v`` [B, T, H, dv], ``g`` [B, T, H, dk] float32 (log decay, in
+    ``(-88 / chunk, 0]``), ``beta`` [B, T, H], ``state`` [B, H, dk, dv]
+    float32.  A row with ``g = 0`` and ``beta = 0`` is the identity on
+    the state (a call's padding).  Returns ``(o [B, T, H, dv] float32,
+    state')``.
+
+    Within a chunk of C rows, with ``L_t`` the running sum of ``g`` and
+    ``u_t = beta_t (v_t - S_^T k_t)`` the row's correction, the
+    corrections solve a unit lower triangular system (the chunk's own
+    rows see each other through ``A_sr = sum_c k_sc k_rc exp(L_sc -
+    L_rc)``, r < s)::
+
+        (I + diag(beta) A) U = diag(beta) (V - (K e^L) S_0)
+        O = (Q e^L) S_0 + tril(Q e^L (K e^-L)^T) U
+        S_C = diag(e^{L_C}) S_0 + (K e^{L_C - L})^T U
+
+    Everything that does not read the state (``A``, the inverse, ``T
+    V``, ``T K e^L``) is made for all chunks at once; the scan over
+    chunks is four small products a chunk.  State and decays float32;
+    matmul operands in ``dtype`` (None: ``q``'s) into float32.
+    """
+    f32 = jnp.float32
+    b, t, h, dk = q.shape
+    mm = jnp.dtype(dtype or q.dtype)
+    n = -(-t // chunk)
+
+    def chunks(x):                       # [B, T, H, d] -> [n, B, H, C, d]
+        x = jnp.pad(x, ((0, 0), (0, n * chunk - t)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((b, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 2, 3), 1, 0)
+
+    ein = functools.partial(jnp.einsum, preferred_element_type=f32,
+                            precision=(jax.lax.Precision.HIGHEST
+                                       if mm == f32 else None))
+    qc, kc, vc = chunks(q.astype(f32)), chunks(k.astype(f32)), chunks(v)
+    run = jnp.cumsum(chunks(g.astype(f32)), axis=-2)     # L, inclusive
+    bc = chunks(beta.astype(f32)[..., None])[..., 0]     # [n, B, H, C]
+    last = run[..., -1:, :]
+    # Rows meet each other through exp(L_s - L_r), formed as two
+    # factors about the chunk's middle row (|L - L_mid| <= 40: no
+    # factor near the range's ends, where a small component of q or k
+    # would flush to zero); they meet S_0 through exp(L) itself.
+    mid = run - run[..., chunk // 2:chunk // 2 + 1, :]
+    k_in = (kc * jnp.exp(run)).astype(mm)                # meets S_0
+    q_in = (qc * jnp.exp(run)).astype(mm)
+    k_end = (kc * jnp.exp(last - run)).astype(mm)        # to the chunk's end
+    k_out = (kc * jnp.exp(-mid)).astype(mm)              # met by later rows
+    rows = jnp.arange(chunk)
+    among = ein("...sc,...rc->...sr", (kc * jnp.exp(mid)).astype(mm), k_out)
+    reads = jnp.where(
+        rows[:, None] >= rows[None, :],
+        ein("...sc,...rc->...sr", (qc * jnp.exp(mid)).astype(mm), k_out),
+        0.0)
+    solve = _unit_lower_inverse(
+        jnp.where(rows[:, None] > rows[None, :], among, 0.0)
+        * bc[..., None]) * bc[..., None, :]              # (I + bA)^-1 b
+    solve_m = solve.astype(mm)
+    u_free = ein("...sr,...rv->...sv", solve_m, vc.astype(mm))
+    u_state = ein("...sr,...rc->...sc", solve_m, k_in).astype(mm)
+
+    def step(s, xs):
+        u_free, u_state, q_in, reads, k_end, last = xs
+        sm = s.astype(mm)
+        u = (u_free - ein("bhsc,bhcv->bhsv", u_state, sm)).astype(mm)
+        o = (ein("bhsc,bhcv->bhsv", q_in, sm)
+             + ein("bhsr,bhrv->bhsv", reads, u))
+        s = (jnp.exp(last)[..., 0, :, None] * s
+             + ein("bhsc,bhsv->bhcv", k_end, u))
+        return s, o
+
+    state, o = jax.lax.scan(
+        step, state.astype(f32),
+        (u_free, u_state, q_in, reads.astype(mm), k_end, last))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)        # [B, n, C, H, dv]
+    return o.reshape(b, n * chunk, h, -1)[:, :t], state
+
+
+def delta_rule_recurrence(q, k, v, g, beta, state):
+    """``delta_rule_scan``'s definition, a token at a time (the oracle
+    of its tests and of the decode step's kernel)."""
+    from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
+        delta_state_step_reference,
+    )
+
+    def step(s, xs):
+        return delta_state_step_reference(s, *xs)
+
+    state, o = jax.lax.scan(
+        step, state.astype(jnp.float32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), state

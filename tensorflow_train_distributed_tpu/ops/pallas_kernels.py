@@ -1297,3 +1297,83 @@ def _moe_combine_bwd(tokens, dtype, interpret, res, g):
 
 
 moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Delta-rule state step (models.layers.DeltaAttention's decode step)
+# ---------------------------------------------------------------------------
+# (At the END of the file, as ``moe_combine`` above says of itself.)
+
+
+def delta_state_step_reference(state, q, k, v, g, beta):
+    """One token of the gated delta rule for every lane and head.
+    ``state`` [B, H, dk, dv] float32; ``q``, ``k``, ``g`` [B, H, dk]
+    (``g`` the log of the decay, a value a key channel, <= 0); ``v``
+    [B, H, dv]; ``beta`` [B, H].  Returns ``(state', o [B, H, dv])``::
+
+        S_ = diag(exp g) S;  S' = S_ + beta k (v - S_^T k)^T;  o = S'^T q
+    """
+    f32 = jnp.float32
+    q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
+    decayed = jnp.exp(g)[..., None] * state
+    seen = jnp.sum(k[..., None] * decayed, axis=-2)             # [B,H,dv]
+    new = decayed + k[..., None] * (beta[..., None] * (v - seen))[..., None, :]
+    return new, jnp.sum(q[..., None] * new, axis=-2)
+
+
+def _delta_state_kernel(s_ref, cols_ref, v_ref, beta_ref, s_out, o_ref, *,
+                        heads: int):
+    # One lane: its heads one after another, each head's [dk, dv] state
+    # read once and written once.  ``cols`` holds g | k | q of every
+    # head as COLUMNS ([dk, 3 x heads]): a value a key channel scales a
+    # row of the state, so it must lie along the sublanes.
+    for h in range(heads):
+        g = cols_ref[0, :, h:h + 1]                             # [dk, 1]
+        k = cols_ref[0, :, heads + h:heads + h + 1]
+        q = cols_ref[0, :, 2 * heads + h:2 * heads + h + 1]
+        decayed = jnp.exp(g) * s_ref[0, h]                      # [dk, dv]
+        seen = jnp.sum(k * decayed, axis=0, keepdims=True)      # [1, dv]
+        write = beta_ref[0, h:h + 1, :] * (v_ref[0, h:h + 1, :] - seen)
+        new = decayed + k * write
+        s_out[0, h] = new
+        o_ref[0, h:h + 1, :] = jnp.sum(q * new, axis=0, keepdims=True)
+
+
+def delta_state_step(state, q, k, v, g, beta, *,
+                     use_pallas: Optional[bool] = None,
+                     interpret: bool = False):
+    """``delta_state_step_reference`` as one kernel: a grid step a
+    lane, the lane's state (heads x dk x dv float32) read once, decayed,
+    corrected and written once IN PLACE (the output aliases ``state``),
+    the step's rows beside it.  Bytes a call: 2 x state + the rows."""
+    if not _use_pallas(use_pallas) and not interpret:
+        return delta_state_step_reference(state, q, k, v, g, beta)
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads, dk, dv = state.shape
+    f32 = jnp.float32
+    cols = jnp.concatenate(
+        [t.astype(f32).transpose(0, 2, 1) for t in (g, k, q)],
+        axis=-1)                                      # [B, dk, 3H]: g|k|q
+    beta_rows = jnp.broadcast_to(beta.astype(f32)[..., None],
+                                 (lanes, heads, dv))
+
+    def lane(*block):
+        return pl.BlockSpec((1,) + block,
+                            lambda i: (i,) + (0,) * len(block))
+
+    new, o = pl.pallas_call(
+        functools.partial(_delta_state_kernel, heads=heads),
+        name="delta_state_step",
+        grid=(lanes,),
+        in_specs=[lane(heads, dk, dv), lane(dk, 3 * heads),
+                  lane(heads, dv), lane(heads, dv)],
+        out_specs=[lane(heads, dk, dv), lane(heads, dv)],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, f32),
+                   jax.ShapeDtypeStruct((lanes, heads, dv), f32)],
+        input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=4 * heads * dk * dv * 4 + (8 << 20)),
+        interpret=interpret,
+    )(state, cols, v.astype(f32), beta_rows)
+    return new, o

@@ -137,13 +137,17 @@ CONTRACT = {
     # the live lanes; where some layers see a sliding window, the
     # blocks one such layer's walk of its rings reaches over all slots
     # (kv_window_blocks: the kernel's rule from the window's first
-    # block on); starved_ms / drains: the milliseconds, and the times,
+    # block on); kv_bytes: kv_blocks in bytes, over the layers whose
+    # blocks the allocator hands out; where some layers keep a
+    # recurrent state and no rows, the bytes of state the live lanes
+    # hold over those layers (state_bytes); starved_ms / drains: the milliseconds, and the times,
     # the device's queue was known empty while the engine had work
     # (ServingEngine._launch, _poll_drained); away_ms: from the
     # previous step's exit to this one's entry (the caller's pass)
     "engine/step": ("lanes positions kv_blocks kv_table_blocks "
-                    "kv_window_blocks pieces piece_calls "
-                    "prefill_tokens committed queued experts_hit "
+                    "kv_window_blocks kv_bytes state_bytes pieces "
+                    "piece_calls prefill_tokens committed queued "
+                    "experts_hit "
                     "expert_load_cv experts_held routed_here "
                     "rows_scored rows_selected "
                     "starved_ms drains away_ms"),
@@ -181,7 +185,9 @@ CONTRACT = {
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",
     # pool: "full" (blocks of the allocator's pool, a lane's whole
-    # context) or "window" (the slot's ring in each window layer)
+    # context), "window" (the slot's ring in each window layer) or
+    # "state" (the slot's recurrent state in each linear layer: no
+    # blocks)
     "kv/alloc": "rid blocks shared pool",
     "kv/export": "tokens",
     "kv/install": "tokens",

@@ -104,6 +104,27 @@ over them, so such an engine shares no prefix: no radix match,
 ``preload_prefix`` raises, KV export ships nothing (the receiver
 prefills).
 
+**Recurrent layers beside layers of rows** (a ``MoeConfig`` whose
+``attn_period`` names "linear" kinds: ``layers.DeltaAttention``): a
+third kind of cache in the one slot grid, a leaf that holds no rows.  A
+linear layer keeps, a lane, one float32 state ``[heads, d, d]`` and the
+last rows its short convolution reaches back to (``_STATE_LEAVES``),
+whatever the context: ``[slots, ...]`` in the grid, ``[1, ...]`` in the
+batch-1 prefill cache, which carries them from call to call.
+``_paged_insert`` writes a slot's state and tail WHOLE from the batch-1
+cache, so nothing of the last occupant survives; no table maps them and
+no length walks them.  A positional cache forgives a row that is run
+twice or a pad row that is written; a state does not: ``_prefill_piece``
+tells the layers how many of a call's trailing rows are padding
+(``pad_rows``) and they leave the state where the last REAL token left
+it.  Every real row of a prompt runs once (pieces tile ``work`` without
+overlap) and every decoded token once (the carry chains chunks; a
+refilled slot's first input is spliced from the host).  The state at a
+prefix's end is not kept, so such an engine shares no prefix (no radix
+match, ``preload_prefix`` raises, KV export ships nothing and install
+installs nothing: the receiver prefills), and it refuses a draft model
+and a mesh.
+
 **Fused paged attention** (TPU): the paged decode read is one Pallas
 kernel (``ops.pallas_kernels.paged_attention``) that attends through
 the block table; the dense per-lane copy ``paged_kv_gather`` would
@@ -255,6 +276,12 @@ _ROW_LEAVES = {"key_pool": ("key_cache", 1, 2),
                "kv_pool_scales": ("kv_scales", 1, 1),
                "latent_pool": ("latent_cache", 1, 1),
                "index_pool": ("index_cache", 1, 1)}
+#: Cache leaves that hold a lane's recurrent state and no rows
+#: (``layers.DeltaAttention``): [..., B, *state] in the grid and in the
+#: batch-1 cache alike, the batch axis first of the leaf's own dims;
+#: an insert copies them whole.  ``pad_rows`` is the call's argument to
+#: those layers (``_prefill_piece``), not state.
+_STATE_LEAVES = {"delta_state": 3, "conv_tail": 2}
 _LINEAR_ROW_DIMS = {lin: dims for lin, _, dims in _ROW_LEAVES.values()}
 _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 
@@ -282,12 +309,18 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: the piece programs launched for them: one call runs one piece, or
 #: the budget's worth of consecutive pieces of one prompt
 #: (``_advance_piece``, ``_piece_counts``).
+#: ``kv_bytes``: ``kv_blocks`` in bytes, over the layers whose blocks
+#: the allocator hands out (``bytes_per_block``; a window layer's rings
+#: apart).  ``state_bytes``: the bytes of recurrent state the live
+#: lanes hold over the model's linear layers (lanes x a lane's state
+#: and tail x layers; 0 for a model without them), beside them.
 #: ``starved_ms`` / ``drains``: the milliseconds, and the times, the
 #: device's queue was known empty while the engine had work
 #: (``_launch``, ``_poll_drained``); ``away_ms``: from the previous
 #: step's exit to this one's entry, the caller's pass between them.
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
-                "kv_window_blocks", "pieces", "piece_calls",
+                "kv_window_blocks", "kv_bytes", "state_bytes", "pieces",
+                "piece_calls",
                 "prefill_tokens", "committed", "starved_ms", "drains",
                 "away_ms")
 
@@ -455,12 +488,30 @@ class ServingEngine:
                 "yet (a verify block's rollback over a ring is untested)")
         if getattr(draft_config, "attn_window", None) is not None:
             raise ValueError("a draft with window layers is not served")
+        # Layers that keep a recurrent state a lane and no rows
+        # (``MoeConfig.recurrent_layers``; 0: none).
+        self._state_layers = getattr(config, "recurrent_layers", 0)
+        if self._state_layers or getattr(draft_config,
+                                         "recurrent_layers", 0):
+            if draft_config is not None:
+                raise ValueError(
+                    "speculative decoding (draft_config) beside recurrent "
+                    "layers is not served: a rejected draft token has "
+                    "already moved the state, and no snapshot is kept to "
+                    "roll it back to")
+            if mesh is not None:
+                raise ValueError(
+                    "sharded serving (mesh=) beside recurrent layers is "
+                    "not served: the state step is a single-device kernel "
+                    "and the state's sharding rules are not written")
         # Whether requests may share cached prefix rows (the radix
         # index, preloaded pairs, KV handoff).  Not where routing
-        # depends on the prefill's length, and not beside window
-        # layers, whose rows behind the window are gone.
+        # depends on the prefill's length, not beside window layers,
+        # whose rows behind the window are gone, and not beside
+        # recurrent layers, whose state at a prefix's end is not kept.
         self._share_prefix = (not self._exact_prefill
-                              and self._window is None)
+                              and self._window is None
+                              and not self._state_layers)
         # Chunked prefill: long prompts run through the SAME per-piece
         # program in ``prefill_chunk``-token pieces (the decode cache
         # appends multi-token blocks at any position), bounding prefill
@@ -826,6 +877,16 @@ class ServingEngine:
         # scrape thread reads a plain int.  The --kv-pool-blocks
         # oversizing lever is sized against this number.
         self._kv_pool_bytes = self._kv_ring_bytes = 0
+        # Bytes of recurrent state the grid pins (every slot, every
+        # linear layer: state and convolution tail) and a lane's share
+        # of them; no rows, so no part of ``kv_pool_bytes``.
+        self._state_pool_bytes = sum(
+            int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
+            for p, leaf in jax.tree_util.tree_flatten_with_path(
+                self._cache_struct(self.slots, grid=True))[0]
+            if getattr(p[-1], "key", "") in _STATE_LEAVES
+        ) if self._state_layers else 0
+        self._state_lane_bytes = self._state_pool_bytes // self.slots
         if self.paged:
             def _pool_bytes(struct, ringed: bool):
                 """Bytes of the row-holding leaves of one kind: a
@@ -960,6 +1021,14 @@ class ServingEngine:
         position, so the continuation is the one the uninterrupted run
         would have sampled.
         """
+        if self._state_layers:
+            # Rows past the call's last real one must not move a
+            # recurrent state: tell its layers how many there are.
+            pad = tokens_1xl.shape[1] - 1 - local_idx
+            cache = jax.tree_util.tree_map_with_path(
+                lambda p, leaf: jnp.full_like(leaf, pad)
+                if getattr(p[-1], "key", "") == "pad_rows" else leaf,
+                cache)
         with quantized_inference():
             logits, vs = self._prefill_model.apply(
                 dict(variables, cache=cache), tokens_1xl,
@@ -1119,6 +1188,12 @@ class ServingEngine:
             name = getattr(path[-1], "key", "")
             if name == "index":
                 return pb.at[..., slot].set(true_len)
+            if name == "pad_rows":
+                return pb
+            if name in _STATE_LEAVES:
+                return jax.lax.dynamic_update_slice_in_dim(
+                    pb, p1.astype(pb.dtype), slot,
+                    axis=pb.ndim - (1 + _STATE_LEAVES[name]))
             return jax.lax.dynamic_update_slice_in_dim(
                 pb, p1, slot,
                 axis=pb.ndim - (2 + _LINEAR_ROW_DIMS[name]))
@@ -1257,9 +1332,19 @@ class ServingEngine:
         there)."""
         cache = self._scatter_rows_tree(cache, cache_1, table_row,
                                         start, true_len, slot)
+        flat_1 = {self._path_key(p): leaf for p, leaf
+                  in jax.tree_util.tree_flatten_with_path(cache_1)[0]}
 
         def pin(path, leaf):
             name = getattr(path[-1], "key", "")
+            if name in _STATE_LEAVES:
+                # A lane's recurrent state and tail, whole: the leaf of
+                # the same path in the batch-1 cache.
+                with jax.named_scope("state_pool/write"):
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        leaf, flat_1[self._path_key(path)].astype(
+                            leaf.dtype), slot,
+                        axis=leaf.ndim - (1 + _STATE_LEAVES[name]))
             if name == "block_table":
                 return leaf.at[..., slot, :].set(table_row)
             if name == "window_table":
@@ -1697,6 +1782,12 @@ class ServingEngine:
                 "prefix caching needs length-independent routing; "
                 "dense-dispatch MoE prefills at the exact prompt length "
                 "(dispatch='gmm' supports prefix caching)")
+        if self._state_layers:
+            raise ValueError(
+                "this model has recurrent layers: the state at a "
+                "prefix's end is not kept (only a lane's newest is), so "
+                "no prefix is shared between requests: preload_prefix "
+                "and the radix index are off")
         if self._window is not None:
             raise ValueError(
                 f"this model has window layers (window {self._window}): "
@@ -2347,6 +2438,14 @@ class ServingEngine:
         against this."""
         return self._kv_pool_bytes
 
+    def state_pool_bytes(self) -> int:
+        """Device bytes of recurrent state the slot grid pins: every
+        slot's state and convolution tail in every linear layer (0 for
+        a model without them).  Constant per engine, and apart from
+        ``kv_pool_bytes()``: these are no rows, no table maps them and
+        no length walks them."""
+        return self._state_pool_bytes
+
     def _prefill_pair_bytes(self) -> int:
         """Bytes of one batch-1 prefill cache pair (target + draft) —
         the marginal device allocation an admission mints; memoized
@@ -2552,6 +2651,11 @@ class ServingEngine:
                 events.instant("kv/alloc", rid=rid,
                                blocks=self._ring_blocks, shared=0,
                                pool="window")
+            if self._state_layers:
+                # The slot's own state in each linear layer: claimed
+                # with the slot, no blocks, nothing to refuse.
+                events.instant("kv/alloc", rid=rid, blocks=0, shared=0,
+                               pool="state")
             pre_len, pre_pair = self._admission_match(kv, prompt)
         else:
             pre_len, pre_pair = self._match_prefix(prompt, touch=True)
@@ -2981,8 +3085,9 @@ class ServingEngine:
         call's ``spec_k + 1`` queries reach, one for an idle slot) over
         all slots, of the ``kv_table_blocks`` their tables have;
         ``kv_window_blocks`` the same rule from a window's first block
-        on, for one window layer; all 0 on a linear cache."""
-        kv_blocks = kv_table_blocks = kv_window_blocks = 0
+        on, for one window layer; ``kv_bytes`` what ``kv_blocks`` are in
+        bytes; all 0 on a linear cache."""
+        kv_blocks = kv_table_blocks = kv_window_blocks = kv_bytes = 0
         if self.paged:
             kv_table_blocks = self.slots * self._kv_nblk_lane
             lengths = np.asarray(held, np.int64)
@@ -2994,10 +3099,12 @@ class ServingEngine:
                     paged_blocks_walked(
                         lengths, spec_k + 1, self.kv_block_size,
                         self._kv_nblk_lane, self._window).sum())
+            kv_bytes = kv_blocks * self._kv_pool.bytes_per_block
         self._step_counts.update(
             lanes=len(held), positions=sum(held), kv_blocks=kv_blocks,
             kv_table_blocks=kv_table_blocks,
-            kv_window_blocks=kv_window_blocks)
+            kv_window_blocks=kv_window_blocks, kv_bytes=kv_bytes,
+            state_bytes=len(held) * self._state_lane_bytes)
 
     def _count_sown(self, sown) -> None:
         """``engine/step``'s account of what the layers of a harvested

@@ -142,6 +142,9 @@ import benchmark.harness.serve_share  # noqa: E402,F401
 # The same stop-gap, for the family of a decoder whose attention layers
 # differ by a pattern (``serve_pattern.py`` registers "moe_pattern").
 import benchmark.harness.serve_pattern  # noqa: E402,F401
+# The same stop-gap, for the family of a decoder with linear-attention
+# layers beside latent ones (``serve_hybrid.py`` registers "moe_hybrid").
+import benchmark.harness.serve_hybrid  # noqa: E402,F401
 
 
 # ``tests/benchmark/test_benchmark_deepseek_v32.py::
@@ -197,8 +200,37 @@ _LAST_SIX_PER_LAYER = (
     "stage_metrics_were_appended")
 
 
+# ``tests/benchmark/test_benchmark_piece_calls.py::test_the_stage_
+# metrics_read_as_before_the_call_counts_were_appended`` asserts that
+# its two metrics (``prefill_pieces_per_call.*``) are the LAST two of
+# ``per_layer``.  The next PR that adds per-layer metrics appends them
+# after those (the ``.hybrid`` readers of ``ling3-flash-1chip.reason-
+# docs``) and may not edit that file.  ``tests/benchmark/
+# test_benchmark_ling.py::test_the_call_counts_read_as_before_the_
+# hybrid_metrics_were_appended`` runs the same function, every
+# assertion of it (and through it the two of ``test_benchmark_step_
+# stages.py`` that it runs), on the manifest as it was before the
+# later cell.  The same stop-gap as the four above, strict for the
+# same reason: the `benchmark` PR finds the two by name and deletes
+# this.
+_LAST_TWO_PER_LAYER = (
+    "test_benchmark_piece_calls.py::test_the_stage_metrics_read_as_"
+    "before_the_call_counts_were_appended",
+    # ... and pins the exact cells of one of the two, to whose list the
+    # later cell's name is appended
+    "test_benchmark_piece_calls.py::test_the_metric_is_declared_for_"
+    "the_cells_that_count_calls[prefill_pieces_per_call.serve]")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_LAST_TWO_PER_LAYER):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts its two metrics are the last of "
+                       "per_layer (or the exact cells of one of them); "
+                       "run whole on the manifest as it was "
+                       "by test_the_call_counts_read_as_before_the_"
+                       "hybrid_metrics_were_appended", strict=True))
         if item.nodeid.endswith(_LAST_SIX_PER_LAYER):
             item.add_marker(pytest.mark.xfail(
                 reason="asserts its six metrics are the last of "

@@ -423,6 +423,21 @@ def test_trace_report_prints_the_kv_walk_live_share(tmp_path, capsys):
     assert "a window layer read 2144 of the 20000 blocks" in out
     assert "window share 10.7%" in out
 
+    # Layers that keep a recurrent state: its bytes beside the rows'.
+    hybrid = Recorder(capacity=8)
+    for lanes, blocks in ((60, 9000), (64, 11000)):
+        with hybrid.span("engine/step") as step:
+            step.set(lanes=lanes, positions=160_000, kv_blocks=blocks,
+                     kv_table_blocks=81920, kv_bytes=blocks * 20480,
+                     state_bytes=lanes * 1_000_000)
+    hybrid.save(str(path))
+    assert mod.kv_cache_summary(
+        mod.load_events(str(path)))["state_bytes"] == 124_000_000
+    assert mod.main([str(path)]) == 0
+    out = capsys.readouterr().out
+    assert "recurrent state    124000000 bytes" in out
+    assert "beside 409600000 bytes of rows walked: state share 23.2%" in out
+
     linear = Recorder(capacity=8)
     with linear.span("engine/step") as step:
         step.set(lanes=2, positions=9, kv_blocks=0, kv_table_blocks=0)

@@ -265,15 +265,19 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     for s in steps:
         assert set(s[5]) == {"lanes", "positions", "kv_blocks",
                              "kv_table_blocks", "kv_window_blocks",
-                             "pieces", "piece_calls", "prefill_tokens",
-                             "committed", "queued", "starved_ms",
-                             "drains", "away_ms"}
+                             "kv_bytes", "state_bytes", "pieces",
+                             "piece_calls", "prefill_tokens",
+                             "committed", "queued",
+                             "starved_ms", "drains", "away_ms"}
         assert s[5]["starved_ms"] >= 0 and s[5]["away_ms"] >= 0
         assert s[5]["kv_window_blocks"] == 0     # no window layer here
+        assert s[5]["state_bytes"] == 0          # no recurrent layer
         assert 0 <= s[5]["lanes"] <= 2
         # A dispatch reads a block or more of every slot, of the 2
         # slots x 2 blocks (cache 32, block 16) their tables have; a
         # step without one reads none.
+        assert s[5]["kv_bytes"] == (
+            s[5]["kv_blocks"] * eng._kv_pool.bytes_per_block)
         if s[5]["lanes"]:
             assert 2 <= s[5]["kv_blocks"] <= s[5]["kv_table_blocks"] == 4
         else:

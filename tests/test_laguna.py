@@ -345,27 +345,45 @@ def test_the_tile_walk_with_a_first_tile_equals_masked_attention(start):
                                atol=2e-6, rtol=0)
 
 
-def test_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(params):
+def _ling_share():
+    """The same test for the family whose router chooses within groups
+    (``ling_tiny``: 8 experts in 4 groups of 2, 2 groups stay): its
+    preset, seeded weights, reference and file keys."""
+    import test_ling
+
+    plain = weights.make_params(
+        serve_family.moe_param_shapes(test_ling.TINY), SEED, jnp.float32)
+    return test_ling.TINY, plain, test_ling.reference, test_ling.cfg_file_of
+
+
+SHARES = {"laguna": lambda params: (TINY, params, reference, cfg_file_of),
+          "ling": lambda params: _ling_share()}
+
+
+@pytest.mark.parametrize("family", sorted(SHARES))
+def test_four_shares_of_an_expert_layer_add_up_to_the_uncut_layer(
+        params, family):
     """Ranks 0-3 of four chips hold experts [0,2) [2,4) [4,6) [6,8) of
     the router's 8.  What the program's layer gives for each share,
     with the shared expert (which every chip computes alike) counted
     once, adds up to the reference's uncut layer."""
-    layer = params["layer_2"]["moe"]
+    tiny, tree, ref, file_of = SHARES[family](params)
+    layer = tree["layer_2"]["moe"]
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.standard_normal((1, 24, 64)), jnp.float32)
-    cfg_file = cfg_file_of(TINY)
-    whole = np.asarray(reference.expert_layer(x[0], layer, cfg_file))
-    shared = np.asarray(reference.swiglu(x[0], layer["shared_mlp"]))
+    cfg_file = file_of(tiny)
+    whole = np.asarray(ref.expert_layer(x[0], layer, cfg_file))
+    shared = np.asarray(ref.swiglu(x[0], layer["shared_mlp"]))
     total = np.zeros_like(whole)
     for rank in range(4):
-        cfg = dataclasses.replace(TINY, experts_held=2,
+        cfg = dataclasses.replace(tiny, experts_held=2,
                                   experts_offset=2 * rank)
         mine = dict(layer, experts=jax.tree.map(
             lambda kernel: kernel[2 * rank:2 * rank + 2], layer["experts"]))
         y = np.asarray(moe.MoEMlpBlock(cfg).apply({"params": mine}, x)[0])
-        ref = np.asarray(reference.expert_layer(
+        want = np.asarray(ref.expert_layer(
             x[0], mine, dict(cfg_file, experts_offset=2 * rank)))
-        np.testing.assert_allclose(y, ref, atol=2e-5, rtol=0)
+        np.testing.assert_allclose(y, want, atol=2e-5, rtol=0)
         total += y - shared
     np.testing.assert_allclose(total + shared, whole, atol=5e-5, rtol=0)
     # and no share is the whole: the experts elsewhere add something

@@ -170,6 +170,18 @@ def _paged_index(q_len):
          ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
 
 
+def _delta_step():
+    """Ling-3.0-flash's state step at the cell's slot grid: 64 lanes of
+    32 heads, each a 128 x 128 float32 state read and written in place
+    (2 MB a lane in, 2 MB out, twice for the double buffers)."""
+    lanes, heads, d = 64, 32, 128
+    row = ((lanes, heads, d), jnp.float32)
+    return (lambda s, q, k, v, g, b: pk.delta_state_step(
+        s, q, k, v, g, b, use_pallas=True),
+        (((lanes, heads, d, d), jnp.float32), row, row, row, row,
+         ((lanes, heads), jnp.float32)))
+
+
 # (heads, kv_heads, head_dim, block_size): llama_350m's layout at the
 # engine's default block size, qwen25_7b's GQA layout, and a full layer
 # of Laguna-S-2.1 (6 queries a KV head).
@@ -186,6 +198,7 @@ CASES = {
     "flash-fwd": lambda: _flash(False),
     "flash-grad": lambda: _flash(True),
 }
+CASES["delta_state_step-l64h32d128"] = _delta_step
 for _q in (1, 3):
     for _h in (48, 72):
         CASES[f"paged_ring-h{_h}kv8d128-w512-q{_q}"] = (
@@ -274,6 +287,24 @@ def test_prefix_attention_compiles_to_a_loop_with_tile_sized_scores(
             < heads * q_len * cache_len * 4)
     assert f"{q_len},{cache_len}]" not in text
     assert f"{heads},{q_len},{attention.PREFIX_TILE}]" in text
+
+
+def test_delta_scan_compiles_to_a_loop_over_chunks(v5e):
+    """``delta_rule_scan`` at ``ling3-flash-1chip``'s largest call (4096
+    rows of 32 heads of 128, bf16 operands): one loop over the call's
+    256 chunks of 16 rows whose carry is the float32 state, and what it
+    makes for all chunks at once stays under 1.5 GB."""
+    one_chip = SingleDeviceSharding(v5e[0])
+    t, h, d = 4096, 32, 128
+    rows = ((1, t, h, d), BF16)
+    shapes = (rows, rows, rows, ((1, t, h, d), jnp.float32),
+              ((1, t, h), jnp.float32), ((1, h, d, d), jnp.float32))
+    compiled = jax.jit(attention.delta_rule_scan).lower(*(
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+        for s, dt in shapes)).compile()
+    text = compiled.as_text()
+    assert " while(" in text and f"f32[1,{h},{d},{d}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
 @pytest.mark.parametrize("case", ["rms_norm-grad", "fused_ce-v32000-grad",

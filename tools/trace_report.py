@@ -220,12 +220,16 @@ def kv_cache_summary(evs: list) -> dict:
     ``kv_blocks`` and ``kv_table_blocks``: the live share), and, where
     some layers see a sliding window, the blocks one such layer's walk
     of its rings read (``kv_window_blocks``: the window layers' share
-    of what the lanes hold).  Empty dict when the window has no
-    paged-KV events (linear cache)."""
+    of what the lanes hold), and, where some layers keep a recurrent
+    state and no rows, the bytes of state the steps' live lanes held
+    beside the bytes of the rows walked (``state_bytes``, ``kv_bytes``,
+    summed like the blocks).  Empty dict when the
+    window has no paged-KV events (linear cache)."""
     out = {"prefix_hits": 0, "prefix_hit_tokens": 0,
            "evicted_blocks": 0, "refused_admissions": 0,
            "fused_attn_dispatches": 0, "kv_blocks": 0,
-           "kv_table_blocks": 0, "kv_window_blocks": 0}
+           "kv_table_blocks": 0, "kv_window_blocks": 0,
+           "kv_bytes": 0, "state_bytes": 0}
     seen = False
     for e in evs:
         name = e.get("name", "")
@@ -240,6 +244,8 @@ def kv_cache_summary(evs: list) -> dict:
             out["kv_blocks"] += args.get("kv_blocks", 0)
             out["kv_table_blocks"] += args["kv_table_blocks"]
             out["kv_window_blocks"] += args.get("kv_window_blocks", 0)
+            out["kv_bytes"] += args.get("kv_bytes", 0)
+            out["state_bytes"] += args.get("state_bytes", 0)
             seen = True
             continue
         if not name.startswith("kv/"):
@@ -932,6 +938,12 @@ def main(argv=None) -> int:
                   f"{kv['kv_blocks']} blocks its lanes hold: window "
                   f"share "
                   f"{100.0 * kv['kv_window_blocks'] / kv['kv_blocks']:.1f}%")
+        if kv["state_bytes"]:
+            held = kv["state_bytes"] + kv["kv_bytes"]
+            print(f"  recurrent state    {kv['state_bytes']} bytes held by "
+                  f"the steps' live lanes beside {kv['kv_bytes']} bytes of "
+                  f"rows walked: state share "
+                  f"{100.0 * kv['state_bytes'] / held:.1f}%")
 
     spec = spec_depth_summary(evs)
     if spec:
