@@ -481,6 +481,20 @@ class _ExpertFfn(nn.Module):
 GMM_INTERPRET = False
 
 
+#: Values of one ``[tk, tn]`` slice of an expert's kernel in a serving
+#: call: 2 MB in bf16, 4 MB double buffered beside the rows' block and
+#: the float32 result's under the default 16 MiB of scoped VMEM.
+_GMM_SLICE = 2 ** 20
+
+
+def _dividing_tile(size: int, most: int) -> int:
+    """The largest multiple of 128 that divides ``size`` (itself one)
+    and is at most ``most``; 128 where none is."""
+    units = size // 128
+    return 128 * max(d for d in range(1, units + 1)
+                     if units % d == 0 and (d == 1 or 128 * d <= most))
+
+
 def _gmm_tiling(rows: int, groups: int, k: int, n: int) -> tuple:
     """Tiles (rows, k, n) of a grouped matmul of ``rows`` rows over
     ``groups`` groups.  A grid step is one [128, tk] x [tk, tn] product
@@ -488,20 +502,42 @@ def _gmm_tiling(rows: int, groups: int, k: int, n: int) -> tuple:
     (serving: lanes x top_k rows of a decode step, or those of a
     prefill call of one to four pieces, over all experts) a step is
     bound by its slice of the expert's kernel coming in, so the slices
-    are as large as VMEM carries double buffered (2 MB in bf16) and the
-    steps few: at 2048 x 1536 three a group, where 128 x 128 tiles make
-    192.  Measured up to 160 rows an expert (PERF.md, PR 26 and PR 38:
-    at 40,960 rows over 256 experts megablox's own tiles took four
-    times as long as these); the rule holds to four row tiles an
-    expert.  With more (training) the tiles stay megablox's own, which
-    nothing here has measured, and the backward's transposed products
-    take the same tiling.  ``rhs`` may hold a share of the groups
-    (``group_offset``): the rows are then all groups' and a held group
-    still gets its share."""
-    if rows <= 4 * 128 * groups:
-        return (128, min(-(-k // 128) * 128, 2048),
-                min(-(-n // 128) * 128, 512))
-    return (128, 128, 128)
+    are as large as VMEM carries double buffered (``_GMM_SLICE``) and
+    they DIVIDE the kernel:
+
+    - ``tk`` is ``k`` whole wherever 128 columns of it fit a slice (to
+      8192), else ``k``'s largest divisor that does.  megablox indexes
+      the rows' block by (row tile, k tile): with one k tile it stays
+      put from one expert to the next and is fetched once a row tile,
+      with more it rides every slice (a [128, 2048] block beside 2 MB
+      of kernel is a quarter more bytes).  And a ``tk`` that does not
+      divide ``k`` makes the last k tile a masked one: megablox then
+      converts that whole slice and the rows' block to float32,
+      selects against an iota and converts back, on the vector unit,
+      at every visit.  A ``k`` that is no multiple of 128 (tests' tiny
+      models) is one rounded-up tile, masked as it must be.
+    - ``tn`` is the largest divisor of ``n`` (rounded up to a multiple
+      of 128) whose slice is within ``_GMM_SLICE``: no half-empty last
+      tile, and a narrow expert's down product gets wide slices.
+
+    At 2048 x 1536 that is 2048 x 512, three steps a group where 128 x
+    128 tiles make 192.  Measured up to 160 rows an expert (PERF.md, PR
+    26, PR 38 and PR 40: at 40,960 rows over 256 experts megablox's
+    own tiles took four times as long as large ones; at 2560 x 768
+    tiles of 2048 x 512, a masked remainder in ``k`` and a half-empty
+    tile in ``n``, took 0.78 ms for a decode step's call where
+    2560 x 384 take 0.48, and two exact tiles of 3584 took 7168 x 2048
+    half as long again as ``k`` whole); the rule holds to four row
+    tiles an expert.  With more (training) the tiles stay megablox's
+    own, which nothing here has measured, and the backward's
+    transposed products take the same tuple.  ``rhs`` may hold a share
+    of the groups (``group_offset``): the rows are then all groups'
+    and a held group still gets its share."""
+    if rows > 4 * 128 * groups:
+        return (128, 128, 128)
+    tk = (-(-k // 128) * 128 if k % 128
+          else _dividing_tile(k, _GMM_SLICE // 128))
+    return (128, tk, _dividing_tile(-(-n // 128) * 128, _GMM_SLICE // tk))
 
 
 def _gmm(lhs, rhs, group_sizes, interpret, group_offset=None):

@@ -362,3 +362,87 @@ def test_routed_rows_equal_a_per_pair_loop(top_k, share, tokens):
                           ["flat", "gate_w", "wi_gate", "wi_up", "wo"]):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5,
                                    err_msg=name)
+
+
+# (k, n) of the gate / up product of the benchmark's four expert
+# configurations, with the router's groups, a decode step's pairs
+# (slots x top_k) and a 1024-token piece's; the down product and the
+# backward's transposed products have k and n in each other's place.
+_BENCH_SHAPES = {
+    "glm": (2048, 1536, 64, 32 * 4, 1024 * 4),
+    "deepseek": (7168, 2048, 256, 16 * 8, 1024 * 8),
+    "laguna": (3072, 1024, 256, 32 * 10, 1024 * 10),
+    "ling": (2560, 768, 512, 64 * 8, 1024 * 8),
+}
+_TODAY = {(2048, 1536): (128, 2048, 512), (1536, 2048): (128, 1536, 512)}
+
+
+@pytest.mark.parametrize("rows_of", ["step", "piece"])
+@pytest.mark.parametrize("transposed", [False, True], ids=["k_n", "n_k"])
+@pytest.mark.parametrize("config", sorted(_BENCH_SHAPES))
+def test_serving_tiles_divide_an_experts_kernel(config, transposed, rows_of):
+    """No remainder tile in ``k`` (megablox masks one on the vector
+    unit at every visit) nor in ``n``, ``k`` whole (one k tile: the
+    rows' block is fetched once a row tile), and a slice within what
+    the docstring states; GLM's tuples are what they were."""
+    k, n, groups, step, piece = _BENCH_SHAPES[config]
+    if transposed:
+        k, n = n, k
+    rows = -(-(step if rows_of == "step" else piece) // 128) * 128
+    tm, tk, tn = moe._gmm_tiling(rows, groups, k, n)
+    assert tm == 128 and tk % 128 == 0 and tn % 128 == 0
+    assert k % tk == 0 and n % tn == 0
+    assert tk == k                          # every benchmark k fits whole
+    assert tk * tn <= moe._GMM_SLICE        # 2 MB in bf16, 4 double buffered
+    # ... and no larger divisor of n would have fitted.
+    assert not [d for d in range(tn + 128, n + 1, 128)
+                if n % d == 0 and tk * d <= moe._GMM_SLICE]
+    if (k, n) in _TODAY:
+        assert (tm, tk, tn) == _TODAY[(k, n)]
+
+
+@pytest.mark.parametrize("args,want", [
+    # a k that is no multiple of 128 is one rounded-up (masked) tile,
+    # an n that is none a whole number of tiles of its rounded width
+    ((128, 4, 64, 64), (128, 128, 128)),
+    ((128, 8, 200, 72), (128, 256, 128)),
+    ((256, 16, 100, 300), (128, 128, 384)),
+    # a k of which 128 columns outgrow a slice is split exactly
+    ((128, 8, 16384, 256), (128, 8192, 128)),
+    ((128, 8, 3 * 8192, 256), (128, 8192, 128)),
+    # more than four row tiles an expert: megablox's own
+    ((4 * 128 * 8 + 128, 8, 2560, 768), (128, 128, 128)),
+    ((4 * 128 * 8, 8, 2560, 768), (128, 2560, 384)),
+], ids=str)
+def test_tiling_outside_the_benchmark_shapes(args, want):
+    assert moe._gmm_tiling(*args) == want
+
+
+@pytest.mark.parametrize("case", ["all_groups", "held_share", "k_split"])
+def test_gmm_with_awkward_tile_ratios_equals_a_per_group_einsum(
+        case, monkeypatch):
+    """``k`` = 5 x 128 and ``n`` = 3 x 128 (2560 x 768 scaled down):
+    one k tile of five lane tiles and one n tile of three, over all
+    groups and over a held share behind ``group_offset`` (rows of
+    groups not held come back zero); and with the slice shrunk so that
+    ``k`` splits into exact tiles that accumulate."""
+    k, n, groups, rows = 640, 384, 6, 256
+    if case == "k_split":
+        monkeypatch.setattr(moe, "_GMM_SLICE", 2 ** 16)
+        assert moe._gmm_tiling(rows, groups, k, n) == (128, 128, 384)
+    else:
+        assert moe._gmm_tiling(rows, groups, k, n) == (128, k, n)
+    first, held = (2, 3) if case == "held_share" else (0, groups)
+    keys = jax.random.split(jax.random.PRNGKey(40), 2)
+    lhs = jax.random.normal(keys[0], (rows, k), jnp.float32)
+    rhs = jax.random.normal(keys[1], (held, k, n), jnp.float32) * 0.1
+    sizes = jnp.asarray([40, 0, 70, 1, 17, 128], jnp.int32)
+    got = moe._gmm(lhs, rhs, sizes, True,
+                   None if case != "held_share" else first)
+    ends = np.cumsum(np.asarray(sizes))
+    want = np.zeros((rows, n), np.float32)
+    for g in range(first, first + held):
+        lo, hi = ends[g] - int(sizes[g]), ends[g]
+        want[lo:hi] = np.einsum("rk,kn->rn", np.asarray(lhs[lo:hi]),
+                                np.asarray(rhs[g - first]))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)

@@ -329,26 +329,58 @@ def test_kernel_partitions_over_a_2x2_mesh(case, v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_routed_rows_go_to_expert_order_and_back_in_one_pass_each(v5e):
-    """``_routed_ffn_rows`` at ``laguna-s21-1chip``'s piece shape (1024
-    tokens, 10 choices, width 3072, 64 of 256 experts of width 1024
-    held): the float32 rows the grouped matmuls return are never laid
-    out again by token (no ``reshape`` or ``copy`` to ``[1024, 10,
-    3072]`` or ``[10, 1024, 3072]``: 126 MB read and 201 written when
-    the pairs lay token-major), no ``select`` pass follows the sort's
-    gather (its indices are promised in bounds), and the un-sort and
-    gate-combine is the one kernel named ``moe_combine``."""
+def _benchmark_config(name):
+    """``benchmark/configs/<name>.json``: a cell's configuration as it
+    is run."""
+    import json
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+_EXPERT_CONFIGS = ("glm47-flash-1chip", "deepseek-v32exp-1chip",
+                   "laguna-s21-1chip", "ling3-flash-1chip")
+
+
+@pytest.mark.parametrize("rows_of", ["step", "piece"])
+@pytest.mark.parametrize("config", _EXPERT_CONFIGS)
+def test_routed_rows_go_to_expert_order_and_back_in_one_pass_each(
+        config, rows_of, v5e):
+    """``_routed_ffn_rows`` at each expert configuration of the
+    benchmark (choices, width, experts held and their width from
+    ``benchmark/configs/``), over a decode step's rows (the engine's
+    slots) and a 1024-token prefill piece's.  The TPU compiler takes
+    the tiles ``_gmm_tiling`` chose (a slice that outgrows VMEM is
+    refused here, before any chip call) and the program holds its three
+    ``gmm`` kernels.  The float32 rows they return are never laid out
+    again by token (no ``reshape`` or ``copy`` to ``[tokens, k, d]`` or
+    ``[k, tokens, d]``: at Laguna's piece 126 MB read and 201 written
+    when the pairs lay token-major), no ``select`` pass follows the
+    sort's gather (its indices are promised in bounds), and the un-sort
+    and gate-combine is the one kernel named ``moe_combine``."""
+    import dataclasses
     import re
 
     from tensorflow_train_distributed_tpu.models import moe
 
-    t, k, d, f, experts, held = 1024, 10, 3072, 1024, 256, 64
+    cfg_file = _benchmark_config(config)
+    cfg = dataclasses.replace(
+        moe.MOE_PRESETS[cfg_file["program"]["preset"]],
+        **cfg_file["program"]["replace"])
+    t = cfg_file["engine"]["slots"] if rows_of == "step" else 1024
+    k, d, f, experts = cfg.top_k, cfg.d_model, cfg.ffn_size, cfg.num_experts
+    held = cfg.experts_held or experts
+    m_pad = -(-t * k // 128) * 128
     one_chip = SingleDeviceSharding(v5e[0])
 
     def rows(flat, top_e, gate_w, wi_gate, wi_up, wo):
         return moe._routed_ffn_rows(
             flat, top_e, gate_w, experts, wi_gate, wi_up, wo, dtype=BF16,
-            interpret=False, group_offset=0)
+            interpret=False,
+            group_offset=None if cfg.experts_held is None
+            else cfg.experts_offset)
 
     text = jax.jit(rows).lower(*(
         jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
@@ -358,12 +390,13 @@ def test_routed_rows_go_to_expert_order_and_back_in_one_pass_each(v5e):
     made = re.findall(
         r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+\[[\d,]*\])\S* ([\w\-]+)\(",
         text, re.M)
-    assert (f"f32[{t * k},{d}]", "custom-call") in made     # gmm's rows
+    assert (f"f32[{m_pad},{d}]", "custom-call") in made     # gmm's rows
     relaid = {f"f32[{t},{k},{d}]", f"f32[{k},{t},{d}]"}
     assert not [m for m in made
                 if m[0] in relaid and m[1] in ("reshape", "copy")]
-    assert (f"bf16[{t * k},{d}]", "select") not in made
-    assert "moe_combine" in {name.split(".")[0] for name in _kernels(text)}
+    assert (f"bf16[{m_pad},{d}]", "select") not in made
+    kernels = [name.split(".")[0] for name in _kernels(text)]
+    assert kernels.count("gmm") == 3 and "moe_combine" in kernels
 
 
 @pytest.fixture(scope="module")
@@ -372,8 +405,6 @@ def decode_program(v5e):
     256 blocks of 16 a lane, 28/4 heads of 128, 12 layers), compiled
     once for the described chip."""
     import dataclasses
-    import json
-    import os
 
     import flax.linen as nn
 
@@ -383,10 +414,7 @@ def decode_program(v5e):
     monkeypatch = pytest.MonkeyPatch()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     try:
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(repo, "benchmark", "configs",
-                               "qwen25-7b-1chip.json")) as f:
-            cfg_file = json.load(f)
+        cfg_file = _benchmark_config("qwen25-7b-1chip")
         cfg = dataclasses.replace(
             llama.LLAMA_PRESETS[cfg_file["program"]["preset"]],
             **cfg_file["program"]["replace"])
