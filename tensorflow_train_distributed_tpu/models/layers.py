@@ -228,19 +228,25 @@ def _quantize_kv_rows(t):
 
 
 def paged_pool_leaves(blocks: int, block_size: int, kv_heads: int,
-                      head_dim: int, dtype, int8: bool) -> dict:
+                      head_dim: int, dtype, int8: bool,
+                      v_head_dim: Optional[int] = None) -> dict:
     """name -> (shape, dtype) of one layer's paged K/V pool leaves, as
     they are STORED: a row is its ``kv_heads * head_dim`` values side by
     side, [blocks, block_size, row], which is the shape the attention
     kernel copies blocks in, so nothing between the cache and the
-    kernel takes a view of a pool.  int8 rows bring their float32
+    kernel takes a view of a pool (a value row is ``kv_heads *
+    v_head_dim`` wide where the value head has a size of its own:
+    nothing is padded).  int8 rows bring their float32
     scales, [2 (K, V), blocks, block_size, kv_heads].  One table for
     the module that owns a layer's pools (``MultiHeadAttention``) and
     for the depth scan that owns every layer's
     (``llama._ScannedBlock``, one leading layer axis)."""
-    row = (blocks, block_size, kv_heads * head_dim)
     store = jnp.int8 if int8 else dtype
-    leaves = {"key_pool": (row, store), "value_pool": (row, store)}
+    leaves = {"key_pool": ((blocks, block_size, kv_heads * head_dim),
+                           store),
+              "value_pool": ((blocks, block_size,
+                              kv_heads * (v_head_dim or head_dim)),
+                             store)}
     if int8:
         leaves["kv_pool_scales"] = (
             (2, blocks, block_size, kv_heads), jnp.float32)
@@ -434,6 +440,32 @@ class MultiHeadAttention(nn.Module):
     # over k prefill pieces of a prompt reads what the pieces read.
     # The engine sets it to its ``prefill_chunk``; 0: all at once.
     query_block: int = 0
+    # A value head's size where it is not the key head's ``head_dim``
+    # (None: it is): the value projection, the value cache and pool
+    # and the heads' outputs are that wide, queries and keys stay
+    # ``head_dim``.
+    v_head_dim: Optional[int] = None
+    # The projected values are multiplied by this before they are
+    # cached (``attention_value_scale``).
+    value_scale: float = 1.0
+    # A learned sink in the softmax: one float32 logit a query head
+    # (``sink/bias`` [num_heads]) that joins every row's denominator
+    # and carries no value, ``p_j = exp(s_j - m) / (exp(b - m) +
+    # sum_i exp(s_i - m))``.  No cached row and no buffer stands
+    # behind it, unlike the StreamingLLM ``sinks`` above, which keep
+    # the first ROWS attendable.
+    sink: bool = False
+
+    @property
+    def _v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    def _sink_logits(self):
+        """float32 [num_heads] sink logits, or None."""
+        if not self.sink:
+            return None
+        return BiasParam((self.num_heads,), ("heads",),
+                         name="sink")().astype(jnp.float32)
 
     def _rope(self, t, positions):
         return apply_rope(t, positions, base=self.rope_base,
@@ -460,7 +492,7 @@ class MultiHeadAttention(nn.Module):
         with jax.named_scope("attn/gate"):
             return out * gate[..., None].astype(out.dtype)
 
-    def _proj(self, x, heads, name):
+    def _proj(self, x, heads, name, width=None):
         # Plain 2-D kernel (embed, heads*head_dim) + reshape: maps onto
         # the MXU as one big matmul, and sidesteps flax's DenseGeneral
         # boxed-kernel reshape which mis-applies logical constraints
@@ -469,14 +501,15 @@ class MultiHeadAttention(nn.Module):
         # axis whenever heads is).  Shared by the training and decode
         # paths — the submodule name/init/partitioning contract between
         # them lives here and only here.
+        width = width or self.head_dim
         y = nn.Dense(
-            heads * self.head_dim,
+            heads * width,
             use_bias=self.use_bias or self.qkv_bias, dtype=self.dtype,
             name=name,
             kernel_init=nn.with_logical_partitioning(
                 nn.initializers.lecun_normal(), ("embed", "heads")),
         )(x)
-        y = y.reshape(*x.shape[:-1], heads, self.head_dim)
+        y = y.reshape(*x.shape[:-1], heads, width)
         return nn.with_logical_constraint(
             y, ("batch", "length", self._head_ax(heads), "kv"))
 
@@ -488,7 +521,10 @@ class MultiHeadAttention(nn.Module):
         if not self.fused_qkv:
             return (self._proj(x, self.num_heads, "query"),
                     self._proj(x, kv_heads, "key"),
-                    self._proj(x, kv_heads, "value"))
+                    self._value(x, kv_heads))
+        if self.v_head_dim or self.value_scale != 1.0:
+            raise ValueError("fused_qkv cuts one gemm into equal heads: "
+                             "no v_head_dim or value_scale beside it")
         tot = self.num_heads + 2 * kv_heads
         y = nn.Dense(
             tot * self.head_dim, use_bias=self.use_bias or self.qkv_bias,
@@ -502,6 +538,12 @@ class MultiHeadAttention(nn.Module):
         return (y[..., :self.num_heads, :],
                 y[..., self.num_heads:self.num_heads + kv_heads, :],
                 y[..., self.num_heads + kv_heads:, :])
+
+    def _value(self, x, kv_heads):
+        """The value projection: ``v_head_dim`` wide, times
+        ``value_scale``."""
+        v = self._proj(x, kv_heads, "value", self._v_dim)
+        return v if self.value_scale == 1.0 else v * self.value_scale
 
     def _head_ax(self, heads):
         """Logical axis for a ``heads``-sized activation dim.
@@ -565,7 +607,7 @@ class MultiHeadAttention(nn.Module):
                                  "(q and kv read different inputs)")
             q = self._proj(x_q, self.num_heads, "query")
             k = self._proj(x_kv, kv_heads, "key")
-            v = self._proj(x_kv, kv_heads, "value")
+            v = self._value(x_kv, kv_heads)
 
         if self.use_rope:
             if positions is None:
@@ -588,6 +630,10 @@ class MultiHeadAttention(nn.Module):
         # [B, S, H, D] → [B, H, S, D] for the kernel.
         qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         sp_mesh = _seq_parallel_mesh(self.seq_parallel)
+        sink_logits = self._sink_logits()
+        if sp_mesh is not None and (self.sink or self.v_head_dim):
+            raise ValueError("seq_parallel attention takes no sink "
+                             "logit and no v_head_dim")
         if sp_mesh is None and kv_heads != self.num_heads:
             # GQA: repeat KV groups to full heads (XLA fuses the broadcast).
             # The SP path rotates/reshards the *unrepeated* KV and repeats
@@ -614,7 +660,7 @@ class MultiHeadAttention(nn.Module):
             out = multihead_attention_kernel(
                 qh, kh, vh, causal=self.causal, mask=mask,
                 segment_ids=segment_ids, window=self.window,
-                sinks=self.sinks,
+                sinks=self.sinks, sink_logits=sink_logits,
             ).transpose(0, 2, 1, 3)
         out = nn.with_logical_constraint(
             out, ("batch", "length", self._head_ax(self.num_heads), "kv"))
@@ -622,8 +668,7 @@ class MultiHeadAttention(nn.Module):
             out = nn.Dropout(self.dropout_rate)(out,
                                                 deterministic=deterministic)
         out = self._gated(out, self._gate(x_q))
-        out = out.reshape(*out.shape[:-2],
-                          self.num_heads * self.head_dim)
+        out = out.reshape(*out.shape[:-2], self.num_heads * self._v_dim)
         y = self._out_proj(out, x_q.shape[-1])
         return nn.with_logical_constraint(y, ("batch", "length", "embed"))
 
@@ -702,7 +747,7 @@ class MultiHeadAttention(nn.Module):
             (b, cache_rows, kv_heads, self.head_dim), cache_dtype)
         cache_v = self.variable(
             "cache", "value_cache", jnp.zeros,
-            (b, cache_rows, kv_heads, self.head_dim), cache_dtype)
+            (b, cache_rows, kv_heads, self._v_dim), cache_dtype)
         if self.kv_cache_int8:
             # One f32 scale per (batch, row, kv_head): symmetric over the
             # head_dim — the standard per-token KV quantization grain.
@@ -720,6 +765,10 @@ class MultiHeadAttention(nn.Module):
             k = self._rope(k, pos_b)
         index.value = cur + q_len
         gate = self._gate(x)
+        if self.sink and rolling:
+            raise ValueError("the rolling window cache takes no sink "
+                             "logit (the serving engine's ring does)")
+        sink_logits = self._sink_logits()
 
         if rolling and q_len > 1:
             return self._rolling_block(x, q, k, v, cache_k, cache_v,
@@ -782,7 +831,8 @@ class MultiHeadAttention(nn.Module):
         # the prefix rule is the whole mask here.
         return self._cache_attend(q, cache_k.value, cache_v.value,
                                   kv_heads, b, q_len, x.shape[-1],
-                                  start=cur, scales=scales, gate=gate)
+                                  start=cur, scales=scales, gate=gate,
+                                  sink_logits=sink_logits)
 
     def _sink_buffers(self, b, kv_heads):
         """The StreamingLLM sink KV buffer pair ([B, sinks, Hkv, D])."""
@@ -791,7 +841,7 @@ class MultiHeadAttention(nn.Module):
             (b, self.sinks, kv_heads, self.head_dim), self.dtype)
         sink_v = self.variable(
             "cache", "sink_value", jnp.zeros,
-            (b, self.sinks, kv_heads, self.head_dim), self.dtype)
+            (b, self.sinks, kv_heads, self._v_dim), self.dtype)
         return sink_k, sink_v
 
     def _write_sinks(self, sink_k, sink_v, k, v, cur, q_len, kdt):
@@ -842,7 +892,7 @@ class MultiHeadAttention(nn.Module):
             (b, self.cache_len, kv_heads, self.head_dim), cache_dtype)
         cache_v = self.variable(
             "cache", "value_cache", jnp.zeros,
-            (b, self.cache_len, kv_heads, self.head_dim), cache_dtype)
+            (b, self.cache_len, kv_heads, self._v_dim), cache_dtype)
         if self.kv_cache_int8:
             kv_scales = self.variable(
                 "cache", "kv_scales", jnp.zeros,
@@ -878,7 +928,8 @@ class MultiHeadAttention(nn.Module):
         return self._cache_attend(q, cache_k.value, cache_v.value,
                                   kv_heads, b, q_len, x.shape[-1],
                                   start=cur, scales=scales,
-                                  window=self.window, gate=self._gate(x))
+                                  window=self.window, gate=self._gate(x),
+                                  sink_logits=self._sink_logits())
 
     def _paged_decode_step(self, x, kv_pools=None):
         """Per-slot decode over the PAGED pool: same append-and-attend
@@ -961,7 +1012,7 @@ class MultiHeadAttention(nn.Module):
                                        dtype)
                    for name, (shape, dtype) in paged_pool_leaves(
                        nb, bs, kv_heads, self.head_dim, self.dtype,
-                       self.kv_cache_int8).items()}
+                       self.kv_cache_int8, self.v_head_dim).items()}
             layer, pools = None, {n: var.value for n, var in own.items()}
         else:                           # the depth scan's, carried
             own, (layer, pools) = {}, kv_pools
@@ -981,6 +1032,7 @@ class MultiHeadAttention(nn.Module):
             k = self._rope(k, positions)
         index.value = cur + q_len
         gate = self._gate(x)
+        sink_logits = self._sink_logits()
 
         # This step's rows into the pools, under the name the
         # device-scope contract (PERF.md §3) gives the write.
@@ -1039,8 +1091,8 @@ class MultiHeadAttention(nn.Module):
                 q, k_pool, v_pool, table.value, held,
                 k_scales=k_scales, v_scales=v_scales,
                 cache_len=self.cache_len, block0=block0,
-                window=self.window, use_pallas=True,
-                interpret=pk.fused_attn_interpret())
+                window=self.window, sink_logits=sink_logits,
+                use_pallas=True, interpret=pk.fused_attn_interpret())
             return self._attn_epilogue(out, b, q_len, x.shape[-1],
                                        gate), pools
 
@@ -1049,28 +1101,30 @@ class MultiHeadAttention(nn.Module):
         def lane_view(pool):
             return pk.paged_kv_gather(
                 pool, table.value + block0, rows).reshape(
-                    b, rows, kv_heads, self.head_dim)
+                    b, rows, kv_heads, -1)
 
         if ring:
             # The ring, whole, under the mask of what each row holds.
             seen = pk.ring_mask(cur, q_len, rows, self.window)
             return self._cache_attend(
                 q, lane_view(k_pool), lane_view(v_pool), kv_heads, b,
-                q_len, x.shape[-1], mask=seen[:, None], gate=gate), pools
+                q_len, x.shape[-1], mask=seen[:, None], gate=gate,
+                sink_logits=sink_logits), pools
         if self.kv_cache_int8:      # else ``scales`` is None already
             scales = tuple(
                 pk.paged_kv_gather(s, table.value, self.cache_len)
                 for s in (k_scales, v_scales))
         return self._cache_attend(
             q, lane_view(k_pool), lane_view(v_pool), kv_heads, b, q_len,
-            x.shape[-1], start=cur, scales=scales, gate=gate), pools
+            x.shape[-1], start=cur, scales=scales, gate=gate,
+            sink_logits=sink_logits), pools
 
     def _fused_paged_ok(self) -> bool:
         return fused_paged_ok()
 
     def _cache_attend(self, q, kc, vc, kv_heads, b, q_len, features, *,
                       start=None, mask=None, scales=None, window=None,
-                      gate=None):
+                      gate=None, sink_logits=None):
         """Attention of q over the cache buffers [B, rows, kv_heads, D].
 
         A linear cache hands in ``start`` [B] (or a scalar), the
@@ -1084,7 +1138,9 @@ class MultiHeadAttention(nn.Module):
         and its sinks, a paged window layer's gathered ring) addresses
         no prefix: it hands in its own ``mask`` over every row.
         ``window`` narrows a linear cache's walk to the tiles a sliding
-        window reaches (``prefix_attention``)."""
+        window reaches (``prefix_attention``).  ``sink_logits``
+        [num_heads]: the learned sink of every row's softmax
+        (``sink``)."""
         from tensorflow_train_distributed_tpu.ops.attention import (
             dot_product_attention, prefix_attention,
         )
@@ -1113,11 +1169,13 @@ class MultiHeadAttention(nn.Module):
         qh = q.transpose(0, 2, 1, 3)        # [B, S, H, D] → [B, H, S, D]
         if start is None:
             out = dot_product_attention(qh, *heads((kc, vc, scales)),
-                                        mask=mask)
+                                        mask=mask,
+                                        sink_logits=sink_logits)
         else:
             out = prefix_attention(qh, (kc, vc, scales), start, heads,
                                    window=window,
-                                   block=self.query_block)
+                                   block=self.query_block,
+                                   sink_logits=sink_logits)
         out = out.transpose(0, 2, 1, 3)
         return self._attn_epilogue(out, b, q_len, features, gate)
 
@@ -1129,7 +1187,7 @@ class MultiHeadAttention(nn.Module):
         out = nn.with_logical_constraint(
             out, ("batch", "length", self._head_ax(self.num_heads), "kv"))
         out = self._gated(out, gate)
-        out = out.reshape(b, q_len, self.num_heads * self.head_dim)
+        out = out.reshape(b, q_len, self.num_heads * self._v_dim)
         y = self._out_proj(out, features)
         return nn.with_logical_constraint(y, ("batch", "length", "embed"))
 

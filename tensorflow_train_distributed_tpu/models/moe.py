@@ -56,7 +56,8 @@ class AttnKind:
     over cached keys and values), ``"latent"`` (``LatentKind``) or
     ``"linear"`` (``LinearKind``); a class attribute and no field, so
     that the five fields stay what readers of ``dataclasses.astuple``
-    compare (``benchmark/harness/serve_pattern.py``)."""
+    compare (``benchmark/harness/serve_pattern.py``); what a softmax
+    layer has beyond the five is ``KvKind``'s."""
 
     num_heads: int
     window: Optional[int] = None
@@ -64,6 +65,18 @@ class AttnKind:
     rotary_share: float = 1.0
     rope_scaling: Optional[tuple] = None
     kind: ClassVar[str] = "softmax"
+
+
+@dataclasses.dataclass(frozen=True)
+class KvKind(AttnKind):
+    """A softmax layer whose KV heads are its own (None: the model's
+    ``num_kv_heads``) and whose softmax may carry a learned ``sink``: one
+    logit a query head in the denominator, with no row and no value
+    behind it (``layers.MultiHeadAttention.sink``).  A subclass, so that
+    ``AttnKind`` keeps its five fields."""
+
+    num_kv_heads: Optional[int] = None
+    sink: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,7 +175,12 @@ class MoeConfig:
     kv_lora_rank: Optional[int] = None
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
+    # The value head's size: the latent layers', and an MHA/GQA layer's
+    # where it is not its key head's (0: ``head_dim`` there).
     v_head_dim: int = 0
+    # An MHA/GQA layer's projected values are multiplied by this
+    # (``attention_value_scale``).
+    value_scale: float = 1.0
     # Rotary scaling of the attention (``layers.apply_rope``'s tagged
     # tuple, e.g. ("yarn", factor, beta_fast, beta_slow, original_max)).
     rope_scaling: Optional[tuple] = None
@@ -191,14 +209,19 @@ class MoeConfig:
     # A head's size where it is not ``d_model // num_heads``.
     head_dim: Optional[int] = None
     # Attention kinds that differ by layer: a period of ``AttnKind``s
-    # counted from layer 0 (layer i is ``attn_period[i % len]``), each
-    # with its own query heads, window and rotary rule; ``num_heads``,
-    # ``rope_base`` and ``rope_scaling`` above then say nothing.  The
-    # layers of such a model share no parameter shape (they are
-    # unrolled here anyway).  None: every layer alike, from the fields
-    # above.  With ``kv_lora_rank`` set the kinds are "latent" and
-    # "linear" alone (the latent sizes above are the latent layers').
+    # counted from the first layer after ``attn_lead`` (layer i is
+    # ``attn_period[(i - len(attn_lead)) % len]``), each with its own
+    # query heads, window and rotary rule; ``num_heads``, ``rope_base``
+    # and ``rope_scaling`` above then say nothing.  The layers of such
+    # a model share no parameter shape (they are unrolled here anyway).
+    # None: every layer alike, from the fields above.  With
+    # ``kv_lora_rank`` set the kinds are "latent" and "linear" alone
+    # (the latent sizes above are the latent layers').
     attn_period: Optional[tuple] = None
+    # The kinds of the layers BEFORE the period starts, one a layer (a
+    # pattern that is no period from layer 0: full at 0, 5, 11, ... is
+    # a lead of one full layer and a period of six).
+    attn_lead: tuple = ()
     # Per-head output gate of the attention (``out_gate`` of the
     # layer's module), every layer.
     attn_gate: bool = False
@@ -211,7 +234,15 @@ class MoeConfig:
         """Layer ``layer``'s kind, or None where layers do not differ."""
         if not self.attn_period:
             return None
-        return self.attn_period[layer % len(self.attn_period)]
+        lead = len(self.attn_lead)
+        if layer < lead:
+            return self.attn_lead[layer]
+        return self.attn_period[(layer - lead) % len(self.attn_period)]
+
+    @property
+    def attn_kinds(self) -> tuple:
+        """Every kind a layer of the model may be (lead and period)."""
+        return self.attn_lead + (self.attn_period or ())
 
     @property
     def recurrent_layers(self) -> int:
@@ -225,7 +256,7 @@ class MoeConfig:
     def attn_window(self) -> Optional[int]:
         """The sliding window of the model's window layers (None: it has
         none).  One size a model: the serving engine sizes one ring."""
-        sizes = {k.window for k in self.attn_period or ()
+        sizes = {k.window for k in self.attn_kinds
                  if k.window is not None}
         if len(sizes) > 1:
             raise ValueError(f"window layers of several sizes {sizes}")
@@ -246,6 +277,18 @@ _LAGUNA_KINDS = (
 #: is latent where (i + 1) % 6 == 0).
 _LING_KINDS = (LinearKind(num_heads=32),) * 5 + (
     LatentKind(num_heads=32, rope_base=6_000_000.0),)
+
+
+#: MiMo-V2.5's two kinds of layer: the first int(0.334 x 192) = 64
+#: values of a head are rotated in both.
+_MIMO_FULL = KvKind(num_heads=64, rope_base=10_000_000.0,
+                    rotary_share=0.334)
+_MIMO_WINDOW = KvKind(num_heads=64, window=128, rope_base=10_000.0,
+                      rotary_share=0.334, num_kv_heads=8, sink=True)
+#: The same two at test size: 4 query heads, a window of 8.
+_MIMO_TINY_FULL = dataclasses.replace(_MIMO_FULL, num_heads=4)
+_MIMO_TINY_WINDOW = dataclasses.replace(_MIMO_WINDOW, num_heads=4,
+                                        window=8, num_kv_heads=2)
 
 
 MOE_PRESETS = {
@@ -382,6 +425,41 @@ MOE_PRESETS = {
         attn_gate=True,
         attn_period=(LinearKind(num_heads=4),) * 5 + (
             LatentKind(num_heads=4, rope_base=6_000_000.0),)),
+    # MiMo-V2.5's language model (XiaomiMiMo, ``mimo_v2``; the model of
+    # MiMo-V2-Flash) at its published widths: full and sliding-window
+    # (128) GQA layers with keys of 192 beside values of 128 (values x
+    # 0.707), 4 KV heads in a full layer and 8 in a window layer, a
+    # learned sink logit a head in a window layer's softmax, the first
+    # 64 values of a head rotated; full at layers 0, 5, 11, ..., 47 (a
+    # lead of one and a period of six); one leading SwiGLU layer, then
+    # 256 sigmoid-routed experts (top 8, gates unscaled) and no shared
+    # one.  Deployments give ``experts_held`` and cut depth and
+    # vocabulary (benchmark/configs).  Served by the engine's tiled
+    # paths; a forward outside it (training, a whole prompt) takes the
+    # dense S x S oracle for the sink and the value width and warns
+    # from 2,048 rows on (``ops.attention.DENSE_WARN_ROWS``; ROADMAP R2).
+    "mimo_v25": MoeConfig(
+        vocab_size=152_576, d_model=4096, num_layers=48, num_heads=64,
+        num_kv_heads=4, head_dim=192, v_head_dim=128, value_scale=0.707,
+        ffn_size=2048, num_experts=256, top_k=8,
+        max_positions=1_048_576, rope_base=10_000_000.0,
+        rms_epsilon=1e-5, dispatch="gmm", norm_topk_prob=True,
+        dense_layers=1, dense_ffn_size=16_384, router="sigmoid",
+        routed_scaling=1.0, attn_lead=(_MIMO_FULL,),
+        attn_period=(_MIMO_WINDOW,) * 4 + (_MIMO_FULL, _MIMO_WINDOW)),
+    # The same block at test size (float32): keys of 24 beside values
+    # of 16, 1 KV head in a full layer and 2 in a window layer under 4
+    # query heads, a window of 8, the first 8 values of a head rotated.
+    "mimo_v25_tiny": MoeConfig(
+        vocab_size=256, d_model=64, num_layers=7, num_heads=4,
+        num_kv_heads=1, head_dim=24, v_head_dim=16, value_scale=0.707,
+        ffn_size=48, num_experts=8, top_k=2, max_positions=128,
+        rope_base=10_000_000.0, dtype=jnp.float32, remat=False,
+        dispatch="gmm", dense_layers=1, dense_ffn_size=160,
+        router="sigmoid", rms_epsilon=1e-5,
+        attn_lead=(_MIMO_TINY_FULL,),
+        attn_period=(_MIMO_TINY_WINDOW,) * 4 + (_MIMO_TINY_FULL,
+                                                _MIMO_TINY_WINDOW)),
     # DeepSeek/Qwen-MoE-style: always-on shared expert beside the
     # routed ones (tiny test shape).
     "moe_tiny_shared": MoeConfig(vocab_size=256, d_model=64,
@@ -1048,9 +1126,16 @@ class MoeDecoderBlock(nn.Module):
     def _mha(self, h, segment_ids, positions):
         cfg = self.config
         head_dim = cfg.head_dim or cfg.d_model // cfg.num_heads
+        kind = cfg.attn_kind(self.layer)
+        # The KV heads and the sink of a kind are ``KvKind``'s fields;
+        # every other kind runs the model's and no sink.
+        own = isinstance(kind, KvKind)
         common = dict(
             qkv_bias=cfg.qkv_bias, head_dim=head_dim,
-            num_kv_heads=cfg.num_kv_heads,
+            v_head_dim=cfg.v_head_dim or None,
+            value_scale=cfg.value_scale,
+            num_kv_heads=(own and kind.num_kv_heads) or cfg.num_kv_heads,
+            sink=own and kind.sink,
             dtype=cfg.dtype, causal=True, use_rope=True,
             name="attention", decode=self.decode,
             cache_len=self.cache_len or cfg.max_positions,
@@ -1058,7 +1143,6 @@ class MoeDecoderBlock(nn.Module):
             paged_kv_blocks=self.paged_kv_blocks,
             kv_block_size=self.kv_block_size,
             query_block=self.query_block)
-        kind = cfg.attn_kind(self.layer)
         if kind is None:
             return L.MultiHeadAttention(
                 num_heads=cfg.num_heads, rope_base=cfg.rope_base,
@@ -1113,7 +1197,10 @@ class MoeLmModel(nn.Module):
         cfg = self.config
         if segment_ids is not None and self.decode:
             raise ValueError("decode mode does not take packed segments")
-        kinds = {k.kind for k in cfg.attn_period or ()}
+        if cfg.attn_lead and not cfg.attn_period:
+            raise ValueError("attn_lead comes before a period: it needs "
+                             "attn_period")
+        kinds = {k.kind for k in cfg.attn_kinds}
         if kinds - {"softmax", "latent", "linear"}:
             raise ValueError(f"unknown kinds of attention layer {kinds}")
         if ("latent" in kinds) != bool(cfg.attn_period

@@ -38,8 +38,10 @@ def dot_product_attention(
     window: Optional[int] = None,
     sinks: int = 0,
     softmax_scale: Optional[float] = None,
+    sink_logits: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Reference attention. q/k/v: [B, H, S, D] (q may have different S).
+    """Reference attention. q/k/v: [B, H, S, D] (q may have different S;
+    v may have a head size of its own, which is the output's).
 
     ``window`` (requires ``causal``): sliding-window attention — each
     query sees only the last ``window`` keys including itself (the
@@ -47,7 +49,9 @@ def dot_product_attention(
     oracle for ``local_attention_chunked``.  ``sinks`` (StreamingLLM):
     the first ``sinks`` absolute positions stay attendable past the
     window — the attention-sink trick that keeps streaming decode
-    stable.
+    stable.  ``sink_logits`` [H] float32 is another thing: a learned
+    logit a head that joins every row's softmax denominator and
+    carries no value (``softmax_with_sink``).
     """
     *_, q_len, head_dim = q.shape
     kv_len = k.shape[-2]
@@ -76,8 +80,24 @@ def dot_product_attention(
         logits = jnp.where(keep, logits, mask_value)
     if mask is not None:
         logits = jnp.where(mask, logits, mask_value)
-    weights = jax.nn.softmax(logits, axis=-1)
+    if sink_logits is None:
+        weights = jax.nn.softmax(logits, axis=-1)
+    else:
+        weights = softmax_with_sink(logits, sink_logits[:, None, None])
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
+
+
+def softmax_with_sink(logits, sink):
+    """``exp(s_j - m) / (exp(b - m) + sum_i exp(s_i - m))`` over the last
+    axis, ``m = max(b, max_i s_i)``: a softmax whose denominator holds
+    one more logit ``b`` (``sink``, broadcast against ``logits`` with a
+    last axis of 1) that has no column of its own.  A sink of ``-inf``
+    is ``jax.nn.softmax``, to the bit."""
+    m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), sink)
+    m = jax.lax.stop_gradient(m)
+    unnormalized = jnp.exp(logits - m)
+    return unnormalized / (jnp.sum(unnormalized, axis=-1, keepdims=True)
+                           + jnp.exp(sink - m))
 
 
 #: Rows of a linear KV cache that ``prefix_attention`` folds into its
@@ -163,6 +183,7 @@ def prefix_attention(
     keep: Optional[jax.Array] = None,
     window: Optional[int] = None,
     block: Optional[int] = None,
+    sink_logits: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Attention of ``q`` [B, H, Q, D] over the prefix of a linear KV
     cache that its lanes hold, tile by tile with a running softmax.
@@ -203,6 +224,11 @@ def prefix_attention(
     each block over its own tiles (``_by_query_blocks``): the cost of a
     call over k pieces of a prompt is the k pieces', not k times the
     last one's.
+
+    ``sink_logits`` [H] float32: a learned sink a head in every row's
+    denominator (``softmax_with_sink``): the running maximum starts at
+    it, the running sum at 1 and the accumulator at 0, which is that
+    expression and costs no tile.
     """
     tile = PREFIX_TILE if tile is None else tile
     cache_len = jax.tree.leaves(cache)[0].shape[1]
@@ -210,7 +236,7 @@ def prefix_attention(
     blocks = _by_query_blocks(
         lambda s, q, keep=None: prefix_attention(
             q, cache, s, kv_of, tile=tile, softmax_scale=softmax_scale,
-            keep=keep, window=window),
+            keep=keep, window=window, sink_logits=sink_logits),
         start, q_len, block, q.ndim - 2, (q, q.ndim - 2),
         *(() if keep is None else ((keep, 1),)))
     if blocks is not None:
@@ -231,7 +257,7 @@ def prefix_attention(
         k, v = kv_of(cache)
         return dot_product_attention(
             q, k, v, mask=seen(jnp.arange(cache_len)),
-            softmax_scale=softmax_scale)
+            softmax_scale=softmax_scale, sink_logits=sink_logits)
 
     scale = (softmax_scale if softmax_scale is not None
              else q.shape[-1] ** -0.5)
@@ -266,10 +292,16 @@ def prefix_attention(
 
     v_like = jax.eval_shape(lambda: tile_kv(0)[1][1])
     stat = jnp.full((*q.shape[:-1], 1), mask_value, jnp.float32)
+    if sink_logits is None:
+        m0, l0 = stat, jnp.zeros_like(stat)
+    else:
+        m0 = jnp.broadcast_to(
+            sink_logits.astype(jnp.float32)[:, None, None], stat.shape)
+        l0 = jnp.ones_like(stat)
     _, l, acc = jax.lax.fori_loop(
         prefix_first_tile(start, tile, window),
         prefix_tiles_walked(start, q_len, tile, cache_len), fold,
-        (stat, jnp.zeros_like(stat),
+        (m0, l0,
          jnp.zeros((*q.shape[:-1], v_like.shape[-1]), jnp.float32)))
     return (acc / l).astype(v_like.dtype)
 
@@ -656,6 +688,11 @@ def splash_window_attention(q, k, v, *, window: int,
     return jax.vmap(one_seg)(qs, k, v, segment_ids)
 
 
+#: Queries from which the dense fallback of a sink or an unequal value
+#: head warns: 64 heads' float32 scores are 1 GiB a sequence there.
+DENSE_WARN_ROWS = 2048
+
+
 def multihead_attention_kernel(
     q: jax.Array,
     k: jax.Array,
@@ -668,6 +705,7 @@ def multihead_attention_kernel(
     sinks: int = 0,
     softmax_scale: Optional[float] = None,
     force_reference: bool = False,
+    sink_logits: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention on TPU, reference path elsewhere.
 
@@ -683,6 +721,13 @@ def multihead_attention_kernel(
     cross-length fall back to the exactly-masked oracle.  ``sinks``
     (StreamingLLM attention sinks, needs ``window``): the first
     ``sinks`` positions stay attendable past the window.
+
+    ``sink_logits`` [H] (a learned sink in the denominator) or a value
+    head of its own size takes the exactly-masked oracle: no kernel
+    here computes either, so a whole forward (training, evaluation, a
+    prompt in one call) holds [B, H, S, S] float32 scores, and says so
+    from ``DENSE_WARN_ROWS`` queries on.  The engine's pieces and decode
+    steps do not come here (``prefix_attention``, ``paged_attention``).
     """
     def _fold_segments(mask):
         """Dense same-segment mask (the packing restriction) — only for
@@ -696,6 +741,23 @@ def multihead_attention_kernel(
     if sinks and window is None:
         raise ValueError("sinks (attention sinks) only apply with a "
                          "sliding window")
+    if sink_logits is not None or v.shape[-1] != q.shape[-1]:
+        if q.shape[-2] >= DENSE_WARN_ROWS and not force_reference:
+            import warnings
+
+            warnings.warn(
+                f"attention with a learned sink logit or a value head "
+                f"of its own size ({v.shape[-1]} beside {q.shape[-1]}) "
+                f"runs the DENSE S×S path at seq={q.shape[-2]}: "
+                f"{q.shape[-3]} heads hold "
+                f"{q.shape[-3] * q.shape[-2] * k.shape[-2] * 4 / 2**30:.1f}"
+                f" GiB of float32 scores a sequence; no tiled kernel "
+                f"computes either outside the serving engine",
+                stacklevel=2)
+        return dot_product_attention(
+            q, k, v, causal=causal, mask=_fold_segments(mask),
+            window=window, sinks=sinks, softmax_scale=softmax_scale,
+            sink_logits=sink_logits)
     if window is not None:
         if not causal:
             raise ValueError("window (sliding-window attention) requires "

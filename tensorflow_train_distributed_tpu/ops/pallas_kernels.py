@@ -267,7 +267,8 @@ def ring_mask(lengths, q_len: int, rows: int, window: int):
 def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
                               k_scales=None, v_scales=None,
                               cache_len: Optional[int] = None,
-                              block0=0, window: Optional[int] = None):
+                              block0=0, window: Optional[int] = None,
+                              sink_logits=None):
     """Pure-jax oracle: gather-then-attend, the exact math of the
     engine's XLA block-gather leg (``models.layers`` ``_cache_attend``
     minus the sharding constraints, which are numerically no-ops).
@@ -286,7 +287,12 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
     is a RING (``table[lane, (p // block_size) % n_blk]`` holds
     position ``p``: ``ring_row_positions``) of at least ``window +
     q_len - 1`` rows, and whose query at ``p`` sees ``p - window <
-    row <= p``.  Returns [lanes, q_len, heads, head_dim]."""
+    row <= p``.  The value pool's rows may be narrower or wider than
+    the key pool's (a value head of its own size: ``v_pool.shape[2] //
+    kv_heads``, the output's head size).  ``sink_logits`` [heads]
+    float32: a learned sink a head in every row's softmax denominator
+    (``ops.attention.softmax_with_sink``).  Returns [lanes, q_len,
+    heads, the value head's size]."""
     from tensorflow_train_distributed_tpu.ops.attention import (
         dot_product_attention,
     )
@@ -299,7 +305,7 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
         c = table.shape[1] * bs             # the ring, whole
     kc = paged_kv_gather_reference(k_pool, table + block0, c)
     vc = paged_kv_gather_reference(v_pool, table + block0, c)
-    kc, vc = (t.reshape(lanes, c, kvh, hd) for t in (kc, vc))
+    kc, vc = (t.reshape(lanes, c, kvh, -1) for t in (kc, vc))
     if k_scales is not None:
         ks = paged_kv_gather_reference(k_scales, table, c)[..., None]
         vs = paged_kv_gather_reference(v_scales, table, c)[..., None]
@@ -316,7 +322,8 @@ def paged_attention_reference(q, k_pool, v_pool, table, lengths, *,
         mask = ring_mask(lengths, q_len, c, window)
     out = dot_product_attention(
         q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
-        vc.transpose(0, 2, 1, 3), mask=mask[:, None])
+        vc.transpose(0, 2, 1, 3), mask=mask[:, None],
+        sink_logits=sink_logits)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -359,9 +366,32 @@ def _paged_fold(bs: int, n_blk: int) -> int:
     return min(n_blk, max(1, 128 // bs))
 
 
+_LANES = 128        # the lane width of a vector tile
+
+
+def _key_spans(kvh: int, hd: int) -> tuple:
+    """``(first column, width)`` of the slice of a key row that the
+    kernel contracts KV head ``g``'s queries with.  A head's own
+    columns, ``[g * hd, (g + 1) * hd)``, where no head straddles the
+    edge of a lane tile (``hd`` a multiple of 128 or a whole fraction
+    of it) or the row is no whole tiles anyway (a test-size row).  Else
+    (a head of 192 in a row of whole tiles) the whole tiles that cover
+    them, one width for every head: the queries come in padded with
+    zeros over the neighbours' columns (``paged_attention``), so the
+    product is the head's own and no slice of the block is shifted
+    across lanes."""
+    row = kvh * hd
+    if hd % _LANES == 0 or _LANES % hd == 0 or row % _LANES:
+        return tuple((g * hd, hd) for g in range(kvh))
+    first = [g * hd // _LANES * _LANES for g in range(kvh)]
+    width = max(-(-(g + 1) * hd // _LANES) * _LANES - f
+                for g, f in enumerate(first))
+    return tuple((min(f, row - width), width) for f in first)
+
+
 def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                        bs, fold, last_row, kvh, rep, q_len, hd, scale,
-                       int8, window=None, ring=0):
+                       int8, vd, spans, sink, window=None, ring=0):
     """Grid (lane,): the lane walks the blocks it holds
     (``paged_blocks_walked``), ``fold`` table entries to a step, and
     stops there.  The pools stay in HBM; each step's blocks come in by
@@ -373,11 +403,17 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     ``row % q_len``.  A sliding ``window`` (static) starts the walk at
     ``paged_first_block``, finds a block in the lane's ``ring`` table
     entries by its number modulo ``ring``, and drops the rows behind
-    each query's window."""
+    each query's window.  ``vd``: the value head's size (a value row is
+    ``kvh * vd`` wide, as are the accumulator's and the output's
+    heads); ``spans``: ``_key_spans``; ``sink``: a [heads*q_len, 1]
+    input holds each row's sink logit, where its running maximum
+    starts, with a running sum of 1 (``softmax_with_sink``)."""
     from jax.experimental.pallas import tpu as pltpu
 
     if int8:
         ks_ref, vs_ref = rest[:2]
+    if sink:
+        sink_ref = rest[2 * int8]
     o_ref, k_buf, v_buf, sem, m_ref, l_ref, acc_ref = rest[-7:]
     i = pl.program_id(0)
     cur = len_ref[i]
@@ -400,12 +436,16 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
                     for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
         return out
 
-    m_ref[:] = jnp.full_like(m_ref, _NEG)
-    l_ref[:] = jnp.zeros_like(l_ref)
+    if sink:
+        m_ref[:] = sink_ref[:]
+        l_ref[:] = jnp.ones_like(l_ref)
+    else:
+        m_ref[:] = jnp.full_like(m_ref, _NEG)
+        l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
     for c in copies(0, 0):
         c.start()
-    qf = q_ref[0].astype(jnp.float32)        # [heads*q_len, hd]
+    qf = q_ref[0].astype(jnp.float32)        # [heads*q_len, span width]
     r = rep * q_len                          # rows per kv-head group
     n = fold * bs                            # cache rows a step folds
     # Causal through the table: row p visible to query qi iff
@@ -428,7 +468,7 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
         for c in copies(step, slot, wait=True):
             c.wait()
         kf = k_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
-        vf = v_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
+        vf = v_buf[slot].astype(jnp.float32).reshape(n, kvh * vd)
         if window is None:
             seen = step * n + col <= last_seen
         else:
@@ -439,8 +479,9 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             ksf, vsf = ks_ref[0, :, cols], vs_ref[0, :, cols]  # [kvh, n]
         for g in range(kvh):                 # static: tiny head count
             rows = slice(g * r, (g + 1) * r)
+            col0, width = spans[g]
             logits = jax.lax.dot_general(
-                qf[rows], kf[:, g * hd:(g + 1) * hd],
+                qf[rows], kf[:, col0:col0 + width],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale      # [r, n]
             if int8:
@@ -463,7 +504,7 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
             if int8:                         # and of its value row
                 p = jnp.where(seen, p * vsf[g:g + 1], 0.0)
             acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
-                p, vf[:, g * hd:(g + 1) * hd], (((1,), (0,)), ((), ())),
+                p, vf[:, g * vd:(g + 1) * vd], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_ref[rows] = m_new
 
@@ -475,6 +516,7 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
                     k_scales=None, v_scales=None,
                     cache_len: Optional[int] = None, block0=0,
                     window: Optional[int] = None,
+                    sink_logits=None,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False):
     """Flash-style decode attention DIRECTLY through the block table —
@@ -497,7 +539,12 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
     sliding-window layer's RING (``paged_attention_reference``): the
     walk starts at the window's first block (``paged_first_block``), so
     a lane reads the blocks its window spans whatever its context, and
-    ``cache_len`` is the context's, not the ring's."""
+    ``cache_len`` is the context's, not the ring's.  A value pool of
+    another width than the key pool's (a value head of its own size)
+    is copied as it lies too; a key head that is no whole number of
+    lane tiles wide is contracted over the whole tiles that cover it
+    (``_key_spans``).  ``sink_logits`` [heads]: each row's running
+    softmax starts from its head's sink, which costs no block."""
     if window is not None:
         if k_scales is not None:
             raise ValueError("a window layer's ring holds no int8 rows")
@@ -508,17 +555,29 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         return paged_attention_reference(
             q, k_pool, v_pool, table, lengths, k_scales=k_scales,
             v_scales=v_scales, cache_len=cache_len, block0=block0,
-            window=window)
+            window=window, sink_logits=sink_logits)
     from jax.experimental.pallas import tpu as pltpu
 
     bs = k_pool.shape[1]
     lanes, q_len, heads, hd = q.shape
     kvh = k_pool.shape[2] // hd
+    vd = v_pool.shape[2] // kvh
     n_blk = table.shape[1]
     if heads % kvh:
         raise ValueError(f"heads {heads} not a multiple of kv_heads "
                          f"{kvh}")
     rep = heads // kvh
+    spans = _key_spans(kvh, hd)
+    if spans[0][1] != hd:
+        # Each head's queries at their place in the span of its KV
+        # head, zeros over the columns that are a neighbour's.
+        width = spans[0][1]
+        groups = q.reshape(lanes, q_len, kvh, rep, hd)
+        q = jnp.stack(
+            [jnp.pad(groups[:, :, g], ((0, 0),) * 3 + (
+                (g * hd - col0, col0 + width - (g + 1) * hd),))
+             for g, (col0, _) in enumerate(spans)],
+            axis=2).reshape(lanes, q_len, heads, width)
     int8 = k_scales is not None
     fold = _paged_fold(bs, n_blk)
     last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
@@ -528,9 +587,12 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         walk = dict(window=window, ring=n_blk)
     # [lanes, q_len, H, hd] → [lanes, H*q_len, hd]: row = h*q_len + qi,
     # so each kv-head group's rows are contiguous in the kernel.
-    qt = q.transpose(0, 2, 1, 3).reshape(lanes, heads * q_len, hd)
-    rows = pl.BlockSpec((1, heads * q_len, hd),
+    qt = q.transpose(0, 2, 1, 3).reshape(lanes, heads * q_len,
+                                         q.shape[-1])
+    rows = pl.BlockSpec((1, heads * q_len, q.shape[-1]),
                         lambda i, tbl, lens: (i, 0, 0))
+    out_rows = pl.BlockSpec((1, heads * q_len, vd),
+                            lambda i, tbl, lens: (i, 0, 0))
     in_specs = [rows] + [pl.BlockSpec(memory_space=pltpu.HBM)] * 2
     args = [table + block0, lengths.astype(jnp.int32), qt, k_pool, v_pool]
     if int8:
@@ -542,11 +604,17 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         args += [jnp.take(s, wide, axis=0, mode="clip")
                  .reshape(lanes, -1, kvh).transpose(0, 2, 1)
                  for s in (k_scales, v_scales)]
+    if sink_logits is not None:
+        in_specs += [pl.BlockSpec((heads * q_len, 1),
+                                  lambda i, tbl, lens: (0, 0))]
+        args += [jnp.repeat(sink_logits.astype(jnp.float32), q_len)
+                 [:, None]]
     out = pl.pallas_call(
         functools.partial(
             _paged_attn_kernel, bs=bs, fold=fold, last_row=last_row,
             kvh=kvh, rep=rep, q_len=q_len, hd=hd, scale=hd ** -0.5,
-            int8=int8, **walk),
+            int8=int8, vd=vd, spans=spans, sink=sink_logits is not None,
+            **walk),
         # No name= here: a name becomes the HLO instruction's, and the
         # benchmark finds this kernel's device events by the name the
         # calling method gives it (``attention._paged_decode_step``).
@@ -554,21 +622,21 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
             num_scalar_prefetch=2,
             grid=(lanes,),
             in_specs=in_specs,
-            out_specs=rows,
+            out_specs=out_rows,
             scratch_shapes=[
                 pltpu.VMEM((2, fold, bs, kvh * hd), k_pool.dtype),
-                pltpu.VMEM((2, fold, bs, kvh * hd), v_pool.dtype),
+                pltpu.VMEM((2, fold, bs, kvh * vd), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((heads * q_len, 1), jnp.float32),
                 pltpu.VMEM((heads * q_len, 1), jnp.float32),
-                pltpu.VMEM((heads * q_len, hd), jnp.float32),
+                pltpu.VMEM((heads * q_len, vd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes, heads * q_len, hd),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads * q_len, vd),
                                        q.dtype),
         interpret=interpret,
     )(*args)
-    return out.reshape(lanes, heads, q_len, hd).transpose(0, 2, 1, 3)
+    return out.reshape(lanes, heads, q_len, vd).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------

@@ -86,7 +86,10 @@ dense family's one global ``sliding_window`` keep the shared-index
 ``generate()`` path.
 
 **Window and full attention layers side by side** (a ``MoeConfig`` with
-``attn_period``): two kinds of cache in the one slot grid.  A full layer
+``attn_period`` after ``attn_lead``): two kinds of cache in the one
+slot grid, each kind with its own row (``KvKind.num_kv_heads``; a key
+and a value head of different sizes make a key and a value pool of
+different widths).  A full layer
 holds a lane's whole context in the blocks of its table, as above.  A
 window layer holds, in a pool of its own, a RING of ``ring_blocks``
 blocks a lane (``ceil(window / kv_block_size) + 1``: a decode step adds
@@ -407,16 +410,23 @@ class ServingEngine:
         # caches both quantize with the linear-cache recipe), and so do
         # window layers that a MoeConfig's ``attn_period`` names (a ring
         # of blocks a lane, below).  The dense family's ONE global
-        # ``sliding_window`` and attention sinks stay generate()-only:
-        # their rolling cache and sink buffer have no per-slot form yet.
+        # ``sliding_window`` and attention sinks (StreamingLLM: the
+        # first ROWS kept attendable past the window) stay
+        # generate()-only: their rolling cache and sink buffer have no
+        # per-slot form yet.  A learned sink LOGIT in a window layer's
+        # softmax (``KvKind.sink``) is another thing, a float a head
+        # with no row behind it, and is served.
         if (getattr(config, "sliding_window", None) is not None
                 or getattr(config, "attention_sinks", 0)):
             raise ValueError(
                 "the serving engine holds a window layer's rows in a "
                 "ring only where the config names its layers' kinds "
                 "(MoeConfig.attn_period); a LlamaConfig's global "
-                "sliding_window and attention_sinks serve through "
-                "models.generate (kv_cache_int8 is supported here)")
+                "sliding_window and attention_sinks (StreamingLLM sink "
+                "ROWS kept past the window) serve through "
+                "models.generate; a learned sink LOGIT in a window "
+                "layer's softmax (KvKind.sink) holds no row and is "
+                "served (kv_cache_int8 is supported here)")
         if has_lora_leaves(params):
             raise ValueError(
                 "merge LoRA adapters before engine serving: params = "

@@ -145,6 +145,10 @@ import benchmark.harness.serve_pattern  # noqa: E402,F401
 # The same stop-gap, for the family of a decoder with linear-attention
 # layers beside latent ones (``serve_hybrid.py`` registers "moe_hybrid").
 import benchmark.harness.serve_hybrid  # noqa: E402,F401
+# The same stop-gap, for the family of a decoder whose full and window
+# layers differ in their KV heads and carry a sink (``serve_sink.py``
+# registers "moe_sink").
+import benchmark.harness.serve_sink  # noqa: E402,F401
 
 
 # ``tests/benchmark/test_benchmark_deepseek_v32.py::
@@ -222,8 +226,57 @@ _LAST_TWO_PER_LAYER = (
     "the_cells_that_count_calls[prefill_pieces_per_call.serve]")
 
 
+# Two tests of ``tests/benchmark/test_benchmark_ling.py`` pin the
+# manifest's TAIL: ``test_new_cells_traffic_and_metrics_are_found_by_
+# name`` asserts that Ling's nine readers are the last nine of
+# ``per_layer`` and that its cell's name ENDS the lists it was appended
+# to; ``test_the_call_counts_read_as_before_the_hybrid_metrics_were_
+# appended`` asserts the same tail and that the last configuration and
+# cell are Ling's.  The next PR that adds a cell appends after them
+# (``mimo-v25-1chip.agent-context`` and its ``.agent`` readers) and may
+# not edit that file.  ``tests/benchmark/test_benchmark_mimo.py::
+# test_the_tests_that_pin_the_manifest_run_whole_as_it_was`` runs
+# both functions, every assertion of them (and through the second the
+# tests it runs in turn), on the manifest as it was before the later
+# cell, which it finds BY NAME.  The same stop-gap as the five above,
+# strict for the same reason: the `benchmark` PR makes those assertions
+# membership and deletes this.
+_LINGS_TAIL = (
+    "test_benchmark_ling.py::"
+    "test_new_cells_traffic_and_metrics_are_found_by_name",
+    "test_benchmark_ling.py::test_the_call_counts_read_as_before_the_"
+    "hybrid_metrics_were_appended")
+# A third pin of the same kind, on a list's WHOLE and not its tail:
+# ``tests/benchmark/test_benchmark_laguna.py::test_new_cells_traffic_
+# and_metrics_are_found_by_name`` asserts that each of Laguna's nine
+# ``.mixed`` readers lists Laguna's cell ALONE.  Five of them
+# (``attn_full_ms``, ``attn_window_ms``, ``moe_experts_ms``,
+# ``experts_hit_mean``, ``window_rows_share``) read ``mimo-v25-1chip.
+# agent-context`` as they stand, so its name is appended to their lists
+# and no copy of a reader is added.  The same test of the same PR runs
+# it whole on the manifest as it was; the `benchmark` PR makes the
+# assertion ``CELL in`` and deletes this.
+_LAGUNAS_OWN = (
+    "test_benchmark_laguna.py::"
+    "test_new_cells_traffic_and_metrics_are_found_by_name",)
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_LINGS_TAIL):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts that Ling's readers, configuration and "
+                       "cell are the last of their lists; run whole on "
+                       "the manifest as it was by test_the_tests_that_"
+                       "pin_the_manifest_run_whole_as_it_was",
+                strict=True))
+        if item.nodeid.endswith(_LAGUNAS_OWN):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts that each of Laguna's .mixed readers "
+                       "lists Laguna's cell alone; run whole on the "
+                       "manifest as it was by test_the_tests_that_pin_"
+                       "the_manifest_run_whole_as_it_was",
+                strict=True))
         if item.nodeid.endswith(_LAST_TWO_PER_LAYER):
             item.add_marker(pytest.mark.xfail(
                 reason="asserts its two metrics are the last of "

@@ -142,6 +142,26 @@ def _paged_ring(heads, q_len):
          ((lanes, ring), jnp.int32), ((lanes,), jnp.int32)))
 
 
+def _paged_sink(window):
+    """MiMo-V2.5's decode attention at its published sizes, as the
+    cell's engine calls it: 64 query heads of 192 over values of 128;
+    a full layer's 4 KV heads (16 queries a head, rows of 768 + 512
+    values) over a context of 26,624 rows, or a window layer's 8 over
+    a ring of 9 blocks of 16 rows with a sink logit a head."""
+    lanes, heads, hd, vd, bs, cache_len = 8, 64, 192, 128, 16, 26624
+    kvh, n_blk = (8, 9) if window else (4, cache_len // bs)
+    nb = 1 + lanes * n_blk
+    shapes = (((lanes, 1, heads, hd), BF16), ((nb, bs, kvh * hd), BF16),
+              ((nb, bs, kvh * vd), BF16), ((lanes, n_blk), jnp.int32),
+              ((lanes,), jnp.int32))
+    if not window:
+        return (lambda q, k, v, t, n: pk.paged_attention(
+            q, k, v, t, n, cache_len=cache_len, use_pallas=True), shapes)
+    return (lambda q, k, v, t, n, s: pk.paged_attention(
+        q, k, v, t, n, cache_len=cache_len, window=window, sink_logits=s,
+        use_pallas=True), shapes + (((heads,), jnp.float32),))
+
+
 def _paged_latent(q_len):
     """GLM-4.7-Flash's absorbed decode kernel at its published sizes:
     20 heads over rows of 512 + 64 values stored 640 wide, blocks of
@@ -199,6 +219,8 @@ CASES = {
     "flash-grad": lambda: _flash(True),
 }
 CASES["delta_state_step-l64h32d128"] = _delta_step
+CASES["paged_attn-h64kv4k192v128"] = lambda: _paged_sink(None)
+CASES["paged_ring-h64kv8k192v128-w128-sink"] = lambda: _paged_sink(128)
 for _q in (1, 3):
     for _h in (48, 72):
         CASES[f"paged_ring-h{_h}kv8d128-w512-q{_q}"] = (
