@@ -320,18 +320,52 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, epsilon=self.epsilon).astype(self.dtype)
 
 
+def _one_way_mesh() -> bool:
+    """No ambient mesh is more than one way: the hand kernels are
+    single-device, so sharded serving keeps the XLA paths GSPMD can
+    partition."""
+    mesh = compat.get_abstract_mesh()
+    return (mesh is None or mesh.empty
+            or all(v <= 1 for v in mesh.shape.values()))
+
+
 def fused_paged_ok() -> bool:
     """Whether a paged decode step should run its fused kernel: the
     env/backend decision (``use_fused_paged_attention``), vetoed under
-    any >1-way ambient mesh — sharded serving keeps the XLA gather path
-    so GSPMD can partition it (the hand kernels are single-device)."""
+    any >1-way ambient mesh (``_one_way_mesh``)."""
     from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
 
-    mesh = compat.get_abstract_mesh()
-    if (mesh is not None and not mesh.empty
-            and any(v > 1 for v in mesh.shape.values())):
-        return False
-    return pk.use_fused_paged_attention()
+    return _one_way_mesh() and pk.use_fused_paged_attention()
+
+
+def flash_walk_ok(q_len: int, k_cache, v_cache, scales=None) -> bool:
+    """Whether attention of ``q_len`` queries a lane over a linear cache
+    (``MultiHeadAttention._cache_attend`` with ``start``) runs
+    ``pallas_kernels.prefix_flash_attention``: plain rows (no int8
+    ``scales``) that the kernel takes (``prefix_flash_engages``: bf16,
+    whole query blocks, whole lane tiles, the backend's decision) and
+    no >1-way ambient mesh.  Everything else walks in XLA
+    (``ops.attention.prefix_attention``)."""
+    from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
+
+    return (scales is None and _one_way_mesh()
+            and pk.prefix_flash_engages(q_len, k_cache, v_cache))
+
+
+def flash_walk_layers(cache, q_len: int) -> int:
+    """Attention layers of a linear ``cache`` tree (a model's "cache"
+    collection) whose walk of a call of ``q_len`` queries runs the
+    kernel (``flash_walk_ok``): every ``key_cache`` beside a
+    ``value_cache`` and no ``kv_scales``, a depth scan's stacked
+    layers each counted."""
+    n = 0
+    if isinstance(cache, dict):
+        k, v = cache.get("key_cache"), cache.get("value_cache")
+        if k is not None and v is not None and flash_walk_ok(
+                q_len, k, v, cache.get("kv_scales")):
+            n += math.prod(k.shape[:-4])
+        n += sum(flash_walk_layers(c, q_len) for c in cache.values())
+    return n
 
 
 class MultiHeadAttention(nn.Module):
@@ -1134,13 +1168,17 @@ class MultiHeadAttention(nn.Module):
         grouped heads and dequantizes int8 rows (``scales``: the keys'
         and the values', [B, rows, kv_heads]) a tile at a time, so a row
         no lane holds is not read.  A cache of one tile is the ordinary
-        masked attention over all of it.  A ring (the rolling window
+        masked attention over all of it.  Where the rows are plain
+        bf16 and the call is long enough (``flash_walk_ok``) the same
+        walk is one kernel (``pallas_kernels.prefix_flash_attention``).
+        A ring (the rolling window
         and its sinks, a paged window layer's gathered ring) addresses
         no prefix: it hands in its own ``mask`` over every row.
         ``window`` narrows a linear cache's walk to the tiles a sliding
         window reaches (``prefix_attention``).  ``sink_logits``
         [num_heads]: the learned sink of every row's softmax
         (``sink``)."""
+        from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
         from tensorflow_train_distributed_tpu.ops.attention import (
             dot_product_attention, prefix_attention,
         )
@@ -1171,6 +1209,13 @@ class MultiHeadAttention(nn.Module):
             out = dot_product_attention(qh, *heads((kc, vc, scales)),
                                         mask=mask,
                                         sink_logits=sink_logits)
+        elif flash_walk_ok(q_len, kc, vc, scales):
+            # The same walk as one kernel: each of its query blocks
+            # walks its own tiles, so ``query_block`` has nothing to
+            # add.
+            out = pk.prefix_flash_attention(
+                qh, kc, vc, start, window=window, sink_logits=sink_logits,
+                interpret=pk.fused_attn_interpret())
         else:
             out = prefix_attention(qh, (kc, vc, scales), start, heads,
                                    window=window,

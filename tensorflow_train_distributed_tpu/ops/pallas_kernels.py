@@ -49,7 +49,13 @@ Two serving-side kernels back the engine's paged KV cache:
   ``TTD_FUSED_ATTN_INTERPRET=1`` forces the kernel in interpret mode
   off-TPU (the CPU parity-test path).
 
-Both have pure-jax references (the CPU path and the numerics oracle) and
+A prefill piece's attention over a LINEAR cache of plain bf16 rows is
+``prefix_flash_attention``: ``ops.attention.prefix_attention``'s walk
+(its oracle, and the path of every other cache) as one kernel, each
+query block over its own tiles, a KV head's tile met by all its query
+heads once, the scores never out of fast memory.
+
+All have pure-jax references (the CPU path and the numerics oracle) and
 run in interpreter mode in tests (``interpret=True``); kernel layout
 follows ``/opt/skills/guides/pallas_guide.md`` (f32 accumulation, 128-lane
 blocks, grid innermost over the reduction axis).
@@ -637,6 +643,316 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         interpret=interpret,
     )(*args)
     return out.reshape(lanes, heads, q_len, vd).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Prefix flash attention (a prefill piece over a linear cache of plain rows)
+# ---------------------------------------------------------------------------
+
+#: Queries of each head that one grid step of ``prefix_flash_attention``
+#: holds, and cache rows it meets them with (chosen on the chip from a
+#: sweep: PERF.md section 6, PR 42; a window layer's tiles are no
+#: longer than its window, in steps of 256 rows).
+PREFIX_FLASH_BLOCK_Q = 512
+PREFIX_FLASH_TILE = 1024
+#: Fast memory the kernel may hold: sixteen heads' query block, output
+#: block and accumulator beside a tile's scores are past the default
+#: 16 MB of scoped VMEM; a v5e core has 128 MiB.
+_PREFIX_FLASH_VMEM = 64 << 20
+
+
+def _prefix_key_blocks(kvh: int, hd: int) -> tuple:
+    """``(width, cw)``: the columns of a key row [kvh * hd] that hold
+    one KV head's keys (``_key_spans``' one width: the head's own, or
+    the whole lane tiles that cover a head of one and a half) and the
+    column block they are fetched in: a lane tile, ``width // cw`` of
+    them side by side, or the head itself where a row is no whole
+    tiles (a test-size row)."""
+    width = _key_spans(kvh, hd)[0][1]
+    return width, _LANES if width % _LANES == 0 else width
+
+
+def prefix_flash_engages(q_len: int, k_cache, v_cache) -> bool:
+    """Whether a walk of ``q_len`` queries a lane over these caches
+    [B, C, kv_heads, D] / [B, C, kv_heads, Dv] runs
+    ``prefix_flash_attention`` and not ``ops.attention.
+    prefix_attention``, by what the call can see: bf16 rows, whole
+    query blocks, a value head and a key head's span of whole lane
+    tiles (what the compiled kernel's blocks need), and the backend
+    decision every kernel here shares."""
+    kvh, hd = k_cache.shape[-2:]
+    return (k_cache.dtype == jnp.bfloat16 == v_cache.dtype
+            and q_len >= PREFIX_FLASH_BLOCK_Q
+            and q_len % PREFIX_FLASH_BLOCK_Q == 0
+            and _prefix_key_blocks(kvh, hd)[1] == _LANES
+            and v_cache.shape[-1] % _LANES == 0
+            and _use_pallas(None))
+
+
+def _prefix_block_tiles(p0, bq: int, tk: int, cache_len: int,
+                        window: Optional[int]):
+    """``(first, end)``: the tiles ``[first, end)`` of ``tk`` cache rows
+    that the ``bq`` queries at positions ``p0 .. p0 + bq - 1`` of one
+    lane walk: ``ops.attention``'s rule for a call (``prefix_first_
+    tile``, ``prefix_tiles_walked``) at a query block's size, so a tile
+    wholly above the block's diagonal, past the cache or behind its
+    window is in no block's range."""
+    from tensorflow_train_distributed_tpu.ops import attention
+
+    return (attention.prefix_first_tile(p0, tk, window),
+            attention.prefix_tiles_walked(p0, bq, tk, cache_len))
+
+
+def _prefix_flash_kernel(start_ref, q_ref, *rest, bq, tk, n_kb, offsets,
+                         last_col, cache_len, scale, window, sink):
+    """Grid (lane, KV head, query block, key tile).  A step holds the
+    ``bq`` queries of every query head of one KV head, [rep, bq, hd],
+    and ONE copy of that head's tile of keys (``n_kb`` column blocks
+    side by side, the head's own ``hd`` columns at one of ``offsets``
+    in them) and values [tk, vd]; each head's running maximum, sum and
+    float32 accumulator stay in scratch across the block's tiles, and
+    its scores live and die inside the step.  They are held
+    TRANSPOSED, [tk, bq] (keys down the sublanes, queries along the
+    lanes), and so is the accumulator, [vd, bq]: a query's maximum,
+    sum and rescaling factor are then whole lane-dense rows [1, bq]
+    and a reduction over keys is elementwise across vector registers,
+    where rows of queries would leave every statistic one lane wide
+    (measured: PERF.md section 6, PR 42).  Step ``j`` of a
+    block is tile ``first + j`` of its own range
+    (``_prefix_block_tiles``); steps past the range compute nothing
+    (and fetch nothing: the index maps repeat the last live tile).
+
+    A masked entry's score is ``_NEG`` and a running maximum never
+    below ``_NEG / 2``, so its probability is ``exp(<= _NEG / 2)``, 0,
+    whether or not its tile holds a row its query sees; a tile every
+    query of the block sees whole skips the mask.  The last tile of a
+    cache that is no whole tiles runs past its rows: what lies there
+    is set to zero before any product."""
+    k_refs, v_ref = rest[:n_kb], rest[n_kb]
+    sink_ref = rest[n_kb + 1] if sink else None
+    (o_ref, m_ref, l_ref, acc_ref, bias_ref, k_tile, v_tile,
+     vt_ref) = rest[-8:]
+    b, g, iq, j = (pl.program_id(a) for a in range(4))
+    rep, _, hd = q_ref.shape
+    p0 = start_ref[b] + iq * bq
+    first, end = _prefix_block_tiles(p0, bq, tk, cache_len, window)
+    t = first + j
+    ragged = bool(cache_len % tk)
+    cw = k_refs[0].shape[-1]
+    # Where the head's columns begin in the blocks fetched for it.
+    off_g = g * hd - jnp.minimum(g * hd // cw, last_col) * cw
+
+    @pl.when(j == 0)
+    def _():
+        if sink:
+            m0 = jnp.maximum(sink_ref[:], _NEG / 2)
+            m_ref[:] = jnp.broadcast_to(m0, m_ref.shape)
+            l_ref[:] = jnp.broadcast_to(jnp.exp(sink_ref[:] - m0),
+                                        l_ref.shape)
+        else:
+            m_ref[:] = jnp.full_like(m_ref, _NEG / 2)
+            l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def fold(masked: bool):
+        k_src, v_src = k_refs[0], v_ref
+        zero_past = masked and ragged
+        if zero_past:
+            held = t * tk + jax.lax.broadcasted_iota(
+                jnp.int32, (tk, 1), 0) < cache_len
+            v_tile[:] = jnp.where(held, v_ref[:], jnp.zeros_like(v_tile))
+            v_src = v_tile
+        if zero_past or n_kb > 1 or offsets != (0,):
+            kk = k_refs[0][:] if n_kb == 1 else jnp.concatenate(
+                [r[:] for r in k_refs], axis=-1)
+            for off in offsets:
+                @pl.when(off_g == off)
+                def _(off=off):
+                    k = kk[:, off:off + hd]
+                    if zero_past:
+                        k = jnp.where(held, k, jnp.zeros_like(k))
+                    k_tile[:] = k
+            k_src = k_tile
+        if masked:
+            pos = p0 + jax.lax.broadcasted_iota(jnp.int32, (tk, bq), 1)
+            kv_pos = t * tk + jax.lax.broadcasted_iota(
+                jnp.int32, (tk, bq), 0)
+            ok = kv_pos <= pos
+            if window is not None:
+                ok &= pos - kv_pos < window
+            bias_ref[:] = jnp.where(ok, 0.0, _NEG)
+
+        vt_ref[:] = v_src[:].T
+
+        def head(h, _):
+            s = jax.lax.dot_general(
+                k_src[:], q_ref[h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [tk, bq]
+            if masked:
+                s = s + bias_ref[:]
+            m_prev = m_ref[h]                                # [1, bq]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                vt_ref[:], p.astype(vt_ref.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)          # [vd, bq]
+            m_ref[h] = m_new
+
+        jax.lax.fori_loop(0, rep, head, None)
+
+    # Whole: the tile's last row is no later than the block's first
+    # query, and its first row inside the window of the block's last.
+    whole = (t + 1) * tk - 1 <= p0
+    if window is not None:
+        whole &= p0 + bq - 1 - t * tk < window
+    live = t < end
+    pl.when(live & whole)(lambda: fold(False))
+    pl.when(live & jnp.logical_not(whole))(lambda: fold(True))
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _():
+        def out(h, _):
+            o_ref[h] = (acc_ref[h] / l_ref[h]).T.astype(o_ref.dtype)
+
+        jax.lax.fori_loop(0, rep, out, None)
+
+
+def prefix_flash_attention(q, k_cache, v_cache, start, *,
+                           window: Optional[int] = None,
+                           sink_logits=None,
+                           softmax_scale: Optional[float] = None,
+                           interpret: bool = False):
+    """``ops.attention.prefix_attention`` (the numerics oracle this is
+    tested against, and the path of every other cache) for a linear
+    cache of plain rows, as ONE kernel: ``q`` [B, H, Q, D] over
+    ``k_cache`` [B, C, kv_heads, D] and ``v_cache`` [B, C, kv_heads,
+    Dv], lane ``b``'s queries at positions ``start[b] + arange(Q)``;
+    [B, H, Q, Dv].
+
+    The caches are read a (tile, one KV head's columns) block at a
+    time, out of rows flattened to [C, kv_heads * D] (a copy: see
+    below): nothing is repeated for grouped heads, and the ``H //
+    kv_heads`` query heads of a KV head meet one copy of its tile.  A
+    key head that is no whole number of lane tiles wide comes in as
+    the whole tiles that cover it (``_key_spans``) and is cut out of
+    them in fast memory.  Each
+    block of ``PREFIX_FLASH_BLOCK_Q`` queries walks its own tiles
+    (``_prefix_block_tiles``: the rule of a call of that many queries
+    at the block's position), so a call over k pieces of a prompt costs
+    the k pieces' tiles and gives the bits of the pieces run apart;
+    ``start`` is scalar-prefetched and the grid is static: one compiled
+    program whatever the lanes hold.  ``window`` and ``sink_logits``
+    [H] as ``prefix_attention``'s.  The arithmetic is its too (products
+    of the inputs' type accumulated in float32, softmax in float32,
+    probabilities cast to the values' type before the second product),
+    but for the scores, which are never rounded to the inputs' type on
+    their way to the softmax."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads, q_len, hd = q.shape
+    cache_len, kvh = k_cache.shape[1:3]
+    vd = v_cache.shape[-1]
+    if heads % kvh:
+        raise ValueError(f"heads {heads} not a multiple of kv_heads "
+                         f"{kvh}")
+    bq, tk = PREFIX_FLASH_BLOCK_Q, PREFIX_FLASH_TILE
+    if window is not None:
+        # A block's walk under a window spans few rows: tiles no longer
+        # than the window (in steps of 256 rows) leave less of them
+        # behind it.
+        tk = min(tk, -(-window // 256) * 256)
+    if q_len % bq:
+        raise ValueError(f"{q_len} queries are no whole blocks of {bq}")
+    rep = heads // kvh
+    scale = softmax_scale if softmax_scale is not None else hd ** -0.5
+    width, cw = _prefix_key_blocks(kvh, hd)
+    n_kb = width // cw
+    offsets = tuple(sorted({g * hd - col0 for g, (col0, _) in
+                            enumerate(_key_spans(kvh, hd))}))
+    start = jnp.broadcast_to(
+        jnp.asarray(start, jnp.int32).reshape(-1), (lanes,))
+    if window is not None:
+        # The kernel's blocks want a row's heads side by side, which a
+        # cache [C, kv_heads, D] in the chip's tiled layout is not: the
+        # flattening below is a copy.  A window layer's walk stays
+        # inside the rows its window and the call's own reach, from
+        # the first tile's first row on: the copy is of those.
+        rows = q_len + -(-(window + tk - 2) // tk) * tk
+        if rows < cache_len:
+            row0 = jnp.minimum((start - (window - 1)).clip(0) // tk * tk,
+                               cache_len - rows)
+            k_cache, v_cache = (
+                jax.vmap(lambda c, r: jax.lax.dynamic_slice_in_dim(
+                    c, r, rows, 0))(c, row0) for c in (k_cache, v_cache))
+            start, cache_len = start - row0, rows
+    n_tiles = -(-cache_len // tk)
+    # Tiles the longest walk of one block spans: the cache's, or what
+    # a window and the block's own rows touch at the worst alignment.
+    steps = n_tiles if window is None else min(
+        n_tiles, (window + bq - 3) // tk + 2)
+    last_col = kvh * hd // cw - n_kb
+
+    def tile(b, iq, j, start_ref):
+        first, end = _prefix_block_tiles(start_ref[b] + iq * bq, bq, tk,
+                                         cache_len, window)
+        return jnp.minimum(first + j, end - 1)
+
+    def key_block(part):
+        return pl.BlockSpec(
+            (None, tk, cw),
+            lambda b, g, iq, j, start_ref: (
+                b, tile(b, iq, j, start_ref),
+                jnp.minimum(g * hd // cw, last_col) + part))
+
+    def query_rows(last):
+        return pl.BlockSpec(
+            (None, None, rep, bq, last),
+            lambda b, g, iq, j, start_ref: (b, g, 0, iq, 0))
+
+    in_specs = [query_rows(hd)] + [key_block(p) for p in range(n_kb)]
+    in_specs.append(pl.BlockSpec(
+        (None, tk, vd),
+        lambda b, g, iq, j, start_ref: (b, tile(b, iq, j, start_ref), g)))
+    args = [start, q.reshape(lanes, kvh, rep, q_len, hd)]
+    args += [k_cache.reshape(lanes, cache_len, -1)] * n_kb
+    args.append(v_cache.reshape(lanes, cache_len, -1))
+    if sink_logits is not None:
+        in_specs.append(pl.BlockSpec(
+            (None, rep, 1, 1), lambda b, g, iq, j, start_ref: (g, 0, 0, 0)))
+        args.append(sink_logits.astype(jnp.float32).reshape(
+            kvh, rep, 1, 1))
+    out = pl.pallas_call(
+        functools.partial(
+            _prefix_flash_kernel, bq=bq, tk=tk, n_kb=n_kb,
+            offsets=offsets, last_col=last_col, cache_len=cache_len,
+            scale=scale, window=window, sink=sink_logits is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(lanes, kvh, q_len // bq, steps),
+            in_specs=in_specs,
+            out_specs=query_rows(vd),
+            scratch_shapes=[
+                pltpu.VMEM((rep, 1, bq), jnp.float32),
+                pltpu.VMEM((rep, 1, bq), jnp.float32),
+                pltpu.VMEM((rep, vd, bq), jnp.float32),
+                pltpu.VMEM((tk, bq), jnp.float32),
+                pltpu.VMEM((tk, hd), k_cache.dtype),
+                pltpu.VMEM((tk, vd), v_cache.dtype),
+                pltpu.VMEM((vd, tk), v_cache.dtype),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, kvh, rep, q_len, vd),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_PREFIX_FLASH_VMEM),
+        interpret=interpret,
+        name="prefix_flash_attention",
+    )(*args)
+    return out.reshape(lanes, heads, q_len, vd)
 
 
 # ---------------------------------------------------------------------------

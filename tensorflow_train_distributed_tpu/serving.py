@@ -172,6 +172,7 @@ from tensorflow_train_distributed_tpu.models.generate import (
     has_lora_leaves,
     validate_sampling,
 )
+from tensorflow_train_distributed_tpu.models.layers import flash_walk_layers
 from tensorflow_train_distributed_tpu.models.quant import (
     check_quant_pairing,
     maybe_quant_variables,
@@ -753,6 +754,7 @@ class ServingEngine:
                            "drafted": 0, "drafted_accepted": 0,
                            "emitted": 0}
         self._cache_shapes: dict = {}  # (draft, batch, grid) -> eval_shape
+        self._flash_layer_counts: dict = {}   # (draft, q_len) -> layers
         self._moe_prefill_lens: set = set()  # distinct exact-prefill lens
         # Linear-path prefix caches (paged mode subsumes them via the
         # radix index): LRU-BOUNDED — keyed by tuple(tokens), these
@@ -1678,6 +1680,17 @@ class ServingEngine:
             shapes = jax.eval_shape(shape_fn, variables)
             self._cache_shapes[key] = shapes
         return shapes
+
+    def _flash_layers(self, draft: bool, q_len: int) -> int:
+        """Attention layers of the batch-1 prefill model whose walk of
+        a call of ``q_len`` tokens runs the kernel
+        (``layers.flash_walk_layers``), for ``prefill/piece``; under
+        ``_ctx`` (a mesh vetoes the kernel)."""
+        key = (draft, q_len)
+        if key not in self._flash_layer_counts:
+            self._flash_layer_counts[key] = flash_walk_layers(
+                self._cache_struct(1, draft), q_len)
+        return self._flash_layer_counts[key]
 
     # Memory discipline (ttd-lint memcheck + TTD_MEMCHECK=1): THE
     # engine allocator — every cache tree this engine mints on device
@@ -2784,7 +2797,8 @@ class ServingEngine:
                 piece=task.cursor + task.d_cursor, pieces=k,
                 n_pieces=task.n_pieces, tokens=real, rows=rows,
                 select_rows=select_rows, window_rows=window_rows,
-                cache_rows=self.cache_len):
+                cache_rows=self.cache_len,
+                flash_layers=self._flash_layers(draft, k * task.piece)):
             if (task.d_cache_1 if draft else task.cache_1) is None:
                 with events.span("prefill/cache", rid=task.request_id,
                                  kind=self._admission_kind(task.pre_pair,

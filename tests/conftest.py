@@ -105,6 +105,34 @@ def walk_in_tiles(monkeypatch):
     return set_tile
 
 
+@pytest.fixture
+def flash_interpreted(monkeypatch):
+    """``flash_interpreted(block_q, tile)``: programs traced from here
+    on hand every linear-cache walk of whole query blocks to
+    ``pallas_kernels.prefix_flash_attention``, interpreted, at these
+    sizes, whatever the rows' type and width (a float32 program at test
+    size; the compiled kernel's own rule is
+    ``prefix_flash_engages``); returns the list that collects the
+    query length of every kernel call traced."""
+    from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
+
+    def engage(block_q, tile):
+        calls, kernel = [], pk.prefix_flash_attention
+        monkeypatch.setattr(
+            pk, "prefix_flash_attention",
+            lambda q, *a, **kw: calls.append(q.shape[2]) or kernel(
+                q, *a, **kw))
+        monkeypatch.setattr(pk, "PREFIX_FLASH_BLOCK_Q", block_q)
+        monkeypatch.setattr(pk, "PREFIX_FLASH_TILE", tile)
+        monkeypatch.setattr(pk, "fused_attn_interpret", lambda: True)
+        monkeypatch.setattr(
+            pk, "prefix_flash_engages",
+            lambda q_len, k, v: q_len >= block_q and q_len % block_q == 0)
+        return calls
+
+    return engage
+
+
 @pytest.fixture(scope="session")
 def mesh_2d():
     """2×4 data×tensor mesh (the DTensor-style 2-D layout)."""
@@ -164,6 +192,20 @@ import benchmark.harness.serve_sink  # noqa: E402,F401
 # those lists.  The same stop-gap as above, strict for the same reason.
 _LAST_IN_ITS_LISTS = ("test_benchmark_deepseek_v32.py::"
                       "test_new_cells_traffic_and_metrics_are_found_by_name")
+
+# ``test_benchmark_mimo.py::test_the_tests_that_pin_the_manifest_run_
+# whole_as_it_was`` rebuilds the manifest as it was before ITS cell by
+# taking the cell's name off every list that holds it, and counts the
+# lists (its four readers' and fourteen accepted ones).  A per-layer
+# metric added later for that cell alone (``prefix_flash_roofline.
+# agent``, as files and one manifest entry: the contract) is a list
+# more.  ``tests/benchmark/test_benchmark_prefix_flash.py::
+# test_the_manifest_before_this_reader_is_what_the_pins_ran_on`` runs
+# that test whole, every assertion of it, on the manifest without the
+# later entry.  The same stop-gap as above, strict for the same reason.
+_COUNTS_ITS_CELLS_LISTS = (
+    "test_benchmark_mimo.py::"
+    "test_the_tests_that_pin_the_manifest_run_whole_as_it_was")
 
 
 # Two tests of the benchmark's own files assert the EXACT set of
@@ -302,6 +344,12 @@ def pytest_collection_modifyitems(items):
                        "superseded for every family by "
                        "test_every_configuration_file_is_what_its_"
                        "family_runs", strict=True))
+        if item.nodeid.endswith(_COUNTS_ITS_CELLS_LISTS):
+            item.add_marker(pytest.mark.xfail(
+                reason="counts the lists that hold its cell's name; run "
+                       "whole on the manifest as it was by test_the_"
+                       "manifest_before_this_reader_is_what_the_pins_"
+                       "ran_on", strict=True))
         if item.nodeid.endswith(_LAST_IN_ITS_LISTS):
             item.add_marker(pytest.mark.xfail(
                 reason="asserts its cell is the last appended to two "

@@ -532,6 +532,30 @@ def test_steps_count_both_kinds_of_walk_at_a_ring_of_three(served):
     assert [again[r] for r in rids] == outs
 
 
+def test_the_engine_serves_the_same_tokens_with_the_walk_as_the_kernel(
+        params, served, flash_interpreted):
+    """``prefix_flash_attention`` (interpreted; blocks of half a piece
+    against tiles of a piece) under the pieces of both kinds of layer,
+    keys of 24 beside values of 16, a window of 8 from a sink: the
+    served tokens are the XLA walk's, and every ``prefill/piece`` says
+    that all seven layers ran it (none of them before)."""
+    _, prompts, outs, recorded = served
+    assert {e[5]["flash_layers"] for e in recorded
+            if e[0] == "prefill/piece"} == {0}
+    traced = flash_interpreted(4, 8)
+    eng = ServingEngine(TINY, params, slots=2, cache_len=96, chunk=4,
+                        prefill_chunk=8, kv_block_size=4)
+    seq0 = events.get_recorder().events_after(0)[0]
+    rids = [eng.submit(p, 24) for p in prompts]
+    out = eng.run()
+    assert [out[r] for r in rids] == outs
+    assert traced and set(traced) <= {8, 16, 32}
+    pieces = [e[5] for e in events.get_recorder().events_after(seq0)[1]
+              if e[0] == "prefill/piece"]
+    assert pieces and {p["flash_layers"] for p in pieces} == {
+        TINY.num_layers}
+
+
 def test_refusals_tell_sink_rows_from_a_sink_logit(params):
     """StreamingLLM sinks (rows kept past the window) and a
     LlamaConfig's one global window stay with ``generate()``, and the

@@ -373,3 +373,43 @@ def test_every_piece_shape_exists_once_a_request_was_admitted(dense):
     plain.submit([3, 4, 5], 2)
     plain.run()
     assert _piece_signatures() == warmed + 1
+
+
+# -- (e) the walk as one kernel ---------------------------------------------
+
+@pytest.mark.parametrize("family, layers", [
+    ("dense", 2), ("window-pattern", 5), ("latent", 0)])
+def test_an_engine_serves_the_same_tokens_with_the_walk_as_the_kernel(
+        family, layers, flash_interpreted):
+    """The pieces' attention through ``prefix_flash_attention``
+    (interpreted, a float32 program at test size, blocks of half a
+    piece against tiles of a piece) serves the tokens the XLA walk
+    serves, calls of one piece and of four alike, and ``prefill/piece``
+    says how many of the call's attention layers ran it: every plain
+    K/V layer, none of a latent family's."""
+    cfg = FAMILIES[family]
+    params = _params(cfg)
+    assert cfg.num_layers == layers or family == "latent"
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(2, 256, n).tolist()
+               for n in (4 * PIECE + 5, PIECE - 3, 2 * PIECE)]
+
+    def serve():
+        eng = _engine(cfg, params)
+        seq0 = events.get_recorder().events_after(0)[0]
+        rids = [eng.submit(p, 6) for p in prompts]
+        out = eng.run()
+        pieces = [a for n, *_, a in
+                  events.get_recorder().events_after(seq0)[1]
+                  if n == "prefill/piece"]
+        return [out[r] for r in rids], pieces
+
+    want, walked = serve()
+    assert {p["flash_layers"] for p in walked} == {0}
+    traced = flash_interpreted(PIECE // 2, PIECE)
+    got, pieces = serve()
+    assert got == want
+    assert set(traced) == ({PIECE, 4 * PIECE} if layers else set())
+    assert [p["pieces"] for p in pieces] == [p["pieces"] for p in walked]
+    assert {p["pieces"] for p in pieces} == {1, 4}
+    assert {p["flash_layers"] for p in pieces} == {layers}

@@ -546,3 +546,85 @@ def test_decode_program_returns_its_cache_in_the_buffers_it_was_given(
     assert mem.alias_size_in_bytes >= pool_bytes, mem
     # cache leaves: two pools, the block tables, the indices
     assert len(aliased) == 4, header
+
+
+# -- the prefill pieces' walk as one kernel ---------------------------------
+
+# heads, kv_heads, key head, value head, window, sink, cache rows: the
+# three families whose pieces walk plain K/V rows, at the cells' caches.
+_FLASH_KINDS = {
+    "mimo-full-h64kv4-k192v128": (64, 4, 192, 128, None, False, 26112),
+    "mimo-window128-h64kv8-k192v128-sink": (64, 8, 192, 128, 128, True,
+                                            26112),
+    "laguna-full-h48kv8-d128": (48, 8, 128, 128, None, False, 17408),
+    "laguna-window512-h72kv8-d128": (72, 8, 128, 128, 512, False, 17408),
+    "qwen-h28kv4-d128": (28, 4, 128, 128, None, False, 4096),
+}
+
+
+@pytest.mark.parametrize("q_len", [1024, 4096])
+@pytest.mark.parametrize("kind", sorted(_FLASH_KINDS))
+def test_prefix_flash_attention_compiles_for_v5e(kind, q_len, v5e):
+    """A call of one piece and of four at the published shapes: ONE
+    ``tpu_custom_call``, named ``prefix_flash_attention`` (the trace
+    finds it by that, and the ``_paged_decode_step`` readers do not),
+    and no loop round it (each query block walks its own tiles inside
+    the kernel's grid)."""
+    heads, kvh, hd, vd, window, sink, cache_len = _FLASH_KINDS[kind]
+    shapes = [((1, heads, q_len, hd), BF16), ((1, cache_len, kvh, hd), BF16),
+              ((1, cache_len, kvh, vd), BF16), ((1,), jnp.int32)]
+    if sink:
+        shapes.append(((heads,), jnp.float32))
+
+    def fn(q, k, v, start, sinks=None):
+        return pk.prefix_flash_attention(q, k, v, start, window=window,
+                                         sink_logits=sinks)
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    text = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        for s, d in shapes)).compile().as_text()
+    kernels = _kernels(text)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        "prefix_flash_attention"), kernels
+    assert "_paged_decode_step" not in kernels[0]
+    assert " while(" not in text
+
+
+@pytest.mark.parametrize("family, moved", [
+    ("glm_lite_tiny", False), ("deepseek_v32_tiny", False),
+    ("ling_tiny", False), ("laguna_tiny", True)])
+def test_a_latent_familys_piece_program_never_asks_for_the_kernel(
+        family, moved, monkeypatch):
+    """A piece program lowered with the kernel's rule saying yes to
+    every walk it is asked about is, for the families whose caches
+    hold latent rows, the text it is with the rule saying no: their
+    walk (``LatentAttention._linear_step``) is ``prefix_attention``'s
+    and asks nothing.  A family of plain K/V rows moves."""
+    from benchmark.harness import weights
+    from tensorflow_train_distributed_tpu.models import moe
+    from tensorflow_train_distributed_tpu.serving import ServingEngine
+
+    cfg = moe.MOE_PRESETS[family]
+    boxed = jax.eval_shape(lambda: moe.MoeLmModel(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    params = weights.make_params(weights.plain_shapes(boxed)["params"], 7,
+                                 jnp.float32)
+    program = ServingEngine._prefill_piece
+    while not hasattr(program, "lower"):       # past the compile sanitizer
+        program = program.__wrapped__
+
+    def lowered(engages):
+        monkeypatch.setattr(pk, "prefix_flash_engages",
+                            lambda q_len, k, v: engages and q_len > 1)
+        monkeypatch.setattr(pk, "fused_attn_interpret", lambda: True)
+        monkeypatch.setattr(pk, "PREFIX_FLASH_BLOCK_Q", 8)
+        monkeypatch.setattr(pk, "PREFIX_FLASH_TILE", 16)
+        eng = ServingEngine(cfg, params, slots=2, chunk=2, cache_len=64,
+                            kv_block_size=8, prefill_chunk=16)
+        return program.lower(
+            eng, eng._variables, eng._cache_struct(1),
+            jax.ShapeDtypeStruct((1, 16), jnp.int32), jnp.int32(3),
+            jnp.uint32(0), jnp.int32(0)).as_text()
+
+    assert (lowered(True) != lowered(False)) is moved
