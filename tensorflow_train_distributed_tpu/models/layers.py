@@ -352,11 +352,36 @@ def flash_walk_ok(q_len: int, k_cache, v_cache, scales=None) -> bool:
             and pk.prefix_flash_engages(q_len, k_cache, v_cache))
 
 
-def flash_walk_layers(cache, q_len: int) -> int:
+def latent_walk_ok(q_len: int, cache, **sizes) -> bool:
+    """Whether attention of ``q_len`` queries a lane over a linear cache
+    of latent rows (``LatentAttention._linear_step``) runs
+    ``pallas_kernels.prefix_flash_latent``: rows and head ``sizes``
+    (``rank``, ``nope``, ``rope``, ``vd``) that the kernel takes
+    (``prefix_flash_latent_engages``) and no >1-way ambient mesh.
+    Everything else walks in XLA, as beside ``flash_walk_ok``."""
+    from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
+
+    return _one_way_mesh() and pk.prefix_flash_latent_engages(
+        q_len, cache, **sizes)
+
+
+def latent_walk_sizes(of) -> Optional[dict]:
+    """The sizes ``latent_walk_ok`` judges a walk by, of a
+    ``LatentAttention`` or of a model's config; None for a config
+    whose attention is not latent."""
+    if not getattr(of, "kv_lora_rank", None):
+        return None
+    return dict(rank=of.kv_lora_rank, nope=of.qk_nope_dim,
+                rope=of.qk_rope_dim, vd=of.v_head_dim)
+
+
+def flash_walk_layers(cache, q_len: int, latent=None) -> int:
     """Attention layers of a linear ``cache`` tree (a model's "cache"
-    collection) whose walk of a call of ``q_len`` queries runs the
-    kernel (``flash_walk_ok``): every ``key_cache`` beside a
-    ``value_cache`` and no ``kv_scales``, a depth scan's stacked
+    collection) whose walk of a call of ``q_len`` queries runs a
+    kernel: every ``key_cache`` beside a ``value_cache`` and no
+    ``kv_scales`` (``flash_walk_ok``) and, where the model's attention
+    is latent (``latent``: its ``latent_walk_sizes``), every
+    ``latent_cache`` (``latent_walk_ok``); a depth scan's stacked
     layers each counted."""
     n = 0
     if isinstance(cache, dict):
@@ -364,7 +389,12 @@ def flash_walk_layers(cache, q_len: int) -> int:
         if k is not None and v is not None and flash_walk_ok(
                 q_len, k, v, cache.get("kv_scales")):
             n += math.prod(k.shape[:-4])
-        n += sum(flash_walk_layers(c, q_len) for c in cache.values())
+        rows = cache.get("latent_cache")
+        if rows is not None and latent and latent_walk_ok(
+                q_len, rows, **latent):
+            n += math.prod(rows.shape[:-3])
+        n += sum(flash_walk_layers(c, q_len, latent)
+                 for c in cache.values())
     return n
 
 
@@ -1642,9 +1672,13 @@ class LatentAttention(nn.Module):
         (``ops.attention.prefix_attention``: a row past the longest
         lane's last query is neither read nor up-projected; a cache of
         one tile is the ordinary masked attention over all of it).
+        Where the rows are bf16 and the call is long enough
+        (``latent_walk_ok``) the same walk is one kernel
+        (``pallas_kernels.prefix_flash_latent``).
         ``index`` is a scalar (``models.generate``) or, under
         ``slot_decode``, one per batch row (the engine's batch-1
         prefill cache)."""
+        from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
         from tensorflow_train_distributed_tpu.ops.attention import (
             prefix_attention,
         )
@@ -1682,16 +1716,26 @@ class LatentAttention(nn.Module):
                 write(keys, k_i)
             keep = self._chosen_rows(q_i, w_i, keys.value, cur)
         kv_b = self._kv_b()
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
         with _scope_when(keep is not None, "attn/sparse"):
-            o = prefix_attention(
-                q.transpose(0, 2, 1, 3), cache.value, cur,
-                lambda rows: [t.transpose(0, 2, 1, 3)
-                              for t in self._up_project(rows, kv_b)],
-                keep=keep, softmax_scale=self.softmax_scale,
-                block=self.query_block,
-            ).transpose(0, 2, 1, 3)
-        return self._out(o, x.shape[-1], self._gate(x))
+            if latent_walk_ok(q_len, cache.value, **latent_walk_sizes(self)):
+                # The same walk as one kernel: each of its query
+                # blocks walks its own tiles, so ``query_block`` has
+                # nothing to add.
+                o = pk.prefix_flash_latent(
+                    q_nope.transpose(0, 2, 1, 3),
+                    q_rope.transpose(0, 2, 1, 3), cache.value, kv_b, cur,
+                    keep=keep, softmax_scale=self.softmax_scale,
+                    interpret=pk.fused_attn_interpret())
+            else:
+                q = jnp.concatenate([q_nope, q_rope], axis=-1)
+                o = prefix_attention(
+                    q.transpose(0, 2, 1, 3), cache.value, cur,
+                    lambda rows: [t.transpose(0, 2, 1, 3)
+                                  for t in self._up_project(rows, kv_b)],
+                    keep=keep, softmax_scale=self.softmax_scale,
+                    block=self.query_block)
+        return self._out(o.transpose(0, 2, 1, 3), x.shape[-1],
+                         self._gate(x))
 
     def _paged_step(self, x):
         """Per-slot decode over the paged latent pool, absorbed: the

@@ -55,6 +55,12 @@ A prefill piece's attention over a LINEAR cache of plain bf16 rows is
 query block over its own tiles, a KV head's tile met by all its query
 heads once, the scores never out of fast memory.
 
+``prefix_flash_latent`` is the same walk over LATENT rows
+(``LatentAttention``'s prefill piece): a tile of rows up-projected in
+fast memory once a head for all the call's queries, the learned
+choice handed in as a tile of ``keep``, one fold (``_fold_head``) for
+both kernels.
+
 All have pure-jax references (the CPU path and the numerics oracle) and
 run in interpreter mode in tests (``interpret=True``); kernel layout
 follows ``/opt/skills/guides/pallas_guide.md`` (f32 accumulation, 128-lane
@@ -703,6 +709,32 @@ def _prefix_block_tiles(p0, bq: int, tk: int, cache_len: int,
             attention.prefix_tiles_walked(p0, bq, tk, cache_len))
 
 
+def _fold_head(s, stats, at, vt, bias=None):
+    """One head's scores of one tile folded into its running softmax:
+    ``s`` [tile, queries] float32, scaled, held TRANSPOSED (keys down
+    the sublanes, queries along the lanes), so a query's maximum, sum
+    and rescaling factor are lane-dense rows [1, queries]; ``stats``
+    the refs of the running maximum, sum [.., 1, queries] and float32
+    accumulator [.., value, queries], read and written at ``at``;
+    ``vt`` and ``bias`` ``(ref, index)`` of the tile's values
+    transposed [value, tile] and of the mask's bias [tile, queries]
+    (0 or ``_NEG``), or no bias for a tile seen whole.  Softmax in
+    float32, the probabilities cast to the values' type before the
+    second product.  The fold of every prefix kernel here."""
+    m_ref, l_ref, acc_ref = stats
+    if bias is not None:
+        s = s + bias[0][bias[1]]
+    m_prev = m_ref[at]                                       # [1, bq]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[at] = l_ref[at] * alpha + jnp.sum(p, axis=0, keepdims=True)
+    acc_ref[at] = acc_ref[at] * alpha + jax.lax.dot_general(
+        vt[0][vt[1]], p.astype(vt[0].dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [vd, bq]
+    m_ref[at] = m_new
+
+
 def _prefix_flash_kernel(start_ref, q_ref, *rest, bq, tk, n_kb, offsets,
                          last_col, cache_len, scale, window, sink):
     """Grid (lane, KV head, query block, key tile).  A step holds the
@@ -788,17 +820,8 @@ def _prefix_flash_kernel(start_ref, q_ref, *rest, bq, tk, n_kb, offsets,
             s = jax.lax.dot_general(
                 k_src[:], q_ref[h], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [tk, bq]
-            if masked:
-                s = s + bias_ref[:]
-            m_prev = m_ref[h]                                # [1, bq]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                vt_ref[:], p.astype(vt_ref.dtype), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)          # [vd, bq]
-            m_ref[h] = m_new
+            _fold_head(s, (m_ref, l_ref, acc_ref), h, (vt_ref, slice(None)),
+                       (bias_ref, slice(None)) if masked else None)
 
         jax.lax.fori_loop(0, rep, head, None)
 
@@ -953,6 +976,268 @@ def prefix_flash_attention(q, k_cache, v_cache, start, *,
         name="prefix_flash_attention",
     )(*args)
     return out.reshape(lanes, heads, q_len, vd)
+
+
+# ---------------------------------------------------------------------------
+# Prefix flash attention over latent rows (LatentAttention's prefill piece)
+# ---------------------------------------------------------------------------
+
+#: Queries one fold of ``prefix_flash_latent`` meets a tile with, cache
+#: rows a grid step up-projects and heads a step holds at most (chosen
+#: on the chip from a sweep: PERF.md section 6, PR 44).
+PREFIX_LATENT_BLOCK_Q = 1024
+PREFIX_LATENT_TILE = 512
+_PREFIX_LATENT_HEADS = 16
+#: Fast memory the kernel may hold (a v5e core has 128 MiB).
+_PREFIX_LATENT_VMEM = 96 << 20
+
+
+def _latent_group(heads: int, q_len: int, bq: int, tk: int, nope: int,
+                  tail: int, vd: int, rank: int, itemsize: int) -> int:
+    """Heads a grid step of ``prefix_flash_latent`` holds: the largest
+    divisor of ``heads`` (at most ``_PREFIX_LATENT_HEADS``) whose
+    blocks fit three quarters of ``_PREFIX_LATENT_VMEM`` beside what a
+    step holds whatever the group.  A head's: its queries and output
+    (two buffers each), its float32 accumulator and statistics, its
+    slices of ``kv_b`` (two buffers).  The step's: its rows (two
+    buffers, a copy and the latent transposed), ``keep`` and the bias,
+    one head's tile of keys and values, a fold's float32 scores and
+    probabilities."""
+    pad = lambda n: -(-n // _LANES) * _LANES                  # noqa: E731
+    a_head = (2 * q_len * (pad(nope) + tail + pad(vd)) * itemsize
+              + (vd + 16) * q_len * 4
+              + 2 * rank * (pad(nope) + vd) * itemsize)
+    step = (4 * tk * (rank + tail) * itemsize + 2 * tk * q_len
+            + tk * q_len * 4 + tk * (pad(nope) + vd) * itemsize
+            + 3 * tk * bq * 4)
+    room = _PREFIX_LATENT_VMEM * 3 // 4 - step
+    return max(g for g in range(1, min(heads, _PREFIX_LATENT_HEADS) + 1)
+               if heads % g == 0 and (g == 1 or g * a_head <= room))
+
+
+def prefix_flash_latent_engages(q_len: int, cache, *, rank: int, nope: int,
+                                rope: int, vd: int) -> bool:
+    """Whether a walk of ``q_len`` queries a lane over a linear cache
+    of latent rows [B, C, row_store] runs ``prefix_flash_latent`` and
+    not ``ops.attention.prefix_attention``, by what the call can see:
+    bf16 rows, whole query blocks, the latent, both head sizes
+    (``nope``, ``vd``) and the rotary key padded (the row's last lane
+    tile, the key alone in it) in whole lane tiles, and the backend
+    decision every kernel here shares.  A key head of one and a half
+    tiles (GLM's 192 beside a rotary 64) computes, but costs three
+    passes of the matrix unit where the XLA walk's concatenated 256
+    costs two, and loses to it (PERF.md section 6, PR 44): left out."""
+    return (cache.dtype == jnp.bfloat16
+            and q_len >= _LANES and q_len % _LANES == 0
+            and q_len % min(PREFIX_LATENT_BLOCK_Q, q_len) == 0
+            and rank % _LANES == 0 and nope % _LANES == 0
+            and vd % _LANES == 0
+            and 0 < rope <= _LANES and cache.shape[-1] == rank + _LANES
+            and _use_pallas(None))
+
+
+def _prefix_latent_kernel(start_ref, qn_ref, qr_ref, rows_ref, wk_ref,
+                          wvt_ref, *rest, bq, tk, rank, cache_len, scale,
+                          keep):
+    """Grid (lane, group of heads, key tile), every query of the call
+    in the step.  A step fetches ONE tile of latent rows [tk,
+    row_store]; each of the group's heads makes of it, in fast memory
+    and once for all the call's queries, its keys ``c . W_uk[h]`` [tk,
+    nope] and its values, already transposed, ``W_uv[h]^T . c^T`` [vd,
+    tk] (rounded to the rows' type, as the XLA walk's up-projection
+    gives them; the rotary key, the row's last lane tile, is every
+    head's), and folds them into its running softmax for each block of
+    ``bq`` queries (``_fold_head``: scores transposed, [tk, bq]) whose
+    own range holds the tile (``_prefix_block_tiles``), so a call of k
+    pieces computes the pieces' tiles and gives their bits.  Steps past
+    the call's range compute nothing (and fetch nothing: the index maps
+    repeat the last live tile).
+
+    ``keep``: the learned choice's tile [tk, q_len] (int8, keys down
+    the sublanes: one mask for every head) becomes the bias once a
+    step; every tile is then masked.  Without it a tile every query of
+    a block sees whole skips the mask (``_prefix_flash_kernel``'s
+    split).  Rows past the cache are set to zero before any product."""
+    keep_ref = rest[0] if keep else None
+    (o_ref, m_ref, l_ref, acc_ref, bias_ref, c_ref, ct_ref, k_ref,
+     vt_ref) = rest[-9:]
+    b, _, j = (pl.program_id(a) for a in range(3))
+    grp, q_len, _ = qn_ref.shape
+    p0 = start_ref[b]
+    blocks = [(q0, _prefix_block_tiles(p0 + q0, bq, tk, cache_len, None)[1])
+              for q0 in range(0, q_len, bq)]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, _NEG / 2)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def kv_pos(width):
+        return j * tk + jax.lax.broadcasted_iota(jnp.int32, (tk, width), 0)
+
+    def whole(q0):
+        return (j + 1) * tk - 1 <= p0 + q0
+
+    def causal_bias(q0):
+        pos = p0 + q0 + jax.lax.broadcasted_iota(jnp.int32, (tk, bq), 1)
+        bias_ref[:, pl.ds(q0, bq)] = jnp.where(kv_pos(bq) <= pos, 0.0, _NEG)
+
+    def fold(h, q0, masked: bool):
+        at = pl.ds(q0, bq)
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(
+            k_ref[:], qn_ref[h, at, :], dims,
+            preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(
+                c_ref[:, rank:], qr_ref[h, at, :], dims,
+                preferred_element_type=jnp.float32)) * scale  # [tk, bq]
+        _fold_head(s, (m_ref, l_ref, acc_ref), (h, slice(None), at),
+                   (vt_ref, slice(None)),
+                   (bias_ref, (slice(None), at)) if masked else None)
+
+    @pl.when(j < blocks[-1][1])         # the last block's end: the call's
+    def _():
+        rows = rows_ref[:]
+        if cache_len % tk:
+            rows = jnp.where(kv_pos(1) < cache_len, rows,
+                             jnp.zeros_like(rows))
+        c_ref[:] = rows
+        ct_ref[:] = rows[:, :rank].T
+        if keep:
+            pos = p0 + jax.lax.broadcasted_iota(jnp.int32, (tk, q_len), 1)
+            ok = (keep_ref[:].astype(jnp.float32) > 0) & (
+                kv_pos(q_len) <= pos)
+            bias_ref[:] = jnp.where(ok, 0.0, _NEG)
+        else:
+            for q0, end in blocks:
+                pl.when((j < end) & jnp.logical_not(whole(q0)))(
+                    functools.partial(causal_bias, q0))
+
+        def head(h, _):
+            k_ref[:] = jnp.dot(
+                c_ref[:, :rank], wk_ref[h],
+                preferred_element_type=jnp.float32).astype(k_ref.dtype)
+            vt_ref[:] = jnp.dot(
+                wvt_ref[h], ct_ref[:],
+                preferred_element_type=jnp.float32).astype(vt_ref.dtype)
+            for q0, end in blocks:
+                if keep and len(blocks) == 1:
+                    fold(h, q0, True)
+                elif keep:
+                    pl.when(j < end)(functools.partial(fold, h, q0, True))
+                else:
+                    pl.when((j < end) & whole(q0))(
+                        functools.partial(fold, h, q0, False))
+                    pl.when((j < end) & jnp.logical_not(whole(q0)))(
+                        functools.partial(fold, h, q0, True))
+
+        jax.lax.fori_loop(0, grp, head, None)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        def out(h, _):
+            o_ref[h] = (acc_ref[h] / l_ref[h]).T.astype(o_ref.dtype)
+
+        jax.lax.fori_loop(0, grp, out, None)
+
+
+def prefix_flash_latent(q_nope, q_rope, cache, kv_b, start, *, keep=None,
+                        softmax_scale: float, interpret: bool = False):
+    """``ops.attention.prefix_attention`` over a linear cache of LATENT
+    rows (``models.layers.LatentAttention._linear_step``'s walk: its
+    oracle, and every other call's path) as ONE kernel: ``q_nope`` [B,
+    H, Q, nope] and ``q_rope`` [B, H, Q, rope] (rotated) over ``cache``
+    [B, C, row_store], a row ``[c_kv (rank) | k_r (rope) | zeros]``
+    with the rotary key alone in its last lane tile; ``kv_b`` [rank, H,
+    nope + Dv] up-projects a row to head ``h``'s key ``[c . W_uk[h] |
+    k_r]`` and value ``c . W_uv[h]``; lane ``b``'s queries at positions
+    ``start[b] + arange(Q)``; [B, H, Q, Dv].
+
+    A tile of rows is fetched once a group of heads and up-projected
+    once a head for ALL the call's queries, in fast memory; no head's
+    keys, values or scores reach HBM.  ``keep`` [B, Q, C] bool
+    (``select_top_rows``) restricts each query to the rows it marks,
+    one mask for every head; the kernel reads it keys-major, ``int8 [B,
+    C, Q]``: a transposed copy, made here.  Each block of
+    ``PREFIX_LATENT_BLOCK_Q`` queries walks its own tiles
+    (``_prefix_block_tiles``), ``start`` is scalar-prefetched and the
+    grid is static.  The arithmetic is the walk's (keys and values
+    rounded to the rows' type, products accumulated in float32, softmax
+    in float32, probabilities cast to the values' type) but for the
+    scores, which are never rounded on their way to the softmax."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes, heads, q_len, nope = q_nope.shape
+    cache_len, store = cache.shape[1:]
+    rank = kv_b.shape[0]
+    vd = kv_b.shape[-1] - nope
+    tail = store - rank
+    bq, tk = min(PREFIX_LATENT_BLOCK_Q, q_len), PREFIX_LATENT_TILE
+    if q_len % bq:
+        raise ValueError(f"{q_len} queries are no whole blocks of {bq}")
+    if not 0 < q_rope.shape[-1] <= tail:
+        raise ValueError(f"a rotary key of {q_rope.shape[-1]} does not "
+                         f"lie in the row's last {tail} columns")
+    grp = _latent_group(heads, q_len, bq, tk, nope, tail, vd, rank,
+                        cache.dtype.itemsize)
+    start = jnp.broadcast_to(
+        jnp.asarray(start, jnp.int32).reshape(-1), (lanes,))
+
+    def of_heads(*last):
+        return pl.BlockSpec((None, grp, *last),
+                            lambda b, g, j, start_ref: (b, g, 0, 0))
+
+    def of_group(*last):
+        return pl.BlockSpec((grp, *last),
+                            lambda b, g, j, start_ref: (g, 0, 0))
+
+    def of_tile(width):
+        return pl.BlockSpec(
+            (None, tk, width),
+            lambda b, g, j, start_ref: (b, jnp.minimum(
+                j, _prefix_block_tiles(start_ref[b], q_len, tk, cache_len,
+                                       None)[1] - 1), 0))
+
+    in_specs = [of_heads(q_len, nope), of_heads(q_len, tail), of_tile(store),
+                of_group(rank, nope), of_group(vd, rank)]
+    args = [start, q_nope.astype(cache.dtype),
+            jnp.pad(q_rope.astype(cache.dtype),
+                    ((0, 0),) * 3 + ((0, tail - q_rope.shape[-1]),)),
+            cache, kv_b[..., :nope].transpose(1, 0, 2),
+            kv_b[..., nope:].transpose(1, 2, 0)]
+    if keep is not None:
+        in_specs.append(of_tile(q_len))
+        args.append(keep.transpose(0, 2, 1).astype(jnp.int8))
+    return pl.pallas_call(
+        functools.partial(
+            _prefix_latent_kernel, bq=bq, tk=tk, rank=rank,
+            cache_len=cache_len, scale=softmax_scale,
+            keep=keep is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(lanes, heads // grp, -(-cache_len // tk)),
+            in_specs=in_specs,
+            out_specs=of_heads(q_len, vd),
+            scratch_shapes=[
+                pltpu.VMEM((grp, 1, q_len), jnp.float32),
+                pltpu.VMEM((grp, 1, q_len), jnp.float32),
+                pltpu.VMEM((grp, vd, q_len), jnp.float32),
+                pltpu.VMEM((tk, q_len), jnp.float32),
+                pltpu.VMEM((tk, store), cache.dtype),
+                pltpu.VMEM((rank, tk), cache.dtype),
+                pltpu.VMEM((tk, nope), cache.dtype),
+                pltpu.VMEM((vd, tk), cache.dtype),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes, heads, q_len, vd),
+                                       cache.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_PREFIX_LATENT_VMEM),
+        interpret=interpret,
+        name="prefix_flash_latent",
+    )(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -173,8 +173,9 @@ CONTRACT = {
     # has no selection); window_rows: of them, the rows a sliding-
     # window layer's walk reads (ops.attention.prefix_first_tile on; 0
     # where no layer has a window); both of the last piece, as rows;
-    # flash_layers: the call's attention layers whose walk ran as the
-    # kernel (pallas_kernels.prefix_flash_attention; 0: the XLA walk)
+    # flash_layers: the call's attention layers whose walk ran as a
+    # kernel (pallas_kernels.prefix_flash_attention over plain K/V
+    # rows, prefix_flash_latent over latent rows; 0: the XLA walk)
     "prefill/piece": ("rid piece pieces n_pieces tokens rows select_rows "
                       "window_rows cache_rows flash_layers"),
     # kind: "fresh" (zeros), "copy" (a preloaded pair's), "gather"

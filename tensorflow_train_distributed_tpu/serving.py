@@ -172,7 +172,10 @@ from tensorflow_train_distributed_tpu.models.generate import (
     has_lora_leaves,
     validate_sampling,
 )
-from tensorflow_train_distributed_tpu.models.layers import flash_walk_layers
+from tensorflow_train_distributed_tpu.models.layers import (
+    flash_walk_layers,
+    latent_walk_sizes,
+)
 from tensorflow_train_distributed_tpu.models.quant import (
     check_quant_pairing,
     maybe_quant_variables,
@@ -451,6 +454,11 @@ class ServingEngine:
         # attention chooses among those it sees; 0: no selection.
         self._index_topk = {
             is_draft: getattr(c, "index_topk", 0)
+            for is_draft, c in ((False, config), (True, draft_config))}
+        # Their attention's latent sizes, for ``_flash_layers``; None:
+        # plain K/V rows.
+        self._latent_sizes = {
+            is_draft: latent_walk_sizes(c)
             for is_draft, c in ((False, config), (True, draft_config))}
         self.slots = slots
         self.cache_len = cache_len or config.max_positions
@@ -1683,13 +1691,15 @@ class ServingEngine:
 
     def _flash_layers(self, draft: bool, q_len: int) -> int:
         """Attention layers of the batch-1 prefill model whose walk of
-        a call of ``q_len`` tokens runs the kernel
-        (``layers.flash_walk_layers``), for ``prefill/piece``; under
-        ``_ctx`` (a mesh vetoes the kernel)."""
+        a call of ``q_len`` tokens runs a kernel, over plain rows or
+        latent ones (``layers.flash_walk_layers``), for
+        ``prefill/piece``; under ``_ctx`` (a mesh vetoes the
+        kernels)."""
         key = (draft, q_len)
         if key not in self._flash_layer_counts:
             self._flash_layer_counts[key] = flash_walk_layers(
-                self._cache_struct(1, draft), q_len)
+                self._cache_struct(1, draft), q_len,
+                self._latent_sizes[draft])
         return self._flash_layer_counts[key]
 
     # Memory discipline (ttd-lint memcheck + TTD_MEMCHECK=1): THE

@@ -133,6 +133,37 @@ def flash_interpreted(monkeypatch):
     return engage
 
 
+@pytest.fixture
+def latent_interpreted(monkeypatch):
+    """``latent_interpreted(block_q, tile)``: programs traced from here
+    on hand every walk of whole query blocks over a linear cache of
+    LATENT rows to ``pallas_kernels.prefix_flash_latent``, interpreted,
+    at these sizes, whatever the rows' type and width (a float32
+    program at test size; the compiled kernel's own rule is
+    ``prefix_flash_latent_engages``); returns the list that collects
+    ``(query length, whether ``keep`` was given)`` of every kernel call
+    traced."""
+    from tensorflow_train_distributed_tpu.ops import pallas_kernels as pk
+
+    def engage(block_q, tile):
+        calls, kernel = [], pk.prefix_flash_latent
+        monkeypatch.setattr(
+            pk, "prefix_flash_latent",
+            lambda q, *a, keep=None, **kw: calls.append(
+                (q.shape[2], keep is not None)) or kernel(
+                    q, *a, keep=keep, **kw))
+        monkeypatch.setattr(pk, "PREFIX_LATENT_BLOCK_Q", block_q)
+        monkeypatch.setattr(pk, "PREFIX_LATENT_TILE", tile)
+        monkeypatch.setattr(pk, "fused_attn_interpret", lambda: True)
+        monkeypatch.setattr(
+            pk, "prefix_flash_latent_engages",
+            lambda q_len, rows, **sizes: (q_len >= block_q
+                                          and q_len % block_q == 0))
+        return calls
+
+    return engage
+
+
 @pytest.fixture(scope="session")
 def mesh_2d():
     """2×4 data×tensor mesh (the DTensor-style 2-D layout)."""
@@ -303,8 +334,31 @@ _LAGUNAS_OWN = (
     "test_new_cells_traffic_and_metrics_are_found_by_name",)
 
 
+# Two tests of ``tests/benchmark/test_benchmark_prefix_flash.py`` assert
+# that ``prefix_flash_roofline.agent`` is the LAST of ``per_layer``.
+# The next per-layer metric (``prefix_flash_roofline.longctx``, as
+# files and one manifest entry: the contract) is appended after it and
+# may not edit that file.  ``tests/benchmark/test_benchmark_prefix_
+# flash_latent.py::test_the_manifest_before_this_reader_is_what_the_
+# pins_ran_on`` runs both functions whole on the manifest without the
+# later entry.  The same stop-gap as those above, strict for the same
+# reason: the `benchmark` PR finds the entry by name and deletes this.
+_LAST_PER_LAYER = (
+    "test_benchmark_prefix_flash.py::"
+    "test_the_reader_is_found_by_name_for_its_cell_alone",
+    "test_benchmark_prefix_flash.py::"
+    "test_the_manifest_before_this_reader_is_what_the_pins_ran_on")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_LAST_PER_LAYER):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts its reader is the last of per_layer; "
+                       "run whole on the manifest as it was by "
+                       "test_benchmark_prefix_flash_latent.py::test_the_"
+                       "manifest_before_this_reader_is_what_the_pins_"
+                       "ran_on", strict=True))
         if item.nodeid.endswith(_LINGS_TAIL):
             item.add_marker(pytest.mark.xfail(
                 reason="asserts that Ling's readers, configuration and "

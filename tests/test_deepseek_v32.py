@@ -77,22 +77,29 @@ def _tokens(n, seed=0):
     return np.random.default_rng(seed).integers(2, 256, n).astype(np.int32)
 
 
-def _pieces_then_decode(cfg, params, toks, n_prompt, piece=16, **kw):
+def _pieces_then_decode(cfg, params, toks, n_prompt, piece=16,
+                        compiled=False, **kw):
     """Logits of every position: chunked prefill on the batch-1 linear
-    caches, the engine's insert into the paged pools, teacher-forced
+    caches (``compiled``: the piece as one jitted program, traced
+    once), the engine's insert into the paged pools, teacher-forced
     decode steps through the block table."""
     eng = ServingEngine(cfg, params, slots=2, chunk=2, cache_len=128,
                         kv_block_size=8, prefill_chunk=piece, **kw)
     variables = {"params": params}
     cache_1 = eng._fresh_cache(1)
     got = []
+
+    def a_piece(cache, part):
+        return eng._prefill_model.apply(
+            dict(variables, cache=cache), part, mutable=["cache"])
+
+    if compiled:
+        a_piece = jax.jit(a_piece)
     for start in range(0, n_prompt, piece):
         part = np.zeros((1, piece), np.int32)       # pad rows after
         real = min(piece, n_prompt - start)
         part[0, :real] = toks[start:start + real]
-        logits, upd = eng._prefill_model.apply(
-            dict(variables, cache=cache_1), jnp.asarray(part),
-            mutable=["cache"])
+        logits, upd = a_piece(cache_1, jnp.asarray(part))
         cache_1 = upd["cache"]
         got.append(np.asarray(logits[0, :real]))
     grid = eng._fresh_cache(eng.slots, grid=True)
@@ -134,6 +141,23 @@ def test_prefill_then_paged_decode_matches_the_reference(
     assert eng.fused_attn() == kernel
     assert (16, 16, 128) in set(walks)
     want = reference.logits_at(params, FILE_CFG, toks, np.arange(90))
+    np.testing.assert_allclose(got, np.asarray(want), atol=TOL, rtol=0)
+
+
+def test_the_pieces_walk_as_one_kernel_matches_the_reference(
+        params, latent_interpreted, walk_in_tiles):
+    """The same five pieces with their attention through
+    ``prefix_flash_latent`` (interpreted, tiles of a piece): the rows
+    up-projected in the kernel and the learned choice handed in as
+    ``keep`` give the reference's logits, and the engine counts every
+    layer's walk as the kernel's."""
+    walk_in_tiles(16)
+    calls = latent_interpreted(16, 16)
+    toks = _tokens(71, seed=1)          # (and one step, on its table)
+    got, eng = _pieces_then_decode(CFG, params, toks, 70, compiled=True)
+    assert calls == [(16, True)] * CFG.num_layers      # traced once
+    assert eng._flash_layers(False, 16) == CFG.num_layers
+    want = reference.logits_at(params, FILE_CFG, toks, np.arange(71))
     np.testing.assert_allclose(got, np.asarray(want), atol=TOL, rtol=0)
 
 

@@ -39,6 +39,7 @@ FAMILIES = {
     "latent": moe.MOE_PRESETS["glm_lite_tiny"],
     "latent-selection": moe.MOE_PRESETS["deepseek_v32_tiny"],
     "window-pattern": moe.MOE_PRESETS["laguna_tiny"],
+    "latent-beside-linear": moe.MOE_PRESETS["ling_tiny"],
 }
 
 
@@ -412,4 +413,45 @@ def test_an_engine_serves_the_same_tokens_with_the_walk_as_the_kernel(
     assert set(traced) == ({PIECE, 4 * PIECE} if layers else set())
     assert [p["pieces"] for p in pieces] == [p["pieces"] for p in walked]
     assert {p["pieces"] for p in pieces} == {1, 4}
+    assert {p["flash_layers"] for p in pieces} == {layers}
+
+
+@pytest.mark.parametrize("family, layers, calls", [
+    ("latent", 3, {(PIECE, False), (4 * PIECE, False)}),
+    ("latent-selection", 3, {(PIECE, True)}),
+    ("latent-beside-linear", 1, {(PIECE, False), (4 * PIECE, False)}),
+    ("dense", 0, set()), ("window-pattern", 0, set())])
+def test_an_engine_serves_the_same_tokens_with_the_latent_walk_as_the_kernel(
+        family, layers, calls, latent_interpreted):
+    """The pieces' attention over latent rows through
+    ``prefix_flash_latent`` (interpreted, a float32 program at test
+    size, blocks of half a piece against tiles of a piece) serves the
+    tokens the XLA walk serves, and ``prefill/piece`` says how many of
+    the call's attention layers ran it: every latent layer (the one of
+    seven beside the linear layers; under the learned choice, whose
+    engine runs one piece a call, ``keep`` goes in), none of a family
+    of plain K/V rows, and none on the CPU path."""
+    cfg = FAMILIES[family]
+    params = _params(cfg)
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(2, 256, n).tolist()
+               for n in (4 * PIECE + 5, PIECE - 3, 2 * PIECE)]
+
+    def serve():
+        eng = _engine(cfg, params)
+        seq0 = events.get_recorder().events_after(0)[0]
+        rids = [eng.submit(p, 6) for p in prompts]
+        out = eng.run()
+        pieces = [a for n, *_, a in
+                  events.get_recorder().events_after(seq0)[1]
+                  if n == "prefill/piece"]
+        return [out[r] for r in rids], pieces
+
+    want, walked = serve()
+    assert {p["flash_layers"] for p in walked} == {0}
+    traced = latent_interpreted(PIECE // 2, PIECE)
+    got, pieces = serve()
+    assert got == want
+    assert set(traced) == calls
+    assert [p["pieces"] for p in pieces] == [p["pieces"] for p in walked]
     assert {p["flash_layers"] for p in pieces} == {layers}

@@ -628,3 +628,48 @@ def test_a_latent_familys_piece_program_never_asks_for_the_kernel(
             jnp.uint32(0), jnp.int32(0)).as_text()
 
     assert (lowered(True) != lowered(False)) is moved
+
+
+# -- the same walk over latent rows -----------------------------------------
+
+# heads, nope, rope, value head, keep, cache rows, queries of a call: the
+# three latent families at their cells' caches and calls (one piece, and
+# the most pieces a step's budget makes a call of).
+_LATENT_KINDS = {
+    "deepseek-h128-k128r64v128-keep": (128, 128, 64, 128, True, 32768,
+                                       (1024,)),
+    "glm-h20-k192r64v256": (20, 192, 64, 256, False, 9216, (1024, 2048)),
+    "ling-h32-k128r64v128": (32, 128, 64, 128, False, 19456, (1024, 4096)),
+}
+
+
+@pytest.mark.parametrize("kind, q_len", [
+    (k, q) for k in sorted(_LATENT_KINDS) for q in _LATENT_KINDS[k][-1]])
+def test_prefix_flash_latent_compiles_for_v5e(kind, q_len, v5e):
+    """At the published shapes (a latent of 512 in rows stored 640
+    wide): ONE ``tpu_custom_call``, named ``prefix_flash_latent`` (the
+    trace tells it from ``prefix_flash_attention`` by that), no loop
+    round it, and its blocks inside the fast memory it asks for (a
+    VMEM overrun is the compiler's error).  GLM's shape compiles too,
+    though the rule leaves it to the XLA walk."""
+    heads, nope, rope, vd, keep, cache_len, _ = _LATENT_KINDS[kind]
+    shapes = [((1, heads, q_len, nope), BF16), ((1, heads, q_len, rope), BF16),
+              ((1, cache_len, 640), BF16), ((512, heads, nope + vd), BF16),
+              ((1,), jnp.int32)]
+    if keep:
+        shapes.append(((1, q_len, cache_len), jnp.bool_))
+
+    def fn(q_nope, q_rope, rows, kv_b, start, keep=None):
+        return pk.prefix_flash_latent(
+            q_nope, q_rope, rows, kv_b, start, keep=keep,
+            softmax_scale=(nope + rope) ** -0.5)
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    text = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        for s, d in shapes)).compile().as_text()
+    kernels = _kernels(text)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        "prefix_flash_latent"), kernels
+    assert not kernels[0].startswith("prefix_flash_attention")
+    assert " while(" not in text
