@@ -6,7 +6,7 @@ no CPU fallback and no echo of an older result: a number under a device
 metric's name comes from the device or not at all.  The failure is still
 machine-readable: the LAST stdout line is always a compact (<~500 byte)
 headline JSON {"metric", "value", "unit", "vs_baseline", ...} sized for a
-tail-window capture (BENCH_r04 lesson: one fat last line parsed as null),
+tail-window capture (round 4's lesson: one fat last line parsed as null),
 preceded by the full per-config record on a line of its own.
 
 One process per chip.  Benched families (``--families``): ``lm``

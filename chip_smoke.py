@@ -469,10 +469,10 @@ def phase_serve(opts, ckpt_dir: str) -> dict:
     t0 = time.time()
     gw = serve_http.build_gateway(args, cfg, is_moe, [])
     eng = gw.engine
-    out = {"paged": eng.paged, "fused_attn": eng._fused_attn,
+    out = {"fused_attn": eng._fused_attn,
            "kv_pool_gib": round(eng._kv_pool_bytes / 2**30, 3)}
     want_fused = not opts.rehearse_cpu
-    if not (eng.paged and eng._fused_attn == want_fused):
+    if eng._fused_attn != want_fused:
         raise AssertionError(f"serving defaults are not all on: {out}")
     gw.start()
     try:

@@ -216,10 +216,10 @@ def apply_rope(x, positions, *, base: float = 10000.0, scaling=None,
 def _quantize_kv_rows(t):
     """Symmetric int8 quantization of KV rows, one f32 scale per
     (..., row, kv_head) amax'd over head_dim — THE one KV quantization
-    recipe.  The linear cache, the per-slot serving cache, and the
-    paged block pool all store exactly these values, which is what
-    makes the cross-layout int8 parity bitwise (pinned in
-    tests/test_serving_paged.py)."""
+    recipe.  The shared-index linear cache, the engine's batch-1
+    per-slot cache, and the paged block pool all store exactly these
+    values, which is what makes the cross-layout int8 parity bitwise
+    (pinned in tests/test_serving_paged.py)."""
     amax = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     qt = jnp.clip(jnp.round(t.astype(jnp.float32) / scale[..., None]),
@@ -442,8 +442,8 @@ class MultiHeadAttention(nn.Module):
     # kv_head) with an f32 scale — halves cache HBM vs bf16 (cache
     # reads dominate large-batch/long-context decode) and the dequant
     # fuses into the attention read.  Composes with the shared-index
-    # linear cache, the per-slot serving cache, AND the paged block
-    # pool (scales ride in a parallel pool var).  Unsupported with the
+    # linear cache, the engine's batch-1 per-slot cache, AND the paged
+    # block pool (scales ride in a parallel pool var).  Unsupported with the
     # rolling window cache (roll/concat would need scale plumbing; the
     # window already bounds cache memory).
     kv_cache_int8: bool = False
@@ -455,7 +455,7 @@ class MultiHeadAttention(nn.Module):
     # each slot's own position.  Linear cache, full-precision or
     # kv_cache_int8 (window/sinks keep the shared-index fast path).
     slot_decode: bool = False
-    # Paged KV cache (serving.ServingEngine paged mode; needs
+    # Paged KV cache (serving.ServingEngine's slot grid; needs
     # slot_decode): instead of one contiguous [B, cache_len] strip per
     # lane, KV rows live in a FIXED pool of ``paged_kv_blocks`` physical
     # blocks of ``kv_block_size`` rows, and each lane maps its logical
@@ -987,8 +987,8 @@ class MultiHeadAttention(nn.Module):
             cache_v.value = cache_v.value.at[bidx, positions].set(
                 v.astype(kdt))
         # A window layer keeps every row here (the batch-1 prefill
-        # cache and the linear slot grid are ``cache_len`` long) and
-        # walks the tiles its window reaches.
+        # cache is ``cache_len`` long) and walks the tiles its window
+        # reaches.
         return self._cache_attend(q, cache_k.value, cache_v.value,
                                   kv_heads, b, q_len, x.shape[-1],
                                   start=cur, scales=scales,
@@ -1032,8 +1032,8 @@ class MultiHeadAttention(nn.Module):
         (``ops.pallas_kernels.paged_attention``) that computes
         flash-style decode attention directly through the block table
         — the dense per-lane KV view is never materialized, halving
-        decode's HBM traffic.  ``TTD_NO_FUSED_ATTN=1`` restores the
-        gather path (the byte-comparable A/B leg).  Sharded serving
+        decode's HBM traffic.  ``TTD_NO_PALLAS=1`` keeps the gather
+        path (the byte-comparable A/B leg).  Sharded serving
         (an ambient mesh) keeps the gather path: GSPMD partitions the
         XLA gather, while the hand kernel is single-device.
 

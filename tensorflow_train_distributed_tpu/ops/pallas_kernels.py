@@ -44,7 +44,7 @@ Two serving-side kernels back the engine's paged KV cache:
   optional int8-pool dequant fused into the walk (per-row symmetric
   scales ride in a parallel scale pool) — the dense per-lane view is
   never materialized, and the kernel's time follows what the lanes
-  hold, not slots x blocks a lane.  ``TTD_NO_FUSED_ATTN=1`` restores the
+  hold, not slots x blocks a lane.  ``TTD_NO_PALLAS=1`` keeps the
   gather-then-attend path (the byte-comparable A/B leg);
   ``TTD_FUSED_ATTN_INTERPRET=1`` forces the kernel in interpret mode
   off-TPU (the CPU parity-test path).
@@ -232,18 +232,16 @@ def use_fused_paged_attention() -> bool:
     """Whether the paged decode step should run the FUSED kernel
     (``paged_attention``) instead of gather-then-attend.
 
-    ``TTD_NO_FUSED_ATTN=1`` is the production kill switch (wins over
-    everything — restores the XLA block-gather path, byte-comparable as
-    the A/B leg); ``TTD_FUSED_ATTN_INTERPRET=1`` forces the kernel ON
-    in interpret mode off-TPU (the CPU parity-test path — slow, tiny
-    shapes only); otherwise the decision is the standard pallas one
-    (TPU backend, TTD_NO_PALLAS respected).  Read at TRACE time — flip
-    before the engine compiles its decode programs."""
-    if env_flag("TTD_NO_FUSED_ATTN"):
+    ``TTD_NO_PALLAS=1`` wins over everything (the XLA block-gather
+    path, byte-comparable as the A/B leg);
+    ``TTD_FUSED_ATTN_INTERPRET=1`` forces the kernel ON in interpret
+    mode off-TPU (the CPU parity-test path — slow, tiny shapes only);
+    otherwise the decision is the standard pallas one (TPU backend).
+    Read at TRACE time — set before the engine compiles its decode
+    programs."""
+    if env_flag("TTD_NO_PALLAS"):
         return False
-    if env_flag("TTD_FUSED_ATTN_INTERPRET"):
-        return True
-    return _use_pallas(None)
+    return env_flag("TTD_FUSED_ATTN_INTERPRET") or _use_pallas(None)
 
 
 def fused_attn_interpret() -> bool:

@@ -198,10 +198,9 @@ class _Handler(BaseHTTPRequestHandler):
             "slots_in_use": gw.driver.active_slots(),
             "slots_total": gw.engine.slots,
         }
-        # Paged-KV engines: admission is keyed on free blocks, so
-        # the block occupancy IS the capacity signal load
-        # balancers should watch (absent for linear-cache engines
-        # and stubs).
+        # Admission is keyed on free blocks, so the block occupancy
+        # IS the capacity signal load balancers should watch (absent
+        # for stub engines).
         total_fn = getattr(gw.engine, "kv_blocks_total", None)
         total = total_fn() if total_fn is not None else 0
         if total:

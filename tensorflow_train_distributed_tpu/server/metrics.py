@@ -440,11 +440,11 @@ class GatewayMetrics:
         self.kv_blocks_in_use = r.gauge(
             "ttd_engine_kv_blocks_in_use",
             "Paged-KV physical blocks referenced by live lanes or the "
-            "radix prefix cache (0 = linear cache).",
+            "radix prefix cache.",
             fn=kv_blocks_in_use_fn)
         self.kv_blocks_total = r.gauge(
             "ttd_engine_kv_blocks_total",
-            "Paged-KV pool capacity in blocks (0 = linear cache).",
+            "Paged-KV pool capacity in blocks.",
             fn=kv_blocks_total_fn)
         self.kv_prefix_hit_tokens = r.fn_counter(
             "ttd_engine_prefix_hit_tokens_total",
@@ -462,8 +462,7 @@ class GatewayMetrics:
         # int8 halves it, and the freed HBM buys more blocks/slots.
         self.kv_pool_bytes = r.gauge(
             "ttd_engine_kv_pool_bytes",
-            "Device bytes held by the paged KV block pools "
-            "(0 = linear cache).",
+            "Device bytes held by the paged KV block pools.",
             fn=kv_pool_bytes_fn)
         # Acceptance-adaptive speculation (the telemetry loop closed):
         # the draft depth the NEXT round dispatches at — constant for

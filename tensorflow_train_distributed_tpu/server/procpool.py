@@ -154,7 +154,6 @@ class RemoteEngine:
         "slots": (None, "reader", "main"),
         "kv_block_size": (None, "reader", "main"),
         "cache_len": (None, "reader", "main"),
-        "paged": (None, "reader", "main"),
         "pool_blocks": (None, "reader", "main"),
         "pid": (None, "reader", "main"),
         "role": (None, "reader", "main"),
@@ -165,7 +164,6 @@ class RemoteEngine:
         self.slots = 0
         self.kv_block_size = 16
         self.cache_len: Optional[int] = None
-        self.paged = False
         self.pool_blocks: Optional[int] = None
         self.pid: Optional[int] = None
         # Per-worker HBM footprint from the HELLO (the engine's byte
@@ -188,7 +186,6 @@ class RemoteEngine:
         eng = body.get("engine") or {}
         self.kv_block_size = int(eng.get("kv_block_size") or 16)
         self.cache_len = eng.get("cache_len")
-        self.paged = bool(eng.get("paged"))
         self.pool_blocks = eng.get("pool_blocks")
         self.pid = body.get("pid")
         self.hbm_budget_bytes = eng.get("hbm_budget_bytes")
@@ -293,7 +290,7 @@ class RemoteEngine:
             raise ValueError(
                 f"prompt {len(prompt)} + {max_new} new exceeds "
                 f"cache_len={self.cache_len}")
-        if self.paged and self.pool_blocks:
+        if self.pool_blocks:
             need = -(-(len(prompt) + max_new) // self.kv_block_size)
             if need > self.pool_blocks:
                 raise ValueError(

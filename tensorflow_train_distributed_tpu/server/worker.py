@@ -99,7 +99,6 @@ def engine_info(engine) -> dict:
         "slots": int(getattr(engine, "slots", 0)),
         "kv_block_size": int(getattr(engine, "kv_block_size", 16)),
         "cache_len": getattr(engine, "cache_len", None),
-        "paged": bool(getattr(engine, "paged", False)),
         "pool_blocks": (int(pool.n_blocks) if pool is not None
                         else None),
         "buckets": (list(buckets) if buckets else None),
@@ -236,7 +235,7 @@ def _factory_stub(spec: dict):
 #: in-process reference engine stay configured identically).
 _LLAMA_ENGINE_KWARGS = (
     "slots", "cache_len", "chunk", "temperature", "top_k", "top_p",
-    "prefill_chunk", "prefill_budget", "paged",
+    "prefill_chunk", "prefill_budget",
     "kv_block_size", "kv_pool_blocks", "prefix_cache_limit",
     "hbm_budget_bytes", "hbm_headroom", "spec_depths",
 )

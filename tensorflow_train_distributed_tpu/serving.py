@@ -58,9 +58,8 @@ own rng stream (seeded at submit) wherever it lands.
 5. Hand back the requests that finished, so callers can ``submit()``
    between steps; one chunk stays in flight across the return.
 
-**Paged KV cache with cross-request prefix sharing** (the default;
-``TTD_NO_PAGED_KV=1`` / ``paged=False`` / the CLIs' ``--no-paged-kv``
-select the per-slot linear grid): KV rows live in one pool of
+**Paged KV cache with cross-request prefix sharing** (the slot grid's
+one layout): KV rows live in one pool of
 ``kv_block_size``-row blocks per layer, and a lane maps its positions
 through a block table (``serving_kv``: allocator, refcounts, a radix
 tree over token ids at block granularity).  A lane holds
@@ -131,9 +130,9 @@ and a mesh.
 **Fused paged attention** (TPU): the paged decode read is one Pallas
 kernel (``ops.pallas_kernels.paged_attention``) that attends through
 the block table; the dense per-lane copy ``paged_kv_gather`` would
-make never exists.  ``TTD_NO_FUSED_ATTN=1`` (set BEFORE engine
-construction: the choice compiles into the decode programs) selects
-gather-then-attend; CPU and sharded (``mesh=``) serving always gather.
+make never exists.  CPU and sharded (``mesh=``) serving gather then
+attend, as does ``TTD_NO_PALLAS=1`` (the A/B leg; set BEFORE engine
+construction: the choice compiles into the decode programs).
 """
 
 from __future__ import annotations
@@ -216,14 +215,14 @@ class _PrefillTask:
     piece: int
     n_pieces: int
     resume: int = 0                # rng counter of the first pick
-    pre_pair: Optional[tuple] = None   # matched prefix caches (linear)
+    pre_pair: Optional[tuple] = None   # matched stored prefix pair
     cursor: int = 0                # target pieces completed
     cache_1: object = None         # target batch-1 cache in progress
     first: object = None           # device pick after the last piece
     first_host: Optional[int] = None
     d_cursor: int = 0              # draft pieces completed
     d_cache_1: object = None
-    kv: object = None              # serving_kv.LaneKV claim (paged mode)
+    kv: object = None              # serving_kv.LaneKV claim
     table: object = None           # np.int32 [n_blk] physical block row
 
 
@@ -257,14 +256,6 @@ def _device_hbm_bytes() -> Optional[int]:
     if not stats:
         return None
     return int(stats.get("bytes_limit", 0) or 0) or None
-
-
-def _paged_killed() -> bool:
-    """``TTD_NO_PAGED_KV=1`` restores the per-slot LINEAR cache
-    byte-for-byte (contiguous ``cache_len`` rows per lane, manual
-    ``preload_prefix`` prefix caching) regardless of how the engine was
-    constructed."""
-    return os.environ.get("TTD_NO_PAGED_KV", "0") not in ("", "0")
 
 
 #: Row-holding cache leaves: the paged pool's name -> (the batch-1
@@ -383,7 +374,6 @@ class ServingEngine:
     # pair.
     _GUARDED_BY = {
         "_prefix_caches": ("_prefix_lock",),
-        "_preloaded": ("_prefix_lock",),
         "kv_stats": ("_stats_lock", "driver", "main"),
         "prefill_stats": ("_stats_lock", "driver", "main"),
         "overlap_stats": ("_stats_lock", "driver", "main"),
@@ -404,14 +394,13 @@ class ServingEngine:
                  spec_depths=None,
                  prompt_buckets=(32, 64, 128, 256, 512, 1024),
                  prefill_budget: Optional[int] = None,
-                 paged: Optional[bool] = None,
                  kv_block_size: int = 16,
                  kv_pool_blocks=None,
                  prefix_cache_limit: int = 32,
                  hbm_budget_bytes: Optional[int] = None,
                  hbm_headroom: float = 0.1):
-        # kv_cache_int8 configs SERVE here (the per-slot and paged
-        # caches both quantize with the linear-cache recipe), and so do
+        # kv_cache_int8 configs SERVE here (the batch-1 cache and the
+        # pool both quantize with the linear-cache recipe), and so do
         # window layers that a MoeConfig's ``attn_period`` names (a ring
         # of blocks a lane, below).  The dense family's ONE global
         # ``sliding_window`` and attention sinks (StreamingLLM: the
@@ -563,20 +552,15 @@ class ServingEngine:
         if cast_params:
             params = cast_floating(params, config.dtype)
         self._variables = maybe_quant_variables(params, quant_scales)
-        # Paged KV cache (the default; ``paged=False`` or
-        # TTD_NO_PAGED_KV=1 restores the linear per-slot cache
-        # byte-for-byte).  The pool is sized in BLOCKS: by default
-        # slots * ceil(cache_len / block_size) — the linear cache's
-        # exact memory, so defaults change layout, never capacity;
-        # operators shrink/grow it with ``kv_pool_blocks`` (admission
-        # then keys on free blocks, not free slots).
+        # Paged KV cache.  The pool is sized in BLOCKS: by default
+        # slots * ceil(cache_len / block_size), every lane's whole
+        # context; operators shrink/grow it with ``kv_pool_blocks``
+        # (admission then keys on free blocks, not free slots).
         if kv_block_size < 1:
             raise ValueError(
                 f"kv_block_size must be >= 1, got {kv_block_size}")
         self.kv_block_size = int(kv_block_size)
         self._kv_nblk_lane = -(-self.cache_len // self.kv_block_size)
-        self.paged = ((True if paged is None else bool(paged))
-                      and not _paged_killed())
         # ``kv_pool_blocks="auto"``: solve the pool size + HBM budget
         # exactly from the device's reported memory and the memcheck
         # projection (pool rows + batch-1 prefill transients + draft
@@ -584,9 +568,8 @@ class ServingEngine:
         # on any chip.  The solve itself is DEFERRED below the draft
         # section: it eval_shapes BOTH models' caches, so both
         # variable trees must exist first.  ``TTD_NO_HBM_AUTOSIZE=1``
-        # (or a linear-cache engine, which has no pool) falls back to
-        # the default heuristic with no budget set — bitwise the
-        # hand-tuned defaults.
+        # falls back to the default heuristic with no budget set —
+        # bitwise the hand-tuned defaults.
         if not 0.0 <= hbm_headroom < 1.0:
             raise ValueError(
                 f"hbm_headroom must be in [0, 1), got {hbm_headroom}")
@@ -598,7 +581,7 @@ class ServingEngine:
                 raise ValueError(
                     "kv_pool_blocks='auto' solves hbm_budget_bytes "
                     "itself; pass one or the other")
-            if _hbm_autosize_killed() or not self.paged:
+            if _hbm_autosize_killed():
                 autosize = False
                 kv_pool_blocks = None
         elif isinstance(kv_pool_blocks, str):
@@ -617,10 +600,9 @@ class ServingEngine:
         # refused for want of blocks.
         self.kv_stats = {"prefix_hit_tokens": 0, "prefix_hits": 0,
                          "evictions": 0, "alloc_refusals": 0}
-        # Prefill always runs batch-1 on the LINEAR cache (the same
-        # piece programs as the linear engine — prefix reuse replaces
-        # recompute with a pool gather, never changes the math); only
-        # the slot-grid decode/verify/insert programs go paged.
+        # Prefill always runs batch-1 on a LINEAR cache (prefix reuse
+        # replaces recompute with a pool gather, never changes the
+        # math); the slot-grid decode/verify/insert programs are paged.
         # Its attention walks a call's queries a piece at a time
         # (``query_block``), so a call over several pieces of a prompt
         # (``_advance_piece``) costs what the pieces cost.
@@ -711,7 +693,6 @@ class ServingEngine:
                 config, draft_config)
             self.hbm_budget_bytes = budget
             self._hbm_autosized = budget
-        self._kv_pool = self._radix = None
         # Blocks of a window layer's ring a lane: the window of the
         # newest of the rows ONE model call adds (a decode step adds
         # one: speculation beside window layers is refused above, and
@@ -720,26 +701,23 @@ class ServingEngine:
         # writes a ring: pieces run on the batch-1 cache, which keeps
         # every row, and the insert copies the ring's blocks from it.
         self._ring_blocks = 0
-        if self.paged and self._window is not None:
+        if self._window is not None:
             q_len = 1
             self._ring_blocks = 1 + -(-(self._window + q_len - 1)
                                       // self.kv_block_size)
-        if self.paged:
-            self._kv_pool = serving_kv.KVBlockPool(
-                kv_pool_blocks, self.kv_block_size)
-            self._radix = serving_kv.RadixPrefixIndex(self._kv_pool)
-        self._model = (_decode_model(
+        self._kv_pool = serving_kv.KVBlockPool(
+            kv_pool_blocks, self.kv_block_size)
+        self._radix = serving_kv.RadixPrefixIndex(self._kv_pool)
+        self._model = _decode_model(
             config, self.cache_len, slot_decode=True,
             paged_kv_blocks=1 + kv_pool_blocks,
             kv_block_size=self.kv_block_size,
             ring_blocks=self._ring_blocks)
-            if self.paged else self._prefill_model)
         if draft_config is not None:
-            self._draft_model = (_decode_model(
+            self._draft_model = _decode_model(
                 draft_config, self.cache_len, slot_decode=True,
                 paged_kv_blocks=1 + kv_pool_blocks,
                 kv_block_size=self.kv_block_size)
-                if self.paged else self._draft_prefill_model)
         # Sharded serving: with a mesh, every device call runs under
         # jax.set_mesh + the logical-axis rules, so the models' logical
         # constraints shard weights/cache/activations (e.g. heads over
@@ -764,8 +742,9 @@ class ServingEngine:
         self._cache_shapes: dict = {}  # (draft, batch, grid) -> eval_shape
         self._flash_layer_counts: dict = {}   # (draft, q_len) -> layers
         self._moe_prefill_lens: set = set()  # distinct exact-prefill lens
-        # Linear-path prefix caches (paged mode subsumes them via the
-        # radix index): LRU-BOUNDED — keyed by tuple(tokens), these
+        # Stored batch-1 prefix pairs (``preload_prefix``; they cover
+        # the sub-block tail the radix index cannot): LRU-BOUNDED —
+        # keyed by tuple(tokens), these
         # hold device memory, and an unbounded dict leaks under many
         # distinct preloaded prefixes.  ``prefix_cache_limit`` caps the
         # entries; preload past it evicts the least recently matched.
@@ -779,7 +758,7 @@ class ServingEngine:
         # the driver thread writes: validate_request scans the prefix
         # stores concurrently with admission's LRU touches / preload's
         # eviction, and an OrderedDict mutated mid-iteration raises in
-        # the READER.  Everything touching _prefix_caches/_preloaded
+        # the READER.  Everything touching _prefix_caches
         # holds this lock (admission's hold is nanoseconds — dict
         # walks, never device work).
         import threading
@@ -790,19 +769,16 @@ class ServingEngine:
         # multi-field update is observed whole.  Declared in
         # ``_GUARDED_BY`` above; ttd-lint enforces the discipline.
         self._stats_lock = threading.Lock()
-        # Paged-mode per-lane claims and admission bookkeeping:
+        # Per-lane claims and admission bookkeeping:
         # _lane_kv[slot] holds the LaneKV while the lane decodes;
         # _stale_slots are lanes retired/cancelled since the last
         # dispatch — their block-table rows must be zeroed (pointed at
         # the scratch block) BEFORE the next decode program runs, or
         # the one garbage chunk a retired lane still decodes would
         # write into blocks already freed to (and maybe reallocated
-        # by) someone else.  _preloaded records preload_prefix token
-        # tuples for validate_request's bucket rule (the radix itself
-        # is evictable, so validation must not depend on it).
+        # by) someone else.
         self._lane_kv: list = [None] * slots
         self._stale_slots: set = set()
-        self._preloaded: dict = {}
         self._kv_refused_rid: Optional[int] = None  # dedup refusal count
         # prefill_budget: the prompt tokens of staged prefill a
         # serve_step may advance while a lane decodes (None = one
@@ -868,11 +844,10 @@ class ServingEngine:
                               "harvest_s": 0.0,
                               "overlapped_harvest_s": 0.0}
         # Fused paged attention (ops.pallas_kernels.paged_attention):
-        # decided at construction from the same env/backend rule the
-        # decode trace reads (TTD_NO_FUSED_ATTN kills it; TPU default)
-        # — recorded here so dispatch spans and benches can tag which
-        # leg ran.  Flip the switch BEFORE constructing the engine:
-        # the decision burns into the compiled decode programs.
+        # decided at construction from the same backend rule the
+        # decode trace reads (the TPU runs it; TTD_NO_PALLAS=1, set
+        # BEFORE the engine is built, does not) — recorded here so
+        # dispatch spans can tag which leg ran.
         from tensorflow_train_distributed_tpu.ops import (
             pallas_kernels as _pk,
         )
@@ -884,19 +859,13 @@ class ServingEngine:
         # does not veto the kernel.
         meshed = (self._mesh is not None
                   and any(v > 1 for v in self._mesh.shape.values()))
-        self._fused_attn = bool(self.paged and not meshed
+        self._fused_attn = bool(not meshed
                                 and _pk.use_fused_paged_attention())
         # Span-arg form, precomputed: the dispatch-critical window must
         # not run int() (the dispatch lint cannot tell a host bool from
         # a device scalar there, and keeping the window conversion-free
         # is the cheaper discipline anyway).
         self._fused_tag = 1 if self._fused_attn else 0
-        # Device bytes the paged pools pin (target + draft, int8 scale
-        # pools included) — computed once from the memoized cache
-        # eval_shape (host-only trace, no device work) so the /metrics
-        # scrape thread reads a plain int.  The --kv-pool-blocks
-        # oversizing lever is sized against this number.
-        self._kv_pool_bytes = self._kv_ring_bytes = 0
         # Bytes of recurrent state the grid pins (every slot, every
         # linear layer: state and convolution tail) and a lane's share
         # of them; no rows, so no part of ``kv_pool_bytes``.
@@ -907,36 +876,40 @@ class ServingEngine:
             if getattr(p[-1], "key", "") in _STATE_LEAVES
         ) if self._state_layers else 0
         self._state_lane_bytes = self._state_pool_bytes // self.slots
-        if self.paged:
-            def _pool_bytes(struct, ringed: bool):
-                """Bytes of the row-holding leaves of one kind: a
-                window layer's rings, or the blocks tables map."""
-                rings = self._ringed_modules(struct)
-                return sum(
-                    int(np.prod(leaf.shape))
-                    * jnp.dtype(leaf.dtype).itemsize
-                    for p, leaf in
-                    jax.tree_util.tree_flatten_with_path(struct)[0]
-                    if getattr(p[-1], "key", "") in _ROW_LEAVES
-                    and (self._path_key(p)[:-1] in rings) == ringed)
 
-            grid = self._cache_struct(self.slots, grid=True)
-            self._kv_ring_bytes = _pool_bytes(grid, True)
-            self._kv_pool_bytes = (_pool_bytes(grid, False)
-                                   + self._kv_ring_bytes)
-            if self._draft_model is not None:
-                self._kv_pool_bytes += _pool_bytes(
-                    self._cache_struct(self.slots, draft=True,
-                                       grid=True), False)
-            # Per-block row bytes across the layers whose blocks the
-            # allocator hands out (draft + int8 scale pools included;
-            # a window layer's rings are the slots', not the
-            # allocator's): its byte view of its own blocks, so
-            # block-count accounting (serving_kv) can be read in BYTES
-            # too — what admission and the memcheck gauges reason in.
-            self._kv_pool.bytes_per_block = (
-                (self._kv_pool_bytes - self._kv_ring_bytes)
-                // (1 + self._kv_pool.n_blocks))
+        # Device bytes the paged pools pin (target + draft, int8 scale
+        # pools included) — computed once from the memoized cache
+        # eval_shape (host-only trace, no device work) so the /metrics
+        # scrape thread reads a plain int.  The --kv-pool-blocks
+        # oversizing lever is sized against this number.
+        def _pool_bytes(struct, ringed: bool):
+            """Bytes of the row-holding leaves of one kind: a window
+            layer's rings, or the blocks tables map."""
+            rings = self._ringed_modules(struct)
+            return sum(
+                int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
+                for p, leaf in
+                jax.tree_util.tree_flatten_with_path(struct)[0]
+                if getattr(p[-1], "key", "") in _ROW_LEAVES
+                and (self._path_key(p)[:-1] in rings) == ringed)
+
+        grid = self._cache_struct(self.slots, grid=True)
+        self._kv_ring_bytes = _pool_bytes(grid, True)
+        self._kv_pool_bytes = (_pool_bytes(grid, False)
+                               + self._kv_ring_bytes)
+        if self._draft_model is not None:
+            self._kv_pool_bytes += _pool_bytes(
+                self._cache_struct(self.slots, draft=True, grid=True),
+                False)
+        # Per-block row bytes across the layers whose blocks the
+        # allocator hands out (draft + int8 scale pools included; a
+        # window layer's rings are the slots', not the allocator's):
+        # its byte view of its own blocks, so block-count accounting
+        # (serving_kv) can be read in BYTES too — what admission and
+        # the memcheck gauges reason in.
+        self._kv_pool.bytes_per_block = (
+            (self._kv_pool_bytes - self._kv_ring_bytes)
+            // (1 + self._kv_pool.n_blocks))
         if self.hbm_budget_bytes is not None:
             # Budgeted engines precompute the admission projection NOW:
             # validate_request runs on gateway HANDLER threads, which
@@ -1195,31 +1168,6 @@ class ServingEngine:
         return (t_cache, d_cache, emit, emitted, next_tok, a,
                 counts + emitted)
 
-    @compile_site(buckets="slot-grid (shape-fixed per engine)",
-                  donates=(1,), statics=(0,), max_compiles=4)
-    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
-    def _insert(self, cache_b, cache_1, slot, true_len):
-        """Copy a prefilled request's cache rows into ``slot`` and pin
-        the slot's per-slot index to the TRUE prompt length.  Leaves are
-        the index [..., B] and row-holding [..., B, C, *row]
-        (``_ROW_LEAVES``: the batch axis lies two before the row's own
-        dims)."""
-        def ins(path, pb, p1):
-            name = getattr(path[-1], "key", "")
-            if name == "index":
-                return pb.at[..., slot].set(true_len)
-            if name == "pad_rows":
-                return pb
-            if name in _STATE_LEAVES:
-                return jax.lax.dynamic_update_slice_in_dim(
-                    pb, p1.astype(pb.dtype), slot,
-                    axis=pb.ndim - (1 + _STATE_LEAVES[name]))
-            return jax.lax.dynamic_update_slice_in_dim(
-                pb, p1, slot,
-                axis=pb.ndim - (2 + _LINEAR_ROW_DIMS[name]))
-
-        return jax.tree_util.tree_map_with_path(ins, cache_b, cache_1)
-
     # -- paged-pool programs -----------------------------------------------
 
     @staticmethod
@@ -1345,11 +1293,10 @@ class ServingEngine:
     @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _paged_insert(self, cache, cache_1, slot, table_row, start,
                       true_len):
-        """Paged-mode ``_insert``: scatter the prefilled rows [start,
-        true_len) into the lane's blocks, install its block-table row,
-        and pin its index to the TRUE prompt length (rows below
-        ``start`` come from radix-shared blocks and are already
-        there)."""
+        """Scatter the prefilled rows [start, true_len) into the lane's
+        blocks, install its block-table row, and pin its index to the
+        TRUE prompt length (rows below ``start`` come from
+        radix-shared blocks and are already there)."""
         cache = self._scatter_rows_tree(cache, cache_1, table_row,
                                         start, true_len, slot)
         flat_1 = {self._path_key(p): leaf for p, leaf
@@ -1393,11 +1340,10 @@ class ServingEngine:
         """The inverse of ``_scatter_rows_tree``: read a lane's leading
         ``matched`` rows out of the pool into a fresh batch-1 LINEAR
         cache (index pinned to ``matched``), so the suffix prefill runs
-        the exact piece programs the linear engine's ``preload_prefix``
-        path runs — a prefix hit replaces recompute with this copy.
-        Rows past ``matched`` gather whatever the lane's owned blocks
-        hold — garbage the write-before-read prefill rule keeps
-        invisible, exactly like the linear cache's stale rows."""
+        the piece programs a fresh prompt's does — a prefix hit
+        replaces recompute with this copy.  Rows past ``matched``
+        gather whatever the lane's owned blocks hold — garbage the
+        write-before-read prefill rule keeps invisible."""
         if self._ringed_modules(cache):
             raise ValueError(
                 "a window layer's rows behind its window are gone: no "
@@ -1516,17 +1462,15 @@ class ServingEngine:
             raise ValueError(
                 f"prompt {len(prompt)} + {max_new_tokens} new exceeds "
                 f"cache_len={self.cache_len}")
-        if self.paged:
-            # Admission is keyed on BLOCKS: a request whose worst-case
-            # block need exceeds the whole pool could never be granted
-            # a lane — reject now instead of deadlocking the queue.
-            need = -(-(len(prompt) + max_new_tokens)
-                     // self.kv_block_size)
-            if need > self._kv_pool.n_blocks:
-                raise ValueError(
-                    f"request needs {need} KV blocks "
-                    f"(block_size={self.kv_block_size}) but the pool "
-                    f"has {self._kv_pool.n_blocks}")
+        # Admission is keyed on BLOCKS: a request whose worst-case
+        # block need exceeds the whole pool could never be granted a
+        # lane — reject now instead of deadlocking the queue.
+        need = -(-(len(prompt) + max_new_tokens) // self.kv_block_size)
+        if need > self._kv_pool.n_blocks:
+            raise ValueError(
+                f"request needs {need} KV blocks "
+                f"(block_size={self.kv_block_size}) but the pool "
+                f"has {self._kv_pool.n_blocks}")
         if self.hbm_budget_bytes is not None:
             # Projected BYTES alongside the free-blocks check: this
             # request's marginal device allocation is one batch-1
@@ -1556,18 +1500,17 @@ class ServingEngine:
             # is the feature's primary use (preload before submit: a
             # prefix loaded later cannot rescue an already-rejected
             # request).
-            # Paged mode anchors the rule on operator-DECLARED preloads
-            # (radix entries evict under pressure; admission chunks a
-            # grown suffix, but validation must stay deterministic).
+            # The rule is anchored on the STORED preloads, which the
+            # operator declared, not on the radix index (its entries
+            # evict under pressure; admission chunks a grown suffix,
+            # but validation must stay deterministic).
             # RESUMED requests are exempt: the original admission
             # already passed this policy bound, the resumed tail is the
             # request's own output, and ``_pieces_for`` chunks any span
             # into largest-bucket pieces (the long-preload mechanics) —
             # rejecting here would kill an accepted half-streamed
             # request as 'invalid' mid-failover.
-            work = len(prompt) - (self._longest_declared_prefix(prompt)
-                                  if self.paged
-                                  else self._match_prefix(prompt)[0])
+            work = len(prompt) - self._match_prefix(prompt)[0]
             if work > self.prompt_buckets[-1]:
                 raise ValueError(
                     f"prompt length {len(prompt)} (suffix {work} after "
@@ -1608,12 +1551,12 @@ class ServingEngine:
         staged partial prefill, or free its slot so the next refill
         reuses it (the gateway's deadline lever).  A freed slot's cache
         rows go stale-but-invisible — position masks hide them and the
-        next ``_insert`` re-pins the slot index, the same rule stale
-        rows already obey between ``run()`` cycles; a cancelled staged
-        prefill frees its lane IMMEDIATELY (the partial batch-1 cache
-        is simply dropped — it never touched the slot grid).  Returns
-        False when the id is unknown or already finished (its output,
-        if any, stays harvestable)."""
+        next ``_paged_insert`` re-pins the slot index, the same rule
+        stale rows already obey between ``run()`` cycles; a cancelled
+        staged prefill frees its lane IMMEDIATELY (the partial batch-1
+        cache is simply dropped — it never touched the slot grid).
+        Returns False when the id is unknown or already finished (its
+        output, if any, stays harvestable)."""
         for i, item in enumerate(self._queue):
             if item[0] == request_id:
                 del self._queue[i]
@@ -1622,21 +1565,19 @@ class ServingEngine:
                 return True
         for slot, task in self._staging.items():
             if task.request_id == request_id:
-                if task.kv is not None:
-                    # Partial prefill lived in the batch-1 cache only;
-                    # the claim's blocks were never read — free them.
-                    self._kv_release(task.kv)
+                # Partial prefill lived in the batch-1 cache only; the
+                # claim's blocks were never read — free them.
+                self._kv_release(task.kv)
                 del self._staging[slot]
                 events.instant("engine/cancel", rid=request_id,
                                where="staged")
                 return True
         for slot, state in enumerate(self._slot_states):
             if state is not None and state.request_id == request_id:
-                if self.paged:
-                    # Prompt blocks stay radix-cached (inserted at
-                    # finalize); the generated tail is dropped with
-                    # the lane.
-                    self._lane_release(slot)
+                # Prompt blocks stay radix-cached (inserted at
+                # finalize); the generated tail is dropped with the
+                # lane.
+                self._lane_release(slot)
                 self._slot_states[slot] = None
                 events.instant("engine/cancel", rid=request_id,
                                where="slot")
@@ -1663,8 +1604,8 @@ class ServingEngine:
     def _cache_struct(self, batch: int, draft: bool = False,
                       grid: bool = False):
         """Memoized eval_shape of a cache tree: ``grid`` selects the
-        slot-grid decode model (the paged pool + block tables when
-        paging is on), otherwise the batch-1 LINEAR prefill model.  One
+        slot-grid decode model (the paged pool + block tables),
+        otherwise the batch-1 LINEAR prefill model.  One
         trace per (draft, batch, grid) — re-tracing per request would
         put host latency in the serving loop."""
         key = (draft, batch, grid)
@@ -1854,17 +1795,12 @@ class ServingEngine:
                     pin, d_cache_1)
         # LRU bound: these entries hold device memory (a batch-1 cache
         # pair each) and used to accumulate forever — evict the least
-        # recently MATCHED prefix past the limit.  ``_preloaded`` (the
-        # paged path's validation anchor) is bounded in lockstep so the
-        # host-side record cannot outgrow the limit either.
+        # recently MATCHED prefix past the limit.
         with self._prefix_lock:
             self._prefix_caches[tuple(tokens)] = (cache_1, d_cache_1)
             self._prefix_caches.move_to_end(tuple(tokens))
             while len(self._prefix_caches) > self.prefix_cache_limit:
-                evicted_key, _ = self._prefix_caches.popitem(last=False)
-                self._preloaded.pop(evicted_key, None)
-            if self.paged:
-                self._preloaded[tuple(tokens)] = n
+                self._prefix_caches.popitem(last=False)
         # The STORED pair is a held-as-minted device tree (copied per
         # admission, freed at LRU eviction) — exactly the
         # leaf-lifetime contract, so the memcheck ledger tracks the
@@ -1873,15 +1809,14 @@ class ServingEngine:
         memcheck.track(self, "prefix_cache", (cache_1, d_cache_1),
                        label=f"prefix{n}",
                        budget=self.hbm_budget_bytes)
-        if self.paged:
-            # Paged mode ALSO seeds the radix index with the prefix's
-            # full blocks (scattered from the just-built cache — no
-            # second prefill), so later requests share them through the
-            # pool like any other radix hit; the stored batch-1 pair
-            # keeps covering the sub-block tail (a prefix shorter than
-            # one block has no shareable blocks at all).
-            with self._ctx():
-                self._seed_radix_from_cache(tokens, cache_1, d_cache_1)
+        # The radix index is seeded with the prefix's full blocks too
+        # (scattered from the just-built cache — no second prefill), so
+        # later requests share them through the pool like any other
+        # radix hit; the stored batch-1 pair keeps covering the
+        # sub-block tail (a prefix shorter than one block has no
+        # shareable blocks at all).
+        with self._ctx():
+            self._seed_radix_from_cache(tokens, cache_1, d_cache_1)
 
     def _seed_radix_from_cache(self, tokens, cache_1, d_cache_1) -> None:
         """Scatter a preloaded prefix's FULL blocks from its batch-1
@@ -1954,8 +1889,8 @@ class ServingEngine:
     def export_prefix_kv(self, tokens):
         """Serialize the KV of ``tokens``' full leading blocks for a
         prefill→decode handoff: ``(meta, blob)``, or None when there is
-        nothing exportable (linear cache, sub-block prompt, pool too
-        busy to share).
+        nothing exportable (no prefix sharing, sub-block prompt, pool
+        too busy to share).
 
         The prefill side of disaggregated serving: prefill the prompt's
         block-aligned head (``preload_prefix`` — the tested machinery,
@@ -1969,7 +1904,7 @@ class ServingEngine:
         decode worker, same as any radix hit).  Mutates engine state —
         callers marshal onto the engine's owning thread
         (``EngineDriver.call``)."""
-        if not self.paged or not self._share_prefix:
+        if not self._share_prefix:
             return None
         tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
         bs = self.kv_block_size
@@ -2050,7 +1985,7 @@ class ServingEngine:
         and partial-failure semantics are all the tested ones.  Mutates
         engine state — callers marshal onto the engine's owning thread
         (``EngineDriver.call``)."""
-        if not self.paged or not self._share_prefix:
+        if not self._share_prefix:
             return 0
         tokens = [int(t) for t in meta.get("tokens", ())]
         n = int(meta.get("n", 0))
@@ -2140,9 +2075,10 @@ class ServingEngine:
           gathered from the lane's OWN block table: valid for
           ``[0, len(tokens) - 1)`` (the last sampled token was never
           fed back), hence the head stops at the last full block under
-          that bound.  A linear-cache or sub-block lane exports with
-          ``kv=None`` — the target re-prefills, which is exactly the
-          failover path and stays bitwise by the same contract.
+          that bound.  A sub-block lane, or one that shares no prefix,
+          exports with ``kv=None`` — the target re-prefills, which is
+          exactly the failover path and stays bitwise by the same
+          contract.
         - ``"staged"``: mid-admission in a reserved lane.  The partial
           batch-1 prefill is NOT shipped (pieces are cheap to redo and
           piece boundaries are engine-local); the staged cursor rides
@@ -2179,8 +2115,7 @@ class ServingEngine:
             blob = b""
             bs = self.kv_block_size
             m = max(0, (len(state.tokens) - 1) // bs)
-            kv = (self._lane_kv[slot]
-                  if self.paged and self._share_prefix else None)
+            kv = self._lane_kv[slot] if self._share_prefix else None
             if kv is not None and m > 0 and self._cache is not None:
                 head = [int(t) for t in state.tokens[:m * bs]]
                 # The lane's claim already holds a ref on every block
@@ -2239,20 +2174,6 @@ class ServingEngine:
             if touch and best_key is not None:
                 self._prefix_caches.move_to_end(best_key)
             return best, best_pair
-
-    def _longest_declared_prefix(self, prompt) -> int:
-        """Longest PRELOADED prefix the prompt strictly extends — the
-        paged path's validation anchor.  Validation must not consult
-        the radix index (its entries evict under pressure, and
-        admission handles a shrunk match by chunking the longer
-        suffix); preloads are operator-declared, LRU-bounded like the
-        linear pairs they parallel."""
-        best = 0
-        with self._prefix_lock:
-            for toks, m in self._preloaded.items():
-                if best < m < len(prompt) and prompt[:m] == list(toks):
-                    best = m
-        return best
 
     def _note_moe_prefill_len(self, n: int) -> None:
         if not self._exact_prefill or n in self._moe_prefill_lens:
@@ -2375,7 +2296,7 @@ class ServingEngine:
         """Zero retired/cancelled lanes' block-table rows before the
         next decode program (their freed blocks may already belong to
         someone else; the garbage chunk must write scratch)."""
-        if not self.paged or not self._stale_slots:
+        if not self._stale_slots:
             return
         if self._cache is None:
             self._stale_slots.clear()
@@ -2391,13 +2312,13 @@ class ServingEngine:
         self._stale_slots.clear()
 
     def _admission_match(self, kv, prompt):
-        """(pre_len, pre_pair) for a paged admission: the radix match
+        """(pre_len, pre_pair) for an admission: the radix match
         (kv.matched, gather path) unless a STORED preload pair covers
         more — sub-block prefix tails only the batch-1 pair can
         represent (a prefix shorter than a block has no shareable
         blocks; a 20-token prefix at block 16 shares one block and
         copies the 4-token tail).  Suffix prefill piece sizing follows
-        ``pre_len`` exactly as on the linear path."""
+        ``pre_len``."""
         pre_len, pre_pair = kv.matched, None
         if self._share_prefix:
             lin_len, lin_pair = self._match_prefix(prompt, touch=True)
@@ -2443,29 +2364,28 @@ class ServingEngine:
         ``prefill/cache`` span's ``kind``)."""
         if pre_pair is not None:
             return "copy"
-        if not self.paged or kv is None or kv.matched == 0:
+        if kv.matched == 0:
             return "fresh"
         return "gather"
 
     def kv_blocks_total(self) -> int:
-        """Allocatable physical blocks in the paged pool (0 when the
-        linear cache is serving — the truthful scrape)."""
-        return self._kv_pool.n_blocks if self.paged else 0
+        """Allocatable physical blocks in the paged pool."""
+        return self._kv_pool.n_blocks
 
     def kv_blocks_in_use(self) -> int:
         """Blocks currently referenced (live lanes + radix cache)."""
-        return self._kv_pool.blocks_in_use() if self.paged else 0
+        return self._kv_pool.blocks_in_use()
 
     def kv_bytes_in_use(self) -> int:
         """Referenced pool blocks in device BYTES (live lanes + radix
         cache at the real per-block row cost) — the occupancy half of
         ``kv_pool_bytes()``'s constant capacity, relayed per worker in
         stats frames and shown per replica in /healthz."""
-        return self._kv_pool.bytes_in_use() if self.paged else 0
+        return self._kv_pool.bytes_in_use()
 
     def kv_pool_bytes(self) -> int:
         """Device bytes the paged KV pools pin across layers (target +
-        draft; int8 scale pools included; 0 = linear cache).  Constant
+        draft; int8 scale pools included).  Constant
         per engine — the pool never grows — so scrape threads read a
         plain int; the ``--kv-pool-blocks`` oversizing lever budgets
         against this."""
@@ -2569,8 +2489,8 @@ class ServingEngine:
 
     def fused_attn(self) -> bool:
         """Whether the decode programs were compiled with the fused
-        paged-attention kernel (False on CPU, under a mesh, with the
-        linear cache, or when TTD_NO_FUSED_ATTN killed it)."""
+        paged-attention kernel (False on CPU, under a mesh, or under
+        TTD_NO_PALLAS=1)."""
         return self._fused_attn
 
     def _spec_depth(self) -> int:
@@ -2670,28 +2590,23 @@ class ServingEngine:
         when the pool has no blocks for it: the request goes back to
         the queue's head and staging stops (FIFO — nothing behind may
         jump the head; blocks free as lanes retire)."""
-        kv = table_j = None
-        if self.paged:
-            kv = self._kv_claim(rid, prompt, max_new)
-            if kv is None:
-                self._queue.appendleft(
-                    (rid, prompt, max_new, seed, resume))
-                return None
-            table_j = self._kv_table(kv)
-            if self._ring_blocks:
-                # The slot's own ring in each window layer: claimed
-                # with the slot, nothing to refuse.
-                events.instant("kv/alloc", rid=rid,
-                               blocks=self._ring_blocks, shared=0,
-                               pool="window")
-            if self._state_layers:
-                # The slot's own state in each linear layer: claimed
-                # with the slot, no blocks, nothing to refuse.
-                events.instant("kv/alloc", rid=rid, blocks=0, shared=0,
-                               pool="state")
-            pre_len, pre_pair = self._admission_match(kv, prompt)
-        else:
-            pre_len, pre_pair = self._match_prefix(prompt, touch=True)
+        kv = self._kv_claim(rid, prompt, max_new)
+        if kv is None:
+            self._queue.appendleft((rid, prompt, max_new, seed, resume))
+            return None
+        table_j = self._kv_table(kv)
+        if self._ring_blocks:
+            # The slot's own ring in each window layer: claimed with
+            # the slot, nothing to refuse.
+            events.instant("kv/alloc", rid=rid,
+                           blocks=self._ring_blocks, shared=0,
+                           pool="window")
+        if self._state_layers:
+            # The slot's own state in each linear layer: claimed with
+            # the slot, no blocks, nothing to refuse.
+            events.instant("kv/alloc", rid=rid, blocks=0, shared=0,
+                           pool="state")
+        pre_len, pre_pair = self._admission_match(kv, prompt)
         work = prompt[pre_len:]
         self._note_moe_prefill_len(len(prompt))
         m = len(work)
@@ -2719,8 +2634,7 @@ class ServingEngine:
                            count=task.resume + 1)
         with events.span("prefill/insert", rid=task.request_id):
             self._insert_lane(slot, task)
-            if task.kv is not None:
-                self._lane_claim(slot, task.kv, task.prompt)
+            self._lane_claim(slot, task.kv, task.prompt)
         self._poll_drained()
         # Staging is cleared BEFORE the slot state is set: the gateway's
         # metrics thread reads active_slots() (= decoding + staged)
@@ -2744,13 +2658,9 @@ class ServingEngine:
             if grid is None:
                 grid = self._launch(self._fresh_cache, self.slots,
                                     draft=draft, grid=True)
-            if self.paged:
-                grid = self._launch(
-                    self._paged_insert, grid, cache_1, jnp.int32(slot),
-                    task.table, jnp.int32(task.kv.matched), jnp.int32(n))
-            else:
-                grid = self._launch(self._insert, grid, cache_1,
-                                    jnp.int32(slot), jnp.int32(n))
+            grid = self._launch(
+                self._paged_insert, grid, cache_1, jnp.int32(slot),
+                task.table, jnp.int32(task.kv.matched), jnp.int32(n))
             setattr(self, attr, grid)
 
     def _advance_piece(self, slot: int, task: _PrefillTask,
@@ -2852,8 +2762,7 @@ class ServingEngine:
                         # prefill, which such a request would waste.
                         # Its blocks were never written: hand them
                         # straight back.
-                        if task.kv is not None:
-                            self._kv_release(task.kv)
+                        self._kv_release(task.kv)
                         self._outputs[task.request_id] = (
                             list(task.prompt) + [first])
                         del self._staging[slot]
@@ -2987,11 +2896,10 @@ class ServingEngine:
 
     def _retire_if_done(self, slot, state):
         if state.done:
-            if self.paged:
-                # Feed the radix index with the finished request's
-                # generated full blocks (a follow-up turn extending
-                # this conversation hits warm KV), then free the rest.
-                self._lane_release(slot, tokens=state.tokens)
+            # Feed the radix index with the finished request's
+            # generated full blocks (a follow-up turn extending this
+            # conversation hits warm KV), then free the rest.
+            self._lane_release(slot, tokens=state.tokens)
             self._outputs[state.request_id] = state.tokens
             self._slot_states[slot] = None
             events.instant("slot/retire", rid=state.request_id,
@@ -3119,21 +3027,20 @@ class ServingEngine:
         call's ``spec_k + 1`` queries reach, one for an idle slot) over
         all slots, of the ``kv_table_blocks`` their tables have;
         ``kv_window_blocks`` the same rule from a window's first block
-        on, for one window layer; ``kv_bytes`` what ``kv_blocks`` are in
-        bytes; all 0 on a linear cache."""
-        kv_blocks = kv_table_blocks = kv_window_blocks = kv_bytes = 0
-        if self.paged:
-            kv_table_blocks = self.slots * self._kv_nblk_lane
-            lengths = np.asarray(held, np.int64)
-            kv_blocks = self.slots - len(held) + int(paged_blocks_walked(
-                lengths, spec_k + 1, self.kv_block_size,
-                self._kv_nblk_lane).sum())
-            if self._window is not None:
-                kv_window_blocks = self.slots - len(held) + int(
-                    paged_blocks_walked(
-                        lengths, spec_k + 1, self.kv_block_size,
-                        self._kv_nblk_lane, self._window).sum())
-            kv_bytes = kv_blocks * self._kv_pool.bytes_per_block
+        on, for one window layer (0 without one); ``kv_bytes`` what
+        ``kv_blocks`` are in bytes."""
+        kv_table_blocks = self.slots * self._kv_nblk_lane
+        lengths = np.asarray(held, np.int64)
+        kv_blocks = self.slots - len(held) + int(paged_blocks_walked(
+            lengths, spec_k + 1, self.kv_block_size,
+            self._kv_nblk_lane).sum())
+        kv_window_blocks = 0
+        if self._window is not None:
+            kv_window_blocks = self.slots - len(held) + int(
+                paged_blocks_walked(
+                    lengths, spec_k + 1, self.kv_block_size,
+                    self._kv_nblk_lane, self._window).sum())
+        kv_bytes = kv_blocks * self._kv_pool.bytes_per_block
         self._step_counts.update(
             lanes=len(held), positions=sum(held), kv_blocks=kv_blocks,
             kv_table_blocks=kv_table_blocks,

@@ -1,7 +1,7 @@
 """Paged KV-cache bookkeeping: block pool + radix prefix index.
 
 The HOST half of the serving engine's paged KV cache
-(``serving.ServingEngine`` with ``paged=True``, the default).  Device
+(``serving.ServingEngine``'s slot grid).  Device
 memory is one fixed pool of ``[num_blocks, block_size, kv_heads,
 head_dim]`` rows per layer (static shape — jit/sharding see one
 allocation for the whole session, the Mesh-TensorFlow static-shape

@@ -53,7 +53,7 @@ def test_conftest_armed_and_package_sites_registered():
     for site in ("serving.ServingEngine._prefill_piece",
                  "serving.ServingEngine._decode_chunk",
                  "serving.ServingEngine._spec_round",
-                 "serving.ServingEngine._insert",
+                 "serving.ServingEngine._paged_insert",
                  "generate._generate"):
         assert site in sites, f"{site} not registered (got {sites})"
     # The wrapper actually wrapped (armed path, not the bare jit).

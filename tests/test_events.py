@@ -381,8 +381,8 @@ def test_trace_report_prints_the_kv_walk_live_share(tmp_path, capsys):
     """``engine/step``'s ``kv_blocks`` (what the attention kernel's walk
     read at the step's dispatch) beside ``kv_table_blocks`` (the slots x
     blocks-a-lane table it spans): the report sums both over the window
-    and prints the share; a step with no dispatch, or a linear cache
-    (both 0), adds nothing.  Where some layers see a sliding window,
+    and prints the share; a step with no dispatch (both 0) adds
+    nothing.  Where some layers see a sliding window,
     ``kv_window_blocks`` (one such layer's walk of its rings) is
     printed as a share of ``kv_blocks``; a model without one prints no
     such line."""

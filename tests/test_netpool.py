@@ -178,7 +178,7 @@ def test_hello_reassembled_across_recv_boundaries():
             "proto": proto.PROTO_VERSION, "pid": 12345,
             "replica": None, "role": "decode", "mono": 0.0,
             "engine": {"slots": 1, "kv_block_size": 16,
-                       "cache_len": 64, "paged": False,
+                       "cache_len": 64,
                        "pool_blocks": None, "buckets": None}})
         with socket.create_connection(("127.0.0.1", pool.port),
                                       timeout=10) as sock:

@@ -377,7 +377,7 @@ def test_tcp_stats_frame_lands_hbm_and_programs_in_pool(monkeypatch):
             "proto": proto.PROTO_VERSION, "pid": 4242,
             "replica": None, "role": "decode", "mono": 0.0,
             "engine": {"slots": 1, "kv_block_size": 16,
-                       "cache_len": 64, "paged": False,
+                       "cache_len": 64,
                        "pool_blocks": None, "buckets": None}})
         sock = socket.create_connection(("127.0.0.1", pool.port),
                                         timeout=10)

@@ -240,7 +240,7 @@ class TestPagedAttention:
     def test_cpu_path_uses_reference(self):
         # On this CPU backend the public entry must route to the
         # reference — BITWISE equal (it IS the reference), the property
-        # that makes TTD_NO_FUSED_ATTN parity trivial off-TPU.
+        # that makes TTD_NO_PALLAS parity trivial off-TPU.
         q, kp, vp, table, lengths = self._mk(2, 1, 2, 2)
         out = pk.paged_attention(q, kp, vp, table, lengths)
         ref = pk.paged_attention_reference(q, kp, vp, table, lengths)
@@ -362,15 +362,15 @@ def test_block0_reads_one_layer_of_a_pool_that_holds_several(int8,
 
 
 def test_fused_attn_kill_switches(monkeypatch):
-    """TTD_NO_FUSED_ATTN wins over everything (the production kill
-    switch back to the XLA block-gather leg); TTD_FUSED_ATTN_INTERPRET
-    forces the kernel ON off-TPU (the CPU parity-test path); default
-    follows the backend."""
-    monkeypatch.setenv("TTD_NO_FUSED_ATTN", "1")
+    """TTD_NO_PALLAS wins over everything (the XLA block-gather leg,
+    as for every other kernel); TTD_FUSED_ATTN_INTERPRET forces the
+    kernel ON off-TPU (the CPU parity-test path); default follows the
+    backend."""
+    monkeypatch.setenv("TTD_NO_PALLAS", "1")
     assert pk.use_fused_paged_attention() is False
     monkeypatch.setenv("TTD_FUSED_ATTN_INTERPRET", "1")
-    assert pk.use_fused_paged_attention() is False  # kill switch wins
-    monkeypatch.delenv("TTD_NO_FUSED_ATTN")
+    assert pk.use_fused_paged_attention() is False  # TTD_NO_PALLAS wins
+    monkeypatch.delenv("TTD_NO_PALLAS")
     assert pk.use_fused_paged_attention() is True
     assert pk.fused_attn_interpret() is (
         __import__("jax").default_backend() != "tpu")
@@ -379,7 +379,7 @@ def test_fused_attn_kill_switches(monkeypatch):
         __import__("jax").default_backend() == "tpu")
     assert pk.fused_attn_interpret() is False
     # "0"/"false" mean OFF for both flags (the env_flag parser).
-    monkeypatch.setenv("TTD_NO_FUSED_ATTN", "0")
+    monkeypatch.setenv("TTD_NO_PALLAS", "0")
     monkeypatch.setenv("TTD_FUSED_ATTN_INTERPRET", "false")
     assert pk.use_fused_paged_attention() is (
         __import__("jax").default_backend() == "tpu")

@@ -111,12 +111,12 @@ def test_validation_errors(params):
     with pytest.raises(ValueError, match="sliding_window"):
         ServingEngine(wcfg, params)
     # kv_cache_int8 configs SERVE through the engine since PR 11 (the
-    # per-slot and paged caches quantize with the linear recipe) — the
+    # batch-1 cache and the pool quantize with the linear recipe) — the
     # old rejection must stay lifted.
     icfg = dataclasses.replace(CFG, kv_cache_int8=True)
     eng8 = ServingEngine(icfg, params, slots=2, cache_len=32,
                          prompt_buckets=(8,))
-    assert eng8.kv_cache_int8 and eng8.paged
+    assert eng8.kv_cache_int8 and eng8.kv_pool_bytes() > 0
 
 
 def test_slot_decode_layer_guards():

@@ -1,18 +1,18 @@
 """Paged KV cache: block pool, radix sharing, and engine parity.
 
-North star (the ISSUE 6 acceptance bar): with paging ON (the default),
-engine outputs are BITWISE-IDENTICAL to the linear-cache engine for
-greedy, seeded sampling, and speculative serving — including mid-stream
-cancel and staged-prefill interleave — and ``TTD_NO_PAGED_KV=1`` /
-``paged=False`` restores the linear engine byte-for-byte.  The host
-allocator (``serving_kv``) is pinned separately: radix
-insert/match/evict invariants, copy-on-write divergence after a shared
-prefix, and eviction-under-pressure REFUSING admission rather than
-corrupting a live lane.
+North star: the engine's outputs are ``generate()``'s token for token
+(greedy; seeded sampling where the two draw from the same stream), for
+plain and speculative serving, through refills, mid-stream cancel and
+staged-prefill interleave; and the pool holds, to the bit, the rows the
+batch-1 prefill cache held.  The host allocator (``serving_kv``) is
+pinned separately: radix insert/match/evict invariants, copy-on-write
+divergence after a shared prefix, and eviction-under-pressure REFUSING
+admission rather than corrupting a live lane.
 
-Fast tier: the host-only allocator/radix tests (no device work) plus
-one tiny paged-vs-linear engine parity run.  The full matrix (sampling,
-speculative, cancel, interleave, pressure) is slow-tier.
+Fast tier: the host-only allocator/radix tests (no device work), one
+tiny engine run against ``generate()`` and the pool's programs one by
+one.  The full matrix (sampling, speculative, cancel, interleave,
+pressure) is slow-tier.
 """
 
 import dataclasses
@@ -182,18 +182,15 @@ def _serve(params, reqs, *, seeds=None, **kw):
 
 def test_paged_engine_smoke_matches_generate(params):
     """Fast-tier canary: tiny paged engine run, token-identical to
-    generate() and to the linear engine, with the pool drained back to
-    the radix cache afterwards."""
+    generate(), with the pool drained back to the radix cache
+    afterwards."""
     rng = np.random.default_rng(0)
     reqs = [(list(rng.integers(1, 200, 5)), 4),
             (list(rng.integers(1, 200, 3)), 5)]
     out, eng = _serve(params, reqs, slots=2, cache_len=32, chunk=2,
                       prompt_buckets=(8,), kv_block_size=4)
-    assert eng.paged
-    lin, _ = _serve(params, reqs, slots=2, cache_len=32, chunk=2,
-                    prompt_buckets=(8,), kv_block_size=4, paged=False)
-    for o, l, (p, m) in zip(out, lin, reqs):
-        assert o == l == _ref(params, p, m)
+    for o, (p, m) in zip(out, reqs):
+        assert o == _ref(params, p, m)
     # Lanes released; what's in use is exactly the radix-cached blocks.
     assert eng.kv_blocks_in_use() == eng._radix.cached_blocks()
     eng._radix.check_invariants()
@@ -209,7 +206,7 @@ def test_step_counts_the_blocks_the_kernel_walks(params):
 
     eng = ServingEngine(CFG, params, slots=6, cache_len=32, chunk=2,
                         prompt_buckets=(8,), kv_block_size=4)
-    assert eng.paged and eng._kv_nblk_lane == 8
+    assert eng._kv_nblk_lane == 8
 
     def counted(held, spec_k=0):
         eng._count_dispatch(held, spec_k)
@@ -222,11 +219,6 @@ def test_step_counts_the_blocks_the_kernel_walks(params):
     assert counted([0, 3, 4]) == (1 + 1 + 2 + 3, 48)      # 3 idle slots
     assert counted([0, 1, 4], spec_k=3) == (1 + 2 + 2 + 3, 48)
     assert counted([]) == (6, 48)
-    linear = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=2,
-                           prompt_buckets=(8,), paged=False)
-    linear._count_dispatch([5, 9], 0)
-    assert linear._step_counts["kv_blocks"] == 0
-    assert linear._step_counts["kv_table_blocks"] == 0
 
     rec = events.get_recorder()
     seq0 = rec.events_after(0)[0]
@@ -244,10 +236,9 @@ def test_step_counts_the_blocks_the_kernel_walks(params):
 def test_fused_kill_switch_bitwise_and_attrs(params, monkeypatch):
     """Fast-tier canary for the fused paged-attention plumbing: on CPU
     the fused kernel never engages (``fused_attn()`` False), so the
-    default engine and the ``TTD_NO_FUSED_ATTN=1`` engine must be
-    BITWISE identical — the kill-switch plumbing changes dispatch,
-    never math; and ``kv_pool_bytes`` truthfully reports the pool's
-    device footprint (0 on the linear engine)."""
+    default engine and the ``TTD_NO_PALLAS=1`` engine must be BITWISE
+    identical — the switch changes dispatch, never math; and
+    ``kv_pool_bytes`` reports the pool's device footprint."""
     rng = np.random.default_rng(7)
     reqs = [(list(rng.integers(1, 200, 5)), 4),
             (list(rng.integers(1, 200, 3)), 5)]
@@ -255,19 +246,41 @@ def test_fused_kill_switch_bitwise_and_attrs(params, monkeypatch):
                       prompt_buckets=(8,), kv_block_size=4)
     assert eng.fused_attn() is False          # CPU: gather path
     assert eng.kv_pool_bytes() > 0
-    monkeypatch.setenv("TTD_NO_FUSED_ATTN", "1")
+    monkeypatch.setenv("TTD_NO_PALLAS", "1")
     killed, eng_k = _serve(params, reqs, slots=2, cache_len=32, chunk=2,
                            prompt_buckets=(8,), kv_block_size=4)
     assert eng_k.fused_attn() is False
     assert killed == out
-    monkeypatch.delenv("TTD_NO_FUSED_ATTN")
-    lin, eng_l = _serve(params, reqs, slots=2, cache_len=32, chunk=2,
-                        prompt_buckets=(8,), kv_block_size=4,
-                        paged=False)
-    assert eng_l.kv_pool_bytes() == 0 and eng_l.fused_attn() is False
 
 
 ICFG = dataclasses.replace(CFG, kv_cache_int8=True)
+
+
+def _ref_seeded(cfg, params, prompt, max_new, seed, **sampling):
+    """``generate()`` for one prompt; where the engine samples,
+    ``generate()``'s own decode model (the shared-index cache) stepped
+    here on the engine's stream: token ``i`` of a request is drawn with
+    ``fold_in(key(seed), i)`` wherever the request lands, which
+    ``generate()``, that splits one key a batch, does not offer."""
+    if not sampling:
+        return _ref_cfg(cfg, params, prompt, max_new)
+    from tensorflow_train_distributed_tpu.models.generate import (
+        _decode_model,
+        filter_logits,
+    )
+
+    model = _decode_model(cfg, cache_len=len(prompt) + max_new)
+    tokens, variables = list(prompt), {"params": params}
+    feed = jnp.asarray([prompt], jnp.int32)
+    for i in range(max_new):
+        logits, upd = model.apply(variables, feed, mutable=["cache"])
+        variables = dict(variables, cache=upd["cache"])
+        tokens.append(int(jax.random.categorical(
+            jax.random.fold_in(jax.random.key(seed), i),
+            filter_logits(logits[0, -1].astype(jnp.float32),
+                          **sampling))))
+        feed = jnp.asarray([tokens[-1:]], jnp.int32)
+    return tokens
 
 
 def _serve_cfg(cfg, params, reqs, *, seeds=None, **kw):
@@ -298,7 +311,7 @@ def test_int8_paged_engine_smoke_matches_generate(params):
             (list(rng.integers(1, 200, 3)), 5)]
     out, eng = _serve_cfg(ICFG, params, reqs, slots=2, cache_len=32,
                           chunk=2, prompt_buckets=(8,), kv_block_size=4)
-    assert eng.paged and eng.kv_cache_int8
+    assert eng.kv_cache_int8
     for o, (p, m) in zip(out, reqs):
         assert o == _ref_cfg(ICFG, params, p, m)
     kinds = {p[-1].key: leaf.dtype for p, leaf in
@@ -313,8 +326,8 @@ def test_int8_paged_engine_smoke_matches_generate(params):
     assert eng.kv_pool_bytes() < eng_fp.kv_pool_bytes()
 
 
-# ── the depth scan's carried pools against a pool a layer and the ────
-# ── linear cache, program by program ─────────────────────────────────
+# ── the depth scan's carried pools against a pool a layer, the ───────
+# ── batch-1 cache and generate(), program by program ─────────────────
 
 SCFG = dataclasses.replace(CFG, scan_layers=True)
 
@@ -345,10 +358,32 @@ def _row_leaves(cache, scanned):
                          for i in range(CFG.num_layers)]) for n in names}
 
 
+def _rows(leaves, name, lo, hi):
+    """Rows [lo, hi) of leaf ``name`` of a batch-1 cache's
+    ``_row_leaves`` ([layers, (2,) 1, C, *row])."""
+    axis = leaves[name].ndim - (1 + _LINEAR_ROW_DIMS[name])
+    return np.take(leaves[name], range(lo, hi), axis=axis)
+
+
+def _dequantised(leaves, lo, hi):
+    """K and V rows [lo, hi) of a batch-1 cache's ``_row_leaves`` as
+    floats, each with its quantisation step a (row, KV head): 0 where
+    the rows are floats already."""
+    out = {}
+    for i, name in enumerate(("key_cache", "value_cache")):
+        rows = _rows(leaves, name, lo, hi).astype(np.float32)
+        step = np.zeros(rows.shape[:-1], np.float32)
+        if "kv_scales" in leaves:     # [layers, 2, 1, rows, kv_heads]
+            step = np.take(_rows(leaves, "kv_scales", lo, hi), i, axis=1)
+            rows = rows * step[..., None]
+        out[name] = (rows, step)
+    return out
+
+
 class _Lanes:
     """Four lanes set up by hand through the engine's own programs
-    (prefill pieces -> ``_paged_insert`` / ``_insert``), so that every
-    rule of the pool's write is on a lane of its own:
+    (prefill pieces -> ``_paged_insert``), so that every rule of the
+    pool's write is on a lane of its own:
 
     0. an ordinary lane (11 rows in blocks 1..8);
     1. a lane whose first two blocks are lane 0's (a radix-shared
@@ -357,6 +392,9 @@ class _Lanes:
     2. a lane never inserted: table all scratch, index 0;
     3. a lane two rows short of its table's end, which overruns it in
        the steps that follow (rows dropped).
+
+    ``prefilled[which][slot]``: the batch-1 cache the lane's own
+    prefill wrote (target, then draft), as it was before the poison.
     """
 
     C, BS = 32, 4
@@ -364,14 +402,13 @@ class _Lanes:
               3: list(range(15, 23))}
     START = {0: 0, 1: 8, 3: 0}
 
-    def __init__(self, cfg, params, *, paged, q_len):
+    def __init__(self, cfg, params, *, q_len):
         rng = np.random.default_rng(11)
         a = list(rng.integers(1, 200, 11))
         self.prompts = {0: a, 1: a[:8] + list(rng.integers(1, 200, 3)),
                         3: list(rng.integers(1, 200, 30))}
         kw = dict(slots=4, cache_len=self.C, chunk=2,
-                  prompt_buckets=(16,), kv_block_size=self.BS,
-                  paged=paged)
+                  prompt_buckets=(16,), kv_block_size=self.BS)
         self.q_len, self.spec = q_len, q_len > 1
         if self.spec:
             kw.update(draft_config=cfg, draft_params=params,
@@ -380,6 +417,7 @@ class _Lanes:
         self.caches = [eng._fresh_cache(4, grid=True)]
         if self.spec:
             self.caches.append(eng._fresh_cache(4, draft=True, grid=True))
+        self.prefilled = [{} for _ in self.caches]
         tok = np.zeros(4, np.int32)
         for slot, prompt in self.prompts.items():
             for which, draft in enumerate([False, True][:len(self.caches)]):
@@ -388,17 +426,14 @@ class _Lanes:
                     cache_1=eng._fresh_cache(1, draft=draft), draft=draft)
                 if not draft:
                     tok[slot] = int(first)
-                if paged:
-                    cache_1 = self._poisoned(cache_1, self.START[slot])
-                    self.caches[which] = eng._paged_insert(
-                        self.caches[which], cache_1, jnp.int32(slot),
-                        jnp.asarray(self.TABLES[slot], jnp.int32),
-                        jnp.int32(self.START[slot]),
-                        jnp.int32(len(prompt)))
-                else:
-                    self.caches[which] = eng._insert(
-                        self.caches[which], cache_1, jnp.int32(slot),
-                        jnp.int32(len(prompt)))
+                self.prefilled[which][slot] = cache_1
+                self.caches[which] = eng._paged_insert(
+                    self.caches[which],
+                    self._poisoned(cache_1, self.START[slot]),
+                    jnp.int32(slot),
+                    jnp.asarray(self.TABLES[slot], jnp.int32),
+                    jnp.int32(self.START[slot]), jnp.int32(len(prompt)))
+        self.first = tok
         self.tok = jnp.asarray(tok)
         self.emitted = []
 
@@ -441,29 +476,44 @@ class _Lanes:
                 self.caches[0])[0]
             if p[-1].key == "index")).reshape(-1, 4)[0]
 
+    def generated(self, slot):
+        """The tokens lane ``slot`` has produced: its prefill's pick,
+        then what each chunk or round emitted for it."""
+        out = [int(self.first[slot])]
+        for step in self.emitted:
+            n = int(step[1][slot]) if self.spec else step[0].shape[1]
+            out += [int(t) for t in step[0][slot, :n]]
+        return out
+
 
 @pytest.mark.parametrize("leg", ["gather", "kernel"])
 @pytest.mark.parametrize("q_len", [1, 4], ids=["q1", "q4"])
 @pytest.mark.parametrize("cfg", [CFG, ICFG], ids=["f32", "int8"])
-def test_carried_pools_match_a_pool_a_layer_and_the_linear_cache(
+def test_carried_pools_match_a_pool_a_layer_and_the_batch1_cache(
         params, cfg, q_len, leg, monkeypatch):
     """The depth scan CARRIES the pools (one [layers, blocks, ...] leaf
     a pool, written in place); an unrolled model holds a pool a layer.
     With the same weights the two serve the same tokens and leave the
     same bytes in every block, through ``_paged_insert`` -> decode
     chunks (``q_len`` 1) or speculative rounds (``q_len`` 4, target and
-    draft) -> ``_gather_prefix``, and both agree with the linear cache
-    on the tokens and on every row a lane holds.  Lanes: ``_Lanes``.
-    ``kernel``: the same under the fused attention kernel, interpreted,
-    which reads the carried pool through ``block0`` (its online softmax
-    is not the linear cache's to the bit, so the paged two are
-    compared and the linear engine's tokens only)."""
+    draft) -> ``_gather_prefix``.  Lanes: ``_Lanes``.  Both are held to
+    three references that know nothing of the pool: the prompt rows
+    ``_gather_prefix`` reads back are, to the bit, the rows of the
+    batch-1 cache the lane's own prefill wrote; the rows the chunks or
+    rounds wrote agree with the rows a batch-1 prefill of prompt +
+    generated tokens writes, to float32 rounding (int8: to one
+    quantisation step); and the tokens of lanes 0 and 1 are
+    ``generate()``'s (a speculative round's the plain greedy ones).
+    Lane 3 overruns its table and is compared between the two paged
+    layouts alone.  ``kernel``: the same under the fused attention
+    kernel, interpreted, which reads the carried pool through
+    ``block0`` (its online softmax is not the gather leg's to the bit,
+    so the rows it wrote are compared between the paged two only)."""
     if leg == "kernel":
         monkeypatch.setenv("TTD_FUSED_ATTN_INTERPRET", "1")
     scfg = dataclasses.replace(cfg, scan_layers=True)
-    carried = _Lanes(scfg, _stacked(params), paged=True, q_len=q_len)
-    layered = _Lanes(cfg, params, paged=True, q_len=q_len)
-    linear = _Lanes(cfg, params, paged=False, q_len=q_len)
+    carried = _Lanes(scfg, _stacked(params), q_len=q_len)
+    layered = _Lanes(cfg, params, q_len=q_len)
     assert carried.eng.fused_attn() is (leg == "kernel")
     shared = {}
     for which, cache in enumerate(carried.caches):
@@ -473,22 +523,23 @@ def test_carried_pools_match_a_pool_a_layer_and_the_linear_cache(
         shared[which] = {n: v[..., 1:3, :, :].copy()
                          for n, v in pools.items()}
     np.testing.assert_array_equal(carried.tok, layered.tok)
-    np.testing.assert_array_equal(carried.tok, linear.tok)
     for _ in range(2):
-        for lanes in (carried, layered, linear):
+        for lanes in (carried, layered):
             lanes.step()
-    held = [0, 1, 3]             # lane 2 reads scratch: garbage, its own
-    for got, want, lin in zip(carried.emitted, layered.emitted,
-                              linear.emitted):
-        for g, w, l in zip(got, want, lin):
+    for got, want in zip(carried.emitted, layered.emitted):
+        for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
-            np.testing.assert_array_equal(g[held], l[held])
     lengths = carried.lengths()
     np.testing.assert_array_equal(lengths, layered.lengths())
-    np.testing.assert_array_equal(lengths[held], linear.lengths()[held])
     assert lengths[3] > _Lanes.C                      # lane 3 overran
-    for which, (c_cache, l_cache, lin_cache) in enumerate(zip(
-            carried.caches, layered.caches, linear.caches)):
+    for slot in (0, 1):
+        prompt = carried.prompts[slot]
+        tokens = carried.generated(slot)
+        assert lengths[slot] == len(prompt) + len(tokens) - 1
+        assert prompt + tokens == _ref_cfg(cfg, params, prompt,
+                                           len(tokens))
+    for which, (c_cache, l_cache) in enumerate(zip(
+            carried.caches, layered.caches)):
         c_pools = _row_leaves(c_cache, scanned=True)
         l_pools = _row_leaves(l_cache, scanned=False)
         assert set(c_pools) == set(l_pools) == (
@@ -502,24 +553,36 @@ def test_carried_pools_match_a_pool_a_layer_and_the_linear_cache(
             # the shared blocks: lane 1's insert wrote nothing there
             np.testing.assert_array_equal(pool[..., 1:3, :, :],
                                           shared[which][name])
-        lin_rows = _row_leaves(lin_cache, scanned=False)
-        for slot in held if leg == "gather" else ():
-            n = min(int(lengths[slot]), _Lanes.C)
-            table = jnp.asarray(_Lanes.TABLES[slot], jnp.int32)
-            for lanes, cache, scanned in ((carried, c_cache, True),
-                                          (layered, l_cache, False)):
-                rows = _row_leaves(lanes.eng._gather_prefix(
-                    cache, table, bool(which), jnp.int32(n)), scanned)
-                for name, lin in lin_rows.items():
-                    # [layers, (2,) batch, C, *row]: the lane's rows
-                    batch = lin.ndim - (2 + _LINEAR_ROW_DIMS[name])
-
-                    def lane_rows(leaf, lane):
-                        return np.take(np.take(leaf, lane, axis=batch),
-                                       range(n), axis=batch)
-
+        for lanes, cache, scanned in ((carried, c_cache, True),
+                                      (layered, l_cache, False)):
+            for slot, prompt in lanes.prompts.items():
+                n = min(int(lengths[slot]), _Lanes.C)
+                got = _row_leaves(lanes.eng._gather_prefix(
+                    cache, jnp.asarray(_Lanes.TABLES[slot], jnp.int32),
+                    bool(which), jnp.int32(n)), scanned)
+                wrote = _row_leaves(lanes.prefilled[which][slot], scanned)
+                for name in wrote:
                     np.testing.assert_array_equal(
-                        lane_rows(rows[name], 0), lane_rows(lin, slot))
+                        _rows(got, name, _Lanes.START[slot], len(prompt)),
+                        _rows(wrote, name, _Lanes.START[slot],
+                              len(prompt)))
+                if slot == 3 or leg == "kernel":
+                    continue
+                # Positions [len(prompt), n) hold the lane's generated
+                # tokens but the last, which was never fed back.
+                again, _ = lanes.eng._prefill_tokens(
+                    prompt + lanes.generated(slot)[:-1], seed=0,
+                    cache_1=lanes.eng._fresh_cache(1, draft=bool(which)),
+                    draft=bool(which))
+                want = _dequantised(_row_leaves(again, scanned),
+                                    len(prompt), n)
+                for name, (rows, step) in _dequantised(
+                        got, len(prompt), n).items():
+                    ref, ref_step = want[name]
+                    tol = (1e-5 + 1e-5 * np.abs(ref) + 1.001 * np.maximum(
+                        step, ref_step)[..., None])
+                    worst = (np.abs(rows - ref) / tol).max()
+                    assert worst <= 1, (name, slot, which, worst)
 
 
 # ── slow tier: the full parity matrix ──────────────────────────────────
@@ -533,9 +596,11 @@ pytestmark_slow = pytest.mark.slow
     dict(temperature=0.9, top_k=16),
     dict(temperature=0.7, top_p=0.9),
 ])
-def test_paged_matches_linear_with_refills(params, sampling):
+def test_paged_matches_generate_with_refills(params, sampling):
     """Six mixed requests through two slots (every lane refills):
-    bitwise identity paged vs linear for greedy and seeded sampling."""
+    each request's tokens are ``generate()``'s for its prompt alone,
+    greedy and seeded sampling (a request's stream is keyed by its
+    seed and the tokens it has drawn, as a batch of one's is)."""
     rng = np.random.default_rng(1)
     reqs = [(list(rng.integers(1, 200, n)), m)
             for n, m in [(5, 6), (3, 9), (7, 4), (4, 12), (6, 1),
@@ -544,14 +609,18 @@ def test_paged_matches_linear_with_refills(params, sampling):
     kw = dict(slots=2, cache_len=64, chunk=4, prompt_buckets=(8, 16),
               kv_block_size=4, **sampling)
     out, _ = _serve(params, reqs, seeds=seeds, **kw)
-    lin, _ = _serve(params, reqs, seeds=seeds, paged=False, **kw)
-    assert out == lin
+    for o, (p, m), seed in zip(out, reqs, seeds):
+        assert o == _ref_seeded(CFG, params, p, m, seed, **sampling)
 
 
 @pytest.mark.slow
-def test_paged_matches_linear_speculative(params):
+def test_paged_matches_generate_speculative(params):
     """Speculative serving (self-draft, full acceptance) and a
-    DISAGREEING draft: paged == linear bitwise, greedy and sampled."""
+    DISAGREEING draft, through two slots that refill.  Greedy: the
+    tokens are plain greedy ``generate()``'s.  Sampled: a speculative
+    round's draws are not plain sampling's, so no plain reference has
+    its tokens; each request's are those it gets ALONE on a one-slot
+    engine (its stream is its own wherever it lands)."""
     dcfg = LLAMA_PRESETS["llama_tiny_scan"]
     dparams = LlamaModel(dcfg).init(
         jax.random.PRNGKey(9), jnp.zeros((1, 4), jnp.int32))["params"]
@@ -565,71 +634,71 @@ def test_paged_matches_linear_speculative(params):
                       draft_config=draft_cfg, draft_params=draft_params,
                       speculative_k=3, **sampling)
             out, eng = _serve(params, reqs, seeds=[1, 2, 3], **kw)
-            lin, _ = _serve(params, reqs, seeds=[1, 2, 3], paged=False,
-                            **kw)
-            assert out == lin
+            for o, req, seed in zip(out, reqs, [1, 2, 3]):
+                if sampling:
+                    alone, _ = _serve(params, [req], seeds=[seed],
+                                      **dict(kw, slots=1))
+                    assert o == alone[0]
+                else:
+                    assert o == _ref(params, *req)
             assert eng.spec_stats["rounds"] >= 1
 
 
 @pytest.mark.slow
-def test_paged_matches_linear_mid_stream_cancel(params):
+def test_paged_matches_generate_mid_stream_cancel(params):
     """Cancel mid-decode and mid-staged-prefill: the surviving
-    requests' outputs stay bitwise-identical paged vs linear, and the
-    cancelled lanes' blocks return to the pool."""
+    requests' outputs stay ``generate()``'s, and the cancelled lanes'
+    blocks return to the pool."""
     rng = np.random.default_rng(3)
     long_prompt = list(rng.integers(1, 200, 24))
     short = [list(rng.integers(1, 200, 5)) for _ in range(3)]
 
-    def run(paged):
-        eng = ServingEngine(CFG, params, slots=2, cache_len=64, chunk=3,
-                            prompt_buckets=(8,), prefill_chunk=8,
-                            kv_block_size=4, paged=paged)
-        a = eng.submit(short[0], 10)
-        b = eng.submit(short[1], 10)
-        eng.serve_step()
-        c = eng.submit(long_prompt, 8)     # stages behind the decode
-        d = eng.submit(short[2], 6)
-        eng.serve_step()
-        assert eng.cancel(c)               # mid-staged-prefill
-        eng.serve_step()
-        assert eng.cancel(a)               # mid-decode
-        out = {}
-        while eng.pending():
-            out.update(eng.serve_step())
-        return out.get(b), out.get(d), eng
-
-    b_p, d_p, eng_p = run(True)
-    b_l, d_l, _ = run(False)
-    assert b_p == b_l and d_p == d_l
-    assert all(kv is None for kv in eng_p._lane_kv)
-    eng_p._radix.check_invariants()
+    eng = ServingEngine(CFG, params, slots=2, cache_len=64, chunk=3,
+                        prompt_buckets=(8,), prefill_chunk=8,
+                        kv_block_size=4)
+    a = eng.submit(short[0], 10)
+    b = eng.submit(short[1], 10)
+    eng.serve_step()
+    c = eng.submit(long_prompt, 8)     # stages behind the decode
+    d = eng.submit(short[2], 6)
+    eng.serve_step()
+    assert eng.cancel(c)               # mid-staged-prefill
+    eng.serve_step()
+    assert eng.cancel(a)               # mid-decode
+    out = {}
+    while eng.pending():
+        out.update(eng.serve_step())
+    assert out.get(b) == _ref(params, short[1], 10)
+    assert out.get(d) == _ref(params, short[2], 6)
+    assert all(kv is None for kv in eng._lane_kv)
+    eng._radix.check_invariants()
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("sampling", [dict(),
                                       dict(temperature=0.8, top_k=12)])
-def test_paged_matches_linear_staged_interleave(params, sampling):
+def test_paged_matches_generate_staged_interleave(params, sampling):
     """A long prompt admitted mid-stream under the interleaved prefill
-    scheduler (several budget installments): bitwise identity paged vs
-    linear for the long request AND the active lanes around it."""
+    scheduler (several budget installments): the long request AND the
+    active lanes around it produce ``generate()``'s tokens, greedy and
+    on their own seeded streams."""
     rng = np.random.default_rng(4)
     active = [(list(rng.integers(1, 200, 6)), 14) for _ in range(2)]
     long_req = (list(rng.integers(1, 200, 30)), 6)
 
-    def run(paged):
-        eng = ServingEngine(CFG, params, slots=3, cache_len=64, chunk=3,
-                            prompt_buckets=(8,), prefill_chunk=8,
-                            kv_block_size=4, paged=paged, **sampling)
-        ids = [eng.submit(p, m, seed=7 + i)
-               for i, (p, m) in enumerate(active)]
-        eng.serve_step()
-        ids.append(eng.submit(*long_req, seed=99))
-        out = {}
-        while eng.pending():
-            out.update(eng.serve_step())
-        return [out[i] for i in ids]
-
-    assert run(True) == run(False)
+    eng = ServingEngine(CFG, params, slots=3, cache_len=64, chunk=3,
+                        prompt_buckets=(8,), prefill_chunk=8,
+                        kv_block_size=4, **sampling)
+    ids = [eng.submit(p, m, seed=7 + i)
+           for i, (p, m) in enumerate(active)]
+    eng.serve_step()
+    ids.append(eng.submit(*long_req, seed=99))
+    out = {}
+    while eng.pending():
+        out.update(eng.serve_step())
+    for rid, (p, m), seed in zip(ids, active + [long_req], [7, 8, 99]):
+        assert out[rid] == _ref_seeded(CFG, params, p, m, seed,
+                                       **sampling)
 
 
 @pytest.mark.slow
@@ -677,7 +746,7 @@ def test_copy_on_write_divergence_after_shared_prefix(params):
 def test_eviction_under_pressure_refuses_admission(params):
     """A pool too small for two lanes: the second request is REFUSED
     admission (queued, counted) until the first retires — outputs stay
-    exactly the linear engine's, and no live lane is ever corrupted.
+    exactly ``generate()``'s, and no live lane is ever corrupted.
     Retired prefixes evict LRU to make room."""
     rng = np.random.default_rng(6)
     reqs = [(list(rng.integers(1, 200, 6)), 8) for _ in range(3)]
@@ -686,9 +755,8 @@ def test_eviction_under_pressure_refuses_admission(params):
                         kv_pool_blocks=4)    # one lane's worth
     ids = [eng.submit(p, m) for p, m in reqs]
     out = eng.run()
-    lin, _ = _serve(params, reqs, slots=2, cache_len=32, chunk=3,
-                    prompt_buckets=(8,), paged=False)
-    assert [out[i] for i in ids] == lin
+    assert [out[i] for i in ids] == [_ref(params, p, m)
+                                     for p, m in reqs]
     assert eng.kv_stats["alloc_refusals"] >= 1
     assert eng.kv_stats["evictions"] >= 1
     assert eng.kv_blocks_in_use() <= eng.kv_blocks_total()
@@ -700,25 +768,12 @@ def test_eviction_under_pressure_refuses_admission(params):
 
 
 @pytest.mark.slow
-def test_kill_switch_restores_linear_engine(params, monkeypatch):
-    """TTD_NO_PAGED_KV=1 at construction: the engine IS the linear
-    engine (no pool, no radix, byte-for-byte the old behavior)."""
-    monkeypatch.setenv("TTD_NO_PAGED_KV", "1")
-    eng = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=2,
-                        prompt_buckets=(8,))
-    assert not eng.paged
-    assert eng.kv_blocks_total() == 0 and eng.kv_blocks_in_use() == 0
-    rid = eng.submit([1, 2, 3], 4)
-    assert eng.run()[rid] == _ref(params, [1, 2, 3], 4)
-
-
-@pytest.mark.slow
-def test_linear_prefix_cache_is_lru_bounded(params):
-    """The linear path's ``_prefix_caches`` no longer leaks: preloads
-    past ``prefix_cache_limit`` evict the least recently matched."""
+def test_stored_prefix_pairs_are_lru_bounded(params):
+    """The stored batch-1 pairs (``_prefix_caches``) do not leak:
+    preloads past ``prefix_cache_limit`` evict the least recently
+    matched."""
     eng = ServingEngine(CFG, params, slots=1, cache_len=32, chunk=2,
-                        prompt_buckets=(8,), paged=False,
-                        prefix_cache_limit=2)
+                        prompt_buckets=(8,), prefix_cache_limit=2)
     eng.preload_prefix([1, 1])
     eng.preload_prefix([2, 2])
     eng._match_prefix([1, 1, 9], touch=True)   # refresh [1, 1]
@@ -730,9 +785,9 @@ def test_linear_prefix_cache_is_lru_bounded(params):
 
 
 @pytest.mark.slow
-def test_paged_rejects_nothing_linear_accepts(params):
-    """Engine-level guards carry over: the paged engine screens the
-    same configs the linear one does, plus its own block knobs."""
+def test_block_knobs_and_window_configs_are_screened(params):
+    """Engine-level guards: the pool's block knobs, and the configs
+    whose cache has no per-slot form."""
     with pytest.raises(ValueError, match="kv_block_size"):
         ServingEngine(CFG, params, slots=1, cache_len=16,
                       prompt_buckets=(8,), kv_block_size=0)
@@ -749,12 +804,11 @@ def test_paged_rejects_nothing_linear_accepts(params):
     dict(),
     dict(temperature=0.9, top_k=16),
 ])
-def test_int8_paged_matches_linear_with_refills(params, sampling):
-    """kv_cache_int8 through two slots with every lane refilling:
-    paged == the int8 LINEAR engine bitwise (same quantized rows, same
-    scales, different physical layout) for greedy and seeded
-    sampling — the 'int8-pool parity pinned against the linear-cache
-    kv_cache_int8 path at matched config' acceptance bar."""
+def test_int8_paged_matches_generate_with_refills(params, sampling):
+    """kv_cache_int8 through two slots with every lane refilling: each
+    request's tokens are those of ``generate()``'s shared-index int8
+    cache (same quantized rows, same scales, different physical
+    layout), greedy and on its own seeded stream."""
     rng = np.random.default_rng(11)
     reqs = [(list(rng.integers(1, 200, n)), m)
             for n, m in [(5, 6), (3, 9), (7, 4), (4, 8), (6, 1)]]
@@ -762,14 +816,8 @@ def test_int8_paged_matches_linear_with_refills(params, sampling):
     kw = dict(slots=2, cache_len=64, chunk=4, prompt_buckets=(8, 16),
               kv_block_size=4, **sampling)
     out, _ = _serve_cfg(ICFG, params, reqs, seeds=seeds, **kw)
-    lin, _ = _serve_cfg(ICFG, params, reqs, seeds=seeds, paged=False,
-                        **kw)
-    assert out == lin
-    # And token-identical to the shared-index generate() path (greedy
-    # only: generate's sampling streams are per-batch, not comparable).
-    if not sampling:
-        for o, (p, m) in zip(out, reqs):
-            assert o == _ref_cfg(ICFG, params, p, m)
+    for o, (p, m), seed in zip(out, reqs, seeds):
+        assert o == _ref_seeded(ICFG, params, p, m, seed, **sampling)
 
 
 @pytest.mark.slow
@@ -786,9 +834,8 @@ def test_int8_paged_speculative_and_prefix(params):
               kv_block_size=4, draft_config=ICFG, draft_params=params,
               speculative_k=3)
     out, eng = _serve_cfg(ICFG, params, reqs, seeds=[1, 2, 3], **kw)
-    lin, _ = _serve_cfg(ICFG, params, reqs, seeds=[1, 2, 3],
-                        paged=False, **kw)
-    assert out == lin
+    for o, (p, m) in zip(out, reqs):
+        assert o == _ref_cfg(ICFG, params, p, m)
     assert eng.spec_stats["rounds"] >= 1
     # Prefix sharing: a block-aligned shared prefix hits warm int8 KV
     # and the continuation still equals generate().
@@ -819,7 +866,7 @@ def test_fused_interpret_parity_matrix(params, monkeypatch):
     the interpret-mode fused kernel, and every scenario — greedy,
     seeded sampling, speculative, staged-prefill interleave,
     prefix-hit admission, mid-stream cancel, int8 pool — must produce
-    the SAME TOKENS as the ``TTD_NO_FUSED_ATTN=1`` XLA block-gather
+    the SAME TOKENS as the ``TTD_NO_PALLAS=1`` XLA block-gather
     leg.  Both legs are deterministic functions of the same inputs, so
     token equality here is a stable pin, not a flaky race."""
     rng = np.random.default_rng(13)
@@ -850,10 +897,10 @@ def test_fused_interpret_parity_matrix(params, monkeypatch):
         fused, eng_f = scenario(cfg, **kw)
         assert eng_f.fused_attn() is True
         monkeypatch.delenv("TTD_FUSED_ATTN_INTERPRET")
-        monkeypatch.setenv("TTD_NO_FUSED_ATTN", "1")
+        monkeypatch.setenv("TTD_NO_PALLAS", "1")
         gather, eng_g = scenario(cfg, **kw)
         assert eng_g.fused_attn() is False
-        monkeypatch.delenv("TTD_NO_FUSED_ATTN")
+        monkeypatch.delenv("TTD_NO_PALLAS")
         return fused, gather
 
     for cfg in (CFG, ICFG):

@@ -16,12 +16,6 @@ identical passes of one engine, reporting the tok/s overhead
 percentage — the committed proof the recorder is cheap enough to leave
 on (``profiles/bench/trace_overhead_ab.jsonl``).
 
-``--fused-ab`` runs the fused paged-attention decode push's three
-stacked A/Bs (fused kernel vs the ``TTD_NO_FUSED_ATTN`` XLA
-block-gather leg, int8 KV pool vs fp, and the ``--sweep-slots``
-capacity-growth curve) — committed to
-``profiles/bench/fused_attn_ab.jsonl``.
-
 Every decode record carries ``mbu_pct`` (model-bandwidth utilization,
 the serving analog of training MFU — null off-TPU where no bandwidth
 table exists) beside tok/s, so the metric decode optimization is
@@ -349,336 +343,6 @@ def bench_trace_fleet_ab(preset, slots, chunk, n_requests, prompt_range,
     }
 
 
-def bench_paged_kv_ab(preset, slots, chunk, n_requests, prefix_len,
-                      cache_len, seed, kv_block_size, reps=3):
-    """The --shared-prefix A/B: every request = one shared system
-    prompt + a distinct short tail, served with the paged KV cache's
-    radix prefix sharing ON (the default engine) vs the linear cache
-    (the ``TTD_NO_PAGED_KV`` kill switch path — every request
-    re-prefills the prefix).  Legs run as leg-order-alternating pairs
-    (the --trace-ab noise discipline) on TWO warmed engines; the
-    headline is the shared-prefix TTFT p50 improvement, with the
-    engine's ``prefix_hit_tokens`` committed alongside so the
-    prefill-compute saving is a counter, not an inference.
-
-    A second, NON-SHARED pair (disjoint random prompts, same shapes)
-    pins the paged gather/scatter overhead: its tok/s ratio is the
-    "no regression" guard — block indirection must not tax plain
-    decode."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from tensorflow_train_distributed_tpu.models.llama import (
-        LLAMA_PRESETS, LlamaModel,
-    )
-    from tensorflow_train_distributed_tpu.serving import ServingEngine
-
-    cfg = LLAMA_PRESETS[preset]
-    params = LlamaModel(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    vocab = min(cfg.vocab_size, 30_000)
-    rng = np.random.default_rng(seed)
-    # new=32: the no-regression guard is about steady-state DECODE
-    # tok/s, so decode must dominate the pass — with tiny generations
-    # the fixed per-admission work (claim + insert + reset programs,
-    # identical at any model size) masquerades as a decode tax.
-    tail, new = 8, 32
-    prefix = list(rng.integers(1, vocab, prefix_len))
-    cache_len = cache_len or min(cfg.max_positions,
-                                 prefix_len + tail + new + 8)
-    if prefix_len + tail + new > cache_len:
-        raise ValueError(f"--prefix-len {prefix_len} + tail {tail} + "
-                         f"{new} new exceeds cache_len {cache_len}")
-
-    # EVERY pass serves FRESH prompts (lengths fixed — compiles
-    # reuse): the engines persist across passes, and the radix caches
-    # every retired request, so reusing prompts would let pass 2+ of
-    # the DISJOINT pair prefix-hit its own pass-1 history — crediting
-    # prefix-cache wins to the "pure layout overhead" guard.  Fresh
-    # tails keep the shared pair honest too: its hits measure the
-    # SHARED PREFIX only.
-    def shared_pass():
-        return [(prefix + list(rng.integers(1, vocab, tail)), new)
-                for _ in range(n_requests)]
-
-    def disjoint_pass():
-        return [(list(rng.integers(1, vocab, prefix_len + tail)), new)
-                for _ in range(n_requests)]
-
-    def warm(paged, reqs):
-        e = ServingEngine(cfg, params, slots=slots, chunk=chunk,
-                          cache_len=cache_len, paged=paged,
-                          kv_block_size=kv_block_size)
-        for p, m in reqs:                          # warmup: compiles
-            e.submit(p, m)
-        e.run()
-        return e
-
-    def ab(make_pass):
-        """Leg-order-alternating BACK-TO-BACK pairs; besides best-leg
-        stats, collect each pair's wall ratio (linear/paged) — the
-        trace-ab noise discipline: on a shared 1-core host, single
-        walls swing far more than a few-percent effect, min-wall
-        compares different load regimes, and the MEDIAN of per-pair
-        ratios is the estimator that survives scheduler spikes."""
-        eng = {True: warm(True, make_pass()),
-               False: warm(False, make_pass())}
-        best = {True: None, False: None}
-        hits = {True: 0, False: 0}
-        ratios = []
-        for i in range(max(1, reps)):
-            # Both legs of a pair serve the SAME fresh request list.
-            pass_reqs = make_pass()
-            walls = {}
-            for paged in ((True, False) if i % 2 == 0
-                          else (False, True)):
-                e = eng[paged]
-                h0 = e.kv_prefix_hit_tokens()
-                rec = _run_engine_timed(e, pass_reqs)
-                walls[paged] = rec[0]
-                if best[paged] is None or rec[0] < best[paged][0]:
-                    best[paged] = rec
-                    hits[paged] = e.kv_prefix_hit_tokens() - h0
-            ratios.append(walls[False] / walls[True])
-        ratios.sort()
-        return eng, best, hits, ratios[len(ratios) // 2], ratios
-
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-
-    def leg(best, hits, gen_tokens):
-        wall, ttfts, itls, _ = best
-        out = {
-            "tokens_per_sec": round(gen_tokens / wall, 1),
-            "wall_s": round(wall, 3),
-            "ttft_ms_p50": round(1e3 * _percentile(ttfts, 0.5), 2),
-            "inter_token_ms_mean": round(
-                1e3 * sum(itls) / len(itls), 3) if itls else 0.0,
-            "prefix_hit_tokens": hits,
-        }
-        out.update(decode_mbu_fields(cfg, n_params, slots, cache_len,
-                                     out["tokens_per_sec"]))
-        return out
-
-    gen_tokens = n_requests * new
-    _, s_best, s_hits, s_ratio, s_ratios = ab(shared_pass)
-    _, n_best, n_hits, n_ratio, n_ratios = ab(disjoint_pass)
-    on = leg(s_best[True], s_hits[True], gen_tokens)
-    off = leg(s_best[False], s_hits[False], gen_tokens)
-    pn = leg(n_best[True], n_hits[True], gen_tokens)
-    ln = leg(n_best[False], n_hits[False], gen_tokens)
-    prompt_tokens = n_requests * (prefix_len + tail)
-    dev = jax.devices()[0]
-    rec = {
-        "metric": f"{preset}_serving_paged_kv_shared_prefix_"
-                  f"ttft_improvement",
-        "value": (round(off["ttft_ms_p50"] / on["ttft_ms_p50"], 3)
-                  if on["ttft_ms_p50"] else 0.0),
-        "unit": "x TTFT p50, shared-prefix paged vs linear "
-                "(leg-order-alternating pairs, best-of-reps)",
-        "slots": slots,
-        "chunk": chunk,
-        "n_requests": n_requests,
-        "prefix_len": prefix_len,
-        "tail_len": tail,
-        "max_new": new,
-        "kv_block_size": kv_block_size,
-        "prompt_tokens_per_pass": prompt_tokens,
-        "shared": {"paged": on, "linear": off},
-        "nonshared": {"paged": pn, "linear": ln},
-        "backend": dev.platform,
-        "device_kind": dev.device_kind,
-    }
-    # The "no decode regression" guard: paged vs linear on DISJOINT
-    # prompts (no sharing to win, pure layout overhead), as the MEDIAN
-    # of per-pair wall ratios — > 1.0 means paged is faster.  The
-    # shared-pair median quantifies the headline the same way.
-    rec["shared_wall_ratio_median"] = round(s_ratio, 3)
-    rec["shared_pair_wall_ratios"] = [round(r, 4) for r in s_ratios]
-    rec["nonshared_tokens_per_sec_ratio"] = round(n_ratio, 3)
-    rec["nonshared_pair_wall_ratios"] = [round(r, 4) for r in n_ratios]
-    return rec
-
-
-def bench_fused_attn_ab(preset, slots, chunk, n_requests, prompt_range,
-                        new_range, cache_len, seed, kv_block_size,
-                        sweep_slots, reps=3):
-    """The --fused-ab run: the three stacked decode-speed stages of the
-    fused paged-attention push, each as its own A/B, one committed
-    record (``profiles/bench/fused_attn_ab.jsonl``).
-
-    1. **fused vs gather** — one engine compiled with the fused
-       paged-attention kernel (the default), one under the
-       ``TTD_NO_FUSED_ATTN=1`` kill switch (the XLA block-gather leg);
-       the env choice burns into the compiled programs, so each leg is
-       its own warmed engine and the switch flips around CONSTRUCTION,
-       not the timed passes.  On CPU both legs compile the gather
-       program — the committed ratio ~1.0 IS the no-regression bar
-       (≤2%), and the same harness run on TPU measures the real
-       kernel.
-    2. **int8 pool vs fp** — ``kv_cache_int8`` engine vs the
-       full-precision pool at the same shape (half the cache bytes on
-       the bandwidth-bound path; CPU pays the quantize/dequant compute
-       honestly).
-    3. **capacity growth** — the freed HBM spent: slots grown along
-       ``sweep_slots`` with the pool sized to match
-       (slots × ceil(cache_len / block_size) int8 blocks), tok/s +
-       ``mbu_pct`` + ``kv_pool_bytes`` per point — the raw decode-MBU
-       curve ROADMAP item 2 asks for.
-
-    All timed pairs follow the trace-ab noise discipline:
-    leg-order-alternating back-to-back pairs, median of per-pair wall
-    ratios.
-    """
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from tensorflow_train_distributed_tpu.models.llama import (
-        LLAMA_PRESETS, LlamaModel,
-    )
-    from tensorflow_train_distributed_tpu.serving import ServingEngine
-
-    cfg = LLAMA_PRESETS[preset]
-    icfg = dataclasses.replace(cfg, kv_cache_int8=True)
-    params = LlamaModel(cfg).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-    vocab = min(cfg.vocab_size, 30_000)
-    reqs = _requests(n_requests, *prompt_range, *new_range, vocab, seed)
-    gen_tokens = sum(m for _, m in reqs)
-    rows = cache_len or cfg.max_positions
-    nblk_lane = -(-rows // kv_block_size)
-
-    def build(config, fused_killed, s=slots, pool=None):
-        """Construct + warm an engine under the requested kill-switch
-        state (the fused/gather choice compiles in at first trace;
-        after warmup the jit cache pins it, so the timed passes below
-        need no env management)."""
-        had = os.environ.get("TTD_NO_FUSED_ATTN")
-        if fused_killed:
-            os.environ["TTD_NO_FUSED_ATTN"] = "1"
-        else:
-            os.environ.pop("TTD_NO_FUSED_ATTN", None)
-        try:
-            e = ServingEngine(config, params, slots=s, chunk=chunk,
-                              cache_len=cache_len,
-                              kv_block_size=kv_block_size,
-                              kv_pool_blocks=pool)
-            for p, m in reqs:                      # warmup: compiles
-                e.submit(p, m)
-            e.run()
-        finally:
-            if had is None:
-                os.environ.pop("TTD_NO_FUSED_ATTN", None)
-            else:
-                os.environ["TTD_NO_FUSED_ATTN"] = had
-        return e
-
-    def ab(eng_a, eng_b, kv8_a=False, kv8_b=False):
-        """Leg-order-alternating pairs → (leg_a, leg_b, median of
-        per-pair wall ratios b/a, ratios).  >1 means leg a faster."""
-        best = {"a": None, "b": None}
-        ratios = []
-        for i in range(max(1, reps)):
-            walls = {}
-            for tag in (("a", "b") if i % 2 == 0 else ("b", "a")):
-                e = eng_a if tag == "a" else eng_b
-                r = _run_engine_timed(e, reqs)
-                walls[tag] = r[0]
-                if best[tag] is None or r[0] < best[tag][0]:
-                    best[tag] = r
-            ratios.append(walls["b"] / walls["a"])
-        ratios.sort()
-
-        def leg(b, s, kv8):
-            wall, ttfts, itls, _ = b
-            out = {
-                "tokens_per_sec": round(gen_tokens / wall, 1),
-                "wall_s": round(wall, 3),
-                "ttft_ms_p50": round(1e3 * _percentile(ttfts, 0.5), 2),
-            }
-            out.update(decode_mbu_fields(
-                cfg, n_params, s, rows, out["tokens_per_sec"], kv8))
-            return out
-
-        return (leg(best["a"], slots, kv8_a), leg(best["b"], slots,
-                                                  kv8_b),
-                ratios[len(ratios) // 2],
-                [round(r, 4) for r in ratios])
-
-    # Stage 1: fused vs the TTD_NO_FUSED_ATTN gather leg.
-    eng_fused = build(cfg, fused_killed=False)
-    eng_gather = build(cfg, fused_killed=True)
-    fused_leg, gather_leg, fused_ratio, fused_ratios = ab(
-        eng_fused, eng_gather)
-
-    # Stage 2: int8 pool vs fp (both on the default fused/gather
-    # choice — the fp leg reuses stage 1's engine).
-    eng_int8 = build(icfg, fused_killed=False)
-    int8_leg, fp_leg, int8_ratio, int8_ratios = ab(
-        eng_int8, eng_fused, kv8_a=True)
-    int8_leg["kv_pool_bytes"] = eng_int8.kv_pool_bytes()
-    fp_leg["kv_pool_bytes"] = eng_fused.kv_pool_bytes()
-
-    # Stage 3: spend the freed HBM — slots (and the pool with them)
-    # grown along the sweep, int8 pools, mbu per point.  The stage-1/2
-    # engines are fully consumed: drop them BEFORE the sweep, or their
-    # three pinned pools (+ cast param copies) shrink the very HBM
-    # headroom the largest sweep points exist to probe.
-    fused_engaged = eng_fused.fused_attn()
-    del eng_fused, eng_gather, eng_int8
-    growth = []
-    for s in sweep_slots:
-        e = build(icfg, fused_killed=False, s=s, pool=s * nblk_lane)
-        best = None
-        for _ in range(max(1, reps)):
-            r = _run_engine_timed(e, reqs)
-            if best is None or r[0] < best[0]:
-                best = r
-        tps = round(gen_tokens / best[0], 1)
-        point = {"slots": s, "kv_pool_blocks": s * nblk_lane,
-                 "kv_pool_bytes": e.kv_pool_bytes(),
-                 "tokens_per_sec": tps,
-                 "wall_s": round(best[0], 3)}
-        point.update(decode_mbu_fields(icfg, n_params, s, rows, tps,
-                                       True))
-        growth.append(point)
-
-    dev = jax.devices()[0]
-    return {
-        "metric": f"{preset}_serving_fused_attn_wall_ratio",
-        "value": round(fused_ratio, 3),
-        "unit": "x wall, XLA block-gather leg vs fused paged-attention"
-                " leg (median of per-pair wall ratios; ~1.0 on CPU "
-                "where both legs compile the gather program — the "
-                "no-regression bar; >1 on TPU = fused faster)",
-        "fused_engaged": fused_engaged,
-        "slots": slots,
-        "chunk": chunk,
-        "n_requests": n_requests,
-        "gen_tokens": gen_tokens,
-        "cache_len": rows,
-        "kv_block_size": kv_block_size,
-        "reps": reps,
-        "fused": fused_leg,
-        "gather": gather_leg,
-        "pair_wall_ratios": fused_ratios,
-        "int8_pool": {
-            "unit": "x wall, fp pool vs int8 pool (median of per-pair "
-                    "wall ratios; >1 = int8 faster)",
-            "wall_ratio_median": round(int8_ratio, 3),
-            "pair_wall_ratios": int8_ratios,
-            "int8": int8_leg,
-            "fp": fp_leg,
-        },
-        "pool_growth": growth,
-        "backend": dev.platform,
-        "device_kind": dev.device_kind,
-    }
-
-
 def bench_serving(preset, slots, chunk, n_requests, prompt_range,
                   new_range, cache_len, baseline, seed,
                   draft_preset="", speculative_k=0, kv_int8=False,
@@ -697,7 +361,7 @@ def bench_serving(preset, slots, chunk, n_requests, prompt_range,
 
     cfg = LLAMA_PRESETS[preset]
     if kv_int8:
-        # int8 paged/per-slot KV cache: half the cache bytes per decode
+        # int8 KV cache: half the cache bytes per decode
         # step — params are layout-independent, so the same tree serves.
         cfg = dataclasses.replace(cfg, kv_cache_int8=True)
     params = LlamaModel(cfg).init(
@@ -1038,35 +702,6 @@ def main(argv=None) -> int:
                         "for itself, the acceptance CEILING — the pair "
                         "brackets real trained drafts)")
     p.add_argument("--speculative-k", type=int, default=4)
-    p.add_argument("--shared-prefix", action="store_true",
-                   help="paged-KV prefix-sharing A/B instead of the "
-                        "throughput run: every request shares one "
-                        "long system prompt (--prefix-len) + a "
-                        "distinct tail, paged radix sharing vs the "
-                        "linear cache, leg-order-alternating pairs; "
-                        "plus a disjoint-prompt pair pinning the "
-                        "no-regression guard (committed record: "
-                        "profiles/bench/paged_kv_ab.jsonl)")
-    p.add_argument("--prefix-len", type=int, default=96,
-                   help="--shared-prefix only: shared system prompt "
-                        "length in tokens")
-    p.add_argument("--kv-block-size", type=int, default=16,
-                   help="--shared-prefix / --fused-ab: paged-KV block "
-                        "size")
-    p.add_argument("--fused-ab", action="store_true",
-                   help="fused paged-attention A/B instead of the "
-                        "throughput run: fused kernel vs the "
-                        "TTD_NO_FUSED_ATTN XLA block-gather leg, int8 "
-                        "KV pool vs fp, and the --sweep-slots capacity "
-                        "growth curve — tok/s + mbu_pct per leg "
-                        "(committed record: "
-                        "profiles/bench/fused_attn_ab.jsonl)")
-    p.add_argument("--sweep-slots", default="",
-                   help="--fused-ab only: comma-separated slot counts "
-                        "for the capacity-growth sweep (each point "
-                        "sizes the int8 pool to slots * "
-                        "ceil(cache_len/block_size)); default: "
-                        "slots,2*slots")
     p.add_argument("--kv-int8", action="store_true",
                    help="throughput run with the int8 KV cache "
                         "(kv_cache_int8 config): half the cache bytes "
@@ -1126,12 +761,7 @@ def main(argv=None) -> int:
     prompt_range = tuple(int(x) for x in args.prompt_range.split(","))
     new_range = tuple(int(x) for x in args.new_range.split(","))
     try:
-        if args.shared_prefix:
-            rec = bench_paged_kv_ab(
-                args.preset, args.slots, args.chunk, args.requests,
-                args.prefix_len, args.cache_len or None, args.seed,
-                args.kv_block_size, reps=args.reps)
-        elif args.trace_ab:
+        if args.trace_ab:
             rec = bench_trace_ab(args.preset, args.slots, args.chunk,
                                  args.requests, prompt_range,
                                  new_range, args.cache_len or None,
@@ -1152,15 +782,6 @@ def main(argv=None) -> int:
                 args.requests, prompt_range, new_range,
                 args.cache_len or None, args.seed, depths,
                 reps=args.reps, wide_d_model=args.spec_d_model)
-        elif args.fused_ab:
-            sweep = ([int(s) for s in args.sweep_slots.split(",")]
-                     if args.sweep_slots
-                     else [args.slots, 2 * args.slots])
-            rec = bench_fused_attn_ab(
-                args.preset, args.slots, args.chunk, args.requests,
-                prompt_range, new_range, args.cache_len or None,
-                args.seed, args.kv_block_size, sweep,
-                reps=args.reps)
         else:
             rec = bench_serving(args.preset, args.slots, args.chunk,
                                 args.requests, prompt_range,
@@ -1173,11 +794,7 @@ def main(argv=None) -> int:
                                 kv_int8=args.kv_int8,
                                 reps=args.reps)
     except Exception as e:
-        if args.shared_prefix:
-            metric = (f"{args.preset}_serving_paged_kv_shared_prefix_"
-                      f"ttft_improvement")
-            unit = "x TTFT p50, shared-prefix paged vs linear"
-        elif args.trace_ab:
+        if args.trace_ab:
             metric = f"{args.preset}_serving_trace_overhead_pct"
             unit = "% tok/s lost, flight recorder on vs TTD_NO_TRACE=1"
         elif args.trace_fleet_ab:
@@ -1187,10 +804,6 @@ def main(argv=None) -> int:
         elif args.spec_adaptive_ab:
             metric = f"{args.preset}_serving_spec_adaptive_wall_ratio"
             unit = "x wall, adaptive depth vs best fixed depth"
-        elif args.fused_ab:
-            metric = f"{args.preset}_serving_fused_attn_wall_ratio"
-            unit = ("x wall, XLA block-gather leg vs fused "
-                    "paged-attention leg")
         else:
             name = (f"{args.preset}_serving_engine_spec"
                     if args.speculative_draft

@@ -108,8 +108,8 @@ def add_engine_args(p) -> None:
     p.add_argument("--kv-int8", action="store_true",
                    help="int8 KV cache (llama-family configs): cache "
                         "rows store 1 byte + a per-row f32 scale — "
-                        "halves KV HBM in both the paged pool and the "
-                        "linear cache, the large-batch decode "
+                        "halves KV HBM in the paged pool and the "
+                        "batch-1 prefill cache, the large-batch decode "
                         "bandwidth lever. The freed memory is worth "
                         "spending: grow --kv-pool-blocks (and --slots) "
                         "into it. Composes with --quant and "
@@ -171,8 +171,8 @@ def add_engine_args(p) -> None:
     p.add_argument("--kv-pool-blocks", type=_int_or_auto, default=None,
                    help="paged KV cache: total physical blocks in the "
                         "pool (default: slots * ceil(cache_len / "
-                        "block_size) — the linear cache's exact "
-                        "memory). Admission is keyed on free blocks: "
+                        "block_size): every lane's whole context). "
+                        "Admission is keyed on free blocks: "
                         "shrink to trade memory for queueing, grow to "
                         "serve more/longer shared prefixes warm. "
                         "'auto' solves the pool size AND "
@@ -187,14 +187,6 @@ def add_engine_args(p) -> None:
                         "leaves free (weights, activations, XLA "
                         "scratch live outside the solved pools); only "
                         "meaningful with --kv-pool-blocks auto")
-    p.add_argument("--no-paged-kv", action="store_true",
-                   help="serve on the per-slot LINEAR KV cache instead "
-                        "of the paged block pool (no cross-request "
-                        "prefix sharing beyond --prefix); "
-                        "TTD_NO_PAGED_KV=1 is the no-redeploy "
-                        "equivalent. Outputs are bitwise-identical "
-                        "either way — this is a memory-layout kill "
-                        "switch")
     p.add_argument("--hbm-budget-bytes", type=int, default=None,
                    help="declared HBM budget for the engine's memory "
                         "pools (memcheck): with TTD_MEMCHECK=1, the "
@@ -311,7 +303,6 @@ def build_engine(args, cfg, is_moe, prefix_ids):
                          else None),
             prefill_chunk=getattr(args, "prefill_chunk", None),
             prefill_budget=getattr(args, "prefill_budget", None),
-            paged=not getattr(args, "no_paged_kv", False),
             kv_block_size=getattr(args, "kv_block_size", 16),
             kv_pool_blocks=getattr(args, "kv_pool_blocks", None),
             hbm_budget_bytes=getattr(args, "hbm_budget_bytes", None),

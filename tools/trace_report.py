@@ -224,7 +224,7 @@ def kv_cache_summary(evs: list) -> dict:
     state and no rows, the bytes of state the steps' live lanes held
     beside the bytes of the rows walked (``state_bytes``, ``kv_bytes``,
     summed like the blocks).  Empty dict when the
-    window has no paged-KV events (linear cache)."""
+    window has no paged-KV events."""
     out = {"prefix_hits": 0, "prefix_hit_tokens": 0,
            "evicted_blocks": 0, "refused_admissions": 0,
            "fused_attn_dispatches": 0, "kv_blocks": 0,
