@@ -1438,6 +1438,17 @@ class LatentAttention(nn.Module):
       result back (``o = (P·c_kv) · W_uv``).  The rows are read once
       and serve as key and as value, which is the point of the cache:
       a decode step is bound by the bytes of the rows it reads.
+
+    A layer may see a sliding ``window`` of its rows (a model whose
+    latent layers are of two kinds: ``models.moe.OwnLatentKind``): the
+    forward masks the band, a piece's walk starts at the window's first
+    tile (``prefix_attention(window=)``: the XLA walk; the prefill
+    kernel knows no window), and the paged step keeps the rows in a
+    ring of ``ring_blocks`` blocks a lane under ``window_table``, as
+    ``MultiHeadAttention`` does, read by the same absorbed kernel from
+    the window's first block.  ``lora_rescale`` multiplies both
+    normalised latents by ``sqrt(d_model / rank)`` before anything reads
+    them (the cached row holds the rescaled latent).
     """
 
     num_heads: int
@@ -1466,6 +1477,21 @@ class LatentAttention(nn.Module):
     index_topk: int = 0
     # Per-head output gate, as ``MultiHeadAttention.out_gate``.
     out_gate: bool = False
+    # A sliding window (None: every row): a query at position p sees
+    # ``p - window < row <= p``.  The paged step then keeps its rows in
+    # a RING of ``ring_blocks`` blocks a lane under ``window_table``
+    # (``MultiHeadAttention._paged_decode_step``'s rule); a window
+    # layer chooses no rows.
+    window: Optional[int] = None
+    ring_blocks: int = 0
+    # The normalised latents times ``sqrt(d_model / rank)``.
+    lora_rescale: bool = False
+
+    def _rescaled(self, c, d_model: int):
+        """The normalised latent ``c`` at the hidden size's scale."""
+        if not self.lora_rescale:
+            return c
+        return c * jnp.asarray((d_model / c.shape[-1]) ** 0.5, c.dtype)
 
     @property
     def row_dim(self) -> int:
@@ -1511,9 +1537,11 @@ class LatentAttention(nn.Module):
             c_q = None
             q = self._dense(width, ("embed", "heads"), "query")(x)
         else:
-            c_q = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
-                          name="q_norm")(
-                self._dense(self.q_lora_rank, ("embed", None), "q_a")(x))
+            c_q = self._rescaled(
+                RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
+                        name="q_norm")(
+                    self._dense(self.q_lora_rank, ("embed", None),
+                                "q_a")(x)), x.shape[-1])
             q = self._dense(width, (None, "heads"), "q_b")(c_q)
         q = q.reshape(*x.shape[:-1], self.num_heads, -1)
         q = nn.with_logical_constraint(
@@ -1525,8 +1553,10 @@ class LatentAttention(nn.Module):
     def _rows(self, x, positions):
         """This call's cache rows [B, S, row_store]."""
         kv = self._dense(self.row_dim, ("embed", None), "kv_a")(x)
-        c_kv = RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
-                       name="kv_norm")(kv[..., :self.kv_lora_rank])
+        c_kv = self._rescaled(
+            RMSNorm(epsilon=self.rms_epsilon, dtype=self.dtype,
+                    name="kv_norm")(kv[..., :self.kv_lora_rank]),
+            x.shape[-1])
         k_r = self._rope(kv[..., None, self.kv_lora_rank:],
                          positions)[..., 0, :]
         return _pad_last(jnp.concatenate([c_kv, k_r], axis=-1),
@@ -1625,6 +1655,10 @@ class LatentAttention(nn.Module):
                     "supported in decode mode")
             if self.cache_len <= 0:
                 raise ValueError("decode=True needs cache_len > 0")
+            if self.window is not None and self.index_topk:
+                raise ValueError(
+                    "a window layer chooses no rows: window="
+                    f"{self.window} beside index_topk={self.index_topk}")
             if self.paged_kv_blocks:
                 if not self.slot_decode:
                     raise ValueError(
@@ -1663,6 +1697,7 @@ class LatentAttention(nn.Module):
             o = multihead_attention_kernel(
                 *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
                 causal=mask is None, mask=mask, segment_ids=segment_ids,
+                window=self.window,
                 softmax_scale=self.softmax_scale).transpose(0, 2, 1, 3)
         return self._out(o, x.shape[-1], self._gate(x))
 
@@ -1674,7 +1709,9 @@ class LatentAttention(nn.Module):
         one tile is the ordinary masked attention over all of it).
         Where the rows are bf16 and the call is long enough
         (``latent_walk_ok``) the same walk is one kernel
-        (``pallas_kernels.prefix_flash_latent``).
+        (``pallas_kernels.prefix_flash_latent``); a layer with a
+        ``window`` keeps the XLA walk, which starts at the window's
+        first tile.
         ``index`` is a scalar (``models.generate``) or, under
         ``slot_decode``, one per batch row (the engine's batch-1
         prefill cache)."""
@@ -1717,7 +1754,8 @@ class LatentAttention(nn.Module):
             keep = self._chosen_rows(q_i, w_i, keys.value, cur)
         kv_b = self._kv_b()
         with _scope_when(keep is not None, "attn/sparse"):
-            if latent_walk_ok(q_len, cache.value, **latent_walk_sizes(self)):
+            if self.window is None and latent_walk_ok(
+                    q_len, cache.value, **latent_walk_sizes(self)):
                 # The same walk as one kernel: each of its query
                 # blocks walks its own tiles, so ``query_block`` has
                 # nothing to add.
@@ -1732,7 +1770,8 @@ class LatentAttention(nn.Module):
                     q.transpose(0, 2, 1, 3), cache.value, cur,
                     lambda rows: [t.transpose(0, 2, 1, 3)
                                   for t in self._up_project(rows, kv_b)],
-                    keep=keep, softmax_scale=self.softmax_scale,
+                    keep=keep, window=self.window,
+                    softmax_scale=self.softmax_scale,
                     block=self.query_block)
         return self._out(o.transpose(0, 2, 1, 3), x.shape[-1],
                          self._gate(x))
@@ -1742,7 +1781,10 @@ class LatentAttention(nn.Module):
         append-and-attend contract of
         ``MultiHeadAttention._paged_decode_step`` (block table, scratch
         block 0, dropped overrun rows, an empty lane told length 0)
-        with ONE pool of rows a layer."""
+        with ONE pool of rows a layer.  With a ``window`` the pool is
+        the lanes' RINGS (entry ``(p // block) % ring_blocks`` of
+        ``window_table``) and the kernel walks from the window's first
+        block (``paged_latent_attention(window=)``)."""
         from tensorflow_train_distributed_tpu.ops import pallas_kernels \
             as pk
 
@@ -1750,10 +1792,22 @@ class LatentAttention(nn.Module):
         bs, nb = self.kv_block_size, self.paged_kv_blocks
         n_blk = -(-self.cache_len // bs)
         rank = self.kv_lora_rank
+        ring = self.window is not None
+        if ring:
+            # A window layer's own pool: a ring of blocks a lane and the
+            # scratch block, its table under a name of its own
+            # (``MultiHeadAttention._paged_decode_step``).
+            if self.ring_blocks * bs < self.window + q_len - 1:
+                raise ValueError(
+                    f"a ring of {self.ring_blocks} blocks of {bs} rows "
+                    f"cannot hold a window of {self.window} and "
+                    f"{q_len} new rows")
+            nb, n_blk = 1 + b * self.ring_blocks, self.ring_blocks
         pool = self.variable("cache", "latent_pool", jnp.zeros,
                              (nb, bs, self.row_store), self.dtype)
         table = self.variable(
-            "cache", "block_table", jnp.zeros, (b, n_blk), jnp.int32)
+            "cache", "window_table" if ring else "block_table", jnp.zeros,
+            (b, n_blk), jnp.int32)
         index = self.variable(
             "cache", "index", lambda: jnp.zeros((b,), jnp.int32))
         cur = index.value
@@ -1761,8 +1815,10 @@ class LatentAttention(nn.Module):
         index.value = cur + q_len
         q_nope, q_rope, c_q = self._queries(x, positions)
         rows = self._rows(x, positions)
-        with jax.named_scope("kv_pool/write"):
-            dest = _paged_dest(table.value, positions, bs, nb)
+        with jax.named_scope("kv_pool/write/window" if ring
+                             else "kv_pool/write"):
+            dest = _paged_dest(table.value, positions, bs, nb,
+                               ring_of=self.cache_len if ring else None)
             pool.value = _set_pool_rows(pool.value, (), *dest, rows)
 
         w = self._kv_b()
@@ -1784,7 +1840,7 @@ class LatentAttention(nn.Module):
         else:
             o_lat = pk.paged_latent_attention(
                 q_cat, pool.value, table.value, held,
-                cache_len=self.cache_len, **kernel)
+                cache_len=self.cache_len, window=self.window, **kernel)
         with jax.named_scope("attn/absorb"):
             o = jnp.einsum("bqhc,chd->bqhd", o_lat.astype(self.dtype),
                            w[..., self.qk_nope_dim:])

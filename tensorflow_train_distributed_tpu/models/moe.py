@@ -82,10 +82,48 @@ class KvKind(AttnKind):
 @dataclasses.dataclass(frozen=True)
 class LatentKind(AttnKind):
     """A latent-attention layer of a period (``layers.LatentAttention``
-    at the model's latent sizes: ``kv_lora_rank`` and the rest);
-    ``window`` and ``rotary_share`` say nothing."""
+    at the model's latent sizes: ``kv_lora_rank`` and the rest).
+    ``window`` and ``rope_base`` mean what they mean on a softmax kind
+    (a window layer's latent rows live in a ring); ``rotary_share``
+    says nothing: a head's rotary part is ``qk_rope_dim`` wide."""
 
     kind: ClassVar[str] = "latent"
+
+
+@dataclasses.dataclass(frozen=True)
+class OwnLatentKind(LatentKind):
+    """A latent layer whose latent sizes are its own (None: the
+    model's), beside latent layers of another kind in one model, and
+    which says whether it chooses its rows (``index_topk``; 0: it
+    attends every row it sees, and has no indexer and no index keys).
+    A subclass, so that ``LatentKind`` keeps ``AttnKind``'s five
+    fields; ``MoeConfig.latent_sizes`` resolves a layer's."""
+
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_dim: Optional[int] = None
+    qk_rope_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    index_topk: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """What ONE latent layer runs at (``MoeConfig.latent_sizes``): its
+    kind's own sizes where it has them, else the model's."""
+
+    num_heads: int
+    q_lora_rank: Optional[int]
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+    window: Optional[int]
+    rope_base: float
+    rope_scaling: Optional[tuple]
+    index_heads: int
+    index_dim: int
+    index_topk: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,8 +253,10 @@ class MoeConfig:
     # and ``rope_scaling`` above then say nothing.  The layers of such
     # a model share no parameter shape (they are unrolled here anyway).
     # None: every layer alike, from the fields above.  With
-    # ``kv_lora_rank`` set the kinds are "latent" and "linear" alone
-    # (the latent sizes above are the latent layers').
+    # ``kv_lora_rank`` set the kinds are "latent" and "linear" alone:
+    # the latent sizes above are those of every latent layer whose kind
+    # has none of its own (``OwnLatentKind``; ``latent_sizes`` is where
+    # a layer's are resolved).
     attn_period: Optional[tuple] = None
     # The kinds of the layers BEFORE the period starts, one a layer (a
     # pattern that is no period from layer 0: full at 0, 5, 11, ... is
@@ -229,6 +269,11 @@ class MoeConfig:
     # decay (``layers.DeltaAttention``).
     linear_conv: int = 4
     linear_decay_floor: float = -5.0
+    # Latent attention's two normalised latents are multiplied by
+    # ``sqrt(d_model / rank)`` (``apply_mla_qkv_lora_rescale``): a
+    # latent narrower than the hidden size is brought back to the
+    # hidden size's scale (``layers.LatentAttention.lora_rescale``).
+    lora_rescale: bool = False
 
     def attn_kind(self, layer: int) -> Optional[AttnKind]:
         """Layer ``layer``'s kind, or None where layers do not differ."""
@@ -238,6 +283,28 @@ class MoeConfig:
         if layer < lead:
             return self.attn_lead[layer]
         return self.attn_period[(layer - lead) % len(self.attn_period)]
+
+    def latent_sizes(self, layer: int) -> LatentSizes:
+        """What latent layer ``layer`` runs at: THE place where a
+        kind's own sizes (``OwnLatentKind``) stand before the model's;
+        the block, the engine and the benchmark's harness read it."""
+        kind = self.attn_kind(layer)
+
+        def own(name):
+            had = getattr(kind, name, None)
+            return getattr(self, name) if had is None else had
+
+        return LatentSizes(
+            num_heads=kind.num_heads if kind else self.num_heads,
+            q_lora_rank=own("q_lora_rank"),
+            kv_lora_rank=own("kv_lora_rank"),
+            qk_nope_dim=own("qk_nope_dim"), qk_rope_dim=own("qk_rope_dim"),
+            v_head_dim=own("v_head_dim"),
+            window=kind.window if kind else None,
+            rope_base=kind.rope_base if kind else self.rope_base,
+            rope_scaling=kind.rope_scaling if kind else self.rope_scaling,
+            index_heads=self.index_heads, index_dim=self.index_dim,
+            index_topk=own("index_topk"))
 
     @property
     def attn_kinds(self) -> tuple:
@@ -277,6 +344,24 @@ _LAGUNA_KINDS = (
 #: is latent where (i + 1) % 6 == 0).
 _LING_KINDS = (LinearKind(num_heads=32),) * 5 + (
     LatentKind(num_heads=32, rope_base=6_000_000.0),)
+
+
+#: dots3-note-prev's two kinds of LATENT layer: a full one at the
+#: model's latent sizes, over the rows its indexer picks, and a window
+#: one (513 keys, the token's own among them) with ranks, heads and a
+#: rotary base of its own and no indexer.
+_DOTS3_FULL = LatentKind(num_heads=128, rope_base=80_000_000.0)
+_DOTS3_WINDOW = OwnLatentKind(
+    num_heads=64, window=513, rope_base=50_000.0, q_lora_rank=1024,
+    kv_lora_rank=1024, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=128,
+    index_topk=0)
+#: The same two at test size: a window of 9, and a window row of two
+#: lane tiles (136 + 8 values) beside a full row of one (32 + 8).
+_DOTS3_TINY_FULL = LatentKind(num_heads=4, rope_base=80_000_000.0)
+_DOTS3_TINY_WINDOW = OwnLatentKind(
+    num_heads=2, window=9, rope_base=50_000.0, q_lora_rank=20,
+    kv_lora_rank=136, qk_nope_dim=20, qk_rope_dim=8, v_head_dim=16,
+    index_topk=0)
 
 
 #: MiMo-V2.5's two kinds of layer: the first int(0.334 x 192) = 64
@@ -460,6 +545,42 @@ MOE_PRESETS = {
         attn_lead=(_MIMO_TINY_FULL,),
         attn_period=(_MIMO_TINY_WINDOW,) * 4 + (_MIMO_TINY_FULL,
                                                 _MIMO_TINY_WINDOW)),
+    # dots3-note-prev's language model (dots-studio, ``dots3_note``) at
+    # its published widths: latent attention of TWO kinds, full layers
+    # (128 heads, ranks 1024 / 512, keys of 128 + 64) over the 2048
+    # rows a learned indexer picks and window layers (513; 64 heads,
+    # ranks 1024 / 1024, keys of 192 + 64) with no indexer, full at
+    # layers 0, 1, 5, 9, ..., 45 (a lead of one and a period of four);
+    # both latents rescaled, a per-head output gate on both kinds; one
+    # leading SwiGLU layer, then 256 sigmoid-routed experts (top 8,
+    # gates unscaled) beside one shared.  Deployments give
+    # ``experts_held`` and cut depth and vocabulary (benchmark/configs).
+    "dots3_note": MoeConfig(
+        vocab_size=152_064, d_model=5120, num_layers=46, num_heads=128,
+        num_kv_heads=None, ffn_size=1536, num_experts=256, top_k=8,
+        max_positions=524_288, rope_base=80_000_000.0, rms_epsilon=1e-5,
+        dispatch="gmm", shared_expert_size=1536, norm_topk_prob=True,
+        dense_layers=1, dense_ffn_size=13_824, router="sigmoid",
+        routed_scaling=1.0, q_lora_rank=1024, kv_lora_rank=512,
+        qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        index_heads=64, index_dim=128, index_topk=2048,
+        attn_lead=(_DOTS3_FULL,),
+        attn_period=(_DOTS3_FULL,) + (_DOTS3_WINDOW,) * 3,
+        attn_gate=True, lora_rescale=True),
+    # The same block at test size (float32): a full layer chooses 16
+    # rows, a window layer sees 9; one dense layer, then full, window
+    # x 3.
+    "dots3_note_tiny": MoeConfig(
+        vocab_size=256, d_model=64, num_layers=5, num_heads=4,
+        num_kv_heads=None, ffn_size=48, num_experts=8, top_k=2,
+        max_positions=128, rope_base=80_000_000.0, dtype=jnp.float32,
+        remat=False, dispatch="gmm", shared_expert_size=48,
+        dense_layers=1, dense_ffn_size=160, router="sigmoid",
+        rms_epsilon=1e-5, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_dim=12, qk_rope_dim=8, v_head_dim=16, index_heads=4,
+        index_dim=16, index_topk=16, attn_lead=(_DOTS3_TINY_FULL,),
+        attn_period=(_DOTS3_TINY_FULL,) + (_DOTS3_TINY_WINDOW,) * 3,
+        attn_gate=True, lora_rescale=True),
     # DeepSeek/Qwen-MoE-style: always-on shared expert beside the
     # routed ones (tiny test shape).
     "moe_tiny_shared": MoeConfig(vocab_size=256, d_model=64,
@@ -1087,27 +1208,28 @@ class MoeDecoderBlock(nn.Module):
                 )(h, segment_ids=segment_ids, positions=positions)
         elif cfg.kv_lora_rank:
             # Every layer alike from the model's fields, or the latent
-            # layers of a period, whose scope names them for the trace.
-            with L._scope_when(kind is not None, "attn/latent"):
+            # layers of a period, whose scope names them for the trace:
+            # ``attn/latent`` the kind without a window,
+            # ``attn/latent_window`` the kind with one.
+            own = cfg.latent_sizes(self.layer)
+            with L._scope_when(
+                    kind is not None,
+                    "attn/latent" if own.window is None
+                    else "attn/latent_window"):
                 attn = L.LatentAttention(
-                    num_heads=kind.num_heads if kind else cfg.num_heads,
-                    q_lora_rank=cfg.q_lora_rank,
-                    kv_lora_rank=cfg.kv_lora_rank,
-                    qk_nope_dim=cfg.qk_nope_dim,
-                    qk_rope_dim=cfg.qk_rope_dim,
-                    v_head_dim=cfg.v_head_dim, dtype=cfg.dtype,
-                    rope_base=kind.rope_base if kind else cfg.rope_base,
-                    rope_scaling=(kind.rope_scaling if kind
-                                  else cfg.rope_scaling),
-                    rms_epsilon=cfg.rms_epsilon,
-                    index_heads=cfg.index_heads, index_dim=cfg.index_dim,
-                    index_topk=cfg.index_topk, out_gate=cfg.attn_gate,
+                    **{f.name: getattr(own, f.name)
+                       for f in dataclasses.fields(own)},
+                    dtype=cfg.dtype, rms_epsilon=cfg.rms_epsilon,
+                    out_gate=cfg.attn_gate,
+                    lora_rescale=cfg.lora_rescale,
                     name="attention", decode=self.decode,
                     cache_len=self.cache_len or cfg.max_positions,
                     slot_decode=self.slot_decode,
                     paged_kv_blocks=self.paged_kv_blocks,
                     kv_block_size=self.kv_block_size,
                     query_block=self.query_block,
+                    ring_blocks=(self.ring_blocks
+                                 if own.window is not None else 0),
                 )(h, segment_ids=segment_ids, positions=positions)
         else:
             attn = self._mha(h, segment_ids, positions)

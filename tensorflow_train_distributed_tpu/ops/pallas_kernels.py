@@ -1245,20 +1245,28 @@ def prefix_flash_latent(q_nope, q_rope, cache, kv_b, start, *, keep=None,
 
 def paged_latent_attention_reference(q, pool, table, lengths, *,
                                      value_dim: int, scale: float,
-                                     cache_len: Optional[int] = None):
+                                     cache_len: Optional[int] = None,
+                                     window: Optional[int] = None):
     """Pure-jax oracle (and the CPU path) of ``paged_latent_attention``:
     gather each lane's rows, attend.  ``q`` [lanes, q_len, heads, row]
     (absorbed queries, RoPE applied); ``pool`` [num_blocks, block_size,
     row], every head's key; its leading ``value_dim`` columns are every
-    head's value.  Returns [lanes, q_len, heads, value_dim]."""
+    head's value.  ``window``: the table is a RING, gathered whole
+    under ``ring_mask`` (``paged_attention_reference``'s rule).
+    Returns [lanes, q_len, heads, value_dim]."""
     nb, bs, row = pool.shape
     lanes, q_len = q.shape[:2]
     c = cache_len if cache_len is not None else table.shape[1] * bs
+    if window is not None:
+        c = table.shape[1] * bs             # the ring, whole
     rows = jnp.take(pool, table, axis=0).reshape(lanes, -1, row)[:, :c]
     logits = jnp.einsum("bqhr,bkr->bhqk", q, rows,
                         preferred_element_type=jnp.float32) * scale
     positions = lengths[:, None] + jnp.arange(q_len)        # [B, q]
-    mask = jnp.arange(c)[None, None, :] <= positions[:, :, None]
+    if window is None:
+        mask = jnp.arange(c)[None, None, :] <= positions[:, :, None]
+    else:
+        mask = ring_mask(lengths, q_len, c, window)
     logits = jnp.where(mask[:, None], logits, _NEG)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkc->bqhc", p, rows[..., :value_dim])
@@ -1266,7 +1274,8 @@ def paged_latent_attention_reference(q, pool, table, lengths, *,
 
 def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
                          sem, m_ref, l_ref, acc_ref, *, bs, fold,
-                         last_row, q_len, value_dim, scale):
+                         last_row, q_len, value_dim, scale, window=None,
+                         ring=0):
     """``_paged_attn_kernel``'s walk over ONE pool: grid (lane,), the
     lane's own blocks (``paged_blocks_walked``) ``fold`` to a
     double-buffered copy-and-fold step.  A row is the key of every head
@@ -1274,21 +1283,30 @@ def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
     so it is copied once and the query rows [heads*q_len, row] meet it
     in two products.  The products take the pool's own type (bf16 on
     the chip) and accumulate in float32; max, sum and softmax are
-    float32."""
+    float32.  A sliding ``window`` (static) is ``_paged_attn_kernel``'s:
+    the walk starts at ``paged_first_block``, a block lies in the
+    lane's ``ring`` table entries at its number modulo ``ring``, and
+    the rows behind each query's window are dropped."""
     from jax.experimental.pallas import tpu as pltpu
 
     i = pl.program_id(0)
     cur = len_ref[i]
-    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1)
+    live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1, window)
     steps = pl.cdiv(live, fold)
+    first = paged_first_block(cur, bs, window)     # 0 without a window
 
-    def copies(step, slot, wait=False):
+    def entry(at):
         # Past the lane's count a step repeats the lane's last live
         # block: finite rows the mask drops; no block the lane does not
-        # hold is read.  A wait needs only shapes.
+        # hold is read.
+        at = jnp.minimum(at, live - 1)
+        return at if window is None else jax.lax.rem(first + at, ring)
+
+    def copies(step, slot, wait=False):
+        # A wait needs only shapes.
         return [pltpu.make_async_copy(
             pool_hbm.at[0 if wait else tbl_ref[
-                i, jnp.minimum(step * fold + p, live - 1)]],
+                i, entry(step * fold + p)]],
             buf.at[slot, p], sem.at[slot]) for p in range(fold)]
 
     m_ref[:] = jnp.full_like(m_ref, _NEG)
@@ -1316,12 +1334,21 @@ def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
         logits = jax.lax.dot_general(
             q, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale          # [r, n]
-        logits = jnp.where(step * n + col <= last_seen, logits, _NEG)
+        if window is None:
+            seen = step * n + col <= last_seen
+        else:
+            pos = first * bs + step * n + col
+            seen = (pos <= last_seen) & (cur + qi - pos < window)
+        logits = jnp.where(seen, logits, _NEG)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev,
                             jnp.max(logits, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(logits - m_new)
+        if window is not None:
+            # A step may hold no row some query sees (its window
+            # starts further on): such entries add nothing.
+            p = jnp.where(seen, p, 0.0)
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p.astype(rows.dtype), rows[:, :value_dim],
@@ -1334,17 +1361,25 @@ def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
 
 def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
                            scale: float, cache_len: Optional[int] = None,
+                           window: Optional[int] = None,
                            use_pallas: Optional[bool] = None,
                            interpret: bool = False):
     """Absorbed latent-attention decode directly through the block
     table.  Arguments as ``paged_latent_attention_reference``.  One grid
     step a lane, which reads the blocks its length reaches
     (``paged_blocks_walked``) and no others, each row once: HBM reads
-    are ``blocks x block_size x row`` values a call."""
+    are ``blocks x block_size x row`` values a call.  ``window``: the
+    same kernel over a window layer's RING, as ``paged_attention``'s:
+    the walk starts at the window's first block, so a lane reads the
+    blocks its window spans whatever its context, and ``cache_len`` is
+    the context's, not the ring's."""
+    if window is not None and cache_len is None:
+        raise ValueError("a ring says nothing of the context's length: "
+                         "window needs cache_len")
     if not _use_pallas(use_pallas) and not interpret:
         return paged_latent_attention_reference(
             q, pool, table, lengths, value_dim=value_dim, scale=scale,
-            cache_len=cache_len)
+            cache_len=cache_len, window=window)
     from jax.experimental.pallas import tpu as pltpu
 
     nb, bs, row = pool.shape
@@ -1352,6 +1387,10 @@ def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
     n_blk = table.shape[1]
     fold = _paged_fold(bs, n_blk)
     last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
+    walk = {}
+    if window is not None:
+        last_row = cache_len - 1
+        walk = dict(window=window, ring=n_blk)
     r = heads * q_len
     qt = q.transpose(0, 2, 1, 3).reshape(lanes, r, row).astype(pool.dtype)
 
@@ -1361,8 +1400,12 @@ def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
     out = pl.pallas_call(
         functools.partial(
             _paged_latent_kernel, bs=bs, fold=fold, last_row=last_row,
-            q_len=q_len, value_dim=value_dim, scale=scale),
-        name="paged_latent_attention",
+            q_len=q_len, value_dim=value_dim, scale=scale, **walk),
+        # A name of its own over a ring, so that the device trace tells
+        # the window layers' kernel from the full layers' (whose calls
+        # over the chosen rows keep the name the accepted readers find).
+        name=("paged_latent_attention" if window is None
+              else "paged_latent_window"),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(lanes,),

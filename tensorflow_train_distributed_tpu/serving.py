@@ -95,8 +95,14 @@ blocks a lane (``ceil(window / kv_block_size) + 1``: a decode step adds
 one row, and a window that starts mid-block reaches one block more;
 position ``p`` in ring entry ``(p // block_size) % ring_blocks``): its memory and a decode step's reads are bounded by the
 window whatever the context (``paged_blocks_walked`` with a first
-block).  Slot ``s`` owns ring ``s``: admission keys on the full layers'
-free blocks and on a free slot, and a retired lane's ring table points
+block).  A LATENT layer with a window (``OwnLatentKind``) keeps its
+latent rows in such a ring too, one ``latent_pool`` of ``1 + slots x
+ring_blocks`` blocks at ITS kind's row width under a ``window_table``,
+beside the full latent layers' pool (at theirs) and its index keys:
+every pool, ring, batch-1 cache leaf and ring copy is sized by the
+layer's own row, read off the cache tree (``_ringed_modules``,
+``_paired_leaves``, ``kv_pool_parts``).  Slot ``s`` owns ring
+``s``: admission keys on the full layers' free blocks and on a free slot, and a retired lane's ring table points
 at the scratch block like its block table.  The batch-1 prefill cache
 keeps every row in both kinds of layer (a piece of a window layer walks
 the tiles its window reaches: ``prefix_first_tile``) and the insert
@@ -882,25 +888,34 @@ class ServingEngine:
         # eval_shape (host-only trace, no device work) so the /metrics
         # scrape thread reads a plain int.  The --kv-pool-blocks
         # oversizing lever is sized against this number.
-        def _pool_bytes(struct, ringed: bool):
-            """Bytes of the row-holding leaves of one kind: a window
-            layer's rings, or the blocks tables map."""
+        def _pool_parts(struct) -> dict:
+            """Bytes of the row-holding leaves by what holds them, each
+            layer's at its own row: ``<leaf>_bytes`` the blocks tables
+            map (``latent_pool_bytes``, ``index_pool_bytes``,
+            ``key_pool_bytes``, ...) and ``<leaf's rows>_ring_bytes`` a
+            window layer's rings (``latent_ring_bytes``, ...)."""
             rings = self._ringed_modules(struct)
-            return sum(
-                int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
-                for p, leaf in
-                jax.tree_util.tree_flatten_with_path(struct)[0]
-                if getattr(p[-1], "key", "") in _ROW_LEAVES
-                and (self._path_key(p)[:-1] in rings) == ringed)
+            parts: dict = {}
+            for p, leaf in jax.tree_util.tree_flatten_with_path(struct)[0]:
+                name = getattr(p[-1], "key", "")
+                if name not in _ROW_LEAVES:
+                    continue
+                if self._path_key(p)[:-1] in rings:
+                    name = name.replace("_pool", "_ring")
+                parts[name + "_bytes"] = parts.get(name + "_bytes", 0) + (
+                    int(np.prod(leaf.shape))
+                    * jnp.dtype(leaf.dtype).itemsize)
+            return parts
 
-        grid = self._cache_struct(self.slots, grid=True)
-        self._kv_ring_bytes = _pool_bytes(grid, True)
-        self._kv_pool_bytes = (_pool_bytes(grid, False)
-                               + self._kv_ring_bytes)
+        self._kv_pool_parts = _pool_parts(
+            self._cache_struct(self.slots, grid=True))
+        self._kv_ring_bytes = sum(
+            n for name, n in self._kv_pool_parts.items() if "_ring" in name)
+        self._kv_pool_bytes = sum(self._kv_pool_parts.values())
         if self._draft_model is not None:
-            self._kv_pool_bytes += _pool_bytes(
-                self._cache_struct(self.slots, draft=True, grid=True),
-                False)
+            # A draft has no window layers (refused above): no rings.
+            self._kv_pool_bytes += sum(_pool_parts(self._cache_struct(
+                self.slots, draft=True, grid=True)).values())
         # Per-block row bytes across the layers whose blocks the
         # allocator hands out (draft + int8 scale pools included; a
         # window layer's rings are the slots', not the allocator's):
@@ -2390,6 +2405,14 @@ class ServingEngine:
         plain int; the ``--kv-pool-blocks`` oversizing lever budgets
         against this."""
         return self._kv_pool_bytes
+
+    def kv_pool_parts(self) -> dict:
+        """``kv_pool_bytes()``'s parts for the target model, by kind of
+        row and by what holds it: ``latent_pool_bytes`` and
+        ``index_pool_bytes`` (the blocks tables map),
+        ``latent_ring_bytes`` (a window layer's rings), ``key_`` /
+        ``value_`` likewise; each layer at its own row's width."""
+        return dict(self._kv_pool_parts)
 
     def state_pool_bytes(self) -> int:
         """Device bytes of recurrent state the slot grid pins: every

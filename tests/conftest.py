@@ -208,6 +208,9 @@ import benchmark.harness.serve_hybrid  # noqa: E402,F401
 # layers differ in their KV heads and carry a sink (``serve_sink.py``
 # registers "moe_sink").
 import benchmark.harness.serve_sink  # noqa: E402,F401
+# The same stop-gap, for the family of a decoder whose latent attention
+# layers are of two kinds (``serve_latents.py`` registers "moe_latents").
+import benchmark.harness.serve_latents  # noqa: E402,F401
 
 
 # ``tests/benchmark/test_benchmark_deepseek_v32.py::
@@ -350,8 +353,33 @@ _LAST_PER_LAYER = (
     "test_the_manifest_before_this_reader_is_what_the_pins_ran_on")
 
 
+# Two tests of ``tests/benchmark/test_benchmark_prefix_flash_latent.py``
+# assert that ``prefix_flash_roofline.longctx`` is the LAST of
+# ``per_layer``.  The next PR that adds a cell appends its per-layer
+# metrics after it (``dots3-note-1chip.transcript-notes`` and its
+# ``.notes`` readers) and may not edit that file.
+# ``tests/benchmark/test_benchmark_dots3.py::test_the_tests_that_pin_
+# the_manifest_run_whole_as_it_was`` runs both functions whole (and
+# through the second the tests it runs in turn) on the manifest as it
+# was before the later cell, which it finds BY NAME.  The same stop-gap
+# as those above, strict for the same reason: the `benchmark` PR finds
+# the entry by name and deletes this.
+_LAST_PER_LAYER_LATENT = (
+    "test_benchmark_prefix_flash_latent.py::"
+    "test_the_reader_is_found_by_name_for_its_cell_alone",
+    "test_benchmark_prefix_flash_latent.py::"
+    "test_the_manifest_before_this_reader_is_what_the_pins_ran_on")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(_LAST_PER_LAYER_LATENT):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts its reader is the last of per_layer; "
+                       "run whole on the manifest as it was by "
+                       "test_benchmark_dots3.py::test_the_tests_that_"
+                       "pin_the_manifest_run_whole_as_it_was",
+                strict=True))
         if item.nodeid.endswith(_LAST_PER_LAYER):
             item.add_marker(pytest.mark.xfail(
                 reason="asserts its reader is the last of per_layer; "

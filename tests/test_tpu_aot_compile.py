@@ -176,6 +176,21 @@ def _paged_latent(q_len):
          ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
 
 
+def _paged_latent_ring():
+    """dots3-note-prev's window layers' absorbed decode kernel at their
+    published sizes: 64 heads over rows of 1024 + 64 values stored
+    1,152 wide (nine lane tiles), a value of 1024, a window of 513 in a
+    ring of 34 blocks of 16 a lane, a context of 16,384."""
+    lanes, cache_len, bs, heads, row, rank = 32, 16384, 16, 64, 1152, 1024
+    ring = 34
+    return (lambda q, p, t, n: pk.paged_latent_attention(
+        q, p, t, n, value_dim=rank, scale=256 ** -0.5,
+        cache_len=cache_len, window=513, use_pallas=True),
+        (((lanes, 1, heads, row), BF16),
+         ((1 + lanes * ring, bs, row), BF16),
+         ((lanes, ring), jnp.int32), ((lanes,), jnp.int32)))
+
+
 def _paged_index(q_len):
     """DeepSeek-V3.2's index scores at their published sizes through a
     lane's table: 64 heads of 128 against one key of 128 a row, blocks
@@ -221,6 +236,7 @@ CASES = {
 CASES["delta_state_step-l64h32d128"] = _delta_step
 CASES["paged_attn-h64kv4k192v128"] = lambda: _paged_sink(None)
 CASES["paged_ring-h64kv8k192v128-w128-sink"] = lambda: _paged_sink(128)
+CASES["paged_latent_ring-h64r1088-w513"] = _paged_latent_ring
 for _q in (1, 3):
     for _h in (48, 72):
         CASES[f"paged_ring-h{_h}kv8d128-w512-q{_q}"] = (
