@@ -76,12 +76,22 @@ not by Python frame: ``prefill/stage`` (one request's staging: block
 claim, prefix match, padding; ``kv/alloc`` nests inside),
 ``prefill/piece`` with its children ``prefill/cache`` (the batch-1
 cache a request's pieces append to: ``fresh``, ``copy`` or ``gather``),
-``prefill/dispatch`` (a piece's puts and its enqueue), ``prefill/wait``
-and ``prefill/insert`` (the inserts and the lane's claim), and
+``prefill/dispatch`` (a piece's puts and its enqueue), and
+``prefill/insert`` (the inserts and the lane's claim), and
 ``decode/dispatch`` with its child ``decode/stage`` (the host prelude
 of a chunk: slot loop, counts, stale lanes' reset, the carry, the
 seeds' put; the program call lies directly under ``decode/dispatch``),
-``decode/wait``, ``decode/harvest``.  ``engine/step`` also counts, on
+``decode/wait`` (the read of the chunk a step earlier, and in the same
+wait of the first tokens that have run), ``decode/harvest``.  A
+prompt's first token stays on the device when its last piece is
+enqueued (``first_deferred`` counts them a step) and a later step's
+``decode/wait`` reads it, behind a chunk that has run: while a lane
+decodes no read waits for the newest program on the queue, so
+``prefill/wait`` appears NOWHERE on the admission path.  It is left
+for the one read that may: at the end of a step with no lane decoding
+and nothing in flight, of the tokens of requests of one token (the
+queue is empty at its end, which ``benchmark/harness/step_stages.py``'s
+``level_point`` relies on).  ``engine/step`` also counts, on
 the engine's own clock and with no capture running, what the device
 was left without: ``starved_ms`` (milliseconds the device's queue was
 known empty while the engine had work, from the moment a poll of the
@@ -143,10 +153,14 @@ CONTRACT = {
     # hold over those layers (state_bytes); starved_ms / drains: the milliseconds, and the times,
     # the device's queue was known empty while the engine had work
     # (ServingEngine._launch, _poll_drained); away_ms: from the
-    # previous step's exit to this one's entry (the caller's pass)
+    # previous step's exit to this one's entry (the caller's pass);
+    # first_deferred: the prompts whose last piece the step enqueued,
+    # each leaving its first token on the device for a harvest to read
+    # (committed counts it in the step whose harvest did)
     "engine/step": ("lanes positions kv_blocks kv_table_blocks "
                     "kv_window_blocks kv_bytes state_bytes pieces "
-                    "piece_calls prefill_tokens committed queued "
+                    "piece_calls prefill_tokens first_deferred "
+                    "committed queued "
                     "experts_hit "
                     "expert_load_cv experts_held routed_here "
                     "rows_scored rows_selected "
@@ -184,6 +198,9 @@ CONTRACT = {
     # a call's puts (tokens, scalars) and its enqueue; pieces, tokens,
     # rows: its prefill/piece's; draft: 1 for the draft model's pieces
     "prefill/dispatch": "rid piece pieces tokens rows draft",
+    # only with no lane decoding and nothing in flight, at a step's
+    # end: the read of the tokens of requests of one token, which
+    # waits for the newest program (rid: the newest of them)
     "prefill/wait": "rid",
     "prefill/insert": "rid",
     "prefill/prefix": "tokens",

@@ -19,12 +19,16 @@ own rng stream (seeded at submit) wherever it lands.
   has as many left as the step's budget holds; a dense-dispatch MoE
   prefills whole at its exact length, since router capacity depends on
   it).  A speculative engine then builds the draft's cache over the
-  same piece grid.  The last target call yields the first token.
+  same piece grid.  The last target call yields the first token, on
+  the device.
 - Decoding (``_SlotState`` in ``_slot_states``): the finished batch-1
   rows were inserted into the slot grid (``_paged_insert``); the host
-  holds the request's tokens, its remaining budget and its rng
-  counter; the device holds the grid cache and the CARRY, each slot's
-  next input token and rng counter, which never come to the host.
+  holds the request's tokens as far as it has read them, its remaining
+  budget and its rng counter; the device holds the grid cache and the
+  CARRY, each slot's next input token and rng counter, which never
+  come to the host.  A new lane's first token is PENDING: spliced into
+  the carry from where its prefill left it, and read by the host at a
+  harvest a step or two later (``_read_picks``).
 
 **A step** (``serve_step``, one ``engine/step`` span), in order:
 
@@ -34,21 +38,28 @@ own rng stream (seeded at submit) wherever it lands.
    BEFORE the chunk already in flight is read.  JAX dispatch is
    asynchronous, so the successor queues behind its predecessor and the
    device stays busy through everything below.  Refilled slots have
-   their host-known token and counter spliced over the carry
-   (``_carry_arrays``); stale lanes' tables are pointed at the scratch
-   block first (``_flush_stale_lanes``).  Skipped when every active
-   lane certainly retires in the chunk in flight
-   (``_skip_eager_dispatch``).
+   their first token (a device scalar) and their host-known counter
+   spliced over the carry (``_carry_arrays``); stale lanes' tables are
+   pointed at the scratch block first (``_flush_stale_lanes``).
+   Skipped when every active lane certainly retires in the chunk in
+   flight (``_skip_eager_dispatch``).
 2. *Admit* (``_advance_prefills``): claim free slots for queued
    requests (``_stage_from_queue``: host bookkeeping only) and advance
    staged prefills in arrival order by at most ``prefill_budget``
    prompt tokens (default: one piece), enqueued BEHIND the chunk just
    dispatched: the gap admission adds to a decoding lane is bounded by
    the budget, and a long prompt spreads over steps.  With no lane
-   decoding there is nobody to delay and the budget is waived.
+   decoding there is nobody to delay and the budget is waived.  A
+   prompt's last piece leaves its first token on the device and the
+   lane is inserted behind it: nothing here reads from the device.
 3. *Harvest* (``_harvest_prev``): read the PREVIOUS chunk's tokens
-   (this blocks until that chunk is done), append them to their
-   requests, stop on budget or EOS, retire finished lanes.  Stop and
+   (this blocks until that chunk is done) and, in the same wait, the
+   pending first tokens that have run by then: those the chunk was
+   dispatched behind, and any other that is ready.  Append them to
+   their requests, stop on budget or EOS, retire finished lanes.  This
+   is the step's ONE read of the device, and while a lane decodes it
+   waits for a program that has a successor queued behind it (the
+   chunk step 1 dispatched), never for the newest.  Stop and
    refill decisions therefore lag one chunk: a slot whose request
    finished keeps decoding garbage through the successor; each chunk
    records which request held each slot at dispatch and the harvest
@@ -56,7 +67,11 @@ own rng stream (seeded at submit) wherever it lands.
 4. *Restage* freed lanes, and dispatch now if step 1 did not (first
    step of a session, a harvest-first step, restart after idle).
 5. Hand back the requests that finished, so callers can ``submit()``
-   between steps; one chunk stays in flight across the return.
+   between steps; while a lane decodes, one chunk stays in flight
+   across EVERY return, also of a step that finished a prompt.  A
+   client sees a request's first token after the first harvest that
+   finds it run: with the first chunk that carried its lane at the
+   latest.
 
 **Paged KV cache with cross-request prefix sharing** (the slot grid's
 one layout): KV rows live in one pool of
@@ -127,7 +142,8 @@ tells the layers how many of a call's trailing rows are padding
 (``pad_rows``) and they leave the state where the last REAL token left
 it.  Every real row of a prompt runs once (pieces tile ``work`` without
 overlap) and every decoded token once (the carry chains chunks; a
-refilled slot's first input is spliced from the host).  The state at a
+refilled slot's first input is spliced in from its prefill's
+output).  The state at a
 prefix's end is not kept, so such an engine shares no prefix (no radix
 match, ``preload_prefix`` raises, KV export ships nothing and install
 installs nothing: the receiver prefills), and it refuses a draft model
@@ -196,11 +212,25 @@ from tensorflow_train_distributed_tpu.ops.pallas_kernels import (
 class _SlotState:
     request_id: int
     remaining: int                 # generated tokens still allowed
-    tokens: list                   # prompt + generated so far
-    last_token: int                # feeds the next decode step
+    tokens: list                   # prompt + generated, as far as read
     seed: int = 0                  # per-request sampling stream
     count: int = 1                 # tokens sampled so far (rng counter)
     done: bool = False
+    # The first token where the last prefill piece left it, a device
+    # scalar, until a harvest has read it (``_read_picks``); then None.
+    # ``remaining`` and ``count`` include it from the insert on,
+    # ``tokens`` from its read on.
+    first: object = None
+
+
+@dataclasses.dataclass(eq=False)
+class _AwaitedPick:
+    """A request of ONE token between its prefill and the read of that
+    token (``_read_picks``): it takes no lane and holds no blocks."""
+
+    request_id: int
+    prompt: list
+    first: object                  # device scalar
 
 
 @dataclasses.dataclass
@@ -225,7 +255,6 @@ class _PrefillTask:
     cursor: int = 0                # target pieces completed
     cache_1: object = None         # target batch-1 cache in progress
     first: object = None           # device pick after the last piece
-    first_host: Optional[int] = None
     d_cursor: int = 0              # draft pieces completed
     d_cache_1: object = None
     kv: object = None              # serving_kv.LaneKV claim
@@ -318,6 +347,10 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 #: apart).  ``state_bytes``: the bytes of recurrent state the live
 #: lanes hold over the model's linear layers (lanes x a lane's state
 #: and tail x layers; 0 for a model without them), beside them.
+#: ``first_deferred``: the prompts whose last piece the step enqueued,
+#: each leaving its first token on the device for a harvest to read
+#: (``_advance_piece``, ``_read_picks``); ``committed`` counts a first
+#: token in the step whose harvest read it.
 #: ``starved_ms`` / ``drains``: the milliseconds, and the times, the
 #: device's queue was known empty while the engine had work
 #: (``_launch``, ``_poll_drained``); ``away_ms``: from the previous
@@ -325,8 +358,8 @@ _POOL_OF = {lin: pool for pool, (lin, _, _) in _ROW_LEAVES.items()}
 _STEP_COUNTS = ("lanes", "positions", "kv_blocks", "kv_table_blocks",
                 "kv_window_blocks", "kv_bytes", "state_bytes", "pieces",
                 "piece_calls",
-                "prefill_tokens", "committed", "starved_ms", "drains",
-                "away_ms")
+                "prefill_tokens", "first_deferred", "committed",
+                "starved_ms", "drains", "away_ms")
 
 
 def _ffn_passes(next_fun, args, kwargs, context):
@@ -842,7 +875,13 @@ class ServingEngine:
         # counts [slots]) — never materialized on the host, so a chunk
         # can be enqueued while its predecessor still computes.
         self._carry = None
-        self._refills: set = set()     # slots refilled since last dispatch
+        # Slots refilled since the last dispatch -> the device scalar
+        # that holds the lane's first token (``_carry_arrays``).
+        self._refills: dict = {}
+        # Requests of one token, prefilled, whose token no harvest has
+        # read yet (a lane's unread first token is on its
+        # ``_SlotState``).
+        self._awaited: list = []
         # overlapped_harvests counts harvest passes that ran with a
         # successor chunk already in flight; the _s pair feeds
         # overlap_ratio() (the host-stall share the lookahead hides).
@@ -1408,6 +1447,21 @@ class ServingEngine:
 
         return jax.tree_util.tree_map_with_path(rst, cache)
 
+    @compile_site(buckets="slot-grid (shape-fixed per engine)",
+                  donates=(), statics=(0,), max_compiles=4)
+    @partial(jax.jit, static_argnums=(0,))
+    def _splice_refills(self, tok, counts, refill_counts, picks):
+        """The carry with the refilled slots' values in it, as ONE
+        program whatever the step refilled: ``refill_counts`` [slots]
+        holds a refilled slot's rng counter and -1 elsewhere; ``picks``
+        is a device scalar a slot, a refilled slot's first token where
+        its last prefill piece left it and any of those elsewhere (not
+        read: the tuple keeps one structure, so one compile)."""
+        refilled = refill_counts >= 0
+        return (jnp.where(refilled, jnp.stack(picks).astype(tok.dtype),
+                          tok),
+                jnp.where(refilled, refill_counts, counts))
+
     @compile_site(buckets="slot-grid (the un-bucketed-prompt storm "
                           "surfaces HERE when prefill discipline "
                           "slips)",
@@ -1570,6 +1624,9 @@ class ServingEngine:
         stale rows already obey between ``run()`` cycles; a cancelled
         staged prefill frees its lane IMMEDIATELY (the partial batch-1
         cache is simply dropped — it never touched the slot grid).
+        A lane whose first token no harvest has read yet is freed like
+        any other (the token is not needed), and a request of one token
+        whose token is awaited is dropped.
         Returns False when the id is unknown or already finished (its
         output, if any, stays harvestable)."""
         for i, item in enumerate(self._queue):
@@ -1596,6 +1653,13 @@ class ServingEngine:
                 self._slot_states[slot] = None
                 events.instant("engine/cancel", rid=request_id,
                                where="slot")
+                return True
+        for i, pick in enumerate(self._awaited):
+            if pick.request_id == request_id:
+                # Prefilled, its one token not read yet: nothing held.
+                del self._awaited[i]
+                events.instant("engine/cancel", rid=request_id,
+                               where="awaited")
                 return True
         return False
 
@@ -2104,7 +2168,13 @@ class ServingEngine:
         committed and then ``cancel()``s this side (the
         ``EngineDriver.export_lane`` wrapper does both atomically on
         the engine-owning thread, so no token can generate after the
-        snapshot)."""
+        snapshot).  The history has to be whole, so a lane whose first
+        token no harvest has read yet has it read HERE, between steps
+        and off the serving path (outside ``engine/step``): the read may
+        wait for the lane's last piece, and the token may retire the
+        lane, which then has nothing to move.  A request of one token
+        whose token is awaited is finished but for that read and
+        exports as None."""
         for item in self._queue:
             if item[0] == request_id:
                 _, prompt, max_new, seed, resume = item
@@ -2121,10 +2191,14 @@ class ServingEngine:
         for slot, state in enumerate(self._slot_states):
             if state is None or state.request_id != request_id:
                 continue
+            if state.first is not None:
+                self._commit_picks([(slot, state, int(state.first))])
+                if self._slot_states[slot] is not state:
+                    return None         # its first token ended it
             meta = {"kind": "lane",
                     "tokens": [int(t) for t in state.tokens],
                     "remaining": int(state.remaining),
-                    "last_token": int(state.last_token),
+                    "last_token": int(state.tokens[-1]),
                     "seed": int(state.seed), "count": int(state.count),
                     "done": bool(state.done), "kv": None}
             blob = b""
@@ -2648,13 +2722,20 @@ class ServingEngine:
 
     def _finalize_prefill(self, slot: int, task: _PrefillTask) -> None:
         """Both caches complete: insert into the slot grid and flip the
-        lane to decoding (caller holds ``self._ctx()``)."""
-        first = task.first_host
+        lane to decoding, its first token PENDING: the pick stays on
+        the device, where the lane's first chunk takes it from
+        (``_carry_arrays``), and a harvest takes the host's copy
+        (``_read_picks``).  ``remaining`` and ``count`` need no token to
+        be known, so the lane is scheduled like any other from here;
+        ``progress()`` / ``snapshot()`` show the prompt alone until the
+        token is read.  Nothing here waits for the device: with a lane
+        decoding, the piece and the insert stay queued behind the chunk
+        in flight and the next chunk is enqueued directly behind them.
+        Caller holds ``self._ctx()``."""
         state = _SlotState(request_id=task.request_id,
                            remaining=task.max_new - 1,
-                           tokens=list(task.prompt) + [first],
-                           last_token=first, seed=task.seed,
-                           count=task.resume + 1)
+                           tokens=list(task.prompt), seed=task.seed,
+                           count=task.resume + 1, first=task.first)
         with events.span("prefill/insert", rid=task.request_id):
             self._insert_lane(slot, task)
             self._lane_claim(slot, task.kv, task.prompt)
@@ -2666,7 +2747,7 @@ class ServingEngine:
         # slots_total (the overlap_ratio() torn-read rule).
         del self._staging[slot]
         self._slot_states[slot] = state
-        self._refills.add(slot)        # next dispatch splices host carry
+        self._refills[slot] = task.first    # next dispatch splices it
         events.instant("slot/insert", rid=task.request_id, slot=slot)
 
     def _insert_lane(self, slot: int, task: _PrefillTask) -> None:
@@ -2701,7 +2782,12 @@ class ServingEngine:
         request's output to rounding (every attention row to the bit:
         ``ops.attention.prefix_attention`` walks a call's queries a
         piece at a time; the matmuls are row-wise), not by construction
-        to the bit."""
+        to the bit.  The draft's pieces follow the target's without
+        waiting for the target's pick: a request that stops at its
+        first token (``eos_id``) is learnt at a harvest, so with a
+        draft model it pays a draft prefill it would not need (one path
+        for every request; a request of ONE token is known to the host
+        and pays none)."""
         draft = task.cursor >= task.n_pieces
         i = task.d_cursor if draft else task.cursor
         k = self._piece_counts[-1]
@@ -2769,25 +2855,21 @@ class ServingEngine:
             if not draft:
                 task.cursor += k
                 if task.cursor == task.n_pieces:
-                    # The host copy of the first token: the read blocks
-                    # until this piece has run (behind the decode chunk
-                    # in flight), so it is a ``*/wait`` span.
-                    with events.span("prefill/wait",
-                                     rid=task.request_id):
-                        first = int(task.first)
-                    self._poll_drained()
-                    self._step_counts["committed"] += 1
-                    task.first_host = first
-                    if (task.max_new == 1
-                            or (self.eos_id is not None
-                                and first == self.eos_id)):
-                        # Resolved at prefill — before the draft
-                        # prefill, which such a request would waste.
-                        # Its blocks were never written: hand them
-                        # straight back.
+                    # The pick stays on the device: a read here would
+                    # wait for the newest program on the queue and
+                    # leave the device idle from its end to the next
+                    # step's dispatch.  A harvest reads it
+                    # (``_read_picks``).
+                    self._step_counts["first_deferred"] += 1
+                    if task.max_new == 1:
+                        # One token, whatever it is: no lane, no insert
+                        # and no draft prefill.  Its blocks were never
+                        # written: hand them straight back.  The output
+                        # is written when the token is read.
                         self._kv_release(task.kv)
-                        self._outputs[task.request_id] = (
-                            list(task.prompt) + [first])
+                        self._awaited.append(_AwaitedPick(
+                            task.request_id, list(task.prompt),
+                            task.first))
                         del self._staging[slot]
                     elif self._draft_model is None:
                         self._finalize_prefill(slot, task)
@@ -2808,7 +2890,11 @@ class ServingEngine:
         queue whenever a lane is decoding, so decoding lanes lose no
         more cadence to it than the budget.  With no lane decoding
         there is nobody to delay, so the budget is waived and admission
-        runs at full speed, in the largest calls the engine compiled."""
+        runs at full speed, in the largest calls the engine compiled.
+        Only enqueues: a prompt's last piece and its lane's insert go
+        onto the queue and the pick stays there (``_advance_piece``,
+        ``_finalize_prefill``), so the chunk in flight still has its
+        successors behind it when this returns."""
         self._stage_from_queue()
         if not self._staging:
             return
@@ -2869,8 +2955,7 @@ class ServingEngine:
         return jax.tree.leaves(out)[-1]
 
     def _has_work(self) -> bool:
-        return bool(self._queue or self._staging) or any(
-            s is not None for s in self._slot_states)
+        return self.pending() > 0
 
     def _poll_drained(self) -> None:
         """At a stage's boundary and at the return of a ``*/wait``: if
@@ -2906,16 +2991,20 @@ class ServingEngine:
         speculative harvests alike."""
         before = len(state.tokens)
         for t in tokens:
-            t = int(t)
-            state.tokens.append(t)
-            state.last_token = t
             state.count += 1
             state.remaining -= 1
-            if (state.remaining <= 0
-                    or (self.eos_id is not None and t == self.eos_id)):
-                state.done = True
+            if self._append(state, int(t)):
                 break
         self._step_counts["committed"] += len(state.tokens) - before
+
+    def _append(self, state, t: int) -> bool:
+        """One token that ``count`` and ``remaining`` already include
+        onto its request; True when it was the request's last."""
+        state.tokens.append(t)
+        if (state.remaining <= 0
+                or (self.eos_id is not None and t == self.eos_id)):
+            state.done = True
+        return state.done
 
     def _retire_if_done(self, slot, state):
         if state.done:
@@ -2944,7 +3033,7 @@ class ServingEngine:
         """Consume each slot's emitted prefix from a speculative round
         (variable per slot; budget/EOS via the shared consume rule),
         tracking acceptance stats.  The round's bonus token is the last
-        emitted one, so a surviving slot's ``last_token`` already holds
+        emitted one, so a surviving slot's newest token already is
         ``next_tok`` after consuming.  ``k``: the depth the round was
         DISPATCHED at (recorded in the in-flight dict — under adaptive
         speculation the current pick may already differ); it sizes the
@@ -2980,15 +3069,19 @@ class ServingEngine:
 
     def pending(self) -> int:
         """Requests not yet finished (queued + staged mid-prefill +
-        decoding)."""
+        decoding + those of one token whose token is awaited)."""
         return (len(self._queue) + len(self._staging)
-                + sum(s is not None for s in self._slot_states))
+                + sum(s is not None for s in self._slot_states)
+                + len(self._awaited))
 
     def progress(self) -> dict:
         """Token COUNTS so far per in-flight request, ``{request_id:
         len(prompt + generated)}`` — the O(slots) poll for TTFT/pace
         tracking (``snapshot()`` copies whole token lists; benches
-        polling every step want this instead)."""
+        polling every step want this instead).  A lane shows its prompt
+        alone from its insert until a harvest has read its first token
+        (``_read_picks``), then prompt + first + what its chunks
+        gave."""
         return {s.request_id: len(s.tokens)
                 for s in self._slot_states if s is not None}
 
@@ -2997,7 +3090,9 @@ class ServingEngine:
         ``{request_id: [prompt + generated]}`` — the streaming view
         between ``serve_step()`` calls (tokens arrive chunk-wise; a
         finished request leaves the snapshot and is returned by the
-        step that completed it).  Copies, so callers may mutate."""
+        step that completed it; a lane's first token arrives with the
+        first harvest that finds it run, ``progress()``).  Copies, so
+        callers may mutate."""
         return {s.request_id: list(s.tokens)
                 for s in self._slot_states if s is not None}
 
@@ -3006,40 +3101,36 @@ class ServingEngine:
     @dispatch_critical
     def _carry_arrays(self):
         """The next dispatch's (tok, counts): the device-resident carry
-        from the previous chunk, with host values spliced in for slots
-        refilled since (``jnp.where`` only ENQUEUES — still no sync).
-        Retired-but-unrefilled slots keep garbage carry and decode
-        garbage, as idle slots do."""
+        from the previous chunk, with each slot refilled since spliced
+        in from where its values are: the first token from the DEVICE,
+        where the lane's last prefill piece left it (the host may not
+        have read it yet), the rng counter from the host.  One route
+        for the session's first dispatch (a carry of zeros, every live
+        lane a refill) and every later one, and ONE program a dispatch
+        whatever it refilled (``_splice_refills``), which only ENQUEUES:
+        a lane's first chunk goes onto the queue directly behind its
+        last piece and its insert, with no host round trip between
+        them.  Retired-but-unrefilled slots keep garbage carry and
+        decode garbage, as idle slots do."""
         if self._carry is None:
-            # First dispatch of the session: everything is host-known.
-            tok = np.zeros((self.slots,), np.int32)
-            counts = np.zeros((self.slots,), np.int32)
-            for slot, state in enumerate(self._slot_states):
-                if state is not None:
-                    tok[slot] = state.last_token
-                    counts[slot] = state.count
-            self._refills.clear()
-            return jnp.asarray(tok), jnp.asarray(counts)
+            zeros = np.zeros((self.slots,), np.int32)
+            self._carry = (jnp.asarray(zeros), jnp.asarray(zeros))
         tok, counts = self._carry
-        if self._refills:
-            mask = np.zeros((self.slots,), bool)
-            tok_h = np.zeros((self.slots,), np.int32)
-            cnt_h = np.zeros((self.slots,), np.int32)
-            for slot in self._refills:
-                state = self._slot_states[slot]
-                if state is None:      # refilled then cancelled
-                    continue
-                mask[slot] = True
-                tok_h[slot] = state.last_token
-                cnt_h[slot] = state.count
-            jmask, jtok, jcnt = (jnp.asarray(mask), jnp.asarray(tok_h),
-                                 jnp.asarray(cnt_h))
-            # The puts took their time, and the lanes' reset ahead of
-            # them is short: ask again before the splice is enqueued.
+        refills = {slot: pick for slot, pick in self._refills.items()
+                   if self._slot_states[slot] is not None}  # not cancelled
+        self._refills.clear()
+        if refills:
+            refill_counts = np.full((self.slots,), -1, np.int32)
+            picks = [next(iter(refills.values()))] * self.slots
+            for slot, pick in refills.items():
+                refill_counts[slot] = self._slot_states[slot].count
+                picks[slot] = pick
+            jcounts = jnp.asarray(refill_counts)
+            # The put took its time, and the lanes' reset ahead of it
+            # is short: ask again before the splice is enqueued.
             self._poll_drained()
-            tok = self._launch(jnp.where, jmask, jtok, tok)
-            counts = self._launch(jnp.where, jmask, jcnt, counts)
-            self._refills.clear()
+            tok, counts = self._launch(self._splice_refills, tok, counts,
+                                       jcounts, tuple(picks))
         return tok, counts
 
     def _count_dispatch(self, held: list, spec_k: int) -> None:
@@ -3120,7 +3211,10 @@ class ServingEngine:
                     if state is not None:
                         seeds[slot] = state.seed
                         rids[slot] = state.request_id
-                        held.append(len(state.tokens))
+                        # its positions: the tokens read and the
+                        # first token where no harvest has read it
+                        held.append(len(state.tokens)
+                                    + (state.first is not None))
                 # Depth for THIS round: the controller's pick (adaptive)
                 # or the fixed k.  Host ints end to end — read before
                 # the first enqueue (the controller is
@@ -3160,6 +3254,11 @@ class ServingEngine:
                 self._carry = (last, counts_next)
                 self._inflight = {"spec": False, "rids": rids,
                                   "toks": toks, "sown": sown}
+            # Every first token still unread was picked by a program
+            # ahead of this chunk on the queue: this chunk's harvest
+            # reads them without a wait.
+            self._inflight["picks"] = [
+                holder for _, holder in self._pending_picks()]
         self._poll_drained()
         with self._stats_lock:
             self.overlap_stats["chunks"] += 1
@@ -3191,12 +3290,55 @@ class ServingEngine:
                    for s in self._slot_states if s is not None]
         return bool(certain) and all(certain)
 
+    def _pending_picks(self) -> list:
+        """``(slot, holder)`` of every first token no harvest has read,
+        in no order: a lane's ``_SlotState`` under its slot, a request
+        of one token (``_AwaitedPick``) under None."""
+        return [(slot, state)
+                for slot, state in enumerate(self._slot_states)
+                if state is not None and state.first is not None] + [
+            (None, pick) for pick in self._awaited]
+
+    def _read_picks(self, due=None) -> list:
+        """Host copies of the pending first tokens that have run,
+        ``[(slot, holder, token)]`` for ``_commit_picks``: those among
+        ``due`` (the picks a chunk was dispatched behind, read once
+        that chunk has run: the queue runs in order, so each returns at
+        once) and any other whose ``is_ready()`` says so (it asks and
+        does not wait).  ``due=None`` reads every one and may wait: for
+        when no lane decodes."""
+        return [(slot, holder, int(holder.first))
+                for slot, holder in self._pending_picks()
+                if due is None or any(holder is d for d in due)
+                or holder.first.is_ready()]
+
+    def _commit_picks(self, read) -> None:
+        """Hand each first token ``_read_picks`` read to its request:
+        onto a lane's tokens, under the ONE termination rule (a first
+        token that is ``eos_id`` retires its lane here, and the rid
+        guard trims what the lane decoded meanwhile); with its prompt
+        as the output of a request of one token."""
+        for slot, holder, first in read:
+            holder.first = None
+            self._step_counts["committed"] += 1
+            if slot is None:
+                self._awaited.remove(holder)
+                self._outputs[holder.request_id] = holder.prompt + [first]
+            else:
+                self._append(holder, first)
+                self._retire_if_done(slot, holder)
+
     def _harvest_prev(self, inf: dict, overlapped: bool) -> None:
         """Materialize the previous chunk's host copy (this blocks
         until THAT chunk finishes — when ``overlapped``, the successor
         is already enqueued and keeps the device busy through the wait
         and the host passes that follow) and consume it under the
-        dispatch-time rid guard.  Only the post-materialization host
+        dispatch-time rid guard.  The first tokens that have run are
+        read in the same wait (``_read_picks``: none of them waits for
+        more than the chunk did) and handed over BEFORE the chunk is
+        consumed: the first chunk that carried a lane was dispatched
+        behind the lane's pick, so a lane's first token always precedes
+        its chunks' tokens.  Only the post-materialization host
         pass is timed into ``overlap_stats``: the block inside
         ``np.asarray`` is device time, not host-harvest time, and would
         drown the ratio."""
@@ -3210,9 +3352,11 @@ class ServingEngine:
             else:
                 toks = np.asarray(inf["toks"])
                 sown = jax.tree.map(np.asarray, inf["sown"])
+            picks = self._read_picks(inf["picks"])
         self._poll_drained()
         t0 = time.perf_counter()
         with events.span("decode/harvest", overlapped=overlapped):
+            self._commit_picks(picks)
             if inf["spec"]:
                 self._harvest_spec(*args, inf["k"], rids=rids)
             else:
@@ -3256,11 +3400,20 @@ class ServingEngine:
         the device-resident carry BEFORE the in-flight chunk's host
         copy is touched, so stop detection, admission and the caller's
         streaming/deadline passes (which run between ``serve_step``
-        calls — a chunk stays in flight across the return) all hide
-        under device compute.  Stop decisions lag one chunk; the
-        harvest trims the overshoot.  A finished session leaves one
-        garbage chunk in flight — harmless, discarded by the next
-        cycle's trim guard.
+        calls) all hide under device compute.  The guarantee: while a
+        lane decodes, a chunk is in flight across EVERY return, and
+        every host read of a device value inside the step waits for a
+        program that has a successor queued behind it.  A step that
+        enqueues a prompt's last piece reads nothing of it: the first
+        token stays on the device (``first_deferred``), the lane's
+        first chunk takes it from there, and a harvest reads it once
+        it has run (``_read_picks``).  So a client sees a first token
+        with the first harvest that finds it ready: with the lane's
+        first chunk at the latest, a step or two after the piece was
+        enqueued.  Stop decisions lag one chunk, a stop at the first
+        token by as much as that; the harvest trims the overshoot.  A
+        finished session leaves one garbage chunk in flight —
+        harmless, discarded by the next cycle's trim guard.
 
         The whole step is one ``engine/step`` span, the parent of the
         ``decode/*`` and ``prefill/*`` spans recorded inside it (every
@@ -3299,6 +3452,16 @@ class ServingEngine:
                 # post-idle restart): dispatch now so the NEXT step's
                 # harvest overlaps.
                 self._dispatch_chunk()
+            if self._inflight is None and self._awaited:
+                # No lane decodes and nothing is in flight to read the
+                # awaited tokens behind: read them here.  This waits
+                # for the newest program on the queue, and delays
+                # nobody.
+                with events.span("prefill/wait",
+                                 rid=self._awaited[-1].request_id):
+                    picks = self._read_picks()
+                self._poll_drained()
+                self._commit_picks(picks)
             out, self._outputs = self._outputs, {}
             self._poll_drained()
             if self._drained_at is not None and not self._has_work():

@@ -300,7 +300,8 @@ def test_trace_report_prints_a_step_by_stage_and_what_starved(
         tmp_path, capsys):
     """From a ring export: a step's time by the innermost span open
     (self time), what no span covers, and the starved / away seconds a
-    step from ``engine/step``'s own counters."""
+    step and the first tokens left on the device from ``engine/step``'s
+    own counters."""
     import importlib.util
     import os
 
@@ -312,17 +313,21 @@ def test_trace_report_prints_a_step_by_stage_and_what_starved(
 
     rec = Recorder(capacity=64)
     # 100 ms: a dispatch of 30 with its prelude of 20 inside; a piece
-    # of 50 holding a dispatch of 10 and a wait of 30; 20 under no span.
+    # of 20 holding a dispatch of 10, which reads nothing (the first
+    # token stays on the device); the harvest's wait of 30 behind it;
+    # 20 under no span.
     for name, off, dur in (("decode/stage", 0.0, 0.02),
                            ("decode/dispatch", 0.0, 0.03),
                            ("prefill/dispatch", 0.035, 0.01),
-                           ("prefill/wait", 0.05, 0.03),
-                           ("prefill/piece", 0.03, 0.05)):
+                           ("prefill/piece", 0.03, 0.02),
+                           ("decode/wait", 0.05, 0.03)):
         rec.record_at(name, "X", 10.0 + off, dur)
     rec.record_at("engine/step", "X", 10.0, 0.1,
-                  dict(starved_ms=4.0, drains=1, away_ms=0.0))
+                  dict(starved_ms=4.0, drains=1, away_ms=0.0,
+                       first_deferred=1))
     rec.record_at("engine/step", "X", 10.15, 0.05,
-                  dict(starved_ms=8.0, drains=2, away_ms=50.0))
+                  dict(starved_ms=8.0, drains=2, away_ms=50.0,
+                       first_deferred=2))
     path = tmp_path / "trace.json"
     rec.save(str(path))
     got = mod.step_stages(mod.load_events(str(path)))
@@ -330,15 +335,17 @@ def test_trace_report_prints_a_step_by_stage_and_what_starved(
     assert rows == pytest.approx({
         "decode/stage": (10.0, 1), "decode/dispatch": (5.0, 1),
         "prefill/piece": (5.0, 1), "prefill/dispatch": (5.0, 1),
-        "prefill/wait": (15.0, 1), "(no span)": (35.0, 2)})
+        "decode/wait": (15.0, 1), "(no span)": (35.0, 2)})
     assert (got["starved_ms"], got["drains"], got["away_ms"]) == (
         12.0, 3, 50.0)
+    assert got["first_deferred"] == 3
     assert got["span_ms"] == pytest.approx(200.0)
     assert mod.main([str(path)]) == 0
     out = capsys.readouterr().out
     assert "== engine step by stage (2 steps" in out
     assert "device starved     0.012 s of 0.200 s" in out
     assert "25.000 ms between two steps" in out
+    assert "first tokens left on the device for a harvest 3 (1.50" in out
     # A trace from before the counters prints the table alone.
     old = Recorder(capacity=8)
     old.record_at("engine/step", "X", 1.0, 0.1, dict(lanes=1))
@@ -346,6 +353,7 @@ def test_trace_report_prints_a_step_by_stage_and_what_starved(
     assert mod.main([str(path)]) == 0
     out = capsys.readouterr().out
     assert "engine step by stage" in out and "device starved" not in out
+    assert "first tokens left" not in out
 
 
 def test_trace_report_counts_fused_dispatches(tmp_path):

@@ -182,6 +182,10 @@ def test_a_step_counts_what_the_device_was_left_without():
         "engine/step")
 
 
+def test_a_step_counts_the_first_tokens_it_left_on_the_device():
+    assert "first_deferred" in events.contract_attrs("engine/step")
+
+
 class _Handle:
     """Stands for an output of the newest program in the engine's
     starved-device account: ``is_ready`` as the test sets it."""
@@ -267,7 +271,7 @@ def test_engine_spans_keep_the_contract(tiny, variant):
                              "kv_table_blocks", "kv_window_blocks",
                              "kv_bytes", "state_bytes", "pieces",
                              "piece_calls", "prefill_tokens",
-                             "committed", "queued",
+                             "first_deferred", "committed", "queued",
                              "starved_ms", "drains", "away_ms"}
         assert s[5]["starved_ms"] >= 0 and s[5]["away_ms"] >= 0
         assert s[5]["kv_window_blocks"] == 0     # no window layer here
@@ -308,8 +312,26 @@ def test_engine_spans_keep_the_contract(tiny, variant):
     if "draft" not in variant:
         assert min(s[5]["pieces"] for s in steps if s[5]["pieces"]) == (
             2 if variant == "budget-8" else 1)
-    # A first token is read inside a */wait span, once per request.
-    assert len([e for e in evs if e[0] == "prefill/wait"]) == len(reqs)
+    # A first token stays on the device when its prompt's last piece
+    # is enqueued, once per request, and a harvest reads it in its
+    # ``decode/wait``.  No ``prefill/wait`` while a lane decodes; the
+    # one there is reads the token of the request of ONE token, the
+    # session's last, at the end of a step with nothing in flight.
+    assert sum(s[5]["first_deferred"] for s in steps) == len(reqs)
+    last_calls = [p for p in pieces         # of the target's pieces
+                  if p[5]["piece"] + p[5]["pieces"] == p[5]["n_pieces"]]
+    for s in steps:
+        assert s[5]["first_deferred"] == len(
+            [p for p in last_calls
+             if s[4] == p[4] and s[2] <= p[2] <= s[2] + s[3]])
+    waited = [e for e in evs if e[0] == "prefill/wait"]
+    assert [e[5]["rid"] for e in waited] in ([], [ids[-1]])
+    for e in waited:
+        own = [s for s in steps if s[4] == e[4]
+               and s[2] <= e[2] <= s[2] + s[3]]
+        assert len(own) == 1 and not any(
+            d[4] == e[4] and own[0][2] <= d[2] <= e[2]
+            for d in evs if d[0] == "decode/dispatch")
     # Every harvest waited in a decode/wait span of its own; the last
     # of a session has no successor dispatched over it.
     waits = [e[5]["overlapped"] for e in evs if e[0] == "decode/wait"]

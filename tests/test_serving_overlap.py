@@ -54,14 +54,23 @@ def _serve(params, reqs, **kw):
 
 
 def _alone(params, reqs, **kw):
-    """Each request by itself in a one-slot engine, same seeds: what a
-    sampled request must produce wherever the scheduler puts it."""
+    """Each request by itself in a one-slot engine, one after the
+    other, same seeds: what a sampled request must produce wherever the
+    scheduler puts it (no refill, no neighbour, nothing in flight when
+    its prompt finishes)."""
+    eng = ServingEngine(CFG, params, **dict(kw, slots=1))
     outs = []
     for i, (p, m) in enumerate(reqs):
-        eng = ServingEngine(CFG, params, **dict(kw, slots=1))
         rid = eng.submit(p, m, seed=i)
         outs.append(eng.run()[rid])
     return outs
+
+
+def _draft_kw():
+    dcfg = LLAMA_PRESETS["llama_tiny_scan"]
+    dparams = LlamaModel(dcfg).init(
+        jax.random.PRNGKey(99), jnp.zeros((1, 4), jnp.int32))["params"]
+    return dict(draft_config=dcfg, draft_params=dparams, speculative_k=3)
 
 
 # ── tier-1 smoke: the lookahead engages ────────────────────────────────
@@ -151,6 +160,204 @@ def test_interleave_smoke(params):
     assert out[b] == _ref(params, long_prompt, 4)
 
 
+# ── tier-1: a finished prefill's first token stays on the device ───────
+
+
+def _cut_at(tokens, n_prompt, eos):
+    """``tokens`` as an engine with ``eos_id=eos`` hands them back."""
+    for i in range(n_prompt, len(tokens)):
+        if tokens[i] == eos:
+            return tokens[:i + 1]
+    return tokens
+
+
+@pytest.mark.parametrize("draft", [False, True], ids=["plain", "draft"])
+@pytest.mark.parametrize("sampling", [False, True],
+                         ids=["greedy", "sampled"])
+def test_deferred_first_token_parity(params, sampling, draft):
+    """A closed loop whose every slot refills mid-stream, among its
+    requests one of ONE token, one of none, one of three pieces and one
+    whose FIRST token is the stop token: the tokens are those of each
+    request served alone with nothing in flight (and, greedy, those of
+    ``generate()``), cut at the stop token.  The first tokens were read
+    at harvests: every finished prompt left its pick on the device."""
+    from tensorflow_train_distributed_tpu.runtime import events
+
+    rng = np.random.default_rng(41)
+    kw = dict(cache_len=64, chunk=3, prefill_chunk=4)
+    if sampling:
+        kw.update(temperature=0.8, top_k=20)
+    if draft:
+        kw.update(_draft_kw())
+    reqs = [(list(rng.integers(1, 200, n)), m)
+            for n, m in [(5, 6), (3, 9), (7, 5), (4, 12), (6, 1), (2, 0),
+                         (11, 7), (3, 1)]]
+    free = _alone(params, reqs, **kw)               # no stop token
+    if not sampling:
+        for got, (p, m) in zip(free, reqs):
+            assert got == _ref(params, p, m)
+    eos = free[2][len(reqs[2][0])]          # request 2 stops at its first
+    want = [_cut_at(t, len(p), eos) for t, (p, _) in zip(free, reqs)]
+    assert len(want[2]) == len(reqs[2][0]) + 1
+
+    eng = ServingEngine(CFG, params, slots=2, eos_id=eos, **kw)
+    rec = events.get_recorder()
+    seq0 = rec.events_after(0)[0]
+    out = {}
+    ids = [eng.submit(p, m, seed=i) for i, (p, m) in enumerate(reqs[:3])]
+    out.update(eng.serve_step())
+    out.update(eng.serve_step())
+    ids += [eng.submit(p, m, seed=3 + i)            # arrive mid-stream
+            for i, (p, m) in enumerate(reqs[3:])]
+    while eng.pending():
+        out.update(eng.serve_step())
+    assert [out[i] for i in ids] == want
+    steps = [e[5] for e in rec.events_after(seq0)[1]
+             if e[0] == "engine/step"]
+    prompts = sum(1 for _, m in reqs if m)
+    assert sum(s["first_deferred"] for s in steps) == prompts
+    assert sum(s["committed"] for s in steps) == sum(
+        len(t) - len(p) for t, (p, _) in zip(want, reqs))
+
+
+def test_cancel_while_the_first_token_is_pending(params):
+    """A lane cancelled between its insert and the harvest that would
+    have read its first token: the lane is freed and refilled, its
+    request never resolves, the others' tokens are ``generate()``'s."""
+    rng = np.random.default_rng(43)
+    reqs = [(list(rng.integers(1, 200, n)), m)
+            for n, m in [(5, 7), (4, 9), (6, 5)]]
+    eng = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=3,
+                        prompt_buckets=(8,))
+    a, b = (eng.submit(p, m) for p, m in reqs[:2])
+    out = dict(eng.serve_step())
+    # Both prompts finished in that step and nothing was harvested yet.
+    assert eng.progress() == {a: len(reqs[0][0]), b: len(reqs[1][0])}
+    assert eng.cancel(a)
+    c = eng.submit(*reqs[2])                 # takes the freed lane
+    while eng.pending():
+        out.update(eng.serve_step())
+    assert a not in out and not eng.cancel(a)
+    assert out[b] == _ref(params, *reqs[1])
+    assert out[c] == _ref(params, *reqs[2])
+
+
+def test_export_reads_a_pending_first_token(params):
+    """Off the serving path the token is read where it is needed: a
+    lane exported before any harvest ships its whole history, first
+    token included, and serves on; a request of one token whose token
+    is awaited is finished but for that read and exports as None."""
+    prompt = [3, 1, 4, 1, 5]
+    ref = _ref(params, prompt, 9)
+    eng = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=3,
+                        prompt_buckets=(8,))
+    rid = eng.submit(prompt, 9)
+    eng.serve_step()
+    assert eng.progress() == {rid: len(prompt)}          # pending
+    meta, _ = eng.export_lane(rid)
+    assert meta["kind"] == "lane"
+    assert meta["tokens"] == ref[:len(prompt) + 1]
+    assert meta["last_token"] == meta["tokens"][-1]
+    assert (meta["remaining"], meta["count"]) == (8, 1)
+    assert eng.progress() == {rid: len(prompt) + 1}
+    one = eng.submit([2, 7, 1], 1)          # prefilled behind a chunk
+    out = dict(eng.serve_step())
+    assert eng.export_lane(one) is None     # awaited, or handed back
+    while eng.pending():
+        out.update(eng.serve_step())
+    assert out[rid] == ref and out[one] == _ref(params, [2, 7, 1], 1)
+
+
+def test_progress_shows_the_prompt_until_a_harvest_reads_the_first(params):
+    """``progress()`` / ``snapshot()`` between a lane's insert and the
+    harvest of its first chunk show the prompt alone; that harvest
+    brings the first token and the chunk's together.  Later lanes: the
+    prompt alone, or with its first token where a harvest found that
+    ready before the lane's first chunk, then whole chunks."""
+    prompt, chunk = [3, 1, 4, 1, 5], 3
+    ref = _ref(params, prompt, 11)
+    eng = ServingEngine(CFG, params, slots=2, cache_len=32, chunk=chunk,
+                        prompt_buckets=(8,))
+    rid = eng.submit(prompt, 11)
+    eng.serve_step()                 # prefill, insert, the first dispatch
+    assert eng.progress() == {rid: len(prompt)}
+    assert eng.snapshot() == {rid: prompt}
+    eng.serve_step()                 # harvests the lane's first chunk
+    assert eng.progress() == {rid: len(prompt) + 1 + chunk}
+    assert eng.snapshot() == {rid: ref[:len(prompt) + 1 + chunk]}
+    late = [9, 2, 6]
+    rid2 = eng.submit(late, 6)       # admitted behind a chunk in flight
+    seen = []
+    while eng.pending():
+        done = eng.serve_step()
+        seen.append(len(done.get(rid2) or eng.snapshot().get(rid2) or ()))
+    seen = [n for n in seen if n]
+    assert seen == sorted(seen) and seen[-1] == len(late) + 6
+    assert set(seen) <= {len(late)} | {
+        len(late) + 1 + i * chunk for i in range(3)} | {len(late) + 6}
+
+
+def test_no_step_waits_for_the_newest_program_while_a_lane_decodes(
+        params, monkeypatch):
+    """THE invariant: with a lane decoding, a step that enqueues a
+    prompt's last piece reads nothing of it.  No ``prefill/wait`` in a
+    step that had lanes; ``first_deferred`` counts every finished
+    prompt in the step that enqueued its last piece; a chunk is in
+    flight across every return; and every scalar the host reads inside
+    such a step (a first token) had run already when it was read: the
+    read waited for nothing (the step's one wait is its
+    ``decode/wait``, for a chunk with its successor queued behind)."""
+    from jax._src import array as jax_array
+
+    from tensorflow_train_distributed_tpu.runtime import events
+
+    rng = np.random.default_rng(47)
+    reqs = [(list(rng.integers(1, 200, n)), m)
+            for n, m in [(4, 14), (5, 6), (9, 5), (3, 7), (6, 4), (2, 8)]]
+    eng = ServingEngine(CFG, params, slots=2, cache_len=64, chunk=2,
+                        prefill_chunk=4)
+    watching, waited = [False], []
+    value = jax_array.ArrayImpl.__dict__["_value"]
+
+    def logged(self):
+        if watching[0] and self.ndim == 0 and not self.is_ready():
+            waited.append(self)
+        return value.fget(self)
+
+    monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(logged))
+    rec = events.get_recorder()
+    seq0 = rec.events_after(0)[0]
+    out, ids, todo = {}, [eng.submit(*reqs[0])], list(reqs[1:])
+    while eng.pending():
+        watching[0] = any(s is not None for s in eng._slot_states)
+        out.update(eng.serve_step())
+        watching[0] = False
+        if any(s is not None for s in eng._slot_states):
+            assert eng._inflight is not None     # across the return
+        if todo and len(out) + 2 > len(ids):     # keep two in the loop
+            ids.append(eng.submit(*todo.pop(0)))
+    for rid, (p, m) in zip(ids, reqs):
+        assert out[rid] == _ref(params, p, m)
+    assert waited == []
+    _, evs = rec.events_after(seq0)
+    steps = [e for e in evs if e[0] == "engine/step"]
+    assert sum(s[5]["first_deferred"] for s in steps) == len(reqs)
+
+    def inside(e, s):
+        return e[4] == s[4] and s[2] <= e[2] <= s[2] + s[3]
+
+    for s in steps:
+        inserts = [e for e in evs
+                   if e[0] == "prefill/insert" and inside(e, s)]
+        assert s[5]["first_deferred"] == len(inserts)
+    assert not [e for e in evs if e[0] == "prefill/wait"]
+    # ... and the chunk a step waited for had its successor queued
+    # behind it, but where every lane was to retire in it
+    # (``_skip_eager_dispatch``: the successor would be garbage).
+    waits = [e[5]["overlapped"] for e in evs if e[0] == "decode/wait"]
+    assert waits.count(True) >= 10 > waits.count(False)
+
+
 def test_prefill_budget_zero_is_refused(params):
     """0 used to select atomic admission; it is input from outside and
     is refused, by the engine and through the CLI's flag, with an error
@@ -207,12 +414,9 @@ def test_overlap_parity_speculative(params, sampling):
     so round N+1 enqueues before round N's host copy exists — greedy
     must equal generate(), sampled each request served alone, and the
     budget trims must account for every token."""
-    dcfg = LLAMA_PRESETS["llama_tiny_scan"]
-    dparams = LlamaModel(dcfg).init(
-        jax.random.PRNGKey(99), jnp.zeros((1, 4), jnp.int32))["params"]
     rng = np.random.default_rng(21)
     kw = dict(slots=2, cache_len=48, chunk=3, prompt_buckets=(8,),
-              draft_config=dcfg, draft_params=dparams, speculative_k=3)
+              **_draft_kw())
     if sampling:
         kw.update(temperature=1.0, top_k=8)
     reqs = [(list(rng.integers(1, 200, n)), m)
@@ -335,12 +539,9 @@ def test_interleave_parity_speculative(params):
     """Speculative serving: the DRAFT's prefill stages alongside the
     target's (same piece grid, budget-metered too) — outputs must
     equal generate()'s and every token be accounted for."""
-    dcfg = LLAMA_PRESETS["llama_tiny_scan"]
-    dparams = LlamaModel(dcfg).init(
-        jax.random.PRNGKey(99), jnp.zeros((1, 4), jnp.int32))["params"]
     rng = np.random.default_rng(27)
     kw = dict(slots=2, cache_len=64, chunk=3, prefill_chunk=4,
-              draft_config=dcfg, draft_params=dparams, speculative_k=3)
+              **_draft_kw())
     active = [(list(rng.integers(1, 200, 4)), 9)]
     long_req = (list(rng.integers(1, 200, 12)), 6)
     tail_req = (list(rng.integers(1, 200, 3)), 5)

@@ -105,11 +105,13 @@ def step_stages(evs: list) -> dict:
     time), or to no span at all.  ``{"steps": n, "rows": [(name,
     self_mean_ms, self_p50_ms, self_p75_ms, steps_with_it)], busiest
     first, with ``(no span)`` among them, "starved_ms", "away_ms",
-    "drains", "span_ms"}``: the last four are sums of the steps' own
-    counters (milliseconds the engine knew the device's queue empty
-    while it had work, the caller's passes between steps, how often
-    the queue was found empty) and of the steps' durations plus those
-    passes; ``None`` for counters a trace predates.  The benchmark
+    "drains", "first_deferred", "span_ms"}``: the last five are sums of
+    the steps' own counters (milliseconds the engine knew the device's
+    queue empty while it had work, the caller's passes between steps,
+    how often the queue was found empty, the prompts finished whose
+    first token stayed on the device for a harvest to read) and of the
+    steps' durations plus those passes; ``None`` for counters a trace
+    predates.  The benchmark
     reads the same from the live ring (``benchmark/harness/
     step_stages.py``, which may not import this tool)."""
     by_thread = collections.defaultdict(list)
@@ -164,6 +166,7 @@ def step_stages(evs: list) -> dict:
     away = total("away_ms")
     return {"steps": n, "rows": rows, "starved_ms": total("starved_ms"),
             "away_ms": away, "drains": total("drains"),
+            "first_deferred": total("first_deferred"),
             "span_ms": sum(d for _, _, d, _ in steps) / 1e3 + (away or 0.0)}
 
 
@@ -899,6 +902,10 @@ def main(argv=None) -> int:
             print(f"  away (the caller)  {stages['away_ms'] / 1e3:.3f} s "
                   f"({100.0 * stages['away_ms'] / span:.2f}%): "
                   f"{stages['away_ms'] / n:.3f} ms between two steps")
+        if stages["first_deferred"] is not None:
+            print(f"  first tokens left on the device for a harvest "
+                  f"{stages['first_deferred']} "
+                  f"({stages['first_deferred'] / n:.2f} a step)")
     pieces, walked, held, counted = prefill_walk(evs)
     if held:
         print(f"  prefill/piece attention walked {walked} of {held} "
