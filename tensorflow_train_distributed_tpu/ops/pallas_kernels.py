@@ -37,10 +37,10 @@ Two serving-side kernels back the engine's paged KV cache:
   the block table: one grid step a lane, which walks the blocks its
   prefetched length reaches and no others (``paged_blocks_walked``;
   the pools stay in HBM and the scalar-prefetched table steers
-  hand-issued, double-buffered copies of 128 rows' worth of blocks a
-  step), an online (max, sumexp, acc) accumulator per (head, query
-  row) carried across steps in VMEM scratch, per-lane causal masking
-  from the same length, GQA handled per kv-head group in-kernel, and
+  hand-issued, double-buffered copies of up to ``PAGED_FOLD_ROWS``
+  rows' worth of blocks a step), an online (max, sumexp, acc) accumulator
+  per (head, query row) carried across steps in VMEM scratch, per-lane
+  causal masking from the same length, GQA per kv-head group in-kernel, and
   optional int8-pool dequant fused into the walk (per-row symmetric
   scales ride in a parallel scale pool) — the dense per-lane view is
   never materialized, and the kernel's time follows what the lanes
@@ -369,11 +369,116 @@ def paged_blocks_walked(lengths, q_len: int, bs: int, n_blk: int,
     return last - paged_first_block(lengths, bs, window).clip(0, last - 1)
 
 
-def _paged_fold(bs: int, n_blk: int) -> int:
-    """Table entries one step of the kernel's walk folds into the
-    accumulators: 128 rows' worth, so a step moves 256 KB at the
-    benchmark's widths instead of one 16-row block's 32 KB."""
-    return min(n_blk, max(1, 128 // bs))
+#: Most cached rows that one copy-and-fold step of the three paged
+#: decode walks holds (``_paged_fold``; chosen on the chip from a sweep:
+#: PERF.md section 6, PR 50).
+PAGED_FOLD_ROWS = 512
+#: Fast memory that a step's rows may take by ``_paged_fold``'s sum, of
+#: the 16 MiB of scoped VMEM that the three calls are compiled with (the
+#: sum counts a step's float32 copies whole, which the compiler does
+#: not hold at once: PERF.md section 6, PR 50).
+_PAGED_STEP_VMEM = 12 << 20
+
+
+def _paged_fold(bs: int, n_blk: int, row_bytes: int) -> int:
+    """Table entries one step of a paged walk folds into the
+    accumulators: up to ``PAGED_FOLD_ROWS`` rows' worth, so the running
+    maximum, sum and rescale run once for that many rows and a step
+    waits once on that many copies; a table or ring shorter than that
+    is one step.  ``row_bytes`` is what one cached row costs a step in
+    fast memory (``_step_row_bytes``): a step is as many whole lane
+    tiles of rows as ``_PAGED_STEP_VMEM`` holds at that cost, and never
+    under one tile (the 128 rows every width has compiled with), so the
+    rows of a wide cache (an MHA model's 8,192 columns of keys and
+    values) are folded fewer to a step and the constant is the most."""
+    rows = max(_LANES, _PAGED_STEP_VMEM // row_bytes // _LANES * _LANES)
+    return min(n_blk, max(1, min(PAGED_FOLD_ROWS, rows) // bs))
+
+
+def _step_row_bytes(pools, q_rows: int, widened: bool = False) -> int:
+    """Fast memory one cached row costs a step of a paged walk over
+    ``pools`` ([blocks, block_size, columns] each): its place in both
+    double buffers, its float32 copy where the kernel widens a step's
+    rows before the products (``widened``), and its column of the six
+    float32-sized [``q_rows``, step] arrays the scores pass through
+    (positions, mask, logits, probabilities)."""
+    return sum(p.shape[-1] * (2 * p.dtype.itemsize + 4 * widened)
+               for p in pools) + 24 * q_rows
+
+
+#: Entries of a step that one turn of ``_walk_copies``' loop handles:
+#: their starts written out, their wait ONE wait for the bytes of all
+#: of them (chosen on the chip: PERF.md section 6, PR 50).
+_COPY_GROUP = 8
+
+
+def _walk_copies(tbl_ref, pairs, sems, fold, live, first=0, ring=None):
+    """``copies(step, slot, wait=False)`` of a paged walk's kernel: start
+    the copies of the lane's step ``step`` into slot ``slot`` of the
+    double buffers, or await them.  Entry ``p`` of the step is entry
+    ``step * fold + p`` of the lane's walk, which the table holds there
+    or, over a ``ring``, at ``(first + entry) % ring``; its block of
+    each ``pool`` goes to ``buf.at[slot, p]``, for every ``(pool, buf)``
+    of ``pairs`` (``buf`` [2, fold, block_size, row]), all signalling
+    ``sems.at[slot]``.  A step holds ``min(fold, live - step * fold)``
+    entries, what the lane holds of it, and a wait awaits as many: no
+    block is read that the lane does not hold, and none twice.  (A wait
+    needs only shapes, and a semaphore counts bytes: one wait for a
+    group's bytes stands for the group's waits.)  The entries are a
+    ROLLED loop over groups of ``_COPY_GROUP`` and a second over the
+    entries left, so the kernel's traced size, and with it what every
+    process pays to trace and lower it, does not grow with the step's
+    width."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lane = pl.program_id(0)
+    group = min(_COPY_GROUP, fold)
+
+    def copies(step, slot, wait=False):
+        sem = sems.at[slot]
+
+        def start(p):
+            entry = step * fold + p
+            if ring is not None:
+                entry = jax.lax.rem(first + entry, ring)
+            blk = tbl_ref[lane, entry]
+            for pool, buf in pairs:
+                pltpu.make_async_copy(pool.at[blk], buf.at[slot, p],
+                                      sem).start()
+
+        def await_(entries):
+            for pool, buf in pairs:
+                pltpu.make_async_copy(
+                    pool.at[pl.ds(0, entries)],
+                    buf.at[slot, pl.ds(0, entries)], sem).wait()
+
+        def a_group(g, _):
+            if wait:
+                await_(group)
+            else:
+                for u in range(group):
+                    start(g * group + u)
+
+        def an_entry(p, _):
+            await_(1) if wait else start(p)
+
+        count = jnp.minimum(fold, live - step * fold)
+        groups = count // group
+        jax.lax.fori_loop(0, groups, a_group, None)
+        jax.lax.fori_loop(groups * group, count, an_entry, None)
+
+    return copies
+
+
+def _walk_params():
+    """The three paged walks' compiler parameters: the lanes of a call
+    run IN ORDER on one core.  The kernels that clear their value
+    buffers do so once a call, at lane 0 (``_paged_attn_kernel``), which
+    holds only while no lane can run before it or on another core's
+    scratch: the lane axis must never be marked parallel."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 _LANES = 128        # the lane width of a vector tile
@@ -405,21 +510,30 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     """Grid (lane,): the lane walks the blocks it holds
     (``paged_blocks_walked``), ``fold`` table entries to a step, and
     stops there.  The pools stay in HBM; each step's blocks come in by
-    hand-issued copies steered by the scalar-prefetched table, double
+    hand-issued copies steered by the scalar-prefetched table
+    (``_walk_copies``: as many as the lane holds in the step), double
     buffered so step s + 1 is in flight while step s is folded into
-    each query row's online (max, sumexp, acc) accumulator.  Row layout
-    is [heads*q_len, hd] with row = head*q_len + qi, so each GQA group's
-    rows are one contiguous slice and the per-row query position is
-    ``row % q_len``.  A sliding ``window`` (static) starts the walk at
-    ``paged_first_block``, finds a block in the lane's ``ring`` table
-    entries by its number modulo ``ring``, and drops the rows behind
-    each query's window.  ``vd``: the value head's size (a value row is
-    ``kvh * vd`` wide, as are the accumulator's and the output's
-    heads); ``spans``: ``_key_spans``; ``sink``: a [heads*q_len, 1]
-    input holds each row's sink logit, where its running maximum
-    starts, with a running sum of 1 (``softmax_with_sink``)."""
-    from jax.experimental.pallas import tpu as pltpu
+    each query row's online (max, sumexp, acc) accumulator: one
+    [rows, fold * bs] product a KV head, one maximum, sum and rescale a
+    step.  Row layout is [heads*q_len, hd] with row = head*q_len + qi,
+    so each GQA group's rows are one contiguous slice and the per-row
+    query position is ``row % q_len``.  A sliding ``window`` (static)
+    starts the walk at ``paged_first_block``, finds a block in the
+    lane's ``ring`` table entries by its number modulo ``ring``, and
+    drops the rows behind each query's window.  ``vd``: the value
+    head's size (a value row is ``kvh * vd`` wide, as are the
+    accumulator's and the output's heads); ``spans``: ``_key_spans``;
+    ``sink``: a [heads*q_len, 1] input holds each row's sink logit,
+    where its running maximum starts, with a running sum of 1
+    (``softmax_with_sink``).
 
+    The rows of a lane's last step that no copy filled lie past the
+    lane's length, so the mask drops their scores whatever the key
+    buffer holds there; their VALUES meet a probability of 0 in a
+    product, where 0 times a NaN is NaN, so the value buffers are set
+    to zero once a call, by its first lane (the grid runs in order on
+    one core: afterwards a buffer holds zeros or rows that some lane of
+    the call held)."""
     if int8:
         ks_ref, vs_ref = rest[:2]
     if sink:
@@ -431,20 +545,12 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     steps = pl.cdiv(live, fold)
     first = paged_first_block(cur, bs, window)
 
-    def copies(step, slot, wait=False):
-        # Entries of the lane's last step past its count repeat its
-        # last live block: finite rows the mask drops, and no block the
-        # lane does not hold is ever read.  A wait needs only shapes.
-        out = []
-        for p in range(fold):
-            entry = jnp.minimum(step * fold + p, live - 1)
-            if window is not None:
-                entry = jax.lax.rem(first + entry, ring)
-            blk = 0 if wait else tbl_ref[i, entry]
-            out += [pltpu.make_async_copy(pool.at[blk], buf.at[slot, p],
-                                          sem.at[slot])
-                    for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
-        return out
+    copies = _walk_copies(tbl_ref, ((k_hbm, k_buf), (v_hbm, v_buf)), sem,
+                          fold, live, first, ring or None)
+
+    @pl.when(i == 0)
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)
 
     if sink:
         m_ref[:] = sink_ref[:]
@@ -453,8 +559,7 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
-    for c in copies(0, 0):
-        c.start()
+    copies(0, 0)
     qf = q_ref[0].astype(jnp.float32)        # [heads*q_len, span width]
     r = rep * q_len                          # rows per kv-head group
     n = fold * bs                            # cache rows a step folds
@@ -472,11 +577,9 @@ def _paged_attn_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
         @pl.when(step + 1 < steps)
         def _():
-            for c in copies(step + 1, 1 - slot):
-                c.start()
+            copies(step + 1, 1 - slot)
 
-        for c in copies(step, slot, wait=True):
-            c.wait()
+        copies(step, slot, wait=True)
         kf = k_buf[slot].astype(jnp.float32).reshape(n, kvh * hd)
         vf = v_buf[slot].astype(jnp.float32).reshape(n, kvh * vd)
         if window is None:
@@ -589,7 +692,8 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
              for g, (col0, _) in enumerate(spans)],
             axis=2).reshape(lanes, q_len, heads, width)
     int8 = k_scales is not None
-    fold = _paged_fold(bs, n_blk)
+    fold = _paged_fold(bs, n_blk, _step_row_bytes(
+        (k_pool, v_pool), rep * q_len, widened=True))
     last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
     walk = {}
     if window is not None:
@@ -644,6 +748,7 @@ def paged_attention(q, k_pool, v_pool, table, lengths, *,
         ),
         out_shape=jax.ShapeDtypeStruct((lanes, heads * q_len, vd),
                                        q.dtype),
+        compiler_params=_walk_params(),
         interpret=interpret,
     )(*args)
     return out.reshape(lanes, heads, q_len, vd).transpose(0, 2, 1, 3)
@@ -1278,7 +1383,8 @@ def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
                          ring=0):
     """``_paged_attn_kernel``'s walk over ONE pool: grid (lane,), the
     lane's own blocks (``paged_blocks_walked``) ``fold`` to a
-    double-buffered copy-and-fold step.  A row is the key of every head
+    double-buffered copy-and-fold step, of which the blocks the lane
+    holds are copied (``_walk_copies``).  A row is the key of every head
     and, in its leading ``value_dim`` columns, the value of every head,
     so it is copied once and the query rows [heads*q_len, row] meet it
     in two products.  The products take the pool's own type (bf16 on
@@ -1286,34 +1392,27 @@ def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
     float32.  A sliding ``window`` (static) is ``_paged_attn_kernel``'s:
     the walk starts at ``paged_first_block``, a block lies in the
     lane's ``ring`` table entries at its number modulo ``ring``, and
-    the rows behind each query's window are dropped."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    the rows behind each query's window are dropped.  The buffer is the
+    values' too, so it is set to zero once a call, by its first lane,
+    as ``_paged_attn_kernel``'s value buffers are and for its reason: a
+    row that no copy filled meets a probability of 0 in a product."""
     i = pl.program_id(0)
     cur = len_ref[i]
     live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1, window)
     steps = pl.cdiv(live, fold)
     first = paged_first_block(cur, bs, window)     # 0 without a window
 
-    def entry(at):
-        # Past the lane's count a step repeats the lane's last live
-        # block: finite rows the mask drops; no block the lane does not
-        # hold is read.
-        at = jnp.minimum(at, live - 1)
-        return at if window is None else jax.lax.rem(first + at, ring)
+    copies = _walk_copies(tbl_ref, ((pool_hbm, buf),), sem, fold, live,
+                          first, ring or None)
 
-    def copies(step, slot, wait=False):
-        # A wait needs only shapes.
-        return [pltpu.make_async_copy(
-            pool_hbm.at[0 if wait else tbl_ref[
-                i, entry(step * fold + p)]],
-            buf.at[slot, p], sem.at[slot]) for p in range(fold)]
+    @pl.when(i == 0)
+    def _():
+        buf[...] = jnp.zeros_like(buf)
 
     m_ref[:] = jnp.full_like(m_ref, _NEG)
     l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
-    for c in copies(0, 0):
-        c.start()
+    copies(0, 0)
     q = q_ref[0]                             # [heads*q_len, row]
     r, n = q.shape[0], fold * bs
     col = jax.lax.broadcasted_iota(jnp.int32, (r, n), 1)
@@ -1325,11 +1424,9 @@ def _paged_latent_kernel(tbl_ref, len_ref, q_ref, pool_hbm, o_ref, buf,
 
         @pl.when(step + 1 < steps)
         def _():
-            for c in copies(step + 1, 1 - slot):
-                c.start()
+            copies(step + 1, 1 - slot)
 
-        for c in copies(step, slot, wait=True):
-            c.wait()
+        copies(step, slot, wait=True)
         rows = buf[slot].reshape(n, buf.shape[-1])
         logits = jax.lax.dot_general(
             q, rows, (((1,), (1,)), ((), ())),
@@ -1385,7 +1482,7 @@ def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
     nb, bs, row = pool.shape
     lanes, q_len, heads, _ = q.shape
     n_blk = table.shape[1]
-    fold = _paged_fold(bs, n_blk)
+    fold = _paged_fold(bs, n_blk, _step_row_bytes((pool,), heads * q_len))
     last_row = min(cache_len or n_blk * bs, n_blk * bs) - 1
     walk = {}
     if window is not None:
@@ -1420,6 +1517,7 @@ def paged_latent_attention(q, pool, table, lengths, *, value_dim: int,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((lanes, r, value_dim), q.dtype),
+        compiler_params=_walk_params(),
         interpret=interpret,
     )(table, lengths.astype(jnp.int32), qt, pool)
     return out.reshape(lanes, heads, q_len, value_dim).transpose(0, 2, 1, 3)
@@ -1454,45 +1552,38 @@ def _paged_index_kernel(tbl_ref, len_ref, q_ref, w_ref, pool_hbm, o_ref,
                         buf, sem, *, bs, fold, last_row, q_len, heads):
     """``_paged_latent_kernel``'s walk over the index keys: grid
     (lane,), the lane's own blocks (``paged_blocks_walked``) ``fold``
-    to a double-buffered copy-and-score step.  A step's keys [fold *
+    to a double-buffered copy-and-score step, of which the blocks the
+    lane holds are copied (``_walk_copies``).  A step's keys [fold *
     bs, dim] meet the query rows [q_len * heads, dim] in one product
     (the pool's own type, float32 accumulation); ReLU, the heads'
     weights and their sum are float32 on the vector unit.  The scores
     of a step are one row ``o_ref[0, query, step]`` [fold * bs] a
-    query; the rows the lane's walk does not reach stay ``-inf``."""
-    from jax.experimental.pallas import tpu as pltpu
-
+    query; the rows the lane's walk does not reach stay ``-inf``.  A
+    key that no copy filled lies past the lane's length, where the
+    score is replaced and enters no product, so the buffer is never
+    cleared."""
     i = pl.program_id(0)
     cur = len_ref[i]
     live = paged_blocks_walked(cur, q_len, bs, last_row // bs + 1)
     steps = pl.cdiv(live, fold)
 
-    def copies(step, slot, wait=False):
-        return [pltpu.make_async_copy(
-            pool_hbm.at[0 if wait else tbl_ref[
-                i, jnp.minimum(step * fold + p, live - 1)]],
-            buf.at[slot, p], sem.at[slot]) for p in range(fold)]
+    copies = _walk_copies(tbl_ref, ((pool_hbm, buf),), sem, fold, live)
 
     o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
-    for c in copies(0, 0):
-        c.start()
+    copies(0, 0)
     q = q_ref[0]                             # [q_len*heads, dim]
     w = w_ref[0]                             # [q_len*heads, 1] float32
     n = fold * bs
     col = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-    last_seen = jnp.minimum(cur + jax.lax.broadcasted_iota(
-        jnp.int32, (q_len, n), 0), last_row)
 
     def score_step(step, _):
         slot = jax.lax.rem(step, 2)
 
         @pl.when(step + 1 < steps)
         def _():
-            for c in copies(step + 1, 1 - slot):
-                c.start()
+            copies(step + 1, 1 - slot)
 
-        for c in copies(step, slot, wait=True):
-            c.wait()
+        copies(step, slot, wait=True)
         keys = buf[slot].reshape(n, buf.shape[-1])
         s = jax.lax.dot_general(
             q, keys, (((1,), (1,)), ((), ())),
@@ -1500,7 +1591,7 @@ def _paged_index_kernel(tbl_ref, len_ref, q_ref, w_ref, pool_hbm, o_ref,
         s = jnp.maximum(s, 0.0) * w
         for j in range(q_len):
             o_ref[0, j, pl.ds(step, 1), :] = jnp.where(
-                step * n + col <= last_seen[j:j + 1],
+                step * n + col <= jnp.minimum(cur + j, last_row),
                 jnp.sum(s[j * heads:(j + 1) * heads], axis=0,
                         keepdims=True), -jnp.inf)
 
@@ -1524,7 +1615,7 @@ def paged_index_scores(q, w, pool, table, lengths, *,
     nb, bs, dim = pool.shape
     lanes, q_len, heads, _ = q.shape
     n_blk = table.shape[1]
-    fold = _paged_fold(bs, n_blk)
+    fold = _paged_fold(bs, n_blk, _step_row_bytes((pool,), heads * q_len))
     c = min(cache_len or n_blk * bs, n_blk * bs)
     n = fold * bs
     slabs = -(-n_blk // fold)
@@ -1552,6 +1643,7 @@ def paged_index_scores(q, w, pool, table, lengths, *,
         ),
         out_shape=jax.ShapeDtypeStruct((lanes, q_len, slabs, n),
                                        jnp.float32),
+        compiler_params=_walk_params(),
         interpret=interpret,
     )(table, lengths.astype(jnp.int32),
       q.reshape(lanes, r, dim).astype(pool.dtype),

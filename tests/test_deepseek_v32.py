@@ -382,7 +382,7 @@ class TestPagedIndexKernel:
     @pytest.mark.parametrize("q_len", [1, 3])
     def test_kernel_matches_the_gathered_reference(self, q_len,
                                                    monkeypatch):
-        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        monkeypatch.setattr(pk, "_paged_fold", lambda *shape: self.FOLD)
         case = self._case(q_len)
         want = pk.paged_index_scores(*case, use_pallas=False)
         got = pk.paged_index_scores(*case, use_pallas=True, interpret=True)
@@ -396,7 +396,7 @@ class TestPagedIndexKernel:
     def test_walk_stops_at_the_lanes_length(self, monkeypatch):
         """Every block a lane's length does not reach is NaN and the
         scores do not move."""
-        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        monkeypatch.setattr(pk, "_paged_fold", lambda *shape: self.FOLD)
         q, w, pool, table, lengths = self._case(1, seed=5)
         clean = pk.paged_index_scores(q, w, pool, table, lengths,
                                       use_pallas=True, interpret=True)

@@ -307,7 +307,7 @@ class TestPagedLatentKernel:
     @pytest.mark.parametrize("q_len", [1, 3])
     def test_kernel_matches_the_gathered_reference(self, q_len,
                                                    monkeypatch):
-        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        monkeypatch.setattr(pk, "_paged_fold", lambda *shape: self.FOLD)
         case = self._case(q_len)
         want = self._run(*case, use_pallas=False)
         got = self._run(*case, use_pallas=True, interpret=True)
@@ -320,7 +320,7 @@ class TestPagedLatentKernel:
         """PR 25's poison test for the latent pool: every block a
         lane's length does not reach is NaN and the output does not
         move."""
-        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        monkeypatch.setattr(pk, "_paged_fold", lambda *shape: self.FOLD)
         q, pool, table, lengths = self._case(q_len, seed=5)
         clean = self._run(q, pool, table, lengths, use_pallas=True,
                           interpret=True)

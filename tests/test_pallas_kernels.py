@@ -287,7 +287,7 @@ class TestPagedAttention:
     @pytest.mark.parametrize("q_len", [1, 4])
     def test_ragged_walk_matches_oracle(self, q_len, heads, kvh, int8,
                                         monkeypatch):
-        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        monkeypatch.setattr(pk, "_paged_fold", lambda *shape: self.FOLD)
         q, kp, vp, table, lengths, scales = self._walk_case(
             q_len, heads, kvh, int8)
         if not int8:    # the cell's storage: bf16 rows, f32 arithmetic
@@ -309,7 +309,7 @@ class TestPagedAttention:
         rows, or NaN scales under int8 rows) and the output does not
         move: the walk reads ``paged_blocks_walked`` blocks of a lane's
         table and nothing behind them."""
-        monkeypatch.setattr(pk, "_paged_fold", lambda bs, n_blk: self.FOLD)
+        monkeypatch.setattr(pk, "_paged_fold", lambda *shape: self.FOLD)
         q, kp, vp, table, lengths, scales = self._walk_case(
             q_len, 4, 2, int8, seed=5)
         clean = pk.paged_attention(q, kp, vp, table, lengths, **scales,
@@ -359,6 +359,242 @@ def test_block0_reads_one_layer_of_a_pool_that_holds_several(int8,
         assert np.all(np.isfinite(np.asarray(alone)))
         np.testing.assert_array_equal(np.asarray(stacked),
                                       np.asarray(alone))
+
+
+# ---------------------------------------------------------------------------
+# The three paged walks at a wide step (``PAGED_FOLD_ROWS``)
+# ---------------------------------------------------------------------------
+
+# Blocks of 4 rows and a step of 32: 8 table entries a step, as 512 rows
+# are 32 blocks of 16.  A context of 19 blocks is two steps and 3 blocks.
+_W_BS, _W_ROWS, _W_BLOCKS = 4, 32, 19
+#: walk -> (window, the ring's blocks): a ring shorter than a step is one
+#: step of 6 entries; a ring of 12 is a step of 8 and one of up to 3.
+_WALKS = {"table": (None, _W_BLOCKS), "ring<step": (13, 6),
+          "ring=2steps": (37, 12)}
+_WALK_KERNELS = ("attn", "attn-sink", "attn-int8", "latent", "index")
+
+
+def _wide_walk_case(kernel, walk, q_len, seed=0):
+    """One lane at every length where a wide step's count of blocks,
+    of copies or of steps changes; each lane owns its table's blocks.
+    Returns ``(call, table, lengths, pools)``: ``call(pools, **kw)`` is
+    the kernel's public entry (``use_pallas=False``: its reference)."""
+    bs, rows, n_ctx = _W_BS, _W_ROWS, _W_BLOCKS
+    window, n_blk = _WALKS[walk]
+    cache_len = n_ctx * bs
+    lengths = [0, 1, bs, rows - 1, rows, rows + 1, cache_len - q_len]
+    if window is None:
+        lengths.append(cache_len + 3)       # a lane that overran its table
+    lanes, nb = len(lengths), 1 + len(lengths) * n_blk
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(
+        1 + np.arange(lanes * n_blk).reshape(lanes, n_blk), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    walk_kw = dict(cache_len=cache_len)
+    if window is not None:
+        walk_kw["window"] = window
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    if kernel == "latent":
+        heads, row, rank = 5, 24, 16
+        q = normal(lanes, q_len, heads, row)
+        pools = {"pool": normal(nb, bs, row)}
+
+        def call(pools, **kw):
+            return pk.paged_latent_attention(
+                q, pools["pool"], table, lengths, value_dim=rank,
+                scale=0.125, **walk_kw, **kw)
+        return call, table, lengths, pools
+    if kernel == "index":
+        heads, dim = 3, 8
+        q, w = normal(lanes, q_len, heads, dim), normal(lanes, q_len, heads)
+        pools = {"pool": normal(nb, bs, dim)}
+
+        def call(pools, **kw):
+            return pk.paged_index_scores(q, w, pools["pool"], table, lengths,
+                                         **walk_kw, **kw)
+        return call, table, lengths, pools
+    heads, kvh, hd = 4, 2, 8
+    q = normal(lanes, q_len, heads, hd)
+    extra = {}
+    if kernel == "attn-sink":
+        extra["sink_logits"] = normal(heads)
+    if kernel == "attn-int8":
+        pools = {name: jnp.asarray(
+            rng.integers(-127, 128, (nb, bs, kvh * hd)), jnp.int8)
+            for name in ("k_pool", "v_pool")}
+        pools.update({name: jnp.asarray(
+            np.abs(rng.normal(size=(nb, bs, kvh))) / 127 + 1e-3, jnp.float32)
+            for name in ("k_scales", "v_scales")})
+    else:
+        pools = {"k_pool": normal(nb, bs, kvh * hd),
+                 "v_pool": normal(nb, bs, kvh * hd)}
+
+    def call(pools, **kw):
+        pools = dict(pools)
+        return pk.paged_attention(
+            q, pools.pop("k_pool"), pools.pop("v_pool"), table, lengths,
+            **pools, **extra, **walk_kw, **kw)
+    return call, table, lengths, pools
+
+
+def _blocks_not_held(table, lengths, q_len, walk):
+    """Block 0 and every block of a lane's table that its walk does not
+    read: behind its length, or outside the span of its window's ring."""
+    window, n_blk = _WALKS[walk]
+    lengths = np.asarray(lengths)
+    live = np.asarray(pk.paged_blocks_walked(lengths, q_len, _W_BS,
+                                             _W_BLOCKS, window))
+    first = np.asarray(pk.paged_first_block(lengths, _W_BS, window)
+                       ) * np.ones_like(live)
+    dead = [0]
+    for lane, (f, n) in enumerate(zip(first, live)):
+        held = {int(f + e) % n_blk for e in range(n)}
+        dead += [int(table[lane, e]) for e in range(n_blk)
+                 if e not in held]
+    return np.asarray(dead)
+
+
+@pytest.mark.parametrize("q_len", [1, 3])
+@pytest.mark.parametrize("kernel,walk", [
+    (k, w) for k in _WALK_KERNELS for w in _WALKS
+    if w == "table" or k in ("attn", "attn-sink", "latent")])
+def test_wide_step_reads_what_a_lane_holds_and_nothing_else(
+        kernel, walk, q_len, monkeypatch):
+    """The three paged walks against their references with a step of
+    several blocks, lanes at every edge of a step, and every block a
+    lane does not hold poisoned (NaN rows; NaN scales under int8
+    rows): the kernel, which is given the poisoned pools, copies the
+    blocks a lane holds and no others, and what a step's buffer holds
+    beside them (the scratch's first contents, an earlier lane's rows)
+    reaches no output."""
+    monkeypatch.setattr(pk, "PAGED_FOLD_ROWS", _W_ROWS)
+    assert pk._paged_fold(_W_BS, _WALKS[walk][1], row_bytes=256) == min(
+        _WALKS[walk][1], _W_ROWS // _W_BS)
+    call, table, lengths, pools = _wide_walk_case(kernel, walk, q_len)
+    want = np.asarray(call(pools, use_pallas=False))
+    dead = _blocks_not_held(table, lengths, q_len, walk)
+    assert 0 < len(dead) < next(iter(pools.values())).shape[0]
+    poisoned = {name: pool.at[dead].set(jnp.nan)
+                if pool.dtype != jnp.int8 else pool
+                for name, pool in pools.items()}
+    got = np.asarray(call(poisoned, use_pallas=True, interpret=True))
+    assert got.shape == want.shape
+    if kernel == "index":       # -inf past a query's position, in both
+        np.testing.assert_array_equal(np.isneginf(got), np.isneginf(want))
+        assert not np.isnan(got).any()
+    else:
+        assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _kv_row_bytes(kvh, hd, dtype, q_rows, vd=None):
+    """``_step_row_bytes`` of ``paged_attention`` over pools of ``kvh``
+    heads of ``hd`` keys and ``vd`` values a row."""
+    pools = [jax.ShapeDtypeStruct((1, 16, kvh * d), dtype)
+             for d in (hd, vd or hd)]
+    return pk._step_row_bytes(pools, q_rows, widened=True)
+
+
+@pytest.mark.parametrize("name,row_bytes,rows", [
+    # The cells' widths fold the constant's rows ...
+    ("qwen25_7b", _kv_row_bytes(4, 128, jnp.bfloat16, 7), 512),
+    ("laguna-kv8d128-q3", _kv_row_bytes(8, 128, jnp.bfloat16, 18), 512),
+    ("mimo-kv4k192v128", _kv_row_bytes(4, 192, jnp.bfloat16, 16, vd=128),
+     512),
+    ("dots3-latent-h128-q3", pk._step_row_bytes(
+        [jax.ShapeDtypeStruct((1, 16, 640), jnp.bfloat16)], 384), 512),
+    # ... a wider row fewer, in whole lane tiles of rows ...
+    ("kv12d128", _kv_row_bytes(12, 128, jnp.bfloat16, 4), 384),
+    ("kv16d128", _kv_row_bytes(16, 128, jnp.bfloat16, 2), 256),
+    # ... and the MHA presets never under the 128 rows they had.
+    ("llama2_7b-int8", _kv_row_bytes(32, 128, jnp.int8, 1), 128),
+    ("llama2_7b", _kv_row_bytes(32, 128, jnp.bfloat16, 1), 128),
+    ("gemma_7b-q3", _kv_row_bytes(16, 256, jnp.bfloat16, 3), 128),
+    ("llama2_13b", _kv_row_bytes(40, 128, jnp.bfloat16, 1), 128),
+])
+def test_a_steps_rows_follow_what_a_row_costs_in_fast_memory(
+        name, row_bytes, rows):
+    """``_paged_fold``: ``PAGED_FOLD_ROWS`` is the most a step holds; a
+    step's rows by ``_step_row_bytes`` stay within ``_PAGED_STEP_VMEM``
+    wherever that leaves a lane tile of rows or more, and a short table
+    or ring is one step whatever the width."""
+    bs = 16
+    assert pk._paged_fold(bs, 4096, row_bytes) * bs == rows
+    assert rows == pk._LANES or rows * row_bytes <= pk._PAGED_STEP_VMEM
+    assert rows == pk.PAGED_FOLD_ROWS or (
+        (rows + pk._LANES) * row_bytes > pk._PAGED_STEP_VMEM)
+    assert pk._paged_fold(bs, 5, row_bytes) == 5
+
+
+def _count_eqns(jaxpr, name):
+    """Equations of primitive ``name`` in ``jaxpr`` and every jaxpr
+    inside it (a loop's body, a branch, a kernel)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += eqn.primitive.name == name
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    total += _count_eqns(inner, name)
+    return total
+
+
+def _tpu_walk(kernel):
+    """One instance of a paged walk at a served width, as shapes:
+    ``(fn, args, copies a site)``."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    lanes, bs, n_blk = 8, 16, 256
+    shape = jax.ShapeDtypeStruct
+    tail = (shape((lanes, n_blk), i32), shape((lanes,), i32))
+
+    def pool(row):
+        return shape((1 + lanes * n_blk, bs, row), bf16)
+
+    if kernel == "attn":
+        return (lambda q, k, v, t, n: pk.paged_attention(
+            q, k, v, t, n, cache_len=n_blk * bs, use_pallas=True),
+            (shape((lanes, 1, 28, 128), bf16), pool(512), pool(512), *tail),
+            2)
+    if kernel == "latent":
+        return (lambda q, p, t, n: pk.paged_latent_attention(
+            q, p, t, n, value_dim=512, scale=0.0625, cache_len=n_blk * bs,
+            use_pallas=True),
+            (shape((lanes, 1, 20, 640), bf16), pool(640), *tail), 1)
+    return (lambda q, w, p, t, n: pk.paged_index_scores(
+        q, w, p, t, n, cache_len=n_blk * bs, use_pallas=True),
+        (shape((lanes, 1, 64, 128), bf16),
+         shape((lanes, 1, 64), jnp.float32), pool(128), *tail), 1)
+
+
+@pytest.mark.parametrize("kernel", ["attn", "latent", "index"])
+def test_a_walks_traced_size_does_not_grow_with_the_steps_width(
+        kernel, monkeypatch):
+    """What every process pays before a compile cache's key exists is
+    the trace and the lowering of each kernel instance, and that
+    follows the kernel's size.  A step's copies are rolled loops
+    (``_walk_copies``: one over groups of ``_COPY_GROUP`` entries, one
+    over the entries left), so at each of its two sites (the first
+    step's copies, the next step's) the kernel holds a group's starts
+    and one more for each pool, and two waits a pool, whatever
+    ``PAGED_FOLD_ROWS``; and it lowers for a TPU.  (A count of
+    equations, not of seconds: a clock makes a test unsteady.)"""
+    counts = {}
+    for rows in (128, 512, 1024):
+        monkeypatch.setattr(pk, "PAGED_FOLD_ROWS", rows)
+        fn, args, pools = _tpu_walk(kernel)   # a new function: no cached trace
+        traced = jax.jit(fn).trace(*args)
+        assert "tpu_custom_call" in traced.lower(
+            lowering_platforms=("tpu",)).as_text()
+        counts[rows] = tuple(_count_eqns(traced.jaxpr.jaxpr, name)
+                             for name in ("dma_start", "dma_wait"))
+    assert set(counts.values()) == {
+        (2 * (pk._COPY_GROUP + 1) * pools, 2 * pools)}, counts
 
 
 def test_fused_attn_kill_switches(monkeypatch):

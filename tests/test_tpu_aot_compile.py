@@ -176,19 +176,35 @@ def _paged_latent(q_len):
          ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
 
 
-def _paged_latent_ring():
+def _paged_latent_ring(q_len=1):
     """dots3-note-prev's window layers' absorbed decode kernel at their
     published sizes: 64 heads over rows of 1024 + 64 values stored
     1,152 wide (nine lane tiles), a value of 1024, a window of 513 in a
-    ring of 34 blocks of 16 a lane, a context of 16,384."""
+    ring of 34 blocks of 16 a lane (one more under a speculative round's
+    ``q_len`` of 3), a context of 16,384."""
     lanes, cache_len, bs, heads, row, rank = 32, 16384, 16, 64, 1152, 1024
-    ring = 34
+    ring = 34 + (q_len > 1)
     return (lambda q, p, t, n: pk.paged_latent_attention(
         q, p, t, n, value_dim=rank, scale=256 ** -0.5,
         cache_len=cache_len, window=513, use_pallas=True),
-        (((lanes, 1, heads, row), BF16),
+        (((lanes, q_len, heads, row), BF16),
          ((1 + lanes * ring, bs, row), BF16),
          ((lanes, ring), jnp.int32), ((lanes,), jnp.int32)))
+
+
+def _paged_latent_chosen(q_len):
+    """dots3-note-prev's full layers' absorbed decode kernel over the
+    2,048 rows a lane's indexer chose, the widest scores a step of the
+    walk holds: 128 heads (384 query rows under a speculative round's
+    ``q_len`` of 3) over rows of 512 + 64 values stored 640 wide."""
+    lanes, cache_len, bs, heads, row, rank = 32, 2048, 16, 128, 640, 512
+    n_blk = cache_len // bs
+    return (lambda q, p, t, n: pk.paged_latent_attention(
+        q, p, t, n, value_dim=rank, scale=192 ** -0.5,
+        cache_len=cache_len, use_pallas=True),
+        (((lanes, q_len, heads, row), BF16),
+         ((1 + lanes * n_blk, bs, row), BF16),
+         ((lanes, n_blk), jnp.int32), ((lanes,), jnp.int32)))
 
 
 def _paged_index(q_len):
@@ -218,10 +234,15 @@ def _delta_step():
 
 
 # (heads, kv_heads, head_dim, block_size): llama_350m's layout at the
-# engine's default block size, qwen25_7b's GQA layout, and a full layer
-# of Laguna-S-2.1 (6 queries a KV head).
+# engine's default block size, qwen25_7b's GQA layout, a full layer of
+# Laguna-S-2.1 (6 queries a KV head), and the widest rows a preset
+# ships, where a step of the walk holds fewest (``pk._paged_fold``):
+# llama2_7b's, gemma_7b's and llama2_13b's MHA rows of 4,096 to 5,120
+# keys and as many values.
 _LAYOUTS = {"h16kv16d64": (16, 16, 64, 16), "h28kv4d128": (28, 4, 128, 32),
-            "h48kv8d128": (48, 8, 128, 16)}
+            "h48kv8d128": (48, 8, 128, 16), "h32kv32d128": (32, 32, 128, 16),
+            "h16kv16d256": (16, 16, 256, 16),
+            "h40kv40d128": (40, 40, 128, 16)}
 
 CASES = {
     "rms_norm-fwd": lambda: _rms(False),
@@ -237,11 +258,14 @@ CASES["delta_state_step-l64h32d128"] = _delta_step
 CASES["paged_attn-h64kv4k192v128"] = lambda: _paged_sink(None)
 CASES["paged_ring-h64kv8k192v128-w128-sink"] = lambda: _paged_sink(128)
 CASES["paged_latent_ring-h64r1088-w513"] = _paged_latent_ring
+CASES["paged_latent_ring-h64r1088-w513-q3"] = lambda: _paged_latent_ring(3)
 for _q in (1, 3):
     for _h in (48, 72):
         CASES[f"paged_ring-h{_h}kv8d128-w512-q{_q}"] = (
             lambda h=_h, q=_q: _paged_ring(h, q))
     CASES[f"paged_latent-h20r576-q{_q}"] = lambda q=_q: _paged_latent(q)
+    CASES[f"paged_latent-h128r576-chosen2048-q{_q}"] = (
+        lambda q=_q: _paged_latent_chosen(q))
     CASES[f"paged_index-h64d128-q{_q}"] = lambda q=_q: _paged_index(q)
 for _name, (_h, _kvh, _hd, _bs) in _LAYOUTS.items():
     CASES[f"paged_gather-{_name}"] = (
